@@ -39,3 +39,18 @@ def analysis_ctx():
     tree = SourceTree(os.path.join(repo, "thinvids_tpu"),
                       extra_files=(os.path.join(repo, "bench.py"),))
     return default_manifest(), tree
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs():
+    """Free each test module's compiled programs when it ends. One
+    long tier-1 process otherwise keeps every program of ~700 tests
+    alive, and XLA's CPU compiler then segfaults mid-run (seen in
+    tests/test_rdo.py under jax 0.9.0; ROADMAP D0)."""
+    yield
+    import gc
+
+    import jax
+
+    jax.clear_caches()
+    gc.collect()

@@ -190,3 +190,37 @@ class TestTypes:
         ]
         with pytest.raises(ValueError, match="duplicate"):
             concat_segments(segs)
+
+
+class TestCompileCache:
+    """core.devices.configure_compile_cache: the environment places the
+    cache; the code only supplies the fixed in-checkout default."""
+
+    @pytest.fixture
+    def cache_config(self):
+        import jax
+
+        before = jax.config.jax_compilation_cache_dir
+        yield jax.config
+        jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_env_set_code_sets_nothing(self, monkeypatch, cache_config):
+        from thinvids_tpu.core import devices
+
+        before = cache_config.jax_compilation_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+        assert devices.configure_compile_cache() == "/somewhere/else"
+        assert cache_config.jax_compilation_cache_dir == before
+
+    def test_env_unset_uses_fixed_checkout_path(self, monkeypatch,
+                                                cache_config):
+        import os
+
+        from thinvids_tpu.core import devices
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache")
+        assert devices.DEFAULT_COMPILE_CACHE == want
+        assert devices.configure_compile_cache() == want
+        assert cache_config.jax_compilation_cache_dir == want

@@ -321,3 +321,27 @@ class TestGuards:
         bad.luma_ac[0, 0, 0] = 3000
         with pytest.raises(ValueError, match="too large"):
             pack_slice(bad, 4, 3, sps, pps, 27, native=True)
+
+
+def test_native_artifact_is_named_by_source_content(tmp_path, monkeypatch):
+    """The .so's name carries a hash of cavlc_pack.cpp's CONTENT (no
+    mtime comparison): an artifact built from any other source — stale,
+    or copied in from another tree — is never the one loaded."""
+    import hashlib
+    import os
+
+    from thinvids_tpu import native
+
+    with open(native._SRC, "rb") as fp:
+        source = fp.read()
+    assert native.source_hash() == hashlib.sha256(source).hexdigest()[:16]
+    real = native._so_path("")
+    assert os.path.basename(real) == \
+        f"cavlc_pack.{native.source_hash()}.so"
+    assert native._so_path("asan").endswith(
+        f".{native.source_hash()}.asan.so")
+
+    edited = tmp_path / "cavlc_pack.cpp"
+    edited.write_bytes(source + b"\n// edited\n")
+    monkeypatch.setattr(native, "_SRC", str(edited))
+    assert native._so_path("") != real

@@ -67,3 +67,92 @@ def test_pallas_kernel_matches_xla_reference(w, h):
     # sanity: the engineered content really did split MB decisions
     mv = np.asarray(out_x[0]).reshape(-1, 2)
     assert len({tuple(v) for v in mv}) > 1
+
+
+# ---------------------------------------------------------------------------
+# the production (non-interpret) kernel, lowered for TPU from the CPU
+#
+# On CPU `use_pallas()` is False, so every other test takes the XLA
+# mirror and the kernel only ever runs in the interpreter, outside
+# shard_map. These trace the path a chip takes — `use_pallas` forced
+# on — and cross-lower it for TPU, so a jax upgrade that breaks the
+# kernel (or its legality under shard_map's check_vma) fails here
+# instead of on the device.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def force_pallas(monkeypatch):
+    # traces are cached per (function, shapes): a trace another test
+    # took through the XLA mirror must not be handed back here, nor
+    # this one's to a later test
+    jax.clear_caches()
+    monkeypatch.setattr(jaxme, "use_pallas", lambda: True)
+    yield
+    jax.clear_caches()
+
+
+def _tpu_text(jitted, *args, **kwargs) -> str:
+    return jitted.trace(*args, **kwargs).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def test_kernel_lowers_for_tpu_at_1080p(force_pallas):
+    H, W = 1088, 1920
+    plane = jax.ShapeDtypeStruct((H, W), jnp.int16)
+    chroma = jax.ShapeDtypeStruct((H // 2, W // 2), jnp.int16)
+    text = _tpu_text(
+        jax.jit(jaxme.me_search), plane, plane, chroma, chroma,
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_kernel_lowers_inside_gop_wave_shard_map(force_pallas):
+    from jax.sharding import Mesh
+
+    from thinvids_tpu.parallel import dispatch
+
+    mesh = Mesh(np.array(jax.devices()[:8]), ("gop",))
+    G, F, H, W = 8, 2, 64, 256
+    text = _tpu_text(
+        dispatch._encode_wave_gop,
+        jax.ShapeDtypeStruct((G, F, H, W), jnp.uint8),
+        jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
+        jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
+        jax.ShapeDtypeStruct((G,), jnp.int32),
+        mbw=W // 16, mbh=H // 16, mesh=mesh, compact=True)
+    assert "tpu_custom_call" in text
+
+
+def test_kernel_lowers_inside_sfe_p_step_shard_map(force_pallas):
+    from jax.sharding import Mesh
+
+    from thinvids_tpu.parallel import dispatch
+
+    n = 8
+    mesh = Mesh(np.array(jax.devices()[:n]), ("band",))
+    mbh_band, W = 2, 256
+    H = n * mbh_band * 16
+    y = jax.ShapeDtypeStruct((H, W), jnp.uint8)
+    c = jax.ShapeDtypeStruct((H // 2, W // 2), jnp.uint8)
+    ry = jax.ShapeDtypeStruct((H, W), jnp.int16)
+    rc = jax.ShapeDtypeStruct((H // 2, W // 2), jnp.int16)
+    text = _tpu_text(
+        dispatch._sfe_p_step, y, c, c, ry, rc, rc,
+        jax.ShapeDtypeStruct((n, 2), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32),
+        jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        mbw=W // 16, mbh_band=mbh_band, mesh=mesh, halo_rows=32,
+        num_bands=n)
+    assert "tpu_custom_call" in text
+
+
+def test_use_pallas_is_not_silent(monkeypatch):
+    """cpu -> the XLA mirror, tpu -> the kernel, anything else raises
+    (the mirror is not a fallback for an unknown platform); the choice
+    is readable afterwards (`/metrics_snapshot` -> motion_search)."""
+    assert jaxme.use_pallas() is False
+    assert jaxme.motion_search() == "xla"
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        jaxme.use_pallas()

@@ -155,7 +155,7 @@ def _banded_me(cur, ref, ru, rv, pmv, qp, bands, halo):
     `bands` devices of the virtual mesh."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from thinvids_tpu.core.devices import shard_map
+    from jax import shard_map
 
     H = cur.shape[0]
     Hb = H // bands

@@ -39,12 +39,10 @@ Layout:
     cli.py     coordinator + agent + worker daemon entrypoints
                (deploy/*.service)
 
-Known deviation: H.264 in-loop deblocking stays disabled in the emitted
-bitstreams (PPS/slice flags). The spec's filter order is an MB-raster
-wavefront — each MB's vertical edges read the horizontally-filtered
-output of its left neighbor — which is inherently sequential at MB
-granularity and maps poorly onto XLA's whole-array execution model;
-output quality is instead tracked via the PSNR/SSIM bench line.
+H.264 in-loop deblocking (§8.7) is implemented on the recon carried
+between frames (codecs/h264/deblock.py, jaxdeblock.py) and signaled in
+the slice headers; like the other rate-distortion features it is off by
+default (the `deblock` setting, core/config.py).
 """
 
 __version__ = "0.4.0"

@@ -594,6 +594,11 @@ class ApiServer:
                 "active": w.host in active,
                 "disabled": w.disabled,
                 "quarantine_reason": w.quarantine_reason,
+                # what the node's own agent sampled from jax
+                # (cluster/agent.sample_device_metrics)
+                "platform": w.metrics.get("platform", ""),
+                "device_kind": w.metrics.get("device_kind", ""),
+                "devices": self.coordinator.worker_devices(w),
             })
         nodes.sort(key=lambda n: n["host"])
         return 200, {"nodes": nodes}
@@ -915,6 +920,9 @@ class ApiServer:
         metrics = {w.host: dict(w.metrics, last_seen=w.last_seen)
                    for w in self.coordinator.registry.all()}
         out: dict[str, Any] = {"metrics": metrics}
+        # why the admission gate last held a WAITING job back ("" =
+        # it did not): the answer to "queued forever, exit code 0"
+        out["scheduler"] = {"wait_reason": self.coordinator.wait_reason}
         # Host encode-stage breakdown (decode / stage / dispatch /
         # device wait / fetch / dense_retry / sparse unpack / unflatten
         # / pack / concat wall-clock ms) plus the boundary counters
@@ -932,6 +940,13 @@ class ApiServer:
         # (dashboard SFE line + this snapshot)
         out["sfe_latency_ms"] = (disp.frame_latency_percentiles()
                                  if disp is not None else {})
+        # which motion search this process traced: "pallas" (the TPU
+        # kernel) or "xla" (its CPU mirror); None before the first P
+        # frame — an operator (and chip_smoke.py) reads here whether
+        # the kernel or the mirror served the jobs
+        jaxme = _sys.modules.get("thinvids_tpu.codecs.h264.jaxme")
+        out["motion_search"] = (jaxme.motion_search()
+                                if jaxme is not None else None)
         if self.work is not None:
             out["work"] = self.work.snapshot()
         # origin serving counters + per-job concurrent-session gauges

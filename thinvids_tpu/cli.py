@@ -27,6 +27,20 @@ import signal
 import threading
 
 
+def _prepare_encode_process(log) -> None:
+    """Start-up of a process that compiles and packs (coordinator,
+    worker): place the persistent compile cache, and say out loud when
+    the native entropy packer cannot be built — the pure-Python packer
+    emits the same bits orders of magnitude slower."""
+    from . import native
+    from .core.devices import configure_compile_cache
+
+    log.info("jax compile cache at %s", configure_compile_cache())
+    if not native.available():
+        log.warning("native packer unavailable (no g++, or the build "
+                    "failed): entropy packing runs in pure Python")
+
+
 def run_coordinator(args: argparse.Namespace) -> None:
     from .api import ApiServer
     from .cluster.agent import NodeAgent, coordinator_submitter
@@ -39,6 +53,7 @@ def run_coordinator(args: argparse.Namespace) -> None:
     from .core.config import get_settings
 
     log = get_logging("thinvids_tpu.coordinator")
+    _prepare_encode_process(log)
     state_dir = args.state_dir or os.environ.get("TVT_STATE_DIR")
     co = Coordinator(state_dir=state_dir)
     backend = str(getattr(args, "backend", "") or
@@ -154,6 +169,7 @@ def run_worker(args: argparse.Namespace) -> None:
     from .core.log import get_logging
 
     log = get_logging("thinvids_tpu.worker")
+    _prepare_encode_process(log)
     daemon = WorkerDaemon(args.coordinator, host=args.node_name,
                           poll_s=args.poll)
     # liveness + health metrics ride the agent heartbeat; the daemon's

@@ -173,6 +173,10 @@ class Coordinator:
         self._settings_fn = settings_fn
         self._sched_lock = threading.RLock()
         self._active_ids: set[str] = set()
+        #: why the scheduler's last pass left its chosen job WAITING
+        #: ("" when it dispatched, or nothing waits) — written under
+        #: _sched_lock, served by /metrics_snapshot (`scheduler`)
+        self.wait_reason = ""
         #: QoS state: priority classes + live deadline preemption
         #: (cluster/qos.py). Executors report live part latency here;
         #: the ShardBoard and local wave loops read the batch gate.
@@ -621,17 +625,23 @@ class Coordinator:
                 and job.done_ratio >= drain_ratio)
 
     @staticmethod
-    def _worker_slots(worker: WorkerInfo) -> int:
+    def worker_devices(worker: WorkerInfo) -> int:
+        """Accelerator devices a registry row's heartbeat reports (0
+        for a missing or malformed count — heartbeats come from outside
+        the process)."""
+        try:
+            return max(0, int(worker.metrics.get("devices", 0) or 0))
+        except (TypeError, ValueError):
+            return 0
+
+    @classmethod
+    def _worker_slots(cls, worker: WorkerInfo) -> int:
         """Scheduler slots one registry row contributes: the host
         itself plus one per accelerator device it reports. Devices
         used to be faked as per-device `{host}-devN` pseudo-nodes in
         the registry (VERDICT Weak #7) — now the device count rides the
         real node's heartbeat metrics and is weighted here instead."""
-        try:
-            devices = int(worker.metrics.get("devices", 0) or 0)
-        except (TypeError, ValueError):
-            devices = 0
-        return 1 + max(0, devices)
+        return 1 + cls.worker_devices(worker)
 
     def _job_rank(self, job: Job, snap: Settings | None = None) -> int:
         """Priority rank (live=0 > ladder=1 > batch=2) from the job's
@@ -694,6 +704,8 @@ class Coordinator:
                 t = getattr(j, "tenant", "default") or "default"
                 usage[t] = usage.get(t, 0.0) + 1.0
             job = None
+            if not waiting:
+                self.wait_reason = ""
             while waiting:
                 chosen = min(waiting, key=lambda j: (
                     self._job_rank(j, snap),
@@ -704,7 +716,16 @@ class Coordinator:
                 ok, _why = self._can_dispatch_locked(
                     active, snap, now, rank=self._job_rank(chosen, snap))
                 if not ok:
+                    if _why != self.wait_reason:
+                        # once per change, not once per 2 s poll: a
+                        # gate that can never pass (a 1-chip host under
+                        # the default min_idle_workers) must be visible
+                        self.activity.emit(
+                            "dispatch", f"waiting: {_why}",
+                            job_id=chosen.id)
+                    self.wait_reason = _why
                     return None
+                self.wait_reason = ""
                 token = new_run_token()
 
                 def reserve(j: Job) -> None:

@@ -2265,7 +2265,12 @@ class WorkerDaemon:
                     import jax
 
                     self._device_count = len(jax.devices())
-            except Exception:   # noqa: BLE001 - degraded heartbeat
+            except Exception as exc:  # noqa: BLE001 - degraded heartbeat
+                from ..core.log import get_logging
+
+                get_logging("thinvids_tpu.worker").warning(
+                    "cannot count devices (%s: %s); heartbeat "
+                    "advertises 1", type(exc).__name__, exc)
                 self._device_count = 1
         return {"worker": True, "worker_busy": self.busy,
                 "worker_devices": self._device_count,

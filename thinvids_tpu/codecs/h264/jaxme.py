@@ -52,6 +52,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.log import get_logging
+
+_LOG = get_logging(__name__)
+
 SEARCH_RANGE = 16          # max |mv| in integer pel
 _WR = 4                    # integer window radius (pel) around each center
 _HR = 3                    # fine half-pel window radius (half units)
@@ -497,11 +501,14 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
                     k=k, kl=kl)))
     in_specs.append(vspec((256, 384), lambda r, c: (0, 0)))
 
+    # under shard_map the outputs vary over the same mesh axes as the
+    # frame they are computed from (check_vma requires it to be said)
+    vma = jax.typeof(cur).vma
     out_shape = (
-        jax.ShapeDtypeStruct((RG, nch, 8, 256), jnp.int32),
-        jax.ShapeDtypeStruct((H4, WcK), jnp.int16),
-        jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16),
-        jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16),
+        jax.ShapeDtypeStruct((RG, nch, 8, 256), jnp.int32, vma=vma),
+        jax.ShapeDtypeStruct((H4, WcK), jnp.int16, vma=vma),
+        jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16, vma=vma),
+        jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16, vma=vma),
     )
     out_specs = (
         pl.BlockSpec((1, 1, 8, 256), lambda r, c: (r, c, 0, 0),
@@ -692,8 +699,33 @@ def centers_from(cur16, ref16, pred_mv_h):
 # public entry
 # ---------------------------------------------------------------------------
 
+#: the motion search this process traced first ("pallas" | "xla"),
+#: None until then — read by /metrics_snapshot (`motion_search`)
+_CHOSEN: str | None = None
+
+
 def use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
+    """The one switch between the kernel (TPU) and its XLA mirror (CPU).
+    The choice is logged once per process and kept for `motion_search()`;
+    any other backend raises — the mirror is the CPU path, not a
+    fallback for a platform the kernel was never built for."""
+    global _CHOSEN
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"motion search supports the tpu (Pallas kernel) and cpu "
+            f"(XLA mirror) backends, not {backend!r}")
+    if _CHOSEN is None:
+        _CHOSEN = "pallas" if backend == "tpu" else "xla"
+        _LOG.info("motion search: %s on %s (%s x%d)", _CHOSEN, backend,
+                  jax.devices()[0].device_kind, len(jax.devices()))
+    return backend == "tpu"
+
+
+def motion_search() -> str | None:
+    """Which motion search this process runs: "pallas", "xla", or None
+    before the first P frame was traced."""
+    return _CHOSEN
 
 
 def me_search_pallas(cur_y16, ref_y16, ref_u16, ref_v16, centers, lam,

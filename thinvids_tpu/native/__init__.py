@@ -6,7 +6,10 @@ TPU kernel (bit-serial, data-dependent) — runs as compiled C++ while the
 blockwise math stays on the TPU. Falls back to the pure-Python packer
 when no compiler is available (same output bits, tested identical).
 
-Build artifacts go to native/_build/ (gitignored).
+Build artifacts go to native/_build/ (gitignored), named by a hash of
+the source's content, so only an artifact built from cavlc_pack.cpp as
+it stands is ever loaded. The coordinator and worker daemons log a
+WARNING at start-up when the packer cannot be built (cli.py).
 
 Sanitizer builds: ``TVT_NATIVE_SANITIZE=asan|ubsan`` compiles the
 library with AddressSanitizer / UndefinedBehaviorSanitizer (own .so
@@ -24,6 +27,7 @@ not leak-clean).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -49,9 +53,21 @@ def _sanitize_mode() -> str:
     return mode if mode in _SANITIZE_MODES else ""
 
 
+def source_hash() -> str:
+    """sha256 (first 16 hex digits) of cavlc_pack.cpp's content — part
+    of the artifact's name, and what the chip smoke reports."""
+    with open(_SRC, "rb") as fp:
+        return hashlib.sha256(fp.read()).hexdigest()[:16]
+
+
 def _so_path(mode: str) -> str:
+    """The artifact is named by the CONTENT of its source (not compared
+    by mtime): an .so built from any other cavlc_pack.cpp — stale, or
+    copied in from another tree — has another name and is never
+    loaded."""
     tag = f".{mode}" if mode else ""
-    return os.path.join(_BUILD_DIR, f"cavlc_pack{tag}.so")
+    return os.path.join(_BUILD_DIR,
+                        f"cavlc_pack.{source_hash()}{tag}.so")
 
 
 #: mode captured ONCE at import: flags and the .so name must come from
@@ -98,8 +114,7 @@ def _build_and_load() -> ctypes.CDLL:
         if _load_failed is not None:
             raise RuntimeError(_load_failed)
         try:
-            if (not os.path.exists(_SO)
-                    or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
+            if not os.path.exists(_SO):
                 os.makedirs(_BUILD_DIR, exist_ok=True)
                 # pid-unique tmp: concurrent builders (spawned pack
                 # sidecars racing a fresh checkout) each compile their

@@ -29,9 +29,9 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.devices import shard_map
 from ..core.types import EncodedSegment, Frame, VideoMeta
 from .dispatch import GopShardEncoder
 
