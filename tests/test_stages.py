@@ -1,0 +1,182 @@
+"""The stage names of the device program (codecs/h264/stages.py).
+
+Every served step program is compiled here on the CPU at a tiny shape
+and the `op_name` of each instruction of the compiled module — what a
+profile of the program files the op under — is checked: the stages the
+program runs all occur, a stage never encloses a stage, and nearly
+every instruction that does work carries one. Values are not tested
+here: the parity tests against the numpy reference encoder hold the
+bytes (test_inter, test_jaxcore, test_parallel, test_sfe).
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh
+
+from thinvids_tpu.codecs.h264.rdo import RdConfig
+from thinvids_tpu.codecs.h264.stages import PREFIX, STAGES, stage
+from thinvids_tpu.parallel import dispatch
+
+GOP = {"intra", "me_prep", "me_search", "me_median", "residual", "layout"}
+SPARSE = {"pack", "compact"}
+SFE_P = {"me_prep", "me_search", "me_median", "residual", "halo", "layout"}
+SFE_I = {"intra", "halo", "layout"}
+RD_ON = RdConfig(mode_decision=True, pskip=True, deblock=True)
+
+#: instructions that do no work of their own
+NO_WORK = {"constant", "parameter", "tuple", "get-tuple-element", "bitcast"}
+INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(")
+OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def _unscoped_by_design(path: str) -> bool:
+    """The exceptions to "every working instruction has a stage":
+
+    - no path at all: what the compiler made itself (copies, layout
+      broadcasts, the pieces it splits a cumsum or a sort into), and
+      the bodies jax lowers out of line and names relative to nothing
+      (`reduce_window_sum` of a cumsum, the `lt`/`select_n` of an
+      argmax's reducer);
+    - the boundary of a `shard_map`: the per-device view of an input
+      or output, before any stage is entered.
+    """
+    return (not path.startswith("jit(")
+            or re.fullmatch(r"jit\(\w+\)/shard_map(/broadcast\.\d+)?", path)
+            is not None)
+
+
+def _shapes(*specs):
+    return [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in specs]
+
+
+def _gop_args():
+    G, F, H, W = 2, 3, 32, 64
+    c = (G, F, H // 2, W // 2)
+    return (_shapes(((G, F, H, W), jnp.uint8), (c, jnp.uint8),
+                    (c, jnp.uint8), ((G,), jnp.int32)),
+            dict(mbw=W // 16, mbh=H // 16))
+
+
+def _gop_mesh():
+    return Mesh(np.array(jax.devices()[:2]), ("gop",))
+
+
+def _sfe(p_frame: bool):
+    n, mbh_band, W = 2, 2, 64
+    H = n * mbh_band * 16
+    y, c = ((H, W), jnp.uint8), ((H // 2, W // 2), jnp.uint8)
+    tail = [((), jnp.int32), ((n, 1), jnp.int32)]
+    kwargs = dict(mbw=W // 16, mbh_band=mbh_band,
+                  mesh=Mesh(np.array(jax.devices()[:n]), ("band",)),
+                  total_mb_rows=n * mbh_band)
+    if not p_frame:
+        return _shapes(y, c, c, *tail), kwargs
+    ry, rc = ((H, W), jnp.int16), ((H // 2, W // 2), jnp.int16)
+    return (_shapes(y, c, c, ry, rc, rc, ((n, 2), jnp.int32), *tail),
+            dict(kwargs, halo_rows=16, num_bands=n))
+
+
+def _lower_gop(program, **more):
+    args, kwargs = _gop_args()
+    return program.lower(*args, **kwargs, **more)
+
+
+def _lower_sfe(program, p_frame, **more):
+    args, kwargs = _sfe(p_frame)
+    return program.lower(*args, **kwargs, **more)
+
+
+CASES = {
+    "gop_single": (
+        lambda: _lower_gop(dispatch._encode_gop_single, compact=True),
+        GOP | SPARSE),
+    "gop_single_dense": (
+        lambda: _lower_gop(dispatch._encode_gop_single_dense,
+                           dtype=jnp.int16),
+        GOP),
+    "wave_gop": (
+        lambda: _lower_gop(dispatch._encode_wave_gop, mesh=_gop_mesh(),
+                           compact=True),
+        GOP | SPARSE),
+    "wave_gop_dense": (
+        lambda: _lower_gop(dispatch._encode_wave_gop_dense,
+                           mesh=_gop_mesh(), dtype=jnp.int16),
+        GOP),
+    "sfe_intra": (
+        lambda: _lower_sfe(dispatch._sfe_intra_step, False),
+        SFE_I | SPARSE),
+    "sfe_intra_dense": (
+        lambda: _lower_sfe(dispatch._sfe_intra_step_dense, False),
+        SFE_I),
+    "sfe_p": (
+        lambda: _lower_sfe(dispatch._sfe_p_step, True),
+        SFE_P | SPARSE),
+    "sfe_p_dense": (
+        lambda: _lower_sfe(dispatch._sfe_p_step_dense, True),
+        SFE_P),
+    # the RD features: the in-loop filter is a stage of its own, and in
+    # a band it sits between two halo exchanges
+    "gop_single_rd": (
+        lambda: _lower_gop(dispatch._encode_gop_single, compact=True,
+                           rd=RD_ON),
+        GOP | SPARSE | {"deblock"}),
+    "sfe_p_rd": (
+        lambda: _lower_sfe(dispatch._sfe_p_step, True, rd=RD_ON),
+        SFE_P | SPARSE | {"deblock"}),
+}
+
+
+def _working_paths(text: str) -> list[str]:
+    """`op_name` ("" where there is none) of every instruction of a
+    compiled module that does work."""
+    paths = []
+    for line in text.splitlines():
+        found = INSTRUCTION.match(line)
+        if found and found.group(1) not in NO_WORK:
+            name = OP_NAME.search(line)
+            paths.append(name.group(1) if name else "")
+    return paths
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_step_program_ops_are_filed_under_their_stage(case):
+    lower, want = CASES[case]
+    paths = _working_paths(lower().compile().as_text())
+    assert len(paths) > 500, "the compiled module was not read"
+    seen, unscoped = set(), []
+    for path in paths:
+        parts = path.split("/")
+        at = [i for i, part in enumerate(parts) if part.startswith(PREFIX)]
+        if not at:
+            unscoped.append(path)
+            continue
+        seen.add(parts[at[-1]][len(PREFIX):])
+        # (ii) a stage never encloses a stage. Only `layout` may stand
+        # before another scope, and only as the name of a loop (GOPs,
+        # P frames) whose body holds it.
+        for outer, inner in zip(at, at[1:]):
+            assert parts[outer] == PREFIX + "layout" \
+                and "while" in parts[outer + 1:inner], path
+    # (i) each stage the program runs occurs, and no name outside the set
+    assert seen <= set(STAGES)
+    assert want <= seen, f"stages missing: {sorted(want - seen)}"
+    assert not seen & (set(STAGES) - want - {"layout"}), \
+        f"unexpected stages: {sorted(seen - want)}"
+    # (iii) what carries no stage is an exception named above, and rare
+    strays = sorted({p for p in unscoped if not _unscoped_by_design(p)})
+    assert not strays, f"ops outside every stage: {strays[:10]}"
+    assert len(unscoped) <= 0.10 * len(paths), \
+        f"{len(unscoped)} of {len(paths)} working instructions unscoped"
+
+
+def test_stage_names_are_a_closed_set():
+    with pytest.raises(ValueError, match="no stage named"):
+        stage("mux")
+    text = jax.jit(stage("pack")(lambda x: x + 1)).lower(
+        jnp.zeros(4, jnp.int32)).as_text(debug_info=True)
+    assert '"jit(<lambda>)/tvt.pack/add"' in text

@@ -29,6 +29,7 @@ from . import rdo
 from .encoder import FrameLevels, _mode_policy
 from .intra import LUMA_BLOCK_ORDER
 from .rdo import RD_OFF
+from .stages import stage
 from .transform import MF_TABLE, V_TABLE, ZIGZAG_4x4, CHROMA_QP_TABLE
 
 _MF = jnp.asarray(MF_TABLE)          # (6, 4, 4)
@@ -305,6 +306,7 @@ def _chroma_dc_pred_row(ts4, ls4, avail_left, avail_top):
     return jnp.concatenate([top, bot], axis=1)
 
 
+@stage("intra")
 def _intra_core(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
     """Intra compute for one (padded) frame.
 
@@ -568,13 +570,14 @@ def _encode_intra_packed(y, u, v, qp, *, mbw: int, mbh: int, dtype,
     the tail (see intra_flat_len)."""
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
     luma_dc, luma_ac, chroma_dc, chroma_ac = out[:4]
-    parts = [luma_dc.reshape(-1), luma_ac.reshape(-1),
-             chroma_dc.reshape(-1), chroma_ac.reshape(-1)]
-    flat = jnp.concatenate(parts).astype(dtype)
-    if rd.ships_modes:
-        flat = jnp.concatenate([flat,
-                                _mode_tail(out[7], out[8], out[9])
-                                .astype(dtype)])
+    with stage("layout"):
+        parts = [luma_dc.reshape(-1), luma_ac.reshape(-1),
+                 chroma_dc.reshape(-1), chroma_ac.reshape(-1)]
+        flat = jnp.concatenate(parts).astype(dtype)
+        if rd.ships_modes:
+            flat = jnp.concatenate([flat,
+                                    _mode_tail(out[7], out[8], out[9])
+                                    .astype(dtype)])
     return flat
 
 
@@ -596,6 +599,7 @@ _SPARSE_ESCAPES = 4096
 _BIT_WEIGHTS = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], jnp.uint8)
 
 
+@stage("pack")
 def _sparse_pack(flat, budget_div: int = _SPARSE_BUDGET_DIV):
     """Compact a flat int32 level vector on device.
 
@@ -644,6 +648,7 @@ _BLOCK = 16
 _BLOCK_BUDGET_DIV = 4
 
 
+@stage("pack")
 def _block_sparse_pack(flat, budget_div: int = _BLOCK_BUDGET_DIV):
     """Compact a flat int16 level vector on device at BLOCK granularity.
 
@@ -710,6 +715,7 @@ def block_sparse_fits(nblk: int, n_esc: int, L: int,
 _VAL_BUDGET_DIV = 24
 
 
+@stage("pack")
 def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
                         val_div: int = _VAL_BUDGET_DIV):
     """Two-tier device compaction: block-granular gather (tier 1, see
@@ -794,6 +800,7 @@ def _block_sparse_unpack2(nblk: int, nval: int, bitmap: np.ndarray,
     return block_sparse_unpack2_host(nblk, nval, bitmap, bmask16, vals, L)
 
 
+@stage("compact")
 def _compact_stream(nblk, nval, bitmap, bmask16, vals):
     """Device-side stream compaction (tier 3 of the transfer pack):
     concatenate the two-tier sparse streams into ONE dense uint8
@@ -882,11 +889,14 @@ def _sparse_unpack(nnz: int, n_esc: int, bitmap: np.ndarray,
 def _encode_intra_sparse(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
     luma_dc, luma_ac, chroma_dc, chroma_ac = out[:4]
-    parts = [luma_dc.reshape(-1), luma_ac.reshape(-1),
-             chroma_dc.reshape(-1), chroma_ac.reshape(-1)]
-    if rd.ships_modes:
-        parts.append(_mode_tail(out[7], out[8], out[9]).astype(jnp.int32))
-    return _sparse_pack(jnp.concatenate(parts))
+    with stage("layout"):
+        parts = [luma_dc.reshape(-1), luma_ac.reshape(-1),
+                 chroma_dc.reshape(-1), chroma_ac.reshape(-1)]
+        if rd.ships_modes:
+            parts.append(_mode_tail(out[7], out[8], out[9])
+                         .astype(jnp.int32))
+        flat = jnp.concatenate(parts)
+    return _sparse_pack(flat)
 
 
 def _unpack_levels(flat: np.ndarray, mbw: int, mbh: int,

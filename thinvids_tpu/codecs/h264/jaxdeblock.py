@@ -20,6 +20,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from .deblock import deblock_frame
+from .stages import stage
 
 
 class _JaxOps:
@@ -43,6 +44,7 @@ class _JaxOps:
 JAX_OPS = _JaxOps()
 
 
+@stage("deblock")
 def deblock_frame_jax(y, u, v, qp_map, *, intra: bool, nz4=None,
                       mv=None, mb_row0: int = 0,
                       total_mb_rows: int | None = None):

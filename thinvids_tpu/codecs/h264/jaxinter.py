@@ -42,6 +42,7 @@ from .jaxcore import (
 )
 from . import jaxdeblock, jaxme, rdo
 from .rdo import RD_OFF
+from .stages import stage
 
 SEARCH_RANGE = jaxme.SEARCH_RANGE      # integer-pel, each direction
 
@@ -176,25 +177,31 @@ def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
     rest of the GOP's compute).
     """
     n = mbw * mbh
-    cy16 = cy.astype(jnp.int16)
-    cu16 = cu.astype(jnp.int16)
-    cv16 = cv.astype(jnp.int16)
+    with stage("layout"):
+        cy16 = cy.astype(jnp.int16)
+        cu16 = cu.astype(jnp.int16)
+        cv16 = cv.astype(jnp.int16)
+        qp32 = qp.astype(jnp.int32)
 
     mv, pred_y, pred_u, pred_v, med_mv = jaxme.me_search(
-        cy16, ry, ru, rv, pred_mv, qp.astype(jnp.int32))
+        cy16, ry, ru, rv, pred_mv, qp32)
 
     (luma_levels, chroma_dc, chroma_ac, recon_y, recon_u, recon_v,
      nz4) = _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp,
                         qpc, mbw=mbw, mbh=mbh, blocked=blocked, rd=rd)
     if rd.deblock:
-        qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh, mbw))
+        with stage("deblock"):
+            qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh, mbw))
         recon_y, recon_u, recon_v = jaxdeblock.deblock_frame_jax(
             recon_y, recon_u, recon_v, qp_map, intra=False, nz4=nz4,
             mv=mv)
-    return (mv.reshape(n, 2), luma_levels, chroma_dc, chroma_ac,
+    with stage("layout"):
+        mv = mv.reshape(n, 2)
+    return (mv, luma_levels, chroma_dc, chroma_ac,
             recon_y, recon_u, recon_v, med_mv)
 
 
+@stage("residual")
 def _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp, qpc, *,
                 mbw: int, mbh: int, blocked: bool = True, rd=RD_OFF):
     """Residual transform/quant/recon for one P frame given its
@@ -333,20 +340,45 @@ def _intra_frame_outputs(y, u, v, qp, *, mbw: int, mbh: int, rd):
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
     il_dc, il_ac, ic_dc, ic_ac, ry, ru, rv = out[:7]
     luma_mode, chroma_mode, qp_delta = out[7:]
-    ry = ry.astype(jnp.int16)
-    ru = ru.astype(jnp.int16)
-    rv = rv.astype(jnp.int16)
+    with stage("intra"):
+        ry = ry.astype(jnp.int16)
+        ru = ru.astype(jnp.int16)
+        rv = rv.astype(jnp.int16)
     if rd.deblock:
-        qp_map = (qp.astype(jnp.int32) + qp_delta).reshape(mbh, mbw)
+        with stage("deblock"):
+            qp_map = (qp.astype(jnp.int32) + qp_delta).reshape(mbh, mbw)
         ry, ru, rv = jaxdeblock.deblock_frame_jax(
             ry, ru, rv, qp_map, intra=True)
     if rd.ships_modes:
-        tail = _mode_tail(luma_mode, chroma_mode, qp_delta)
-        intra = (il_dc, il_ac, ic_dc, ic_ac,
-                 tail[:mbw * mbh], tail[mbw * mbh:])
+        with stage("intra"):
+            tail = _mode_tail(luma_mode, chroma_mode, qp_delta)
+            intra = (il_dc, il_ac, ic_dc, ic_ac,
+                     tail[:mbw * mbh], tail[mbw * mbh:])
     else:
         intra = (il_dc, il_ac, ic_dc, ic_ac)
     return intra, (ry, ru, rv)
+
+
+@stage("layout")
+def _gop_head(ys, us, vs, qp):
+    """(qp int32, chroma qp, the IDR frame's three planes) of a GOP."""
+    qp = qp.astype(jnp.int32)
+    return qp, _QPC[jnp.clip(qp, 0, 51)], ys[0], us[0], vs[0]
+
+
+@stage("layout")
+def _scan_p_frames(p_step, recon, planes):
+    """Chain `p_step` over frames 1..F-1 of a GOP from the IDR's
+    recon. The scope names the loop itself (its `while`, the slicing
+    of the frames and the stacking of the outputs); the stages inside
+    `p_step` keep their own names."""
+    # Inits derived from data (not constants) so the scan carries keep
+    # the mesh-varying axes under shard_map — see jaxcore._varying_zero.
+    zero = _varying_zero(recon[0])
+    zero_mv = jnp.zeros(2, jnp.int32) + zero
+    _, pouts = jax.lax.scan(
+        p_step, (*recon, zero_mv), tuple(p[1:] for p in planes))
+    return pouts
 
 
 @functools.partial(jax.jit,
@@ -363,10 +395,9 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
     chained between frames (and emitted) is the §8.7-filtered plane —
     exactly what a conformant decoder holds.
     """
-    qp = qp.astype(jnp.int32)
-    qpc = _QPC[jnp.clip(qp, 0, 51)]
+    qp, qpc, y0, u0, v0 = _gop_head(ys, us, vs, qp)
     intra, (ry, ru, rv) = _intra_frame_outputs(
-        ys[0], us[0], vs[0], qp, mbw=mbw, mbh=mbh, rd=rd)
+        y0, u0, v0, qp, mbw=mbw, mbh=mbh, rd=rd)
 
     def p_step(carry, xs):
         ry, ru, rv, pred_mv = carry
@@ -379,17 +410,13 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
             outs = outs + (ry2, ru2, rv2)
         return (ry2, ru2, rv2, med_mv), outs
 
-    # Inits derived from data (not constants) so the scan carries keep
-    # the mesh-varying axes under shard_map — see jaxcore._varying_zero.
-    zero = _varying_zero(ry)
-    zero_mv = jnp.zeros(2, jnp.int32) + zero
-    _, pouts = jax.lax.scan(
-        p_step, (ry, ru, rv, zero_mv), (ys[1:], us[1:], vs[1:]))
+    pouts = _scan_p_frames(p_step, (ry, ru, rv), (ys, us, vs))
     if emit_recon:
         mv, l16, cdc, cac, pry, pru, prv = pouts
-        recon_y = jnp.concatenate([ry[None], pry]).astype(jnp.int32)
-        recon_u = jnp.concatenate([ru[None], pru]).astype(jnp.int32)
-        recon_v = jnp.concatenate([rv[None], prv]).astype(jnp.int32)
+        with stage("layout"):
+            recon_y = jnp.concatenate([ry[None], pry]).astype(jnp.int32)
+            recon_u = jnp.concatenate([ru[None], pru]).astype(jnp.int32)
+            recon_v = jnp.concatenate([rv[None], prv]).astype(jnp.int32)
         return intra, (mv, l16, cdc, cac), (recon_y, recon_u, recon_v)
     mv, l16, cdc, cac = pouts
     return intra, (mv, l16, cdc, cac)
@@ -425,10 +452,9 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF):
     # predecessor — MVs never accumulate).
     if 2 * SEARCH_RANGE > 127:
         raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
-    qp = qp.astype(jnp.int32)
-    qpc = _QPC[jnp.clip(qp, 0, 51)]
+    qp, qpc, y0, u0, v0 = _gop_head(ys, us, vs, qp)
     intra, (ry, ru, rv) = _intra_frame_outputs(
-        ys[0], us[0], vs[0], qp, mbw=mbw, mbh=mbh, rd=rd)
+        y0, u0, v0, qp, mbw=mbw, mbh=mbh, rd=rd)
 
     def p_step(carry, xs):
         ry, ru, rv, pred_mv = carry
@@ -438,23 +464,23 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF):
             blocked=False, rd=rd)
         return (ry2, ru2, rv2, med_mv), (mv.astype(jnp.int8), lp, cdc, cac)
 
-    zero = _varying_zero(ry)
-    zero_mv = jnp.zeros(2, jnp.int32) + zero
-    _, (mv8, lps, cdcs, cacs) = jax.lax.scan(
-        p_step, (ry, ru, rv, zero_mv), (ys[1:], us[1:], vs[1:]))
+    mv8, lps, cdcs, cacs = _scan_p_frames(
+        p_step, (ry, ru, rv), (ys, us, vs))
     # cdcs: (F-1, 2, n, 4) int16; cacs: (F-1, 2, H/2, W/2) int16
-    parts = [
-        intra[0].reshape(-1).astype(jnp.int16),
-        intra[1].reshape(-1).astype(jnp.int16),
-        intra[2].reshape(-1).astype(jnp.int16),
-        intra[3].reshape(-1).astype(jnp.int16),
-        lps.reshape(-1),
-        cdcs[:, 0].reshape(-1), cdcs[:, 1].reshape(-1),
-        cacs[:, 0].reshape(-1), cacs[:, 1].reshape(-1),
-    ]
-    if rd.ships_modes:
-        parts.extend([intra[4], intra[5]])
-    return mv8, jnp.concatenate(parts)
+    with stage("layout"):
+        parts = [
+            intra[0].reshape(-1).astype(jnp.int16),
+            intra[1].reshape(-1).astype(jnp.int16),
+            intra[2].reshape(-1).astype(jnp.int16),
+            intra[3].reshape(-1).astype(jnp.int16),
+            lps.reshape(-1),
+            cdcs[:, 0].reshape(-1), cdcs[:, 1].reshape(-1),
+            cacs[:, 0].reshape(-1), cacs[:, 1].reshape(-1),
+        ]
+        if rd.ships_modes:
+            parts.extend([intra[4], intra[5]])
+        flat = jnp.concatenate(parts)
+    return mv8, flat
 
 
 # ---------------------------------------------------------------------------
@@ -492,23 +518,32 @@ def _deblock_band(ry, ru, rv, qp, *, intra: bool, nz4, mv, mbw: int,
     ry_e = exch(ry, 16)
     ru_e = exch(ru, 8)
     rv_e = exch(rv, 8)
-    idx = jax.lax.axis_index(axis_name) if banded \
-        else jnp.int32(0) + _varying_zero(ry)
-    mb_row0 = idx * mbh_band - 1          # extended plane: 1 MB row above
-    qp_map = jnp.broadcast_to(qp.astype(jnp.int32),
-                              (mbh_band + 2, mbw))
+    with stage("deblock"):
+        idx = jax.lax.axis_index(axis_name) if banded \
+            else jnp.int32(0) + _varying_zero(ry)
+        mb_row0 = idx * mbh_band - 1      # extended plane: 1 MB row above
+        qp_map = jnp.broadcast_to(qp.astype(jnp.int32),
+                                  (mbh_band + 2, mbw))
     nz_e = mv_e = None
     if not intra:
-        nz_e = exch(nz4.astype(jnp.int16), 4) != 0
-        mv_e = exch(mv.reshape(mbh_band, 2 * mbw), 1) \
-            .reshape(mbh_band + 2, mbw, 2)
+        with stage("deblock"):
+            nz16 = nz4.astype(jnp.int16)
+        nz_e = exch(nz16, 4)
+        with stage("deblock"):
+            nz_e = nz_e != 0
+            mv_rows = mv.reshape(mbh_band, 2 * mbw)
+        mv_e = exch(mv_rows, 1)
+        with stage("deblock"):
+            mv_e = mv_e.reshape(mbh_band + 2, mbw, 2)
     y2, u2, v2 = jaxdeblock.deblock_frame_jax(
         ry_e, ru_e, rv_e, qp_map, intra=intra, nz4=nz_e, mv=mv_e,
         mb_row0=mb_row0, total_mb_rows=total_mb_rows)
-    return (y2[16:16 + 16 * mbh_band], u2[8:8 + 8 * mbh_band],
-            v2[8:8 + 8 * mbh_band])
+    with stage("deblock"):
+        return (y2[16:16 + 16 * mbh_band], u2[8:8 + 8 * mbh_band],
+                v2[8:8 + 8 * mbh_band])
 
 
+@stage("halo")
 def _fixup_band_recon(plane, real_rows, scale: int = 1):
     """Maintain the SFE recon invariant on a band plane: rows at/past
     this band's real content (the last band's MB padding) are the
@@ -530,9 +565,13 @@ def _sfe_intra_common(y, u, v, qp, real_rows, *, mbw: int,
     (with rd.deblock) the cross-band-halo in-loop filter on the carry.
     Returns (core outputs, (ry, ru, rv, zero_mv))."""
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh_band, rd=rd)
-    ry = _fixup_band_recon(out[4].astype(jnp.int16), real_rows)
-    ru = _fixup_band_recon(out[5].astype(jnp.int16), real_rows, 2)
-    rv = _fixup_band_recon(out[6].astype(jnp.int16), real_rows, 2)
+
+    def recon(plane, scale):
+        with stage("intra"):
+            plane = plane.astype(jnp.int16)
+        return _fixup_band_recon(plane, real_rows, scale)
+
+    ry, ru, rv = recon(out[4], 1), recon(out[5], 2), recon(out[6], 2)
     if rd.deblock:
         # SFE runs AQ-free (enforced at encoder construction), so the
         # band qp map is flat and no qp metadata crosses bands.
@@ -543,7 +582,8 @@ def _sfe_intra_common(y, u, v, qp, real_rows, *, mbw: int,
         ry = _fixup_band_recon(ry, real_rows)
         ru = _fixup_band_recon(ru, real_rows, 2)
         rv = _fixup_band_recon(rv, real_rows, 2)
-    zero_mv = jnp.zeros(2, jnp.int32) + _varying_zero(ry)
+    with stage("intra"):
+        zero_mv = jnp.zeros(2, jnp.int32) + _varying_zero(ry)
     return out, (ry, ru, rv, zero_mv)
 
 
@@ -570,13 +610,14 @@ def sfe_intra_band(y, u, v, qp, real_rows, *, mbw: int, mbh_band: int,
         total_mb_rows=total_mb_rows, axis_name=axis_name,
         num_bands=num_bands)
     il_dc, il_ac, ic_dc, ic_ac = out[:4]
-    dense_parts = [il_dc.reshape(-1).astype(jnp.int16),
-                   ic_dc.reshape(-1).astype(jnp.int16)]
-    if rd.ships_modes:
-        dense_parts.append(_mode_tail(out[7], out[8], out[9]))
-    dense = jnp.concatenate(dense_parts)
-    rest = jnp.concatenate([il_ac.reshape(-1).astype(jnp.int16),
-                            ic_ac.reshape(-1).astype(jnp.int16)])
+    with stage("layout"):
+        dense_parts = [il_dc.reshape(-1).astype(jnp.int16),
+                       ic_dc.reshape(-1).astype(jnp.int16)]
+        if rd.ships_modes:
+            dense_parts.append(_mode_tail(out[7], out[8], out[9]))
+        dense = jnp.concatenate(dense_parts)
+        rest = jnp.concatenate([il_ac.reshape(-1).astype(jnp.int16),
+                                ic_ac.reshape(-1).astype(jnp.int16)])
     return dense, rest, carry
 
 
@@ -594,14 +635,16 @@ def sfe_intra_band_dense(y, u, v, qp, real_rows, *, mbw: int,
         total_mb_rows=total_mb_rows, axis_name=axis_name,
         num_bands=num_bands)
     il_dc, il_ac, ic_dc, ic_ac = out[:4]
-    parts = [
-        il_dc.reshape(-1).astype(jnp.int16),
-        il_ac.reshape(-1).astype(jnp.int16),
-        ic_dc.reshape(-1).astype(jnp.int16),
-        ic_ac.reshape(-1).astype(jnp.int16)]
-    if rd.ships_modes:
-        parts.append(_mode_tail(out[7], out[8], out[9]))
-    return jnp.concatenate(parts), carry
+    with stage("layout"):
+        parts = [
+            il_dc.reshape(-1).astype(jnp.int16),
+            il_ac.reshape(-1).astype(jnp.int16),
+            ic_dc.reshape(-1).astype(jnp.int16),
+            ic_ac.reshape(-1).astype(jnp.int16)]
+        if rd.ships_modes:
+            parts.append(_mode_tail(out[7], out[8], out[9]))
+        flat = jnp.concatenate(parts)
+    return flat, carry
 
 
 def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
@@ -637,11 +680,12 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
         raise ValueError("deblock is not supported on cross-host band "
                          "slices; use GOP sharding for this job")
     ry, ru, rv, pred_mv = carry
-    qp32 = qp.astype(jnp.int32)
-    qpc = _QPC[jnp.clip(qp32, 0, 51)]
-    cy16 = y.astype(jnp.int16)
-    cu16 = u.astype(jnp.int16)
-    cv16 = v.astype(jnp.int16)
+    with stage("layout"):
+        qp32 = qp.astype(jnp.int32)
+        qpc = _QPC[jnp.clip(qp32, 0, 51)]
+        cy16 = y.astype(jnp.int16)
+        cu16 = u.astype(jnp.int16)
+        cv16 = v.astype(jnp.int16)
     out = jaxme.me_search_banded(
         cy16, ry, ru, rv, pred_mv, qp32, halo_rows=halo_rows,
         num_bands=num_bands, axis_name=axis_name, real_rows=real_rows,
@@ -665,11 +709,12 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
         ry2 = _fixup_band_recon(ry2, real_rows)
         ru2 = _fixup_band_recon(ru2, real_rows, 2)
         rv2 = _fixup_band_recon(rv2, real_rows, 2)
-    flat = jnp.concatenate([
-        lp.reshape(-1),
-        cdc[0].reshape(-1), cdc[1].reshape(-1),
-        cac[0].reshape(-1), cac[1].reshape(-1)])
-    mv8 = mv.reshape(-1, 2).astype(jnp.int8)
+    with stage("layout"):
+        flat = jnp.concatenate([
+            lp.reshape(-1),
+            cdc[0].reshape(-1), cdc[1].reshape(-1),
+            cac[0].reshape(-1), cac[1].reshape(-1)])
+        mv8 = mv.reshape(-1, 2).astype(jnp.int8)
     if return_hist:
         # the host owns the median in farm mode: carry the INPUT pred
         # (ignored — the next step receives the cross-host median as a

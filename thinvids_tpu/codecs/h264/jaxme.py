@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.log import get_logging
+from .stages import stage
 
 _LOG = get_logging(__name__)
 
@@ -548,6 +549,7 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
 # XLA reference implementation (identical semantics; CPU/conformance)
 # ---------------------------------------------------------------------------
 
+@stage("me_search")
 def me_search_xla(cur_y, ref_y, ref_u, ref_v, centers, lam):
     """Pure-XLA mirror of the kernel: same OFFSET_TABLE, same strict-<
     selection, same interpolation — the executable spec the Pallas
@@ -673,6 +675,7 @@ def coarse_probe(cur16, ref16, sr: int = SEARCH_RANGE):
     return jnp.stack([bi // n - qsr, bi % n - qsr]) * qs
 
 
+@stage("me_median")
 def hist_median(mv_flat, lim: int):
     """Per-component median of an (n, 2) int field via histogram +
     cumsum (jnp.median sorts — measured ~4 ms on TPU for 8K MBs)."""
@@ -737,33 +740,35 @@ def me_search_pallas(cur_y16, ref_y16, ref_u16, ref_v16, centers, lam,
     production kernel code path."""
     H, W = cur_y16.shape
     mbh, mbw, H4, RG, WcK, nch, W2K, WcuK, W2cK = _geom(H, W)
-    cent = jnp.concatenate(
-        [centers[:2].reshape(-1), jnp.zeros(2, jnp.int32),
-         lam.reshape(1), jnp.zeros(1, jnp.int32)]).reshape(1, 8)
-    cur = _pad_cur(cur_y16, H, H4, W, WcK)
-    wy_ = _pad_luma_wide(ref_y16, H, H4, W, W2K)
-    wu_ = _pad_chroma_wide(ref_u16, H, H4, W, W2cK)
-    wv_ = _pad_chroma_wide(ref_v16, H, H4, W, W2cK)
-    cys = [16 + centers[i, 0] for i in range(3)]
-    cxs = [16 + centers[i, 1] for i in range(3)]
-    refy = _center_stack(wy_, cys, cxs, H4 + 128, W2K)
-    ccys = [8 + (centers[i, 0] >> 1) for i in range(3)]
-    ccxs = [8 + (centers[i, 1] >> 1) for i in range(3)]
-    refu = _center_stack(wu_, ccys, ccxs, H4 // 2 + 64, W2cK)
-    refv = _center_stack(wv_, ccys, ccxs, H4 // 2 + 64, W2cK)
-    ss = jnp.asarray(_ss_np(), jnp.bfloat16)
-    mvo, py, pu, pv = _me_pallas(cent, cur, refy, refu, refv, ss,
-                                 H=H, W=W, interpret=interpret)
-    # (RG, nch, 8, 256): rows 0:4 = bmy, 4:8 = bmx, one per MB row of
-    # the band; per-MB values sit at every 16th lane
-    bmy = mvo[:, :, 0:4, ::16]                    # (RG, nch, 4, 16)
-    bmx = mvo[:, :, 4:8, ::16]
-    bmy = bmy.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
-    bmx = bmx.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
-    mv = jnp.stack([bmy[:mbh, :mbw], bmx[:mbh, :mbw]], axis=-1)
-    return (mv, py[:H, :W].astype(jnp.int16),
-            pu[:H // 2, :W // 2].astype(jnp.int16),
-            pv[:H // 2, :W // 2].astype(jnp.int16))
+    with stage("me_prep"):
+        cent = jnp.concatenate(
+            [centers[:2].reshape(-1), jnp.zeros(2, jnp.int32),
+             lam.reshape(1), jnp.zeros(1, jnp.int32)]).reshape(1, 8)
+        cur = _pad_cur(cur_y16, H, H4, W, WcK)
+        wy_ = _pad_luma_wide(ref_y16, H, H4, W, W2K)
+        wu_ = _pad_chroma_wide(ref_u16, H, H4, W, W2cK)
+        wv_ = _pad_chroma_wide(ref_v16, H, H4, W, W2cK)
+        cys = [16 + centers[i, 0] for i in range(3)]
+        cxs = [16 + centers[i, 1] for i in range(3)]
+        refy = _center_stack(wy_, cys, cxs, H4 + 128, W2K)
+        ccys = [8 + (centers[i, 0] >> 1) for i in range(3)]
+        ccxs = [8 + (centers[i, 1] >> 1) for i in range(3)]
+        refu = _center_stack(wu_, ccys, ccxs, H4 // 2 + 64, W2cK)
+        refv = _center_stack(wv_, ccys, ccxs, H4 // 2 + 64, W2cK)
+        ss = jnp.asarray(_ss_np(), jnp.bfloat16)
+    with stage("me_search"):
+        mvo, py, pu, pv = _me_pallas(cent, cur, refy, refu, refv, ss,
+                                     H=H, W=W, interpret=interpret)
+        # (RG, nch, 8, 256): rows 0:4 = bmy, 4:8 = bmx, one per MB row
+        # of the band; per-MB values sit at every 16th lane
+        bmy = mvo[:, :, 0:4, ::16]                # (RG, nch, 4, 16)
+        bmx = mvo[:, :, 4:8, ::16]
+        bmy = bmy.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
+        bmx = bmx.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
+        mv = jnp.stack([bmy[:mbh, :mbw], bmx[:mbh, :mbw]], axis=-1)
+        return (mv, py[:H, :W].astype(jnp.int16),
+                pu[:H // 2, :W // 2].astype(jnp.int16),
+                pv[:H // 2, :W // 2].astype(jnp.int16))
 
 
 # ---------------------------------------------------------------------------
@@ -791,6 +796,7 @@ def halo_clamp(halo_rows: int) -> int:
     return max(0, min(_CLIM, ((halo_rows - _WR - 3) // 2) * 2))
 
 
+@stage("halo")
 def band_halo_exchange(plane, halo: int, axis_name, num_bands: int,
                        top_ext=None, bot_ext=None,
                        edge_top: bool = True, edge_bot: bool = True):
@@ -863,35 +869,39 @@ def banded_probe_cost(cur16, ref16, real_rows, axis_name,
     cross-host reduction and argmin (probe_center_from_cost)."""
     qs = _COARSE
     qsr = sr // qs
-    cq = _box_sum(cur16, qs)
-    rq = _box_sum(ref16, qs)
-    hc, wc = cq.shape
-    rows = jnp.arange(hc)
-    real_c = jnp.maximum(real_rows // qs, 1)
-    # cells at/past the band's real content hold padding: clamp them to
-    # the last real cell row so (a) this band's cost rows are masked
-    # anyway and (b) the halo cells it SENDS (and its own bottom edge
-    # replication) equal the full-frame probe's bottom edge padding.
-    rq = jnp.take(rq, jnp.minimum(rows, real_c - 1), axis=0)
-    # the injected neighbor rows are raw recon pixels (never a padded
-    # band — only the global-last band pads, and it has no neighbor
-    # below), so their box sums equal the neighbor's own unclamped
-    # cells bit for bit
-    top_cells = _box_sum(top_ext, qs)[-qsr:] if top_ext is not None \
-        else None
-    bot_cells = _box_sum(bot_ext, qs)[:qsr] if bot_ext is not None \
-        else None
+    with stage("me_prep"):
+        cq = _box_sum(cur16, qs)
+        rq = _box_sum(ref16, qs)
+        hc, wc = cq.shape
+        rows = jnp.arange(hc)
+        real_c = jnp.maximum(real_rows // qs, 1)
+        # cells at/past the band's real content hold padding: clamp
+        # them to the last real cell row so (a) this band's cost rows
+        # are masked anyway and (b) the halo cells it SENDS (and its
+        # own bottom edge replication) equal the full-frame probe's
+        # bottom edge padding.
+        rq = jnp.take(rq, jnp.minimum(rows, real_c - 1), axis=0)
+        # the injected neighbor rows are raw recon pixels (never a
+        # padded band — only the global-last band pads, and it has no
+        # neighbor below), so their box sums equal the neighbor's own
+        # unclamped cells bit for bit
+        top_cells = _box_sum(top_ext, qs)[-qsr:] if top_ext is not None \
+            else None
+        bot_cells = _box_sum(bot_ext, qs)[:qsr] if bot_ext is not None \
+            else None
     rq_ext = band_halo_exchange(rq, qsr, axis_name, num_bands,
                                 top_ext=top_cells, bot_ext=bot_cells,
                                 edge_top=edge_top, edge_bot=edge_bot)
-    rq_ext = jnp.pad(rq_ext, ((0, 0), (qsr, qsr)), mode="edge")
-    mask = (rows < real_c)[:, None]
-    n = 2 * qsr + 1
-    wins = jnp.stack([jax.lax.slice(rq_ext, (oy, ox), (oy + hc, ox + wc))
-                      for oy in range(n) for ox in range(n)])
-    cost = (jnp.abs(cq[None] - wins) * mask[None]).sum((1, 2))
-    if axis_name is not None and num_bands > 1:
-        cost = jax.lax.psum(cost, axis_name)
+    with stage("me_prep"):
+        rq_ext = jnp.pad(rq_ext, ((0, 0), (qsr, qsr)), mode="edge")
+        mask = (rows < real_c)[:, None]
+        n = 2 * qsr + 1
+        wins = jnp.stack(
+            [jax.lax.slice(rq_ext, (oy, ox), (oy + hc, ox + wc))
+             for oy in range(n) for ox in range(n)])
+        cost = (jnp.abs(cq[None] - wins) * mask[None]).sum((1, 2))
+        if axis_name is not None and num_bands > 1:
+            cost = jax.lax.psum(cost, axis_name)
     return cost
 
 
@@ -905,8 +915,9 @@ def banded_coarse_probe(cur16, ref16, real_rows, axis_name,
     n = 2 * qsr + 1
     cost = banded_probe_cost(cur16, ref16, real_rows, axis_name,
                              num_bands, sr=sr)
-    bi = jnp.argmin(cost).astype(jnp.int32)
-    return jnp.stack([bi // n - qsr, bi % n - qsr]) * qs
+    with stage("me_prep"):
+        bi = jnp.argmin(cost).astype(jnp.int32)
+        return jnp.stack([bi // n - qsr, bi % n - qsr]) * qs
 
 
 def probe_center_from_cost(cost, sr: int = SEARCH_RANGE):
@@ -937,17 +948,19 @@ def banded_centers_from(cur16, ref16, pred_mv_h, real_rows,
     if probe is None:
         probe = banded_coarse_probe(cur16, ref16, real_rows, axis_name,
                                     num_bands)
-    med_pel = jnp.clip((pred_mv_h + 2) >> 2, -(_CLIM // 2),
-                       _CLIM // 2) * 2
-    lims = jnp.asarray([min(halo_clamp(halo_rows), _CLIM), _CLIM],
-                       jnp.int32)
-    probe = jnp.clip(probe, -lims, lims)
-    med_pel = jnp.clip(med_pel, -lims, lims)
-    zero = jnp.zeros(2, jnp.int32) + (cur16.reshape(-1)[0] * 0).astype(
-        jnp.int32)
-    return jnp.stack([probe, med_pel, zero])
+    with stage("me_prep"):
+        med_pel = jnp.clip((pred_mv_h + 2) >> 2, -(_CLIM // 2),
+                           _CLIM // 2) * 2
+        lims = jnp.asarray([min(halo_clamp(halo_rows), _CLIM), _CLIM],
+                           jnp.int32)
+        probe = jnp.clip(probe, -lims, lims)
+        med_pel = jnp.clip(med_pel, -lims, lims)
+        zero = jnp.zeros(2, jnp.int32) + (cur16.reshape(-1)[0] * 0).astype(
+            jnp.int32)
+        return jnp.stack([probe, med_pel, zero])
 
 
+@stage("me_median")
 def hist_counts_banded(mv_flat, mb_mask, lim: int, axis_name,
                        num_bands: int):
     """Per-band MV histogram counts over the REAL macroblocks, psum'd
@@ -974,8 +987,10 @@ def hist_median_banded(mv_flat, mb_mask, lim: int, axis_name,
     search center)."""
     cnt, n = hist_counts_banded(mv_flat, mb_mask, lim, axis_name,
                                 num_bands)
-    cum = jnp.cumsum(cnt, axis=0)
-    return ((cum >= (n + 1) // 2).argmax(axis=0) - lim).astype(jnp.int32)
+    with stage("me_median"):
+        cum = jnp.cumsum(cnt, axis=0)
+        return ((cum >= (n + 1) // 2).argmax(axis=0)
+                - lim).astype(jnp.int32)
 
 
 def median_from_counts(cnt, n, lim: int):
@@ -1035,14 +1050,16 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
     rv_ext = band_halo_exchange(ref_v16, halo // 2, axis_name, num_bands,
                                 top_ext=tv, bot_ext=bv,
                                 edge_top=edge_top, edge_bot=edge_bot)
-    # halo rows of CUR only feed the discarded extension MBs' SADs;
-    # edge replication keeps them in range
-    cur_ext = jnp.concatenate([
-        jnp.broadcast_to(cur_y16[:1], (halo, W)), cur_y16,
-        jnp.broadcast_to(cur_y16[Hb - 1:], (halo, W))])
+    with stage("me_prep"):
+        # halo rows of CUR only feed the discarded extension MBs' SADs;
+        # edge replication keeps them in range
+        cur_ext = jnp.concatenate([
+            jnp.broadcast_to(cur_y16[:1], (halo, W)), cur_y16,
+            jnp.broadcast_to(cur_y16[Hb - 1:], (halo, W))])
     centers = banded_centers_from(cur_y16, ref_y16, pred_mv_h, real_rows,
                                   halo, axis_name, num_bands, probe=probe)
-    lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
+    with stage("me_prep"):
+        lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
     if use_pallas():
         mv_e, py_e, pu_e, pv_e = me_search_pallas(
             cur_ext, ry_ext, ru_ext, rv_ext, centers, lam)
@@ -1051,18 +1068,23 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
             cur_ext, ry_ext, ru_ext, rv_ext, centers, lam)
     hm = halo // 16
     mbh_b = Hb // 16
-    mv = jax.lax.slice_in_dim(mv_e, hm, hm + mbh_b, axis=0)
-    py = jax.lax.slice_in_dim(py_e, halo, halo + Hb, axis=0)
-    pu = jax.lax.slice_in_dim(pu_e, halo // 2, (halo + Hb) // 2, axis=0)
-    pv = jax.lax.slice_in_dim(pv_e, halo // 2, (halo + Hb) // 2, axis=0)
-    mb_mask = jnp.repeat(jnp.arange(mbh_b) * 16 < real_rows, mv.shape[1])
+    with stage("me_search"):
+        mv = jax.lax.slice_in_dim(mv_e, hm, hm + mbh_b, axis=0)
+        py = jax.lax.slice_in_dim(py_e, halo, halo + Hb, axis=0)
+        pu = jax.lax.slice_in_dim(pu_e, halo // 2, (halo + Hb) // 2,
+                                  axis=0)
+        pv = jax.lax.slice_in_dim(pv_e, halo // 2, (halo + Hb) // 2,
+                                  axis=0)
+    with stage("me_median"):
+        mb_mask = jnp.repeat(jnp.arange(mbh_b) * 16 < real_rows,
+                             mv.shape[1])
+        mv_flat = mv.reshape(-1, 2)
     if return_hist:
-        cnt, n = hist_counts_banded(mv.reshape(-1, 2), mb_mask,
-                                    2 * SEARCH_RANGE, axis_name,
-                                    num_bands)
+        cnt, n = hist_counts_banded(mv_flat, mb_mask, 2 * SEARCH_RANGE,
+                                    axis_name, num_bands)
         return mv, py, pu, pv, cnt, n
-    med = hist_median_banded(mv.reshape(-1, 2), mb_mask,
-                             2 * SEARCH_RANGE, axis_name, num_bands)
+    med = hist_median_banded(mv_flat, mb_mask, 2 * SEARCH_RANGE,
+                             axis_name, num_bands)
     return mv, py, pu, pv, med
 
 
@@ -1072,8 +1094,9 @@ def me_search(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp):
     qp the frame's quantizer (drives the MV-cost lambda).
     Returns (mv (mbh, mbw, 2) int32 half-pel, pred_y, pred_u, pred_v
     int16, med_mv_h (2,) int32)."""
-    centers = centers_from(cur_y16, ref_y16, pred_mv_h)
-    lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
+    with stage("me_prep"):
+        centers = centers_from(cur_y16, ref_y16, pred_mv_h)
+        lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
     if use_pallas():
         mv, pred_y, pred_u, pred_v = me_search_pallas(
             cur_y16, ref_y16, ref_u16, ref_v16, centers, lam)

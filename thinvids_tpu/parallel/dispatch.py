@@ -83,6 +83,7 @@ from ..codecs.h264.encoder import (FrameLevels, _mode_policy,
                                    unpack_mode16)
 from ..codecs.h264.headers import PPS, SPS
 from ..codecs.h264.rdo import RD_OFF, RdConfig, rd_from_settings
+from ..codecs.h264.stages import stage
 # Transfer-layout contract (jax-free module shared with the process
 # pack sidecars): per-MB flat sizes + the zero-copy host unflattens.
 from ..codecs.h264.layout import _INTRA_FLAT_MB as _INTRA_MB
@@ -397,12 +398,13 @@ def _sparse_unpack2_host(nblk: int, nval: int, bitmap, bmask16, vals,
 def _flat_levels(y, u, v, qp, mbw, mbh, rd=RD_OFF):
     out = jaxcore._intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
     ldc, lac, cdc, cac = out[:4]
-    parts = [ldc.reshape(-1), lac.reshape(-1), cdc.reshape(-1),
-             cac.reshape(-1)]
-    if rd.ships_modes:
-        parts.append(jaxcore._mode_tail(out[7], out[8], out[9])
-                     .astype(jnp.int32))
-    return jnp.concatenate(parts)
+    with stage("layout"):
+        parts = [ldc.reshape(-1), lac.reshape(-1), cdc.reshape(-1),
+                 cac.reshape(-1)]
+        if rd.ships_modes:
+            parts.append(jaxcore._mode_tail(out[7], out[8], out[9])
+                         .astype(jnp.int32))
+        return jnp.concatenate(parts)
 
 
 def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
@@ -429,17 +431,18 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
                                            rd=rd)
     nmb = mbw * mbh
     ndc, nlac, ncdc = nmb * 16, nmb * 240, nmb * 8
-    dense_parts = [flat[:ndc], flat[ndc + nlac:ndc + nlac + ncdc]]
-    if rd.ships_modes:
-        # intra [mode16 | dqp16] tail rides the dense prefix (it is
-        # small and mode 0 = V would defeat the sparse pack anyway)
-        dense_parts.append(flat[-2 * nmb:])
-        rest = jnp.concatenate([flat[ndc:ndc + nlac],
-                                flat[ndc + nlac + ncdc:-2 * nmb]])
-    else:
-        rest = jnp.concatenate([flat[ndc:ndc + nlac],
-                                flat[ndc + nlac + ncdc:]])
-    dense = jnp.concatenate(dense_parts)
+    with stage("pack"):
+        dense_parts = [flat[:ndc], flat[ndc + nlac:ndc + nlac + ncdc]]
+        if rd.ships_modes:
+            # intra [mode16 | dqp16] tail rides the dense prefix (it is
+            # small and mode 0 = V would defeat the sparse pack anyway)
+            dense_parts.append(flat[-2 * nmb:])
+            rest = jnp.concatenate([flat[ndc:ndc + nlac],
+                                    flat[ndc + nlac + ncdc:-2 * nmb]])
+        else:
+            rest = jnp.concatenate([flat[ndc:ndc + nlac],
+                                    flat[ndc + nlac + ncdc:]])
+        dense = jnp.concatenate(dense_parts)
     nblk, nval, n_esc, bitmap, bmask16, vals = \
         jaxcore._block_sparse_pack2(rest)
     if not compact:
@@ -454,7 +457,16 @@ def _per_gop_dense(y, u, v, qp, mbw: int, mbh: int, dtype, rd=RD_OFF):
 
     _mv8, flat = jaxinter.encode_gop_planes(y, u, v, qp, mbw=mbw, mbh=mbh,
                                             rd=rd)
-    return flat.astype(dtype)
+    with stage("layout"):
+        return flat.astype(dtype)
+
+
+@stage("layout")
+def _map_gops(one, xs):
+    """`lax.map` over a device's GOPs (or an all-intra GOP's frames).
+    The scope names the loop itself; the stages inside `one` keep
+    their own names (codecs/h264/stages.py)."""
+    return jax.lax.map(one, xs)
 
 
 # Zero-copy unflatten views (flat transfer segments → slice arrays) —
@@ -480,7 +492,7 @@ def _encode_wave_gop(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
             y, u, v, qp = args
             return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
                                    rd=rd)
-        return jax.lax.map(one, (y_g, u_g, v_g, qp_g))
+        return _map_gops(one, (y_g, u_g, v_g, qp_g))
 
     shard = shard_map(
         per_dev, mesh=mesh,
@@ -505,7 +517,7 @@ def _encode_gop_single(ys, us, vs, qps, *, mbw: int, mbh: int,
         y, u, v, qp = args
         return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
                                rd=rd)
-    return jax.lax.map(one, (ys, us, vs, qps))
+    return _map_gops(one, (ys, us, vs, qps))
 
 
 @functools.partial(jax.jit,
@@ -515,7 +527,7 @@ def _encode_gop_single_dense(ys, us, vs, qps, *, mbw: int, mbh: int, dtype,
     def one(args):
         y, u, v, qp = args
         return _per_gop_dense(y, u, v, qp, mbw, mbh, dtype, rd=rd)
-    return jax.lax.map(one, (ys, us, vs, qps))
+    return _map_gops(one, (ys, us, vs, qps))
 
 
 @functools.partial(jax.jit,
@@ -528,7 +540,7 @@ def _encode_wave_gop_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
         def one(args):
             y, u, v, qp = args
             return _per_gop_dense(y, u, v, qp, mbw, mbh, dtype, rd=rd)
-        return jax.lax.map(one, (y_g, u_g, v_g, qp_g))
+        return _map_gops(one, (y_g, u_g, v_g, qp_g))
 
     shard = shard_map(
         per_dev, mesh=mesh,
@@ -559,7 +571,7 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
                 return jaxcore._sparse_pack(
                     _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd))
 
-            return jax.lax.map(per_frame, (y_f, u_f, v_f))
+            return _map_gops(per_frame, (y_f, u_f, v_f))
 
         return jax.vmap(one)(y_g, u_g, v_g, qp_g)         # each (1, F, ...)
 
@@ -584,9 +596,11 @@ def _encode_wave_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
                 y, u, v = planes
                 return _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd)
 
-            return jax.lax.map(per_frame, (y_f, u_f, v_f))
+            return _map_gops(per_frame, (y_f, u_f, v_f))
 
-        return jax.vmap(one)(y_g, u_g, v_g, qp_g).astype(dtype)
+        levels = jax.vmap(one)(y_g, u_g, v_g, qp_g)
+        with stage("layout"):
+            return levels.astype(dtype)
 
     shard = shard_map(
         per_gop, mesh=mesh,
