@@ -305,6 +305,88 @@ class TestShardedInterDispatch:
         assert len(inter_stream) < len(intra_stream) / 1.7
 
 
+def _pack2_reference(flat, budget_div, val_div):
+    """The documented transfer format of jaxcore._block_sparse_pack2,
+    written out in numpy with boolean indexing only (no positions, no
+    scatter): what the device function must return, array for array."""
+    L = flat.shape[0]
+    NB = -(-L // 16)
+    budget, vbudget = NB // budget_div, L // val_div
+    blocks = np.zeros(NB * 16, np.int16)
+    blocks[:L] = flat
+    blocks = blocks.reshape(NB, 16)
+    bmask = blocks.any(axis=1)
+    kept = blocks[bmask][:budget]          # overflow keeps the prefix
+    gathered = np.zeros((budget, 16), np.int16)
+    gathered[:kept.shape[0]] = kept
+    emask = gathered != 0
+    stream = np.clip(gathered[emask], -127, 127)[:vbudget]
+    vals = np.zeros(vbudget, np.int8)
+    vals[:stream.shape[0]] = stream
+    return (np.int32(bmask.sum()), np.int32(emask.sum()),
+            np.int32((np.abs(gathered.astype(np.int32)) > 127).sum()),
+            np.packbits(bmask),
+            (emask << np.arange(16)).sum(axis=1).astype(np.uint16),
+            vals)
+
+
+#: case -> (length, nonzero blocks, most lanes set in one, budget_div,
+#: val_div): the wave path's divisors and `_sfe_pack_band`'s unit ones
+PACK2_CASES = {
+    "gop_divisors": (16 * 1000, 120, 5, 4, 24),
+    "unit_divisors": (16 * 500, 200, 16, 1, 1),
+    "odd_length": (16 * 1000 + 8, 120, 5, 4, 24),
+    "odd_length_unit": (16 * 333 + 5, 150, 16, 1, 1),
+    "all_zero": (16 * 64 + 3, 0, 1, 4, 24),
+    # 200 blocks of the 250 allowed, half full: far over 666 values
+    "nval_over_vbudget": (16 * 1000, 200, 16, 4, 24),
+    "nblk_over_budget": (16 * 1000, 600, 2, 4, 24),
+    "one_escape": (16 * 64, 10, 4, 4, 24),
+}
+
+
+def _pack2_levels(case):
+    """(flat int32 levels, budget_div, val_div) of one named case:
+    nonzeros clustered in a few blocks, like residuals."""
+    L, hot, max_lanes, budget_div, val_div = PACK2_CASES[case]
+    rng = np.random.default_rng(25)
+    flat = np.zeros(L, np.int32)
+    for b in rng.choice(L // 16, hot, replace=False):
+        lanes = rng.choice(16, rng.integers(1, max_lanes + 1),
+                           replace=False)
+        flat[b * 16 + lanes] = rng.integers(1, 121, len(lanes)) \
+            * rng.choice([-1, 1], len(lanes))
+    if case == "one_escape":
+        flat[3] = 300
+    return flat, budget_div, val_div
+
+
+@pytest.mark.parametrize("case", sorted(PACK2_CASES))
+def test_block_sparse_pack2_matches_the_documented_format(case):
+    from thinvids_tpu.codecs.h264 import jaxcore
+    import jax.numpy as jnp
+
+    flat, budget_div, val_div = _pack2_levels(case)
+    want = _pack2_reference(flat, budget_div, val_div)
+    got = [np.asarray(x) for x in jaxcore._block_sparse_pack2(
+        jnp.asarray(flat), budget_div, val_div)]
+    names = ("nblk", "nval", "n_esc", "bitmap", "bmask16", "vals")
+    for name, w, g in zip(names, want, got):
+        assert g.dtype == w.dtype and g.shape == w.shape, name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+    L = flat.shape[0]
+    NB = -(-L // 16)
+    nblk, nval, n_esc = (int(x) for x in got[:3])
+    # the cases are what their names say
+    assert (nblk > NB // budget_div) == (case == "nblk_over_budget")
+    assert (nval > L // val_div) == (case == "nval_over_vbudget")
+    assert n_esc == (case == "one_escape")
+    if jaxcore.block_sparse2_fits(nblk, nval, n_esc, L, budget_div,
+                                  val_div):
+        back = jaxcore._block_sparse_unpack2(nblk, nval, *got[3:], L)
+        np.testing.assert_array_equal(back, flat.astype(np.int16))
+
+
 class TestHostPipeline:
     """Stage-profiled wave pipeline: slice-granular threaded pack, the
     zero-copy int16 unflatten, native sparse unpack, per-GOP QP on the

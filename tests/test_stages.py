@@ -9,6 +9,7 @@ here: the parity tests against the numpy reference encoder hold the
 bytes (test_inter, test_jaxcore, test_parallel, test_sfe).
 """
 
+import functools
 import re
 
 import numpy as np
@@ -143,10 +144,17 @@ def _working_paths(text: str) -> list[str]:
     return paths
 
 
+@functools.cache
+def _compiled_paths(case: str) -> tuple[str, ...]:
+    """One compile per program, whichever tests read it."""
+    lower = PACK_ALONE[case] if case in PACK_ALONE else CASES[case][0]
+    return tuple(_working_paths(lower().compile().as_text()))
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_step_program_ops_are_filed_under_their_stage(case):
-    lower, want = CASES[case]
-    paths = _working_paths(lower().compile().as_text())
+    want = CASES[case][1]
+    paths = _compiled_paths(case)
     assert len(paths) > 500, "the compiled module was not read"
     seen, unscoped = set(), []
     for path in paths:
@@ -172,6 +180,36 @@ def test_step_program_ops_are_filed_under_their_stage(case):
     assert not strays, f"ops outside every stage: {strays[:10]}"
     assert len(unscoped) <= 0.10 * len(paths), \
         f"{len(unscoped)} of {len(paths)} working instructions unscoped"
+
+
+def _lower_pack2(budget_div, val_div):
+    from thinvids_tpu.codecs.h264 import jaxcore
+
+    return jax.jit(lambda flat: jaxcore._block_sparse_pack2(
+        flat, budget_div, val_div)).lower(
+            jax.ShapeDtypeStruct((16 * 64 + 3,), jnp.int32))
+
+
+#: the pack alone, with the wave path's divisors and the unit budgets
+#: of `_sfe_pack_band`, then every step program that packs
+#: (`_per_gop_sparse` under the GOP programs, `_sfe_pack_band` under
+#: the split-frame steps)
+PACK_ALONE = {"pack2": lambda: _lower_pack2(4, 24),
+              "pack2_unit": lambda: _lower_pack2(1, 1)}
+PACKING = sorted(PACK_ALONE) + sorted(
+    case for case, (_lower, want) in CASES.items() if SPARSE <= want)
+
+
+@pytest.mark.parametrize("case", PACKING)
+def test_no_scatter_under_the_pack_stage(case):
+    """ISSUE 25: the pack compacts with static addressing. A scatter
+    under `tvt.pack` (XLA's TPU lowering: a sort plus one update at a
+    time, 7 ns per candidate) must not come back unnoticed."""
+    packing = {path for path in _compiled_paths(case)
+               if PREFIX + "pack" in path}
+    assert len(packing) > 20, "the pack stage was not read"
+    scatters = sorted(path for path in packing if "/scatter" in path)
+    assert not scatters, scatters
 
 
 def test_stage_names_are_a_closed_set():

@@ -652,11 +652,13 @@ _BLOCK_BUDGET_DIV = 4
 def _block_sparse_pack(flat, budget_div: int = _BLOCK_BUDGET_DIV):
     """Compact a flat int16 level vector on device at BLOCK granularity.
 
-    The element-granular `_sparse_pack` needs cumsums/scatters over the
-    full coefficient vector — XLA lowers a 25M-element cumsum as
-    O(n log n) passes, measured ~0.6 s per 1080p GOP on a v5e chip.
-    At 16-coeff-block granularity the position computation shrinks 16x
-    and the values move by GATHER (fast) instead of scatter:
+    The element-granular `_sparse_pack` needs cumsums and scatters over
+    the full coefficient vector; at 16-coeff-block granularity the
+    position computation shrinks 16x and the values move by a gather
+    of whole blocks. No served program calls this one-tier form (the
+    GOP and split-frame paths pack with `_block_sparse_pack2`), so it
+    keeps the scatters that PR 25 took out of that function, and no
+    chip run has timed it (PERF.md §7).
 
     Returns (nblk, n_esc, bitmap, payload, esc_pos, esc_val):
     - bitmap: 1 bit per 16-coeff block (any-nonzero), L/128 bytes;
@@ -707,11 +709,36 @@ def block_sparse_fits(nblk: int, n_esc: int, L: int,
             and int(n_esc) <= _SPARSE_ESCAPES)
 
 
+def _compact_left(shift, *streams):
+    """Order-preserving stream compaction with static addressing.
+
+    `shift[i]` is how far element i moves left: its position minus its
+    rank among the kept elements, and 0 in a slot that holds nothing
+    (whose `streams` entries must be 0 too). Round k moves every
+    element whose shift has bit k set left by 2**k: a select, a static
+    slice and an OR — no gather, no scatter, no sort. Ranks rise with
+    position, so taken from bit 0 upwards no two elements ever meet:
+    after ceil(log2(n)) rounds element i sits at i - shift[i] and every
+    other slot holds 0. Returns the moved (shift, *streams); a moved
+    shift still says how far its element came, so position + shift is
+    where it started."""
+    n = shift.shape[0]
+    for k in range(max(n - 1, 0).bit_length()):
+        by = 1 << k
+        leaves = shift & by != 0
+        movers = [jnp.where(leaves, x, 0) for x in (shift, *streams)]
+        # no mover lands on a stayer, so OR merges the two
+        shift, *streams = ((x ^ m) | jnp.pad(m[by:], (0, by))
+                           for x, m in zip((shift, *streams), movers))
+    return (shift, *streams)
+
+
 # Value-stream budget for the two-tier pack: elementwise nonzero density
 # beyond 1/div falls back dense. Measured 1080p GOP at qp 27 on heavily
 # grainy content: ~723K nonzero coeffs of 25.5M (~2.8%); 1/24 still
-# leaves ~1.5x headroom, and every budget byte rides the ~8 MB/s
-# device->host link once per GOP.
+# leaves ~1.5x headroom. The budget sizes the device buffer only:
+# the compact transfer fetches the used prefix (85 kB per 1080p frame,
+# `d2h_bytes_per_frame`, PERF_LEDGER PR 24).
 _VAL_BUDGET_DIV = 24
 
 
@@ -736,12 +763,20 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
       fixed (L//val_div,) buffer;
     - n_esc: COUNT of coeffs exceeding int8. There is no escape
       side-channel: levels beyond ±127 are rare at practical QPs, and
-      the old (position, value) stream needed a full-size cumsum plus
-      two more full-size scatters — measured ~90 ms of a 160 ms pack
-      per 1080p GOP. Any escape (n_esc > 0) now falls back to the
-      dense fetch for the whole wave.
+      a (position, value) stream would need a full-size cumsum plus
+      two more full-size scatters. Any escape (n_esc > 0) falls back
+      to the dense fetch for the whole wave.
     Caller falls back to a dense fetch iff nblk/nval/n_esc exceed their
     budgets (`block_sparse2_fits`).
+
+    Both compactions (nonzero blocks → `blist`, nonzero values →
+    `vals`) go through `_compact_left`. As scatters
+    (`zeros.at[pos].set(..., mode="drop")`) XLA's TPU lowering sorted
+    the (position, value) pairs and then applied them one at a time:
+    5.52 + 1.57 ms per 1080p frame for the values and 1.17 ms for the
+    block list, of a 10.25 ms pack (PERF_LEDGER PR 24, `hd-backlog`).
+    Same bytes out, overflow included: past a budget the leading
+    blocks / values are the ones kept.
     """
     L = flat.shape[0]
     NB = -(-L // _BLOCK)
@@ -755,12 +790,14 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
     bmask = jnp.any(blocks != 0, axis=1)
     nblk = jnp.sum(bmask.astype(jnp.int32))
     pos = jnp.cumsum(bmask.astype(jnp.int32)) - 1
-    idx = jnp.where(bmask, pos, budget)
-    blist = jnp.zeros(budget + 1, jnp.int32).at[idx].set(
-        jnp.arange(NB, dtype=jnp.int32), mode="drop")[:budget]
+    # the k-th nonzero block's index is k plus the shift it arrives with
+    (came,) = _compact_left(
+        jnp.where(bmask, jnp.arange(NB, dtype=jnp.int32) - pos, 0))
+    slot = jnp.arange(budget, dtype=jnp.int32)
+    live = slot < nblk
+    blist = jnp.where(live, slot + came[:budget], 0)
     gathered = jnp.take(blocks, blist, axis=0)           # (budget, 16)
-    live = (jnp.arange(budget, dtype=jnp.int32) < nblk)[:, None]
-    gathered = jnp.where(live, gathered, 0)
+    gathered = jnp.where(live[:, None], gathered, 0)
     bitmap = jnp.sum(
         _pad8(bmask).reshape(-1, 8).astype(jnp.uint8) * _BIT_WEIGHTS,
         axis=-1).astype(jnp.uint8)
@@ -773,10 +810,12 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
     offs = jnp.cumsum(counts) - counts
     within = jnp.cumsum(emask.astype(jnp.int32), axis=1) - 1
     nval = jnp.sum(counts)
-    vpos = jnp.where(emask, offs[:, None] + within, vbudget)
+    at = jnp.arange(budget * _BLOCK, dtype=jnp.int32).reshape(emask.shape)
+    shift = jnp.where(emask, at - (offs[:, None] + within), 0)
     clipped = jnp.clip(gathered, -_I8_MAX, _I8_MAX).astype(jnp.int8)
-    vals = jnp.zeros(vbudget + 1, jnp.int8).at[
-        vpos.reshape(-1)].set(clipped.reshape(-1), mode="drop")[:vbudget]
+    _, vals = _compact_left(shift.reshape(-1), clipped.reshape(-1))
+    # fit the budget: cut, or fill where it passes the blocks' 16 each
+    vals = jnp.pad(vals, (0, max(vbudget - vals.shape[0], 0)))[:vbudget]
     n_esc = jnp.sum((jnp.abs(gathered) > _I8_MAX).astype(jnp.int32))
     return (nblk, nval, n_esc, bitmap, bmask16, vals)
 

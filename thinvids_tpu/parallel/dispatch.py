@@ -416,8 +416,8 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
     (nmb * 8), ~390 KB combined at 1080p — ship DENSE: hadamard DC
     levels are the only ones that exceed int8 at practical QPs (chroma
     DC crosses at QP <~ 20), and the sparse pack has no escape
-    side-channel (its full-size scatters were ~60% of the pack's
-    device time) — an escape anywhere forces the wave-wide dense
+    side-channel (full-size scatters, the op PR 25 took out of the
+    pack, would carry it) — an escape anywhere forces the wave-wide dense
     fallback, so low-QP encodes would otherwise fall permanently into
     the slow path (ADVICE round 5).
 
