@@ -270,6 +270,44 @@ class TestShardedPaths:
             for g in range(0, n, gop))
         assert sharded == direct
 
+    def test_served_encoder_at_the_serving_point(self):
+        """The served path's door (`make_shard_encoder`, settings as
+        the daemon reads them: QP 25, mode decision, P_Skip, in-loop
+        filter, AQ 1.0 — benchmark/configs/serving-1080p.json) on a
+        CPU mesh against the repo's reference encode of a GOP
+        (`encoder.encode_gop`, the blocked single-GOP program whose
+        recon the oracle tests hold to libavcodec): the same bytes.
+        Integer-exact codec, so equality is the only tolerance."""
+        import jax
+        from jax.sharding import Mesh
+
+        from thinvids_tpu.core.config import (get_settings,
+                                              reset_live_settings,
+                                              update_live_settings)
+        from thinvids_tpu.core.types import concat_segments
+        from thinvids_tpu.parallel.dispatch import make_shard_encoder
+
+        w, h, n, gop = 96, 80, 8, 4
+        frames = make_frames(n, w, h)
+        meta = _meta(w, h, n)
+        mesh = Mesh(np.asarray(jax.devices()[:2]), ("gop",))
+        try:
+            update_live_settings({
+                "qp": 25, "gop_frames": gop, "mode_decision": True,
+                "pskip": True, "deblock": True, "aq_strength": 1.0})
+            enc = make_shard_encoder(meta, get_settings(), mesh)
+        finally:
+            reset_live_settings()
+        assert enc.rd == RD_ALL
+        served = concat_segments(enc.encode_waves(
+            enc.stage_waves(frames)))
+        direct = b"".join(
+            enc_mod.encode_gop(frames[g:g + gop], meta, qp=25,
+                               idr_pic_id=g // gop, with_headers=True,
+                               rd=RD_ALL, return_recon=True)[0]
+            for g in range(0, n, gop))
+        assert served == direct
+
     @pytest.mark.slow
     def test_process_pack_backend_with_features(self):
         from thinvids_tpu.core.types import concat_segments

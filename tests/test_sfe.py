@@ -526,9 +526,10 @@ class TestExecutorWiring:
 class TestSfeRdFeatures:
     """Split-frame encoding with the RD features on: band slices must
     stay conformant (recon == independent decode) for every band
-    count, the in-loop filter must cross band boundaries exactly like
-    the unbanded program (the halo exchange), and the per-band mode
-    decision must stay SLICE-local."""
+    count, the in-loop filter must run SLICE-locally (every band
+    slice signals disable_deblocking_filter_idc 2 and filters its own
+    rows, §8.7's order ends at a slice's first row), and the per-band
+    mode decision must stay SLICE-local too."""
 
     RD_ON = None     # set lazily (rdo import inside jax-ready process)
 
@@ -548,6 +549,46 @@ class TestSfeRdFeatures:
         enc, stream = encode_sfe(clip(w, h, n), meta, bands=3,
                                  rd=self._rd_on())
         assert_decode_parity(enc, stream, n)
+
+    @multi_device
+    def test_band_slices_signal_idc2_and_libavcodec_agrees(self):
+        """A two-slice deblocked picture: every slice header carries
+        idc 2, and libavcodec — which then filters no edge between the
+        two slices — decodes the encoder's recon, sample for sample
+        (tolerance zero: the recon is the next frame's reference).
+        The in-repo decoder reads the same idc and agrees too."""
+        from thinvids_tpu.codecs.h264.headers import (PPS, SPS,
+                                                      SliceHeader)
+        from thinvids_tpu.io.bits import BitReader, split_annexb
+        from thinvids_tpu.tools import oracle
+
+        w, h, n = 96, 112, 4
+        meta = VideoMeta(width=w, height=h, num_frames=n)
+        enc, stream = encode_sfe(clip(w, h, n), meta, bands=2, qp=30,
+                                 rd=self._rd_on())
+        sps = pps = None
+        idcs = []
+        for ref_idc, typ, rbsp in split_annexb(stream):
+            if typ == 7:
+                sps = SPS.parse_rbsp(rbsp)
+            elif typ == 8:
+                pps = PPS.parse_rbsp(rbsp)
+            elif typ in (1, 5):
+                idcs.append(SliceHeader.parse(
+                    BitReader(rbsp), sps, pps, typ, ref_idc).deblock_idc)
+        assert idcs == [2] * (2 * n)
+        dec = assert_decode_parity(enc, stream, n)
+        if not oracle.oracle_available():
+            pytest.skip("libavcodec oracle not available")
+        decoded = oracle.decode_h264(stream)
+        assert len(decoded) == n
+        for i, planes in enumerate(decoded):
+            for got, want, ours in zip(planes, enc.recon_frames[i],
+                                       (dec.frames[i].y, dec.frames[i].u,
+                                        dec.frames[i].v)):
+                np.testing.assert_array_equal(got, want,
+                                              err_msg=f"frame {i}")
+                np.testing.assert_array_equal(ours, got)
 
     @multi_device
     @pytest.mark.slow
@@ -601,9 +642,9 @@ class TestSfeRdFeatures:
         assert enc.rd.aq_q == 0 and enc.rd.pskip
 
     def test_farm_band_slice_rejects_deblock(self):
-        """A cross-host band SLICE cannot run the deblock halo
-        collective; construction must refuse (the remote planner falls
-        back to GOP shards for deblock jobs)."""
+        """Cross-host band SLICES have never run with the in-loop
+        filter; construction must refuse (the remote planner keeps GOP
+        shards for deblock jobs)."""
         from thinvids_tpu.codecs.h264.rdo import RdConfig
 
         meta = VideoMeta(width=64, height=192, num_frames=2)

@@ -120,8 +120,8 @@ CASES = {
     "sfe_p_dense": (
         lambda: _lower_sfe(dispatch._sfe_p_step_dense, True),
         SFE_P),
-    # the RD features: the in-loop filter is a stage of its own, and in
-    # a band it sits between two halo exchanges
+    # the RD features: the in-loop filter is a stage of its own (in a
+    # band it filters the band's own rows: no halo exchange round it)
     "gop_single_rd": (
         lambda: _lower_gop(dispatch._encode_gop_single, compact=True,
                            rd=RD_ON),
@@ -210,6 +210,37 @@ def test_no_scatter_under_the_pack_stage(case):
     assert len(packing) > 20, "the pack stage was not read"
     scatters = sorted(path for path in packing if "/scatter" in path)
     assert not scatters, scatters
+
+
+DEBLOCKING = sorted(case for case, (_lower, want) in CASES.items()
+                    if "deblock" in want)
+
+
+@pytest.mark.parametrize("case", DEBLOCKING)
+def test_deblock_stage_is_a_named_loop_without_gather_or_scatter(case):
+    """ISSUE 26: the in-loop filter addresses samples by static slices
+    of reshaped planes and walks the wavefronts in one loop. What it
+    adds is filed under `tvt.deblock`; the loop itself is named
+    through `tvt.layout` (the one scope that may enclose a stage); and
+    no `gather` / `scatter` sits under the stage — the six-pass form
+    it replaced indexed whole sample planes by index arrays (PR 25
+    measured such addressing at 1 GB/s on the chip). The threshold
+    tables are read by compares (`deblock._lut`), so the count is 0,
+    per-block grids included."""
+    def stage_of(path):
+        scopes = [part for part in path.split("/")
+                  if part.startswith(PREFIX)]
+        return scopes[-1] if scopes else None
+
+    filter_ops = [path for path in _compiled_paths(case)
+                  if stage_of(path) == PREFIX + "deblock"]
+    assert len(filter_ops) > 100, "the deblock stage was not read"
+    in_loop = [path for path in filter_ops if re.search(
+        r"tvt\.layout/while/body/(?:closed_call/)?tvt\.deblock/", path)]
+    assert len(in_loop) > 50, "the wavefront loop is not named tvt.layout"
+    indexed = sorted(path for path in filter_ops
+                     if re.search(r"/(gather|scatter)", path))
+    assert not indexed, indexed
 
 
 def test_stage_names_are_a_closed_set():

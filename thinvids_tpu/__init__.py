@@ -39,10 +39,11 @@ Layout:
     cli.py     coordinator + agent + worker daemon entrypoints
                (deploy/*.service)
 
-H.264 in-loop deblocking (§8.7) is implemented on the recon carried
-between frames (codecs/h264/deblock.py, jaxdeblock.py) and signaled in
-the slice headers; like the other rate-distortion features it is off by
-default (the `deblock` setting, core/config.py).
+H.264 in-loop deblocking (§8.7, in the order §8.7 prescribes: what any
+decoder reconstructs, sample for sample) runs on the recon carried
+between frames (codecs/h264/deblock.py, jaxdeblock.py) and is signaled
+in the slice headers; like the other rate-distortion features it is off
+by default (the `deblock` setting, core/config.py).
 """
 
 __version__ = "0.4.0"

@@ -1339,10 +1339,9 @@ class RemoteExecutor(LocalExecutor):
         `sfe_farm` (default on) lets the remote backend spread the
         bands across hosts; ladder/live jobs keep their existing shard
         shapes (rung x range / local edge). A deblock-enabled job
-        keeps GOP-range shards: the in-loop filter's cross-band halo
-        is a device collective, which a cross-host band slice cannot
-        run (the SFE steps refuse it), while whole GOPs deblock
-        entirely worker-locally."""
+        keeps GOP-range shards: cross-host band slices have never run
+        with the in-loop filter (the SFE steps refuse it), while whole
+        GOPs deblock entirely worker-locally."""
         from ..core.config import as_bool
 
         return (int(settings.get("sfe_bands", 0) or 0) > 0

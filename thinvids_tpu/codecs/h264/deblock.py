@@ -1,45 +1,54 @@
-"""In-loop deblocking filter (H.264 §8.7) — shifted-plane form.
+"""In-loop deblocking filter (H.264 §8.7), in the order §8.7 prescribes.
 
-The spec orders filtering per macroblock in raster order (all vertical
-edges of a MB, then its horizontal edges, each reading samples already
-modified by earlier MBs) — an inherently wavefront-sequential schedule.
-This module implements the standard filters and boundary-strength
-derivation in a PLANE-PARALLEL pass order instead:
+The spec filters macroblock by macroblock in raster order: the four
+vertical luma edges of a macroblock left to right, then its four
+horizontal edges top to bottom (chroma likewise, two edges each way),
+every edge reading what earlier edges wrote. Macroblock (x, y)
+therefore needs (x-1, y) finished — its left edge reads that
+macroblock's filtered columns — and (x+1, y-1): its top edge reads the
+rows above after THAT macroblock's left edge touched their last three
+columns. Nothing else orders two macroblocks, so all macroblocks with
+the same t = x + 2y are independent: a WAVEFRONT, mbw + 2(mbh - 1)
+steps for a picture (254 at 1080p).
 
-    1. luma vertical INTERNAL edges   (x % 16 in {4, 8, 12})
-    2. luma vertical MB edges         (x % 16 == 0, x > 0)
-    3. luma horizontal INTERNAL edges
-    4. luma horizontal MB edges
-    5. chroma vertical edges          (x % 8 in {0, 4}, x > 0)
-    6. chroma horizontal edges
+Layout. Rolling macroblock row y by 2y columns turns a wavefront into
+one column of a skewed plane, `[t, r, c, y]` (sample (r, c) of
+macroblock (t - 2y, y); the macroblock row is the minor axis), and
+every neighbour into a static offset: the left macroblock is block
+t-1 in the same lane, the one above block t-2 one lane down. The skew
+is log2(mbh) conditional rolls of whole blocks along t — static slices
+and selects, no index arrays — and one step of the loop is
+`_wavefront_step`: elementwise arithmetic on static slices of three
+blocks. Boundary strengths and the alpha / beta / tC0 thresholds are
+computed for the whole picture beforehand, on per-block grids in the
+skewed layout (`_edge_params`), and ride along packed in one int32 per
+sample line.
 
-Within a pass every edge reads the PASS INPUT and writes disjoint
-samples (internal luma edges write p1..q1 — 4-apart edges never
-collide; MB edges are 16 apart so even the strong filter's p2/q2
-writes stay disjoint; chroma edges write only p0/q0), so each pass is
-one data-parallel plane operation. This deviates from the spec's
-sample ordering only where one edge's write lands in a neighboring
-edge's read window — rare (both filters must trigger adjacently), and
-the deviation is bounded by the measured oracle parity test
-(tests/test_deblock.py, skipped when libavcodec is absent) rather than
-assumed. The in-repo encoder and decoder both run EXACTLY this
-schedule, so encoder recon == decoder output bit for bit, and P-frame
-prediction never drifts.
+One implementation, three users, through a tiny ops shim: the encoder's
+device programs (jaxdeblock: `lax.scan` over t), the in-repo decoder
+and the numpy reference encoder (a python loop over t). The plain
+reference it is tested against is tools/deblock_plain.py; libavcodec
+agrees with both sample for sample (tests/test_deblock.py).
 
 Boundary strength (§8.7.2.1, restricted to this codec's streams —
-pictures are homogeneous: all-intra IDR or all-inter P, one reference):
+pictures are homogeneous: all-intra IDR or all-inter P, one reference,
+16x16 partitions):
 
     intra picture:  MB edge -> 4, internal edge -> 3
     P picture:      either side's 4x4 luma block coded -> 2,
                     |mv_p - mv_q| >= 1 integer pel (either comp) -> 1,
                     else 0
 
-The module is written against a tiny ops shim (`_NumpyOps`) so
-jaxdeblock can run the SAME code under jax.numpy — one semantics, two
-backends, parity-tested.
+A plane handed to `deblock_frame` is filtered as ONE slice whose first
+row has nothing above it: a split-frame band filters its own rows and
+signals disable_deblocking_filter_idc = 2. A decoder that holds a
+picture of several slices passes `edges`, the per-macroblock masks of
+which left / top / internal edges exist.
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import numpy as np
 
@@ -73,6 +82,10 @@ assert TC0_TABLE.shape == (3, 52)
 
 _QPC_NP = np.asarray(CHROMA_QP_TABLE, np.int32)
 
+#: blocks that follow the last wavefront, to push the last two out of
+#: the loop's carry (a block is final two steps after its own)
+_FLUSH = 2
+
 
 class _NumpyOps:
     """Backend shim: numpy. jaxdeblock provides the jnp twin."""
@@ -80,89 +93,226 @@ class _NumpyOps:
     xp = np
 
     @staticmethod
-    def scatter_cols(X, writes):
-        """Return X with columns updated; writes = [(xs, vals)] where
-        the xs sets of one pass are mutually disjoint."""
-        out = X.copy()
-        for xs, vals in writes:
-            out[:, xs] = vals
-        return out
-
-    @staticmethod
-    def gather_cols(X, xs):
-        return X[:, xs]
-
-    @staticmethod
     def asarray(a):
         return np.asarray(a)
+
+    @staticmethod
+    def scope(name):
+        """The device program's stage names (stages.py): none here."""
+        return contextlib.nullcontext()
+
+    @staticmethod
+    def barrier(a):
+        """`lax.optimization_barrier` under JAX; nothing to keep apart
+        here."""
+        return a
+
+    @staticmethod
+    def lanes(mbh: int) -> int:
+        """Width of the minor (macroblock row) axis of the skewed
+        layout: the device's kernel wants whole vector registers."""
+        return mbh
+
+    @staticmethod
+    def scan(step, carry, xs):
+        """out[t] = step's output at wavefront t, the carry (zeros at
+        the start) handed from step to step: `lax.scan`'s contract."""
+        outs = []
+        for t in range(xs[0].shape[0]):
+            carry, out = step(carry, tuple(x[t] for x in xs))
+            outs.append(out)
+        return tuple(np.stack(o) for o in zip(*outs))
 
 
 NUMPY_OPS = _NumpyOps()
 
 
 # ---------------------------------------------------------------------------
-# boundary strength + per-edge QP at 4x4 block granularity
+# the skewed layout
 # ---------------------------------------------------------------------------
 
-def _block_grids(qp_map, intra: bool, nz4, mv, ops):
-    """Per-4x4-block expansions of the MB-granular inputs: (qp_blk,
-    nz_blk, mv_blk) with shapes (4*mbh, 4*mbw[, 2])."""
+def _to_lanes(a, perm, lanes: int, ops):
+    """A [y, ...] array with y moved to the minor axis by `perm` and
+    padded to `lanes` blank macroblock rows. A transpose of its own:
+    the barrier keeps the compiler from folding it into the rolls of
+    `_skew`, which measured 5x the cost of the two apart on the v5e."""
     xp = ops.xp
-    qp_blk = xp.repeat(xp.repeat(qp_map, 4, axis=0), 4, axis=1)
-    if intra:
-        return qp_blk, None, None
-    nz_blk = ops.asarray(nz4).astype(xp.int32)
-    mvg = xp.repeat(xp.repeat(mv, 4, axis=0), 4, axis=1)
-    return qp_blk, nz_blk, mvg
+    a = xp.transpose(a, perm)
+    pad = [(0, 0)] * (a.ndim - 1) + [(0, lanes - a.shape[-1])]
+    return ops.barrier(xp.pad(a, pad))
 
 
-def _edge_bs(qp_blk, nz_blk, mv_blk, edge_cols, intra: bool, ops):
-    """(bS, qp_p, qp_q) for vertical edges at BLOCK columns `edge_cols`
-    of the block grid — (rows, n_edges) each. Horizontal edges reuse
-    this on the transposed grids."""
-    xp = ops.xp
-    e = edge_cols
-    qp_p = qp_blk[:, e - 1]
-    qp_q = qp_blk[:, e]
-    is_mb_edge = (e % 4 == 0).astype(np.int32)[None, :]
-    if intra:
-        bs = xp.where(ops.asarray(is_mb_edge) > 0, 4, 3) \
-            + xp.zeros_like(qp_p)
-        return bs, qp_p, qp_q
-    nzp = nz_blk[:, e - 1]
-    nzq = nz_blk[:, e]
-    coded = (nzp | nzq) > 0
-    dmv = xp.abs(mv_blk[:, e - 1, :] - mv_blk[:, e, :])
-    moved = xp.max(dmv, axis=-1) >= 2          # >= 1 integer pel (half units)
-    bs = xp.where(coded, 2, xp.where(moved, 1, 0))
-    return bs, qp_p, qp_q
+def _skew(a, mbh: int, xp):
+    """[x, ..., y] -> [t, ..., y] with out[t] = the input at x = t - 2y
+    (zeros where no such macroblock exists), t < mbw + 2(mbh-1) +
+    _FLUSH: lane y rolled down the leading axis by 2y, one conditional
+    roll of whole blocks per bit of y — static slices and selects, no
+    index arrays. Lanes past mbh are blank and stay blank."""
+    pad = [(0, 2 * (mbh - 1) + _FLUSH)] + [(0, 0)] * (a.ndim - 1)
+    a = xp.pad(a, pad)
+    lane = xp.arange(a.shape[-1])
+    bit = 1
+    while bit < mbh:
+        a = xp.where((lane & bit) > 0, xp.roll(a, 2 * bit, axis=0), a)
+        bit <<= 1
+    return a
 
 
-def _expand_rows(seg, n: int, ops):
-    """(rows, E) per-4-sample-segment values → per-sample rows."""
-    return ops.xp.repeat(seg, n, axis=0)
+def _unskew(a, mbh: int, mbw: int, xp):
+    """The inverse of `_skew` on the blocks before the flush."""
+    lane = xp.arange(a.shape[-1])
+    bit = 1
+    while bit < mbh:
+        a = xp.where((lane & bit) > 0, xp.roll(a, -2 * bit, axis=0), a)
+        bit <<= 1
+    return a[:mbw]
+
+
+def _from_left(a, xp):
+    """out[t] = a[t-1]: the value at the macroblock to the left."""
+    return xp.concatenate([xp.zeros_like(a[:1]), a[:-1]], axis=0)
+
+
+def _lane_down(a, xp):
+    """out[..., y] = a[..., y-1] (lane 0 takes what its masks void)."""
+    return xp.roll(a, 1, axis=-1)
+
+
+def _lane_up(a, xp):
+    return xp.roll(a, -1, axis=-1)
+
+
+def _from_above(a, xp):
+    """out[t, ..., y] = a[t-2, ..., y-1]: the macroblock above."""
+    return _lane_down(_from_left(_from_left(a, xp), xp), xp)
 
 
 # ---------------------------------------------------------------------------
-# the edge filters (vertical form; horizontal = transpose outside)
+# boundary strengths and thresholds of every edge, on per-block grids
+# ---------------------------------------------------------------------------
+
+def _lut(table, idx, xp):
+    """table[idx] for a small constant table, as compares and a sum
+    (no gather): idx int32 of any shape."""
+    lead = (len(table),) + (1,) * idx.ndim      # the table axis leads:
+    hit = idx[None] == xp.arange(len(table)).reshape(lead)  # no padding
+    return xp.sum(xp.where(hit, xp.asarray(table).reshape(lead), 0), axis=0)
+
+
+def _thresholds(qpav, xp):
+    """(alpha, beta, tc0 for bS 1..3) of an average QP grid."""
+    idx = xp.clip(qpav, 0, 51)
+    return (_lut(ALPHA_TABLE, idx, xp), _lut(BETA_TABLE, idx, xp),
+            [_lut(TC0_TABLE[k], idx, xp) for k in range(3)])
+
+
+def _pack_params(bs, thresholds, xp):
+    """One int32 per block edge: alpha | beta << 8 | tc0 << 13 |
+    bS << 18 (`_unpack_params`). `thresholds` broadcast against bs."""
+    alpha, beta, tc0s = thresholds
+    tc0 = xp.where(bs == 1, tc0s[0], xp.where(bs == 2, tc0s[1], tc0s[2]))
+    return alpha | (beta << 8) | (tc0 << 13) | (bs << 18)
+
+
+def _unpack_params(prm):
+    return prm & 255, (prm >> 8) & 31, (prm >> 13) & 31, prm >> 18
+
+
+def _edge_params(grid, intra: bool, xp):
+    """The packed parameters of every sample line of every edge, in the
+    skewed layout: [t, 10, 16, y] int32 — rows 0..3 the vertical luma
+    edges of a macroblock (16 sample rows each), 4..7 the horizontal
+    ones (16 columns), 8 and 9 the chroma edges (two of 8 lines each).
+
+    `grid` is the skewed per-macroblock metadata [t, 22, y]: 16 flags
+    "4x4 luma block coded" (raster), QP_Y, mv (2), and the masks
+    `internal edges exist`, `left edge exists`, `top edge exists`."""
+    T, mbh = grid.shape[0], grid.shape[-1]
+    nz = grid[:, :16].reshape(T, 4, 4, mbh)          # [t, by, bx, y]
+    qp = grid[:, 16]
+    mv = grid[:, 17:19]
+    on, left_ok, top_ok = grid[:, 19], grid[:, 20], grid[:, 21]
+    first = (xp.arange(4) == 0)                      # the MB edge
+
+    if intra:
+        full = xp.zeros((T, 4, 4, mbh), xp.int32)
+        bs_v = full + xp.where(first[None, None, :, None], 4, 3)
+        bs_h = full + xp.where(first[None, :, None, None], 4, 3)
+    else:
+        def moved(other):
+            d = xp.abs(mv - other)
+            # >= 1 integer sample, in half-sample units
+            return (xp.max(d, axis=1) >= 2)[:, None, None, :]
+
+        nz_l = xp.concatenate(
+            [_from_left(nz, xp)[:, :, 3:], nz[:, :, :3]], axis=2)
+        nz_t = xp.concatenate(
+            [_from_above(nz, xp)[:, 3:], nz[:, :3]], axis=1)
+        bs_v = xp.where(
+            (nz | nz_l) > 0, 2,
+            xp.where(moved(_from_left(mv, xp))
+                     & first[None, None, :, None], 1, 0))
+        bs_h = xp.where(
+            (nz | nz_t) > 0, 2,
+            xp.where(moved(_from_above(mv, xp))
+                     & first[None, :, None, None], 1, 0))
+    exists_v = xp.where(first[None, None, :, None],
+                        left_ok[:, None, None, :], on[:, None, None, :])
+    exists_h = xp.where(first[None, :, None, None],
+                        top_ok[:, None, None, :], on[:, None, None, :])
+    bs_v = bs_v * exists_v                           # [t, by, bx=e, y]
+    bs_h = bs_h * exists_h                           # [t, by=e, bx, y]
+
+    qp_l, qp_t = _from_left(qp, xp), _from_above(qp, xp)
+    qpc = _lut(_QPC_NP, xp.clip(qp, 0, 51), xp)
+    qpc_l, qpc_t = _from_left(qpc, xp), _from_above(qpc, xp)
+
+    th_luma, th_chroma = _thresholds(qp, xp), _thresholds(qpc, xp)
+
+    def per_edge(own, th_own, other, axis_first):
+        """Thresholds of the MB edge (QP averaged with the neighbour)
+        and of the internal edges, selected by `axis_first`."""
+        th_mb = _thresholds((own + other + 1) >> 1, xp)
+
+        def pick(a_mb, a_own):
+            return xp.where(axis_first, a_mb[:, None, None, :],
+                            a_own[:, None, None, :])
+        return (pick(th_mb[0], th_own[0]), pick(th_mb[1], th_own[1]),
+                [pick(m, o) for m, o in zip(th_mb[2], th_own[2])])
+
+    fv, fh = first[None, None, :, None], first[None, :, None, None]
+    luma_v = _pack_params(bs_v, per_edge(qp, th_luma, qp_l, fv), xp)
+    luma_h = _pack_params(bs_h, per_edge(qp, th_luma, qp_t, fh), xp)
+    chroma_v = _pack_params(bs_v, per_edge(qpc, th_chroma, qpc_l, fv), xp)
+    chroma_h = _pack_params(bs_h, per_edge(qpc, th_chroma, qpc_t, fh), xp)
+
+    # per block edge -> per sample line: a luma line takes its 4x4
+    # block's value, a chroma line that of the luma line it maps to
+    # (2 chroma lines per block); chroma edges are luma edges 0 and 2
+    lv = xp.repeat(xp.transpose(luma_v, (0, 2, 1, 3)), 4, axis=2)
+    lh = xp.repeat(luma_h, 4, axis=2)
+    cv = xp.repeat(xp.stack([chroma_v[:, :, 0], chroma_v[:, :, 2]], 1),
+                   2, axis=2).reshape(T, 1, 16, mbh)
+    ch = xp.repeat(xp.stack([chroma_h[:, 0], chroma_h[:, 2]], 1),
+                   2, axis=2).reshape(T, 1, 16, mbh)
+    return xp.concatenate([lv, lh, cv, ch], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# the edge filters: one edge, all its sample lines at once
 # ---------------------------------------------------------------------------
 
 def _clip3(lo, hi, x, xp):
     return xp.minimum(hi, xp.maximum(lo, x))
 
 
-def _filter_luma_cols(X, xs, bs, qpav, ops):
-    """Filter the vertical luma edges at sample columns `xs` of plane X
-    (int32, (H, W)). bs/qpav: (H, len(xs)) int32 per-sample-row values.
-    Returns the filtered plane; every read comes from the pass input."""
-    xp = ops.xp
-    g = ops.gather_cols
-    p3, p2, p1, p0 = (g(X, xs - 4), g(X, xs - 3), g(X, xs - 2),
-                      g(X, xs - 1))
-    q0, q1, q2, q3 = g(X, xs), g(X, xs + 1), g(X, xs + 2), g(X, xs + 3)
-    idx = _clip3(0, 51, qpav, xp)
-    alpha = ops.asarray(ALPHA_TABLE)[idx]
-    beta = ops.asarray(BETA_TABLE)[idx]
+def _filter_luma_edge(p, q, prm, xp):
+    """§8.7.2.3 / 8.7.2.4 across one luma edge. p = (p0, p1, p2, p3),
+    q likewise, each [lines, y] int32; prm the packed parameters of the
+    lines. Returns ((p0', p1', p2'), (q0', q1', q2'))."""
+    p0, p1, p2, p3 = p
+    q0, q1, q2, q3 = q
+    alpha, beta, tc0, bs = _unpack_params(prm)
     filt = ((bs > 0)
             & (xp.abs(p0 - q0) < alpha)
             & (xp.abs(p1 - p0) < beta)
@@ -171,7 +321,6 @@ def _filter_luma_cols(X, xs, bs, qpav, ops):
     aq = xp.abs(q2 - q0) < beta
 
     # -- normal filter (bS 1..3) --
-    tc0 = ops.asarray(TC0_TABLE)[_clip3(0, 2, bs - 1, xp), idx]
     tc = tc0 + ap.astype(xp.int32) + aq.astype(xp.int32)
     delta = _clip3(-tc, tc,
                    (((q0 - p0) << 2) + (p1 - q1) + 4) >> 3, xp)
@@ -185,7 +334,6 @@ def _filter_luma_cols(X, xs, bs, qpav, ops):
     out_q0 = xp.where(normal, nq0, q0)
     out_p1 = xp.where(normal & ap, np1, p1)
     out_q1 = xp.where(normal & aq, nq1, q1)
-    out_p2, out_q2 = p2, q2
 
     # -- strong filter (bS == 4) --
     strong = filt & (bs == 4)
@@ -202,30 +350,21 @@ def _filter_luma_cols(X, xs, bs, qpav, ops):
     wq0 = (2 * q1 + q0 + p1 + 2) >> 2
     out_p0 = xp.where(strong, xp.where(sp, sp0, wp0), out_p0)
     out_p1 = xp.where(sp, sp1, out_p1)
-    out_p2 = xp.where(sp, sp2, out_p2)
+    out_p2 = xp.where(sp, sp2, p2)
     out_q0 = xp.where(strong, xp.where(sq, sq0, wq0), out_q0)
     out_q1 = xp.where(sq, sq1, out_q1)
-    out_q2 = xp.where(sq, sq2, out_q2)
-
-    return ops.scatter_cols(X, [
-        (xs - 3, out_p2), (xs - 2, out_p1), (xs - 1, out_p0),
-        (xs, out_q0), (xs + 1, out_q1), (xs + 2, out_q2)])
+    out_q2 = xp.where(sq, sq2, q2)
+    return (out_p0, out_p1, out_p2), (out_q0, out_q1, out_q2)
 
 
-def _filter_chroma_cols(C, xs, bs, qpav_c, ops):
-    """Chroma vertical edge filter (writes p0/q0 only)."""
-    xp = ops.xp
-    g = ops.gather_cols
-    p1, p0 = g(C, xs - 2), g(C, xs - 1)
-    q0, q1 = g(C, xs), g(C, xs + 1)
-    idx = _clip3(0, 51, qpav_c, xp)
-    alpha = ops.asarray(ALPHA_TABLE)[idx]
-    beta = ops.asarray(BETA_TABLE)[idx]
+def _filter_chroma_edge(p0, p1, q0, q1, prm, xp):
+    """Chroma edge (only p0 / q0 change). Samples [2, lines, y] (Cb,
+    Cr), prm [lines, y]. Returns (p0', q0')."""
+    alpha, beta, tc0, bs = _unpack_params(prm)
     filt = ((bs > 0)
             & (xp.abs(p0 - q0) < alpha)
             & (xp.abs(p1 - p0) < beta)
             & (xp.abs(q1 - q0) < beta))
-    tc0 = ops.asarray(TC0_TABLE)[_clip3(0, 2, bs - 1, xp), idx]
     tc = tc0 + 1
     delta = _clip3(-tc, tc,
                    (((q0 - p0) << 2) + (p1 - q1) + 4) >> 3, xp)
@@ -237,148 +376,168 @@ def _filter_chroma_cols(C, xs, bs, qpav_c, ops):
     strong = filt & (bs == 4)
     out_p0 = xp.where(strong, sp0, xp.where(normal, np0, p0))
     out_q0 = xp.where(strong, sq0, xp.where(normal, nq0, q0))
-    return ops.scatter_cols(C, [(xs - 1, out_p0), (xs, out_q0)])
+    return out_p0, out_q0
+
+
+# ---------------------------------------------------------------------------
+# one wavefront
+# ---------------------------------------------------------------------------
+
+#: the axis the sample lines of an edge are counted along, in a luma
+#: block [r, c, y] and in a chroma block [2, r, c, y] alike: a vertical
+#: edge separates columns, a horizontal one rows
+_COLS, _ROWS = -2, -3
+
+
+def _span(blk, lo, hi, axis: int):
+    return blk[(Ellipsis, slice(lo, hi)) + (slice(None),) * (-axis - 1)]
+
+
+def _line(blk, k: int, axis: int):
+    return blk[(Ellipsis, k) + (slice(None),) * (-axis - 1)]
+
+
+def _put_lines(blk, first: int, lines, axis: int, xp):
+    """`blk` with the consecutive lines from `first` on replaced."""
+    parts = [_span(blk, 0, first, axis), xp.stack(lines, axis=axis),
+             _span(blk, first + len(lines), None, axis)]
+    return xp.concatenate([p for p in parts if p.shape[axis]], axis)
+
+
+def _luma_mb_edge(near, cur, prm, axis: int, xp):
+    """Edge 0 of `cur` against the last four lines of `near` (the
+    macroblock to the left, or above)."""
+    p = tuple(_line(near, 15 - i, axis) for i in range(4))
+    q = tuple(_line(cur, i, axis) for i in range(4))
+    new_p, new_q = _filter_luma_edge(p, q, prm, xp)
+    return (_put_lines(near, 13, new_p[::-1], axis, xp),
+            _put_lines(cur, 0, new_q, axis, xp))
+
+
+def _luma_inner_edges(cur, prm, axis: int, xp):
+    for edge in (1, 2, 3):
+        at = 4 * edge
+        p = tuple(_line(cur, at - 1 - i, axis) for i in range(4))
+        q = tuple(_line(cur, at + i, axis) for i in range(4))
+        new_p, new_q = _filter_luma_edge(p, q, prm[edge], xp)
+        cur = _put_lines(cur, at - 3, new_p[::-1] + new_q, axis, xp)
+    return cur
+
+
+def _chroma_mb_edge(near, cur, prm, axis: int, xp):
+    """The chroma twin of `_luma_mb_edge` (8 lines, p0 / q0 only)."""
+    new_p0, new_q0 = _filter_chroma_edge(
+        _line(near, 7, axis), _line(near, 6, axis),
+        _line(cur, 0, axis), _line(cur, 1, axis), prm, xp)
+    return (_put_lines(near, 7, (new_p0,), axis, xp),
+            _put_lines(cur, 0, (new_q0,), axis, xp))
+
+
+def _chroma_inner_edge(cur, prm, axis: int, xp):
+    new_p0, new_q0 = _filter_chroma_edge(
+        _line(cur, 3, axis), _line(cur, 2, axis),
+        _line(cur, 4, axis), _line(cur, 5, axis), prm, xp)
+    return _put_lines(cur, 3, (new_p0, new_q0), axis, xp)
+
+
+def _wavefront_step(carry, blocks, xp):
+    """All macroblocks of wavefront t at once.
+
+    carry: the luma and chroma blocks of wavefronts t-1 (filtered but
+    for what t and t+1 do to them) and t-2; blocks: wavefront t's
+    unfiltered luma [16, 16, y] and chroma [2, 8, 8, y] and its packed
+    edge parameters [10, 16, y]. Emits wavefront t-2, now final."""
+    left_y, above_y, left_c, above_c = carry
+    cur_y, cur_c, prm = blocks
+    cur_y = cur_y.astype(xp.int32)
+    cur_c = cur_c.astype(xp.int32)
+
+    # vertical edges, left to right
+    left_y, cur_y = _luma_mb_edge(left_y, cur_y, prm[0], _COLS, xp)
+    cur_y = _luma_inner_edges(cur_y, prm[0:4], _COLS, xp)
+    left_c, cur_c = _chroma_mb_edge(left_c, cur_c, prm[8, :8], _COLS, xp)
+    cur_c = _chroma_inner_edge(cur_c, prm[8, 8:], _COLS, xp)
+
+    # horizontal edges, top to bottom. The macroblock above sits one
+    # lane down in block t-2.
+    top_y, cur_y = _luma_mb_edge(_lane_down(above_y, xp), cur_y, prm[4],
+                                 _ROWS, xp)
+    cur_y = _luma_inner_edges(cur_y, prm[4:8], _ROWS, xp)
+    top_c, cur_c = _chroma_mb_edge(_lane_down(above_c, xp), cur_c,
+                                   prm[9, :8], _ROWS, xp)
+    cur_c = _chroma_inner_edge(cur_c, prm[9, 8:], _ROWS, xp)
+    done_y, done_c = _lane_up(top_y, xp), _lane_up(top_c, xp)
+    return ((cur_y, left_y, cur_c, left_c),
+            (done_y.astype(blocks[0].dtype), done_c.astype(blocks[1].dtype)))
 
 
 # ---------------------------------------------------------------------------
 # frame-level driver
 # ---------------------------------------------------------------------------
 
-def _luma_edge_sets(nblk: int):
-    """(internal, mb) PLANE-LOCAL block rows/cols of the luma edges —
-    static (from the plane shape only, so a traced band position never
-    shapes an index set). Global liveness — frame/band-padding bounds
-    for horizontal edges — is applied as a traced bS mask instead
-    (:func:`_edge_live`)."""
-    idx = np.arange(nblk)
-    internal = idx[(idx > 0) & (idx % 4 != 0)]
-    mb = idx[(idx > 0) & (idx % 4 == 0)]
-    return internal, mb
-
-
-def _edge_live(edge_blocks, blk0, blk_hi, ops):
-    """(nE,) bool: does this plane-local edge exist in the PICTURE?
-    `blk0`/`blk_hi` may be traced scalars (SFE band position under
-    shard_map)."""
-    g = ops.asarray(edge_blocks) + blk0
-    return (g > 0) & (g < blk_hi)
-
-
-def _deblock_luma(y32, qp_blk, nz_blk, mv_blk, intra: bool, ops,
-                  blk_row0, total_blk_rows):
-    """The four luma passes over one (possibly band-sliced) plane.
-    `blk_row0` is the global 4x4-block row of plane row 0 and
-    `total_blk_rows` the picture's real block-row count (both may be
-    traced) — horizontal edges outside (0, total) don't exist in the
-    picture (band padding / frame boundary) and are masked to bS 0."""
-    nbh, nbw = y32.shape[0] // 4, y32.shape[1] // 4
-
-    def vpass(plane, qb, nb, mb_, edge_blocks, live):
-        if len(edge_blocks) == 0:
-            return plane
-        bs, qp_p, qp_q = _edge_bs(qb, nb, mb_, edge_blocks, intra, ops)
-        if live is not None:
-            bs = ops.xp.where(live[None, :], bs, 0)
-        qpav = (qp_p + qp_q + 1) >> 1
-        return _filter_luma_cols(
-            plane, edge_blocks * 4,
-            _expand_rows(bs, 4, ops), _expand_rows(qpav, 4, ops), ops)
-
-    internal, mb_cols = _luma_edge_sets(nbw)
-    y32 = vpass(y32, qp_blk, nz_blk, mv_blk, internal, None)
-    y32 = vpass(y32, qp_blk, nz_blk, mv_blk, mb_cols, None)
-
-    # horizontal passes: transpose, reuse the vertical machinery
-    yt = y32.T
-    qbt = qp_blk.T
-    nbt = None if intra else nz_blk.T
-    mbt = None if intra else ops.xp.transpose(mv_blk, (1, 0, 2))
-    internal_h, mb_h = _luma_edge_sets(nbh)
-    yt = vpass(yt, qbt, nbt, mbt, internal_h,
-               _edge_live(internal_h, blk_row0, total_blk_rows, ops))
-    yt = vpass(yt, qbt, nbt, mbt, mb_h,
-               _edge_live(mb_h, blk_row0, total_blk_rows, ops))
-    return yt.T
-
-
-def _deblock_chroma(c32, qp_blk, nz_blk, mv_blk, intra: bool, ops,
-                    blk_row0, total_blk_rows):
-    """Both chroma passes for one chroma plane (u or v). Chroma edges
-    at chroma x % 8 in {0, 4} take the bS of the corresponding luma
-    edge (luma x = 2·chroma x); chroma qpav averages the two MBs'
-    QP_C. Chroma rows map 2:1 onto luma rows, so the per-row bS/qp
-    vectors are the luma block rows repeated twice."""
-    xp = ops.xp
-    nbh, nbw = c32.shape[0] // 4, c32.shape[1] // 4  # chroma 4x4 blocks
-
-    def cpass(plane, qb, nb, mb_, edge_blocks, live):
-        # edge_blocks: LUMA block columns of the corresponding luma
-        # edges (chroma col 4c <-> luma col 8c: luma block col 2*eb)
-        if len(edge_blocks) == 0:
-            return plane
-        bs, qp_p, qp_q = _edge_bs(qb, nb, mb_, edge_blocks, intra, ops)
-        if live is not None:
-            bs = xp.where(live[None, :], bs, 0)
-        qpc_av = (ops.asarray(_QPC_NP)[_clip3(0, 51, qp_p, xp)]
-                  + ops.asarray(_QPC_NP)[_clip3(0, 51, qp_q, xp)]
-                  + 1) >> 1
-        # luma 4-row segments -> luma rows -> chroma rows (2:1)
-        bs_rows = _expand_rows(bs, 2, ops)
-        qp_rows = _expand_rows(qpc_av, 2, ops)
-        return _filter_chroma_cols(plane, edge_blocks * 2, bs_rows,
-                                   qp_rows, ops)
-
-    # vertical chroma edges: chroma x in {0 (x>0), 4} per MB = luma
-    # block cols {0, 2} per MB (even luma block columns)
-    cols = np.arange(2 * nbw)                 # luma block cols 0..2nbw
-    vcols = cols[(cols % 2 == 0) & (cols > 0)]
-    c32 = cpass(c32, qp_blk, nz_blk, mv_blk, vcols, None)
-
-    ct = c32.T
-    qbt = qp_blk.T
-    nbt = None if intra else nz_blk.T
-    mbt = None if intra else xp.transpose(mv_blk, (1, 0, 2))
-    rows = np.arange(2 * nbh)                 # luma block rows, local
-    hrows = rows[(rows % 2 == 0) & (rows > 0)]
-    ct = cpass(ct, qbt, nbt, mbt, hrows,
-               _edge_live(hrows, blk_row0, total_blk_rows, ops))
-    return ct.T
-
-
 def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
-                  mb_row0: int = 0, total_mb_rows: int | None = None,
-                  ops=NUMPY_OPS):
-    """Deblock one (padded) frame or band slice.
+                  mb_row0=0, total_mb_rows: int | None = None,
+                  edges=None, ops=NUMPY_OPS):
+    """Deblock one (padded) frame, or one band slice by itself.
 
     y: (16·mbh_p, 16·mbw) luma plane (any int dtype; uint8 ok);
     u/v: (8·mbh_p, 8·mbw); qp_map: (mbh_p, mbw) int QP_Y per MB;
     `intra` selects the picture-homogeneous bS rule. For P pictures,
     nz4: (4·mbh_p, 4·mbw) any-nonzero per 4x4 luma block and
-    mv: (mbh_p, mbw, 2) half-pel MVs. `mb_row0`/`total_mb_rows`
-    position a band slice inside the picture (horizontal edges outside
-    the picture's real MB rows are skipped); the defaults describe a
-    full frame. Returns filtered (y, u, v) in the input dtypes.
+    mv: (mbh_p, mbw, 2) half-pel MVs. `mb_row0` (may be traced) and
+    `total_mb_rows` say where the plane's first macroblock row lies in
+    the picture and how many the picture has: rows past the picture
+    (band padding) are left alone. `edges` = (internal, left, top)
+    (mbh_p, mbw) masks of the edges that exist, for a picture of
+    several slices; the default is one slice. Returns filtered
+    (y, u, v) in the input dtypes.
     """
     xp = ops.xp
-    mbh_p, mbw = qp_map.shape[0], qp_map.shape[1]
-    if total_mb_rows is None:
-        total_mb_rows = mb_row0 + mbh_p
-    y_dt, c_dt = y.dtype, u.dtype
-    y32 = ops.asarray(y).astype(xp.int32)
-    u32 = ops.asarray(u).astype(xp.int32)
-    v32 = ops.asarray(v).astype(xp.int32)
-    qp_map = ops.asarray(qp_map).astype(xp.int32)
-    if not intra:
-        if nz4 is None or mv is None:
-            raise ValueError("P-frame deblock requires nz4 and mv")
-        mv = ops.asarray(mv).astype(xp.int32)
-    qp_blk, nz_blk, mv_blk = _block_grids(qp_map, intra, nz4, mv, ops)
-    blk_row0 = 4 * mb_row0
-    total_blk = 4 * total_mb_rows
-    y32 = _deblock_luma(y32, qp_blk, nz_blk, mv_blk, intra, ops,
-                        blk_row0, total_blk)
-    u32 = _deblock_chroma(u32, qp_blk, nz_blk, mv_blk, intra, ops,
-                          blk_row0, total_blk)
-    v32 = _deblock_chroma(v32, qp_blk, nz_blk, mv_blk, intra, ops,
-                          blk_row0, total_blk)
-    return (y32.astype(y_dt), u32.astype(c_dt), v32.astype(c_dt))
+    mbh, mbw = qp_map.shape[0], qp_map.shape[1]
+    with ops.scope("deblock"):
+        y, u, v = ops.asarray(y), ops.asarray(u), ops.asarray(v)
+        rows = xp.arange(mbh)[:, None]
+        live = xp.ones((mbh, mbw), xp.int32)
+        if total_mb_rows is not None:
+            live = live * (rows + mb_row0 < total_mb_rows)
+        if edges is None:
+            edges = (live, live * (xp.arange(mbw)[None, :] > 0),
+                     live * (rows > 0))
+        if intra:
+            nz = xp.zeros((mbh, 4, mbw, 4), xp.int32)
+            mv = xp.zeros((mbh, mbw, 2), xp.int32)
+        else:
+            if nz4 is None or mv is None:
+                raise ValueError("P-frame deblock requires nz4 and mv")
+            nz = ops.asarray(nz4).astype(xp.int32).reshape(mbh, 4, mbw, 4)
+            mv = ops.asarray(mv).astype(xp.int32)
+        lanes = ops.lanes(mbh)
+        grid = xp.concatenate(                       # [y, 22, x]
+            [xp.transpose(nz, (0, 1, 3, 2)).reshape(mbh, 16, mbw),
+             ops.asarray(qp_map).astype(xp.int32)[:, None],
+             xp.transpose(mv, (0, 2, 1))]
+            + [ops.asarray(e).astype(xp.int32)[:, None] for e in edges],
+            axis=1)
+        prm = _edge_params(
+            _skew(_to_lanes(grid, (2, 1, 0), lanes, ops), mbh, xp),
+            intra, xp)
+        # [y, r, x, c] -> [x, r, c, y] -> [t, r, c, y]; chroma
+        # [2, y, r, x, c] -> [x, 2, r, c, y] -> [t, 2, r, c, y]
+        ys = _skew(_to_lanes(y.reshape(mbh, 16, mbw, 16), (2, 1, 3, 0),
+                             lanes, ops), mbh, xp)
+        cs = _skew(_to_lanes(xp.stack([u, v]).reshape(2, mbh, 8, mbw, 8),
+                             (3, 0, 2, 4, 1), lanes, ops), mbh, xp)
+        zero_y = ys[0].astype(xp.int32) * 0
+        zero_c = cs[0].astype(xp.int32) * 0
+
+    done_y, done_c = ops.scan(
+        lambda carry, blocks: _wavefront_step(carry, blocks, xp),
+        (zero_y, zero_y, zero_c, zero_c), (ys, cs, prm))
+
+    with ops.scope("deblock"):
+        out_y = ops.barrier(_unskew(done_y[_FLUSH:], mbh, mbw, xp))
+        out_c = ops.barrier(_unskew(done_c[_FLUSH:], mbh, mbw, xp))
+        out_y = xp.transpose(out_y[..., :mbh], (3, 1, 0, 2)).reshape(y.shape)
+        out_c = xp.transpose(out_c[..., :mbh], (1, 4, 2, 0, 3)).reshape(
+            (2,) + u.shape)
+        return out_y, out_c[0], out_c[1]

@@ -9,7 +9,8 @@ stamp/seam verification tooling to decode without external binaries.
 
 Scope grows with the encoder: one reference frame (the previous
 decoded picture), whole-MB partitions, half-pel MVs (quarter-pel mvd),
-deblocking disabled, and pictures split into any number of slices —
+the in-loop filter as each slice signals it (idc 0, 1 or 2, offsets
+0), and pictures split into any number of slices —
 the split-frame-encoding path emits one slice per MB-row band, and
 this decoder applies the same §7.4.3 cross-slice neighbor
 unavailability the encoder's band packers assume.
@@ -76,11 +77,27 @@ class _Picture:
         # in-loop deblocking state: the effective QP_Y of every MB (the
         # running slice QP after mb_qp_delta; uncoded MBs keep the
         # running value — §8.7's QP for skipped MBs), the picture's
-        # coding type, and whether ANY slice enabled the filter (all
-        # slices of a picture carry the same idc in our streams).
+        # coding type, and of every MB the slice it came in and that
+        # slice's disable_deblocking_filter_idc (1 until decoded).
         self.qp_mb = np.zeros((mbh, mbw), np.int32)
         self.intra = True
-        self.deblock = False
+        self.slices = 0
+        self.slice_of = np.zeros(mbh * mbw, np.int32)
+        self.idc_of = np.ones(mbh * mbw, np.int32)
+
+    def deblock_edges(self):
+        """§8.7's filterInternalEdgesFlag / filterLeftMbEdgeFlag /
+        filterTopMbEdgeFlag of every MB, (mbh, mbw) masks: idc 1 leaves
+        an MB alone, idc 2 leaves its edges to another slice alone."""
+        idc = self.idc_of.reshape(self.mbh, self.mbw)
+        sl = self.slice_of.reshape(self.mbh, self.mbw)
+        on = idc != 1
+        left = np.zeros_like(on)
+        top = np.zeros_like(on)
+        left[:, 1:] = on[:, 1:] & ((idc[:, 1:] == 0)
+                                   | (sl[:, 1:] == sl[:, :-1]))
+        top[1:] = on[1:] & ((idc[1:] == 0) | (sl[1:] == sl[:-1]))
+        return on, left, top
 
 
 def _tap6(x: np.ndarray, axis: int) -> np.ndarray:
@@ -394,11 +411,11 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             raise ValueError(
                 f"picture ended with {pic.decoded} of "
                 f"{pic.mbw * pic.mbh} MBs decoded (missing slice?)")
-        if pic.deblock:
+        if (pic.idc_of != 1).any():
             # §8.7 in-loop filter over the whole decoded picture
-            # (shifted-plane schedule, codecs/h264/deblock.py): the
-            # filtered planes are both the output frame and the next
-            # P picture's reference — exactly the encoder's recon
+            # (codecs/h264/deblock.py, the filter the encoder runs):
+            # the filtered planes are both the output frame and the
+            # next P picture's reference — exactly the encoder's recon
             # carry. Intra prediction inside the picture already ran
             # on unfiltered samples, as the spec requires.
             from .deblock import deblock_frame
@@ -406,7 +423,8 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             nz4 = None if pic.intra else (pic.luma_counts > 0)
             pic.y, pic.u, pic.v = deblock_frame(
                 pic.y, pic.u, pic.v, pic.qp_mb, intra=pic.intra,
-                nz4=nz4, mv=None if pic.intra else pic.mv)
+                nz4=nz4, mv=None if pic.intra else pic.mv,
+                edges=pic.deblock_edges())
         w, h = sps.width, sps.height
         frames.append(Frame(
             pic.y[:h, :w], pic.u[:h // 2, :w // 2],
@@ -427,23 +445,24 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             if header.slice_type not in (SLICE_TYPE_I, SLICE_TYPE_P):
                 raise ValueError(
                     f"unsupported slice type {header.slice_type}")
-            if header.deblock_idc == 2:
-                raise ValueError(
-                    "disable_deblocking_filter_idc == 2 (slice-local "
-                    "filtering) not supported; this codec emits 0 or 1")
             if header.first_mb == 0:
                 finish_picture()              # new access unit
                 pic = _Picture(sps)
             elif pic is None:
                 raise ValueError("slice with first_mb != 0 opens a picture")
             pic.intra = header.slice_type == SLICE_TYPE_I
-            pic.deblock = pic.deblock or header.deblock_idc == 0
+            before = pic.decoded
             if header.slice_type == SLICE_TYPE_I:
                 _decode_islice(br, pic, header)
             else:
                 if ref is None:
                     raise ValueError("P slice without a reference frame")
                 _decode_pslice(br, pic, header, ref)
+            mbs = slice(header.first_mb,
+                        header.first_mb + pic.decoded - before)
+            pic.slice_of[mbs] = pic.slices
+            pic.idc_of[mbs] = header.deblock_idc
+            pic.slices += 1
     finish_picture()
     if sps is None:
         raise ValueError("no SPS in stream")

@@ -355,7 +355,7 @@ def mb_cbp(levels: FrameLevels, mi: int) -> tuple[int, int]:
 def pack_slice(levels: FrameLevels, mbw: int, mbh: int, sps: SPS, pps: PPS,
                qp: int, frame_num: int = 0, idr: bool = True,
                idr_pic_id: int = 0, native: bool | None = None,
-               first_mb: int = 0, deblock: bool = False) -> bytes:
+               first_mb: int = 0, deblock_idc: int = 1) -> bytes:
     """Entropy-pack one I slice into an Annex-B NAL unit.
 
     `levels`/`mbw`/`mbh` describe the SLICE's macroblocks; with a
@@ -373,7 +373,7 @@ def pack_slice(levels: FrameLevels, mbw: int, mbh: int, sps: SPS, pps: PPS,
     header = SliceHeader(
         slice_type=SLICE_TYPE_I, frame_num=frame_num, idr=idr, qp=qp,
         idr_pic_id=idr_pic_id, first_mb=first_mb,
-        deblock_idc=0 if deblock else 1,
+        deblock_idc=deblock_idc,
     )
     header.write(bw, sps, pps)
 
@@ -624,13 +624,13 @@ def _gop_slice_thunks(intra, pack_p, num_frames: int, mbw: int, mbh: int,
         luma_dc=il_dc, luma_ac=il_ac, chroma_dc=ic_dc, chroma_ac=ic_ac,
         qp_delta=qp_delta)
     head = sps.to_nal() + pps.to_nal() if with_headers else b""
-    deblock = bool(rd.deblock)
+    deblock_idc = 0 if rd.deblock else 1
 
     def pack_idr():
         return head + pack_slice(intra_levels, mbw, mbh, sps, pps, qp,
                                  frame_num=0, idr=True,
                                  idr_pic_id=idr_pic_id % 65536,
-                                 deblock=deblock)
+                                 deblock_idc=deblock_idc)
 
     thunks = [pack_idr]
     for i in range(num_frames - 1):
@@ -668,13 +668,13 @@ def gop_slice_thunks_planes(intra, planes, num_frames: int, mbw: int,
     on the pack pool instead of GOP-by-GOP."""
     from . import inter as inter_mod
 
-    deblock = bool(rd.deblock) if rd is not None else False
+    deblock_idc = 0 if rd is not None and rd.deblock else 1
     mv8, lp, udc, vdc, uac, vac = planes
     return _gop_slice_thunks(
         intra,
         lambda i, fn: inter_mod.pack_p_slice_plane(
             mv8[i], lp[i], udc[i], vdc[i], uac[i], vac[i], mbw, mbh,
-            sps, pps, qp, frame_num=fn, deblock=deblock),
+            sps, pps, qp, frame_num=fn, deblock_idc=deblock_idc),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         rd=rd)
 
@@ -708,12 +708,12 @@ def pack_gop_slices(intra, pouts, num_frames: int, mbw: int, mbh: int,
     """
     from . import inter as inter_mod
 
-    deblock = bool(rd.deblock) if rd is not None else False
+    deblock_idc = 0 if rd is not None and rd.deblock else 1
     mv, l16, cdc, cac = pouts
     return _pack_gop_common(
         intra,
         lambda i, fn: inter_mod.pack_p_slice(
             mv[i], l16[i], cdc[i], cac[i], mbw, mbh, sps, pps, qp,
-            frame_num=fn, deblock=deblock),
+            frame_num=fn, deblock_idc=deblock_idc),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         pool=pool, rd=rd)

@@ -224,8 +224,8 @@ class SliceHeader:
     #: disable_deblocking_filter_idc (§7.4.3): 1 = off (the historical
     #: default — encoder recon needs no filter), 0 = §8.7 in-loop
     #: deblocking across the whole picture (the `deblock` RD feature),
-    #: 2 = filter inside slices only (parsed, but neither emitted by
-    #: this encoder nor decoded by the in-repo decoder).
+    #: 2 = filter inside slices only (what the band slices of a
+    #: split-frame encode signal with `deblock` on).
     deblock_idc: int = 1
 
     @property

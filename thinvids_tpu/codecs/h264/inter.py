@@ -133,7 +133,7 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
                        u_ac: np.ndarray, v_ac: np.ndarray,
                        mbw: int, mbh: int, sps: SPS, pps: PPS, qp: int,
                        frame_num: int, native: bool | None = None,
-                       first_mb: int = 0, deblock: bool = False) -> bytes:
+                       first_mb: int = 0, deblock_idc: int = 1) -> bytes:
     """Entropy-pack one P slice straight from plane-layout levels.
 
     mv: (nmb, 2) int; luma_plane: (16*mbh, 16*mbw) int16 quantized
@@ -152,7 +152,7 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
     bw = BitWriter()
     header = SliceHeader(slice_type=SLICE_TYPE_P, frame_num=frame_num,
                          idr=False, qp=qp, first_mb=first_mb,
-                         deblock_idc=0 if deblock else 1)
+                         deblock_idc=deblock_idc)
     header.write(bw, sps, pps)
 
     if native is not False:
@@ -173,14 +173,14 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
     cdc = np.stack([u_dc, v_dc], axis=1).astype(np.int32)
     return pack_p_slice(np.asarray(mv, np.int32), l16, cdc, cac, mbw, mbh,
                         sps, pps, qp, frame_num, native=False,
-                        first_mb=first_mb, deblock=deblock)
+                        first_mb=first_mb, deblock_idc=deblock_idc)
 
 
 def pack_p_slice(mv: np.ndarray, luma16: np.ndarray, chroma_dc: np.ndarray,
                  chroma_ac: np.ndarray, mbw: int, mbh: int, sps: SPS,
                  pps: PPS, qp: int, frame_num: int,
                  native: bool | None = None, first_mb: int = 0,
-                 deblock: bool = False) -> bytes:
+                 deblock_idc: int = 1) -> bytes:
     """Entropy-pack one P slice into an Annex-B NAL unit.
 
     mv: (nmb, 2) half-pel (dy, dx); luma16: (nmb, 16, 16) z-scan
@@ -194,7 +194,7 @@ def pack_p_slice(mv: np.ndarray, luma16: np.ndarray, chroma_dc: np.ndarray,
     bw = BitWriter()
     header = SliceHeader(slice_type=SLICE_TYPE_P, frame_num=frame_num,
                          idr=False, qp=qp, first_mb=first_mb,
-                         deblock_idc=0 if deblock else 1)
+                         deblock_idc=deblock_idc)
     header.write(bw, sps, pps)
 
     if native is not False:

@@ -498,49 +498,23 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF):
 def _deblock_band(ry, ru, rv, qp, *, intra: bool, nz4, mv, mbw: int,
                   mbh_band: int, total_mb_rows: int, axis_name,
                   num_bands: int):
-    """Deblock one band's recon with a ONE-MB-ROW cross-band halo.
-
-    The §8.7 filter's vertical passes are row-local, and its horizontal
-    passes read/write at most 4 rows across an MB edge — so exchanging
-    16 raw recon rows (plus the neighbor MB row's bS metadata: nz map
-    and MVs; QP is flat in SFE) and running the full shifted-plane
-    schedule on the extended planes reproduces the FULL-FRAME filter
-    exactly: halo rows V-filter to the same values the neighbor band
-    computes for its own rows, the boundary H edge is computed
-    identically on both sides, and the per-band slices back out
-    byte-identical to the unbanded program (tested across band
-    counts). Frame edges / the last band's padding rows are masked via
-    the global (mb_row0, total_mb_rows) coordinates, with mb_row0
-    traced (lax.axis_index) so one program serves every band."""
+    """Deblock one band's recon by itself: the band is a slice that
+    signals disable_deblocking_filter_idc = 2, so a decoder filters no
+    edge between two bands, and §8.7's order (each macroblock row needs
+    the one above finished) ends at the band's first row. No sample and
+    no bS metadata crosses bands. The last band's padding rows lie past
+    the picture (`mb_row0`, traced through lax.axis_index so one
+    program serves every band, against `total_mb_rows`) and are left
+    alone."""
     banded = axis_name is not None and num_bands > 1
-    exch = functools.partial(jaxme.band_halo_exchange,
-                             axis_name=axis_name, num_bands=num_bands)
-    ry_e = exch(ry, 16)
-    ru_e = exch(ru, 8)
-    rv_e = exch(rv, 8)
     with stage("deblock"):
         idx = jax.lax.axis_index(axis_name) if banded \
             else jnp.int32(0) + _varying_zero(ry)
-        mb_row0 = idx * mbh_band - 1      # extended plane: 1 MB row above
-        qp_map = jnp.broadcast_to(qp.astype(jnp.int32),
-                                  (mbh_band + 2, mbw))
-    nz_e = mv_e = None
-    if not intra:
-        with stage("deblock"):
-            nz16 = nz4.astype(jnp.int16)
-        nz_e = exch(nz16, 4)
-        with stage("deblock"):
-            nz_e = nz_e != 0
-            mv_rows = mv.reshape(mbh_band, 2 * mbw)
-        mv_e = exch(mv_rows, 1)
-        with stage("deblock"):
-            mv_e = mv_e.reshape(mbh_band + 2, mbw, 2)
-    y2, u2, v2 = jaxdeblock.deblock_frame_jax(
-        ry_e, ru_e, rv_e, qp_map, intra=intra, nz4=nz_e, mv=mv_e,
+        mb_row0 = idx * mbh_band
+        qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh_band, mbw))
+    return jaxdeblock.deblock_frame_jax(
+        ry, ru, rv, qp_map, intra=intra, nz4=nz4, mv=mv,
         mb_row0=mb_row0, total_mb_rows=total_mb_rows)
-    with stage("deblock"):
-        return (y2[16:16 + 16 * mbh_band], u2[8:8 + 8 * mbh_band],
-                v2[8:8 + 8 * mbh_band])
 
 
 @stage("halo")
@@ -562,7 +536,7 @@ def _sfe_intra_common(y, u, v, qp, real_rows, *, mbw: int,
                       mbh_band: int, rd, total_mb_rows: int,
                       axis_name, num_bands: int):
     """Shared intra-band compute: slice-local core + recon fixup +
-    (with rd.deblock) the cross-band-halo in-loop filter on the carry.
+    (with rd.deblock) the band's own in-loop filter on the carry.
     Returns (core outputs, (ry, ru, rv, zero_mv))."""
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh_band, rd=rd)
 
@@ -595,7 +569,7 @@ def sfe_intra_band(y, u, v, qp, real_rows, *, mbw: int, mbh_band: int,
     live in ANOTHER slice and are unavailable to intra prediction
     (§8.3: exactly what a conformant decoder reconstructs), so no
     cross-band exchange is needed on intra frames (the in-loop filter,
-    when enabled, is the one cross-band consumer — _deblock_band).
+    when enabled, is slice-local too — _deblock_band).
 
     Returns (dense, rest, (ry, ru, rv, pred_mv)): dense is the
     hadamard-DC prefix [il_dc | ic_dc] shipped uncompressed (the only
@@ -673,10 +647,9 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
         raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
     if rd.deblock and (ext is not None or probe is not None
                        or return_hist):
-        # Farm band slices exchange halos over the host relay once per
-        # frame; the in-loop filter would need a second (post-recon)
-        # relay round. The remote planner falls back to GOP-range
-        # shards for deblock-enabled jobs instead.
+        # Farm band slices have never run with the in-loop filter
+        # (their references cross hosts once per frame, before it):
+        # the remote planner keeps GOP-range shards for deblock jobs.
         raise ValueError("deblock is not supported on cross-host band "
                          "slices; use GOP sharding for this job")
     ry, ru, rv, pred_mv = carry

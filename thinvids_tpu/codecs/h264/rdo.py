@@ -16,8 +16,8 @@ specialization, never a traced branch:
   (pure prediction — exactly what a decoder reconstructs for a
   skipped MB).
 - ``deblock``: §8.7 in-loop deblocking applied to the recon carried
-  between frames (and signaled in the slice headers), as the
-  shifted-plane approximation implemented in codecs/h264/deblock.py.
+  between frames (and signaled in the slice headers), in §8.7's own
+  order: codecs/h264/deblock.py, a wavefront over macroblocks.
 - ``aq_strength``: perceptual (variance/JND-style) per-MB QP
   modulation on INTRA frames: flat MBs (where quantization error is
   most visible) encode finer, busy MBs (where texture masks it)

@@ -66,9 +66,10 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     # pskip (TVT_PSKIP): P_Skip bias — near-zero inter residuals drop
     #   so static MBs code as skip runs;
     # deblock (TVT_DEBLOCK): §8.7 in-loop deblocking on the recon
-    #   carried between frames (signaled in the slice headers; SFE
-    #   runs it with a cross-band halo, and the remote planner keeps
-    #   deblock jobs on GOP shards);
+    #   carried between frames, in §8.7's order (signaled in the slice
+    #   headers: idc 0, or 2 on the band slices of SFE, which filter
+    #   their own rows; the remote planner keeps deblock jobs on GOP
+    #   shards);
     # aq_strength (TVT_AQ_STRENGTH, 0..3): perceptual variance-AQ
     #   per-MB QP modulation on intra frames (0 = off; quantized to
     #   quarter steps — the config is a compile-time specialization).

@@ -1715,9 +1715,10 @@ class SfeShardEncoder(GopShardEncoder):
         # RD feature gates for the banded shape: perceptual AQ would
         # make the per-band activity mean band-local (a different map
         # than the unbanded program) — strip it with a log line rather
-        # than encode something byte-different per band count; the
-        # in-loop filter needs the cross-band halo exchange, which the
-        # cross-host (farm) slices cannot run in one device program.
+        # than encode something byte-different per band count. The
+        # in-loop filter runs slice-locally in a band (every band slice
+        # signals disable_deblocking_filter_idc 2); cross-host (farm)
+        # slices have never run with it and stay refused.
         if self.rd.aq_q:
             _LOG.warning("perceptual AQ is not supported by split-frame "
                          "encoding; encoding this job with aq off")
@@ -1732,6 +1733,9 @@ class SfeShardEncoder(GopShardEncoder):
         #: the picture's REAL MB rows (band-grid padding rows beyond it
         #: carry no coded MBs): the deblock masks key off this
         self._total_mb_rows = mbh
+        #: 2 = each band slice filters its own rows, edges between
+        #: slices stay as they are (what jaxinter._deblock_band computes)
+        self._deblock_idc = 2 if self.rd.deblock else 1
         bp = self.band_plan
         self._real_rows = jax.device_put(
             np.asarray([[b.mb_rows * 16] for b in bp.bands], np.int32),
@@ -1895,7 +1899,7 @@ class SfeShardEncoder(GopShardEncoder):
                           qp, frame_num=0, idr=True,
                           idr_pic_id=idr_pic_id,
                           first_mb=band.start_mb_row * mbw,
-                          deblock=self.rd.deblock)
+                          deblock_idc=self._deblock_idc)
 
     def _pack_intra_band(self, dense_b, rest, bi: int, qp: int,
                          idr_pic_id: int) -> bytes:
@@ -1921,7 +1925,8 @@ class SfeShardEncoder(GopShardEncoder):
             mv[:n_real], lp[0][:rr], udc[0][:n_real], vdc[0][:n_real],
             uac[0][:rr // 2], vac[0][:rr // 2], mbw, band.mb_rows,
             self.sps, self.pps, qp, frame_num=frame_num,
-            first_mb=band.start_mb_row * mbw, deblock=self.rd.deblock)
+            first_mb=band.start_mb_row * mbw,
+            deblock_idc=self._deblock_idc)
 
     def _gather_frame(self, thunks: list) -> list[bytes]:
         pool = self._slice_pool()
