@@ -11,18 +11,23 @@ is the hot op and is built TPU-first):
   so the footprint is resolution-independent and far under the 16 MB
   physical VMEM (exceeding it silently corrupts rather than erroring
   when a raised vmem_limit_bytes "permits" the allocation).
-- The per-MB SAD reduction rides the MXU: `dot(absdiff(16, 256),
-  S(256, 128))` with a 0/1 block-sum selector — a matmul, not a
-  vector-reduce tree. absdiff values (<= 255) are exact in bf16 and the
-  f32 accumulation is exact (< 2^24), so the SADs are integer-exact.
-  The per-MB -> per-lane take-mask expansion is also a matmul (with the
-  selector transpose): pltpu.repeat is a TILE repeat, not the element
-  repeat it looks like.
+- The per-MB SAD reduction rides the MXU, one matmul per ROW of
+  candidates (fixed wy, 2..9 values of wx): the row's |cur - cand|
+  planes (64 rows each) are laid under each other and multiplied by
+  the constant 0/1 block-sum selector, `dot(stack(64 nx, 256),
+  SS(256, 384))` — 39 matmuls per grid step for the 227 candidates.
+  absdiff values (<= 255) are exact in bf16 and the f32 accumulation
+  is exact (< 2^24), so the SADs are integer-exact. SS leaves every
+  lane holding its MB's sum, so no per-MB -> per-lane expansion is
+  needed (pltpu.repeat is a TILE repeat, not the element repeat it
+  looks like).
 - Search centers are folded in on the XLA side: the wide-padded
   reference planes are re-anchored per center with dynamic slices and
   stacked (leading dim 3), so the kernel needs no dynamic shifts at
-  all — every candidate is a STATIC slice of a plane stepped by
-  constant-shift rolls inside per-parity-class fori_loops.
+  all — a row of candidates is reached by constant-shift row rolls
+  inside a per-parity-class fori_loop, and each candidate of the row
+  is a STATIC lane window of that plane (the row is unrolled, so its
+  chroma fractions and window offsets in x are static too).
 - Half-pel candidates read H.264 6-tap interpolation planes (b/h/j,
   §8.4.2.2.1) built in-kernel over exactly the rows the windows touch;
   chroma prediction is the §8.4.2.2.2 eighth-pel bilinear (centers are
@@ -95,9 +100,10 @@ def _round_up(x: int, m: int) -> int:
 # Offsets around a center decompose into PARITY CLASSES — each class
 # reads one interpolation plane (full-pel / b / h / j) and forms a
 # regular grid whose luma row/lane step is exactly one sample of that
-# plane. The kernel walks each class with two nested fori_loops,
-# stepping a rolled plane by one row/lane per iteration, so every
-# candidate is a STATIC slice and the live set stays bounded (a fully
+# plane. The kernel walks each class's rows with a fori_loop, stepping
+# a rolled plane by one row per iteration, and unrolls the 2..9
+# candidates of a row as static lane windows, so every candidate is a
+# STATIC slice and the live set stays bounded by one row (a fully
 # unrolled 267-candidate body made Mosaic's scoped-VMEM stack exceed
 # the 16 MB physical VMEM).
 # ---------------------------------------------------------------------------
@@ -190,9 +196,15 @@ def _geom(H: int, W: int):
     The kernel runs on a 2D grid over (4-MB-row bands x 256-lane
     chunks): every VMEM buffer is band-sized, so the footprint is
     resolution-independent (a frame-wide variant overflowed the 16 MB
-    physical VMEM at 1080p), while the 64-row band keeps the MXU's M
-    dimension busy (a 16-row variant was dominated by small-matmul
-    latency — measured ~3x slower)."""
+    physical VMEM at 1080p). The band is 64 rows, and the block-sum
+    matmul takes a whole ROW of candidates (M = 64 nx = 128..576),
+    because a matmul against the constant selector has a fixed cost
+    worth about 90 rows: in a serial loop on a v5e, dot((M, 256),
+    SS(256, 384)) with its |x - c| producer took 169 / 210 / 275 / 437
+    / 920 ns at M = 16 / 64 / 128 / 256 / 576 — about 120 ns + 1.4 ns
+    per row (PR 27, step 0; PERF.md §5). Per 64 rows that is 210 ns at
+    M = 64 (one candidate per matmul), 102 ns at M = 576, and 674 ns
+    at M = 16 — the "3x slower" once measured for a 16-row band."""
     mbh, mbw = H // 16, W // 16
     H4 = _round_up(H, 64)               # band-padded height
     RG = H4 // 64                       # grid rows (bands)
@@ -217,7 +229,11 @@ def _ss_np():
     same for chroma lanes (l // 16 == c // 8). dot(ad, SS) followed by
     a row-group sum leaves every lane holding its MB's SAD — the
     running best state stays per-lane and needs no MB->lane
-    expansion."""
+    expansion. The left side is a row of candidates' |cur - cand|
+    planes laid under each other (row_body), so SS is pushed into the
+    MXU 39 times per grid step, not 227. Each MB sum is written 16
+    (chroma: 8) times over: 388 GFLOP per 1080p P frame, which the
+    kernel runs at about 40 % of the v5e's bf16 peak (PERF.md §5)."""
     m = np.zeros((256, 384), np.float32)
     for l in range(256):
         mb = l // 16
@@ -293,7 +309,7 @@ def _me_kernel(H: int, W: int):
             jnp.concatenate([rv00[:], rv10[:], rv20[:], rv30[:]], axis=1),
             jnp.concatenate([rv01[:], rv11[:], rv21[:], rv31[:]], axis=1),
         ], axis=2)
-        cur = cur_ref[:].astype(jnp.bfloat16)             # (64, 256)
+        cur = cur_ref[:].astype(jnp.float32)              # (64, 256)
         SS = ss_ref[:]                                    # (256, 384) bf16
         lam = cent_ref[0, 6].astype(jnp.float32)
 
@@ -305,9 +321,6 @@ def _me_kernel(H: int, W: int):
             """Roll rows by a traced 0/1 without a dynamic rotate."""
             return jnp.where(flag > 0, roll_rows(x, -1), x)
 
-        def roll01_lanes(x, flag):
-            return jnp.where(flag > 0, roll_lanes(x, -1), x)
-
         # Running best per LANE (4 MB rows x 256 luma / 128 chroma
         # lanes). Luma and chroma track the same per-MB cost values in
         # the same order, so their winners agree exactly (integer-exact
@@ -316,68 +329,89 @@ def _me_kernel(H: int, W: int):
         bestc = jnp.full((4, 256), 2.0**30, jnp.float32)
         bmy = jnp.zeros((4, 256), jnp.int32)
         bmx = jnp.zeros((4, 256), jnp.int32)
-        py = jnp.zeros((64, 256), jnp.bfloat16)
+        py = jnp.zeros((64, 256), jnp.float32)
         bestcc = jnp.full((4, 128), 2.0**30, jnp.float32)
-        pu = jnp.zeros((32, 128), jnp.int16)
-        pv = jnp.zeros((32, 128), jnp.int16)
+        pu = jnp.zeros((32, 128), jnp.int32)
+        pv = jnp.zeros((32, 128), jnp.int32)
         state = (bestc, bmy, bmx, py, bestcc, pu, pv)
 
-        def offset_body(state, Lr, Cu33, Cv33, wy, wx, cy, cx):
-            """One candidate: Lr is 64 rows of the class plane, rolled
-            so the candidate occupies lanes [_PH, _PH+256); Cu33/Cv33
-            are 33 chroma rows rolled likewise. wy/wx traced."""
-            bestc, bmy, bmx, py, bestcc, pu, pv = state
-            cand = jax.lax.slice(Lr, (0, _PH), (64, _PH + 256)
-                                 ).astype(jnp.bfloat16)
-            ad = jnp.abs(cur - cand)
-            sad = jnp.dot(ad, SS, preferred_element_type=jnp.float32)
-            sad4a = sad.reshape(4, 16, 384).sum(1)        # (4, 384)
-            sad4 = jax.lax.slice(sad4a, (0, 0), (4, 256))
-            sad4c = jax.lax.slice(sad4a, (0, 256), (4, 384))
-            mvy = 2 * cy + wy
-            mvx = 2 * cx + wx
-            pen = lam * (jnp.abs(mvy) + jnp.abs(mvx)).astype(jnp.float32)
-            cost = sad4 + pen
-            take = cost < bestc                           # (4, 256) bool
-            bestc = jnp.where(take, cost, bestc)
-            bmy = jnp.where(take, mvy, bmy)
-            bmx = jnp.where(take, mvx, bmx)
-            tly = jnp.broadcast_to(take[:, None, :], (4, 16, 256)
-                                   ).reshape(64, 256)
-            py = jnp.where(tly, cand, py)
+        def row_body(state, Pl, Cur, Cvr, wy, wxs, cy, cx):
+            """One ROW of candidates (fixed wy, every wx of `wxs`): Pl,
+            Cur, Cvr are the class plane and the chroma planes rolled to
+            this row, so candidate wx is the STATIC window of Pl at lane
+            _PH + (wx >> 1) (chroma: _PHC + (wx >> 2)). The row's
+            |cur - cand| planes are laid under each other and block-
+            summed by ONE matmul against SS; the selection then walks
+            the row in table order. wy, cy, cx traced; wxs static."""
+            cands = [
+                jax.lax.slice(Pl, (_KPV, _PH + (wx >> 1)),
+                              (_KPV + 64, _PH + (wx >> 1) + 256))
+                for wx in wxs]
+            stack = jnp.concatenate(
+                [jnp.abs(cur - cand).astype(jnp.bfloat16)
+                 for cand in cands], axis=0)              # (64 nx, 256)
+            sad = jnp.dot(stack, SS, preferred_element_type=jnp.float32)
 
-            costc = sad4c + pen
-            takec = costc < bestcc                        # (4, 128)
-            bestcc = jnp.where(takec, costc, bestcc)
-            mc = jnp.broadcast_to(takec[:, None, :], (4, 8, 128)
-                                  ).reshape(32, 128)
-
-            # §8.4.2.2.2 bilinear, eighth-pel fracs (w & 3) * 2 (traced;
-            # exact for frac 0: (64 * a + 32) >> 6 == a).
+            # §8.4.2.2.2 bilinear, eighth-pel fracs (w & 3) * 2 (exact
+            # for frac 0: (64 * a + 32) >> 6 == a). ex is static per
+            # candidate, so a full-pel column drops its two zero taps.
             ey = (wy & 3) * 2
-            ex = (wx & 3) * 2
 
-            def cpred(C33):
-                a = jax.lax.slice(C33, (0, _PHC), (32, _PHC + 128))
-                b = jax.lax.slice(C33, (0, _PHC + 1), (32, _PHC + 129))
-                c = jax.lax.slice(C33, (1, _PHC), (33, _PHC + 128))
-                d = jax.lax.slice(C33, (1, _PHC + 1), (33, _PHC + 129))
-                out = ((8 - ex) * (8 - ey) * a + ex * (8 - ey) * b
-                       + (8 - ex) * ey * c + ex * ey * d + 32) >> 6
-                return out.astype(jnp.int16)
+            def chroma_row(C):
+                @functools.lru_cache(maxsize=None)
+                def win(dr, dl):        # shared by neighbouring wx
+                    return jax.lax.slice(
+                        C, (_KPVC + dr, _PHC + dl),
+                        (_KPVC + dr + 32, _PHC + dl + 128))
 
-            pu = jnp.where(mc, cpred(Cu33), pu)
-            pv = jnp.where(mc, cpred(Cv33), pv)
+                def cpred(wx):
+                    ex, ox = (wx & 3) * 2, wx >> 2
+                    out = ((8 - ex) * (8 - ey) * win(0, ox)
+                           + (8 - ex) * ey * win(1, ox) + 32)
+                    if ex:
+                        out = (out + ex * (8 - ey) * win(0, ox + 1)
+                               + ex * ey * win(1, ox + 1))
+                    return out >> 6
+                return cpred
+
+            cpred_u, cpred_v = chroma_row(Cur), chroma_row(Cvr)
+            bestc, bmy, bmx, py, bestcc, pu, pv = state
+            mvy = 2 * cy + wy
+            for k, (wx, cand) in enumerate(zip(wxs, cands)):
+                sad4a = jax.lax.slice(sad, (64 * k, 0), (64 * k + 64, 384)
+                                      ).reshape(4, 16, 384).sum(1)
+                sad4 = jax.lax.slice(sad4a, (0, 0), (4, 256))
+                sad4c = jax.lax.slice(sad4a, (0, 256), (4, 384))
+                mvx = 2 * cx + wx
+                pen = lam * (jnp.abs(mvy) + jnp.abs(mvx)
+                             ).astype(jnp.float32)
+                cost = sad4 + pen
+                take = cost < bestc                       # (4, 256) bool
+                bestc = jnp.where(take, cost, bestc)
+                bmy = jnp.where(take, mvy, bmy)
+                bmx = jnp.where(take, mvx, bmx)
+                tly = jnp.broadcast_to(take[:, None, :], (4, 16, 256)
+                                       ).reshape(64, 256)
+                py = jnp.where(tly, cand, py)
+
+                costc = sad4c + pen
+                takec = costc < bestcc                    # (4, 128)
+                bestcc = jnp.where(takec, costc, bestcc)
+                mc = jnp.broadcast_to(takec[:, None, :], (4, 8, 128)
+                                      ).reshape(32, 128)
+                pu = jnp.where(mc, cpred_u(wx), pu)
+                pv = jnp.where(mc, cpred_v(wx), pv)
             return (bestc, bmy, bmx, py, bestcc, pu, pv)
 
         def class_scan(plane, CUc, CVc, cy, cx, wys, wxs, state):
-            """Walk one parity class's (wys x wxs) grid. The plane and
-            chroma planes are pre-rolled to the first offset; each
-            fori_loop step rolls by the grid's one-sample stride, so
-            every candidate is a static slice and the loop carries are
-            band-sized."""
-            ny, nx = len(wys), len(wxs)
-            wy0, wx0 = wys[0], wxs[0]
+            """Walk one parity class's (wys x wxs) grid, a row of
+            candidates per fori_loop step. The plane and chroma planes
+            are pre-rolled to the first row and roll by the grid's
+            one-sample row stride per step, so every candidate is a
+            static slice and the loop carries are band-sized; the row
+            itself is unrolled (row_body) — rows, not classes: a fully
+            unrolled class set overflows Mosaic's scoped VMEM."""
+            wy0 = wys[0]
             Pl = roll_rows(plane, -(wy0 >> 1))
             Cur = roll_rows(CUc, -(wy0 >> 2))
             Cvr = roll_rows(CVc, -(wy0 >> 2))
@@ -385,34 +419,13 @@ def _me_kernel(H: int, W: int):
             def outer(iy, carry):
                 Pl, Cur, Cvr, state = carry
                 wy = wy0 + 2 * iy
-                # only lanes [0, _PH + 256 + steps) are ever sliced —
-                # a 384-lane slab rolls 25% cheaper than the full 512
-                Lr = jax.lax.slice(Pl, (_KPV, 0), (_KPV + 64, 384))
-                Lr = roll_lanes(Lr, -(wx0 >> 1))
-                Cu33 = roll_lanes(
-                    jax.lax.slice(Cur, (_KPVC, 0), (_KPVC + 33, _LWC)),
-                    -(wx0 >> 2))
-                Cv33 = roll_lanes(
-                    jax.lax.slice(Cvr, (_KPVC, 0), (_KPVC + 33, _LWC)),
-                    -(wx0 >> 2))
-
-                def inner(ix, icarry):
-                    Lr, Cu33, Cv33, state = icarry
-                    wx = wx0 + 2 * ix
-                    state = offset_body(state, Lr, Cu33, Cv33, wy, wx,
-                                        cy, cx)
-                    cd = ((wx + 2) >> 2) - (wx >> 2)
-                    return (roll_lanes(Lr, -1), roll01_lanes(Cu33, cd),
-                            roll01_lanes(Cv33, cd), state)
-
-                _, _, _, state = jax.lax.fori_loop(
-                    0, nx, inner, (Lr, Cu33, Cv33, state))
+                state = row_body(state, Pl, Cur, Cvr, wy, wxs, cy, cx)
                 rd = ((wy + 2) >> 2) - (wy >> 2)
                 return (roll_rows(Pl, -1), roll01_rows(Cur, rd),
                         roll01_rows(Cvr, rd), state)
 
             _, _, _, state = jax.lax.fori_loop(
-                0, ny, outer, (Pl, Cur, Cvr, state))
+                0, len(wys), outer, (Pl, Cur, Cvr, state))
             return state
 
         def run_center(ci, classes, state):
@@ -471,8 +484,8 @@ def _me_kernel(H: int, W: int):
         mv_ref[0, 0, 0:4, :] = bmy
         mv_ref[0, 0, 4:8, :] = bmx
         py_ref[:] = py.astype(jnp.int16)
-        pu_ref[:] = pu
-        pv_ref[:] = pv
+        pu_ref[:] = pu.astype(jnp.int16)
+        pv_ref[:] = pv.astype(jnp.int16)
 
     return kernel
 
