@@ -11,7 +11,7 @@ settings a small host needs (TVT_MIN_IDLE_WORKERS=0,
 TVT_PIPELINE_WORKER_COUNT=2 — with the defaults a one-chip host has 2
 scheduler slots against `min_idle_workers` 4 and never admits a job).
 
-Jobs, content generated from `--seed` (bench.make_frames' diagonal pan):
+Jobs, content generated from `--seed` (tools/pan.make_frames' diagonal pan):
 two 1920x1080 160-frame clips (the second re-uses every compiled
 shape), one 3840x2160 64-frame clip and, on a host with more than one
 chip, the 2160p clip again through `POST /add_job` with `sfe_bands` =
@@ -332,9 +332,9 @@ def run(args) -> dict:
     dies, stops it at once (SmokeFailure); every other requirement
     that does not hold is collected, so one run on the chip reports
     all of them — and raised together at the end."""
-    from bench import make_frames
     from thinvids_tpu import native as native_mod
     from thinvids_tpu.core.devices import DEFAULT_COMPILE_CACHE
+    from thinvids_tpu.tools.pan import make_frames
 
     t_start = time.time()
     t_limit = t_start + args.time_limit

@@ -27,7 +27,7 @@ def pytest_configure(config):
 
 @pytest.fixture(scope="session")
 def analysis_ctx():
-    """(manifest, SourceTree over the package + bench.py) — the same
+    """(manifest, SourceTree over the package) — the same
     tree `cli.py check` analyzes. Shared by the subsystem-contract
     tests that migrated off the old grep guards (test_abr, test_live,
     test_compact, test_streaming); session scope so the ~70 modules
@@ -36,8 +36,7 @@ def analysis_ctx():
     from thinvids_tpu.analysis import SourceTree, default_manifest
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    tree = SourceTree(os.path.join(repo, "thinvids_tpu"),
-                      extra_files=(os.path.join(repo, "bench.py"),))
+    tree = SourceTree(os.path.join(repo, "thinvids_tpu"))
     return default_manifest(), tree
 
 

@@ -1028,8 +1028,7 @@ class TestWaivers:
 class TestCleanTree:
     def test_run_all_clean_on_head(self):
         manifest = default_manifest()
-        tree = SourceTree(PKG_DIR, extra_files=(
-            os.path.join(REPO, "bench.py"),))
+        tree = SourceTree(PKG_DIR)
         open_, _waived, stale = apply_waivers(run_all(tree, manifest),
                                               manifest)
         assert not open_, "\n".join(f.format() for f in open_)

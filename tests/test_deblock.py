@@ -278,11 +278,11 @@ class TestOracleParity:
 
         if not oracle.oracle_available():
             pytest.skip("libavcodec oracle not available")
-        from bench import make_frames
         from thinvids_tpu.codecs.h264.decoder import decode_annexb
         from thinvids_tpu.codecs.h264.encoder import encode_gop
         from thinvids_tpu.codecs.h264.rdo import RdConfig
         from thinvids_tpu.core.types import VideoMeta
+        from thinvids_tpu.tools.pan import make_frames
 
         w, h, n = 192, 160, 32
         frames = make_frames(n, w, h)

@@ -1,6 +1,6 @@
 """JAX compute path and native packer must match the numpy/Python reference
 bit-exactly — these are the "same bits, different engine" guarantees that
-let bench run the fast paths while conformance is proven on the slow ones."""
+let production run the fast paths while conformance is proven on the slow ones."""
 
 import numpy as np
 import pytest

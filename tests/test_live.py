@@ -377,7 +377,7 @@ class TestWatcherLive:
 def test_every_settings_key_has_a_reader_outside_config(analysis_ctx):
     """Dead config lies to operators: every DEFAULT_SETTINGS key must
     be referenced somewhere outside core/config.py (executor, planner,
-    API, dashboard, bench, ...). Promoted from a source-blob grep into
+    API, dashboard, ...). Promoted from a source-blob grep into
     the analyzer's config-discipline pass (TVT-C001), which this test
     now drives directly."""
     from thinvids_tpu.analysis.configcheck import check_dead_keys

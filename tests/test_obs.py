@@ -35,8 +35,7 @@ from thinvids_tpu.core.types import VideoMeta
 from thinvids_tpu.io.y4m import write_y4m
 from thinvids_tpu.obs import flight, trace
 from thinvids_tpu.obs.metrics import MetricsRegistry, REGISTRY
-
-import bench
+from thinvids_tpu.tools.pan import make_frames
 
 
 # ---------------------------------------------------------------------------
@@ -49,7 +48,7 @@ def make_settings(**over):
 
 
 def clip_frames(w=64, h=48, n=8):
-    return bench.make_frames(n, w, h)
+    return make_frames(n, w, h)
 
 
 def write_clip(path, w=64, h=48, n=8):
@@ -621,35 +620,55 @@ class TestFlightRecorder:
 
 
 class TestTracingParity:
-    def test_tracing_changes_no_output_bytes(self):
-        from thinvids_tpu.core.types import concat_segments
+    @staticmethod
+    def _encoder(w=64, h=48):
         from thinvids_tpu.parallel.dispatch import GopShardEncoder
 
-        frames = clip_frames(n=8)
-        meta = VideoMeta(width=64, height=48, fps_num=30, fps_den=1,
+        meta = VideoMeta(width=w, height=h, fps_num=30, fps_den=1,
                          num_frames=8)
-        enc = GopShardEncoder(meta, qp=30, gop_frames=2)
-        baseline = concat_segments(enc.encode(frames))
-        trace.TRACE.start("parity-job")
-        enc.stages.set_tracer(trace.TRACE.recorder("parity-job"))
+        return GopShardEncoder(meta, qp=30, gop_frames=2)
+
+    @staticmethod
+    def _encode_traced(enc, frames, job):
+        """(stream, spans) of one encode with `job`'s recorder bound."""
+        from thinvids_tpu.core.types import concat_segments
+
+        trace.TRACE.start(job)
+        enc.stages.set_tracer(trace.TRACE.recorder(job))
         try:
-            traced = concat_segments(enc.encode(frames))
+            stream = concat_segments(enc.encode(frames))
         finally:
             enc.stages.set_tracer(None)
-        assert traced == baseline
-        spans = trace.TRACE.snapshot("parity-job")["spans"]
-        assert spans, "tracer was bound but recorded nothing"
-        trace.TRACE.drop("parity-job")
+        spans = trace.TRACE.snapshot(job)["spans"]
+        trace.TRACE.drop(job)
+        return stream, spans
 
-    def test_overhead_guard(self):
-        """Loose CI-safe bound — the honest <3% gate is the BENCH's
-        trace_overhead_pct on the driver's 1080p run; this guard
-        catches only a catastrophic regression (spans on the per-MB
-        path instead of the per-stage path, a lock convoy, ...)."""
-        r = bench._run_trace_overhead(64, 48, nframes=8, qp=27,
-                                      gop_frames=2, runs=3)
-        assert r["sampled"] is True
-        assert r["overhead_pct"] < 50.0, r
+    def test_tracing_changes_no_output_bytes(self):
+        from thinvids_tpu.core.types import concat_segments
+
+        frames = clip_frames(n=8)
+        enc = self._encoder()
+        baseline = concat_segments(enc.encode(frames))
+        traced, spans = self._encode_traced(enc, frames, "parity-job")
+        assert traced == baseline
+        assert spans, "tracer was bound but recorded nothing"
+
+    def test_span_count_does_not_grow_with_the_picture(self):
+        """Spans belong to stages, waves, GOPs and frames, never to
+        macroblocks: the same 8 frames in the same GOPs and waves
+        record the same spans at four times the macroblocks. (What
+        the spans cost is inside every `frames_per_s` of the
+        benchmark, whose cells trace every job.)"""
+        import collections
+
+        def span_names(w, h):
+            _, spans = self._encode_traced(
+                self._encoder(w, h), clip_frames(w, h, 8),
+                f"span-count-{w}x{h}")
+            return collections.Counter(s["name"] for s in spans)
+
+        small, large = span_names(64, 48), span_names(128, 96)
+        assert small and small == large
 
 
 # ---------------------------------------------------------------------------

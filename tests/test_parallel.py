@@ -13,9 +13,11 @@ import jax
 from thinvids_tpu.core.types import Frame, VideoMeta, concat_segments
 from thinvids_tpu.codecs.h264.encoder import H264Encoder
 from thinvids_tpu.parallel.dispatch import (
+    STAGE_COUNTERS,
     GopShardEncoder,
     default_mesh,
     encode_clip_sharded,
+    stage_snapshot,
 )
 from thinvids_tpu.parallel.planner import plan_segments
 
@@ -529,8 +531,28 @@ class TestHostPipeline:
         agg = dispatch_mod.stage_snapshot()
         assert set(dispatch_mod.STAGE_NAMES) <= set(agg)
         assert agg["pack"] >= snap["pack"]
-        enc.stages.reset()
-        assert enc.stages.snapshot()["pack"] == 0.0
+
+    @pytest.fixture(scope="class")
+    def counted_encode(self):
+        """(the encoder's snapshot, the process-wide one) after ONE
+        encode, shared by the counter cases below."""
+        meta = VideoMeta(width=64, height=48, num_frames=8)
+        enc = GopShardEncoder(meta, qp=27, gop_frames=2)
+        concat_segments(enc.encode(_make_frames(8, seed=2)))
+        return enc.stages.snapshot(), stage_snapshot()
+
+    @pytest.mark.parametrize("counter", STAGE_COUNTERS)
+    def test_stage_counters_ride_both_snapshots(self, counted_encode,
+                                                counter):
+        """Each counter /metrics_snapshot exports (the benchmark reads
+        the byte, fetch-shard and dense-fallback ones) is in the
+        encoder's snapshot and, at least as large, in the process-wide
+        one; an encode moves bytes both ways across the boundary."""
+        snap, agg = counted_encode
+        assert isinstance(snap[counter], int) and snap[counter] >= 0
+        assert agg[counter] >= snap[counter]
+        if counter in ("h2d_bytes", "d2h_bytes"):
+            assert snap[counter] > 0
 
     def test_pack_knobs_read_from_config_env(self, monkeypatch):
         from thinvids_tpu.core.config import invalidate_settings_cache

@@ -7,12 +7,12 @@ libavcodec oracle when present).
 import numpy as np
 import pytest
 
-from bench import make_frames
 from thinvids_tpu.codecs.h264 import decoder as dec_mod
 from thinvids_tpu.codecs.h264 import encoder as enc_mod
 from thinvids_tpu.codecs.h264 import jaxcore, rdo
 from thinvids_tpu.codecs.h264.rdo import RD_OFF, RdConfig
 from thinvids_tpu.core.types import VideoMeta
+from thinvids_tpu.tools.pan import make_frames
 
 
 RD_ALL = RdConfig(mode_decision=True, pskip=True, deblock=True,

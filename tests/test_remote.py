@@ -1015,7 +1015,6 @@ def test_coordinator_crash_resume_end_to_end(tmp_path):
         coord.wait(timeout=10)
 
         # chaos: one spooled part rots while the coordinator is down
-        # (the production chaos helper the bench tier uses)
         from thinvids_tpu.tools.loadgen import corrupt_spooled_part
 
         spool_dir = os.path.join(state_dir, "part-spool", job["id"])

@@ -143,10 +143,6 @@ class _LadderStages:
     def tracer(self):
         return self._ladder._stager.stages.tracer()
 
-    def reset(self) -> None:
-        for enc in self._ladder._all_encoders():
-            enc.stages.reset()
-
     def snapshot(self) -> dict:
         out: dict = {}
         for enc in self._ladder._all_encoders():
@@ -305,7 +301,7 @@ class LadderShardEncoder:
         return bundles
 
     def encode(self, frames) -> list[LadderGopBundle]:
-        """Stream-encode the whole ladder (worker shards / bench):
+        """Stream-encode the whole ladder (worker shards):
         staging on a background thread, depth-2 dispatch window."""
         from collections import deque
 

@@ -50,16 +50,11 @@ class SourceTree:
 
     `package_dir` is the directory of the package's __init__.py;
     modules are addressed by their dotted name rooted at the package
-    (``thinvids_tpu.abr.hls``). Extra top-level files (bench.py for the
-    config-reader scan) can ride along via `extra_files` — they appear
-    with a ``::`` pseudo-module name so they join text scans without
-    polluting the import graph."""
+    (``thinvids_tpu.abr.hls``)."""
 
-    def __init__(self, package_dir: str, package: str | None = None,
-                 extra_files: tuple[str, ...] = ()) -> None:
+    def __init__(self, package_dir: str, package: str | None = None) -> None:
         self.package_dir = os.path.abspath(package_dir)
         self.package = package or os.path.basename(self.package_dir)
-        self.extra_files = tuple(extra_files)
         self._sources: dict[str, str] = {}
         self._asts: dict[str, ast.Module] = {}
         self._paths: dict[str, str] = {}
@@ -80,14 +75,9 @@ class SourceTree:
                 mod = ".".join([self.package] + parts) if parts \
                     else self.package
                 self._paths[mod] = path
-        for path in self.extra_files:
-            self._paths["::" + os.path.basename(path)] = path
 
     def modules(self) -> list[str]:
-        """Dotted names of every in-package module (no extra files)."""
-        return sorted(m for m in self._paths if not m.startswith("::"))
-
-    def all_names(self) -> list[str]:
+        """Dotted names of every in-package module."""
         return sorted(self._paths)
 
     def has_module(self, mod: str) -> bool:
@@ -109,7 +99,7 @@ class SourceTree:
         return self._asts[mod]
 
     def items(self) -> Iterator[tuple[str, ast.Module]]:
-        for mod in self.all_names():
+        for mod in self.modules():
             yield mod, self.tree(mod)
 
 

@@ -50,7 +50,7 @@ def check_dead_keys(tree: SourceTree, manifest: Manifest,
     defaults = _default_settings() if defaults is None else defaults
     attrs: set[str] = set()
     consts: set[str] = set()
-    for mod in tree.all_names():
+    for mod in tree.modules():
         if mod == manifest.config_module:
             continue
         attrs |= attribute_names(tree.tree(mod))

@@ -155,14 +155,13 @@ class ApiServer:
         #: chaos-harness fault injection (tools/loadgen.py --chaos):
         #: while the monotonic clock is before this stamp, every
         #: /work/* route answers 503 — the "partitioned /work routes"
-        #: failure the autoscale bench drives. Guarded by its own lock
+        #: failure the chaos tests drive. Guarded by its own lock
         #: (written by the chaos thread, read by every handler thread).
         self._fault_lock = threading.Lock()
         self._work_partition_until = 0.0
         #: chaos: bit-flip the next N /work/part upload bodies before
-        #: unpack (the in-flight corruption the crash/corruption bench
-        #: tier injects — every flip must surface as a digest
-        #: rejection, never as corrupt stitched bytes)
+        #: unpack (in-flight corruption — every flip must surface as
+        #: a digest rejection, never as corrupt stitched bytes)
         self._corrupt_parts_left = 0
         api = self
 
@@ -935,9 +934,8 @@ class ApiServer:
 
         disp = _sys.modules.get("thinvids_tpu.parallel.dispatch")
         out["stage_ms"] = disp.stage_snapshot() if disp is not None else {}
-        # SFE per-frame latency percentiles — the frame_done_t data
-        # the bench always recorded, finally summarized for operators
-        # (dashboard SFE line + this snapshot)
+        # SFE per-frame latency percentiles — the frame_done_t data,
+        # summarized for operators (dashboard SFE line + this snapshot)
         out["sfe_latency_ms"] = (disp.frame_latency_percentiles()
                                  if disp is not None else {})
         # which motion search this process traced: "pallas" (the TPU
@@ -957,7 +955,7 @@ class ApiServer:
             out["qos"] = qos.snapshot()
         # elastic-farm lifecycle panel (farm/controller.py): per-host
         # ACTIVE/DRAINING/SUSPENDED/WAKING plus the worker-seconds
-        # integral the autoscale bench reports
+        # integral (farm_active_worker_s)
         farm = getattr(self.coordinator, "farm", None)
         if farm is not None:
             out["farm"] = farm.snapshot()
@@ -1191,7 +1189,7 @@ class ApiServer:
 
     def _h_work_chaos(self, query, body) -> tuple[int, Any]:
         """Chaos-injection control channel for the out-of-process
-        harness (bench `_run_crash_resume` drives a SUBPROCESS
+        harness (a crash-resume drill SIGKILLs a SUBPROCESS
         coordinator, so the in-process `partition_work` /
         `corrupt_parts` hooks need an HTTP surface). Deliberately NOT
         behind the partition blackhole — this IS the control channel

@@ -473,8 +473,8 @@ class Coordinator:
         `live_recover_parts` consecutive good parts."""
         if not self.token_is_current(job_id, token):
             return False
-        # the latency DISTRIBUTION the bench only spot-samples: every
-        # live part observes the fixed-bucket histogram
+        # the latency DISTRIBUTION: every live part observes the
+        # fixed-bucket histogram
         obs_metrics.LIVE_PART_SECONDS.observe(latency_s)
         recover = int(self._settings_fn().get("live_recover_parts", 2))
         event = self.qos.note_live_part(job_id, latency_s, budget_s,

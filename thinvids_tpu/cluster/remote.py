@@ -508,7 +508,7 @@ class ShardBoard:
             except PartIntegrityError as exc:
                 with self._lock:
                     # keep the snapshot counter in step with the
-                    # Prometheus total: the dashboard/bench read both
+                    # Prometheus total: the dashboard reads both
                     self._integrity_rejects += 1
                 obs_metrics.PART_INTEGRITY_FAILURES.inc()
                 raise RuntimeError(

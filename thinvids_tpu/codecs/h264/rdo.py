@@ -43,8 +43,8 @@ AQ_MAX_DELTA = 6
 #: P_Skip bias: an inter MB whose quantized levels sum to <= this (in
 #: absolute value, all planes) with every |level| <= 1 drops its
 #: residual. 2 keeps the bias to MBs whose coded cost would exceed the
-#: distortion it buys back (measured on the bench clip: bits fall with
-#: no PSNR loss at 2; 4+ starts to visibly smear grain).
+#: distortion it buys back (on the pan clip of tools/pan.py, CPU run:
+#: bits fall with no PSNR loss at 2; 4+ starts to visibly smear grain).
 PSKIP_SUM = 2
 
 

@@ -113,7 +113,7 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     "job_priority": "auto",          # auto | live | ladder | batch
     "live_part_budget_s": 0.0,
     "live_recover_parts": 2,
-    # load harness defaults (tools/loadgen.py + bench.py's origin run):
+    # load harness defaults (tools/loadgen.py):
     # concurrent player sessions (TVT_LOADGEN_SESSIONS) and the load
     # window in seconds (TVT_LOADGEN_DURATION_S)
     "loadgen_sessions": 500,
@@ -205,7 +205,7 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     # dispatch pass and the shard board's claim.
     "tenant": "",
     "tenant_shares": "",
-    # chaos harness (tools/loadgen.py --chaos + bench _run_autoscale):
+    # chaos harness (tools/loadgen.py --chaos):
     # mean seconds between worker SIGKILLs (0 = no kills), the /work
     # route partition length (0 = no partition), and the diurnal load
     # curve's period.

@@ -56,7 +56,7 @@ def configure_compile_cache() -> str:
     """Place jax's persistent compilation cache; returns its directory.
 
     Called once from each process entry point that compiles (cli
-    coordinator/worker, bench, the driver entry) — never from the
+    coordinator/worker, the driver entry) — never from the
     tests. `JAX_COMPILATION_CACHE_DIR` wins: jax reads it itself and
     this code sets nothing. Unset, the cache goes to the fixed
     `DEFAULT_COMPILE_CACHE` so every process of a checkout shares one

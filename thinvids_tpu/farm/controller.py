@@ -36,8 +36,8 @@ Policy (one tick, everything on the injected clock, autoscale gated by
   so demand re-wakes a replacement on the next tick.
 
 ``farm_active_worker_s`` (worker-seconds of non-SUSPENDED lifetime) is
-accumulated here — the energy-proportionality figure the autoscale
-bench reports against the always-on baseline.
+accumulated here — the energy-proportionality figure, to be read
+against an always-on baseline.
 
 Lock order: the board's lock may nest THIS controller's lock
 (``claim`` → ``claim_allowed``); therefore tick() never touches the

@@ -3,8 +3,8 @@ suspends worker hosts.
 
 The reference pair was WoL magic packets (manager side) + agent
 self-suspend (node side); a TPU-VM farm substitutes a cloud API call;
-tests and the autoscale bench substitute real ``cli.py worker``
-subprocesses. The controller only ever sees two callables:
+tests substitute real ``cli.py worker`` subprocesses. The controller
+only ever sees two callables:
 
     wake(host) -> bool      bring the host's worker daemon up
     suspend(host) -> bool   take it down (after the controller drained it)
@@ -69,9 +69,8 @@ class NullProvider(CallableProvider):
 class SubprocessProvider:
     """Spawn/kill real ``python -m thinvids_tpu.cli worker`` daemons on
     this host — the hermetic analog of the reference's WoL wake +
-    agent-suspend pair, used by tests and the autoscale bench
-    (bench.py ``_run_autoscale``). ``suspend`` SIGTERMs the daemon
-    (graceful: the controller already drained its leases); ``kill``
+    agent-suspend pair, used by the tests. ``suspend`` SIGTERMs the
+    daemon (graceful: the controller already drained its leases); ``kill``
     SIGKILLs it without ceremony — the chaos harness's worker-crash
     primitive."""
 

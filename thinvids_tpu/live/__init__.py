@@ -13,7 +13,7 @@ moment the GOP clears every rung: rolling live/EVENT playlists (no
 EXT-X-ENDLIST until the stream closes), EXT-X-PART partial segments
 with preload hints, and a sliding DVR window (EXT-X-MEDIA-SEQUENCE
 advance + on-disk GC). The headline metric is glass-to-playlist
-latency (`live_latency_s` in BENCH), not fps.
+latency, not fps (not measured on the chip yet: ROADMAP S7).
 """
 
 from .packager import LiveLadderPackager

@@ -114,7 +114,7 @@ class LiveLadderPackager:
         self._initialized = False
         self._packaged_s = 0.0      # lifetime stream seconds packaged
         self.closed = False
-        #: lifetime counters (bench `live_dvr_segments` + job facts)
+        #: lifetime counters (job facts)
         self.segments_announced = 0
         self.parts_announced = 0
         self.segments_gced = 0

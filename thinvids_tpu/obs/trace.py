@@ -25,10 +25,10 @@ they executed.
 Sampling: `trace_sample` (0..1) decides PER JOB at trace start whether
 spans record at all; an unsampled job costs one dict lookup per stage.
 Tracing never touches encoded bytes — output is bit-identical with
-tracing on or off (parity-tested). `bench.py` still computes an
-in-process ``trace_overhead_pct``, but nothing reads it: the benchmark
-is `benchmark/run.py`, whose cells all run with `trace_sample` at its
-default of 1.0, so the spans' cost is inside every number it reports.
+tracing on or off (parity-tested). No figure isolates what the spans
+cost: every cell of the benchmark (`benchmark/run.py`) runs with
+`trace_sample` at its default of 1.0, so that cost is inside every
+number it reports.
 
 jax-free by contract (analysis manifest).
 """

@@ -13,8 +13,8 @@ the single-device encode is asserted by tests/test_parallel.py on an
 Host side, the pipeline is instrumented per stage (StageProfile): every
 wave's source decode / staging (stack + H2D upload) / dispatch / device
 wait / D2H fetch / sparse unpack / unflatten / CAVLC pack / concat
-wall-clock accumulates on the encoder and is exported through bench.py
-(`stage_ms`) and the API's /metrics_snapshot. The entropy pack fans out
+wall-clock accumulates on the encoder and is exported through the
+API's /metrics_snapshot (`stage_ms`). The entropy pack fans out
 at SLICE granularity across a per-encoder pool sized by `pack_workers`
 (TVT_PACK_WORKERS; default: all cores; threads spawn on demand and
 retire with the encoder), decoupled from the in-flight wave window
@@ -123,8 +123,8 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 #: staging waves — the ABR ladder's proof that decode+upload happens
 #: ONCE per wave regardless of rung count: lower rungs derive on
 #: device, so this must not scale with rungs), d2h_bytes (actual
-#: device→host bytes fetched — bench derives d2h_bytes_per_frame from
-#: it), fetch_shards (per-shard concurrent fetch transfers issued; 0
+#: device→host bytes fetched — the benchmark's d2h_bytes_per_frame is
+#: its growth per frame), fetch_shards (per-shard concurrent fetch transfers issued; 0
 #: means every fetch was a single blocking device_get), proc_pack_gops
 #: (GOPs handed to the pack_backend=process sidecars instead of the
 #: thread pool), sfe_frames (frames that crossed the split-frame
@@ -141,8 +141,7 @@ class StageProfile:
 
     `mirror` (the process-wide cumulative profile) receives every add
     too, so /metrics_snapshot keeps a job's totals after its encoder is
-    garbage-collected; reset() only clears THIS profile (bench resets
-    per timed pass without zeroing the process counters)."""
+    garbage-collected."""
 
     def __init__(self, mirror: "StageProfile | None" = None,
                  metrics: bool = False) -> None:
@@ -219,14 +218,6 @@ class StageProfile:
             out.update(self._counts)
             out["waves"] = self._waves
             return out
-
-    def reset(self) -> None:
-        with self._lock:
-            for k in self._ms:
-                self._ms[k] = 0.0
-            for k in self._counts:
-                self._counts[k] = 0
-            self._waves = 0
 
 
 #: process-cumulative stage totals (every encoder mirrors into this;
@@ -678,7 +669,7 @@ class GopShardEncoder:
             compact_transfer = as_bool(snap.get("compact_transfer", True),
                                        True)
         self.compact_transfer = bool(compact_transfer)
-        #: per-stage host wall-clock (bench `stage_ms`, /metrics_snapshot)
+        #: per-stage host wall-clock (/metrics_snapshot `stage_ms`)
         self.stages = StageProfile(mirror=_TOTALS)
         #: streaming-ingest instrumentation: peak decoded frames the
         #: staging cursor held at once (tests assert the bound)
@@ -808,11 +799,6 @@ class GopShardEncoder:
             # the caller staged this wave into device arrays; frames
             # below the next wave's start will never be read again
             cursor.release_below(wave[-1].end_frame)
-
-    def prepare_waves(self, frames) -> tuple[SegmentPlan, list[tuple]]:
-        """Eager staging of ALL waves (benchmarks / short clips); for
-        long clips prefer encode(), which streams with a bounded window."""
-        return self.plan(len(frames)), list(self.stage_waves(frames))
 
     def encode(self, frames) -> list[EncodedSegment]:
         """Stream-encode: source decode + staging run on a background
@@ -1612,7 +1598,7 @@ class SfeShardEncoder(GopShardEncoder):
     are fetched and its band slices packed (concurrently on the pack
     pool) as soon as its step completes, while the device runs the
     next frame — `frame_done_t` records each frame's bitstream-ready
-    timestamp and the bench derives `sfe_latency_ms_2160p` from it.
+    timestamp, which `frame_latencies_ms` turns into per-frame latency.
 
     A "wave" for the executor's retry/progress machinery is one GOP
     (closed: an IDR step resets the carry, so a failed GOP re-dispatches
@@ -1694,10 +1680,10 @@ class SfeShardEncoder(GopShardEncoder):
         self.halo_rows = min(self.halo_rows,
                              self.band_plan.band_mb_rows * 16)
         #: per-frame bitstream-ready timestamps (time.perf_counter), in
-        #: encode order — the bench's latency source. Bounded: a
+        #: encode order — frame_latencies_ms' source. Bounded: a
         #: long-running job appends one entry per frame forever, so
         #: only the most recent window survives (enough for any
-        #: latency percentile; bench clears it per timed pass anyway).
+        #: latency percentile).
         self.frame_done_t: deque = deque(maxlen=4096)
         #: previous frame's bitstream-ready perf_counter — the source
         #: of the per-frame latency gap fed to the process-global
@@ -1806,8 +1792,8 @@ class SfeShardEncoder(GopShardEncoder):
                      pack_workers: int | None = None):
         # fresh latency baseline per encode pass: the idle gap since a
         # PREVIOUS pass's last frame is not a per-frame latency and
-        # must not become the reported p99 (bench reuses one encoder
-        # across warmup + timed passes)
+        # must not become the reported p99 (one encoder may run
+        # several passes)
         self._last_frame_done = None
         return super().encode_waves(waves, window=window,
                                     pack_workers=pack_workers)
@@ -1935,8 +1921,8 @@ class SfeShardEncoder(GopShardEncoder):
         return [f.result() for f in [pool.submit(t) for t in thunks]]
 
     def _note_frame_done(self, frame_index: int) -> None:
-        """One SFE frame's bitstream is ready: stamp frame_done_t (the
-        bench's latency source), count it, and — when a previous frame
+        """One SFE frame's bitstream is ready: stamp frame_done_t,
+        count it, and — when a previous frame
         exists — record the steady-state gap as a latency sample
         (global percentile ring + histogram) and a `sfe_frame` span in
         the job's trace."""

@@ -133,10 +133,7 @@ def run_check(json_out: bool = False, sarif_out: bool = False,
 
     package_dir = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))
-    repo_root = os.path.dirname(package_dir)
-    extra = tuple(
-        p for p in (os.path.join(repo_root, "bench.py"),) if os.path.exists(p))
-    tree = SourceTree(package_dir, extra_files=extra)
+    tree = SourceTree(package_dir)
     manifest = default_manifest()
     findings = run_all(tree, manifest)
     open_, waived, stale = apply_waivers(findings, manifest)

@@ -14,8 +14,7 @@ Each session holds ONE keep-alive connection and identifies itself
 with an `X-Tvt-Session` header, which is what the origin's per-job
 concurrent-session gauge counts. The aggregate result pins
 `sessions_sustained` (sessions that ran the whole window with zero
-errors) and per-segment fetch latency percentiles — the
-`origin_sessions_sustained` / `origin_p99_segment_ms` BENCH lines.
+errors) and per-segment fetch latency percentiles.
 
     python -m thinvids_tpu.tools.loadgen --url http://host:port \
         --job <job_id> [--sessions 500] [--duration 10] [--live]
@@ -223,8 +222,8 @@ class PlayerSession:
 def chaos_defaults(snap=None) -> dict:
     """The chaos knobs' settings tier (TVT_CHAOS_*): mean seconds
     between worker kills (0 = none), /work partition length (0 =
-    none), and the diurnal curve period. One reader for every harness
-    (this CLI's --chaos mode and bench.py's _run_autoscale)."""
+    none), and the diurnal curve period. Read by this CLI's --chaos
+    mode."""
     from ..core.config import get_settings
 
     snap = snap if snap is not None else get_settings()
@@ -258,7 +257,7 @@ def flip_part_bit(path: str) -> int:
 def corrupt_spooled_part(spool_root: str, job_id: str) -> str | None:
     """Corrupt ONE spooled part of `job_id` under `spool_root` (the
     coordinator's part-spool directory) — the
-    while-the-coordinator-is-down storage rot the crash bench injects.
+    storage rot that sets in while the coordinator is down.
     Returns the corrupted path, or None when the job has no spooled
     parts."""
     import os
@@ -281,8 +280,8 @@ def diurnal_rate(t_s: float, period_s: float, lo_rps: float,
     """Sinusoidal day curve: submission rate at time `t_s` into the
     run, peaking at hi_rps mid-period and bottoming at lo_rps at the
     start/end — one compressed diurnal cycle per `period_s`. The
-    autoscale bench drives job arrivals with this so the farm has a
-    real trough to scale down into."""
+    chaos load drives job arrivals with this so the farm has a real
+    trough to scale down into."""
     import math
 
     phase = (t_s % max(1e-9, period_s)) / max(1e-9, period_s)
@@ -302,8 +301,8 @@ def run_chaos_load(submit, duration_s: float, *, period_s: float = 60.0,
     SIGKILLs a worker; `partition(seconds)` (fired once, mid-run at
     the curve's peak, when given) black-holes the /work routes.
     `clock`/`sleep` are injectable for deterministic tests. Returns
-    submission/chaos-event counts plus the curve parameters so the
-    bench pins its context."""
+    submission/chaos-event counts plus the curve parameters, so a
+    result carries its context."""
     import time as _time
 
     clock = clock or _time.monotonic
@@ -430,8 +429,8 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--chaos", action="store_true",
                    help="diurnal job-submission curve against the "
                         "coordinator's /add_job (worker kills and "
-                        "/work partitions need the in-process bench "
-                        "harness — bench.py _run_autoscale)")
+                        "/work partitions need an in-process caller "
+                        "of run_chaos_load)")
     p.add_argument("--input", help="clip to submit repeatedly "
                                    "(--chaos mode)")
     p.add_argument("--hi-rps", type=float, default=1.0,

@@ -3,10 +3,9 @@
 The reference had no quality instrumentation at all — output quality
 was judged by eye off the preview player (SURVEY.md §4); the driver
 metric ("VMAF parity", BASELINE.md) demands numbers. VMAF itself needs
-its trained model files (not in this image), so the harness reports
-PSNR + SSIM — the standard proxies VMAF correlates with — computed
-against the source on every bench run so quality regressions are
-visible next to fps.
+its trained model files (not in this image), so the tests and
+`chip_smoke.py` use PSNR + SSIM — the standard proxies VMAF correlates
+with — computed against the source.
 """
 
 from __future__ import annotations
@@ -63,9 +62,8 @@ def vmaf_proxy(psnr_y: float, ssim_y: float) -> float:
     """VMAF-PROXY score on VMAF's 0..100 scale — NOT VMAF.
 
     Real VMAF needs its trained model files (absent from this image);
-    the bench still has to track a perceptual 0..100 figure (the north
-    star's acceptance metric), so this maps the two metrics VMAF
-    correlates with most strongly onto its scale: a logistic of luma
+    a perceptual 0..100 figure is still wanted beside PSNR, so this
+    maps the two metrics VMAF correlates with most strongly onto its scale: a logistic of luma
     PSNR (saturating like VMAF does at high fidelity — another dB past
     ~45 buys almost nothing perceptually) blended with a power curve
     of SSIM (structure loss hurts faster than MSE suggests). Monotone
