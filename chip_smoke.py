@@ -346,8 +346,8 @@ def run(args) -> dict:
             log(f"PROBLEM: {problem}")
             problems.append(problem)
 
-    # (name, width, height, frames): 160 frames = 5 GOPs, one full
-    # wave and a tail on one chip; hd_b re-uses hd_a's compiled shapes
+    # (name, width, height, frames): 160 frames = 5 GOPs, five
+    # one-GOP waves on one chip; hd_b re-uses hd_a's compiled shape
     clips = [("hd_a", 1920, 1080, 160), ("hd_b", 1920, 1080, 160),
              ("uhd", 3840, 2160, 64)]
     if tiny:    # one frame size: the rehearsal's cost is its compiles

@@ -335,3 +335,150 @@ class TestProgressHistory:
         dones = [p["parts_done"] for p in progress if "parts_done" in p]
         assert dones == sorted(dones) and dones[-1] == 8
         assert job.heartbeat_stage in ("encode", "stitch")
+
+
+class TestWaveOrder:
+    """The executor's wave loop (PR 29): wave n's fetch is started
+    before wave n+1's program is dispatched, and wave n is collected
+    under wave n+1's program; encoders without the step keep the old
+    order."""
+
+    def _rig(self, tmp_path, factory, **settings):
+        import jax
+
+        from thinvids_tpu.parallel.dispatch import default_mesh
+
+        snap = make_settings(gop_frames=4, qp=30,
+                             heartbeat_throttle_s=0.0, **settings)
+        return make_rig(tmp_path, settings=snap,
+                        mesh=default_mesh(jax.devices()[:1]),
+                        encoder_factory=factory)
+
+    def _run(self, coord, clip_y4m):
+        job = coord.add_job(clip_y4m, VideoMeta(width=64, height=48,
+                                                num_frames=12))
+        job = coord.store.get(job.id)
+        assert job.status is Status.DONE, job.failure_reason
+        assert job.parts_done == 3          # 12 frames, GOP 4, 1 device
+        return job
+
+    def test_fetch_of_wave_n_starts_before_dispatch_of_wave_n_plus_1(
+            self, tmp_path, clip_y4m):
+        from thinvids_tpu.parallel.dispatch import GopShardEncoder
+
+        log = []
+
+        class Recording(GopShardEncoder):
+            def dispatch_wave(self, staged):
+                log.append(("dispatch", staged[0][0].index))
+                return super().dispatch_wave(staged)
+
+            def start_fetch(self, pending):
+                if pending[8].tiny is None:     # not started yet
+                    log.append(("fetch", pending[0][0].index))
+                super().start_fetch(pending)
+
+            def collect_wave(self, pending):
+                log.append(("collect", pending[0][0].index))
+                return super().collect_wave(pending)
+
+        def factory(meta, settings, mesh):
+            return Recording(meta, qp=int(settings.qp), mesh=mesh,
+                             gop_frames=int(settings.gop_frames))
+
+        coord, _ = self._rig(tmp_path, factory)
+        job = self._run(coord, clip_y4m)
+        # one GOP per wave: three waves for three GOPs
+        assert [e for e in log if e[0] == "dispatch"] \
+            == [("dispatch", i) for i in range(3)]
+        assert log == [
+            ("dispatch", 0),
+            ("fetch", 0), ("dispatch", 1), ("collect", 0),
+            ("fetch", 1), ("dispatch", 2), ("collect", 1),
+            ("collect", 2), ("fetch", 2),   # last wave: collect starts it
+        ], log
+        # the handoff has a span of its own in the job's trace
+        from thinvids_tpu.obs import trace as obs_trace
+
+        doc = obs_trace.TRACE.export_chrome(job.id)
+        names = [e["name"] for e in doc["traceEvents"] if e["ph"] == "X"]
+        assert names.count("wave_fetch_start") == 3
+        assert names.count("wave_dispatch") == 3
+        assert names.count("wave_collect") == 3
+
+    def test_encoder_without_the_step_keeps_the_old_order(
+            self, tmp_path, clip_y4m):
+        """A double with no start_fetch (the ladder encoder has none
+        either): dispatch n+1, then collect n, as before."""
+        log = []
+
+        class Plain:
+            def __init__(self, meta, settings, mesh):
+                self.inner = LocalExecutor._default_encoder(
+                    meta, settings, mesh)
+
+            def plan(self, n):
+                return self.inner.plan(n)
+
+            def stage_waves(self, frames):
+                return self.inner.stage_waves(frames)
+
+            def dispatch_wave(self, staged):
+                log.append(("dispatch", staged[0][0].index))
+                return self.inner.dispatch_wave(staged)
+
+            def collect_wave(self, pending):
+                log.append(("collect", pending[0][0].index))
+                return self.inner.collect_wave(pending)
+
+        coord, _ = self._rig(tmp_path, Plain)
+        self._run(coord, clip_y4m)
+        assert log == [("dispatch", 0), ("dispatch", 1), ("collect", 0),
+                       ("dispatch", 2), ("collect", 1), ("collect", 2)]
+
+    def test_ladder_call_order_unchanged(self, monkeypatch):
+        """The ladder dispatches a wave's rungs together and collects
+        them together, two waves deep; each rung's fetch is started
+        inside its own collect_wave, never ahead of a dispatch."""
+        from thinvids_tpu.abr.ladder import LadderShardEncoder, plan_ladder
+        from thinvids_tpu.parallel.dispatch import GopShardEncoder
+
+        log = []
+        depth = {"collect": 0}
+
+        class Rung(GopShardEncoder):
+            def dispatch_wave(self, staged):
+                log.append(("dispatch", staged[0][0].index))
+                return super().dispatch_wave(staged)
+
+            def start_fetch(self, pending):
+                if pending[8].tiny is None:     # not started yet
+                    assert depth["collect"] == 1, "fetch ahead of collect"
+                super().start_fetch(pending)
+
+            def collect_wave(self, pending):
+                log.append(("collect", pending[0][0].index))
+                depth["collect"] += 1
+                try:
+                    return super().collect_wave(pending)
+                finally:
+                    depth["collect"] -= 1
+
+        import jax
+
+        from thinvids_tpu.parallel import dispatch as dispatch_mod
+
+        meta = VideoMeta(width=64, height=48, fps_num=30, fps_den=1,
+                         num_frames=12)
+        rungs = plan_ladder(meta, make_settings(qp=30, ladder_rungs="32"))
+        assert len(rungs) == 2
+        monkeypatch.setattr(dispatch_mod, "GopShardEncoder", Rung)
+        ladder = LadderShardEncoder(
+            meta, rungs, gop_frames=4,
+            mesh=dispatch_mod.default_mesh(jax.devices()[:1]))
+        assert not hasattr(ladder, "start_fetch")
+        bundles = ladder.encode(clip_frames(n=12))
+        assert len(bundles) == 3
+        assert log == [("dispatch", 0)] * 2 + [("dispatch", 1)] * 2 \
+            + [("collect", 0)] * 2 + [("dispatch", 2)] * 2 \
+            + [("collect", 1)] * 2 + [("collect", 2)] * 2, log

@@ -609,3 +609,187 @@ class TestHostPipeline:
                                             pps, 27, idr_pic_id=0,
                                             pool=pool)
         assert pooled == serial
+
+
+# ---------------------------------------------------------------------------
+# the wave pipeline's unit and order (PR 29)
+# ---------------------------------------------------------------------------
+
+
+class _RecordingGopEncoder(GopShardEncoder):
+    """Logs (event, first GOP index of the wave, thread) for every
+    dispatch and every return of the fetch-start step, and keeps the
+    staged shapes it was handed."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.log: list[tuple] = []
+        self.shapes: set = set()
+
+    def _note(self, what: str, wave) -> None:
+        import threading
+
+        self.log.append((what, wave[0].index,
+                         threading.current_thread().name))
+
+    def dispatch_wave(self, staged):
+        self._note("dispatch", staged[0])
+        self.shapes.add(tuple(staged[1].shape))
+        return super().dispatch_wave(staged)
+
+    def start_fetch(self, pending):
+        super().start_fetch(pending)
+        # logged on return: the step has run by now, whoever ran it
+        self._note("fetch_started", pending[0])
+
+
+def _smooth_frames(n, w=64, h=48):
+    """Content the sparse budgets hold (noise overflows them and takes
+    the dense fallback, which enqueues no payload slice)."""
+    yy, xx = np.mgrid[0:h, 0:w]
+    return [Frame(
+        y=((xx * 2 + yy + 7 * i) % 256).astype(np.uint8),
+        u=np.full((h // 2, w // 2), 108, np.uint8),
+        v=np.full((h // 2, w // 2), 148, np.uint8)) for i in range(n)]
+
+
+def _first(log, what, index, thread=None):
+    for pos, (w, i, t) in enumerate(log):
+        if w == what and i == index and thread in (None, t):
+            return pos
+    raise AssertionError(f"no {what} of wave {index} in {log}")
+
+
+class TestWavePipeline:
+    def _one_device(self):
+        return default_mesh(jax.devices()[:1])
+
+    def test_default_wave_is_one_gop_per_device(self):
+        meta = VideoMeta(width=64, height=48, num_frames=16)
+        enc = GopShardEncoder(meta, qp=27, gop_frames=2,
+                              mesh=self._one_device())
+        assert enc.gops_per_wave == 1
+        frames = _make_frames(16, seed=5)
+        waves = [wave for wave, *_ in enc.stage_waves(frames)]
+        assert [len(w) for w in waves] == [1] * 8
+        # on the 8-device mesh a wave is one GOP on each device
+        enc8 = GopShardEncoder(meta, qp=27, gop_frames=2)
+        assert [len(wave) for wave, *_ in enc8.stage_waves(frames)] == [8]
+        # and the stage snapshot counts them
+        enc.encode(frames)
+        assert enc.stages.snapshot()["waves"] == 8
+
+    def test_encode_waves_starts_fetch_before_next_dispatch(self):
+        """For every wave n the dispatch loop has wave n's fetch
+        started (counts in, payload slice enqueued) before it enqueues
+        wave n+1's program — on the dispatching thread itself, not by
+        the luck of a collector thread."""
+        import threading
+
+        meta = VideoMeta(width=64, height=48, num_frames=20)
+        enc = _RecordingGopEncoder(meta, qp=27, gop_frames=4,
+                                   mesh=self._one_device())
+        frames = _smooth_frames(20)
+        segs = enc.encode_waves(enc.stage_waves(frames))
+        assert len(segs) == 5
+        me = threading.current_thread().name
+        for n in range(4):
+            assert _first(enc.log, "fetch_started", n, me) \
+                < _first(enc.log, "dispatch", n + 1, me), enc.log
+        # a started fetch is not started twice: the collector thread's
+        # own call finds the step done and the counts unchanged
+        snap = enc.stages.snapshot()
+        assert snap["waves"] == 5 and snap["dense_fallback_waves"] == 0
+
+    def test_start_fetch_is_idempotent_and_optional(self):
+        meta = VideoMeta(width=64, height=48, num_frames=4)
+        frames = _smooth_frames(4)
+
+        def run(calls: int) -> tuple[bytes, dict]:
+            enc = GopShardEncoder(meta, qp=27, gop_frames=4,
+                                  mesh=self._one_device())
+            (staged,) = list(enc.stage_waves(frames))
+            handle = enc.dispatch_wave(staged)
+            for _ in range(calls):
+                enc.start_fetch(handle)
+            out = concat_segments(enc.collect_wave(handle))
+            snap = enc.stages.snapshot()
+            assert snap["dense_fallback_waves"] == 0
+            return out, snap
+
+        base, snap0 = run(0)        # collect_wave performs the step
+        for calls in (1, 3):
+            out, snap = run(calls)
+            assert out == base
+            assert snap["d2h_bytes"] == snap0["d2h_bytes"]
+
+    @pytest.mark.parametrize("gops_per_wave", [1, 2, 4])
+    def test_uneven_gops_byte_identical_at_any_wave_size(self,
+                                                         gops_per_wave):
+        """A plan with GOPs of `base` and `base + 1` frames encodes to
+        the single-device reference GOP for GOP however the GOPs are
+        grouped into waves (closed GOPs; the padded frame is dropped)."""
+        from thinvids_tpu.codecs.h264.encoder import encode_gop
+
+        n = 13
+        frames = _make_frames(n, seed=8)
+        meta = VideoMeta(width=64, height=48, num_frames=n)
+        enc = GopShardEncoder(meta, qp=27, gop_frames=4,
+                              mesh=self._one_device(),
+                              gops_per_wave=gops_per_wave)
+        plan = enc.plan(n)
+        assert sorted({g.num_frames for g in plan.gops}) == [3, 4]
+        segs = enc.encode(frames)
+        assert [s.gop.num_frames for s in segs] \
+            == [g.num_frames for g in plan.gops]
+        for seg, gop in zip(segs, plan.gops):
+            assert seg.payload == encode_gop(
+                frames[gop.start_frame:gop.end_frame], meta, qp=27,
+                idr_pic_id=gop.index), f"GOP {gop.index}"
+
+    def test_uneven_gops_dispatch_one_program_shape(self):
+        """F is the plan's longest GOP, not the wave's: one-GOP waves
+        of a clip with 3- and 4-frame GOPs all dispatch (1, 4, H, W)."""
+        n = 13
+        meta = VideoMeta(width=64, height=48, num_frames=n)
+        enc = _RecordingGopEncoder(meta, qp=27, gop_frames=4,
+                                   mesh=self._one_device())
+        enc.encode(_make_frames(n, seed=8))
+        assert enc.shapes == {(1, 4, 48, 64)}
+        # the analysis pass stages the same static F
+        assert {tuple(ys.shape) for _w, ys in enc.stage_luma_waves(
+            _make_frames(n, seed=8))} == {(1, 4, 48, 64)}
+
+    def test_sfe_dispatch_order_unchanged(self):
+        """The split-frame encoder's start_fetch is empty, so its
+        encode_waves still dispatches `window` GOPs ahead: wave 1 is
+        dispatched while wave 0's collect has not returned."""
+        import threading
+
+        from thinvids_tpu.parallel.dispatch import SfeShardEncoder
+
+        dispatched_1 = threading.Event()
+        seen: dict = {}
+
+        class Rec(SfeShardEncoder):
+            def dispatch_wave(self, staged):
+                out = super().dispatch_wave(staged)
+                if staged[0].index == 1:
+                    dispatched_1.set()
+                return out
+
+            def collect_wave(self, pending):
+                if pending[0].index == 0:
+                    seen["d1_before_c0_returns"] = dispatched_1.wait(60)
+                return super().collect_wave(pending)
+
+        w, h, n = 64, 64, 6
+        meta = VideoMeta(width=w, height=h, num_frames=n)
+        enc = Rec(meta, qp=27, gop_frames=2, bands=2)
+        frames = _make_frames(n, w=w, h=h, seed=9)
+        handle = enc.dispatch_wave(next(iter(enc.stage_waves(frames))))
+        assert enc.start_fetch(handle) is None      # nothing to start
+        dispatched_1.clear()
+        segs = enc.encode_waves(enc.stage_waves(frames), window=2)
+        assert len(segs) == 3
+        assert seen["d1_before_c0_returns"]

@@ -223,10 +223,6 @@ class LadderShardEncoder:
     def decode_ahead(self) -> int:
         return self._stager.decode_ahead
 
-    @property
-    def gops_per_wave(self) -> int:
-        return self._stager.gops_per_wave
-
     def _all_encoders(self) -> list:
         encs = list(self.encoders)
         if self._stager is not self.encoders[0]:

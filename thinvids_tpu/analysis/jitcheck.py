@@ -22,9 +22,9 @@ TVT-X002  **hot-loop transfer ban.** The manifest's `hot_loops`
           declare the per-wave / per-SFE-frame functions. Blocking
           transfer calls there (`device_put`, `device_get`,
           `block_until_ready`, `.item()`) serialize the pipeline —
-          staging (`stage_waves`) and collect (`collect_wave`,
-          `_fetch_*`) are the allowlisted transfer sites and are
-          deliberately NOT declared hot. `copy_to_host_async` stays
+          staging (`stage_waves`) and collect (`start_fetch`,
+          `collect_wave`, `_fetch_*`) are the allowlisted transfer
+          sites and are deliberately NOT declared hot. `copy_to_host_async` stays
           legal everywhere (it is the prefetch that OVERLAPS the
           pipeline, not a sync).
 """

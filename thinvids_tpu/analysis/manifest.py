@@ -362,14 +362,16 @@ class Manifest:
     #: `.item()` on runtime data) inside a jit module must route
     #: through one of these, or every wave recompiles (the PR 4
     #: quantized-slice rule; `cut` is the used-prefix quantizer in
-    #: GopShardEncoder._fetch_payload_rows).
+    #: GopShardEncoder._slice_payload_rows).
     shape_quantizers: tuple[str, ...] = ("cut",)
     #: wave/frame hot-loop functions ("module:Qual.name"): code that
     #: runs once per dispatched wave or per SFE frame step. Blocking
     #: transfers (`device_put`, `device_get`, `block_until_ready`,
     #: `.item()`) are banned here — staging (stage_waves) and collect
-    #: (collect_wave, _fetch_*) are the allowlisted transfer sites and
-    #: are deliberately NOT in this set.
+    #: (start_fetch, collect_wave, _fetch_*) are the allowlisted
+    #: transfer sites and are deliberately NOT in this set. The one
+    #: wait the GOP-wave loops make by design is start_fetch's, at a
+    #: wave boundary (the order rule, parallel/dispatch).
     hot_loops: tuple[str, ...] = (
         "thinvids_tpu.parallel.dispatch:GopShardEncoder.dispatch_wave",
         "thinvids_tpu.parallel.dispatch:GopShardEncoder.encode_waves",

@@ -17,6 +17,7 @@ attribution.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -312,9 +313,9 @@ class LocalExecutor:
 
         Latency model: at the live edge one GOP encodes at a time
         (glass-to-playlist ≈ GOP duration + one wave's encode+package);
-        during backlog/catch-up, up to one full wave of GOPs batches
-        per dispatch. End-of-stream is the tail source's stall timeout
-        (`live_stall_s`) or `.eos` marker; the packager then finalizes
+        during backlog/catch-up, up to LIVE_CATCHUP_WAVES waves of GOPs
+        batch per dispatch. End-of-stream is the tail source's stall
+        timeout (`live_stall_s`) or `.eos` marker; the packager then finalizes
         with EXT-X-ENDLIST and — when nothing was GC'd out of the DVR
         window — the tree passes the full VOD conformance lint. Waves
         do not retry or replan here: a live edge cannot rewind, so a
@@ -386,8 +387,8 @@ class LocalExecutor:
                 whole = (avail - frames_done) // gop_n
                 # at the live edge whole==1 (lowest latency); during
                 # catch-up batch up to the backlog cap per dispatch
-                # (one local wave — or the whole farm's width when the
-                # remote backend fans catch-up GOPs out)
+                # (a few local waves — or the whole farm's width when
+                # the remote backend fans catch-up GOPs out)
                 count = min(whole, wave_cap) * gop_n
             bundles = self._live_encode_batch(
                 job, token, settings, enc, rungs, tail, frames_done,
@@ -454,13 +455,21 @@ class LocalExecutor:
         return make_shard_encoder(meta, settings, self.mesh,
                                   rungs=rungs), False
 
+    #: waves one live catch-up batch may hold. A batch's parts reach the
+    #: packager when the whole batch is encoded, so this bounds what a
+    #: viewer waits for during catch-up; within the batch the waves
+    #: pipeline (one GOP per device each), which a one-wave batch could
+    #: not: it would pay a bare lead-in and tail per GOP and recover
+    #: slower than the source runs.
+    LIVE_CATCHUP_WAVES = 4
+
     def _live_backlog_cap(self, job, settings, enc) -> int:
-        """Whole GOPs one catch-up dispatch may batch: one local wave.
-        The remote backend widens this to the farm (its override fans
-        the backlog across workers) — but only when the fan-out will
-        actually engage, so a disabled knob keeps the pre-farm local
-        batch bound."""
-        return enc.num_devices * enc.gops_per_wave
+        """Whole GOPs one catch-up dispatch may batch:
+        LIVE_CATCHUP_WAVES local waves. The remote backend widens this
+        to the farm (its override fans the backlog across workers) —
+        but only when the fan-out will actually engage, so a disabled
+        knob keeps the pre-farm local batch bound."""
+        return enc.num_devices * self.LIVE_CATCHUP_WAVES
 
     def _live_encode_batch(self, job, token, settings, enc, rungs,
                            tail, frames_done: int, gops_done: int,
@@ -682,13 +691,25 @@ class LocalExecutor:
     def _encode_range(self, job: Job, token: str, enc, frames,
                       start_frame: int, settings, total_gops: int,
                       done0: int) -> list:
-        """Depth-2 pipelined wave loop over frames[start_frame:].
+        """Pipelined wave loop over frames[start_frame:].
+
+        A wave is one GOP per device (parallel/dispatch), and the loop's
+        order is: start wave n's fetch (`enc.start_fetch`: its counts
+        are in, its payload slice is enqueued), THEN dispatch wave n+1,
+        then unpack and pack wave n under wave n+1's program. The
+        payload slice is a program on the device's compute queue;
+        dispatched after wave n+1 it would wait for all of it, and
+        wave n's host work would land behind it with the device idle.
+        An encoder without the step (the ladder, test doubles) or with
+        an empty one (split-frame) keeps the old order: dispatch n+1,
+        collect n.
 
         The decode → stack → H2D staging chain runs on a background
         staging thread (`decode_ahead` waves ahead of the dispatch
         window — parallel/dispatch.background_stage), so ingest
-        overlaps device compute instead of serializing ahead of it.
-        Staging stays bounded, not free: input residency is now the 2
+        overlaps device compute instead of serializing ahead of it and
+        wave n+1's inputs are on the device when wave n ends.
+        Staging stays bounded, not free: input residency is the 2
         in-flight waves PLUS up to `decode_ahead` staged-but-undispatched
         waves (+1 blocked in the queue put) of HBM-resident YUV arrays —
         size `decode_ahead` against the device's HBM headroom, not just
@@ -730,11 +751,18 @@ class LocalExecutor:
             if not co.token_is_current(job.id, token):
                 raise HaltedError("stale run token")
 
+        start_fetch = getattr(enc, "start_fetch", None)
+
         def dispatch_next() -> None:
             try:
                 i, staged = next(staged_iter)
             except StopIteration:
                 return
+            if pending and start_fetch is not None:
+                # the order rule. A failure here is the wave's own:
+                # collect_wave repeats the step and owns the retry
+                with contextlib.suppress(Exception):
+                    start_fetch(pending[-1][2])
             with rec.span("wave_dispatch", wave=i):
                 pending.append((i, staged, enc.dispatch_wave(staged)))
 

@@ -1782,7 +1782,7 @@ class RemoteExecutor(LocalExecutor):
         direct-mode job), the LOCAL wave bound stays in force — an
         inflated batch would otherwise serialize whole farm-widths of
         GOPs through the local mesh before the packager sees a part."""
-        base = enc.num_devices * enc.gops_per_wave
+        base = super()._live_backlog_cap(job, settings, enc)
         if not bool(settings.get("live_farm_catchup", True))                 or str(getattr(job, "processing_mode", "split")
                        or "split") == "direct":
             return base

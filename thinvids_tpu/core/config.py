@@ -136,9 +136,10 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     "trace_ring_spans": 4096,
     "flight_record": True,
     # host wave pipeline (parallel/dispatch.py): slice-granular CAVLC
-    # pack threads (0 = os.cpu_count()) and the in-flight wave window.
+    # pack threads (0 = os.cpu_count()) and the window of waves being
+    # collected at once (split-frame: GOPs dispatched ahead).
     # Deliberately independent: the pack pool sizes to the host's cores,
-    # the window to device queue depth / HBM budget.
+    # the window to the HBM / host memory the waves' outputs may pin.
     "pack_workers": 0,
     "pipeline_window": 4,
     # device→host boundary (parallel/dispatch.py): compact_transfer

@@ -8,7 +8,11 @@ coordinator (`trace_ring_spans`). Sources:
   calls the bound recorder from every timed stage: decode / stage /
   dispatch / device_wait / fetch / sparse_unpack / unflatten / pack /
   concat, plus the SFE per-frame leg);
-- the executor's per-wave spans (`wave_dispatch` / `wave_collect`);
+- the executor's per-wave spans (`wave_dispatch` / `wave_collect`) and
+  the encoder's handoff between them (`wave_fetch_start`: the wait for
+  a wave's counts and the enqueue of its payload slices, which the
+  dispatch loops run before the next wave's program — a device idle at
+  a wave boundary is idle under this span);
 - coordinator-side per-shard spans (ShardBoard lease → accepted part);
 - remote workers: a :class:`SpanBuffer` collects the worker-side spans
   (open_source / encode / upload, plus the worker's own stage clocks)

@@ -10,6 +10,18 @@ entropy packing. Encoded segments concat in index order; bit-identity with
 the single-device encode is asserted by tests/test_parallel.py on an
 8-device virtual mesh.
 
+A wave is the pipeline's unit: ONE GOP per mesh device (`gops_per_wave`
+1), one program shape per clip (every wave is padded to the plan's
+longest GOP). More GOPs per wave buy no device time — 18.70 ms per
+1080p frame at 1x32, 18.71 at 4x32 (ledger PR 28) — and cost a job a
+lead-in (decode + stage before the first program) and a tail (unpack +
+pack after the last) of a whole wave each, with the device idle. The
+pipeline's order: wave n's fetch is STARTED (start_fetch: counts in,
+payload slice enqueued) before wave n+1's program is enqueued — the
+slice is itself a program on the device's compute queue and would
+otherwise wait for all of wave n+1 — and wave n's unpack and pack then
+run under wave n+1's compute.
+
 Host side, the pipeline is instrumented per stage (StageProfile): every
 wave's source decode / staging (stack + H2D upload) / dispatch / device
 wait / D2H fetch / sparse unpack / unflatten / CAVLC pack / concat
@@ -17,7 +29,7 @@ wall-clock accumulates on the encoder and is exported through the
 API's /metrics_snapshot (`stage_ms`). The entropy pack fans out
 at SLICE granularity across a per-encoder pool sized by `pack_workers`
 (TVT_PACK_WORKERS; default: all cores; threads spawn on demand and
-retire with the encoder), decoupled from the in-flight wave window
+retire with the encoder), decoupled from the collector-thread window
 `pipeline_window` (TVT_PIPELINE_WINDOW).
 
 Ingest is a pipelined stage, not a blocking prologue: `stage_waves`
@@ -26,7 +38,8 @@ list and holds only the current wave's decoded frames (a sliding
 _FrameCursor window), and :func:`background_stage` runs the whole
 decode→stack→upload chain on a staging thread up to `decode_ahead`
 waves (TVT_DECODE_AHEAD) ahead of dispatch, overlapping source decode
-with device compute.
+with device compute: wave n+1's inputs are on the device when wave n
+ends.
 
 The device→host boundary itself is compacted and parallelized three
 ways (BENCH r04→r05 showed every device-side win dying here):
@@ -601,12 +614,29 @@ def _encode_wave_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
     return shard(ys, us, vs, qps)
 
 
+class _WaveFetch:
+    """What :meth:`GopShardEncoder.start_fetch` leaves on a dispatched
+    wave's handle for :meth:`GopShardEncoder.collect_wave`: the tiny
+    counts, whether the sparse budgets held, and the payload's used
+    prefixes already sliced on the device and on their way to the host.
+    The lock makes the step run once whoever comes first (the dispatch
+    loop, or the wave's own collector thread)."""
+
+    __slots__ = ("lock", "tiny", "sparse_ok", "payload")
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.tiny = self.payload = None     # tiny is set last: it
+                                            # says the step has run
+        self.sparse_ok = False
+
+
 class GopShardEncoder:
     """Encode a clip as closed GOPs fanned across a device mesh."""
 
     def __init__(self, meta: VideoMeta, qp: int = 27, mesh: Mesh | None = None,
                  gop_frames: int = 32, max_segments: int = 200,
-                 inter: bool = True, gops_per_wave: int = 4,
+                 inter: bool = True, gops_per_wave: int = 1,
                  pack_workers: int | None = None,
                  pipeline_window: int | None = None,
                  decode_ahead: int | None = None,
@@ -622,8 +652,12 @@ class GopShardEncoder:
         self.gop_frames = gop_frames
         self.max_segments = max_segments
         #: GOPs encoded per device per wave (lax.map'd inside one
-        #: program) — batches device dispatch + transfer so per-call
-        #: host<->device latency amortizes. Inter path only.
+        #: program). One: the wave is the pipeline's unit, so a job's
+        #: bare lead-in (decode + stage before the first program) and
+        #: tail (unpack + pack after the last) are each one wave long,
+        #: and a longer wave buys no device time (18.70 ms per 1080p
+        #: frame at 1x32, 18.71 at 4x32: ledger PR 28). Analysis passes
+        #: and tests may still batch more. Inter path only.
         self.gops_per_wave = max(1, int(gops_per_wave))
         self.sps = SPS(width=meta.width, height=meta.height,
                        fps_num=meta.fps_num, fps_den=meta.fps_den)
@@ -648,8 +682,11 @@ class GopShardEncoder:
         if pack_workers is None:
             pack_workers = int(snap.get("pack_workers", 0) or 0)
         self.pack_workers = int(pack_workers) or (os.cpu_count() or 2)
-        #: in-flight wave window: staged inputs + outputs of this many
-        #: waves stay alive at once (device queue x transfer overlap).
+        #: collector-thread window: outputs of this many waves may be
+        #: in fetch / unpack / pack at once (encode_waves). The device
+        #: queue itself holds one GOP-wave program at a time (the order
+        #: rule, start_fetch); split-frame encoders dispatch this many
+        #: GOPs ahead.
         if pipeline_window is None:
             pipeline_window = int(snap.get("pipeline_window", 0) or 0)
         self.pipeline_window = int(pipeline_window) or self.PIPELINE_WINDOW
@@ -781,18 +818,22 @@ class GopShardEncoder:
         """Shared wave grouping: (wave, device-padded wave, static F,
         frame cursor). Stacks into (G, F, ...) with tail-repeat padding
         to static F; the wave itself pads to a multiple of D gops (the
-        pad GOPs are encoded then discarded). The cursor decodes frames
-        on demand and each wave's frames are released once the caller
-        has staged them into device arrays."""
+        pad GOPs are encoded then discarded). F is the longest GOP of
+        the PLAN, not of the wave: the planner balances GOPs to `base`
+        and `base + 1` frames, and a per-wave F would compile one
+        program shape for each (the padded frame is encoded and
+        dropped, see collect_wave). The cursor decodes frames on demand
+        and each wave's frames are released once the caller has staged
+        them into device arrays."""
         plan = self.plan(len(frames))
         cursor = _FrameCursor(frames, self.stages, require_420=require_420,
                               stats=self.staging_stats)
         D = self.num_devices
         per_wave = D * (self.gops_per_wave if self.inter else 1)
         gops = list(plan.gops)
+        F = max((g.num_frames for g in gops), default=0)
         for wave_start in range(0, len(gops), per_wave):
             wave = gops[wave_start:wave_start + per_wave]
-            F = max(g.num_frames for g in wave)
             pad_n = (-len(wave)) % D
             full = wave + [wave[-1]] * pad_n
             yield wave, full, F, cursor
@@ -851,7 +892,7 @@ class GopShardEncoder:
                             "device→host prefetch disabled for this "
                             "encoder", type(exc).__name__, exc)
                         break
-            return (wave, ysd, usd, vsd, qpsd, mbw, mbh, out)
+            return (wave, ysd, usd, vsd, qpsd, mbw, mbh, out, _WaveFetch())
 
     def _new_pack_pool(self):
         """This encoder's slice-pack pool (threads spawn on demand up
@@ -947,40 +988,62 @@ class GopShardEncoder:
             host.append(a)
         return host
 
-    def _fetch_payload_rows(self, payload, used) -> list[np.ndarray]:
-        """Fetch the wave's compact payloads SLICED to their used
-        prefix: each shard moves max(used) bytes per GOP (rounded up to
-        PAYLOAD_QUANTUM) instead of the whole budget-padded buffer, one
-        transfer thread per device shard. Returns a 1-D uint8 row per
-        GOP (row length >= that GOP's used bytes)."""
+    def _payload_cuts(self, payload, used) -> list[tuple]:
+        """[(device array, cut)] per shard, in GOP order: payloads are
+        fetched SLICED to their used prefix, max(used) bytes per GOP
+        (rounded up to PAYLOAD_QUANTUM), not the whole padded buffer."""
         used = np.asarray(used)
-        G, PB = payload.shape
+        PB = payload.shape[1]
         q = max(256, min(self.PAYLOAD_QUANTUM, PB // 8))
 
         def cut(n) -> int:
             return min(PB, -(-max(int(n), 1) // q) * q)
 
-        pool = self._fetch_pool
-        if pool is None:
-            host = np.asarray(payload[:, :cut(used.max())])
-            self.stages.bump("d2h_bytes", int(host.nbytes))
-            return list(host)
+        if self._fetch_pool is None:
+            return [(payload, cut(used.max()))]
         shards = sorted(payload.addressable_shards,
                         key=lambda s: s.index[0].start or 0)
         self.stages.bump("fetch_shards", len(shards))
-        futs = []
+        cuts = []
         for s in shards:
             a = s.index[0].start or 0
-            mu = cut(used[a:a + s.data.shape[0]].max())
-            futs.append((a, pool.submit(
-                lambda d=s.data, m=mu: np.asarray(d[:, :m]))))
-        rows: list = [None] * G
-        for a, f in futs:
-            part = f.result()
-            self.stages.bump("d2h_bytes", int(part.nbytes))
-            for i in range(part.shape[0]):
-                rows[a + i] = part[i]
-        return rows
+            cuts.append((s.data, cut(used[a:a + s.data.shape[0]].max())))
+        return cuts
+
+    def _slice_payload_rows(self, payload, used) -> list:
+        """Enqueue the payload slices ON THE DEVICE and start their
+        copies to the host. A slice is a program on the compute queue:
+        it runs after everything enqueued before it, so start_fetch
+        enqueues it before the next wave's program. Returns the sliced
+        device arrays for :meth:`_gather_payload_rows`."""
+        parts = [d[:, :m] for d, m in self._payload_cuts(payload, used)]
+        if not self._async_copy_unavailable:
+            # (a platform that rejects it was logged by dispatch_wave)
+            with contextlib.suppress(Exception):
+                for part in parts:
+                    part.copy_to_host_async()
+        return parts
+
+    def _gather_payload_rows(self, parts: list) -> list[np.ndarray]:
+        """Host side of :meth:`_slice_payload_rows`: a 1-D uint8 row
+        per GOP (row length >= that GOP's used bytes)."""
+        return self._payload_rows([np.asarray(part) for part in parts])
+
+    def _fetch_payload_rows(self, payload, used) -> list[np.ndarray]:
+        """Slice and fetch in one go, a transfer thread per shard: the
+        split-frame encoders' per-frame collect."""
+        cuts = self._payload_cuts(payload, used)
+        pool = self._fetch_pool
+        if pool is None:
+            return self._payload_rows([np.asarray(d[:, :m])
+                                       for d, m in cuts])
+        futs = [pool.submit(lambda d=d, m=m: np.asarray(d[:, :m]))
+                for d, m in cuts]
+        return self._payload_rows([f.result() for f in futs])
+
+    def _payload_rows(self, hosts: list) -> list[np.ndarray]:
+        self.stages.bump("d2h_bytes", sum(int(h.nbytes) for h in hosts))
+        return [row for host in hosts for row in host]
 
     @staticmethod
     def _unpack_compact(payload_row: np.ndarray, nblk: int, nval: int,
@@ -1067,56 +1130,93 @@ class GopShardEncoder:
 
         return gather
 
+    def _level_sizes(self, F: int, nmb: int) -> tuple[int, int]:
+        """(L, Lr) of one GOP's (inter) or frame's (intra) flat levels:
+        the whole vector, and its sparse remainder once both intra
+        hadamard DC segments (luma + chroma) and the [mode16 | dqp16]
+        tail, when shipped, go dense (_per_gop_sparse)."""
+        tail = 2 * nmb if self.rd.ships_modes else 0
+        L = (nmb * _INTRA_MB + (F - 1) * nmb * _P_FLAT_MB + tail
+             if self.inter else nmb * _INTRA_MB + tail)
+        return L, L - nmb * 16 - nmb * 8 - tail
+
+    def start_fetch(self, pending: tuple) -> None:
+        """First step of collecting a dispatched wave, split out so the
+        dispatch loop can run it BEFORE it enqueues the next wave's
+        program: wait for the tiny counts (they complete when the
+        wave's compute does — `device_wait`), decide whether the sparse
+        budgets held, and enqueue the payload's used-prefix slices with
+        their copies to the host. The slice is a program on the
+        compute queue: enqueued after the next wave's program it would
+        wait for all of it, and the wave's unpack and pack with it
+        (9.4 ms per frame of `fetch` in `hd-backlog`, ledger PR 28).
+        Idempotent, and :meth:`collect_wave` performs it itself for
+        callers that have not."""
+        _wave, ysd, _usd, _vsd, _qpsd, mbw, mbh, out, fetch = pending
+        with fetch.lock:
+            if fetch.tiny is not None:
+                return
+            prof = self.stages
+            compact = self.inter and self.compact_transfer
+            tracer = prof.tracer()
+            with (tracer.span("wave_fetch_start") if tracer is not None
+                  else contextlib.nullcontext()):
+                # Barrier on the tiny count outputs first: splits
+                # "waiting on the device" from the bulk D2H fetch in
+                # the stage breakdown — and lets a budget overflow
+                # skip the bulk sparse fetch entirely.
+                with prof.stage("device_wait"):
+                    if self.inter:
+                        tiny = jax.device_get(list(out[2:6] if compact
+                                                   else out[2:5]))
+                    else:
+                        tiny = jax.device_get([out[0], out[1]])
+                prof.bump("d2h_bytes", sum(int(a.nbytes) for a in tiny))
+                L, Lr = self._level_sizes(ysd.shape[1], mbw * mbh)
+                if self.inter:
+                    nblk, nval, n_esc = tiny[:3]
+                    fetch.sparse_ok = jaxcore.block_sparse2_fits(
+                        nblk.max(), nval.max(), n_esc.max(), Lr)
+                    if fetch.sparse_ok and compact:
+                        fetch.payload = self._slice_payload_rows(
+                            out[6], tiny[3])
+                else:
+                    nnz, n_esc = tiny
+                    fetch.sparse_ok = jaxcore.sparse_fits(
+                        nnz.max(), n_esc.max(), L)
+            fetch.tiny = tiny
+
     def collect_wave(self, pending: tuple) -> list[EncodedSegment]:
         """Fetch one dispatched wave's levels (compact or sparse, with
         the dense fallback) and entropy-pack its GOPs on host, fanning
         the pack across the slice pool — or, with pack_backend=process,
         handing whole GOPs to the shared-memory sidecars."""
-        wave, ysd, usd, vsd, qpsd, mbw, mbh, out = pending
+        self.start_fetch(pending)
+        wave, ysd, usd, vsd, qpsd, mbw, mbh, out, fetch = pending
         prof = self.stages
         F = ysd.shape[1]
         nmb = mbw * mbh
         ships_modes = self.rd.ships_modes
-        tail = 2 * nmb if ships_modes else 0     # [mode16 | dqp16]
-        L = (nmb * _INTRA_MB + (F - 1) * nmb * _P_FLAT_MB + tail
-             if self.inter else nmb * _INTRA_MB + tail)
+        L, Lr = self._level_sizes(F, nmb)
         compact = self.inter and self.compact_transfer
-        # Barrier on the tiny count outputs first: they complete when
-        # the wave's compute does, splitting "waiting on the device"
-        # from the bulk D2H fetch in the stage breakdown — and letting
-        # a budget overflow skip the bulk sparse fetch entirely.
-        with prof.stage("device_wait"):
-            if self.inter:
-                tiny = jax.device_get(list(out[2:6] if compact
-                                           else out[2:5]))
-            else:
-                tiny = jax.device_get([out[0], out[1]])
-        prof.bump("d2h_bytes", sum(int(a.nbytes) for a in tiny))
+        tiny, sparse_ok = fetch.tiny, fetch.sparse_ok
         flat = None
         used = payload_rows = None
         if self.inter:
-            nblk, nval, n_esc = tiny[0], tiny[1], tiny[2]
-            # dense prefix = both intra hadamard DC segments (luma +
-            # chroma) + the mode/dqp tail when shipped; the sparse
-            # remainder skips them (_per_gop_sparse)
-            ndc, ncdc = nmb * 16, nmb * 8
-            Lr = L - ndc - ncdc - tail
-            sparse_ok = jaxcore.block_sparse2_fits(
-                nblk.max(), nval.max(), n_esc.max(), Lr)
+            nblk, nval = tiny[0], tiny[1]
             if sparse_ok:
                 with prof.stage("fetch"):
                     if compact:
                         used = tiny[3]
                         mv8, dc16 = self._fetch_bulk(out[0:2])
-                        payload_rows = self._fetch_payload_rows(out[6],
-                                                                used)
+                        payload_rows = self._gather_payload_rows(
+                            fetch.payload)
                     else:
                         mv8, dc16, bitmap, bmask16, vals = \
                             self._fetch_bulk(
                                 (out[0], out[1], out[5], out[6], out[7]))
         else:
             nnz, n_esc = tiny
-            sparse_ok = jaxcore.sparse_fits(nnz.max(), n_esc.max(), L)
             if sparse_ok:
                 with prof.stage("fetch"):
                     bitmap, vals, esc_pos, esc_val = \
@@ -1263,16 +1363,23 @@ class GopShardEncoder:
     def encode_waves(self, waves, window: int | None = None,
                      pack_workers: int | None = None
                      ) -> list[EncodedSegment]:
-        """Dispatch staged waves: device compute → async sparse fetch →
-        host entropy pack, in wave order.
+        """Dispatch staged waves: device compute → sparse fetch → host
+        entropy pack, in wave order.
 
-        Pipelined three ways: up to `window` (default: the
-        `pipeline_window` setting) waves are dispatched ahead — device
-        queue + async device→host copies overlap the current fetch —
-        each wave's fetch+unpack runs on a collector thread per
-        in-flight wave, and every slice of every in-flight GOP packs
-        on this encoder's `pack_workers` pool (collect_wave), so host
-        packing scales with cores instead of with the window.
+        The order rule: wave n's fetch is started (:meth:`start_fetch`:
+        its counts are in, its payload slice is enqueued) BEFORE wave
+        n+1's program is enqueued, so the slice never queues behind a
+        whole program and wave n's unpack and pack run under wave
+        n+1's compute. The staged inputs of wave n+1 are on the device
+        already (background_stage), so the device waits one host
+        reaction at a boundary. Each wave's fetch + unpack runs on a
+        collector thread — at most `window` (default: the
+        `pipeline_window` setting) of them in flight — and every slice
+        of every in-flight GOP packs on this encoder's `pack_workers`
+        pool (collect_wave), so host packing scales with cores instead
+        of with the window. An encoder whose start_fetch does nothing
+        (the split-frame encoders: per-frame collect) keeps `window`
+        waves dispatched ahead.
         """
         import concurrent.futures as cf
 
@@ -1285,16 +1392,19 @@ class GopShardEncoder:
         segments: list[EncodedSegment] = []
         waves = iter(waves)
         pending: list[cf.Future] = []
+        newest = None               # the last dispatched wave's handle
 
         with cf.ThreadPoolExecutor(window) as pool:
             def dispatch_next():
+                nonlocal newest
                 try:
                     staged = next(waves)
                 except StopIteration:
                     return False
-                pending.append(
-                    pool.submit(self.collect_wave,
-                                self.dispatch_wave(staged)))
+                if newest is not None:
+                    self.start_fetch(newest)
+                newest = self.dispatch_wave(staged)
+                pending.append(pool.submit(self.collect_wave, newest))
                 return True
 
             for _ in range(window):
@@ -1797,6 +1907,11 @@ class SfeShardEncoder(GopShardEncoder):
         self._last_frame_done = None
         return super().encode_waves(waves, window=window,
                                     pack_workers=pack_workers)
+
+    def start_fetch(self, pending: tuple) -> None:
+        """Nothing to start ahead: the collect is per FRAME and owns
+        its fetches (each frame's slice follows that frame's step), so
+        the dispatch loops keep their order here."""
 
     def _step_mesh(self) -> Mesh | None:
         """None on a single band: the per-band program runs without the

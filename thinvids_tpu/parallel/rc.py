@@ -173,7 +173,7 @@ def refine_gop_qps(prev_qps: np.ndarray, actual_bits: float,
 def encode_vbr2pass(frames: list[Frame], meta: VideoMeta,
                     target_bitrate_kbps: float, base_qp: int = 27,
                     mesh: Mesh | None = None, gop_frames: int = 32,
-                    gops_per_wave: int = 4, tolerance: float = 0.08,
+                    gops_per_wave: int = 1, tolerance: float = 0.08,
                     max_refine: int = 3, enc: GopShardEncoder | None = None,
                     encode_fn=None, on_pass=None,
                     aq_strength: float = 0.0,
