@@ -734,10 +734,18 @@ def _compact_left(shift, *streams):
 
 
 # Value-stream budget for the two-tier pack: elementwise nonzero density
-# beyond 1/div falls back dense. Measured 1080p GOP at qp 27 on heavily
-# grainy content: ~723K nonzero coeffs of 25.5M (~2.8%); 1/24 still
-# leaves ~1.5x headroom. The budget sizes the device buffer only:
-# the compact transfer fetches the used prefix (85 kB per 1080p frame,
+# beyond 1/div falls back dense. It is NOT the budget that goes first.
+# On a pan with white grain that is new on every frame (tools/pan
+# make_frames' `grain`; QP 27, GOP 16, 320x192, counted on the dense
+# twin's levels, PR 30) the blocks with a level / the non-zero values
+# are 7.8 % / 0.95 % of a GOP's sparse remainder at sigma 0, 20.1 % /
+# 2.05 % at sigma 3, 26.9 % / 2.73 % at 3.5, 34.6 % / 3.65 % at 4 and
+# 48.8 % / 6.26 % at 5: the 25 % block budget (_BLOCK_BUDGET_DIV)
+# overflows at sigma ~3.4, the 4.17 % value budget not before ~4.2, so
+# ordinarily grainy footage at CQP 27 leaves the sparse transfer
+# through the BLOCK budget (tests/test_grain.py holds the table).
+# Either budget sizes a device buffer only: the compact transfer
+# fetches the used prefix (85 kB per 1080p frame,
 # `d2h_bytes_per_frame`, PERF_LEDGER PR 24).
 _VAL_BUDGET_DIV = 24
 
@@ -820,11 +828,18 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
     return (nblk, nval, n_esc, bitmap, bmask16, vals)
 
 
+def block_sparse2_budgets(L: int, budget_div: int = _BLOCK_BUDGET_DIV,
+                          val_div: int = _VAL_BUDGET_DIV) -> tuple[int, int]:
+    """(blocks, values) a flat level vector of length `L` may fill
+    before `_block_sparse_pack2`'s buffers overflow."""
+    return (-(-L // _BLOCK)) // budget_div, L // val_div
+
+
 def block_sparse2_fits(nblk: int, nval: int, n_esc: int, L: int,
                        budget_div: int = _BLOCK_BUDGET_DIV,
                        val_div: int = _VAL_BUDGET_DIV) -> bool:
-    return (int(nblk) <= (-(-L // _BLOCK)) // budget_div
-            and int(nval) <= L // val_div
+    blocks, values = block_sparse2_budgets(L, budget_div, val_div)
+    return (int(nblk) <= blocks and int(nval) <= values
             and int(n_esc) == 0)
 
 

@@ -315,6 +315,19 @@ STAGE_COUNTER_TOTALS = {
     "sfe_frames": REGISTRY.counter(
         "tvt_sfe_frames_total",
         "frames through the split-frame per-frame collect path"),
+    "sparse_blocks_used": REGISTRY.counter(
+        "tvt_sparse_blocks_used_total",
+        "16-coefficient blocks with a level, of the GOPs collected"),
+    "sparse_blocks_budget": REGISTRY.counter(
+        "tvt_sparse_blocks_budget_total",
+        "blocks the sparse transfer buffers of those GOPs hold"),
+    "sparse_values_used": REGISTRY.counter(
+        "tvt_sparse_values_used_total",
+        "non-zero levels of the GOPs collected (a lower bound once "
+        "the blocks overflow)"),
+    "sparse_values_budget": REGISTRY.counter(
+        "tvt_sparse_values_budget_total",
+        "values the sparse transfer buffers of those GOPs hold"),
 }
 
 # -- origin serving (origin/serve.OriginStats + origin/cache) ----------

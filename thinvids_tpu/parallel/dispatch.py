@@ -120,15 +120,22 @@ def default_mesh(devices=None) -> Mesh:
 #: the staging thread when background_stage wraps the generator;
 #: scale = dispatching the device-side ABR downscale that derives
 #: lower ladder rungs from the staged wave (abr/scale.py);
-#: dense_retry = the rare wave-wide dense re-encode + wide fetch when
-#: the sparse budgets overflow — split out of "fetch" so the fetch
-#: number answers only "what does the COMMON bulk transfer cost";
+#: dense_retry = the wave-wide dense re-encode + wide fetch when the
+#: sparse budgets overflow — split out of "fetch" so the fetch number
+#: answers only "what does the COMMON bulk transfer cost" — and, on
+#: the GOP wave path, the sum of its two halves: dense_reencode
+#: (waiting for the dense twin's program, which start_fetch enqueued
+#: ahead of the next wave's) and dense_fetch (what is then left to
+#: wait of the int16 levels' copy to the host). The split-frame
+#: escape fallback interleaves steps, copies and packs per frame and
+#: files them under dense_retry alone;
 #: sfe = the split-frame path's per-frame host leg (band sparse unpack
 #: + band-slice entropy pack + frame assembly) — the host half of the
 #: single-stream glass-to-bitstream latency (SfeShardEncoder))
 STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
-               "fetch", "dense_retry", "sparse_unpack", "unflatten",
-               "pack", "concat", "sfe", "halo")
+               "fetch", "dense_retry", "dense_reencode", "dense_fetch",
+               "sparse_unpack", "unflatten", "pack", "concat", "sfe",
+               "halo")
 
 #: monotonic counters riding in the same snapshot as the stage clocks:
 #: dense_fallback_waves (waves that overflowed the sparse budgets and
@@ -141,9 +148,18 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 #: means every fetch was a single blocking device_get), proc_pack_gops
 #: (GOPs handed to the pack_backend=process sidecars instead of the
 #: thread pool), sfe_frames (frames that crossed the split-frame
-#: per-frame collect path — bands fetched + packed as band slices)
+#: per-frame collect path — bands fetched + packed as band slices),
+#: sparse_{blocks,values}_{used,budget} (how full the sparse transfer
+#: buffers were: blocks with a level and non-zero values counted on
+#: the device, against what the buffers hold, summed over every GOP —
+#: or split-frame band — collected (all-intra waves, which pack by
+#: value alone, are not counted); used / budget over 1 means the wave
+#: went dense. Once the blocks overflow, the value count is a
+#: lower bound: the device counts values in the blocks it kept)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "d2h_bytes",
-                  "fetch_shards", "proc_pack_gops", "sfe_frames")
+                  "fetch_shards", "proc_pack_gops", "sfe_frames",
+                  "sparse_blocks_used", "sparse_blocks_budget",
+                  "sparse_values_used", "sparse_values_budget")
 
 
 class StageProfile:
@@ -205,7 +221,10 @@ class StageProfile:
             self._mirror.bump(counter, n)
 
     @contextlib.contextmanager
-    def stage(self, name: str, **tags):
+    def stage(self, name: str, part_of: str | None = None, **tags):
+        """Time a stage (and record its span). `part_of` names the
+        stage this one is a part of: it receives the same seconds, so
+        the whole stays the sum of its parts."""
         tracer = self._tracer
         t0_wall = time.time() if tracer is not None else 0.0
         t0 = time.perf_counter()
@@ -214,6 +233,8 @@ class StageProfile:
         finally:
             dt = time.perf_counter() - t0
             self.add(name, dt)
+            if part_of is not None:
+                self.add(part_of, dt)
             if tracer is not None:
                 tracer.record(name, t0_wall, dt, **tags)
 
@@ -617,17 +638,19 @@ def _encode_wave_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
 class _WaveFetch:
     """What :meth:`GopShardEncoder.start_fetch` leaves on a dispatched
     wave's handle for :meth:`GopShardEncoder.collect_wave`: the tiny
-    counts, whether the sparse budgets held, and the payload's used
-    prefixes already sliced on the device and on their way to the host.
-    The lock makes the step run once whoever comes first (the dispatch
-    loop, or the wave's own collector thread)."""
+    counts, whether the sparse budgets held, and either the payload's
+    used prefixes already sliced on the device and on their way to the
+    host or, where they did not hold, the dense twin's levels, enqueued
+    and on their way likewise. The lock makes the step run once whoever
+    comes first (the dispatch loop, or the wave's own collector
+    thread)."""
 
-    __slots__ = ("lock", "tiny", "sparse_ok", "payload")
+    __slots__ = ("lock", "tiny", "sparse_ok", "payload", "dense")
 
     def __init__(self) -> None:
         self.lock = threading.Lock()
-        self.tiny = self.payload = None     # tiny is set last: it
-                                            # says the step has run
+        # tiny is set last: it says the step has run
+        self.tiny = self.payload = self.dense = None
         self.sparse_ok = False
 
 
@@ -1140,6 +1163,47 @@ class GopShardEncoder:
              if self.inter else nmb * _INTRA_MB + tail)
         return L, L - nmb * 16 - nmb * 8 - tail
 
+    def _note_sparse_fill(self, nblk, nval, L: int,
+                          budget_div: int = jaxcore._BLOCK_BUDGET_DIV,
+                          val_div: int = jaxcore._VAL_BUDGET_DIV) -> None:
+        """Count how full the sparse transfer buffers of the collected
+        GOPs (or split-frame bands) were, from the counts the host
+        holds anyway: `nblk` / `nval` per GOP against the budgets of a
+        level vector of length `L`. `nval` undercounts once the blocks
+        overflow (_block_sparse_pack2 counts the values of the blocks
+        it kept), so past the block budget the value fill is a lower
+        bound."""
+        blocks, values = jaxcore.block_sparse2_budgets(L, budget_div,
+                                                       val_div)
+        prof, n = self.stages, int(np.size(nblk))
+        prof.bump("sparse_blocks_used", int(np.sum(nblk)))
+        prof.bump("sparse_blocks_budget", blocks * n)
+        prof.bump("sparse_values_used", int(np.sum(nval)))
+        prof.bump("sparse_values_budget", values * n)
+
+    def _enqueue_dense(self, pending: tuple):
+        """Enqueue the wave's dense twin — the same encode, its levels
+        re-emitted whole as int16 — and start their copy to the host.
+        Returns the device array."""
+        _wave, ysd, usd, vsd, qpsd, mbw, mbh, _out, _fetch = pending
+        if self.inter and self.num_devices == 1:
+            dense = _encode_gop_single_dense(
+                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, dtype=jnp.int16,
+                rd=self.rd)
+        elif self.inter:
+            dense = _encode_wave_gop_dense(
+                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, mesh=self.mesh,
+                dtype=jnp.int16, rd=self.rd)
+        else:
+            dense = _encode_wave_dense(
+                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, mesh=self.mesh,
+                dtype=jnp.int16, rd=self.rd)
+        if not self._async_copy_unavailable:
+            # (a platform that rejects it was logged by dispatch_wave)
+            with contextlib.suppress(Exception):
+                dense.copy_to_host_async()
+        return dense
+
     def start_fetch(self, pending: tuple) -> None:
         """First step of collecting a dispatched wave, split out so the
         dispatch loop can run it BEFORE it enqueues the next wave's
@@ -1150,6 +1214,12 @@ class GopShardEncoder:
         compute queue: enqueued after the next wave's program it would
         wait for all of it, and the wave's unpack and pack with it
         (9.4 ms per frame of `fetch` in `hd-backlog`, ledger PR 28).
+        A wave that left the budgets gets its dense twin enqueued here
+        by the same rule: behind the next wave's program the twin would
+        wait for it, and the 199 MB of a 1080p GOP's int16 levels would
+        then cross to the host with the device idle (0.53 s per GOP,
+        40.9 % idle in `hd-grain`, PERF.md §6 PR 30) instead of under
+        the next wave's compute.
         Idempotent, and :meth:`collect_wave` performs it itself for
         callers that have not."""
         _wave, ysd, _usd, _vsd, _qpsd, mbw, mbh, out, fetch = pending
@@ -1175,6 +1245,7 @@ class GopShardEncoder:
                 L, Lr = self._level_sizes(ysd.shape[1], mbw * mbh)
                 if self.inter:
                     nblk, nval, n_esc = tiny[:3]
+                    self._note_sparse_fill(nblk, nval, Lr)
                     fetch.sparse_ok = jaxcore.block_sparse2_fits(
                         nblk.max(), nval.max(), n_esc.max(), Lr)
                     if fetch.sparse_ok and compact:
@@ -1184,6 +1255,8 @@ class GopShardEncoder:
                     nnz, n_esc = tiny
                     fetch.sparse_ok = jaxcore.sparse_fits(
                         nnz.max(), n_esc.max(), L)
+                if not fetch.sparse_ok:
+                    fetch.dense = self._enqueue_dense(pending)
             fetch.tiny = tiny
 
     def collect_wave(self, pending: tuple) -> list[EncodedSegment]:
@@ -1192,7 +1265,7 @@ class GopShardEncoder:
         the pack across the slice pool — or, with pack_backend=process,
         handing whole GOPs to the shared-memory sidecars."""
         self.start_fetch(pending)
-        wave, ysd, usd, vsd, qpsd, mbw, mbh, out, fetch = pending
+        wave, ysd, _usd, _vsd, qpsd, mbw, mbh, out, fetch = pending
         prof = self.stages
         F = ysd.shape[1]
         nmb = mbw * mbh
@@ -1222,24 +1295,22 @@ class GopShardEncoder:
                     bitmap, vals, esc_pos, esc_val = \
                         self._fetch_bulk(out[2:6])
         if not sparse_ok:
-            # Rare wave-wide dense retry: re-encode + wide int16 fetch.
-            # Its own stage (not "fetch") so the fetch number answers
-            # only "what does the common bulk transfer cost", plus a
-            # counter so overflow-prone content is visible in metrics.
+            # Wave-wide dense retry: the dense twin's program and its
+            # wide int16 fetch, both started by start_fetch. Not rare
+            # on grainy footage: white grain of sigma 3.5 at CQP 27
+            # already fills the block budget (jaxcore _VAL_BUDGET_DIV
+            # has the table), and every GOP of such a clip comes
+            # through here. Its own stage (not "fetch") so the fetch
+            # number answers only "what does the common bulk transfer
+            # cost", in two halves so the record says which one this
+            # thread waited for, plus a counter so overflow-prone
+            # content is visible in metrics.
             prof.bump("dense_fallback_waves")
-            with prof.stage("dense_retry"):
-                if self.inter and self.num_devices == 1:
-                    flat = jax.device_get(_encode_gop_single_dense(
-                        ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
-                        dtype=jnp.int16, rd=self.rd))
-                elif self.inter:
-                    flat = jax.device_get(_encode_wave_gop_dense(
-                        ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
-                        mesh=self.mesh, dtype=jnp.int16, rd=self.rd))
-                else:
-                    flat = jax.device_get(_encode_wave_dense(
-                        ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
-                        mesh=self.mesh, dtype=jnp.int16, rd=self.rd))
+            with prof.stage("dense_reencode", part_of="dense_retry"):
+                jax.block_until_ready(fetch.dense)
+            with prof.stage("dense_fetch", part_of="dense_retry"):
+                flat = jax.device_get(fetch.dense)
+                fetch.dense = None      # the device's copy may go
                 prof.bump("d2h_bytes", int(flat.nbytes))
                 if self.inter:
                     # the dense program re-emits levels only; MVs still
@@ -2095,6 +2166,8 @@ class SfeShardEncoder(GopShardEncoder):
                 dense_from = fi         # escape: rerun the GOP dense
                 break
             _, L = self._band_sizes(intra=(fi == 0))
+            # unit budgets (_sfe_pack_band): only an escape overflows
+            self._note_sparse_fill(nblk_h, nval_h, L, 1, 1)
             with prof.stage("fetch"):
                 (head_h,) = self._fetch_bulk([head])
                 rows = self._fetch_payload_rows(payload, used_h)

@@ -252,6 +252,7 @@ class FarmBandEncoder(SfeShardEncoder):
             if dense_from is not None:
                 continue
             _, L = self._band_sizes(intra=(fi == 0))
+            self._note_sparse_fill(nblk_h, nval_h, L, 1, 1)
             with self.stages.stage("fetch"):
                 (head_h,) = self._fetch_bulk([head])
                 rows = self._fetch_payload_rows(payload, used_h)
