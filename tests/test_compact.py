@@ -163,9 +163,9 @@ class TestCompactTransferParity:
         assert snap_old["dense_fallback_waves"] >= 1
 
     def test_dense_retry_is_its_own_stage(self, monkeypatch):
-        # Stage honesty: the dense re-encode must land in dense_retry,
-        # not pollute the fetch number (it used to re-encode the whole
-        # wave inside prof.stage("fetch")).
+        # Stage honesty: the dense fallback's wide fetch must land in
+        # dense_retry, not pollute the fetch number (it used to run
+        # inside prof.stage("fetch")).
         monkeypatch.setattr(jaxcore, "block_sparse2_fits",
                             lambda *a, **k: False)
         frames = _smooth_frames(8)

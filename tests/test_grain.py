@@ -3,8 +3,10 @@
 `tools/pan.make_frames(..., grain=sigma)` adds white grain that is new
 on every frame: no motion search predicts it, every P frame carries a
 dense residual, and from sigma ~3.4 at QP 27 a GOP leaves the sparse
-transfer's block budget and is re-encoded by the dense twin
-(`GopShardEncoder.collect_wave`). Held here, at small sizes on the CPU:
+transfer's block budget and ships its whole int16 levels instead — the
+last output of the wave's one program, which the host fetches only
+then (`GopShardEncoder.start_fetch`, ISSUE 31: no second program).
+Held here, at small sizes on the CPU:
 
 - the served encoder's bytes equal the plain encoder's (`encode_gop`:
   no budgets, no transfer format) for clips in which every GOP falls
@@ -15,7 +17,10 @@ transfer's block budget and is re-encoded by the dense twin
   against both budgets, so a later change to budgets or quantiser that
   moves it is seen;
 - the counters and stages that say so (`sparse_*`, `dense_retry` =
-  `dense_reencode` + `dense_fetch`);
+  `dense_fetch`; `dense_reencode` stays a key and reads 0);
+- a wave that leaves the budgets runs ONE device program, a wave inside
+  them drops the levels before the next is dispatched and fetches not
+  a byte more, and `dispatch_wave` starts no copy of the levels;
 - the benchmark's own copy of the generator gives the same planes.
 """
 
@@ -54,8 +59,8 @@ def _clip(sigmas, seed=3, w=W, h=H, gop=GOP):
 
 
 def _one_chip():
-    """The served one-chip shape: `_encode_gop_single` and its dense
-    twin, one GOP a wave."""
+    """The served one-chip shape: `_encode_gop_single`, one GOP a
+    wave."""
     return default_mesh(jax.devices()[:1])
 
 
@@ -71,6 +76,12 @@ def _plain(frames, segments, qp, rd=None, w=W, h=H, **kw):
     return [encode_gop(frames[s.gop.start_frame:s.gop.end_frame], meta,
                        qp=qp, idr_pic_id=s.gop.index, rd=rd, **kw)
             for s in segments]
+
+
+#: `d2h_bytes` of the clip `_clip((2.0, 0.0))` at QP 27 on one chip, as
+#: the parent of ISSUE 31 (9267aa0) fetched it: counts, MVs, dense DC
+#: prefix and the payloads' used prefixes, two waves
+D2H_BYTES_OF_2_0 = 10518
 
 
 def _went_dense(sigmas):
@@ -94,7 +105,7 @@ class TestServedEqualsPlainAcrossTheCliff:
     @pytest.mark.parametrize("clip", sorted(CLIPS))
     def test_serving_set_on_and_libavcodec_agrees(self, clip, qp):
         """mode_decision + pskip + deblock + AQ 1.0 through the dense
-        twin: same bytes as the plain encoder, and an independent
+        fallback: same bytes as the plain encoder, and an independent
         decoder reproduces the encoder's reconstruction."""
         from thinvids_tpu.tools import oracle
 
@@ -121,8 +132,8 @@ class TestServedEqualsPlainAcrossTheCliff:
 
     def test_sharded_wave_goes_dense_as_a_whole(self):
         """On a mesh a wave is one GOP per device and the budgets are
-        judged on the fullest GOP: one grainy GOP takes the whole wave
-        through `_encode_wave_gop_dense`, same bytes."""
+        judged on the fullest GOP: one grainy GOP takes the whole
+        wave's levels across dense, same bytes."""
         sigmas = (0.0, 6.0, 0.0, 0.0)
         frames = _clip(sigmas)
         enc, segs = _served(frames, 27,
@@ -134,16 +145,18 @@ class TestServedEqualsPlainAcrossTheCliff:
 
 def _true_fill(frames, qp, w, h):
     """(blocks with a level, non-zero values) as shares of one GOP's
-    sparse remainder, counted on the dense twin's levels: what the
-    budgets are set against, with no budget in the way."""
+    sparse remainder, counted on the whole levels the one program
+    leaves on the device: what the budgets are set against, with no
+    budget in the way."""
     pads = [f.padded(16) for f in frames]
     mbw, mbh = pads[0].y.shape[1] // 16, pads[0].y.shape[0] // 16
     nmb = mbw * mbh
     stack = [jnp.asarray(np.stack([getattr(p, k) for p in pads]))[None]
              for k in "yuv"]
-    flat = np.asarray(dispatch._encode_gop_single_dense(
+    flat = np.asarray(dispatch._encode_gop_single(
         *stack, jnp.asarray([qp], jnp.int32), mbw=mbw, mbh=mbh,
-        dtype=jnp.int16))[0]
+        compact=True)[-1])[0]
+    assert flat.dtype == np.int16
     ndc, nlac, ncdc = nmb * 16, nmb * 240, nmb * 8
     rest = np.concatenate([flat[ndc:ndc + nlac], flat[ndc + nlac + ncdc:]])
     nb = -(-rest.size // 16)
@@ -222,8 +235,110 @@ class _Spans:
         return contextlib.nullcontext()
 
 
+def _count_programs(monkeypatch):
+    """Count the calls of every jitted program of `parallel/dispatch`
+    from here on; returns the list the names are appended to."""
+    calls = []
+    for name, program in list(vars(dispatch).items()):
+        if callable(program) and hasattr(program, "lower"):
+            def counted(*a, _name=name, _program=program, **kw):
+                calls.append(_name)
+                return _program(*a, **kw)
+            monkeypatch.setattr(dispatch, name, counted)
+    return calls
+
+
+class _CopySpy:
+    """Stands for one output of a wave's program: records whether a
+    copy to the host was started on it."""
+
+    def __init__(self, array):
+        self.array, self.copied = array, False
+
+    def copy_to_host_async(self):
+        self.copied = True
+
+
+class TestOneProgramPerWave:
+    """ISSUE 31: the levels a wave's program computed are its dense
+    fallback; nothing is encoded twice, and a wave inside the budgets
+    pays nothing across the link for them."""
+
+    @pytest.mark.parametrize("devices", [1, 4])
+    def test_a_wave_that_leaves_the_budgets_runs_one_program(
+            self, devices, monkeypatch):
+        sigmas = (5.0,) + (0.0,) * (devices - 1)
+        frames = _clip(sigmas)
+        meta = VideoMeta(width=W, height=H, num_frames=len(frames))
+        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP,
+                              mesh=default_mesh(jax.devices()[:devices]))
+        (staged,) = enc.stage_waves(frames)
+        calls = _count_programs(monkeypatch)
+        handle = enc.dispatch_wave(staged)
+        enc.start_fetch(handle)
+        fetch = handle[-1]
+        assert not fetch.sparse_ok and fetch.payload is None  # no slice
+        segs = enc.collect_wave(handle)
+        assert calls == ["_encode_gop_single" if devices == 1
+                         else "_encode_wave_gop"]
+        assert enc.stages.snapshot()["dense_fallback_waves"] == 1
+        assert [s.payload for s in segs] == _plain(frames, segs, 27)
+
+    def test_a_wave_inside_the_budgets_drops_the_levels_unfetched(self):
+        frames = _clip((2.0, 0.0))
+        meta = VideoMeta(width=W, height=H, num_frames=len(frames))
+        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
+        first, second = enc.stage_waves(frames)
+        handle = enc.dispatch_wave(first)
+        levels = handle[-1].dense
+        L, _Lr = enc._level_sizes(GOP, (W // 16) * (H // 16))
+        assert levels.shape == (1, L) and levels.dtype == jnp.int16
+        enc.start_fetch(handle)
+        # before the next wave is dispatched the reference is gone
+        assert handle[-1].sparse_ok and handle[-1].dense is None
+        nxt = enc.dispatch_wave(second)
+        segs = enc.collect_wave(handle) + enc.collect_wave(nxt)
+        assert [s.payload for s in segs] == _plain(frames, segs, 27)
+        snap = enc.stages.snapshot()
+        assert snap["dense_fallback_waves"] == 0
+        assert snap["dense_retry"] == 0 and snap["dense_fetch"] == 0
+        # the levels never crossed: the parent (9267aa0) fetched the
+        # same bytes for this clip, 7 % of one GOP's levels
+        assert snap["d2h_bytes"] == D2H_BYTES_OF_2_0
+        assert snap["d2h_bytes"] < levels.nbytes
+
+    @pytest.mark.parametrize("path", ["compact", "sparse2", "intra"])
+    def test_dispatch_starts_no_copy_of_the_levels(self, path, monkeypatch):
+        """`dispatch_wave` prefetches every small output and neither the
+        budget-padded compact payload nor the whole levels: 199 MB per
+        1080p GOP would cross on every wave of every cell."""
+        frames = _clip((0.0,))
+        meta = VideoMeta(width=W, height=H, num_frames=len(frames))
+        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip(),
+                              inter=path != "intra",
+                              compact_transfer=path == "compact")
+        (staged,) = enc.stage_waves(frames)
+        name = "_encode_wave" if path == "intra" else "_encode_gop_single"
+        program = getattr(dispatch, name)
+        monkeypatch.setattr(
+            dispatch, name,
+            lambda *a, **kw: tuple(_CopySpy(x) for x in program(*a, **kw)))
+        handle = enc.dispatch_wave(staged)
+        out, levels = handle[7], handle[-1].dense
+        assert isinstance(levels, _CopySpy) and not levels.copied
+        assert levels.array.dtype == jnp.int16
+        assert levels not in out
+        held_back = {6} if path == "compact" else set()
+        assert [spy.copied for spy in out] == [
+            i not in held_back for i in range(len(out))]
+        assert len(out) == {"compact": 7, "sparse2": 8, "intra": 6}[path]
+
+
 class TestTheRecordSaysWhichHalfCosts:
     def test_dense_retry_is_the_sum_of_its_halves_and_both_are_spans(self):
+        """Since ISSUE 31 there is one half: `dense_fetch`. The key
+        `dense_reencode` stays registered (the benchmark's files read
+        it) and reads 0; no span of that name is recorded."""
         frames = _clip(CLIPS["mixed"])
         meta = VideoMeta(width=W, height=H, num_frames=len(frames))
         enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
@@ -232,34 +347,35 @@ class TestTheRecordSaysWhichHalfCosts:
         enc.encode(frames)
         snap = enc.stages.snapshot()
         assert snap["dense_fallback_waves"] == 2
-        assert snap["dense_reencode"] > 0 and snap["dense_fetch"] > 0
+        assert snap["dense_reencode"] == 0 and snap["dense_fetch"] > 0
         assert snap["dense_retry"] == pytest.approx(
             snap["dense_reencode"] + snap["dense_fetch"], abs=0.02)
         names = [n for n, _d in rec.spans]
-        assert names.count("dense_reencode") == 2
+        assert names.count("dense_reencode") == 0
         assert names.count("dense_fetch") == 2
         # the sparse waves' fetch is not in the retry's number
         assert snap["fetch"] > 0 and names.count("fetch") == 2
 
-    def test_the_twin_is_enqueued_by_start_fetch_before_the_next_wave(self):
-        """The order rule holds for the retry too: once start_fetch
-        has run, the dense twin's levels are on the wave's handle (its
-        program is on the queue ahead of whatever is dispatched next);
-        a wave inside the budgets gets its payload slices and no twin.
-        collect_wave then only waits."""
+    def test_the_levels_are_sent_by_start_fetch_before_the_next_wave(self):
+        """The order rule needs no program for the fallback: the
+        levels are on the wave's handle from dispatch on; start_fetch
+        keeps them (and starts their copy) where the budgets gave way,
+        and drops them where they held, in which case the wave gets
+        its payload slices. collect_wave then only waits."""
         frames = _clip((5.0, 0.0))
         meta = VideoMeta(width=W, height=H, num_frames=len(frames))
         enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
         grainy, clean = enc.stage_waves(frames)
         first = enc.dispatch_wave(grainy)
-        assert first[-1].dense is None and first[-1].tiny is None
+        levels = first[-1].dense
+        assert levels is not None and first[-1].tiny is None
+        assert levels.dtype == jnp.int16 and levels.shape[0] == 1
         enc.start_fetch(first)
-        twin = first[-1].dense
-        assert twin is not None and not first[-1].sparse_ok
-        assert twin.dtype == jnp.int16 and twin.shape[0] == 1
+        assert first[-1].dense is levels and not first[-1].sparse_ok
+        assert first[-1].payload is None
         second = enc.dispatch_wave(clean)
         enc.start_fetch(first)                  # idempotent
-        assert first[-1].dense is twin
+        assert first[-1].dense is levels
         enc.start_fetch(second)
         assert second[-1].sparse_ok and second[-1].dense is None
         assert second[-1].payload is not None

@@ -182,20 +182,14 @@ class TestShardedInterDispatch:
                 idr_pic_id=gop.index))
         assert got == b"".join(parts)
 
-    def test_low_qp_stays_on_sparse_path(self, monkeypatch):
+    def test_low_qp_stays_on_sparse_path(self):
         """Saturated chroma drives intra chroma DC past int8 at QP <= 20
         (measured: |level| up to ~250 at QP 15); with BOTH hadamard DC
         segments shipping dense, a low-QP encode must keep the sparse
-        transfer — the wave-wide dense fallback raising here proves the
-        trap is closed — and stay bit-identical to the reference."""
+        transfer — no wave takes the wave-wide dense fallback, which
+        proves the trap is closed — and stay bit-identical to the
+        reference."""
         from thinvids_tpu.codecs.h264.encoder import encode_gop
-        from thinvids_tpu.parallel import dispatch as dispatch_mod
-
-        def boom(*a, **k):
-            raise AssertionError("dense fallback taken at low QP")
-
-        monkeypatch.setattr(dispatch_mod, "_encode_gop_single_dense", boom)
-        monkeypatch.setattr(dispatch_mod, "_encode_wave_gop_dense", boom)
         # smooth luma (sparse residuals fit the block budget even at low
         # QP) + saturated chroma (its hadamard DC escapes int8)
         w, h, n = 64, 48, 8
@@ -219,7 +213,9 @@ class TestShardedInterDispatch:
             jnp.asarray(15), mbw=w // 16, mbh=h // 16)
         cdc = np.asarray(flat)[nmb * 256:nmb * 264]
         assert np.abs(cdc).max() > 127
-        got = encode_clip_sharded(frames, meta, qp=15, gop_frames=2)
+        enc = GopShardEncoder(meta, qp=15, gop_frames=2)
+        got = concat_segments(enc.encode(frames))
+        assert enc.stages.snapshot()["dense_fallback_waves"] == 0
         plan = plan_segments(n, 2, len(jax.devices()))
         parts = [encode_gop(frames[g.start_frame:g.end_frame], meta,
                             qp=15, idr_pic_id=g.index)

@@ -9,6 +9,7 @@ here: the parity tests against the numpy reference encoder hold the
 bytes (test_inter, test_jaxcore, test_parallel, test_sfe).
 """
 
+import collections
 import functools
 import re
 
@@ -96,18 +97,10 @@ CASES = {
     "gop_single": (
         lambda: _lower_gop(dispatch._encode_gop_single, compact=True),
         GOP | SPARSE),
-    "gop_single_dense": (
-        lambda: _lower_gop(dispatch._encode_gop_single_dense,
-                           dtype=jnp.int16),
-        GOP),
     "wave_gop": (
         lambda: _lower_gop(dispatch._encode_wave_gop, mesh=_gop_mesh(),
                            compact=True),
         GOP | SPARSE),
-    "wave_gop_dense": (
-        lambda: _lower_gop(dispatch._encode_wave_gop_dense,
-                           mesh=_gop_mesh(), dtype=jnp.int16),
-        GOP),
     "sfe_intra": (
         lambda: _lower_sfe(dispatch._sfe_intra_step, False),
         SFE_I | SPARSE),
@@ -180,6 +173,46 @@ def test_step_program_ops_are_filed_under_their_stage(case):
     assert not strays, f"ops outside every stage: {strays[:10]}"
     assert len(unscoped) <= 0.10 * len(paths), \
         f"{len(unscoped)} of {len(paths)} working instructions unscoped"
+
+
+#: the GOP programs whose last output is the GOP's whole int16 levels,
+#: left on the device for the dense fallback (ISSUE 31)
+LEVELS_OUT = {
+    "gop_single": (dispatch._encode_gop_single, lambda: dict(compact=True)),
+    "wave_gop": (dispatch._encode_wave_gop,
+                 lambda: dict(compact=True, mesh=_gop_mesh())),
+    "gop_single_rd": (dispatch._encode_gop_single,
+                      lambda: dict(compact=True, rd=RD_ON)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LEVELS_OUT))
+def test_what_the_levels_output_adds_is_filed_under_a_stage(case):
+    """The same program compiled without its last output is what ran
+    before ISSUE 31: the compiler drops what only that output needs.
+    Every working instruction the output adds carries a `tvt.*` stage
+    (the concatenate of `encode_gop_planes` and the GOP loop's
+    stacking: `tvt.layout`), so no op the SOURCE adds can raise
+    `dev_unscoped_pct`. What the TPU compiler makes of a one-GOP loop's
+    stacking (a zero-fill and a copy with no path at all, PERF.md §6
+    PR 31) is not visible to this CPU compile."""
+    program, more = LEVELS_OUT[case]
+    args, kwargs = _gop_args()
+    inner = program.__wrapped__
+
+    def without_levels(*arrays):
+        return inner(*arrays, **kwargs, **more())[:-1]
+
+    without_levels.__name__ = inner.__name__    # the same `jit(...)` path
+    parent = collections.Counter(_working_paths(
+        jax.jit(without_levels).lower(*args).compile().as_text()))
+    added = collections.Counter(_compiled_paths(case)) - parent
+    assert added and not parent - collections.Counter(_compiled_paths(case))
+    stages = {part for path in added for part in path.split("/")
+              if part.startswith(PREFIX)}
+    assert PREFIX + "layout" in stages
+    unscoped = sorted(path for path in added if PREFIX not in path)
+    assert not unscoped, unscoped
 
 
 def _lower_pack2(budget_div, val_div):

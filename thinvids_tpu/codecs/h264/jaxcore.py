@@ -736,8 +736,8 @@ def _compact_left(shift, *streams):
 # Value-stream budget for the two-tier pack: elementwise nonzero density
 # beyond 1/div falls back dense. It is NOT the budget that goes first.
 # On a pan with white grain that is new on every frame (tools/pan
-# make_frames' `grain`; QP 27, GOP 16, 320x192, counted on the dense
-# twin's levels, PR 30) the blocks with a level / the non-zero values
+# make_frames' `grain`; QP 27, GOP 16, 320x192, counted on a GOP's
+# whole levels, PR 30) the blocks with a level / the non-zero values
 # are 7.8 % / 0.95 % of a GOP's sparse remainder at sigma 0, 20.1 % /
 # 2.05 % at sigma 3, 26.9 % / 2.73 % at 3.5, 34.6 % / 3.65 % at 4 and
 # 48.8 % / 6.26 % at 5: the 25 % block budget (_BLOCK_BUDGET_DIV)

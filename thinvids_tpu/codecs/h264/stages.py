@@ -8,8 +8,8 @@ files each device op under its stage in Perfetto / XProf, and
 
 Each stage is entered at the one place its function is defined, so
 every step program that reaches it (`parallel/dispatch._encode_gop_single`,
-`_encode_wave_gop`, `_sfe_intra_step`, `_sfe_p_step`, their dense and
-farm twins, the XLA mirror) inherits the name. Stages never enclose one
+`_encode_wave_gop`, `_sfe_intra_step`, `_sfe_p_step`, the split-frame
+steps' dense and farm twins, the XLA mirror) inherits the name. Stages never enclose one
 another. The one scope that may enclose a stage is `layout`: it names
 the loops over GOPs and P frames, whose bodies hold the stages; an op's
 stage is the LAST `tvt.*` component of its path.

@@ -301,7 +301,7 @@ WAVES_TOTAL = REGISTRY.counter(
 STAGE_COUNTER_TOTALS = {
     "dense_fallback_waves": REGISTRY.counter(
         "tvt_dense_fallback_waves_total",
-        "waves that overflowed the sparse budgets and re-encoded dense"),
+        "waves that overflowed the sparse budgets and shipped dense"),
     "h2d_bytes": REGISTRY.counter(
         "tvt_h2d_bytes_total", "host-to-device bytes staged"),
     "d2h_bytes": REGISTRY.counter(

@@ -120,15 +120,16 @@ def default_mesh(devices=None) -> Mesh:
 #: the staging thread when background_stage wraps the generator;
 #: scale = dispatching the device-side ABR downscale that derives
 #: lower ladder rungs from the staged wave (abr/scale.py);
-#: dense_retry = the wave-wide dense re-encode + wide fetch when the
-#: sparse budgets overflow — split out of "fetch" so the fetch number
-#: answers only "what does the COMMON bulk transfer cost" — and, on
-#: the GOP wave path, the sum of its two halves: dense_reencode
-#: (waiting for the dense twin's program, which start_fetch enqueued
-#: ahead of the next wave's) and dense_fetch (what is then left to
-#: wait of the int16 levels' copy to the host). The split-frame
-#: escape fallback interleaves steps, copies and packs per frame and
-#: files them under dense_retry alone;
+#: dense_retry = what a wave that left the sparse budgets costs the
+#: host on top of the common path — split out of "fetch" so the fetch
+#: number answers only "what does the COMMON bulk transfer cost". On
+#: the GOP wave path that is dense_fetch alone: waiting for the copy
+#: of the whole int16 levels, which the wave's one program left on the
+#: device and start_fetch sent on their way. No wave runs a second
+#: program, so dense_reencode reads 0; it stays a key because the
+#: benchmark's files still ask for it (PERF.md §7). The split-frame
+#: escape fallback re-runs its steps dense, interleaves steps, copies
+#: and packs per frame and files them under dense_retry alone;
 #: sfe = the split-frame path's per-frame host leg (band sparse unpack
 #: + band-slice entropy pack + frame assembly) — the host half of the
 #: single-stream glass-to-bitstream latency (SfeShardEncoder))
@@ -139,8 +140,8 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 
 #: monotonic counters riding in the same snapshot as the stage clocks:
 #: dense_fallback_waves (waves that overflowed the sparse budgets and
-#: re-encoded dense), h2d_bytes (host→device bytes uploaded while
-#: staging waves — the ABR ladder's proof that decode+upload happens
+#: shipped their levels dense), h2d_bytes (host→device bytes uploaded
+#: while staging waves — the ABR ladder's proof that decode+upload happens
 #: ONCE per wave regardless of rung count: lower rungs derive on
 #: device, so this must not scale with rungs), d2h_bytes (actual
 #: device→host bytes fetched — the benchmark's d2h_bytes_per_frame is
@@ -449,7 +450,15 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
     With `compact` the three sparse streams additionally fold into one
     contiguous byte payload on device (jaxcore._compact_stream), so the
     output is (mv8, dense, nblk, nval, n_esc, used, payload) — 7 arrays
-    — instead of the 8-array (…, bitmap, bmask16, vals) layout."""
+    — instead of the 8-array (…, bitmap, bmask16, vals) layout.
+
+    The LAST output, after those, is `flat` itself: the GOP's whole
+    int16 levels, as encode_gop_planes built them. It stays on the
+    device (dispatch_wave starts no copy of it) and start_fetch either
+    drops it — the budgets held, which is every wave of ordinary
+    content — or sends it to the host as the dense fallback: a wave
+    that leaves the budgets ships what its one program already
+    computed, and nothing is encoded twice."""
     from ..codecs.h264 import jaxinter
 
     mv8, flat = jaxinter.encode_gop_planes(y, u, v, qp, mbw=mbw, mbh=mbh,
@@ -471,19 +480,10 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
     nblk, nval, n_esc, bitmap, bmask16, vals = \
         jaxcore._block_sparse_pack2(rest)
     if not compact:
-        return (mv8, dense, nblk, nval, n_esc, bitmap, bmask16, vals)
+        return (mv8, dense, nblk, nval, n_esc, bitmap, bmask16, vals, flat)
     used, payload = jaxcore._compact_stream(nblk, nval, bitmap, bmask16,
                                             vals)
-    return (mv8, dense, nblk, nval, n_esc, used, payload)
-
-
-def _per_gop_dense(y, u, v, qp, mbw: int, mbh: int, dtype, rd=RD_OFF):
-    from ..codecs.h264 import jaxinter
-
-    _mv8, flat = jaxinter.encode_gop_planes(y, u, v, qp, mbw=mbw, mbh=mbh,
-                                            rd=rd)
-    with stage("layout"):
-        return flat.astype(dtype)
+    return (mv8, dense, nblk, nval, n_esc, used, payload, flat)
 
 
 @stage("layout")
@@ -522,7 +522,7 @@ def _encode_wave_gop(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
     shard = shard_map(
         per_dev, mesh=mesh,
         in_specs=(P("gop"),) * 4,
-        out_specs=(P("gop"),) * (7 if compact else 8),
+        out_specs=(P("gop"),) * (8 if compact else 9),
     )
     return shard(ys, us, vs, qps)
 
@@ -545,36 +545,6 @@ def _encode_gop_single(ys, us, vs, qps, *, mbw: int, mbh: int,
     return _map_gops(one, (ys, us, vs, qps))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("mbw", "mbh", "dtype", "rd"))
-def _encode_gop_single_dense(ys, us, vs, qps, *, mbw: int, mbh: int, dtype,
-                             rd=RD_OFF):
-    def one(args):
-        y, u, v, qp = args
-        return _per_gop_dense(y, u, v, qp, mbw, mbh, dtype, rd=rd)
-    return _map_gops(one, (ys, us, vs, qps))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("mbw", "mbh", "mesh", "dtype", "rd"))
-def _encode_wave_gop_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
-                           dtype, rd=RD_OFF):
-    """Dense fallback for the GOP wave: (G, L) levels in `dtype`."""
-
-    def per_dev(y_g, u_g, v_g, qp_g):
-        def one(args):
-            y, u, v, qp = args
-            return _per_gop_dense(y, u, v, qp, mbw, mbh, dtype, rd=rd)
-        return _map_gops(one, (y_g, u_g, v_g, qp_g))
-
-    shard = shard_map(
-        per_dev, mesh=mesh,
-        in_specs=(P("gop"),) * 4,
-        out_specs=P("gop"),
-    )
-    return shard(ys, us, vs, qps)
-
-
 @functools.partial(jax.jit, static_argnames=("mbw", "mbh", "mesh", "rd"))
 def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
                  rd=RD_OFF):
@@ -585,7 +555,10 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
 
     Returns per-frame sparse-packed levels (jaxcore._sparse_pack — ~10x
     fewer device→host bytes than raw int32) with leading (G, F) dims;
-    the host checks the nnz/escape counts for the rare dense fallback.
+    the host checks the nnz/escape counts for the rare dense fallback,
+    which fetches the last output: the levels themselves as int16
+    (covers the full CAVLC level range), (G, F, L), left on the device
+    like the GOP programs' (_per_gop_sparse).
     """
 
     def per_gop(y_g, u_g, v_g, qp_g):
@@ -593,8 +566,10 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
         def one(y_f, u_f, v_f, qp1):
             def per_frame(planes):
                 y, u, v = planes
-                return jaxcore._sparse_pack(
-                    _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd))
+                flat = _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd)
+                with stage("layout"):
+                    flat16 = flat.astype(jnp.int16)
+                return jaxcore._sparse_pack(flat) + (flat16,)
 
             return _map_gops(per_frame, (y_f, u_f, v_f))
 
@@ -603,54 +578,32 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
     shard = shard_map(
         per_gop, mesh=mesh,
         in_specs=(P("gop"),) * 4,
-        out_specs=(P("gop"),) * 6,
-    )
-    return shard(ys, us, vs, qps)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("mbw", "mbh", "mesh", "dtype", "rd"))
-def _encode_wave_dense(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
-                       dtype, rd=RD_OFF):
-    """Dense fallback: (G, F, L) levels in `dtype` (int16 covers the full
-    CAVLC level range), at the same per-GOP QPs as the sparse pass."""
-
-    def per_gop(y_g, u_g, v_g, qp_g):
-        def one(y_f, u_f, v_f, qp1):
-            def per_frame(planes):
-                y, u, v = planes
-                return _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd)
-
-            return _map_gops(per_frame, (y_f, u_f, v_f))
-
-        levels = jax.vmap(one)(y_g, u_g, v_g, qp_g)
-        with stage("layout"):
-            return levels.astype(dtype)
-
-    shard = shard_map(
-        per_gop, mesh=mesh,
-        in_specs=(P("gop"),) * 4,
-        out_specs=P("gop"),
+        out_specs=(P("gop"),) * 7,
     )
     return shard(ys, us, vs, qps)
 
 
 class _WaveFetch:
-    """What :meth:`GopShardEncoder.start_fetch` leaves on a dispatched
-    wave's handle for :meth:`GopShardEncoder.collect_wave`: the tiny
-    counts, whether the sparse budgets held, and either the payload's
-    used prefixes already sliced on the device and on their way to the
-    host or, where they did not hold, the dense twin's levels, enqueued
-    and on their way likewise. The lock makes the step run once whoever
-    comes first (the dispatch loop, or the wave's own collector
-    thread)."""
+    """The fetch state of a dispatched wave, on its handle between
+    :meth:`GopShardEncoder.dispatch_wave`,
+    :meth:`GopShardEncoder.start_fetch` and
+    :meth:`GopShardEncoder.collect_wave`. From dispatch: `dense`, the
+    wave's whole int16 levels — the last output of its one program,
+    left on the device with no copy started. From start_fetch: the
+    tiny counts, whether the sparse budgets held, and either the
+    payload's used prefixes already sliced on the device and on their
+    way to the host (`dense` is then dropped and its HBM goes back) or,
+    where the budgets did not hold, `dense` on its way likewise. The
+    lock makes the step run once whoever comes first (the dispatch
+    loop, or the wave's own collector thread)."""
 
     __slots__ = ("lock", "tiny", "sparse_ok", "payload", "dense")
 
-    def __init__(self) -> None:
+    def __init__(self, dense) -> None:
         self.lock = threading.Lock()
         # tiny is set last: it says the step has run
-        self.tiny = self.payload = self.dense = None
+        self.tiny = self.payload = None
+        self.dense = dense
         self.sparse_ok = False
 
 
@@ -893,6 +846,12 @@ class GopShardEncoder:
             else:
                 out = _encode_wave(ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
                                    mesh=self.mesh, rd=self.rd)
+            # The last output is the wave's whole int16 levels (199 MB
+            # per 1080p GOP): it goes onto the handle, not among the
+            # outputs the sparse path indexes, and NO copy of it is
+            # started here — start_fetch drops it or sends it once the
+            # counts say which.
+            *out, dense = out
             if not self._async_copy_unavailable:
                 for i, arr in enumerate(out):
                     # Start the device->host copies now, overlapped with
@@ -915,7 +874,8 @@ class GopShardEncoder:
                             "device→host prefetch disabled for this "
                             "encoder", type(exc).__name__, exc)
                         break
-            return (wave, ysd, usd, vsd, qpsd, mbw, mbh, out, _WaveFetch())
+            return (wave, ysd, usd, vsd, qpsd, mbw, mbh, out,
+                    _WaveFetch(dense))
 
     def _new_pack_pool(self):
         """This encoder's slice-pack pool (threads spawn on demand up
@@ -1040,12 +1000,16 @@ class GopShardEncoder:
         enqueues it before the next wave's program. Returns the sliced
         device arrays for :meth:`_gather_payload_rows`."""
         parts = [d[:, :m] for d, m in self._payload_cuts(payload, used)]
-        if not self._async_copy_unavailable:
-            # (a platform that rejects it was logged by dispatch_wave)
-            with contextlib.suppress(Exception):
-                for part in parts:
-                    part.copy_to_host_async()
+        self._start_copies(parts)
         return parts
+
+    def _start_copies(self, arrays) -> None:
+        """Start the device→host copies of `arrays` (a platform that
+        rejects async copies was logged by dispatch_wave)."""
+        if not self._async_copy_unavailable:
+            with contextlib.suppress(Exception):
+                for arr in arrays:
+                    arr.copy_to_host_async()
 
     def _gather_payload_rows(self, parts: list) -> list[np.ndarray]:
         """Host side of :meth:`_slice_payload_rows`: a 1-D uint8 row
@@ -1181,29 +1145,6 @@ class GopShardEncoder:
         prof.bump("sparse_values_used", int(np.sum(nval)))
         prof.bump("sparse_values_budget", values * n)
 
-    def _enqueue_dense(self, pending: tuple):
-        """Enqueue the wave's dense twin — the same encode, its levels
-        re-emitted whole as int16 — and start their copy to the host.
-        Returns the device array."""
-        _wave, ysd, usd, vsd, qpsd, mbw, mbh, _out, _fetch = pending
-        if self.inter and self.num_devices == 1:
-            dense = _encode_gop_single_dense(
-                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, dtype=jnp.int16,
-                rd=self.rd)
-        elif self.inter:
-            dense = _encode_wave_gop_dense(
-                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, mesh=self.mesh,
-                dtype=jnp.int16, rd=self.rd)
-        else:
-            dense = _encode_wave_dense(
-                ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh, mesh=self.mesh,
-                dtype=jnp.int16, rd=self.rd)
-        if not self._async_copy_unavailable:
-            # (a platform that rejects it was logged by dispatch_wave)
-            with contextlib.suppress(Exception):
-                dense.copy_to_host_async()
-        return dense
-
     def start_fetch(self, pending: tuple) -> None:
         """First step of collecting a dispatched wave, split out so the
         dispatch loop can run it BEFORE it enqueues the next wave's
@@ -1214,12 +1155,15 @@ class GopShardEncoder:
         compute queue: enqueued after the next wave's program it would
         wait for all of it, and the wave's unpack and pack with it
         (9.4 ms per frame of `fetch` in `hd-backlog`, ledger PR 28).
-        A wave that left the budgets gets its dense twin enqueued here
-        by the same rule: behind the next wave's program the twin would
-        wait for it, and the 199 MB of a 1080p GOP's int16 levels would
-        then cross to the host with the device idle (0.53 s per GOP,
-        40.9 % idle in `hd-grain`, PERF.md §6 PR 30) instead of under
-        the next wave's compute.
+        The wave's whole int16 levels are on the device already, the
+        last output of its one program (:class:`_WaveFetch`). Budgets
+        held: the reference is dropped here, so the HBM goes back
+        before the next wave's program is enqueued and the levels never
+        cross. Budgets left: their copy to the host is started here —
+        a transfer, nothing on the compute queue — and the 199 MB of a
+        1080p GOP cross under the next wave's compute (with the device
+        idle they cost 0.53 s per GOP, PERF.md §6 PR 30). No wave is
+        encoded twice.
         Idempotent, and :meth:`collect_wave` performs it itself for
         callers that have not."""
         _wave, ysd, _usd, _vsd, _qpsd, mbw, mbh, out, fetch = pending
@@ -1255,8 +1199,10 @@ class GopShardEncoder:
                     nnz, n_esc = tiny
                     fetch.sparse_ok = jaxcore.sparse_fits(
                         nnz.max(), n_esc.max(), L)
-                if not fetch.sparse_ok:
-                    fetch.dense = self._enqueue_dense(pending)
+                if fetch.sparse_ok:
+                    fetch.dense = None
+                else:
+                    self._start_copies([fetch.dense])
             fetch.tiny = tiny
 
     def collect_wave(self, pending: tuple) -> list[EncodedSegment]:
@@ -1295,26 +1241,22 @@ class GopShardEncoder:
                     bitmap, vals, esc_pos, esc_val = \
                         self._fetch_bulk(out[2:6])
         if not sparse_ok:
-            # Wave-wide dense retry: the dense twin's program and its
-            # wide int16 fetch, both started by start_fetch. Not rare
-            # on grainy footage: white grain of sigma 3.5 at CQP 27
-            # already fills the block budget (jaxcore _VAL_BUDGET_DIV
-            # has the table), and every GOP of such a clip comes
-            # through here. Its own stage (not "fetch") so the fetch
-            # number answers only "what does the common bulk transfer
-            # cost", in two halves so the record says which one this
-            # thread waited for, plus a counter so overflow-prone
-            # content is visible in metrics.
+            # Wave-wide dense fallback: the wide int16 fetch of the
+            # levels the wave's program left on the device, started by
+            # start_fetch. Not rare on grainy footage: white grain of
+            # sigma 3.5 at CQP 27 already fills the block budget
+            # (jaxcore _VAL_BUDGET_DIV has the table), and every GOP of
+            # such a clip comes through here. Its own stage (not
+            # "fetch") so the fetch number answers only "what does the
+            # common bulk transfer cost", plus a counter so
+            # overflow-prone content is visible in metrics.
             prof.bump("dense_fallback_waves")
-            with prof.stage("dense_reencode", part_of="dense_retry"):
-                jax.block_until_ready(fetch.dense)
             with prof.stage("dense_fetch", part_of="dense_retry"):
                 flat = jax.device_get(fetch.dense)
                 fetch.dense = None      # the device's copy may go
                 prof.bump("d2h_bytes", int(flat.nbytes))
                 if self.inter:
-                    # the dense program re-emits levels only; MVs still
-                    # come from the already-computed sparse outputs
+                    # MVs come from the sparse outputs, as ever
                     (mv8,) = self._fetch_bulk(out[0:1])
         # Header QP must match what the device QUANTIZED with — read it
         # from the staged per-wave array, not the live gop_qp dict (a
