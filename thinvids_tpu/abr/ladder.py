@@ -239,6 +239,16 @@ class LadderShardEncoder:
             enc.plan_override = plan
 
     @property
+    def scene_cuts(self) -> tuple[int, ...] | None:
+        return self._stager.scene_cuts
+
+    @scene_cuts.setter
+    def scene_cuts(self, cuts: tuple[int, ...] | None) -> None:
+        # one list from the source: every rung plans the same GOPs
+        for enc in self._all_encoders():
+            enc.scene_cuts = cuts
+
+    @property
     def gop_index_offset(self) -> int:
         return self._stager.gop_index_offset
 

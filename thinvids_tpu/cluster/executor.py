@@ -202,11 +202,11 @@ class LocalExecutor:
         stage[0] = "segment"
         enc = self._encoder_factory(meta, settings, self.mesh)
         self._bind_trace(job, enc)
-        plan = enc.plan(len(frames))
+        plan, cut_note = self._plan_on_cuts(enc, frames, settings)
         co.update_progress(job.id, token, parts_total=plan.num_gops,
                            segment_progress=100.0)
         co.heartbeat_job(job.id, token, stage[0], host=self.host,
-                         note=f"{plan.num_gops} GOPs planned")
+                         note=f"{plan.num_gops} GOPs planned{cut_note}")
 
         stage[0] = "encode"
         target_kbps = float(settings.get("target_bitrate_kbps", 0.0))
@@ -218,6 +218,52 @@ class LocalExecutor:
                                                settings)
         self._emit_stage_breakdown(job, enc)
         return segments
+
+    @staticmethod
+    def _scene_cuts(frames, settings, stages):
+        """The `scenecut` setting's look at the source, in the
+        `segment` stage: (cuts taken, cuts suppressed) from one read of
+        its luma under the stage clock and span `scenecut` of `stages`
+        (a StageProfile), or None where the setting is off or the
+        job's shape keeps a fixed GOP grid (planner.plan_encode says
+        which). The device idles meanwhile: the plan needs the whole
+        list before the first wave is staged."""
+        from ..parallel.planner import plan_shape
+
+        threshold = int(settings.get("scenecut", 0) or 0)
+        if threshold <= 0 or plan_shape(settings) != "gop":
+            return None
+        from ..parallel import scenecut
+
+        with stages.stage("scenecut"):
+            return scenecut.detect(frames, int(settings.gop_frames),
+                                   threshold)
+
+    def _plan_on_cuts(self, enc, frames, settings):
+        """(plan, heartbeat note) of a GOP-shape job: hand the encoder
+        the source's scene cuts, ask it for the plan, and count what
+        became of them (`scene_cuts`: cuts that start a GOP of the
+        plan; `scene_cuts_suppressed`: the rest, too close to the last
+        one or over the segment cap)."""
+        stages = getattr(enc, "stages", None)
+        found = self._scene_cuts(frames, settings, stages) \
+            if stages is not None else None
+        if found is None:
+            return enc.plan(len(frames)), ""
+        enc.scene_cuts = found[0]
+        plan = enc.plan(len(frames))
+        return plan, self._count_cuts(stages, plan, *found)
+
+    @staticmethod
+    def _count_cuts(stages, plan, cuts, suppressed: int) -> str:
+        """Bump the two cut counters for a plan made on `cuts` and
+        return the heartbeat's words for it."""
+        starts = {g.start_frame for g in plan.gops}
+        taken = sum(1 for c in cuts if c in starts)
+        stages.bump("scene_cuts", taken)
+        stages.bump("scene_cuts_suppressed",
+                    suppressed + len(cuts) - taken)
+        return f", {taken} scene cuts"
 
     def _encode_ladder(self, job: Job, token: str, frames, settings,
                        meta, stage: list):
@@ -241,12 +287,12 @@ class LocalExecutor:
         rungs = plan_ladder(meta, settings)
         enc = make_shard_encoder(meta, settings, self.mesh, rungs=rungs)
         self._bind_trace(job, enc)
-        plan = enc.plan(len(frames))
+        plan, cut_note = self._plan_on_cuts(enc, frames, settings)
         co.update_progress(job.id, token, parts_total=plan.num_gops,
                            segment_progress=100.0)
         co.heartbeat_job(
             job.id, token, stage[0], host=self.host,
-            note=f"{plan.num_gops} GOPs x {len(rungs)} rungs")
+            note=f"{plan.num_gops} GOPs x {len(rungs)} rungs{cut_note}")
 
         stage[0] = "encode"
         # no elastic replan for ladders: a mesh change mid-job would
@@ -605,8 +651,11 @@ class LocalExecutor:
         replanning: a mesh change mid-pass would change the GOP count
         under the QP solver and orphan the per-GOP QP map.
         """
+        from ..parallel.planner import suffix_cuts
+
         co = self.coordinator
         total_gops = enc.plan(len(frames)).num_gops
+        cuts = getattr(enc, "scene_cuts", None)     # of the whole clip
         segments: list = []
         start_frame = 0
         shrink_attempt = 0
@@ -629,6 +678,9 @@ class LocalExecutor:
                 # collect in order); resume after it on the new mesh
                 start_frame = max(
                     (s.gop.end_frame for s in segments), default=0)
+                if cuts is not None:
+                    # the later cuts go with the suffix: no second look
+                    shrunk.scene_cuts = suffix_cuts(cuts, start_frame)
                 # the suffix re-plans with a different device count, so
                 # the GOP total changes — keep progress honest
                 total_gops = len(segments) + shrunk.plan(

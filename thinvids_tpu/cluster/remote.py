@@ -180,6 +180,10 @@ class Shard:
     band_count: int = 0
     total_bands: int = 0
     halo_rows: int = 0
+    #: the plan was made on scene cuts (SegmentPlan.pin_frames): the
+    #: worker stages every GOP to `gop_frames`, one program shape for
+    #: every shard however the shots fall
+    pin_frames: bool = False
     #: hosts that rejected this shard's shape (old workers): the claim
     #: never offers it to them again, so an unsupported rejection
     #: cannot ping-pong
@@ -269,6 +273,11 @@ class Shard:
         if self.rung:
             desc["rung"] = {"name": self.rung, "width": self.rung_width,
                             "height": self.rung_height}
+        if self.pin_frames:
+            # only where set: the wire form of every other shard is
+            # unchanged, and a worker that does not know the key pads
+            # to the shard's longest GOP — the same bytes
+            desc["pin_frames"] = True
         if self.shape != "gop":
             # explicit shape tag ONLY for new shapes: a GOP-range
             # shard's wire form is unchanged, so a rolling upgrade
@@ -1259,14 +1268,18 @@ class RemoteExecutor(LocalExecutor):
         active = reg.active(float(snap.metrics_ttl_s), now=self._clock())
         return [w for w in active if w.metrics.get("worker")]
 
-    def _plan_remote(self, num_frames: int, settings) -> SegmentPlan:
+    def _plan_remote(self, num_frames: int, settings,
+                     cuts=None) -> SegmentPlan:
+        """The farm's one GOP plan: the local planner's, so shards see
+        the GOPs a local encode would — scene cuts (`cuts`) included."""
         from ..parallel.planner import plan_segments
 
         workers = self._live_workers()
         plan_devices = int(settings.get("remote_plan_devices", 0)) \
             or max(1, len(workers))
         return plan_segments(num_frames, int(settings.gop_frames),
-                             plan_devices, int(settings.max_segments))
+                             plan_devices, int(settings.max_segments),
+                             cuts=cuts)
 
     def _shards_for(self, job: Job, meta, plan: SegmentPlan, settings,
                     qp: int, rung=None, token: str = "") -> list[Shard]:
@@ -1310,6 +1323,7 @@ class RemoteExecutor(LocalExecutor):
                 job_id=job.id, input_path=job.input_path, meta=meta,
                 gops=tuple(gops), qp=int(qp),
                 gop_frames=int(settings.gop_frames),
+                pin_frames=plan.pin_frames,
                 # lease scales with shard size: a 100-GOP shard must
                 # not be failure-counted on a single-GOP budget (dead
                 # workers are swept by heartbeat TTL long before any
@@ -1323,12 +1337,12 @@ class RemoteExecutor(LocalExecutor):
         return shards
 
     def _build_shards(self, job: Job, meta, num_frames: int,
-                      settings, token: str = ""
+                      settings, token: str = "", cuts=None
                       ) -> tuple[SegmentPlan, list[Shard]]:
         if self._band_shape(job, settings):
             return self._build_band_shards(job, meta, num_frames,
                                            settings, token=token)
-        plan = self._plan_remote(num_frames, settings)
+        plan = self._plan_remote(num_frames, settings, cuts)
         return plan, self._shards_for(job, meta, plan, settings,
                                       qp=int(settings.qp), token=token)
 
@@ -1444,11 +1458,15 @@ class RemoteExecutor(LocalExecutor):
             fields.extend(["band", str(sfe_bands),
                            str(int(settings.get("sfe_halo_rows", 32)
                                    or 32))])
+        # likewise the scene-cut threshold: it moves GOP boundaries
+        scenecut = int(settings.get("scenecut", 0) or 0)
+        if scenecut > 0:
+            fields.extend(["scenecut", str(scenecut)])
         return hashlib.sha256("|".join(fields).encode()).hexdigest()[:16]
 
     @staticmethod
-    def _plan_record(sig: str, plan: SegmentPlan,
-                     shards: list[Shard]) -> dict[str, Any]:
+    def _plan_record(sig: str, plan: SegmentPlan, shards: list[Shard],
+                     cuts=None) -> dict[str, Any]:
         """JSON-able form of one deterministic shard plan — what the
         board checkpoint journals so a restarted coordinator re-plans
         from the RECORD, not from whatever worker count happens to be
@@ -1461,6 +1479,11 @@ class RemoteExecutor(LocalExecutor):
             "sig": sig,
             "gop_frames": int(plan.frames_per_gop),
             "num_devices": int(plan.num_devices),
+            # the scene cuts the GOPs were planned on (None: scenecut
+            # off), as planner.EncodePlan.record() carries them: the
+            # rows below are the plan, these say why it looks so
+            "cuts": None if cuts is None else [int(c) for c in cuts],
+            "pin_frames": bool(plan.pin_frames),
             "plan_gops": gop_rows(plan.gops),
             "shards": [{
                 "key": s.key, "qp": int(s.qp),
@@ -1493,9 +1516,10 @@ class RemoteExecutor(LocalExecutor):
                          for i, s, n, idr in rows)
 
         gop_frames = int(rec.get("gop_frames", settings.gop_frames))
+        pin = bool(rec.get("pin_frames", False))
         plan = SegmentPlan(gops=gops_of(rec["plan_gops"]),
                            num_devices=int(rec.get("num_devices", 1)),
-                           frames_per_gop=gop_frames)
+                           frames_per_gop=gop_frames, pin_frames=pin)
         priority = job_rank(
             getattr(job, "job_type", "transcode"),
             str(settings.get("job_priority", "auto") or "auto"))
@@ -1508,7 +1532,7 @@ class RemoteExecutor(LocalExecutor):
                 id=f"{job.id[:12]}-{run}{key}", key=key,
                 job_id=job.id, input_path=job.input_path, meta=meta,
                 gops=gops_of(srec["gops"]), qp=int(srec["qp"]),
-                gop_frames=gop_frames,
+                gop_frames=gop_frames, pin_frames=pin,
                 timeout_s=float(srec["timeout_s"]),
                 rung=str(srec.get("rung", "")),
                 rung_width=int(srec.get("rung_width", 0)),
@@ -1523,7 +1547,7 @@ class RemoteExecutor(LocalExecutor):
         return plan, shards
 
     def _plan_or_resume(self, job: Job, token: str, settings, meta,
-                        num_frames: int, rungs=None
+                        num_frames: int, rungs=None, find_cuts=None
                         ) -> tuple[SegmentPlan, list[Shard], int]:
         """The RESUME path `recover_jobs` grew: when a durable board
         checkpoint exists for this job and its plan signature still
@@ -1531,7 +1555,9 @@ class RemoteExecutor(LocalExecutor):
         every recorded part against its digests, rehydrate the
         verified ones as DONE under the fresh run token, and leave
         only the remainder PENDING. Otherwise plan fresh (waiting for
-        the farm as usual) and anchor a new checkpoint. Returns
+        the farm as usual; on the scene cuts `find_cuts()` returns,
+        where given — a resumed plan has its GOPs on record and looks
+        for none) and anchor a new checkpoint. Returns
         (plan, shards, reused_count)."""
         co = self.coordinator
         sig = self._plan_signature(job, settings, rungs=rungs)
@@ -1548,17 +1574,19 @@ class RemoteExecutor(LocalExecutor):
                                                     settings, token)
         else:
             self._await_first_workers(job, token, settings)
+            cuts = find_cuts() if find_cuts is not None else None
             if rungs is None:
                 plan, shards = self._build_shards(job, meta, num_frames,
-                                                  settings, token=token)
+                                                  settings, token=token,
+                                                  cuts=cuts)
             else:
-                plan = self._plan_remote(num_frames, settings)
+                plan = self._plan_remote(num_frames, settings, cuts)
                 shards = []
                 for rung in rungs:
                     shards.extend(self._shards_for(
                         job, meta, plan, settings, qp=rung.qp,
                         rung=rung, token=token))
-            rec = self._plan_record(sig, plan, shards)
+            rec = self._plan_record(sig, plan, shards, cuts)
         refs = parts.begin_job(job.id, rec)
         reused = 0
         if resume and shards and shards[0].shape == "band":
@@ -1679,8 +1707,8 @@ class RemoteExecutor(LocalExecutor):
                                        meta, stage)
 
         stage[0] = "segment"
-        plan, shards, reused = self._plan_or_resume(
-            job, token, settings, meta, len(frames))
+        plan, shards, reused, cut_note = self._plan_farm(
+            job, token, settings, meta, frames)
         banded = bool(shards) and shards[0].shape == "band"
         parts_total = plan.num_gops * (len(shards) if banded else 1)
         co.update_progress(job.id, token, parts_total=parts_total,
@@ -1690,7 +1718,8 @@ class RemoteExecutor(LocalExecutor):
                     f"slices (farm SFE, {shards[0].total_bands} bands)")
             act = note
         else:
-            note = f"{plan.num_gops} GOPs in {len(shards)} shards"
+            note = (f"{plan.num_gops} GOPs in {len(shards)} shards"
+                    f"{cut_note}")
             act = f"{plan.num_gops} GOPs as {len(shards)} shards"
         co.heartbeat_job(job.id, token, stage[0], host=self.host,
                          note=note)
@@ -1708,6 +1737,37 @@ class RemoteExecutor(LocalExecutor):
                         for seg in shard.segments]
         segments.sort(key=lambda s: s.gop.index)
         return segments
+
+    def _plan_farm(self, job: Job, token: str, settings, meta, frames,
+                   rungs=None):
+        """`_plan_or_resume` for a job whose GOPs may follow its scene
+        cuts: where the `scenecut` setting is on and the job's shape
+        takes cuts (`_scene_cuts`), a FRESH plan looks for them here,
+        at the coordinator (one list, so every shard and every rung
+        sees the same GOPs; stage and span `scenecut`, the two cut
+        counters).
+        Returns (plan, shards, reused, the heartbeat's words for the
+        cuts)."""
+        stages = found = None
+
+        def find_cuts():
+            nonlocal stages, found
+            from ..parallel.dispatch import job_stage_profile
+
+            stages = job_stage_profile()
+            stages.set_tracer(obs_trace.TRACE.recorder(job.id,
+                                                       host=self.host))
+            found = self._scene_cuts(frames, settings, stages)
+            return None if found is None else found[0]
+
+        # (with the setting off the coordinator imports no encoder)
+        wanted = int(settings.get("scenecut", 0) or 0) > 0
+        plan, shards, reused = self._plan_or_resume(
+            job, token, settings, meta, len(frames), rungs=rungs,
+            find_cuts=find_cuts if wanted else None)
+        note = "" if found is None \
+            else self._count_cuts(stages, plan, *found)
+        return plan, shards, reused, note
 
     def _drain_board(self, job: Job, token: str, settings,
                      shards: list[Shard]) -> list[Shard]:
@@ -1901,15 +1961,15 @@ class RemoteExecutor(LocalExecutor):
 
         stage[0] = "segment"
         rungs = plan_ladder(meta, settings)
-        plan, shards, reused = self._plan_or_resume(
-            job, token, settings, meta, len(frames), rungs=rungs)
+        plan, shards, reused, cut_note = self._plan_farm(
+            job, token, settings, meta, frames, rungs=rungs)
         total_parts = plan.num_gops * len(rungs)
         co.update_progress(job.id, token, parts_total=total_parts,
                            segment_progress=100.0)
         co.heartbeat_job(
             job.id, token, stage[0], host=self.host,
             note=f"{plan.num_gops} GOPs x {len(rungs)} rungs in "
-                 f"{len(shards)} shards")
+                 f"{len(shards)} shards{cut_note}")
         co.activity.emit(
             "shard", f"dispatching {plan.num_gops} GOPs x {len(rungs)} "
             f"rungs as {len(shards)} shards to the worker farm"
@@ -2093,7 +2153,8 @@ def encode_shard(desc: Mapping[str, Any], frames, mesh=None, tracer=None,
         enc.stages.set_tracer(tracer)
     enc.plan_override = SegmentPlan(
         gops=gops, num_devices=enc.num_devices,
-        frames_per_gop=int(desc.get("gop_frames", 32)))
+        frames_per_gop=int(desc.get("gop_frames", 32)),
+        pin_frames=bool(desc.get("pin_frames", False)))
     enc.gop_index_offset = int(desc["gop_index_offset"])
     enc.frame_offset = int(desc["start_frame"])
     f0 = int(desc["start_frame"])

@@ -53,6 +53,13 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     # segmentation / sharding
     "gop_frames": 32,                # closed-GOP length (frames)
     "max_segments": 200,
+    # scenecut (TVT_SCENECUT, 0..100, x264's name and scale; 0 = off):
+    # a frame whose inter cost is not at least this share below its
+    # intra cost starts a new shot, and a shot starts a closed GOP
+    # (parallel/scenecut.py costs the frames, planner.take_cuts
+    # decides, plan_segments places the GOPs). GOP-shape jobs and
+    # ladders; band and live jobs keep their fixed grid.
+    "scenecut": 0,
     # encoder operating point (analog of VEM_* env knobs)
     "rc_mode": "cqp",                # cqp | vbr2pass
     "target_bitrate_kbps": 0.0,      # vbr2pass target; 0 = unset
@@ -322,6 +329,7 @@ _CLAMPS: dict[str, Callable[[Any], Any]] = {
     # saturate at ±AQ_MAX_DELTA well before that)
     "aq_strength": lambda v: min(3.0, max(0.0, as_float(v, 0.0))),
     "gop_frames": lambda v: min(600, max(1, as_int(v, 32))),
+    "scenecut": lambda v: min(100, max(0, as_int(v, 0))),
     "max_segments": lambda v: min(4096, max(1, as_int(v, 200))),
     "drain_ratio": lambda v: min(1.0, max(0.0, as_float(v, 0.75))),
     "pipeline_worker_count": lambda v: min(4096, max(1, as_int(v, 8))),
@@ -540,7 +548,7 @@ def reset_live_settings() -> None:
 # mirroring the reference's job-hash settings editable while not RUNNING
 # (/root/reference/manager/app.py:2746-2812).
 JOB_SETTING_KEYS = frozenset(
-    {"gop_frames", "qp", "rc_mode", "target_bitrate_kbps",
+    {"gop_frames", "scenecut", "qp", "rc_mode", "target_bitrate_kbps",
      "max_segments", "profile_dir", "ladder_rungs", "segment_s",
      "live_stall_s", "dvr_window_s", "job_priority",
      "live_part_budget_s", "sfe_bands", "sfe_halo_rows", "tenant",

@@ -206,6 +206,11 @@ class SegmentPlan:
     gops: tuple[GopSpec, ...]
     num_devices: int
     frames_per_gop: int
+    #: stage every GOP of the plan to `frames_per_gop` frames (or the
+    #: longest GOP, where the segment cap made one longer) instead of
+    #: to the plan's longest: plans made on scene cuts, whose GOP
+    #: lengths follow the content, then share one program shape
+    pin_frames: bool = False
 
     @property
     def num_gops(self) -> int:

@@ -58,6 +58,12 @@ class FrameSource:
         needs. Restartable: every call opens its own decode cursor."""
         raise NotImplementedError
 
+    def iter_luma(self) -> Iterator:
+        """Luma planes of every frame, for an analysis that reads
+        nothing else (parallel/scenecut.py). Decodes whole frames
+        unless the container lets the subclass do better."""
+        return (f.y for f in self.iter_frames())
+
     def close(self) -> None:
         """Release any persistent resources (sources keep no open file
         handles between iterations, so this is best-effort hygiene)."""
@@ -145,6 +151,11 @@ class _Y4MFrameSource(FrameSource):
         for frame in self._reader.read_range(max(0, start), stop):
             self.frames_decoded += 1
             yield frame
+
+    def iter_luma(self) -> Iterator:
+        """By offset: the chroma planes are never read, and no frame
+        counts as decoded."""
+        return self._reader.read_luma()
 
 
 class _Mp4FrameSource(FrameSource):

@@ -228,6 +228,20 @@ class Y4MRangeReader:
                         else (None, None))
                 yield Frame(y, u, v, pts=idx)
 
+    def read_luma(self) -> Iterator[np.ndarray]:
+        """Yield the luma plane of every frame, read by offset: the
+        chroma planes are seeked over."""
+        h, w = self._shapes[0]
+        skip = self._record - h * w
+        with open(self.path, "rb") as fp:
+            fp.seek(self._data_start + len(self._MARKER))
+            for _ in range(self.num_frames):
+                data = fp.read(h * w)
+                if len(data) != h * w:
+                    raise EOFError("truncated y4m frame payload")
+                yield np.frombuffer(data, np.uint8).reshape(h, w)
+                fp.seek(skip, os.SEEK_CUR)
+
 
 def read_y4m(path: str | os.PathLike) -> tuple[VideoMeta, list[Frame]]:
     with open(path, "rb") as fp:

@@ -328,6 +328,18 @@ STAGE_COUNTER_TOTALS = {
     "sparse_values_budget": REGISTRY.counter(
         "tvt_sparse_values_budget_total",
         "values the sparse transfer buffers of those GOPs hold"),
+    "scene_cuts": REGISTRY.counter(
+        "tvt_scene_cuts_total",
+        "scene cuts that became GOP starts (the scenecut setting)"),
+    "scene_cuts_suppressed": REGISTRY.counter(
+        "tvt_scene_cuts_suppressed_total",
+        "scene cuts found too close to the last one to start a GOP"),
+    "wave_frames": REGISTRY.counter(
+        "tvt_wave_frames_total",
+        "frames GOP waves staged, repeats included"),
+    "pad_frames": REGISTRY.counter(
+        "tvt_pad_frames_total",
+        "of them repeats the host drops: short GOPs' tails, pad GOPs"),
 }
 
 # -- origin serving (origin/serve.OriginStats + origin/cache) ----------

@@ -128,39 +128,39 @@ def default_mesh(devices=None) -> Mesh:
 #: device and start_fetch sent on their way. No wave runs a second
 #: program, so dense_reencode reads 0; it stays a key because the
 #: benchmark's files still ask for it (PERF.md §7). The split-frame
-#: escape fallback re-runs its steps dense, interleaves steps, copies
-#: and packs per frame and files them under dense_retry alone;
-#: sfe = the split-frame path's per-frame host leg (band sparse unpack
-#: + band-slice entropy pack + frame assembly) — the host half of the
-#: single-stream glass-to-bitstream latency (SfeShardEncoder))
+#: escape fallback re-runs its steps dense and files steps, copies and
+#: packs per frame under dense_retry alone;
+#: sfe = the split-frame path's per-frame host leg: band unpack, band
+#: slice pack, frame assembly; scenecut = the executor's one read of
+#: the source's luma for scene cuts, parallel/scenecut.py, 0 when off)
 STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
                "fetch", "dense_retry", "dense_reencode", "dense_fetch",
                "sparse_unpack", "unflatten", "pack", "concat", "sfe",
-               "halo")
+               "halo", "scenecut")
 
 #: monotonic counters riding in the same snapshot as the stage clocks:
 #: dense_fallback_waves (waves that overflowed the sparse budgets and
 #: shipped their levels dense), h2d_bytes (host→device bytes uploaded
-#: while staging waves — the ABR ladder's proof that decode+upload happens
-#: ONCE per wave regardless of rung count: lower rungs derive on
-#: device, so this must not scale with rungs), d2h_bytes (actual
-#: device→host bytes fetched — the benchmark's d2h_bytes_per_frame is
-#: its growth per frame), fetch_shards (per-shard concurrent fetch transfers issued; 0
-#: means every fetch was a single blocking device_get), proc_pack_gops
-#: (GOPs handed to the pack_backend=process sidecars instead of the
-#: thread pool), sfe_frames (frames that crossed the split-frame
-#: per-frame collect path — bands fetched + packed as band slices),
-#: sparse_{blocks,values}_{used,budget} (how full the sparse transfer
-#: buffers were: blocks with a level and non-zero values counted on
-#: the device, against what the buffers hold, summed over every GOP —
-#: or split-frame band — collected (all-intra waves, which pack by
-#: value alone, are not counted); used / budget over 1 means the wave
-#: went dense. Once the blocks overflow, the value count is a
-#: lower bound: the device counts values in the blocks it kept)
+#: while staging waves: once per wave whatever the ladder's rung
+#: count), d2h_bytes (device→host bytes fetched), fetch_shards
+#: (per-shard concurrent fetch transfers issued; 0 = every fetch was
+#: one blocking device_get), proc_pack_gops (GOPs handed to the
+#: pack_backend=process sidecars), sfe_frames (frames through the
+#: split-frame per-frame collect), sparse_{blocks,values}_{used,budget}
+#: (blocks with a level and non-zero values counted on the device,
+#: against what the sparse transfer buffers hold, summed over every GOP
+#: or split-frame band collected, all-intra waves apart; used / budget
+#: over 1 means the wave went dense, and the value count is then a
+#: lower bound: the device counts values in the blocks it kept),
+#: scene_cuts / scene_cuts_suppressed (cuts that became GOP starts /
+#: were too close to the last), wave_frames / pad_frames (frames GOP
+#: waves staged in all / repeats among them that the host drops)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "d2h_bytes",
                   "fetch_shards", "proc_pack_gops", "sfe_frames",
                   "sparse_blocks_used", "sparse_blocks_budget",
-                  "sparse_values_used", "sparse_values_budget")
+                  "sparse_values_used", "sparse_values_budget",
+                  "scene_cuts", "scene_cuts_suppressed", "wave_frames",
+                  "pad_frames")
 
 
 class StageProfile:
@@ -731,6 +731,11 @@ class GopShardEncoder:
         #: local planner so a worker reproduces the coordinator's global
         #: plan bit-for-bit regardless of its own device count.
         self.plan_override: SegmentPlan | None = None
+        #: Scene cuts of the clip this encoder is about to plan (the
+        #: `scenecut` setting; the executor sets them before it asks
+        #: for the plan): frames that start a shot and so a GOP. None =
+        #: the setting is off and the plan is the fixed balanced one.
+        self.scene_cuts: tuple[int, ...] | None = None
 
     @property
     def num_devices(self) -> int:
@@ -740,7 +745,7 @@ class GopShardEncoder:
         if self.plan_override is not None:
             return self.plan_override
         return plan_segments(num_frames, self.gop_frames, self.num_devices,
-                             self.max_segments)
+                             self.max_segments, cuts=self.scene_cuts)
 
     def stage_waves(self, frames):
         """Host-side staging generator: stack frames into per-wave
@@ -798,7 +803,12 @@ class GopShardEncoder:
         the PLAN, not of the wave: the planner balances GOPs to `base`
         and `base + 1` frames, and a per-wave F would compile one
         program shape for each (the padded frame is encoded and
-        dropped, see collect_wave). The cursor decodes frames on demand
+        dropped, see collect_wave). A plan made on scene cuts pins F
+        to `frames_per_gop` (`pin_frames`): its GOP lengths follow the
+        content, and a clip whose shots are all short would otherwise
+        compile a program shape of its own. What the padding costs is
+        counted: `wave_frames` staged in all, `pad_frames` of them
+        repeats. The cursor decodes frames on demand
         and each wave's frames are released once the caller has staged
         them into device arrays."""
         plan = self.plan(len(frames))
@@ -808,10 +818,16 @@ class GopShardEncoder:
         per_wave = D * (self.gops_per_wave if self.inter else 1)
         gops = list(plan.gops)
         F = max((g.num_frames for g in gops), default=0)
+        if plan.pin_frames:
+            F = max(F, plan.frames_per_gop)
         for wave_start in range(0, len(gops), per_wave):
             wave = gops[wave_start:wave_start + per_wave]
             pad_n = (-len(wave)) % D
             full = wave + [wave[-1]] * pad_n
+            staged = len(full) * F
+            self.stages.bump("wave_frames", staged)
+            self.stages.bump("pad_frames",
+                             staged - sum(g.num_frames for g in wave))
             yield wave, full, F, cursor
             # the caller staged this wave into device arrays; frames
             # below the next wave's start will never be read again
@@ -2288,3 +2304,10 @@ def encode_clip_sharded(frames: list[Frame], meta: VideoMeta, qp: int = 27,
     enc = GopShardEncoder(meta, qp=qp, mesh=mesh, gop_frames=gop_frames,
                           inter=inter)
     return concat_segments(enc.encode(frames))
+
+
+def job_stage_profile() -> StageProfile:
+    """A stage profile for host work of a job that no encoder of this
+    process owns (the remote coordinator's look for scene cuts): it
+    mirrors into the process totals as an encoder's does."""
+    return StageProfile(mirror=_TOTALS)
