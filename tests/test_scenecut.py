@@ -19,6 +19,7 @@ stage. Held here, at small sizes on the CPU:
 - the benchmark's own copy of the generator gives the same planes.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -26,13 +27,17 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 from thinvids_tpu.cluster import Coordinator, WorkerRegistry
+from thinvids_tpu.codecs.h264 import jaxinter
+from thinvids_tpu.codecs.h264.layout import unflatten_gop
+from thinvids_tpu.codecs.h264.rdo import RD_OFF, RdConfig
 from thinvids_tpu.cluster.executor import LocalExecutor
 from thinvids_tpu.core.config import (DEFAULT_SETTINGS, JOB_SETTING_KEYS,
                                       Settings, overlay_job_settings)
 from thinvids_tpu.core.status import Status
-from thinvids_tpu.core.types import VideoMeta
+from thinvids_tpu.core.types import GopSpec, SegmentPlan, VideoMeta
 from thinvids_tpu.io.mp4 import read_mp4
 from thinvids_tpu.io.y4m import write_y4m
 from thinvids_tpu.parallel import dispatch, scenecut
@@ -438,6 +443,8 @@ class TestThroughTheCoordinator:
         assert grew["waves"] == 2               # 7 GOPs over 4 devices
         assert grew["wave_frames"] == 8 * GOP   # one pad GOP
         assert grew["pad_frames"] == 8 * GOP - N
+        # the pad GOP repeats the last one (4 frames), bound and all
+        assert grew["pad_frames_skipped"] == 8 * GOP - N - 4
         with open(job.output_path, "rb") as a, \
                 open(served[1].output_path, "rb") as b:
             assert a.read() == b.read()
@@ -605,6 +612,7 @@ class TestTheFarmPlansOnTheSameCuts:
             return real(self, staged)
 
         monkeypatch.setattr(dispatch.GopShardEncoder, "dispatch_wave", spy)
+        calls = _program_calls(monkeypatch)
         snap = make_settings(gop_frames=GOP, qp=27, scenecut=40,
                              remote_plan_devices=1, remote_shard_gops=2,
                              remote_no_worker_grace_s=10.0)
@@ -643,6 +651,11 @@ class TestTheFarmPlansOnTheSameCuts:
         assert grew["scene_cuts"] - before["scene_cuts"] == 3
         assert grew["scenecut"] > before["scenecut"]
         assert shapes and {s[1] for s in shapes} == {GOP}
+        # the workers' waves run the local job's program: the one that
+        # takes the GOPs' real lengths (ISSUE 34)
+        assert calls == [("_encode_gop_single", True)] * 7
+        assert grew["pad_frames_skipped"] - before["pad_frames_skipped"] \
+            == 7 * GOP - N
         with open(job.output_path, "rb") as a, \
                 open(served[1].output_path, "rb") as b:
             assert a.read() == b.read()
@@ -660,6 +673,200 @@ class TestTheFarmPlansOnTheSameCuts:
                                                 scenecut=40)),
                     sig(job, make_settings(gop_frames=GOP,
                                            scenecut=41))}) == 3
+
+
+# ---------------------------------------------------------------------------
+# a cut-aligned GOP stops at its real length (ISSUE 34)
+# ---------------------------------------------------------------------------
+
+SERVING = RdConfig(mode_decision=True, pskip=True, deblock=True, aq_q=8)
+
+
+def _pinned(lengths, devices=1):
+    """A plan as `plan_segments` makes it on cuts: GOPs of `lengths`
+    frames, every one staged to GOP."""
+    starts = np.concatenate([[0], np.cumsum(lengths)[:-1]])
+    return SegmentPlan(
+        gops=tuple(GopSpec(index=i, start_frame=int(a), num_frames=int(n))
+                   for i, (a, n) in enumerate(zip(starts, lengths))),
+        num_devices=devices, frames_per_gop=GOP, pin_frames=True)
+
+
+def _gop_bytes(frames, plan, rd=RD_OFF, devices=1, index=0):
+    """(payload per GOP, what the stage counters grew by) of `frames`
+    through a GopShardEncoder held to `plan` (None: its own, unpinned,
+    one GOP as long as the clip, the scan form at that length, as GOP
+    `index` of its clip)."""
+    meta = VideoMeta(width=W, height=H, fps_num=30, fps_den=1,
+                     num_frames=len(frames))
+    enc = dispatch.GopShardEncoder(
+        meta, qp=27, mesh=dispatch.default_mesh(jax.devices()[:devices]),
+        gop_frames=GOP if plan is not None else len(frames), rd=rd)
+    enc.plan_override = plan
+    enc.gop_index_offset = index
+    segs = enc.encode(frames)
+    return [seg.payload for seg in segs], enc.stages.snapshot()
+
+
+def _program_calls(monkeypatch):
+    """Record, per call of a GOP program, whether it was handed the
+    GOPs' real lengths."""
+    calls = []
+    for name in ("_encode_gop_single", "_encode_wave_gop"):
+        real = getattr(dispatch, name)
+
+        def spy(*args, _real=real, _name=name, **kwargs):
+            calls.append((_name, len(args) == 5))
+            return _real(*args, **kwargs)
+
+        monkeypatch.setattr(dispatch, name, spy)
+    return calls
+
+
+class TestACutAlignedGopStopsAtItsLength:
+    @pytest.mark.parametrize("rd", [RD_OFF, SERVING], ids=["library", "rd"])
+    @pytest.mark.parametrize("n", [1, 2, GOP - 1, GOP])
+    def test_the_bytes_of_the_same_frames_as_a_gop_of_that_length(
+            self, n, rd):
+        """Staged to GOP frames with `n_frames` = n, against the scan
+        form over a GOP staged to n: the same NAL bytes."""
+        frames = make_frames(n, W, H, seed=11)
+        (bounded,), grew = _gop_bytes(frames, _pinned([n]), rd=rd)
+        (scanned,), plain = _gop_bytes(frames, None, rd=rd)
+        assert bounded == scanned
+        assert grew["wave_frames"] == GOP and plain["wave_frames"] == n
+        assert grew["pad_frames"] == grew["pad_frames_skipped"] == GOP - n
+        assert plain["pad_frames"] == plain["pad_frames_skipped"] == 0
+
+    @pytest.mark.parametrize("rd", [RD_OFF, SERVING], ids=["library", "rd"])
+    @pytest.mark.parametrize("n", [1, 2, GOP - 1, GOP])
+    def test_what_the_loop_skips_is_zero_and_the_rest_is_the_scans(
+            self, n, rd):
+        """`encode_gop_planes` on one staged GOP: frames before n come
+        out as the scan over all GOP frames gives them, frames from n
+        on are zeros, in the layout `unflatten_gop` reads."""
+        frames = make_frames(n, W, H, seed=12)
+        frames = frames + [frames[-1]] * (GOP - n)
+        planes = [jnp.asarray(np.stack([getattr(f.padded(16), p)
+                                        for f in frames])) for p in "yuv"]
+        run = jax.jit(jaxinter.encode_gop_planes,
+                      static_argnames=("mbw", "mbh", "rd"))
+        kw = dict(mbw=W // 16, mbh=H // 16, rd=rd)
+        mv_s, flat_s = run(*planes, jnp.int32(27), **kw)
+        mv_b, flat_b = run(*planes, jnp.int32(27), **kw,
+                           n_frames=jnp.int32(n))
+        assert mv_b.shape == mv_s.shape and flat_b.shape == flat_s.shape
+        assert mv_b.dtype == mv_s.dtype and flat_b.dtype == flat_s.dtype
+        intra_s, p_s = unflatten_gop(np.asarray(flat_s), np.asarray(mv_s),
+                                     GOP, W // 16, H // 16,
+                                     ships_modes=rd.ships_modes)
+        intra_b, p_b = unflatten_gop(np.asarray(flat_b), np.asarray(mv_b),
+                                     GOP, W // 16, H // 16,
+                                     ships_modes=rd.ships_modes)
+        for a, b in zip(intra_s, intra_b):
+            assert np.array_equal(a, b)
+        for a, b in zip(p_s, p_b):
+            assert a.shape[0] == GOP - 1
+            assert np.array_equal(a[:n - 1], b[:n - 1])
+            assert not b[n - 1:].any()
+
+    def test_through_the_dense_fallback(self):
+        """Grain that leaves the sparse budgets: the levels the bounded
+        program left on the device (zeros past n) pack to the bytes of
+        the scan form at that length."""
+        n = 5
+        frames = make_frames(n, W, H, seed=13, grain=8.0)
+        (bounded,), grew = _gop_bytes(frames, _pinned([n]))
+        (scanned,), plain = _gop_bytes(frames, None)
+        assert grew["dense_fallback_waves"] == 1 \
+            == plain["dense_fallback_waves"]
+        assert bounded == scanned
+        assert grew["pad_frames_skipped"] == GOP - n
+
+    def test_on_two_devices_each_loop_has_its_own_bound(self, monkeypatch):
+        """Three GOPs of 5, 8 and 3 frames over a 2-device mesh: two
+        waves, the second with a pad GOP; each GOP's bytes are those of
+        the one-device scan form at its length."""
+        calls = _program_calls(monkeypatch)
+        lengths = [5, GOP, 3]
+        frames = make_frames(sum(lengths), W, H, seed=14)
+        got, grew = _gop_bytes(frames, _pinned(lengths, 2), devices=2)
+        assert calls == [("_encode_wave_gop", True)] * 2
+        a = 0
+        for i, (n, payload) in enumerate(zip(lengths, got)):
+            (want,), _ = _gop_bytes(frames[a:a + n], None, index=i)
+            assert payload == want
+            a += n
+        assert grew["wave_frames"] == 4 * GOP
+        assert grew["pad_frames"] == 4 * GOP - sum(lengths)
+        # the pad GOP repeats the last one, loop bound and all
+        assert grew["pad_frames_skipped"] == 4 * GOP - sum(lengths) - 3
+
+    @pytest.mark.parametrize("program", ["_encode_gop_single",
+                                         "_encode_wave_gop"])
+    def test_a_plain_plans_program_has_no_traced_bound(self, program):
+        """Without `n_frames` the program is the one it was: every loop
+        a `scan` of static length (the jaxpr of the parent commit, text
+        for text: PERF.md, PR 34). With it, one `while` more and one
+        scan fewer, and nothing else of another kind."""
+        G, F = 2, 4
+        c = (G, F, H // 2, W // 2)
+        args = [jax.ShapeDtypeStruct((G, F, H, W), jnp.uint8),
+                jax.ShapeDtypeStruct(c, jnp.uint8),
+                jax.ShapeDtypeStruct(c, jnp.uint8),
+                jax.ShapeDtypeStruct((G,), jnp.int32)]
+        kw = dict(mbw=W // 16, mbh=H // 16, compact=True)
+        if program == "_encode_wave_gop":
+            kw["mesh"] = dispatch.default_mesh(jax.devices()[:2])
+        fn = functools.partial(getattr(dispatch, program), **kw)
+        plain = str(jax.make_jaxpr(fn)(*args))
+        assert plain == str(jax.make_jaxpr(fn)(*args, None))
+        assert "while[" not in plain and plain.count("scan[") > 2
+        bounded = str(jax.make_jaxpr(fn)(*args, args[3]))
+        assert bounded.count("while[") == 1
+        assert bounded.count("scan[") == plain.count("scan[") - 1
+
+    def test_a_job_runs_one_of_the_two_programs(self, edited, monkeypatch):
+        """The plan decides: every wave of a job planned on cuts hands
+        its program the real lengths (full GOPs too), and no wave of a
+        job planned without does. The repeats skipped are counted, in
+        the snapshot and in the registry `/metrics` serves."""
+        from thinvids_tpu.obs import metrics as obs_metrics
+
+        tmp, _frames, path = edited
+        calls = _program_calls(monkeypatch)
+        exported = obs_metrics.STAGE_COUNTER_TOTALS["pad_frames_skipped"]
+        before = exported.get()
+        _c, _job, grew = _run(tmp, "one-on", path, scenecut=40)
+        assert calls == [("_encode_gop_single", True)] * 7
+        assert grew["pad_frames_skipped"] == grew["pad_frames"] \
+            == 7 * GOP - N == exported.get() - before
+        calls.clear()
+        _c, _job, grew = _run(tmp, "one-off", path)
+        assert calls == [("_encode_gop_single", False)] * 5
+        assert grew["pad_frames_skipped"] == grew["pad_frames"] == 0
+        assert exported.get() - before == 7 * GOP - N
+
+    def test_every_rung_of_a_ladder_gets_the_lengths(self, edited,
+                                                     monkeypatch):
+        """The ladder stages once and hands the staged wave to each
+        rung, scaled or not: the real lengths ride along."""
+        from thinvids_tpu.abr.ladder import plan_ladder
+
+        _tmp, frames, _path = edited
+        calls = _program_calls(monkeypatch)
+        snap = make_settings(gop_frames=GOP, qp=27,
+                             ladder_rungs=f"{H},{H // 2}")
+        rungs = plan_ladder(META, snap)
+        for cuts, bounded in (((11, 18, 31), True), (None, False)):
+            enc = dispatch.make_shard_encoder(META, snap, _one_chip(),
+                                              rungs=rungs)
+            enc.scene_cuts = cuts
+            calls.clear()
+            bundles = enc.encode(frames)
+            waves = len(bundles)
+            assert waves == (7 if cuts else 5)
+            assert calls == [("_encode_gop_single", bounded)] * 2 * waves
 
 
 # ---------------------------------------------------------------------------

@@ -83,9 +83,11 @@ def _sfe(p_frame: bool):
             dict(kwargs, halo_rows=16, num_bands=n))
 
 
-def _lower_gop(program, **more):
+def _lower_gop(program, cuts=False, **more):
+    """`cuts`: the program of a plan made on scene cuts, which takes
+    each GOP's real frame count beside its QP (ISSUE 34)."""
     args, kwargs = _gop_args()
-    return program.lower(*args, **kwargs, **more)
+    return program.lower(*args, *args[3:] * cuts, **kwargs, **more)
 
 
 def _lower_sfe(program, p_frame, **more):
@@ -122,6 +124,20 @@ CASES = {
     "sfe_p_rd": (
         lambda: _lower_sfe(dispatch._sfe_p_step, True, rd=RD_ON),
         SFE_P | SPARSE | {"deblock"}),
+    # the P-frame loop with a traced bound: `tvt.layout` still names
+    # the `while`, the stages inside it keep their names
+    "gop_single_cuts": (
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
+                           compact=True),
+        GOP | SPARSE),
+    "wave_gop_cuts": (
+        lambda: _lower_gop(dispatch._encode_wave_gop, cuts=True,
+                           mesh=_gop_mesh(), compact=True),
+        GOP | SPARSE),
+    "gop_single_rd_cuts": (
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
+                           compact=True, rd=RD_ON),
+        GOP | SPARSE | {"deblock"}),
 }
 
 

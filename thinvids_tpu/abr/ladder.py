@@ -277,7 +277,9 @@ class LadderShardEncoder:
         the unscaled rung dispatches the staged tensors directly; each
         scaled rung first derives its input on device (two matmuls per
         plane) — no additional decode or upload."""
-        wave, ysd, usd, vsd, qpsd = staged
+        # reald: each GOP's real length, of a cut-aligned plan alone
+        # (GopShardEncoder.stage_waves): every rung's loop stops there
+        wave, ysd, usd, vsd, qpsd, *reald = staged
         base_qp = self.rungs[0].qp
         handles = []
         for rung, enc, scaler in zip(self.rungs, self.encoders,
@@ -290,7 +292,8 @@ class LadderShardEncoder:
                 # carry any per-GOP QP deltas across rungs relative to
                 # this rung's base operating point
                 rqps = qpsd - base_qp + rung.qp
-            handles.append(enc.dispatch_wave((wave, sy, su, sv, rqps)))
+            handles.append(enc.dispatch_wave((wave, sy, su, sv, rqps,
+                                              *reald)))
         return (wave, handles)
 
     def collect_wave(self, pending: tuple) -> list[LadderGopBundle]:

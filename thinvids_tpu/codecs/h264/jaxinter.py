@@ -367,15 +367,15 @@ def _gop_head(ys, us, vs, qp):
 
 
 @stage("layout")
-def _scan_p_frames(p_step, recon, planes):
-    """Chain `p_step` over frames 1..F-1 of a GOP from the IDR's
-    recon. The scope names the loop itself (its `while`, the slicing
-    of the frames and the stacking of the outputs); the stages inside
-    `p_step` keep their own names."""
-    # Inits derived from data (not constants) so the scan carries keep
-    # the mesh-varying axes under shard_map — see jaxcore._varying_zero.
+def _scan_p_frames(p_step, recon, planes, n_frames=None):
+    """Chain `p_step` over frames 1..F-1 of a GOP from the IDR's recon,
+    or over the GOP's first `n_frames` alone (_loop_p_frames). The scope
+    names the loop; the stages inside `p_step` keep their own names."""
+    # Inits derived from data, not constants: jaxcore._varying_zero.
     zero = _varying_zero(recon[0])
     zero_mv = jnp.zeros(2, jnp.int32) + zero
+    if n_frames is not None and planes[0].shape[0] > 1:
+        return _loop_p_frames(p_step, (*recon, zero_mv), planes, n_frames)
     _, pouts = jax.lax.scan(
         p_step, (*recon, zero_mv), tuple(p[1:] for p in planes))
     return pouts
@@ -432,24 +432,24 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
 from .layout import _INTRA_FLAT_MB, _P_FLAT_MB  # noqa: E402
 
 
-def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF):
+def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
+                      n_frames=None):
     """Closed-GOP compute emitting PLANE-layout levels for the sharded
-    transfer path: returns (mv (F-1, nmb, 2) int8, flat int16).
-
+    transfer path: returns (mv (F-1, nmb, 2) int8, flat int16). With
+    `n_frames` (int32 scalar, 1..F: the real length of a GOP staged to
+    F by tail-repeat) frames from n_frames on are not encoded: zeros.
     flat layout (all reshape(-1), no relayout on device):
       [ intra il_dc | il_ac | ic_dc | ic_ac          (nmb * 384)
       | luma coeff planes   (F-1, H, W)
       | u DC (F-1, nmb, 4) | v DC (F-1, nmb, 4)
       | u AC plane (F-1, H/2, W/2) | v AC plane (F-1, H/2, W/2)
       | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only ]
-
     The host inverse is parallel/dispatch._unflatten_gop.
     """
     # The int8 MV transfer rides on search candidates being bounded by
-    # construction: centers clamp to ±(SEARCH_RANGE - window) pel and
-    # offsets add ≤ the window, so |mv| ≤ 2 * SEARCH_RANGE half-pel
-    # units per frame (each P frame references its immediate
-    # predecessor — MVs never accumulate).
+    # construction: centers clamp to ±(SEARCH_RANGE - window) pel, offsets
+    # add ≤ the window, so |mv| ≤ 2 * SEARCH_RANGE half-pel units per
+    # frame (a P frame references its predecessor: MVs never accumulate).
     if 2 * SEARCH_RANGE > 127:
         raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
     qp, qpc, y0, u0, v0 = _gop_head(ys, us, vs, qp)
@@ -465,7 +465,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF):
         return (ry2, ru2, rv2, med_mv), (mv.astype(jnp.int8), lp, cdc, cac)
 
     mv8, lps, cdcs, cacs = _scan_p_frames(
-        p_step, (ry, ru, rv), (ys, us, vs))
+        p_step, (ry, ru, rv), (ys, us, vs), n_frames)
     # cdcs: (F-1, 2, n, 4) int16; cacs: (F-1, 2, H/2, W/2) int16
     with stage("layout"):
         parts = [
@@ -694,3 +694,33 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
         # fresh input) so the carry shape matches the local chain's
         return mv8, flat, cnt, n, (ry2, ru2, rv2, pred_mv)
     return mv8, flat, (ry2, ru2, rv2, med)
+
+
+def _loop_p_frames(p_step, carry, planes, n_frames):
+    """:func:`_scan_p_frames` for a GOP staged to F frames of which the
+    first `n_frames` (int32 scalar, traced, 1..F) are real and the rest
+    repeats of the last that the host drops: the same `p_step` over
+    frames 1..n_frames-1, a `while` whose bound is data, each frame's
+    outputs written into zero (F-1, ...) buffers — what `lax.scan`
+    lowers to with a static bound. Frames from n_frames on cost
+    nothing and read zero. A program takes this form for every GOP of
+    a plan made on scene cuts (SegmentPlan.pin_frames) and for no
+    other plan."""
+    def frame(i):
+        return tuple(jax.lax.dynamic_index_in_dim(p, i, keepdims=False)
+                     for p in planes)
+
+    zero = _varying_zero(carry[0])      # see _scan_p_frames
+    outs = tuple(
+        jnp.zeros((planes[0].shape[0] - 1, *o.shape), o.dtype)
+        + zero.astype(o.dtype)
+        for o in jax.eval_shape(p_step, carry, frame(1))[1])
+
+    def body(i, state):
+        carry, outs = state
+        carry, out = p_step(carry, frame(i + 1))
+        return carry, tuple(
+            jax.lax.dynamic_update_index_in_dim(o, x, i, 0)
+            for o, x in zip(outs, out))
+
+    return jax.lax.fori_loop(0, n_frames - 1, body, (carry, outs))[1]

@@ -209,7 +209,9 @@ class SegmentPlan:
     #: stage every GOP of the plan to `frames_per_gop` frames (or the
     #: longest GOP, where the segment cap made one longer) instead of
     #: to the plan's longest: plans made on scene cuts, whose GOP
-    #: lengths follow the content, then share one program shape
+    #: lengths follow the content, then share one program shape — the
+    #: one whose P-frame loop stops at each GOP's real length, which
+    #: such a plan's waves carry (jaxinter._loop_p_frames)
     pin_frames: bool = False
 
     @property

@@ -340,6 +340,10 @@ STAGE_COUNTER_TOTALS = {
     "pad_frames": REGISTRY.counter(
         "tvt_pad_frames_total",
         "of them repeats the host drops: short GOPs' tails, pad GOPs"),
+    "pad_frames_skipped": REGISTRY.counter(
+        "tvt_pad_frames_skipped_total",
+        "of those, repeats no program encoded: a plan made on scene "
+        "cuts stops each GOP's P-frame loop at its real length"),
 }
 
 # -- origin serving (origin/serve.OriginStats + origin/cache) ----------

@@ -11,16 +11,16 @@ the single-device encode is asserted by tests/test_parallel.py on an
 8-device virtual mesh.
 
 A wave is the pipeline's unit: ONE GOP per mesh device (`gops_per_wave`
-1), one program shape per clip (every wave is padded to the plan's
-longest GOP). More GOPs per wave buy no device time — 18.70 ms per
-1080p frame at 1x32, 18.71 at 4x32 (ledger PR 28) — and cost a job a
-lead-in (decode + stage before the first program) and a tail (unpack +
-pack after the last) of a whole wave each, with the device idle. The
+1), one program shape per clip (every GOP is staged to the plan's
+longest by tail-repeat; a plan made on scene cuts, whose GOPs are as
+long as their shots, hands each GOP's real length to the program, which
+encodes no frame past it). More GOPs per wave buy no device time (18.70
+ms per 1080p frame at 1x32, 18.71 at 4x32, ledger PR 28) and cost a job
+a lead-in and a tail of a whole wave each, with the device idle. The
 pipeline's order: wave n's fetch is STARTED (start_fetch: counts in,
 payload slice enqueued) before wave n+1's program is enqueued — the
-slice is itself a program on the device's compute queue and would
-otherwise wait for all of wave n+1 — and wave n's unpack and pack then
-run under wave n+1's compute.
+slice is itself a program on the device's compute queue and would wait
+for all of wave n+1 — and wave n's unpack and pack run under its compute.
 
 Host side, the pipeline is instrumented per stage (StageProfile): every
 wave's source decode / staging (stack + H2D upload) / dispatch / device
@@ -152,15 +152,15 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 #: or split-frame band collected, all-intra waves apart; used / budget
 #: over 1 means the wave went dense, and the value count is then a
 #: lower bound: the device counts values in the blocks it kept),
-#: scene_cuts / scene_cuts_suppressed (cuts that became GOP starts /
-#: were too close to the last), wave_frames / pad_frames (frames GOP
-#: waves staged in all / repeats among them that the host drops)
+#: scene_cuts / scene_cuts_suppressed (cuts that began a GOP / came too
+#: soon after one), wave_frames / pad_frames / pad_frames_skipped (GOP
+#: waves' frames staged / repeats among them / repeats never encoded)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "d2h_bytes",
                   "fetch_shards", "proc_pack_gops", "sfe_frames",
                   "sparse_blocks_used", "sparse_blocks_budget",
                   "sparse_values_used", "sparse_values_budget",
                   "scene_cuts", "scene_cuts_suppressed", "wave_frames",
-                  "pad_frames")
+                  "pad_frames", "pad_frames_skipped")
 
 
 class StageProfile:
@@ -434,9 +434,9 @@ def _flat_levels(y, u, v, qp, mbw, mbh, rd=RD_OFF):
 
 
 def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
-                    rd=RD_OFF):
+                    rd=RD_OFF, n_frames=None):
     """(F, H, W) GOP → (mv int8, dense intra-DC segments, two-tier
-    sparse levels for the rest).
+    sparse levels for the rest); `n_frames` as encode_gop_planes'.
 
     BOTH intra hadamard DC segments — luma DC (nmb * 16) and chroma DC
     (nmb * 8), ~390 KB combined at 1080p — ship DENSE: hadamard DC
@@ -462,7 +462,7 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
     from ..codecs.h264 import jaxinter
 
     mv8, flat = jaxinter.encode_gop_planes(y, u, v, qp, mbw=mbw, mbh=mbh,
-                                           rd=rd)
+                                           rd=rd, n_frames=n_frames)
     nmb = mbw * mbh
     ndc, nlac, ncdc = nmb * 16, nmb * 240, nmb * 8
     with stage("pack"):
@@ -504,45 +504,45 @@ _unflatten_gop_parts = unflatten_gop_parts
 
 @functools.partial(jax.jit,
                    static_argnames=("mbw", "mbh", "mesh", "compact", "rd"))
-def _encode_wave_gop(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
-                     compact: bool = False, rd=RD_OFF):
+def _encode_wave_gop(ys, us, vs, qps, n_frames=None, *, mbw: int, mbh: int,
+                     mesh: Mesh, compact: bool = False, rd=RD_OFF):
     """ys: (G, F, H, W) uint8 sharded over `gop`, G = devices x k; each
     device sequentially encodes its k GOPs (IDR + P, jaxinter) at its
     per-GOP QP (qps: (G,) int32, the rate-control hook) and sparse-packs
-    the plane-layout levels (`compact` folds the sparse streams into
-    one byte payload per GOP — see _per_gop_sparse)."""
+    the plane-layout levels (`compact`: see _per_gop_sparse). `n_frames`
+    as _encode_gop_single's: each device's loop has its own GOP's bound."""
 
-    def per_dev(y_g, u_g, v_g, qp_g):
+    def per_dev(y_g, u_g, v_g, qp_g, n_g):
         def one(args):
-            y, u, v, qp = args
+            y, u, v, qp, n = args
             return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
-                                   rd=rd)
-        return _map_gops(one, (y_g, u_g, v_g, qp_g))
+                                   rd=rd, n_frames=n)
+        return _map_gops(one, (y_g, u_g, v_g, qp_g, n_g))
 
     shard = shard_map(
         per_dev, mesh=mesh,
-        in_specs=(P("gop"),) * 4,
+        in_specs=(P("gop"),) * 5,        # n_frames None: no leaf, no spec
         out_specs=(P("gop"),) * (8 if compact else 9),
     )
-    return shard(ys, us, vs, qps)
+    return shard(ys, us, vs, qps, n_frames)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("mbw", "mbh", "compact", "rd"))
-def _encode_gop_single(ys, us, vs, qps, *, mbw: int, mbh: int,
-                       compact: bool = False, rd=RD_OFF):
+def _encode_gop_single(ys, us, vs, qps, n_frames=None, *, mbw: int,
+                       mbh: int, compact: bool = False, rd=RD_OFF):
     """Single-device wave: the same per-GOP program WITHOUT the
-    shard_map wrapper. On one chip shard_map buys nothing and cost a
-    lot under an older jax — measured then on TPU v5e: compile 33 s →
-    810 s and steady-state 256 ms → 800 ms per 1080p GOP under the
-    manual-axes lowering. Under jax 0.9.0 the two forms compile alike
-    (66.5 s vs 65.3 s for 2 GOPs x 20 1080p frames on one v5e chip,
-    PR 21); the steady state has not been measured again."""
+    shard_map wrapper, which on one chip buys nothing and cost a lot
+    under an older jax (compile 33 s → 810 s on a v5e; under jax 0.9.0
+    the two forms compile alike, 66.5 s vs 65.3 s for 2 GOPs x 20 1080p
+    frames, PR 21). `n_frames` ((G,) int32 beside `qps`, from a plan
+    made on scene cuts and no other): each GOP's real length; its P
+    frames past it are not encoded (jaxinter._loop_p_frames)."""
     def one(args):
-        y, u, v, qp = args
+        y, u, v, qp, n = args
         return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
-                               rd=rd)
-    return _map_gops(one, (ys, us, vs, qps))
+                               rd=rd, n_frames=n)
+    return _map_gops(one, (ys, us, vs, qps, n_frames))
 
 
 @functools.partial(jax.jit, static_argnames=("mbw", "mbh", "mesh", "rd"))
@@ -759,9 +759,10 @@ class GopShardEncoder:
         frames stay resident (_FrameCursor). Wrap the result in
         :func:`background_stage` — or use :meth:`encode` — to run the
         decode + stack + H2D upload on a staging thread ahead of the
-        dispatch loop."""
-        for wave, full, F, cursor in self._wave_groups(frames,
-                                                       require_420=True):
+        dispatch loop. A wave of a plan made on scene cuts carries one
+        array more, last: each GOP's real frame count (_wave_groups)."""
+        for wave, full, F, cursor, real in self._wave_groups(frames,
+                                                             encode=True):
             # prefetch the wave's frames OUTSIDE the "stage" timer so
             # the breakdown keeps decode (source pull) and stage
             # (stack + H2D) disjoint — cursor.get runs its own
@@ -776,17 +777,18 @@ class GopShardEncoder:
                                for g in full])
                 qps = np.asarray([self.gop_qp.get(g.index, self.qp)
                                   for g in full], np.int32)
+                small = (qps,) if real is None else (qps, real)
                 self.stages.bump("h2d_bytes", ys.nbytes + us.nbytes
-                                 + vs.nbytes + qps.nbytes)
+                                 + vs.nbytes + sum(a.nbytes for a in small))
                 staged = (wave, jnp.asarray(ys), jnp.asarray(us),
-                          jnp.asarray(vs), jnp.asarray(qps))
+                          jnp.asarray(vs), *map(jnp.asarray, small))
             yield staged
 
     def stage_luma_waves(self, frames):
         """Luma-only staging for analysis passes (rate control): chroma
         never leaves the host, halving the upload of a pass that only
         reads Y. Yields (wave, ys)."""
-        for wave, full, F, cursor in self._wave_groups(frames):
+        for wave, full, F, cursor, _ in self._wave_groups(frames):
             cursor.get(wave[-1].end_frame - 1)   # decode outside "stage"
             with self.stages.stage("stage"):
                 ys = np.stack([self._gop_plane(cursor, g, F, "y")
@@ -795,24 +797,30 @@ class GopShardEncoder:
                 staged = (wave, jnp.asarray(ys))
             yield staged
 
-    def _wave_groups(self, frames, require_420: bool = False):
+    def _wave_groups(self, frames, encode: bool = False):
         """Shared wave grouping: (wave, device-padded wave, static F,
-        frame cursor). Stacks into (G, F, ...) with tail-repeat padding
-        to static F; the wave itself pads to a multiple of D gops (the
-        pad GOPs are encoded then discarded). F is the longest GOP of
-        the PLAN, not of the wave: the planner balances GOPs to `base`
-        and `base + 1` frames, and a per-wave F would compile one
-        program shape for each (the padded frame is encoded and
-        dropped, see collect_wave). A plan made on scene cuts pins F
-        to `frames_per_gop` (`pin_frames`): its GOP lengths follow the
-        content, and a clip whose shots are all short would otherwise
-        compile a program shape of its own. What the padding costs is
-        counted: `wave_frames` staged in all, `pad_frames` of them
-        repeats. The cursor decodes frames on demand
-        and each wave's frames are released once the caller has staged
-        them into device arrays."""
+        frame cursor, real lengths). Stacks into (G, F, ...) with
+        tail-repeat padding to static F; the wave itself pads to a
+        multiple of D gops (the pad GOPs are encoded then discarded).
+        F is the longest GOP of the PLAN, not of the wave: the planner
+        balances GOPs to `base` and `base + 1` frames, and a per-wave F
+        would compile one program shape for each (the one repeated
+        frame is encoded, and dropped by collect_wave). A plan made on
+        scene cuts pins F to `frames_per_gop` (`pin_frames`): its GOP
+        lengths follow the content, and a clip whose shots are all
+        short would otherwise compile a program shape of its own. Such
+        a plan's waves, where they go to the GOP programs (`encode`,
+        the inter path), come with `real`, the (G,) int32 frame counts
+        of their GOPs: the program's P-frame loop stops there, so the
+        repeats are staged and sent but not encoded. Every other wave
+        has None in its place and runs the loop over all F frames: a
+        job runs ONE of the two programs, chosen by its plan. Counted:
+        `wave_frames` staged in all, `pad_frames` of them repeats,
+        `pad_frames_skipped` of those not encoded. The cursor decodes
+        frames on demand and each wave's frames are released once the
+        caller has staged them into device arrays."""
         plan = self.plan(len(frames))
-        cursor = _FrameCursor(frames, self.stages, require_420=require_420,
+        cursor = _FrameCursor(frames, self.stages, require_420=encode,
                               stats=self.staging_stats)
         D = self.num_devices
         per_wave = D * (self.gops_per_wave if self.inter else 1)
@@ -828,7 +836,12 @@ class GopShardEncoder:
             self.stages.bump("wave_frames", staged)
             self.stages.bump("pad_frames",
                              staged - sum(g.num_frames for g in wave))
-            yield wave, full, F, cursor
+            real = None
+            if plan.pin_frames and encode and self.inter:
+                real = np.asarray([g.num_frames for g in full], np.int32)
+                self.stages.bump("pad_frames_skipped",
+                                 staged - int(real.sum()))
+            yield wave, full, F, cursor, real
             # the caller staged this wave into device arrays; frames
             # below the next wave's start will never be read again
             cursor.release_below(wave[-1].end_frame)
@@ -847,18 +860,19 @@ class GopShardEncoder:
         """Enqueue one staged wave's device compute (async); returns an
         opaque pending handle for :meth:`collect_wave`."""
         with self.stages.stage("dispatch"):
-            wave, ysd, usd, vsd, qpsd = staged
+            # reald: the GOPs' real lengths, of a cut-aligned plan alone
+            wave, ysd, usd, vsd, qpsd, *reald = staged
             ph, pw = ysd.shape[2], ysd.shape[3]
             mbh, mbw = ph // 16, pw // 16
             compact = self.inter and self.compact_transfer
             if self.inter and self.num_devices == 1:
-                out = _encode_gop_single(ysd, usd, vsd, qpsd, mbw=mbw,
-                                         mbh=mbh, compact=compact,
+                out = _encode_gop_single(ysd, usd, vsd, qpsd, *reald,
+                                         mbw=mbw, mbh=mbh, compact=compact,
                                          rd=self.rd)
             elif self.inter:
-                out = _encode_wave_gop(ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
-                                       mesh=self.mesh, compact=compact,
-                                       rd=self.rd)
+                out = _encode_wave_gop(ysd, usd, vsd, qpsd, *reald, mbw=mbw,
+                                       mbh=mbh, mesh=self.mesh,
+                                       compact=compact, rd=self.rd)
             else:
                 out = _encode_wave(ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
                                    mesh=self.mesh, rd=self.rd)
@@ -1450,7 +1464,9 @@ class GopShardEncoder:
                    ) -> np.ndarray:
         arrs = [getattr(cursor.get(i), plane)
                 for i in range(gop.start_frame, gop.end_frame)]
-        while len(arrs) < F:            # tail-repeat to the wave's static F
+        # tail-repeat to the wave's static F: the program's shape. What
+        # it encodes of the repeats is the plan's to say (_wave_groups)
+        while len(arrs) < F:
             arrs.append(arrs[-1])
         return np.stack(arrs)
 

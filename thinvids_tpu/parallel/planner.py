@@ -112,7 +112,9 @@ def plan_segments(num_frames: int, gop_frames: int, num_devices: int,
       cuts then stay P frames). A plan made with cuts, even none,
       pins the wave's frame count to `gop_frames` (`pin_frames`), so
       every clip of a resolution runs one program shape however its
-      shots fall.
+      shots fall; its waves carry each GOP's real frame count and the
+      program encodes no frame past it (parallel/dispatch._wave_groups:
+      the repeats are staged, not encoded).
     """
     if num_frames <= 0:
         raise ValueError("num_frames must be positive")
