@@ -16,6 +16,7 @@ Covers the ISSUE 10 acceptance surface:
 - tracing enabled changes no output bytes and its overhead is bounded.
 """
 
+import contextlib
 import json
 import os
 import re
@@ -284,6 +285,115 @@ class TestTraceStore:
 # ---------------------------------------------------------------------------
 # knobs
 # ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for `jax.profiler.TraceAnnotation`: notes every name
+    it is entered and left with."""
+
+    def __init__(self):
+        self.log = []
+
+    @contextlib.contextmanager
+    def __call__(self, name):
+        self.log.append(("enter", name))
+        try:
+            yield
+        finally:
+            self.log.append(("exit", name))
+
+
+class TestProfilerAnnotations:
+    """While a device profile is live the executor hands obs/trace a
+    factory of annotations; every span then opens `tvt:<name>`."""
+
+    @pytest.fixture
+    def fake(self):
+        fake = _FakeAnnotation()
+        trace.set_annotation_factory(fake)
+        yield fake
+        trace.set_annotation_factory(None)
+
+    @staticmethod
+    def _recorders():
+        trace.TRACE.start("annotated-job")
+        yield trace.TRACE.recorder("annotated-job")
+        trace.TRACE.drop("annotated-job")
+        yield trace.NULL_RECORDER       # a job sampled out
+
+    def test_span_recorder_enters_and_leaves_it(self, fake):
+        for rec in self._recorders():
+            fake.log.clear()
+            with rec.span("wave_dispatch", wave=0):
+                assert fake.log == [("enter", "tvt:wave_dispatch")]
+            assert fake.log == [("enter", "tvt:wave_dispatch"),
+                                ("exit", "tvt:wave_dispatch")]
+
+    def test_stage_profile_enters_and_leaves_it(self, fake):
+        from thinvids_tpu.parallel.dispatch import StageProfile
+
+        prof = StageProfile()
+        with pytest.raises(ValueError):
+            with prof.stage("decode"):
+                with prof.stage("pack", part_of="sfe"):
+                    raise ValueError("mid-stage")
+        assert fake.log == [("enter", "tvt:decode"), ("enter", "tvt:pack"),
+                            ("exit", "tvt:pack"), ("exit", "tvt:decode")]
+        snap = prof.snapshot()
+        assert snap["decode"] >= snap["pack"] == snap["sfe"] > 0
+
+    def test_untouched_when_no_profile_is_live(self, fake):
+        from thinvids_tpu.parallel.dispatch import StageProfile
+
+        trace.set_annotation_factory(None)
+        assert trace.annotation("decode") is trace.annotation("pack")
+        with StageProfile().stage("decode"):
+            pass
+        for rec in self._recorders():
+            with rec.span("wave_collect"):
+                pass
+        assert fake.log == []
+
+    def test_trace_module_imports_and_annotates_without_jax(self):
+        import subprocess
+        import sys
+
+        code = ("import sys\n"
+                "sys.modules['jax'] = None\n"
+                "from thinvids_tpu.obs import trace\n"
+                "with trace.annotation('decode'):\n"
+                "    pass\n"
+                "with trace.NULL_RECORDER.span('wave_collect'):\n"
+                "    pass\n"
+                "print('ok')\n")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, PYTHONPATH=repo + os.pathsep
+                   + os.environ.get("PYTHONPATH", ""))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=60)
+        assert out.returncode == 0 and "ok" in out.stdout, out.stderr
+
+
+class TestSnapshotDuringImport:
+    def test_metrics_snapshot_is_whole_while_dispatch_imports(
+            self, monkeypatch):
+        """A module sits in sys.modules from the moment its import
+        starts: while the first job's thread is still importing
+        parallel/dispatch (and jaxme) the snapshot answers 200 with the
+        parts it cannot fill empty, not 500."""
+        import sys
+        import types
+
+        from thinvids_tpu.api.server import ApiServer
+
+        for name in ("thinvids_tpu.parallel.dispatch",
+                     "thinvids_tpu.codecs.h264.jaxme"):
+            monkeypatch.setitem(sys.modules, name, types.ModuleType(name))
+        api = ApiServer(Coordinator(settings_fn=lambda: make_settings()))
+        status, out = api.route("GET", "/metrics_snapshot", {}, {})
+        assert status == 200
+        assert out["stage_ms"] == {} and out["sfe_latency_ms"] == {}
+        assert out["motion_search"] is None
 
 
 class TestKnobs:

@@ -932,19 +932,23 @@ class ApiServer:
         # drag jax in just to report an empty dict.
         import sys as _sys
 
+        # A module is in sys.modules from the moment its import starts:
+        # while the first job's thread is still importing it the
+        # functions are not there yet, and the answer is {} as well.
         disp = _sys.modules.get("thinvids_tpu.parallel.dispatch")
-        out["stage_ms"] = disp.stage_snapshot() if disp is not None else {}
+        stage_ms = getattr(disp, "stage_snapshot", None)
+        out["stage_ms"] = stage_ms() if stage_ms is not None else {}
         # SFE per-frame latency percentiles — the frame_done_t data,
         # summarized for operators (dashboard SFE line + this snapshot)
-        out["sfe_latency_ms"] = (disp.frame_latency_percentiles()
-                                 if disp is not None else {})
+        sfe_lat = getattr(disp, "frame_latency_percentiles", None)
+        out["sfe_latency_ms"] = sfe_lat() if sfe_lat is not None else {}
         # which motion search this process traced: "pallas" (the TPU
         # kernel) or "xla" (its CPU mirror); None before the first P
         # frame — an operator (and chip_smoke.py) reads here whether
         # the kernel or the mirror served the jobs
         jaxme = _sys.modules.get("thinvids_tpu.codecs.h264.jaxme")
-        out["motion_search"] = (jaxme.motion_search()
-                                if jaxme is not None else None)
+        searched = getattr(jaxme, "motion_search", None)
+        out["motion_search"] = searched() if searched is not None else None
         if self.work is not None:
             out["work"] = self.work.snapshot()
         # origin serving counters + per-job concurrent-session gauges

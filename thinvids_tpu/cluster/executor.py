@@ -166,20 +166,26 @@ class LocalExecutor:
                 segments = self._encode_job(job, token, source, settings,
                                             meta, stage)
 
+            from ..parallel.dispatch import job_clock
+
             stage[0] = "stitch"
             co.heartbeat_job(job.id, token, stage[0], host=self.host)
-            stream = concat_segments(segments)
+            with job_clock("job_stitch"):
+                stream = concat_segments(segments)
             base = os.path.splitext(os.path.basename(job.input_path))[0]
             out_path = os.path.join(self.output_dir, base + ".mp4")
             os.makedirs(self.output_dir, exist_ok=True)
-            data = mux_mp4(stream, meta, audio=audio)
+            with job_clock("job_mux"):
+                data = mux_mp4(stream, meta, audio=audio)
             tmp = f"{out_path}.{job.id}.tmp"    # job-unique: no clobber
                                                 # across same-name jobs
-            with open(tmp, "wb") as fp:
-                fp.write(data)
-            os.replace(tmp, out_path)       # atomic commit (ref: tasks.py:769)
-            co.update_progress(job.id, token, combine_progress=100.0)
-            co.complete_job(job.id, token, out_path, len(data))
+            with job_clock("job_write"):
+                with open(tmp, "wb") as fp:
+                    fp.write(data)
+                os.replace(tmp, out_path)   # atomic commit (ref: tasks.py:769)
+            with job_clock("job_commit"):   # the journal's fsyncs
+                co.update_progress(job.id, token, combine_progress=100.0)
+                co.complete_job(job.id, token, out_path, len(data))
         except HaltedError:
             pass                            # fenced: a newer run owns the job
         except Exception as exc:            # noqa: BLE001 - attribute & fail
@@ -198,11 +204,15 @@ class LocalExecutor:
         (len + slicing + iteration; ingest/decode.py) — treat it as a
         sequence, never materialize it wholesale. `stage` is a
         one-element list the hook mutates for failure attribution."""
+        from ..parallel.dispatch import job_clock
+
         co = self.coordinator
         stage[0] = "segment"
-        enc = self._encoder_factory(meta, settings, self.mesh)
+        with job_clock("job_build"):
+            enc = self._encoder_factory(meta, settings, self.mesh)
         self._bind_trace(job, enc)
-        plan, cut_note = self._plan_on_cuts(enc, frames, settings)
+        with job_clock("job_plan"):
+            plan, cut_note = self._plan_on_cuts(enc, frames, settings)
         co.update_progress(job.id, token, parts_total=plan.num_gops,
                            segment_progress=100.0)
         co.heartbeat_job(job.id, token, stage[0], host=self.host,
@@ -598,19 +608,46 @@ class LocalExecutor:
             job_id=job.id, host=self.host)
 
     @staticmethod
+    @contextlib.contextmanager
     def _maybe_trace(settings, job: Job):
         """jax.profiler trace of the encode stage when `profile_dir` is
         set (SURVEY §5.1: the reference had activity timers only; here
-        per-kernel device timelines land beside the job's events)."""
-        import contextlib
+        per-kernel device timelines land beside the job's events).
 
+        The profile names its own parts: the clocks `profile_start` and
+        `profile_stop` time the profiler's start and its stop (the
+        collection of the trace: tens of seconds for a long job), and
+        the annotation `tvt:encode_stage` marks, on the profiler's
+        clock, where the encode stage begins and ends between them.
+        While it is live every span of the program is an annotation
+        `tvt:<name>` too (obs/trace.annotation), so the `.xplane.pb`
+        says what the host did while the device ran nothing."""
         profile_dir = str(settings.get("profile_dir", "") or "")
         if not profile_dir:
-            return contextlib.nullcontext()
+            yield
+            return
         import jax
 
-        return jax.profiler.trace(
-            os.path.join(profile_dir, f"job-{job.id[:8]}"))
+        from ..parallel.dispatch import job_clock
+
+        # no Python-function events: nothing reads them, and collecting
+        # them stretched the split-frame job's encode stage by an eighth
+        # (1.878 s against 1.658 s, PERF.md §6 PR 35). The host tracer
+        # stays at its default: the annotations are its events.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        with job_clock("profile_start"):
+            jax.profiler.start_trace(
+                os.path.join(profile_dir, f"job-{job.id[:8]}"),
+                profiler_options=options)
+        obs_trace.set_annotation_factory(jax.profiler.TraceAnnotation)
+        try:
+            with jax.profiler.TraceAnnotation("tvt:encode_stage"):
+                yield
+        finally:
+            obs_trace.set_annotation_factory(None)
+            with job_clock("profile_stop"):
+                jax.profiler.stop_trace()
 
     def _encode_vbr2pass(self, job: Job, token: str, enc, frames,
                          settings, meta, target_kbps: float) -> list:

@@ -29,12 +29,20 @@ they executed.
 Sampling: `trace_sample` (0..1) decides PER JOB at trace start whether
 spans record at all; an unsampled job costs one dict lookup per stage.
 Tracing never touches encoded bytes — output is bit-identical with
-tracing on or off (parity-tested). No figure isolates what the spans
-cost: every cell of the benchmark (`benchmark/run.py`) runs with
-`trace_sample` at its default of 1.0, so that cost is inside every
-number it reports.
+tracing on or off (parity-tested). What the spans cost, on the chip:
+less than a 45 s window resolves — `hd-shorts` (43 spans a 1.14 s job)
+with `trace_sample` 1.0 against 0, three pairs: the median job takes
+1.139–1.148 s with spans and 1.147–1.165 s without (PERF.md §6, PR 35).
 
-jax-free by contract (analysis manifest).
+While a device profile is live in the process (the per-job setting
+`profile_dir`, cluster/executor.py) every span also opens an annotation
+`tvt:<name>` on its thread — :func:`annotation` —, so the job's
+`.xplane.pb` holds the host's spans on the profiler's own clock, beside
+the device's ops. With no profile live that costs one attribute read
+and an empty context per span.
+
+jax-free by contract (analysis manifest): the factory of those
+annotations is handed in by the one module that starts a profile.
 """
 
 from __future__ import annotations
@@ -63,6 +71,27 @@ MAX_SPANS_PER_UPLOAD = 10_000
 
 def _now() -> float:
     return time.time()
+
+
+#: what opens an annotation on the device profiler's clock (the
+#: profiler's own annotation class), or None: no profile is live. Set
+#: and cleared by the executor's profile context alone.
+_ANNOTATE = None
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def set_annotation_factory(factory) -> None:
+    """Hand in (or, with None, take away) the context-manager factory
+    `factory(name)` that :func:`annotation` opens spans with."""
+    global _ANNOTATE
+    _ANNOTATE = factory
+
+
+def annotation(name: str):
+    """Context manager that files the span `name` as `tvt:<name>` in
+    the live device profile; an empty one when none is live."""
+    factory = _ANNOTATE
+    return _NO_ANNOTATION if factory is None else factory("tvt:" + name)
 
 
 class SpanRecorder:
@@ -94,7 +123,8 @@ class SpanRecorder:
     @contextlib.contextmanager
     def span(self, name: str, **tags: Any):
         if self._store is None:
-            yield
+            with annotation(name):
+                yield
             return
         # wall clock anchors the span on the trace timeline; the
         # DURATION comes from the monotonic clock (an NTP step mid-span
@@ -103,7 +133,8 @@ class SpanRecorder:
         t0 = _now()
         p0 = time.perf_counter()
         try:
-            yield
+            with annotation(name):
+                yield
         finally:
             self.record(name, t0, time.perf_counter() - p0, **tags)
 

@@ -87,7 +87,7 @@ from ..core.log import get_logging
 # bridge into the Prometheus registry, and a bound span recorder (the
 # executor wires one per traced job) turns every timed stage into a
 # span in the job's distributed trace
-from ..obs import metrics as obs_metrics
+from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..core.types import (BandPlan, ChromaFormat, EncodedSegment, Frame,
                           GopSpec, SegmentPlan, VideoMeta)
 from ..codecs.h264 import jaxcore
@@ -186,8 +186,7 @@ class StageProfile:
         #: _TOTALS, which forwards)
         self._metrics = bool(metrics)
         #: optional span recorder (obs/trace): the executor binds one
-        #: per traced job so each timed stage also records a span in
-        #: the job's distributed trace. None = zero tracing overhead.
+        #: per traced job, so each timed stage is a span of its trace
         self._tracer = None
 
     def set_tracer(self, recorder) -> None:
@@ -223,14 +222,15 @@ class StageProfile:
 
     @contextlib.contextmanager
     def stage(self, name: str, part_of: str | None = None, **tags):
-        """Time a stage (and record its span). `part_of` names the
-        stage this one is a part of: it receives the same seconds, so
-        the whole stays the sum of its parts."""
+        """Time a stage (and record its span; `tvt:<name>` in a live
+        device profile). `part_of` names the stage this one is a part
+        of: it gets the same seconds, the whole stays its parts' sum."""
         tracer = self._tracer
         t0_wall = time.time() if tracer is not None else 0.0
         t0 = time.perf_counter()
         try:
-            yield
+            with obs_trace.annotation(name):
+                yield
         finally:
             dt = time.perf_counter() - t0
             self.add(name, dt)
@@ -2327,3 +2327,24 @@ def job_stage_profile() -> StageProfile:
     process owns (the remote coordinator's look for scene cuts): it
     mirrors into the process totals as an encoder's does."""
     return StageProfile(mirror=_TOTALS)
+
+
+#: the executor's clocks for the phases of a job that no encoder's
+#: profile times, in the order a job passes them: starting the device
+#: profile of a job with `profile_dir`, building the encoder, the plan
+#: (`scenecut` nests in it), [the wave pipeline], stopping the profile
+#: (its collection), joining the segments, the MP4 mux, write + rename,
+#: the journal's completion records. They live in the process totals
+#: alone (`/metrics_snapshot.stage_ms`, `tvt_stage_seconds_total`) and
+#: never in a job's span ring: the benchmark takes the extent of the
+#: wave pipeline from that ring's first and last span (PERF.md §7).
+JOB_CLOCKS = ("profile_start", "job_build", "job_plan", "profile_stop",
+              "job_stitch", "job_mux", "job_write", "job_commit")
+for _clock in JOB_CLOCKS:       # keys of the first snapshot already: a
+    _TOTALS.add(_clock, 0.0)    # reader takes growth between two
+
+
+def job_clock(name: str):
+    """Context manager: the stage clock `name` (one of JOB_CLOCKS) of
+    the process totals, `tvt:<name>` in a live device profile."""
+    return _TOTALS.stage(name)
