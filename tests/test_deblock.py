@@ -211,6 +211,63 @@ class TestKernel:
         assert "tvt.deblock/tvt_deblock_wavefront" in text
 
 
+@pytest.mark.parametrize("L", [32 * 8160 * 384, 8160 * (32 * 384 + 2)])
+def test_levels_rewording_compiles_for_the_chip_at_1080p(L, monkeypatch):
+    """ISSUE 37, kept in this file because one test file may describe
+    the TPU topology (one process holds libtpu): the program that
+    re-words a wave's int16 levels as int32 words compiles for a
+    described v5e at the served 1080p GOP shapes (the library's, and
+    the serving set's with its mode tail, which does not fill its last
+    row), wants four copies of the levels in HBM beside them and no
+    more, and EVERY instruction of it that does work is filed under
+    `tvt.pack` by the TPU's compiler too — which `dev_unscoped_pct`
+    rests on: in other forms of the same arithmetic the compiler's own
+    relayout of the one-row array roots a fusion and takes the name
+    away (PERF.md §6 PR 37; the served process's compile still differs
+    from this one by a relayout of the result, and its profile shows
+    the fusion before that without a name: §7). The plain form
+    (`lax.bitcast_convert_type` of (L / 2, 2) pairs) is REFUSED there:
+    the minor dimension of 2 is laid out on 128 lanes, 25.7 GB."""
+    import os
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from thinvids_tpu.parallel import dispatch
+
+    monkeypatch.setitem(os.environ, "TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:          # no TPU compiler in this image
+        pytest.skip(f"no v5e topology can be described here: {exc}")
+    levels = jax.ShapeDtypeStruct(
+        (1, L), jnp.int16, sharding=SingleDeviceSharding(topo.devices[0]))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = dispatch._levels_as_words.lower(levels).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    memory = compiled.memory_analysis()
+    rows = -(-L // dispatch._WORD_ROW)
+    assert memory.output_size_in_bytes == rows * dispatch._WORD_ROW * 2
+    assert memory.temp_size_in_bytes <= 4.1 * 2 * L
+    text = compiled.as_text()
+    working = []
+    for line in text[text.index("ENTRY"):].splitlines():
+        found = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \S+ ([\w\-]+)\(", line)
+        if found and found.group(1) not in (
+                "constant", "parameter", "bitcast", "copy-start",
+                "copy-done"):
+            working.append(line)
+    assert len(working) >= 3
+    for line in working:
+        assert 'op_name="jit(_levels_as_words)/tvt.pack/' in line, line
+
+
 class TestBandSplit:
     """A split-frame band is a slice with disable_deblocking_filter_idc
     2: it filters its own rows, and no edge between two bands."""

@@ -18,9 +18,13 @@ Held here, at small sizes on the CPU:
   moves it is seen;
 - the counters and stages that say so (`sparse_*`, `dense_retry` =
   `dense_fetch`; `dense_reencode` stays a key and reads 0);
-- a wave that leaves the budgets runs ONE device program, a wave inside
+- a wave that leaves the budgets runs ONE encode program, a wave inside
   them drops the levels before the next is dispatched and fetches not
   a byte more, and `dispatch_wave` starts no copy of the levels;
+- the levels of a wave that leaves the budgets cross as int32 words
+  (ISSUE 37: `_levels_as_words`, one small program per such wave and
+  none for any other), and the words viewed as int16 on the host are
+  the program's levels element for element;
 - the benchmark's own copy of the generator gives the same planes.
 """
 
@@ -82,6 +86,13 @@ def _plain(frames, segments, qp, rd=None, w=W, h=H, **kw):
 #: the parent of ISSUE 31 (9267aa0) fetched it: counts, MVs, dense DC
 #: prefix and the payloads' used prefixes, two waves
 D2H_BYTES_OF_2_0 = 10518
+
+
+def _words(L):
+    """int32 words the L levels of a GOP (or all-intra frame) cross the
+    link as: pairs, in whole rows of `dispatch._WORD_ROW` levels."""
+    row = dispatch._WORD_ROW
+    return -(-L // row) * (row // 2)
 
 
 def _went_dense(sigmas):
@@ -279,8 +290,10 @@ class TestOneProgramPerWave:
         fetch = handle[-1]
         assert not fetch.sparse_ok and fetch.payload is None  # no slice
         segs = enc.collect_wave(handle)
+        # one encode program; the re-wording of its levels (ISSUE 37)
+        # is the one other thing the wave puts on the compute queue
         assert calls == ["_encode_gop_single" if devices == 1
-                         else "_encode_wave_gop"]
+                         else "_encode_wave_gop", "_levels_as_words"]
         assert enc.stages.snapshot()["dense_fallback_waves"] == 1
         assert [s.payload for s in segs] == _plain(frames, segs, 27)
 
@@ -334,6 +347,115 @@ class TestOneProgramPerWave:
         assert len(out) == {"compact": 7, "sparse2": 8, "intra": 6}[path]
 
 
+def _noise(n, w=W, h=H, seed=11):
+    """White-noise frames: every coefficient of an all-intra frame is
+    a level, so the frame's sparse pack overflows at any QP."""
+    from thinvids_tpu.core.types import Frame
+
+    rng = np.random.default_rng(seed)
+    return [Frame(y=rng.integers(0, 256, (h, w), np.uint8),
+                  u=rng.integers(0, 256, (h // 2, w // 2), np.uint8),
+                  v=rng.integers(0, 256, (h // 2, w // 2), np.uint8))
+            for _ in range(n)]
+
+
+class TestLevelsCrossAsWords:
+    """ISSUE 37: what crosses the link for a wave that left the sparse
+    budgets is `_levels_as_words` of its program's last output — the
+    same bytes as 32-bit words — and the host's int16 view of the
+    words is that output, element for element."""
+
+    #: name -> (encoder arguments, devices, frames, shape of the levels
+    #: as (GOPs, frames or None), ships_modes)
+    WAVES = {
+        "one_gop": (dict(), 1, lambda: _clip((5.0,)), (1, None)),
+        "two_gops_a_device": (dict(gops_per_wave=2), 1,
+                              lambda: _clip((5.0, 6.0)), (2, None)),
+        "serving_set": (dict(rd=RdConfig(**RD_SERVING)), 1,
+                        lambda: _clip((6.0,)), (1, None)),
+        "mesh_of_two": (dict(), 2, lambda: _clip((0.0, 6.0)), (2, None)),
+        "all_intra": (dict(inter=False), 1, lambda: _noise(3), (1, 3)),
+        "all_intra_serving_modes": (
+            dict(inter=False, rd=RdConfig(mode_decision=True)), 2,
+            lambda: _noise(4), (2, 2)),
+    }
+
+    @pytest.mark.parametrize("wave", sorted(WAVES))
+    def test_words_viewed_as_int16_are_the_programs_levels(self, wave):
+        kwargs, devices, make, (G, F) = self.WAVES[wave]
+        frames = make()
+        qp = 25 if "rd" in kwargs else 27
+        meta = VideoMeta(width=W, height=H, num_frames=len(frames))
+        enc = GopShardEncoder(
+            meta, qp=qp, gop_frames=GOP if F is None else F,
+            mesh=default_mesh(jax.devices()[:devices]), **kwargs)
+        (staged,) = enc.stage_waves(frames)
+        handle = enc.dispatch_wave(staged)
+        fetch = handle[-1]
+        levels = fetch.dense
+        L, _Lr = enc._level_sizes(GOP, (W // 16) * (H // 16))
+        assert enc.rd.ships_modes == ("rd" in kwargs)
+        assert levels.dtype == jnp.int16
+        assert levels.shape == ((G, L) if F is None else (G, F, L))
+        enc.start_fetch(handle)
+        assert not fetch.sparse_ok
+        words = fetch.dense
+        assert words.dtype == jnp.int32
+        assert words.shape == levels.shape[:-1] + (_words(L),)
+        # a wave sharded over `gop` stays sharded
+        assert words.sharding.is_equivalent_to(levels.sharding, words.ndim)
+        assert len(words.sharding.device_set) == devices
+        host = np.asarray(words).view(np.int16)
+        assert np.array_equal(host[..., :L], np.asarray(levels))
+        assert not host[..., L:].any()          # the last row's padding
+        for half in (0, 1):     # both halves carry both signs
+            part = host[..., half:L:2]
+            assert part.min() < 0 < part.max()
+        segs = enc.collect_wave(handle)
+        assert fetch.dense is None              # released once fetched
+        assert enc.stages.snapshot()["dense_fallback_waves"] == 1
+        if enc.inter:
+            assert [s.payload for s in segs] == _plain(
+                frames, segs, qp, rd=kwargs.get("rd"))
+
+    @pytest.mark.parametrize("shape", [(1, 2), (1, 254), (1, 255),
+                                       (2, 258), (2, 3, 770), (3, 769),
+                                       (1, 1 << 17)])
+    def test_every_int16_value_survives_in_either_half(self, shape):
+        """The program alone, on every int16 value in the low and in
+        the high half of a word (the levels pass through f32 and a
+        matmul on the way: exact, or this fails), for lengths that do
+        and do not fill their last row — an odd one too (the transfer
+        layout has none: every MB has an even count), which the row
+        padding carries in the same one format."""
+        every = np.arange(-32768, 32768).astype(np.int16)
+        levels = np.resize(np.concatenate([np.repeat(every, 2), every]),
+                           shape)
+        words = np.asarray(dispatch._levels_as_words(jnp.asarray(levels)))
+        assert words.dtype == np.int32
+        assert words.shape == shape[:-1] + (_words(shape[-1]),)
+        host = words.view(np.int16)
+        assert np.array_equal(host[..., :shape[-1]], levels)
+        assert not host[..., shape[-1]:].any()
+
+    @pytest.mark.parametrize("clip", sorted(CLIPS))
+    def test_one_rewording_per_dense_wave_and_none_for_a_sparse_one(
+            self, clip, monkeypatch):
+        sigmas = CLIPS[clip]
+        frames = _clip(sigmas)
+        meta = VideoMeta(width=W, height=H, num_frames=len(frames))
+        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
+        calls = _count_programs(monkeypatch)
+        segs = enc.encode(frames)
+        assert [s.payload for s in segs] == _plain(frames, segs, 27)
+        snap = enc.stages.snapshot()
+        assert calls.count("_encode_gop_single") == snap["waves"] \
+            == len(sigmas)
+        assert calls.count("_levels_as_words") \
+            == snap["dense_fallback_waves"] == sum(_went_dense(sigmas))
+        assert set(calls) <= {"_encode_gop_single", "_levels_as_words"}
+
+
 class TestTheRecordSaysWhichHalfCosts:
     def test_dense_retry_is_the_sum_of_its_halves_and_both_are_spans(self):
         """Since ISSUE 31 there is one half: `dense_fetch`. The key
@@ -357,11 +479,12 @@ class TestTheRecordSaysWhichHalfCosts:
         assert snap["fetch"] > 0 and names.count("fetch") == 2
 
     def test_the_levels_are_sent_by_start_fetch_before_the_next_wave(self):
-        """The order rule needs no program for the fallback: the
-        levels are on the wave's handle from dispatch on; start_fetch
-        keeps them (and starts their copy) where the budgets gave way,
-        and drops them where they held, in which case the wave gets
-        its payload slices. collect_wave then only waits."""
+        """The order rule holds for the fallback as for the payload
+        slice: the levels are on the wave's handle from dispatch on;
+        start_fetch re-words them (int16 pairs as int32 words, the
+        same bytes) and starts the copy of the words where the budgets
+        gave way, and drops them where they held, in which case the
+        wave gets its payload slices. collect_wave then only waits."""
         frames = _clip((5.0, 0.0))
         meta = VideoMeta(width=W, height=H, num_frames=len(frames))
         enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
@@ -371,11 +494,16 @@ class TestTheRecordSaysWhichHalfCosts:
         assert levels is not None and first[-1].tiny is None
         assert levels.dtype == jnp.int16 and levels.shape[0] == 1
         enc.start_fetch(first)
-        assert first[-1].dense is levels and not first[-1].sparse_ok
+        words = first[-1].dense
+        assert words.dtype == jnp.int32 and not first[-1].sparse_ok
+        L = levels.shape[1]
+        assert words.shape == (1, _words(L))
+        assert np.array_equal(np.asarray(words).view(np.int16)[:, :L],
+                              np.asarray(levels))
         assert first[-1].payload is None
         second = enc.dispatch_wave(clean)
         enc.start_fetch(first)                  # idempotent
-        assert first[-1].dense is levels
+        assert first[-1].dense is words
         enc.start_fetch(second)
         assert second[-1].sparse_ok and second[-1].dense is None
         assert second[-1].payload is not None

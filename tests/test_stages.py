@@ -231,6 +231,31 @@ def test_what_the_levels_output_adds_is_filed_under_a_stage(case):
     assert not unscoped, unscoped
 
 
+@pytest.mark.parametrize("shape,mesh", [((1, 2 * 1152), False),
+                                        ((2, 3, 770), False),
+                                        ((2, 2 * 1152), True)])
+def test_the_rewording_of_the_levels_is_filed_under_pack(shape, mesh):
+    """ISSUE 37: a wave that left the sparse budgets runs one more
+    program, `_levels_as_words` (its int16 levels as int32 words for
+    the link). Every instruction of it that does work carries
+    `tvt.pack` and no other stage, so the stages still sum to busy and
+    `dev_unscoped_pct` gets nothing — in the GOP form (G, L), the
+    all-intra form (G, F, L) and sharded over a `gop` mesh."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    sharding = (NamedSharding(_gop_mesh(), PartitionSpec("gop"))
+                if mesh else None)
+    levels = jax.ShapeDtypeStruct(shape, jnp.int16, sharding=sharding)
+    paths = _working_paths(
+        dispatch._levels_as_words.lower(levels).compile().as_text())
+    assert len(paths) >= 3, "the compiled module was not read"
+    for path in paths:
+        scopes = [part for part in path.split("/")
+                  if part.startswith(PREFIX)]
+        assert scopes == [PREFIX + "pack"], path
+        assert path.startswith("jit(_levels_as_words)/"), path
+
+
 def _lower_pack2(budget_div, val_div):
     from thinvids_tpu.codecs.h264 import jaxcore
 

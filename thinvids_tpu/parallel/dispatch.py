@@ -124,9 +124,9 @@ def default_mesh(devices=None) -> Mesh:
 #: host on top of the common path — split out of "fetch" so the fetch
 #: number answers only "what does the COMMON bulk transfer cost". On
 #: the GOP wave path that is dense_fetch alone: waiting for the copy
-#: of the whole int16 levels, which the wave's one program left on the
-#: device and start_fetch sent on their way. No wave runs a second
-#: program, so dense_reencode reads 0; it stays a key because the
+#: of the whole levels, which the wave's one program left on the device
+#: and start_fetch re-worded (int16 pairs as int32 words) and sent. No
+#: wave is encoded twice, so dense_reencode reads 0; it stays a key: the
 #: benchmark's files still ask for it (PERF.md §7). The split-frame
 #: escape fallback re-runs its steps dense and files steps, copies and
 #: packs per frame under dense_retry alone;
@@ -558,7 +558,7 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
     the host checks the nnz/escape counts for the rare dense fallback,
     which fetches the last output: the levels themselves as int16
     (covers the full CAVLC level range), (G, F, L), left on the device
-    like the GOP programs' (_per_gop_sparse).
+    and re-worded for the link like the GOP programs' (_per_gop_sparse).
     """
 
     def per_gop(y_g, u_g, v_g, qp_g):
@@ -583,6 +583,53 @@ def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
     return shard(ys, us, vs, qps)
 
 
+#: levels in a row of the re-wording program (_levels_as_words): the
+#: matrix unit permutes within a row, 256 lanes of it at a time
+_WORD_ROW = 256
+
+
+@jax.jit
+@stage("pack")
+def _levels_as_words(levels):
+    """A wave's whole levels re-worded for the link: (..., L) int16 →
+    (..., ceil(L / 256) * 128) int32, word k holding level 2k in its
+    low half and level 2k + 1 in its high half — the same bytes in the
+    same order on a little-endian host, where `words.view(np.int16)` is
+    the levels again, followed by under a row of zeros. No value
+    changes. The link moves 32-bit words seven times faster than a
+    one-row int16 array, which it re-lays on the way (0.075 s against
+    0.54 s for the 199 MB of a 1080p GOP, PERF.md §6 PR 37). A program
+    of its own, enqueued by start_fetch for a wave that left the
+    sparse budgets and for no other: the wave programs above are not
+    touched, and a wave that holds the budgets never runs (or
+    compiles) it. Every op keeps the leading dimensions, so a wave
+    sharded over `gop` stays sharded.
+
+    Neighbours in a row are a stride of 2 along the lanes, which the
+    chip's vector unit takes slowly (11–26 ms a slice at 1080p, and
+    `lax.bitcast_convert_type` of (L / 2, 2) pairs does not compile:
+    the minor 2 is laid out on 128 lanes); its matrix unit permutes a
+    row's lanes in passing. So each row of 256 levels, as unsigned
+    16-bit values in f32, goes through one matmul with the permutation
+    "evens, then odds" — exact at Precision.HIGHEST: every output is
+    one product by 1 and a sum with zeros, of a value under 2**16."""
+    *lead, L = levels.shape
+    rows = -(-L // _WORD_ROW)
+    pad = [(0, 0)] * len(lead) + [(0, rows * _WORD_ROW - L)]
+    rowed = jnp.pad(levels, pad).reshape(*lead, rows, _WORD_ROW)
+    half = _WORD_ROW // 2
+    order = np.concatenate([np.arange(0, _WORD_ROW, 2),
+                            np.arange(1, _WORD_ROW, 2)])
+    evens_then_odds = jnp.asarray(
+        np.eye(_WORD_ROW, dtype=np.float32)[:, order])
+    u16 = (rowed.astype(jnp.int32) & 0xFFFF).astype(jnp.float32)
+    turned = jax.lax.dot_general(
+        u16, evens_then_odds, (((u16.ndim - 1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+    words = turned[..., :half] | (turned[..., half:] << 16)
+    return words.reshape(*lead, rows * half)
+
+
 class _WaveFetch:
     """The fetch state of a dispatched wave, on its handle between
     :meth:`GopShardEncoder.dispatch_wave`,
@@ -593,7 +640,9 @@ class _WaveFetch:
     tiny counts, whether the sparse budgets held, and either the
     payload's used prefixes already sliced on the device and on their
     way to the host (`dense` is then dropped and its HBM goes back) or,
-    where the budgets did not hold, `dense` on its way likewise. The
+    where the budgets did not hold, `dense` replaced by the levels as
+    int32 words (:func:`_levels_as_words`; the int16 form goes once
+    that program has read it) and those on their way likewise. The
     lock makes the step run once whoever comes first (the dispatch
     loop, or the wave's own collector thread)."""
 
@@ -1189,11 +1238,14 @@ class GopShardEncoder:
         last output of its one program (:class:`_WaveFetch`). Budgets
         held: the reference is dropped here, so the HBM goes back
         before the next wave's program is enqueued and the levels never
-        cross. Budgets left: their copy to the host is started here —
-        a transfer, nothing on the compute queue — and the 199 MB of a
-        1080p GOP cross under the next wave's compute (with the device
-        idle they cost 0.53 s per GOP, PERF.md §6 PR 30). No wave is
-        encoded twice.
+        cross. Budgets left: one small program re-words them here
+        (:func:`_levels_as_words`: int16 pairs as int32 words, the form
+        the link moves fast) — on the compute queue ahead of the next
+        wave's program, as the payload slice is — and the copy of the
+        words is started: the 199 MB of a 1080p GOP cross under the
+        next wave's compute, and after a job's last wave, with nothing
+        to hide under, in a seventh of the time the int16 form took
+        (PERF.md §6 PR 37). No wave is encoded twice.
         Idempotent, and :meth:`collect_wave` performs it itself for
         callers that have not."""
         _wave, ysd, _usd, _vsd, _qpsd, mbw, mbh, out, fetch = pending
@@ -1232,6 +1284,7 @@ class GopShardEncoder:
                 if fetch.sparse_ok:
                     fetch.dense = None
                 else:
+                    fetch.dense = _levels_as_words(fetch.dense)
                     self._start_copies([fetch.dense])
             fetch.tiny = tiny
 
@@ -1271,9 +1324,9 @@ class GopShardEncoder:
                     bitmap, vals, esc_pos, esc_val = \
                         self._fetch_bulk(out[2:6])
         if not sparse_ok:
-            # Wave-wide dense fallback: the wide int16 fetch of the
-            # levels the wave's program left on the device, started by
-            # start_fetch. Not rare on grainy footage: white grain of
+            # Wave-wide dense fallback: the wide fetch of the levels
+            # the wave's program left on the device, re-worded and sent
+            # by start_fetch. Not rare on grainy footage: white grain of
             # sigma 3.5 at CQP 27 already fills the block budget
             # (jaxcore _VAL_BUDGET_DIV has the table), and every GOP of
             # such a clip comes through here. Its own stage (not
@@ -1282,8 +1335,10 @@ class GopShardEncoder:
             # overflow-prone content is visible in metrics.
             prof.bump("dense_fallback_waves")
             with prof.stage("dense_fetch", part_of="dense_retry"):
-                flat = jax.device_get(fetch.dense)
+                words = jax.device_get(fetch.dense)
                 fetch.dense = None      # the device's copy may go
+                # the levels again, no copy (less the last row's padding)
+                flat = words.view(np.int16)[..., :L]
                 prof.bump("d2h_bytes", int(flat.nbytes))
                 if self.inter:
                     # MVs come from the sparse outputs, as ever
