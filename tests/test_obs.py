@@ -525,6 +525,13 @@ class TestLocalE2E:
         stages_seen = {labels["stage"]
                        for _n, labels, v in stage["samples"] if v > 0}
         assert {"dispatch", "device_wait", "pack"} <= stages_seen
+        # the set-up of this process's executables: a stage clock of
+        # the process totals and a counter beside it (ISSUE 39)
+        assert "program_build" in {
+            labels["stage"] for _n, labels, _v in stage["samples"]}
+        built = fams["tvt_programs_built_total"]
+        assert built["type"] == "counter"
+        assert all(v >= 0 for _n, _labels, v in built["samples"])
         assert fams["tvt_origin_requests_total"]["type"] == "counter"
         assert fams["tvt_qos_breaches_total"]["type"] == "counter"
         assert fams["tvt_qos_preempting"]["type"] == "gauge"

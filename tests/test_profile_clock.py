@@ -8,6 +8,11 @@ the `.xplane.pb`, on the profiler's clock. Every job's phases outside
 the wave pipeline are clocked (`job_build` … `job_commit`) in the
 process totals — and stay OUT of `GET /trace/<job>`, whose first and
 last span the benchmark takes for the extent of the wave pipeline.
+
+ISSUE 39: so is the set-up of an executable. The clock `program_build`
+runs round the first call of each GOP / step program of the process
+(form, RdConfig, shape) and `programs_built` counts them; a second job
+of that shape starts neither, and neither is a span of a job's ring.
 """
 
 import os
@@ -35,13 +40,13 @@ JOB_CLOCKS = ("job_build", "job_plan", "job_stitch", "job_mux",
               "job_write", "job_commit")
 
 
-def run_job(tmp_path, name, encoder_factory=None, **settings):
+def run_job(tmp_path, name, encoder_factory=None, width=64, **settings):
     """(coordinator, finished job) of one 8-frame 64x48 local job in
     four GOPs, run in the calling thread."""
-    meta = VideoMeta(width=64, height=48, fps_num=30, fps_den=1,
+    meta = VideoMeta(width=width, height=48, fps_num=30, fps_den=1,
                      num_frames=8)
     clip = tmp_path / f"{name}.y4m"
-    write_y4m(str(clip), meta, make_frames(8, 64, 48))
+    write_y4m(str(clip), meta, make_frames(8, width, 48))
     snap = Settings(values=dict(
         DEFAULT_SETTINGS, gop_frames=2, qp=30, heartbeat_throttle_s=0.0,
         **settings))
@@ -195,3 +200,98 @@ class TestJobClocks:
         assert status == 200
         names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
         assert names == PIPELINE_SPANS
+
+
+@pytest.fixture
+def no_program_yet(monkeypatch):
+    """This process as if it had called no step program: what other
+    tests of the worker built is forgotten for the test (the jit cache
+    may still hold the executable: the first call is clocked either
+    way, a cache load being one way to set a program up)."""
+    from thinvids_tpu.parallel import dispatch
+
+    monkeypatch.setattr(dispatch, "_PROGRAMS_BUILT", set())
+    return dispatch
+
+
+def with_rd(rd):
+    def factory(meta, settings, mesh):
+        from thinvids_tpu.parallel.dispatch import GopShardEncoder
+
+        return GopShardEncoder(meta, qp=int(settings.qp), mesh=mesh,
+                               gop_frames=int(settings.gop_frames), rd=rd)
+    return factory
+
+
+class TestProgramBuild:
+    #: what the second job differs in -> executables it sets up
+    SECOND = {"nothing": 0, "shape": 1, "rd": 1}
+
+    @pytest.mark.parametrize("differs", sorted(SECOND))
+    def test_counted_once_per_form_rd_and_shape(self, tmp_path,
+                                                no_program_yet, differs):
+        from thinvids_tpu.codecs.h264.rdo import RD_OFF, RdConfig
+
+        s0 = stage_ms()
+        _c, first = run_job(tmp_path, "first", with_rd(RD_OFF))
+        s1 = stage_ms()
+        assert first.status is Status.DONE, first.failure_reason
+        # however many waves the job had, ONE executable: the first
+        # call's
+        assert grew(s0, s1, "programs_built") == 1
+        assert grew(s0, s1, "program_build") > 0
+        assert grew(s0, s1, "program_build") <= grew(s0, s1, "dispatch")
+        _c, second = run_job(
+            tmp_path, "second",
+            with_rd(RdConfig(pskip=True) if differs == "rd" else RD_OFF),
+            width=80 if differs == "shape" else 64)
+        s2 = stage_ms()
+        assert second.status is Status.DONE, second.failure_reason
+        assert grew(s1, s2, "programs_built") == self.SECOND[differs]
+        assert (grew(s1, s2, "program_build") > 0) == \
+            bool(self.SECOND[differs])
+
+    def test_the_log_names_the_executable_once(self, tmp_path,
+                                               no_program_yet, caplog):
+        import logging
+
+        with caplog.at_level(logging.INFO,
+                             logger="thinvids_tpu.parallel.dispatch"):
+            run_job(tmp_path, "a")
+            run_job(tmp_path, "b")
+        lines = [r.getMessage() for r in caplog.records
+                 if r.getMessage().startswith("program built")]
+        assert len(lines) == 1
+        assert "form=scan" in lines[0] and "RdConfig(" in lines[0]
+        assert "shape=(" in lines[0] and ", 48, 64)" in lines[0]
+
+    def test_a_live_profile_holds_it_and_no_ring_does(self, tmp_path,
+                                                      no_program_yet):
+        """`tvt:program_build` inside `tvt:dispatch` in the profiled
+        job's `.xplane.pb`; the job's ring keeps the pipeline's spans
+        alone, so `job_fixed_ms` and `mux_ms_per_job` read what they
+        read."""
+        from thinvids_tpu.api.server import ApiServer
+
+        coord, job = run_job(tmp_path, "live",
+                             profile_dir=str(tmp_path / "profiles"))
+        assert job.status is Status.DONE, job.failure_reason
+        notes = host_annotations(tmp_path / "profiles")
+        built = [n for n in notes if n[1] == "tvt:program_build"]
+        assert len(built) == 1
+        line, _name, lo, hi = built[0]
+        assert any(n[0] == line and n[1] == "tvt:dispatch"
+                   and n[2] <= lo and hi <= n[3] for n in notes)
+        status, doc = ApiServer(coord).route(
+            "GET", f"/trace/{job.id}", {}, {})
+        assert status == 200
+        names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
+        assert names == PIPELINE_SPANS
+
+    def test_the_first_snapshot_has_both_keys(self):
+        """A reader takes growth between two snapshots, and the
+        benchmark's `program_build_s` the first one's value."""
+        snap = stage_ms()
+        assert snap["program_build"] >= 0
+        assert isinstance(snap["programs_built"], int)
+

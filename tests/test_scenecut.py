@@ -16,9 +16,17 @@ stage. Held here, at small sizes on the CPU:
   plan, two decoders agree on it, a clip without cuts keeps its bytes,
   one program shape whatever the shots, and band and live jobs keep
   their fixed grid;
+- the same at the serving point (ISSUE 39, `serving-1080p-edited`: the
+  cuts with mode decision, P_Skip, the in-loop filter and AQ inside
+  the bounded P-frame loop): the cases of `TestThroughTheCoordinator`
+  that take an operating point run at the library's and the serving
+  one, and there the decode also equals the encoder's own
+  reconstruction. Every comparison is for equality: the encoder is
+  integer-exact, so each tolerance is zero;
 - the benchmark's own copy of the generator gives the same planes.
 """
 
+import contextlib
 import functools
 import importlib.util
 import os
@@ -32,10 +40,13 @@ import jax.numpy as jnp
 from thinvids_tpu.cluster import Coordinator, WorkerRegistry
 from thinvids_tpu.codecs.h264 import jaxinter
 from thinvids_tpu.codecs.h264.layout import unflatten_gop
-from thinvids_tpu.codecs.h264.rdo import RD_OFF, RdConfig
+from thinvids_tpu.codecs.h264.rdo import (RD_OFF, RdConfig,
+                                          rd_from_settings)
 from thinvids_tpu.cluster.executor import LocalExecutor
 from thinvids_tpu.core.config import (DEFAULT_SETTINGS, JOB_SETTING_KEYS,
-                                      Settings, overlay_job_settings)
+                                      Settings, overlay_job_settings,
+                                      reset_live_settings,
+                                      update_live_settings)
 from thinvids_tpu.core.status import Status
 from thinvids_tpu.core.types import GopSpec, SegmentPlan, VideoMeta
 from thinvids_tpu.io.mp4 import read_mp4
@@ -337,10 +348,35 @@ def _source(tmp_path, frames, name="clip.y4m"):
     return str(path)
 
 
-def _run(tmp_path, name, path, job_settings=None, mesh=None, **settings):
-    coord, _ = _rig(tmp_path, name, mesh=mesh, **settings)
+#: the operating points of the two edited-footage deployments, as a
+#: daemon gets them: `TVT_*` environment = the live settings, which is
+#: where an encoder built by `make_shard_encoder` reads its RdConfig
+#: (library-1080p-edited: the defaults; serving-1080p-edited: README's
+#: serving point, aq_strength 1.0 = aq_q 4)
+POINTS = {"library": {},
+          "serving": dict(qp=25, mode_decision=True, pskip=True,
+                          deblock=True, aq_strength=1.0)}
+POINT_RD = {point: rd_from_settings(make_settings(**values))
+            for point, values in POINTS.items()}
+
+
+@contextlib.contextmanager
+def _operating_point(point):
+    if POINTS[point]:
+        update_live_settings(POINTS[point])
+    try:
+        yield
+    finally:
+        reset_live_settings()
+
+
+def _run(tmp_path, name, path, job_settings=None, mesh=None,
+         point="library", **settings):
+    coord, _ = _rig(tmp_path, name, mesh=mesh,
+                    **{**POINTS[point], **settings})
     before = dispatch.stage_snapshot()
-    job = coord.add_job(path, META, settings=job_settings)
+    with _operating_point(point):
+        job = coord.add_job(path, META, settings=job_settings)
     job = coord.store.get(job.id)
     assert job.status is Status.DONE, job.failure_reason
     after = dispatch.stage_snapshot()
@@ -360,10 +396,21 @@ def served(edited):
     return _run(tmp, "on", path, scenecut=40)
 
 
+@pytest.fixture(scope="module", params=sorted(POINTS))
+def served_at(request, edited):
+    """(operating point, `_run`'s result) of the edited clip with
+    `scenecut` 40 at each operating point; the library's is `served`."""
+    if request.param == "library":
+        return request.param, request.getfixturevalue("served")
+    tmp, _frames, path = edited
+    return request.param, _run(tmp, "on-" + request.param, path,
+                               point=request.param, scenecut=40)
+
+
 class TestThroughTheCoordinator:
-    def test_stss_is_the_plain_rules_gop_starts(self, edited, served):
+    def test_stss_is_the_plain_rules_gop_starts(self, edited, served_at):
         _tmp, frames, _path = edited
-        _coord, job, grew = served
+        _point, (_coord, job, grew) = served_at
         cuts, suppressed = scenecut_plain.scene_cuts(_lumas(frames),
                                                      GOP, 40)
         assert cuts == cut_frames(N, SHOTS) == [11, 18, 31]
@@ -376,6 +423,9 @@ class TestThroughTheCoordinator:
         assert grew["scenecut"] > 0 and grew["waves"] == len(want)
         assert grew["wave_frames"] == len(want) * GOP
         assert grew["pad_frames"] == len(want) * GOP - N
+        # every wave ran the bounded loop, none left the sparse budgets
+        assert grew["pad_frames_skipped"] == grew["pad_frames"]
+        assert grew["dense_fallback_waves"] == 0
 
     def test_the_note_and_the_span(self, edited, monkeypatch):
         from thinvids_tpu.obs import trace as obs_trace
@@ -396,18 +446,43 @@ class TestThroughTheCoordinator:
         assert names.count("scenecut") == 1
         assert names.index("scenecut") < names.index("stage")
 
-    def test_both_decoders_decode_it_alike(self, edited, served):
+    def test_both_decoders_decode_it_alike(self, edited, served_at):
+        """... and both equal the encoder's own reconstruction, sample
+        for sample (tolerance zero), first frame after each cut
+        included: at the serving point an IDR with AQ's per-MB QP, then
+        P frames whose reference the in-loop filter has been over."""
         from thinvids_tpu.codecs.h264.decoder import decode_annexb
+        from thinvids_tpu.codecs.h264.encoder import encode_gop
         from thinvids_tpu.tools import oracle
         from thinvids_tpu.tools.metrics import psnr
 
         _tmp, frames, _path = edited
-        media = read_mp4(served[1].output_path)
+        point, (_coord, job, _grew) = served_at
+        media = read_mp4(job.output_path)
         stream = media.annexb_for(0, media.num_frames)
+        # the reconstruction the encoder keeps, GOP by GOP of the plan:
+        # the one-GOP program at that GOP's own length (the scan form)
+        # gives the bytes the served job wrote for it, and its recon
+        starts = media.sync_samples() + [N]
+        qp = POINTS[point].get("qp", 27)
+        recon = []
+        for i, (a, b) in enumerate(zip(starts, starts[1:])):
+            gop, planes = encode_gop(frames[a:b], META, qp=qp,
+                                     idr_pic_id=i, return_recon=True,
+                                     rd=POINT_RD[point])
+            assert gop == media.annexb_for(a, b)
+            recon += [[np.asarray(p)[k] for p in planes]
+                      for k in range(b - a)]
         own = decode_annexb(stream).frames
-        assert len(own) == N
+        assert len(own) == N == len(recon)
         assert min(psnr(f.y, o.y[:H, :W])
                    for f, o in zip(frames, own)) > 30.0
+        for o, (ry, ru, rv) in zip(own, recon):
+            assert np.array_equal(o.y[:H, :W], ry[:H, :W])
+            assert np.array_equal(o.u[:H // 2, :W // 2],
+                                  ru[:H // 2, :W // 2])
+            assert np.array_equal(o.v[:H // 2, :W // 2],
+                                  rv[:H // 2, :W // 2])
         if not oracle.oracle_available():
             pytest.skip("libavcodec is not available")
         theirs = oracle.decode_h264(stream)
@@ -436,10 +511,12 @@ class TestThroughTheCoordinator:
                 open(served[1].output_path, "rb") as b:
             assert a.read() == b.read()
 
-    def test_four_devices_encode_the_same_bytes(self, edited, served):
+    def test_four_devices_encode_the_same_bytes(self, edited, served_at):
         tmp, _frames, path = edited
+        point, served = served_at
         mesh = dispatch.default_mesh(jax.devices()[:4])
-        _coord, job, grew = _run(tmp, "wide", path, mesh=mesh, scenecut=40)
+        _coord, job, grew = _run(tmp, "wide-" + point, path, mesh=mesh,
+                                 point=point, scenecut=40)
         assert grew["waves"] == 2               # 7 GOPs over 4 devices
         assert grew["wave_frames"] == 8 * GOP   # one pad GOP
         assert grew["pad_frames"] == 8 * GOP - N
@@ -449,13 +526,15 @@ class TestThroughTheCoordinator:
                 open(served[1].output_path, "rb") as b:
             assert a.read() == b.read()
 
-    def test_a_clip_without_cuts_keeps_its_bytes(self, tmp_path):
+    @pytest.mark.parametrize("point", sorted(POINTS))
+    def test_a_clip_without_cuts_keeps_its_bytes(self, tmp_path, point):
         frames = make_frames(N, W, H, seed=5)
         path = _source(tmp_path, frames)
-        _c, off, _g = _run(tmp_path, "off", path)
-        _c, on, grew = _run(tmp_path, "on", path, scenecut=40)
+        _c, off, _g = _run(tmp_path, "off", path, point=point)
+        _c, on, grew = _run(tmp_path, "on", path, point=point, scenecut=40)
         assert grew["scene_cuts"] == grew["scene_cuts_suppressed"] == 0
         assert grew["scenecut"] > 0 and grew["pad_frames"] == 0
+        assert grew["pad_frames_skipped"] == 0      # the scan form ran
         with open(off.output_path, "rb") as a, \
                 open(on.output_path, "rb") as b:
             assert a.read() == b.read()
@@ -770,14 +849,19 @@ class TestACutAlignedGopStopsAtItsLength:
             assert np.array_equal(a[:n - 1], b[:n - 1])
             assert not b[n - 1:].any()
 
-    def test_through_the_dense_fallback(self):
-        """Grain that leaves the sparse budgets: the levels the bounded
-        program left on the device (zeros past n) pack to the bytes of
-        the scan form at that length."""
+    @pytest.mark.parametrize("rd", [RD_OFF, SERVING], ids=["library", "rd"])
+    def test_through_the_dense_fallback(self, rd, monkeypatch):
+        """Grain that leaves the sparse budgets: the levels — and with
+        the serving tools the per-MB modes and QP deltas beside them —
+        that the bounded program left on the device (zeros past n)
+        pack to the bytes of the scan form at that length, from the ONE
+        program call the wave made."""
         n = 5
         frames = make_frames(n, W, H, seed=13, grain=8.0)
-        (bounded,), grew = _gop_bytes(frames, _pinned([n]))
-        (scanned,), plain = _gop_bytes(frames, None)
+        calls = _program_calls(monkeypatch)
+        (bounded,), grew = _gop_bytes(frames, _pinned([n]), rd=rd)
+        assert calls == [("_encode_gop_single", True)]
+        (scanned,), plain = _gop_bytes(frames, None, rd=rd)
         assert grew["dense_fallback_waves"] == 1 \
             == plain["dense_fallback_waves"]
         assert bounded == scanned
@@ -825,6 +909,34 @@ class TestACutAlignedGopStopsAtItsLength:
         bounded = str(jax.make_jaxpr(fn)(*args, args[3]))
         assert bounded.count("while[") == 1
         assert bounded.count("scan[") == plain.count("scan[") - 1
+
+    @pytest.mark.parametrize("rd", [RD_OFF, SERVING], ids=["library", "rd"])
+    @pytest.mark.parametrize("bounded", [False, True], ids=["scan", "while"])
+    def test_either_loop_traces_the_p_frame_step_once(self, monkeypatch,
+                                                      rd, bounded):
+        """ISSUE 39: the bounded loop sizes its buffers from the ONE
+        trace it evaluates as its body. A second trace for the shapes
+        alone cost the serving set 11 s of every start on the chip
+        (PERF.md §6, PR 39), which is what kept the bounded form away
+        from the plans that do not need it (ROADMAP D10)."""
+        traces = []
+        real = jaxinter._encode_p_plane
+
+        def counting(*args, **kwargs):
+            traces.append(1)
+            return real(*args, **kwargs)
+
+        monkeypatch.setattr(jaxinter, "_encode_p_plane", counting)
+        c = (1, 4, H // 2, W // 2)
+        args = [jax.ShapeDtypeStruct((1, 4, H, W), jnp.uint8),
+                jax.ShapeDtypeStruct(c, jnp.uint8),
+                jax.ShapeDtypeStruct(c, jnp.uint8),
+                jax.ShapeDtypeStruct((1,), jnp.int32)]
+        fn = functools.partial(dispatch._encode_gop_single.__wrapped__,
+                               mbw=W // 16, mbh=H // 16, compact=True,
+                               rd=rd)
+        jax.make_jaxpr(fn)(*args, *args[3:] * bounded)
+        assert len(traces) == 1
 
     def test_a_job_runs_one_of_the_two_programs(self, edited, monkeypatch):
         """The plan decides: every wave of a job planned on cuts hands

@@ -29,6 +29,8 @@ SPARSE = {"pack", "compact"}
 SFE_P = {"me_prep", "me_search", "me_median", "residual", "halo", "layout"}
 SFE_I = {"intra", "halo", "layout"}
 RD_ON = RdConfig(mode_decision=True, pskip=True, deblock=True)
+#: the serving point as a daemon's settings give it (aq_strength 1.0)
+RD_SERVING = RdConfig(mode_decision=True, pskip=True, deblock=True, aq_q=4)
 
 #: instructions that do no work of their own
 NO_WORK = {"constant", "parameter", "tuple", "get-tuple-element", "bitcast"}
@@ -137,6 +139,16 @@ CASES = {
     "gop_single_rd_cuts": (
         lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
                            compact=True, rd=RD_ON),
+        GOP | SPARSE | {"deblock"}),
+    # ISSUE 39, the executable of `serving-1080p-edited`: the bounded
+    # loop with all four serving tools inside, on one device and a mesh
+    "gop_single_serving_cuts": (
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
+                           compact=True, rd=RD_SERVING),
+        GOP | SPARSE | {"deblock"}),
+    "wave_gop_serving_cuts": (
+        lambda: _lower_gop(dispatch._encode_wave_gop, cuts=True,
+                           mesh=_gop_mesh(), compact=True, rd=RD_SERVING),
         GOP | SPARSE | {"deblock"}),
 }
 
@@ -315,6 +327,35 @@ def test_deblock_stage_is_a_named_loop_without_gather_or_scatter(case):
     indexed = sorted(path for path in filter_ops
                      if re.search(r"/(gather|scatter)", path))
     assert not indexed, indexed
+
+
+@pytest.mark.parametrize("case", ["gop_single_serving_cuts",
+                                  "wave_gop_serving_cuts"])
+def test_the_serving_tools_keep_their_stage_inside_the_bounded_loop(case):
+    """ISSUE 39: the P-frame loop of a cut-aligned GOP is a `while`
+    with a traced bound, named `tvt.layout` like the loop over GOPs
+    round it; with the serving tools on, the filter runs inside both
+    and is still filed under `tvt.deblock` (its own wavefront loop, on
+    the CPU mirror, one `tvt.layout` deeper), and nothing that works
+    inside a loop is left without a stage: each op there has exactly
+    one, the last `tvt.*` component of its path."""
+    loop = PREFIX + "layout/while/body/"
+    paths = _compiled_paths(case)
+    in_p_loop = [path for path in paths if re.search(
+        rf"{re.escape(loop)}(?:closed_call/)?{re.escape(loop)}", path)]
+    assert len(in_p_loop) > 1000, "the P-frame loop was not read"
+    stages_there = collections.Counter(
+        [part for part in path.split("/") if part.startswith(PREFIX)][-1]
+        for path in in_p_loop)
+    assert stages_there[PREFIX + "deblock"] > 100
+    assert {PREFIX + name for name in
+            ("me_prep", "me_search", "me_median", "residual")} \
+        <= set(stages_there)
+    # the IDR and the sparse pack stay outside the P-frame loop
+    assert not {PREFIX + "intra", PREFIX + "pack"} & set(stages_there)
+    bare = sorted({path for path in paths
+                   if "while" in path and PREFIX not in path})
+    assert not bare, bare[:10]
 
 
 def test_stage_names_are_a_closed_set():

@@ -705,20 +705,32 @@ def _loop_p_frames(p_step, carry, planes, n_frames):
     lowers to with a static bound. Frames from n_frames on cost
     nothing and read zero. A program takes this form for every GOP of
     a plan made on scene cuts (SegmentPlan.pin_frames) and for no
-    other plan."""
+    other plan.
+
+    `p_step` is traced ONCE, to a jaxpr that gives the buffers their
+    shapes and is then evaluated as the loop's body (the same equations,
+    their stage names and source lines kept): the buffers have to exist
+    before the `while` is built, and tracing `p_step` a second time for
+    its shapes alone cost the serving set 11 s of every start on the
+    chip, where its step holds two Pallas kernels (PERF.md §6, PR 39)."""
+    from jax.extend.core import jaxpr_as_fun
+
     def frame(i):
         return tuple(jax.lax.dynamic_index_in_dim(p, i, keepdims=False)
                      for p in planes)
 
     zero = _varying_zero(carry[0])      # see _scan_p_frames
+    traced, shapes = jax.make_jaxpr(p_step, return_shape=True)(
+        carry, frame(1))
+    step, tree = jaxpr_as_fun(traced), jax.tree.structure(shapes)
     outs = tuple(
         jnp.zeros((planes[0].shape[0] - 1, *o.shape), o.dtype)
-        + zero.astype(o.dtype)
-        for o in jax.eval_shape(p_step, carry, frame(1))[1])
+        + zero.astype(o.dtype) for o in shapes[1])
 
     def body(i, state):
         carry, outs = state
-        carry, out = p_step(carry, frame(i + 1))
+        carry, out = jax.tree.unflatten(
+            tree, step(*jax.tree.leaves((carry, frame(i + 1)))))
         return carry, tuple(
             jax.lax.dynamic_update_index_in_dim(o, x, i, 0)
             for o, x in zip(outs, out))

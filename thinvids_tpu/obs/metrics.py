@@ -344,6 +344,11 @@ STAGE_COUNTER_TOTALS = {
         "tvt_pad_frames_skipped_total",
         "of those, repeats no program encoded: a plan made on scene "
         "cuts stops each GOP's P-frame loop at its real length"),
+    "programs_built": REGISTRY.counter(
+        "tvt_programs_built_total",
+        "GOP / step executables this process set up (first calls: "
+        "compiled or loaded from the compile cache); their seconds are "
+        "tvt_stage_seconds_total{stage=\"program_build\"}"),
 }
 
 # -- origin serving (origin/serve.OriginStats + origin/cache) ----------

@@ -914,17 +914,20 @@ class GopShardEncoder:
             ph, pw = ysd.shape[2], ysd.shape[3]
             mbh, mbw = ph // 16, pw // 16
             compact = self.inter and self.compact_transfer
-            if self.inter and self.num_devices == 1:
-                out = _encode_gop_single(ysd, usd, vsd, qpsd, *reald,
-                                         mbw=mbw, mbh=mbh, compact=compact,
-                                         rd=self.rd)
-            elif self.inter:
-                out = _encode_wave_gop(ysd, usd, vsd, qpsd, *reald, mbw=mbw,
-                                       mbh=mbh, mesh=self.mesh,
-                                       compact=compact, rd=self.rd)
-            else:
-                out = _encode_wave(ysd, usd, vsd, qpsd, mbw=mbw, mbh=mbh,
-                                   mesh=self.mesh, rd=self.rd)
+            form = "intra" if not self.inter \
+                else "bounded" if reald else "scan"
+            with program_build(form, self.rd, ysd.shape, compact):
+                if self.inter and self.num_devices == 1:
+                    out = _encode_gop_single(ysd, usd, vsd, qpsd, *reald,
+                                             mbw=mbw, mbh=mbh,
+                                             compact=compact, rd=self.rd)
+                elif self.inter:
+                    out = _encode_wave_gop(ysd, usd, vsd, qpsd, *reald,
+                                           mbw=mbw, mbh=mbh, mesh=self.mesh,
+                                           compact=compact, rd=self.rd)
+                else:
+                    out = _encode_wave(ysd, usd, vsd, qpsd, mbw=mbw,
+                                       mbh=mbh, mesh=self.mesh, rd=self.rd)
             # The last output is the wave's whole int16 levels (199 MB
             # per 1080p GOP): it goes onto the handle, not among the
             # outputs the sparse path indexes, and NO copy of it is
@@ -1284,7 +1287,8 @@ class GopShardEncoder:
                 if fetch.sparse_ok:
                     fetch.dense = None
                 else:
-                    fetch.dense = _levels_as_words(fetch.dense)
+                    with program_build("words", None, fetch.dense.shape):
+                        fetch.dense = _levels_as_words(fetch.dense)
                     self._start_copies([fetch.dense])
             fetch.tiny = tiny
 
@@ -2020,20 +2024,22 @@ class SfeShardEncoder(GopShardEncoder):
 
     def _intra_step(self, y, u, v, qp):
         bp = self.band_plan
-        return _sfe_intra_step(y, u, v, qp, self._real_rows,
-                               mbw=bp.mb_width, mbh_band=bp.band_mb_rows,
-                               mesh=self._step_mesh(), rd=self.rd,
-                               total_mb_rows=self._total_mb_rows)
+        with program_build("sfe_intra", self.rd, y.shape, bp.num_bands):
+            return _sfe_intra_step(
+                y, u, v, qp, self._real_rows, mbw=bp.mb_width,
+                mbh_band=bp.band_mb_rows, mesh=self._step_mesh(),
+                rd=self.rd, total_mb_rows=self._total_mb_rows)
 
     def _p_step(self, y, u, v, carry, qp):
         bp = self.band_plan
         ry, ru, rv, pmv = carry
-        return _sfe_p_step(y, u, v, ry, ru, rv, pmv, qp, self._real_rows,
-                           mbw=bp.mb_width, mbh_band=bp.band_mb_rows,
-                           mesh=self._step_mesh(),
-                           halo_rows=self.halo_rows,
-                           num_bands=bp.num_bands, rd=self.rd,
-                           total_mb_rows=self._total_mb_rows)
+        with program_build("sfe_p", self.rd, y.shape, bp.num_bands):
+            return _sfe_p_step(
+                y, u, v, ry, ru, rv, pmv, qp, self._real_rows,
+                mbw=bp.mb_width, mbh_band=bp.band_mb_rows,
+                mesh=self._step_mesh(), halo_rows=self.halo_rows,
+                num_bands=bp.num_bands, rd=self.rd,
+                total_mb_rows=self._total_mb_rows)
 
     def dispatch_wave(self, staged: tuple) -> tuple:
         """Enqueue one GOP's per-frame steps (all async — jax dispatch
@@ -2403,3 +2409,43 @@ def job_clock(name: str):
     """Context manager: the stage clock `name` (one of JOB_CLOCKS) of
     the process totals, `tvt:<name>` in a live device profile."""
     return _TOTALS.stage(name)
+
+
+#: the seconds a process spends setting its executables up: the clock
+#: `program_build` runs round the FIRST call of each GOP / step program
+#: (trace, lower, compile or load from the compile cache, until the
+#: call returns with the program enqueued) and `programs_built` counts
+#: them; a later call of that program starts neither. Process totals
+#: like the job clocks above, never a span of a job's ring. They are
+#: registered HERE and not in STAGE_NAMES / STAGE_COUNTERS because the
+#: compile cache's key of every program with the ME kernel holds the
+#: lines of this file above the step programs (PERF.md §7): an entry
+#: added up there would recompile every one of them for nothing.
+_TOTALS.add("program_build", 0.0)
+_TOTALS.bump("programs_built", 0)
+_PROGRAMS_BUILT: set = set()
+_PROGRAMS_LOCK = threading.Lock()
+
+
+@contextlib.contextmanager
+def program_build(form: str, rd, shape, *more):
+    """Round one call of a step program from OUTSIDE its jit: the first
+    call of each (form, rd, shape, *more) of this process is clocked
+    (`program_build`; `tvt:program_build` in a live device profile),
+    counted and named once in the log. `form` is the executable's kind
+    (`scan` | `bounded` for a GOP program by its P-frame loop, `intra`,
+    `sfe_intra`, `sfe_p`, `words`), `shape` its leading operand's."""
+    key = (form, rd, tuple(shape), *more)
+    with _PROGRAMS_LOCK:
+        first = key not in _PROGRAMS_BUILT
+        _PROGRAMS_BUILT.add(key)
+    if not first:
+        yield
+        return
+    t0 = time.perf_counter()
+    with _TOTALS.stage("program_build"):
+        yield
+    _TOTALS.bump("programs_built")
+    _LOG.info("program built: form=%s rd=%s shape=%s %s in %.2f s "
+              "(trace, lower, compile or cache load, enqueue)", form, rd,
+              tuple(shape), more, time.perf_counter() - t0)
