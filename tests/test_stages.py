@@ -85,16 +85,17 @@ def _sfe(p_frame: bool):
             dict(kwargs, halo_rows=16, num_bands=n))
 
 
-def _lower_gop(program, cuts=False, **more):
+def _lower_gop(program, cuts=False, how="lower", **more):
     """`cuts`: the program of a plan made on scene cuts, which takes
-    each GOP's real frame count beside its QP (ISSUE 34)."""
+    each GOP's real frame count beside its QP (ISSUE 34). `how`:
+    "lower" for the module, "trace" for the jaxpr."""
     args, kwargs = _gop_args()
-    return program.lower(*args, *args[3:] * cuts, **kwargs, **more)
+    return getattr(program, how)(*args, *args[3:] * cuts, **kwargs, **more)
 
 
-def _lower_sfe(program, p_frame, **more):
+def _lower_sfe(program, p_frame, how="lower", **more):
     args, kwargs = _sfe(p_frame)
-    return program.lower(*args, **kwargs, **more)
+    return getattr(program, how)(*args, **kwargs, **more)
 
 
 CASES = {
@@ -296,6 +297,81 @@ def test_no_scatter_under_the_pack_stage(case):
     assert len(packing) > 20, "the pack stage was not read"
     scatters = sorted(path for path in packing if "/scatter" in path)
     assert not scatters, scatters
+
+
+#: every program form that runs the P-frame residual: the GOP program
+#: as a `scan` and bounded, at the library and the serving point, and
+#: the split-frame P step
+RESIDUAL = {
+    "gop_single": lambda: _lower_gop(
+        dispatch._encode_gop_single, how="trace", compact=True),
+    "gop_single_cuts": lambda: _lower_gop(
+        dispatch._encode_gop_single, cuts=True, how="trace", compact=True),
+    "gop_single_serving": lambda: _lower_gop(
+        dispatch._encode_gop_single, how="trace", compact=True,
+        rd=RD_SERVING),
+    "gop_single_serving_cuts": lambda: _lower_gop(
+        dispatch._encode_gop_single, cuts=True, how="trace", compact=True,
+        rd=RD_SERVING),
+    "sfe_p": lambda: _lower_sfe(dispatch._sfe_p_step, True, how="trace"),
+    "sfe_p_rd": lambda: _lower_sfe(dispatch._sfe_p_step, True, how="trace",
+                                   rd=RD_ON),
+}
+
+
+def _equations(jaxpr, outer=()):
+    """(path of named scopes, equation) of a jaxpr and of every jaxpr
+    nested in it (`jit`, `scan`, `while`, `shard_map`, ...): an inner
+    equation's scopes follow those of the equation that holds it, as
+    they do in the lowered module's `op_name`."""
+    for eqn in jaxpr.eqns:
+        path = outer + tuple(
+            part for part in str(eqn.source_info.name_stack).split("/")
+            if part)
+        yield path, eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner, path)
+
+
+@pytest.mark.parametrize("case", sorted(RESIDUAL))
+def test_the_residual_keeps_the_planes_tiling(case):
+    """ISSUE 40: under `tvt.residual` no array made from a plane has a
+    minor dimension under 8 (a TPU lays the minor dimension on 128
+    lanes: the (H, W // 4, 4) views of the old butterflies were a
+    relayout at 32 times the bytes, 9.5 of a 1080p frame's 18.9 ms),
+    nothing is sliced with a lane stride, and a gather moves whole
+    rows. Only per-MB maps (at most 16 entries a macroblock: `nz4`, the
+    chroma DC levels) and the (4, 4) quant tables are smaller than
+    that, so the copies cannot come back unseen."""
+    traced = RESIDUAL[case]()
+    args, kwargs = _sfe(True) if case.startswith("sfe") else _gop_args()
+    shape = args[0].shape
+    mb = 16 * (kwargs["mbw"] * shape[-2] // 16)          # 16 per MB
+    seen, narrow, strided = 0, [], []
+    for path, eqn in _equations(traced.jaxpr.jaxpr):
+        scopes = [part for part in path if part.startswith(PREFIX)]
+        if not scopes or scopes[-1] != PREFIX + "residual":
+            continue
+        seen += 1
+        name = eqn.primitive.name
+        for var in (*eqn.invars, *eqn.outvars):
+            aval = var.aval
+            if getattr(aval, "ndim", 0) and aval.shape[-1] < 8 \
+                    and aval.size > mb:
+                narrow.append((name, aval.shape))
+        operand = eqn.invars[0].aval if eqn.invars else None
+        if name == "slice" and (eqn.params["strides"] or (1,))[-1] != 1:
+            strided.append((name, operand.shape, eqn.params["strides"]))
+        if name == "gather" and operand.size > mb \
+                and eqn.params["slice_sizes"][-1] != operand.shape[-1]:
+            strided.append((name, operand.shape,
+                            eqn.params["slice_sizes"]))
+    assert seen > 300, "the residual stage was not read"
+    assert not narrow, sorted(set(narrow))[:10]
+    assert not strided, strided[:10]
 
 
 DEBLOCKING = sorted(case for case, (_lower, want) in CASES.items()

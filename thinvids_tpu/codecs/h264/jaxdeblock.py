@@ -124,12 +124,3 @@ def deblock_frame_jax(y, u, v, qp_map, *, intra: bool, nz4=None,
     return deblock_frame(y, u, v, qp_map, intra=intra, nz4=nz4, mv=mv,
                          mb_row0=mb_row0, total_mb_rows=total_mb_rows,
                          ops=JAX_OPS)
-
-
-def nz4_from_luma_plane(z_plane, mbh: int, mbw: int):
-    """(H, W) quantized luma coeff plane → (4·mbh, 4·mbw) any-nonzero
-    per 4x4 block (the P-frame bS=2 input, computed on device from the
-    same levels the packer ships)."""
-    H, W = 16 * mbh, 16 * mbw
-    b = z_plane[:H, :W].reshape(4 * mbh, 4, 4 * mbw, 4)
-    return jnp.any(b != 0, axis=(1, 3))

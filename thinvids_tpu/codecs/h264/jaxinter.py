@@ -10,9 +10,14 @@ Replaces the inter coding half of the reference's ffmpeg encode op point
   and a running per-MB best-(cost, mv, pred) select — the kernel emits
   the final prediction planes, so MC never runs as a separate pass.
   MVs are HALF-PEL units throughout.
-- Residual DCT/quant/dequant/IDCT run in PLANE layout: 4x4 butterflies
-  as strided slices along H then W of the full frame — no (n, 16, 4, 4)
-  relayout in the hot loop, int16 storage.
+- Residual DCT/quant/dequant/IDCT run on the planes as they lie, taken
+  once to their 128-lane column tiles (T, H, 128): the 4x4 butterflies,
+  the chroma DC Hadamard, the per-block and per-MB reductions are
+  constant block-diagonal matrices on the matrix unit, the quant tables
+  selects on an iota. No plane-sized array of the stage has a minor
+  dimension under 128, none is sliced with a lane stride; int16
+  storage. (The blocked conformance path re-lays the finished levels
+  for the host packer, after the stage's arithmetic.)
 - Frames chain through a `lax.scan` carry holding the recon planes and
   the previous frame's median MV (the EPZS temporal predictor collapsed
   to its frame mode, as one search center).
@@ -48,69 +53,133 @@ SEARCH_RANGE = jaxme.SEARCH_RANGE      # integer-pel, each direction
 
 
 # ---------------------------------------------------------------------------
-# plane-layout 4x4 transforms (bit-exact ports of jaxcore._fwd4/_inv4,
-# applied to whole (H, W) planes via length-4 strided butterflies)
+# the residual's 4x4 structure on 128-lane tiles
+#
+# A TPU lays an array's minor dimension on 128 lanes, so a plane viewed
+# as (H, W // 4, 4) is a relayout at 32 times the bytes, and a lane-
+# strided slice of it a pass of the vector unit per element (PERF.md §6,
+# PR 37 and PR 40). Here a plane is taken ONCE to (T, H, 128) — its
+# 128-lane column tiles one after another, whole (8, 128) tiles moved,
+# none re-laid — and every 4x4 step is a constant block-diagonal matrix
+# on the matrix unit: along lanes `tiles @ kron(I, core^T)`, along rows
+# `kron(I, core) @ rows` in groups of 16. Blocks never straddle a tile
+# (4, 8 and 16 divide 128). Integers ride as f32 at Precision.HIGHEST,
+# which is exact while every partial sum stays below 2**24: forward
+# |r| <= 255 -> 1530 -> 9180; inverse |d| < 2**18 in (chroma DC, QP 0)
+# -> under 2**22 out. tests/test_residual_planes.py holds the extremes
+# at every QP against the numpy spec functions.
 # ---------------------------------------------------------------------------
 
-def _fwd4_axis0(x):
-    """Forward core transform along H (rows of each 4x4 block)."""
+_LANES = 128
+_HI = jax.lax.Precision.HIGHEST
+_F32 = jnp.float32
+
+_CF4 = np.array([[1, 1, 1, 1], [2, 1, -1, -2], [1, -1, -1, 1],
+                 [1, -2, 2, -1]], np.float32)
+# jaxcore._inv4's butterfly, one 1-D pass: out = _INV_D @ d + _INV_H @ (d >> 1)
+_INV_D = np.array([[1, 1, 1, 0], [1, 0, -1, -1], [1, 0, -1, 1],
+                   [1, -1, 1, 0]], np.float32)
+_INV_H = np.array([[0, 0, 0, 1], [0, 1, 0, 0], [0, -1, 0, 0],
+                   [0, 0, 0, -1]], np.float32)
+# 1-D half of the chroma DC Hadamard over the DC positions (0 and 4) of
+# an 8-sample MB row or column; every other position reads 0
+_HAD_DC = np.zeros((8, 8), np.float32)
+_HAD_DC[0, 0] = _HAD_DC[0, 4] = _HAD_DC[4, 0] = 1
+_HAD_DC[4, 4] = -1
+# sum of each 4 rows, left in the first of them
+_SUM4 = np.zeros((4, 4), np.float32)
+_SUM4[0, :] = 1
+
+
+def _block_diag(core, n: int):
+    return np.kron(np.eye(n // core.shape[0], dtype=np.float32), core)
+
+
+def _to_tiles(x):
+    """(H, W) plane -> (T, H, 128): its 128-lane column tiles (W padded
+    with zeros to a multiple of 128)."""
     H, W = x.shape
-    v = x.reshape(H // 4, 4, W)
-    a, b, c, d = v[:, 0], v[:, 1], v[:, 2], v[:, 3]
-    s0, s3 = a + d, a - d
-    s1, s2 = b + c, b - c
-    return jnp.stack(
-        [s0 + s1, 2 * s3 + s2, s0 - s1, s3 - 2 * s2], axis=1
-    ).reshape(H, W)
+    T = -(-W // _LANES)
+    if T * _LANES != W:
+        x = jnp.pad(x, ((0, 0), (0, T * _LANES - W)))
+    return x.reshape(H, T, _LANES).transpose(1, 0, 2)
 
 
-def _fwd4_axis1(x):
-    """Forward core transform along W (columns of each 4x4 block)."""
-    H, W = x.shape
-    v = x.reshape(H, W // 4, 4)
-    a, b, c, d = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
-    s0, s3 = a + d, a - d
-    s1, s2 = b + c, b - c
-    return jnp.stack(
-        [s0 + s1, 2 * s3 + s2, s0 - s1, s3 - 2 * s2], axis=-1
-    ).reshape(H, W)
+def _from_tiles(x, W: int):
+    T, H, _ = x.shape
+    y = x.transpose(1, 0, 2).reshape(H, T * _LANES)
+    return y if T * _LANES == W else y[:, :W]
 
 
-def _fwd4_plane(x):
-    """W = CF @ x @ CF^T per 4x4 block, plane layout (H then W — same
-    order as jaxcore._fwd4's einsum)."""
-    return _fwd4_axis1(_fwd4_axis0(x))
+def _lane_mm(x, core):
+    """`core` applied to every group of core.shape[0] lanes of (T, H, 128)
+    tiles; f32 out."""
+    m = jnp.asarray(_block_diag(core, _LANES).T)
+    return jnp.einsum("thl,lm->thm", x.astype(_F32), m, precision=_HI)
 
 
-def _inv4_axis1(d):
-    H, W = d.shape
-    v = d.reshape(H, W // 4, 4)
-    d0, d1, d2, d3 = v[..., 0], v[..., 1], v[..., 2], v[..., 3]
-    e0, e1 = d0 + d2, d0 - d2
-    e2, e3 = (d1 >> 1) - d3, d1 + (d3 >> 1)
-    return jnp.stack([e0 + e3, e1 + e2, e1 - e2, e0 - e3],
-                     axis=-1).reshape(H, W)
+def _row_mm(x, core):
+    """`core` applied to every group of core.shape[0] rows; f32 out."""
+    T, H, L = x.shape
+    k = 16 if H % 16 == 0 else 8
+    a = jnp.asarray(_block_diag(core, k))
+    return jnp.einsum("ij,bjl->bil", a,
+                      x.astype(_F32).reshape(T * H // k, k, L),
+                      precision=_HI).reshape(T, H, L)
 
 
-def _inv4_axis0(f):
-    H, W = f.shape
-    v = f.reshape(H // 4, 4, W)
-    g0, g1, g2, g3 = v[:, 0], v[:, 1], v[:, 2], v[:, 3]
-    h0, h1 = g0 + g2, g0 - g2
-    h2, h3 = (g1 >> 1) - g3, g1 + (g3 >> 1)
-    return jnp.stack([h0 + h3, h1 + h2, h1 - h2, h0 - h3],
-                     axis=1).reshape(H, W)
+def _pool(group: int):
+    """(128, 128 // group) 0/1: lane l feeds column l // group."""
+    return jnp.asarray((np.arange(_LANES)[:, None] // group
+                        == np.arange(_LANES // group)[None, :]
+                        ).astype(np.float32))
 
 
-def _inv4_plane(d):
-    """Inverse core transform, plane layout (W then H — exactly
-    jaxcore._inv4's stage order, which matters for the >>1 rounding)."""
-    return _inv4_axis0(_inv4_axis1(d))
+def _lane_pool(x, group: int):
+    """(T, R, 128) -> (T, R, 128 // group): sums of `group` lanes, f32."""
+    return jnp.einsum("trl,lm->trm", x.astype(_F32), _pool(group),
+                      precision=_HI)
 
 
-def _tile_plane(tbl, H, W):
-    """Tile a (4, 4) per-coefficient table over an (H, W) plane."""
-    return jnp.tile(tbl, (H // 4, W // 4))
+def _lane_spread(x, group: int):
+    """(T, R, 128 // group) -> (T, R, 128): each entry over its `group`
+    lanes — :func:`_lane_pool`'s transpose."""
+    return jnp.einsum("trm,lm->trl", x.astype(_F32), _pool(group),
+                      precision=_HI)
+
+
+def _tile_maps(x, n: int):
+    """(T, R, g) small per-tile maps -> (R, n): tiles side by side."""
+    T, R, g = x.shape
+    return x.transpose(1, 0, 2).reshape(R, T * g)[:, :n]
+
+
+def _fwd4_tiles(x):
+    """W = CF @ x @ CF^T per 4x4 block (rows then lanes — the order of
+    jaxcore._fwd4's einsum; exact either way)."""
+    return _lane_mm(_row_mm(x, _CF4), _CF4).astype(jnp.int32)
+
+
+def _inv4_tiles(d):
+    """Inverse core transform: lanes then rows, the >> 1 terms shifted
+    BEFORE each matmul — jaxcore._inv4's stage order and rounding."""
+    f = (_lane_mm(d, _INV_D) + _lane_mm(d >> 1, _INV_H)).astype(jnp.int32)
+    return (_row_mm(f, _INV_D) + _row_mm(f >> 1, _INV_H)).astype(jnp.int32)
+
+
+def _chroma_dc_tiles(x):
+    """2x2 Hadamard over the four DC positions of each 8x8 chroma MB,
+    in place: (8i + 4u, 8j + 4v) gets coefficient (u, v); 0 elsewhere."""
+    return _row_mm(_lane_mm(x, _HAD_DC), _HAD_DC).astype(jnp.int32)
+
+
+def _class_tiles(tbl, shape):
+    """A (4, 4) quant table over (T, H, 128) tiles: its three position
+    classes are (row & 1) + (lane & 1), read off an iota."""
+    cls = ((jax.lax.broadcasted_iota(jnp.int32, shape, 1) & 1)
+           + (jax.lax.broadcasted_iota(jnp.int32, shape, 2) & 1))
+    return jnp.where(cls == 0, tbl[0, 0],
+                     jnp.where(cls == 1, tbl[0, 1], tbl[1, 1]))
 
 
 def _quant_plane(w, mf_plane, qp):
@@ -127,16 +196,33 @@ def _dequant_plane(z, v_plane, qp):
     return (z * v_plane) << (qp // 6)
 
 
+def _nz4_tiles(z, mbw: int):
+    """Luma level tiles -> (4·mbh, 4·mbw) any-nonzero per 4x4 block (the
+    P-frame bS=2 input of the in-loop filter, from the same levels the
+    packer ships)."""
+    rows = _row_mm(z != 0, _SUM4)[:, ::4]                # (T, H/4, 128)
+    return _tile_maps(_lane_pool(rows, 4), 4 * mbw) > 0.5
+
+
+_DC_LANES = np.arange(0, _LANES, 4)                     # DC lanes of a tile
+
+
+def _chroma_dc_levels(zc, mbw: int):
+    """Chroma level tiles holding the Hadamard-domain DC levels at their
+    DC positions -> (mbh, 4 * mbw): per MB its four [00 01 10 11]."""
+    parts = []
+    for u in (0, 1):
+        pick = np.zeros((_LANES, _LANES // 2), np.float32)
+        pick[_DC_LANES, 4 * (_DC_LANES // 8) + 2 * u + _DC_LANES % 8 // 4] = 1
+        parts.append(jnp.einsum("trl,lm->trm", zc[:, 4 * u::8].astype(_F32),
+                                jnp.asarray(pick), precision=_HI))
+    return _tile_maps(parts[0] + parts[1], 4 * mbw).astype(jnp.int32)
+
+
 # ---------------------------------------------------------------------------
-# P-frame residual coding in plane layout
+# P-frame residual coding
 # (motion search + compensation live in jaxme.me_search)
 # ---------------------------------------------------------------------------
-
-def _dc_mask(H, W):
-    m = np.ones((4, 4), np.int16)
-    m[0, 0] = 0
-    return jnp.asarray(np.tile(m, (H // 4, W // 4)))
-
 
 def _luma_plane_to_blocks(z, mbw: int, mbh: int):
     """(H, W) coeff plane → (nmb, 16, 16) z-scan blocks of zigzag
@@ -154,27 +240,22 @@ def _chroma_plane_to_blocks(z, mbw: int, mbh: int):
     return x[..., _ZZ]
 
 
-def _dc_pos_expand(dcr_grid, h, wd_):
-    """Place a (h/4, wd_/4) grid at the (0, 0) position of every 4x4
-    block of an (h, wd_) zero plane — an outer-product broadcast, not a
-    scatter (the .at[::4, ::4].set lowering measured ~2 ms/frame)."""
-    m4 = jnp.zeros((4, 4), dcr_grid.dtype).at[0, 0].set(1)
-    out = dcr_grid[:, None, :, None] * m4[None, :, None, :]
-    return out.reshape(h, wd_)
-
-
 def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
                     mbh: int, blocked: bool = True, rd=RD_OFF):
     """One P frame given previous recon planes (int16). `pred_mv` is the
     previous frame's median MV in half-pel units (a search center).
 
+    Search and compensation are the kernel's (jaxme.me_search); the
+    residual (:func:`_residual_p`) transforms, quantizes and
+    reconstructs on the planes' 128-lane tiles and hands back (H, W)
+    planes; with rd.deblock the in-loop filter then runs on the recon.
+
     `blocked=True` returns level arrays in the host packer's blocked
-    layout (the conformance/host path). `blocked=False` skips the
-    device-side relayout entirely and returns raw coefficient PLANES —
-    the sharded transfer path's format; the relayout then happens on
-    host inside the pack pool (measured: the blocked transposes +
-    zigzag gathers cost ~0.5 s per 1080p GOP on a v5e chip, twice the
-    rest of the GOP's compute).
+    layout (the conformance/host path: one relayout of the finished
+    level planes). `blocked=False` returns raw coefficient PLANES — the
+    sharded transfer path's format; the relayout then happens on host
+    inside the pack pool (measured: the blocked transposes + zigzag
+    gathers cost ~0.5 s per 1080p GOP on a v5e chip).
     """
     n = mbw * mbh
     with stage("layout"):
@@ -218,109 +299,102 @@ def _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp, qpc, *,
     whenever its MV matches the skip predictor.
 
     Also returns nz4, the (4·mbh, 4·mbw) any-nonzero map of the FINAL
-    luma levels (the deblocking filter's bS=2 input)."""
-    H, W = cy16.shape
+    luma levels (the deblocking filter's bS=2 input).
+
+    Everything between the first and the last line works on (T, H, 128)
+    tiles (:func:`_to_tiles`); each chroma plane is ONE level array
+    there, its Hadamard-domain DC levels sitting at the DC positions of
+    their 4x4 blocks until the outputs part them."""
+    W = cy16.shape[1]
     n = mbw * mbh
     qp32 = qp.astype(jnp.int32)
-    mf_y = _tile_plane(_MF[qp32 % 6], H, W)
-    v_y = _tile_plane(_V[qp32 % 6], H, W)
-    mf_c = _tile_plane(_MF[qpc % 6], H // 2, W // 2)
-    v_c = _tile_plane(_V[qpc % 6], H // 2, W // 2)
+    pred_y_t = _to_tiles(pred_y)
+    pred_u_t, pred_v_t = _to_tiles(pred_u), _to_tiles(pred_v)
+    ys, cs = pred_y_t.shape, pred_u_t.shape              # (T, H, 128)
+    mf_c = _class_tiles(_MF[qpc % 6], cs)
+    v_c = _class_tiles(_V[qpc % 6], cs)
+    dc_pos = ((jax.lax.broadcasted_iota(jnp.int32, cs, 1) % 4 == 0)
+              & (jax.lax.broadcasted_iota(jnp.int32, cs, 2) % 4 == 0))
 
-    # --- quantize: luma plane + both chroma planes -------------------
-    resid = (cy16 - pred_y).astype(jnp.int32)
-    w = _fwd4_plane(resid)
-    z = _quant_plane(w, mf_y, qp32)
+    # --- quantize: luma tiles + both chroma planes' ------------------
+    resid = (_to_tiles(cy16) - pred_y_t).astype(jnp.int32)
+    z = _quant_plane(_fwd4_tiles(resid), _class_tiles(_MF[qp32 % 6], ys),
+                     qp32)
 
-    def chroma_quant(cplane16, pred):
-        h, wd_ = cplane16.shape
-        resid = (cplane16 - pred).astype(jnp.int32)
-        wch = _fwd4_plane(resid)
-        dc = wch[::4, ::4]                               # (2*mbh, 2*mbw)
-        g = dc.reshape(mbh, 2, mbw, 2)
-        a, b = g[:, 0, :, 0], g[:, 0, :, 1]
-        c, dd = g[:, 1, :, 0], g[:, 1, :, 1]
-        wd2 = jnp.stack([a + b + c + dd, a - b + c - dd,
-                         a + b - c - dd, a - b - c + dd], axis=-1)
+    def chroma_quant(cplane16, pred_t):
+        """One level plane: AC levels, and at each 4x4 block's DC
+        position the MB's Hadamard-domain DC level."""
+        wch = _fwd4_tiles((_to_tiles(cplane16) - pred_t).astype(jnp.int32))
+        wd2 = _chroma_dc_tiles(wch)
         # chroma DC quant (jaxcore._chroma_dc_quant with the inter
         # rounding bias)
         qbits = 15 + qpc // 6
         f = (1 << qbits) // 6
-        mf00 = _MF[qpc % 6, 0, 0]
-        zdc = (jnp.abs(wd2) * mf00 + 2 * f) >> (qbits + 1)
-        zdc = jnp.where(wd2 < 0, -zdc, zdc)              # (mbh, mbw, 4)
-        # AC quant with DC positions zeroed
-        zac = _quant_plane(wch, mf_c, qpc) * _dc_mask(h, wd_)
-        return zdc, zac
+        zdc = (jnp.abs(wd2) * _MF[qpc % 6, 0, 0] + 2 * f) >> (qbits + 1)
+        zdc = jnp.where(wd2 < 0, -zdc, zdc)
+        return jnp.where(dc_pos, zdc, _quant_plane(wch, mf_c, qpc))
 
-    u_zdc, u_zac = chroma_quant(cu16, pred_u)
-    v_zdc, v_zac = chroma_quant(cv16, pred_v)
+    zu = chroma_quant(cu16, pred_u_t)
+    zv = chroma_quant(cv16, pred_v_t)
 
     if rd.pskip:
-        # P_Skip bias: per-MB level mass across every plane
-        zb = z.reshape(mbh, 16, mbw, 16)
-        az = jnp.abs(zb)
-        def cmass(zac):
-            c = jnp.abs(zac.reshape(mbh, 8, mbw, 8))
-            return c.sum(axis=(1, 3)), c.max(axis=(1, 3))
-        us, umx = cmass(u_zac)
-        vs, vmx = cmass(v_zac)
-        mb_sum = (az.sum(axis=(1, 3)) + us + vs
-                  + jnp.abs(u_zdc).sum(axis=-1) + jnp.abs(v_zdc).sum(-1))
-        mb_max = jnp.maximum(
-            jnp.maximum(az.max(axis=(1, 3)), jnp.maximum(umx, vmx)),
-            jnp.maximum(jnp.abs(u_zdc).max(-1), jnp.abs(v_zdc).max(-1)))
-        drop = (mb_sum <= rdo.PSKIP_SUM) & (mb_max <= 1)   # (mbh, mbw)
-        keep_y = ~jnp.repeat(jnp.repeat(drop, 16, 0), 16, 1)
-        keep_c = ~jnp.repeat(jnp.repeat(drop, 8, 0), 8, 1)
-        z = jnp.where(keep_y.reshape(H, W), z, 0)
-        u_zac = jnp.where(keep_c, u_zac, 0)
-        v_zac = jnp.where(keep_c, v_zac, 0)
-        u_zdc = jnp.where(drop[..., None], 0, u_zdc)
-        v_zdc = jnp.where(drop[..., None], 0, v_zdc)
+        # P_Skip bias: per-MB level mass across every plane. A level
+        # over 1 weighs PSKIP_SUM + 1, so one pooled sum holds both
+        # tests (sum <= PSKIP_SUM, max <= 1); it stays under 3 * 384.
+        def mass(zt, mb):
+            a = jnp.abs(zt)
+            a = jnp.where(a > 1, rdo.PSKIP_SUM + 1, a).astype(_F32)
+            rows = a.reshape(a.shape[0], mbh, mb, _LANES).sum(axis=2)
+            return _tile_maps(_lane_pool(rows, mb), mbw)
 
-    nz4 = jaxdeblock.nz4_from_luma_plane(z, mbh, mbw)
+        keep = (mass(z, 16) + mass(zu, 8) + mass(zv, 8)
+                ) > rdo.PSKIP_SUM                        # (mbh, mbw)
+
+        def over_tiles(shape, mb):
+            """`keep` over each MB's samples of a plane's tiles."""
+            T, g = shape[0], _LANES // mb
+            m = jnp.pad(keep, ((0, 0), (0, T * g - mbw)))
+            m = m.reshape(mbh, T, g).transpose(1, 0, 2)  # (T, mbh, g)
+            wide = _lane_spread(m, mb) > 0.5             # (T, mbh, 128)
+            return jnp.broadcast_to(wide[:, :, None, :],
+                                    (T, mbh, mb, _LANES)).reshape(shape)
+
+        z = jnp.where(over_tiles(ys, 16), z, 0)
+        keep_c = over_tiles(cs, 8)
+        zu = jnp.where(keep_c, zu, 0)
+        zv = jnp.where(keep_c, zv, 0)
+
+    nz4 = _nz4_tiles(z, mbw)
 
     # --- reconstruct from the (possibly zeroed) levels ---------------
-    d = _dequant_plane(z, v_y, qp32)
-    recon_y = jnp.clip((_inv4_plane(d) + 32 >> 6) + pred_y, 0, 255
-                       ).astype(jnp.int16)
+    d = _dequant_plane(z, _class_tiles(_V[qp32 % 6], ys), qp32)
+    recon_y = _from_tiles(
+        jnp.clip((_inv4_tiles(d) + 32 >> 6) + pred_y_t, 0, 255
+                 ).astype(jnp.int16), W)
+    luma_levels = _from_tiles(z.astype(jnp.int16), W)    # (H, W) coeff plane
     if blocked:
-        luma_levels = _luma_plane_to_blocks(z.astype(jnp.int16), mbw, mbh
+        luma_levels = _luma_plane_to_blocks(luma_levels, mbw, mbh
                                             ).astype(jnp.int32)
-    else:
-        luma_levels = z.astype(jnp.int16)               # (H, W) coeff plane
 
-    def chroma_recon(pred, zdc, zac):
-        h, wd_ = pred.shape
-        # recon: dequant AC, reinsert dequantized DC, inverse
-        dac = _dequant_plane(zac, v_c, qpc)
-        z00, z01 = zdc[..., 0], zdc[..., 1]
-        z10, z11 = zdc[..., 2], zdc[..., 3]
-        f00 = z00 + z01 + z10 + z11
-        f01 = z00 - z01 + z10 - z11
-        f10 = z00 + z01 - z10 - z11
-        f11 = z00 - z01 - z10 + z11
+    def chroma_recon(pred_t, zc):
+        # recon: dequant AC, dequantized DC back at its positions (zac
+        # is 0 there: a select, no scatter), inverse
+        zac = jnp.where(dc_pos, 0, zc)
+        fdc = _chroma_dc_tiles(jnp.where(dc_pos, zc, 0))
         ls = _V[qpc % 6, 0, 0] * 16
-        fdc = jnp.stack([jnp.stack([f00, f01], -1),
-                         jnp.stack([f10, f11], -1)], -2)  # (mbh,mbw,2,2)
         dcr = ((fdc * ls) << (qpc // 6)) >> 5
-        dcr_grid = dcr.transpose(0, 2, 1, 3).reshape(2 * mbh, 2 * mbw)
-        # zac zeroes every DC position, so dequantized DC re-enters as
-        # an add of an expanded grid — no scatter.
-        dfull = dac + _dc_pos_expand(dcr_grid, h, wd_)
-        rec = jnp.clip((_inv4_plane(dfull) + 32 >> 6) + pred, 0, 255
-                       ).astype(jnp.int16)
+        dfull = jnp.where(dc_pos, dcr, _dequant_plane(zac, v_c, qpc))
+        rec = _from_tiles(
+            jnp.clip((_inv4_tiles(dfull) + 32 >> 6) + pred_t, 0, 255
+                     ).astype(jnp.int16), W // 2)
+        ac = _from_tiles(zac.astype(jnp.int16), W // 2)  # (H/2, W/2) plane
         if blocked:
-            ac = _chroma_plane_to_blocks(zac.astype(jnp.int16), mbw, mbh
-                                         )[..., 1:].astype(jnp.int32)
-        else:
-            ac = zac.astype(jnp.int16)                  # (H/2, W/2) plane
-        dc_lev = zdc.reshape(n, 4)
-        return dc_lev, ac, rec
+            ac = _chroma_plane_to_blocks(ac, mbw, mbh)[..., 1:
+                                                       ].astype(jnp.int32)
+        return _chroma_dc_levels(zc, mbw).reshape(n, 4), ac, rec
 
-    udc, uac, recon_u = chroma_recon(pred_u, u_zdc, u_zac)
-    vdc, vac, recon_v = chroma_recon(pred_v, v_zdc, v_zac)
+    udc, uac, recon_u = chroma_recon(pred_u_t, zu)
+    vdc, vac, recon_v = chroma_recon(pred_v_t, zv)
     if blocked:
         chroma_dc = jnp.stack([udc, vdc], axis=1)        # (n, 2, 4)
         chroma_ac = jnp.stack([uac, vac], axis=1)        # (n, 2, 4, 15)
