@@ -11,6 +11,7 @@ every MB of a lane tile took the same candidate.
 """
 
 import functools
+import types
 
 import numpy as np
 import pytest
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from thinvids_tpu.codecs.h264 import jaxme
+from thinvids_tpu.codecs.h264.rdo import MV_PER_PEL
 
 
 def _mixed_motion_frames(w, h, seed=0):
@@ -38,15 +40,18 @@ def _mixed_motion_frames(w, h, seed=0):
     return cur, ref, ref_u, ref_v
 
 
-def _kernel_and_spec(planes, centers, lam, what):
+SUBPELS = ("half", "quarter")
+
+
+def _kernel_and_spec(planes, centers, lam, what, subpel="half"):
     """Run the kernel (Pallas interpreter) and the XLA spec on (cur,
     ref_y, ref_u, ref_v) uint8 planes, require identical (mv, pred_y,
     pred_u, pred_v), and return the spec's mv as (n, 2)."""
     cy, ry, ru, rv = (jnp.asarray(p, jnp.int16) for p in planes)
     out_k = jax.device_get(jaxme.me_search_pallas(
-        cy, ry, ru, rv, centers, lam, interpret=True))
+        cy, ry, ru, rv, centers, lam, interpret=True, subpel=subpel))
     out_x = jax.device_get(jaxme.me_search_xla(
-        cy, ry, ru, rv, centers, lam))
+        cy, ry, ru, rv, centers, lam, subpel=subpel))
     for name, a, b in zip(["mv", "pred_y", "pred_u", "pred_v"],
                           out_k, out_x):
         np.testing.assert_array_equal(
@@ -58,17 +63,43 @@ def _kernel_and_spec(planes, centers, lam, what):
 # index maps (2*r+k) in _me_pallas and the band-relative row bases in
 # the kernel only execute with >= 2 bands (ADVICE round 5: both original
 # shapes collapsed to a single band, leaving a 1080p-sized blind spot).
+@pytest.mark.parametrize("subpel", SUBPELS)
 @pytest.mark.parametrize("w,h", [(128, 64), (320, 32), (192, 128)])
-def test_pallas_kernel_matches_xla_reference(w, h):
+def test_pallas_kernel_matches_xla_reference(w, h, subpel):
     planes = _mixed_motion_frames(w, h)
+    per_pel = MV_PER_PEL[subpel]
+    # the median centre lands on (2, -2) pel under either unit
     centers = jaxme.centers_from(jnp.asarray(planes[0], jnp.int16),
                                  jnp.asarray(planes[1], jnp.int16),
-                                 jnp.asarray([2, -3], jnp.int32))
-    lam = jnp.asarray(jaxme.LAMBDA_H)[27]
+                                 jnp.asarray([2, -3], jnp.int32)
+                                 * (per_pel // 2), per_pel)
+    lam = jnp.asarray(jaxme._LAMBDAS[subpel])[27]
     mv = _kernel_and_spec(planes, centers, lam,
-                          "pallas kernel diverges from XLA reference")
+                          "pallas kernel diverges from XLA reference",
+                          subpel)
     # sanity: the engineered content really did split MB decisions
     assert len({tuple(v) for v in mv}) > 1
+
+
+def _blurred_motion_frames(w, h, seed=1):
+    """As `_mixed_motion_frames`, the current frame a 2x2 box blur of
+    the moved scene: its best match lies BETWEEN integer samples, so
+    the quarter rows win macroblocks (the sharp frames never pick
+    one)."""
+    cur, ref, ru, rv = _mixed_motion_frames(w, h, seed)
+    c = cur.astype(np.int32)
+    c = (c + np.roll(c, 1, 0) + np.roll(c, 1, 1)
+         + np.roll(c, (1, 1), (0, 1)) + 2) >> 2
+    return c.astype(np.uint8), ref, ru, rv
+
+
+@pytest.mark.parametrize("w,h", [(128, 64), (192, 128)])
+def test_quarter_rows_win_and_kernel_matches(w, h):
+    planes = _blurred_motion_frames(w, h)
+    centers = jnp.asarray([[4, 4], [2, 2], [0, 0]], jnp.int32)
+    mv = _kernel_and_spec(planes, centers, jnp.asarray(2, jnp.int32),
+                          "quarter rows", "quarter")
+    assert (mv & 1).any(axis=1).mean() > 0.25
 
 
 def _tie_frames(w, h, kind):
@@ -99,19 +130,53 @@ def _tie_frames(w, h, kind):
 # win in both — mv AND all three predictions (the chroma planes are
 # textured, so a winner out of order shows there even where luma is
 # flat). 320 x 128: 2 bands x 2 chunks.
+@pytest.mark.parametrize("subpel", SUBPELS)
 @pytest.mark.parametrize("kind", ["flat", "periodic"])
-def test_pallas_kernel_breaks_ties_in_table_order(kind):
+def test_pallas_kernel_breaks_ties_in_table_order(kind, subpel):
     w, h = 320, 128
     centers = jnp.asarray([[4, -2], [-2, 6], [0, 0]], jnp.int32)
     mv = _kernel_and_spec(_tie_frames(w, h, kind), centers,
                           jnp.asarray(0, jnp.int32),
-                          f"tie broken out of table order ({kind})")
+                          f"tie broken out of table order ({kind})", subpel)
     if kind == "flat":
         # every candidate ties everywhere: the table's first entry wins
-        _, wy0, wx0 = jaxme.OFFSET_TABLE[0]
-        assert {tuple(v) for v in mv} == {(2 * 4 + wy0, 2 * -2 + wx0)}
+        _, wy0, wx0 = jaxme.offset_table(subpel)[0]
+        per_pel = MV_PER_PEL[subpel]
+        assert {tuple(v) for v in mv} == {(per_pel * 4 + wy0,
+                                           per_pel * -2 + wx0)}
     else:
         assert len({tuple(v) for v in mv}) > 1
+
+
+def test_quarter_table_is_the_half_table_and_more():
+    """The quarter table holds the half table's candidates in quarter
+    units and, beside them: the fine half-sample classes round the
+    temporal-median centre (as the probe's has them) and, after each of
+    the median and the zero centre's own, every offset with an odd
+    quarter component inside +-1 pixel, each once."""
+    table = jaxme.offset_table("quarter")
+    half = [(c, 2 * wy, 2 * wx) for (c, wy, wx) in jaxme.OFFSET_TABLE]
+    even = [t for t in table if not (t[1] | t[2]) & 1]
+    assert len(even) == len(set(even)) and set(half) < set(even)
+    assert [t for t in table if t in set(half)] == half     # its order
+    # what the median's centre gains on the half grid is what the
+    # probe's has there
+    assert sorted((0,) + t[1:] for t in set(even) - set(half)) \
+        == sorted(t for t in half if t[0] == 0
+                  and (1, ) + t[1:] not in set(half))
+    odd = [t for t in table if (t[1] | t[2]) & 1]
+    assert len(odd) == len(set(odd)) == 56 + 56
+    for ci in (1, 2):
+        assert {(qy, qx) for (c, qy, qx) in odd if c == ci} == {
+            (qy, qx) for qy in range(-4, 5)
+            for qx in range(-4, 5) if (qy | qx) & 1}
+        last_even = max(i for i, t in enumerate(table)
+                        if t[0] == ci and not (t[1] | t[2]) & 1)
+        assert all(i > last_even for i, t in enumerate(table)
+                   if t[0] == ci and (t[1] | t[2]) & 1)
+    assert len(table) == 379 and len(jaxme.OFFSET_TABLE) == 227
+    with pytest.raises(ValueError, match="subpel"):
+        jaxme.offset_table("eighth")
 
 
 # ---------------------------------------------------------------------------
@@ -141,15 +206,17 @@ def _kernel_matmuls(jaxpr, trips=1):
     return out
 
 
-def test_kernel_runs_one_matmul_per_row_of_candidates():
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
     """The mechanism's "how often" is static: per grid step the MXU is
-    handed the constant selector once per row of candidates (39 rows),
-    not once per candidate (227), and never for fewer than 128 rows."""
+    handed the constant selector once per row of candidates (39 rows;
+    68 under "quarter"), not once per candidate (227; 379), and
+    never for fewer than 128 rows."""
     H, W = 128, 320
     _mbh, _mbw, H4, _RG, WcK, _nch, W2K, _WcuK, W2cK = jaxme._geom(H, W)
     sd = jax.ShapeDtypeStruct
     closed = jax.make_jaxpr(functools.partial(
-        jaxme._me_pallas, H=H, W=W, interpret=False))(
+        jaxme._me_pallas, H=H, W=W, interpret=False, subpel=subpel))(
         sd((1, 8), jnp.int32), sd((H4, WcK), jnp.int16),
         sd((3, H4 + 128, W2K), jnp.int16),
         sd((3, H4 // 2 + 64, W2cK), jnp.int16),
@@ -167,11 +234,13 @@ def test_kernel_runs_one_matmul_per_row_of_candidates():
     assert len(calls) == 1, "one kernel, one pallas_call"
     matmuls = _kernel_matmuls(calls[0].params["jaxpr"])
 
-    classes = (jaxme.CENTER_CLASSES, jaxme.CENTER_B_CLASSES,
-               jaxme.ZERO_CLASSES)
-    rows = sum(len(wys) for cl in classes for (_p, wys, _wxs) in cl)
-    cands = len(jaxme.OFFSET_TABLE)
-    assert (rows, cands) == (39, 227)
+    rows = sum(len(wys) for (cl, _q) in jaxme.CENTERS[subpel]
+               for (_p, wys, _wxs) in cl) \
+        + sum(len(qys) for (_cl, qrows) in jaxme.CENTERS[subpel]
+              for (_yf, qys, _qxs) in qrows or ())
+    cands = len(jaxme.offset_table(subpel))
+    assert (rows, cands) == {"half": (39, 227),
+                             "quarter": (68, 379)}[subpel]
     assert sum(t for t, _m in matmuls) == rows
     assert 4 * rows < cands
     # every candidate is in exactly one matmul, 64 rows of it each
@@ -206,18 +275,27 @@ def _tpu_text(jitted, *args, **kwargs) -> str:
         lowering_platforms=("tpu",)).as_text()
 
 
-def test_kernel_lowers_for_tpu_at_1080p(force_pallas):
+def _rd(subpel):
+    from thinvids_tpu.codecs.h264.rdo import RdConfig
+
+    return RdConfig(subpel=subpel)
+
+
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_lowers_for_tpu_at_1080p(force_pallas, subpel):
     H, W = 1088, 1920
     plane = jax.ShapeDtypeStruct((H, W), jnp.int16)
     chroma = jax.ShapeDtypeStruct((H // 2, W // 2), jnp.int16)
     text = _tpu_text(
-        jax.jit(jaxme.me_search), plane, plane, chroma, chroma,
+        jax.jit(functools.partial(jaxme.me_search, subpel=subpel)),
+        plane, plane, chroma, chroma,
         jax.ShapeDtypeStruct((2,), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32))
     assert "tpu_custom_call" in text
 
 
-def test_kernel_lowers_inside_gop_wave_shard_map(force_pallas):
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_lowers_inside_gop_wave_shard_map(force_pallas, subpel):
     from jax.sharding import Mesh
 
     from thinvids_tpu.parallel import dispatch
@@ -230,11 +308,12 @@ def test_kernel_lowers_inside_gop_wave_shard_map(force_pallas):
         jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
         jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
         jax.ShapeDtypeStruct((G,), jnp.int32),
-        mbw=W // 16, mbh=H // 16, mesh=mesh, compact=True)
+        mbw=W // 16, mbh=H // 16, mesh=mesh, compact=True, rd=_rd(subpel))
     assert "tpu_custom_call" in text
 
 
-def test_kernel_lowers_inside_sfe_p_step_shard_map(force_pallas):
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_lowers_inside_sfe_p_step_shard_map(force_pallas, subpel):
     from jax.sharding import Mesh
 
     from thinvids_tpu.parallel import dispatch
@@ -253,7 +332,7 @@ def test_kernel_lowers_inside_sfe_p_step_shard_map(force_pallas):
         jax.ShapeDtypeStruct((), jnp.int32),
         jax.ShapeDtypeStruct((n, 1), jnp.int32),
         mbw=W // 16, mbh_band=mbh_band, mesh=mesh, halo_rows=32,
-        num_bands=n)
+        num_bands=n, rd=_rd(subpel))
     assert "tpu_custom_call" in text
 
 
@@ -266,3 +345,116 @@ def test_use_pallas_is_not_silent(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
     with pytest.raises(RuntimeError, match="gpu"):
         jaxme.use_pallas()
+
+
+# ---------------------------------------------------------------------------
+# §8.4.2.2.1 / §8.4.2.2.2, position by position
+#
+# The plain functions below are written from the standard's equations
+# (8-241..8-261, Figure 8-4, Table 8-12; 8-266), share nothing with
+# jaxme or the decoder, and work on one edge-padded array at a time.
+# ---------------------------------------------------------------------------
+
+_PAD = 12
+
+
+def _at(R, h, w, dy, dx):
+    return R[_PAD + dy:_PAD + dy + h, _PAD + dx:_PAD + dx + w]
+
+
+def _tap(a, b, c, d, e, f):
+    return a - 5 * b + 20 * c + 20 * d - 5 * e + f
+
+
+def _clip255(x):
+    return np.clip(x, 0, 255)
+
+
+def _plain_luma_positions(ref):
+    """{letter: (h, w) plane} of Figure 8-4's samples for every integer
+    sample G of `ref` (coordinates clamped to the picture)."""
+    h, w = ref.shape
+    R = np.pad(ref.astype(np.int64), _PAD, mode="edge")
+    G = lambda dy=0, dx=0: _at(R, h, w, dy, dx)
+    b1 = lambda dy=0, dx=0: _tap(*(G(dy, dx + k) for k in range(-2, 4)))
+    h1 = lambda dy=0, dx=0: _tap(*(G(dy + k, dx) for k in range(-2, 4)))
+    b_ = lambda dy=0, dx=0: _clip255((b1(dy, dx) + 16) >> 5)
+    h_ = lambda dy=0, dx=0: _clip255((h1(dy, dx) + 16) >> 5)
+    j = _clip255((_tap(*(b1(k, 0) for k in range(-2, 4))) + 512) >> 10)
+    mean = lambda p, q: (p + q + 1) >> 1
+    b, hh, m, s = b_(), h_(), h_(0, 1), b_(1, 0)
+    return {
+        "G": G(), "b": b, "h": hh, "j": j,
+        "a": mean(G(), b), "c": mean(G(0, 1), b),
+        "d": mean(G(), hh), "n": mean(G(1, 0), hh),
+        "f": mean(b, j), "i": mean(hh, j), "k": mean(j, m),
+        "q": mean(j, s),
+        "e": mean(b, hh), "g": mean(b, m), "p": mean(hh, s),
+        "r": mean(m, s),
+    }
+
+
+#: Table 8-12: the sample at (xFrac, yFrac)
+_LETTER = {(0, 0): "G", (1, 0): "a", (2, 0): "b", (3, 0): "c",
+           (0, 1): "d", (1, 1): "e", (2, 1): "f", (3, 1): "g",
+           (0, 2): "h", (1, 2): "i", (2, 2): "j", (3, 2): "k",
+           (0, 3): "n", (1, 3): "p", (2, 3): "q", (3, 3): "r"}
+
+
+def _plain_chroma(ref, qy, qx):
+    """Equation 8-266 at eighth fractions (qx & 7, qy & 7)."""
+    h, w = ref.shape
+    R = np.pad(ref.astype(np.int64), _PAD, mode="edge")
+    oy, ox, ey, ex = qy >> 3, qx >> 3, qy & 7, qx & 7
+    A, B = _at(R, h, w, oy, ox), _at(R, h, w, oy, ox + 1)
+    C, D = _at(R, h, w, oy + 1, ox), _at(R, h, w, oy + 1, ox + 1)
+    return ((8 - ex) * (8 - ey) * A + ex * (8 - ey) * B
+            + (8 - ex) * ey * C + ex * ey * D + 32) >> 6
+
+
+@pytest.fixture(scope="module")
+def seeded_planes():
+    rng = np.random.default_rng(41)
+    w, h = 96, 64
+    return (rng.integers(0, 256, (h, w)).astype(np.uint8),
+            rng.integers(0, 256, (h // 2, w // 2)).astype(np.uint8),
+            rng.integers(0, 256, (h // 2, w // 2)).astype(np.uint8))
+
+
+@pytest.mark.parametrize("letter", [v for v in _LETTER.values() if v != "G"])
+def test_each_position_is_the_standards(letter, seeded_planes):
+    """A current frame that IS the reference at quarter vector (qy, qx)
+    — built by the plain functions — is found at exactly that vector
+    with zero residual: luma by §8.4.2.2.1's position, chroma by
+    §8.4.2.2.2 at the vector's eighth fractions; every whole-pixel
+    part the +-1 pixel window holds, so chroma sees all eight
+    fractions; and the in-repo decoder forms the same samples."""
+    from thinvids_tpu.codecs.h264 import decoder
+
+    ref, ru, rv = seeded_planes
+    h, w = ref.shape
+    xf, yf = next(k for k, v in _LETTER.items() if v == letter)
+    wide = _plain_luma_positions(np.pad(ref, _PAD, mode="edge"))[letter]
+    centers = jnp.zeros((3, 2), jnp.int32)
+    search = jax.jit(functools.partial(jaxme.me_search_xla,
+                                       subpel="quarter"))
+    frame = decoder._RefFrame(types.SimpleNamespace(y=ref, u=ru, v=rv))
+    for qy in [q for q in range(-4, 5) if q & 3 == yf]:
+        for qx in [q for q in range(-4, 5) if q & 3 == xf]:
+            # positions of a padded picture, read (qy >> 2, qx >> 2) on:
+            # the padding makes the clamp at the picture's edge
+            cur = _at(wide, h, w, qy >> 2, qx >> 2)
+            mv, py, pu, pv = jax.device_get(search(
+                jnp.asarray(cur, jnp.int16), jnp.asarray(ref, jnp.int16),
+                jnp.asarray(ru, jnp.int16), jnp.asarray(rv, jnp.int16),
+                centers, jnp.asarray(0, jnp.int32)))
+            assert {tuple(v) for v in mv.reshape(-1, 2)} == {(qy, qx)}
+            assert np.array_equal(py, cur)
+            assert np.array_equal(pu, _plain_chroma(ru, qy, qx))
+            assert np.array_equal(pv, _plain_chroma(rv, qy, qx))
+            # the decoder's own position function, an interior block
+            assert np.array_equal(frame.luma_pred(1, 2, (qy, qx)),
+                                  cur[16:32, 32:48])
+            du, dv = frame.chroma_pred(1, 2, (qy, qx))
+            assert np.array_equal(du, pu[8:16, 16:24])
+            assert np.array_equal(dv, pv[8:16, 16:24])

@@ -31,6 +31,9 @@ SFE_I = {"intra", "halo", "layout"}
 RD_ON = RdConfig(mode_decision=True, pskip=True, deblock=True)
 #: the serving point as a daemon's settings give it (aq_strength 1.0)
 RD_SERVING = RdConfig(mode_decision=True, pskip=True, deblock=True, aq_q=4)
+#: ... with quarter-sample vectors (`serving-1080p-camera`)
+RD_QUARTER = RdConfig(mode_decision=True, pskip=True, deblock=True, aq_q=4,
+                      subpel="quarter")
 
 #: instructions that do no work of their own
 NO_WORK = {"constant", "parameter", "tuple", "get-tuple-element", "bitcast"}
@@ -151,6 +154,21 @@ CASES = {
         lambda: _lower_gop(dispatch._encode_wave_gop, cuts=True,
                            mesh=_gop_mesh(), compact=True, rd=RD_SERVING),
         GOP | SPARSE | {"deblock"}),
+    # ISSUE 41, the executables of `serving-1080p-camera`: the quarter
+    # rows run inside the search's stage in the scan form, the bounded
+    # form and the split-frame P step
+    "gop_single_serving_quarter": (
+        lambda: _lower_gop(dispatch._encode_gop_single, compact=True,
+                           rd=RD_QUARTER),
+        GOP | SPARSE | {"deblock"}),
+    "gop_single_serving_quarter_cuts": (
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
+                           compact=True, rd=RD_QUARTER),
+        GOP | SPARSE | {"deblock"}),
+    "sfe_p_quarter": (
+        lambda: _lower_sfe(dispatch._sfe_p_step, True,
+                           rd=RdConfig(subpel="quarter")),
+        SFE_P | SPARSE),
 }
 
 

@@ -169,7 +169,7 @@ class LadderShardEncoder:
 
     def __init__(self, meta: VideoMeta, rungs: list[Rung],
                  mesh=None, gop_frames: int = 32,
-                 max_segments: int = 200) -> None:
+                 max_segments: int = 200, rd=None) -> None:
         from ..parallel.dispatch import GopShardEncoder   # lazy: jax
         from .scale import PlaneScaler
 
@@ -182,7 +182,7 @@ class LadderShardEncoder:
         def build(m: VideoMeta, qp: int) -> GopShardEncoder:
             return GopShardEncoder(m, qp=qp, mesh=mesh,
                                    gop_frames=int(gop_frames),
-                                   max_segments=int(max_segments))
+                                   max_segments=int(max_segments), rd=rd)
 
         self.encoders: list = []
         self.scalers: list = []         # None for the unscaled rung

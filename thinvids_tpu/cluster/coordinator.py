@@ -225,7 +225,7 @@ class Coordinator:
         tenant = tenant_of(
             input_path,
             (settings or {}).get("tenant") or snap.get("tenant", ""))
-        decision = evaluate_job_policy(meta, snap)
+        decision = evaluate_job_policy(meta, snap, settings)
         job = self.store.create(input_path, meta=meta, settings=settings,
                                 job_type=job_type, tenant=tenant)
         if not decision.accepted:

@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from ..core.config import Settings
+from ..core.config import Settings, subpel_of
 from ..core.types import VideoMeta
 
 # Codecs whose long-GOP/interlace quirks made stream-copy segmentation
@@ -35,8 +35,23 @@ class PolicyDecision:
     reason: str = ""                   # rejection reason when not accepted
 
 
-def evaluate_job_policy(meta: VideoMeta, settings: Settings) -> PolicyDecision:
+def evaluate_job_policy(meta: VideoMeta, settings: Settings,
+                        job_settings=None) -> PolicyDecision:
     codec = (meta.codec or "").lower()
+
+    # An encoder takes its vector precision from the daemon's settings
+    # (rdo.rd_from_settings of the live snapshot), never from the job's:
+    # a job that asks for another one is refused here, not encoded at
+    # the daemon's and reported done.
+    runs = subpel_of(settings)
+    asked = subpel_of({"subpel": runs, **(job_settings or {})})
+    if asked != runs:
+        return PolicyDecision(
+            accepted=False,
+            reason=f"subpel={asked!r} asked per job, but this daemon "
+                   f"encodes at subpel={runs!r} (TVT_SUBPEL / POST "
+                   f"/settings): vector precision is a daemon-wide "
+                   f"setting")
 
     if settings.reject_av1 and codec == "av1":
         return PolicyDecision(accepted=False, reason="av1 input rejected")

@@ -47,6 +47,7 @@ import threading
 import time
 from typing import TYPE_CHECKING, Any, Callable, Iterable, Mapping
 
+from ..core.config import subpel_of
 from ..core.status import ShardState, Status
 from ..core.types import (ChromaFormat, EncodedSegment, GopSpec, SegmentPlan,
                           VideoMeta)
@@ -184,6 +185,9 @@ class Shard:
     #: worker stages every GOP to `gop_frames`, one program shape for
     #: every shard however the shots fall
     pin_frames: bool = False
+    #: the vector precision the plan was signed with (the `subpel`
+    #: setting): the worker encodes at it whatever its own daemon's is
+    subpel: str = "half"
     #: hosts that rejected this shard's shape (old workers): the claim
     #: never offers it to them again, so an unsupported rejection
     #: cannot ping-pong
@@ -278,12 +282,19 @@ class Shard:
             # unchanged, and a worker that does not know the key pads
             # to the shard's longest GOP — the same bytes
             desc["pin_frames"] = True
-        if self.shape != "gop":
-            # explicit shape tag ONLY for new shapes: a GOP-range
-            # shard's wire form is unchanged, so a rolling upgrade
-            # keeps old workers serving GOP shards while band shards
-            # flow to new ones (unknown shape → unsupported-requeue)
-            desc["shape"] = self.shape
+        # explicit shape tag ONLY for new shapes: a GOP-range shard's
+        # wire form is unchanged, so a rolling upgrade keeps old
+        # workers serving GOP shards while band shards flow to new ones
+        # (unknown shape → unsupported-requeue). A vector precision
+        # other than half is part of the tag ("gop/quarter",
+        # "band/quarter"; wire_shape reads it back): a worker from
+        # before the setting knows neither and answers `unsupported`,
+        # where a key it ignored would have had it encode the shard at
+        # half-sample precision under the plan's signature.
+        tag = self.shape if self.subpel == "half" \
+            else f"{self.shape}/{self.subpel}"
+        if tag != "gop":
+            desc["shape"] = tag
         if self.shape == "band":
             desc["band"] = {
                 "start": self.band_start, "count": self.band_count,
@@ -1462,6 +1473,9 @@ class RemoteExecutor(LocalExecutor):
         scenecut = int(settings.get("scenecut", 0) or 0)
         if scenecut > 0:
             fields.extend(["scenecut", str(scenecut)])
+        # and the vector precision: other bytes for the same GOPs
+        if subpel_of(settings) != "half":
+            fields.extend(["subpel", subpel_of(settings)])
         return hashlib.sha256("|".join(fields).encode()).hexdigest()[:16]
 
     @staticmethod
@@ -1587,6 +1601,9 @@ class RemoteExecutor(LocalExecutor):
                         job, meta, plan, settings, qp=rung.qp,
                         rung=rung, token=token))
             rec = self._plan_record(sig, plan, shards, cuts)
+        for shard in shards:
+            # the signature holds it, so a resumed plan's is the same
+            shard.subpel = subpel_of(settings)
         refs = parts.begin_job(job.id, rec)
         reused = 0
         if resume and shards and shards[0].shape == "band":
@@ -2040,6 +2057,29 @@ class UnsupportedShardShape(RuntimeError):
     stops offering the shard to this host."""
 
 
+def wire_shape(desc: Mapping[str, Any]) -> tuple[str, str]:
+    """(shard shape, `subpel`) of a claim descriptor's shape tag
+    (Shard.descriptor): no tag is a GOP range, no precision in it is
+    half."""
+    shape, _, subpel = str(desc.get("shape", "gop") or "gop").partition("/")
+    return shape, subpel or "half"
+
+
+def _shard_rd(desc: Mapping[str, Any]):
+    """The RdConfig a claimed shard is encoded with: this worker's own
+    settings, at the vector precision the coordinator signed the plan
+    with."""
+    from ..codecs.h264.rdo import rd_from_settings
+    from ..core.config import SUBPELS, get_settings
+
+    subpel = wire_shape(desc)[1]
+    if subpel not in SUBPELS:
+        raise UnsupportedShardShape(
+            f"vector precision {subpel!r} not implemented by this worker")
+    return dataclasses.replace(rd_from_settings(get_settings()),
+                               subpel=subpel)
+
+
 def _encode_band_shard(desc: Mapping[str, Any], frames, mesh=None,
                        tracer=None, halo_transport=None
                        ) -> list[EncodedSegment]:
@@ -2076,7 +2116,7 @@ def _encode_band_shard(desc: Mapping[str, Any], frames, mesh=None,
         qp=int(desc["qp"]), total_bands=total,
         band_range=(lo, lo + cnt),
         halo_rows=int(band.get("halo_rows", 32) or 32),
-        session=session)
+        session=session, rd=_shard_rd(desc))
     if tracer is not None:
         enc.stages.set_tracer(tracer)
     enc.plan_override = SegmentPlan(
@@ -2120,7 +2160,7 @@ def encode_shard(desc: Mapping[str, Any], frames, mesh=None, tracer=None,
     fetch/pack stages become spans in the job's distributed trace."""
     from ..parallel.dispatch import GopShardEncoder
 
-    shape = str(desc.get("shape", "gop") or "gop")
+    shape = wire_shape(desc)[0]
     if shape == "band":
         return _encode_band_shard(desc, frames, mesh=mesh, tracer=tracer,
                                   halo_transport=halo_transport)
@@ -2145,10 +2185,12 @@ def encode_shard(desc: Mapping[str, Any], frames, mesh=None, tracer=None,
                     height=int(rung_desc["height"]), qp=int(desc["qp"]))
         enc = LadderShardEncoder(meta, [rung], mesh=mesh,
                                  gop_frames=int(desc.get("gop_frames",
-                                                         32)))
+                                                         32)),
+                                 rd=_shard_rd(desc))
     else:
         enc = GopShardEncoder(meta, qp=int(desc["qp"]), mesh=mesh,
-                              gop_frames=int(desc.get("gop_frames", 32)))
+                              gop_frames=int(desc.get("gop_frames", 32)),
+                              rd=_shard_rd(desc))
     if tracer is not None:
         enc.stages.set_tracer(tracer)
     enc.plan_override = SegmentPlan(
@@ -2383,7 +2425,7 @@ class WorkerDaemon:
         # the work loop below stays unconditional
         sink = buf if buf is not None else obs_trace.NULL_RECORDER
         halo_transport = None
-        if str(shard.get("shape", "gop") or "gop") == "band":
+        if wire_shape(shard)[0] == "band":
             band = shard.get("band") or {}
             halo_transport = HaloClient(
                 self.client.base, str(shard.get("job_id", "")),

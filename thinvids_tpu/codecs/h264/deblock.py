@@ -218,7 +218,7 @@ def _unpack_params(prm):
     return prm & 255, (prm >> 8) & 31, (prm >> 13) & 31, prm >> 18
 
 
-def _edge_params(grid, intra: bool, xp):
+def _edge_params(grid, intra: bool, xp, mv_per_pel: int = 2):
     """The packed parameters of every sample line of every edge, in the
     skewed layout: [t, 10, 16, y] int32 — rows 0..3 the vertical luma
     edges of a macroblock (16 sample rows each), 4..7 the horizontal
@@ -241,8 +241,9 @@ def _edge_params(grid, intra: bool, xp):
     else:
         def moved(other):
             d = xp.abs(mv - other)
-            # >= 1 integer sample, in half-sample units
-            return (xp.max(d, axis=1) >= 2)[:, None, None, :]
+            # >= 1 integer sample: 2 in half-sample units, 4 in
+            # quarter-sample units (§8.7.2.1's own)
+            return (xp.max(d, axis=1) >= mv_per_pel)[:, None, None, :]
 
         nz_l = xp.concatenate(
             [_from_left(nz, xp)[:, :, 3:], nz[:, :, :3]], axis=2)
@@ -477,14 +478,15 @@ def _wavefront_step(carry, blocks, xp):
 
 def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
                   mb_row0=0, total_mb_rows: int | None = None,
-                  edges=None, ops=NUMPY_OPS):
+                  edges=None, mv_per_pel: int = 2, ops=NUMPY_OPS):
     """Deblock one (padded) frame, or one band slice by itself.
 
     y: (16·mbh_p, 16·mbw) luma plane (any int dtype; uint8 ok);
     u/v: (8·mbh_p, 8·mbw); qp_map: (mbh_p, mbw) int QP_Y per MB;
     `intra` selects the picture-homogeneous bS rule. For P pictures,
     nz4: (4·mbh_p, 4·mbw) any-nonzero per 4x4 luma block and
-    mv: (mbh_p, mbw, 2) half-pel MVs. `mb_row0` (may be traced) and
+    mv: (mbh_p, mbw, 2) MVs, `mv_per_pel` units to an integer sample
+    (2: half-sample units; 4: quarter). `mb_row0` (may be traced) and
     `total_mb_rows` say where the plane's first macroblock row lies in
     the picture and how many the picture has: rows past the picture
     (band padding) are left alone. `edges` = (internal, left, top)
@@ -520,7 +522,7 @@ def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
             axis=1)
         prm = _edge_params(
             _skew(_to_lanes(grid, (2, 1, 0), lanes, ops), mbh, xp),
-            intra, xp)
+            intra, xp, mv_per_pel)
         # [y, r, x, c] -> [x, r, c, y] -> [t, r, c, y]; chroma
         # [2, y, r, x, c] -> [x, 2, r, c, y] -> [t, 2, r, c, y]
         ys = _skew(_to_lanes(y.reshape(mbh, 16, mbw, 16), (2, 1, 3, 0),

@@ -8,8 +8,11 @@ ctypes oracle — which this container may not have) and by the
 stamp/seam verification tooling to decode without external binaries.
 
 Scope grows with the encoder: one reference frame (the previous
-decoded picture), whole-MB partitions, half-pel MVs (quarter-pel mvd),
-the in-loop filter as each slice signals it (idc 0, 1 or 2, offsets
+decoded picture), whole-MB partitions, quarter-sample motion vectors
+(§8.4.2.2.1 luma, §8.4.2.2.2 chroma at eighth fractions — every vector
+here is in QUARTER-sample units, as mvd is coded, whatever `subpel` the
+encoder ran with: a half-sample encoder's stream simply holds even
+vectors), the in-loop filter as each slice signals it (idc 0, 1 or 2, offsets
 0), and pictures split into any number of slices —
 the split-frame-encoding path emits one slice per MB-row band, and
 this decoder applies the same §7.4.3 cross-slice neighbor
@@ -47,7 +50,8 @@ from .intra import (
 )
 from .transform import chroma_qp, dequant_4x4, inverse_4x4, inverse_zigzag
 
-#: luma interpolation pad: |mv| <= 16 pel plus the 6-tap reach (3)
+#: luma interpolation pad: |mv| <= 16 pel, a quarter position's next
+#: sample (1) plus the 6-tap reach (3)
 _MC_PAD = 24
 _MC_PAD_C = 12
 
@@ -56,6 +60,10 @@ _MC_PAD_C = 12
 class DecodedStream:
     meta: VideoMeta
     frames: list[Frame]
+    #: per picture its (mbh, mbw, 2) motion vectors (dy, dx) in
+    #: quarter-sample units, skipped macroblocks' inferred ones
+    #: included; None for an intra picture
+    mvs: list = dataclasses.field(default_factory=list)
 
 
 class _Picture:
@@ -72,7 +80,7 @@ class _Picture:
         # CONSULTED (availability checks below), matching §7.4.3.
         self.luma_counts = np.zeros((4 * mbh, 4 * mbw), np.int32)
         self.chroma_counts = np.zeros((2, 2 * mbh, 2 * mbw), np.int32)
-        self.mv = np.zeros((mbh, mbw, 2), np.int32)     # (dy, dx) half-pel
+        self.mv = np.zeros((mbh, mbw, 2), np.int32)     # (dy, dx) quarter
         self.decoded = 0                                # MBs decoded so far
         # in-loop deblocking state: the effective QP_Y of every MB (the
         # running slice QP after mb_qp_delta; uncoded MBs keep the
@@ -101,23 +109,59 @@ class _Picture:
 
 
 def _tap6(x: np.ndarray, axis: int) -> np.ndarray:
-    """6-tap §8.4.2.2.1 filter along `axis` with the same roll
-    convention as jaxme._tap6_lane (roll(x, k) moves element l to
-    l + k): out[l] = x[l-2] -5x[l-1] +20x[l] +20x[l+1] -5x[l+2] +x[l+3].
-    Wrapped edge rows/lanes stay inside the MC pad and are never read."""
+    """§8.4.2.2.1's 6-tap filter (1, -5, 20, 20, -5, 1) along `axis`,
+    unrounded: out[l] lies half a sample past x[l], between x[l] and
+    x[l+1]. Wrapped edge rows/lanes stay inside the MC pad and are
+    never read."""
     r = lambda k: np.roll(x, k, axis=axis)
     return r(2) - 5 * r(1) + 20 * x + 20 * r(-1) - 5 * r(-2) + r(-3)
 
 
-def _halfpel_planes_np(ref_y: np.ndarray):
-    """(R, B, H, J) int32 planes over an edge-padded reference — the
-    numpy mirror of jaxme._halfpel_planes (identical rounding)."""
-    r32 = np.pad(ref_y.astype(np.int32), _MC_PAD, mode="edge")
-    hb1 = _tap6(r32, axis=1)
-    b = np.clip((hb1 + 16) >> 5, 0, 255)
-    h = np.clip((_tap6(r32, axis=0) + 16) >> 5, 0, 255)
-    j = np.clip((_tap6(hb1, axis=0) + 512) >> 10, 0, 255)
-    return (r32, b, h, j)
+def _half_sample_planes(ref_y: np.ndarray):
+    """(G, b, h, j) int32 planes over the edge-padded reference: the
+    integer samples and the three half-sample positions of equations
+    8-241..8-247 at every integer sample's offset (b to its right, h
+    below it, j below and right)."""
+    G = np.pad(ref_y.astype(np.int32), _MC_PAD, mode="edge")
+    b1 = _tap6(G, axis=1)
+    b = np.clip((b1 + 16) >> 5, 0, 255)
+    h = np.clip((_tap6(G, axis=0) + 16) >> 5, 0, 255)
+    j = np.clip((_tap6(b1, axis=0) + 512) >> 10, 0, 255)
+    return G, b, h, j
+
+
+# §8.4.2.2.1, Figure 8-4 and Table 8-12: the sixteen luma positions of
+# a sample, one function each, by the names the figure gives them. A
+# position function takes the (G, b, h, j) planes and the block's
+# integer sample (r, c) in them. H, M are the integer samples right of
+# and below G, m the h-type sample under H, s the b-type sample right
+# of M; the twelve quarter positions are the rounded means of the two
+# samples equations 8-250..8-261 name.
+
+def _sample(plane: int, dr: int = 0, dc: int = 0):
+    def position(planes, r, c):
+        return planes[plane][r + dr:r + dr + 16, c + dc:c + dc + 16]
+    return position
+
+
+def _mean_of(p, q):
+    def position(planes, r, c):
+        return (p(planes, r, c) + q(planes, r, c) + 1) >> 1
+    return position
+
+
+_G, _b, _h, _j = (_sample(k) for k in range(4))
+_H, _M = _sample(0, 0, 1), _sample(0, 1, 0)
+_m, _s = _sample(2, 0, 1), _sample(1, 1, 0)
+#: Table 8-12: [yFrac][xFrac] -> the position's function
+_LUMA_POSITIONS = (
+    (_G, _mean_of(_G, _b), _b, _mean_of(_H, _b)),                # G a b c
+    (_mean_of(_G, _h), _mean_of(_b, _h), _mean_of(_b, _j),
+     _mean_of(_b, _m)),                                          # d e f g
+    (_h, _mean_of(_h, _j), _j, _mean_of(_j, _m)),                # h i j k
+    (_mean_of(_M, _h), _mean_of(_h, _s), _mean_of(_j, _s),
+     _mean_of(_m, _s)),                                          # n p q r
+)
 
 
 class _RefFrame:
@@ -130,24 +174,25 @@ class _RefFrame:
         self._cv = None
 
     def luma_pred(self, my: int, mx: int, mv) -> np.ndarray:
+        """The (16, 16) prediction at quarter-sample vector `mv`."""
         if self._planes is None:
-            self._planes = _halfpel_planes_np(self.y)
+            self._planes = _half_sample_planes(self.y)
         dy, dx = int(mv[0]), int(mv[1])
-        plane = self._planes[(dy & 1) * 2 + (dx & 1)]
-        r0 = _MC_PAD + 16 * my + (dy >> 1)
-        c0 = _MC_PAD + 16 * mx + (dx >> 1)
-        return plane[r0:r0 + 16, c0:c0 + 16]
+        r0 = _MC_PAD + 16 * my + (dy >> 2)
+        c0 = _MC_PAD + 16 * mx + (dx >> 2)
+        return _LUMA_POSITIONS[dy & 3][dx & 3](self._planes, r0, c0)
 
     def chroma_pred(self, my: int, mx: int, mv):
-        """(pred_u, pred_v) via the §8.4.2.2.2 eighth-pel bilinear."""
+        """(pred_u, pred_v) via the §8.4.2.2.2 bilinear: a quarter luma
+        sample is an eighth of a chroma sample."""
         if self._cu is None:
             self._cu = np.pad(self.u.astype(np.int32), _MC_PAD_C,
                               mode="edge")
             self._cv = np.pad(self.v.astype(np.int32), _MC_PAD_C,
                               mode="edge")
         dy, dx = int(mv[0]), int(mv[1])
-        oy, ox = dy >> 2, dx >> 2
-        ey, ex = (dy & 3) * 2, (dx & 3) * 2
+        oy, ox = dy >> 3, dx >> 3
+        ey, ex = dy & 7, dx & 7
         r0 = _MC_PAD_C + 8 * my + oy
         c0 = _MC_PAD_C + 8 * mx + ox
 
@@ -337,13 +382,10 @@ def _decode_pslice(br: BitReader, pic: _Picture, header: SliceHeader,
         mb_type = br.ue()
         if mb_type != 0:
             raise ValueError(f"unsupported P mb_type {mb_type}")
-        mvd_x = br.se()                        # quarter-pel, x first
+        mvd_x = br.se()                        # quarter samples, x first
         mvd_y = br.se()
-        if (mvd_x | mvd_y) & 1:
-            raise ValueError("quarter-pel mvd not supported (half-pel "
-                             "encoder)")
         mvp, _ = _mvp_and_skip(pic, my, mx, first)
-        mv = np.array([mvp[0] + mvd_y // 2, mvp[1] + mvd_x // 2], np.int32)
+        mv = np.array([mvp[0] + mvd_y, mvp[1] + mvd_x], np.int32)
         pic.mv[my, mx] = mv
         cbp = _CODE_TO_CBP_INTER[br.ue()]
         cbp_luma, cbp_chroma = cbp & 15, cbp >> 4
@@ -400,6 +442,7 @@ def decode_annexb(stream: bytes) -> DecodedStream:
     sps: SPS | None = None
     pps: PPS | None = None
     frames: list[Frame] = []
+    mvs: list = []
     pic: _Picture | None = None
     ref: _RefFrame | None = None
 
@@ -423,12 +466,13 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             nz4 = None if pic.intra else (pic.luma_counts > 0)
             pic.y, pic.u, pic.v = deblock_frame(
                 pic.y, pic.u, pic.v, pic.qp_mb, intra=pic.intra,
-                nz4=nz4, mv=None if pic.intra else pic.mv,
+                nz4=nz4, mv=None if pic.intra else pic.mv, mv_per_pel=4,
                 edges=pic.deblock_edges())
         w, h = sps.width, sps.height
         frames.append(Frame(
             pic.y[:h, :w], pic.u[:h // 2, :w // 2],
             pic.v[:h // 2, :w // 2], pts=len(frames)))
+        mvs.append(None if pic.intra else pic.mv)
         ref = _RefFrame(pic)                  # next P picture's reference
         pic = None
 
@@ -470,4 +514,4 @@ def decode_annexb(stream: bytes) -> DecodedStream:
                      fps_num=sps.fps_num, fps_den=sps.fps_den,
                      num_frames=len(frames), chroma=ChromaFormat.YUV420,
                      codec="h264", size_bytes=len(stream))
-    return DecodedStream(meta=meta, frames=frames)
+    return DecodedStream(meta=meta, frames=frames, mvs=mvs)

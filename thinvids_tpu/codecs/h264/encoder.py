@@ -669,12 +669,14 @@ def gop_slice_thunks_planes(intra, planes, num_frames: int, mbw: int,
     from . import inter as inter_mod
 
     deblock_idc = 0 if rd is not None and rd.deblock else 1
+    mv_per_pel = rd.mv_per_pel if rd is not None else 2
     mv8, lp, udc, vdc, uac, vac = planes
     return _gop_slice_thunks(
         intra,
         lambda i, fn: inter_mod.pack_p_slice_plane(
             mv8[i], lp[i], udc[i], vdc[i], uac[i], vac[i], mbw, mbh,
-            sps, pps, qp, frame_num=fn, deblock_idc=deblock_idc),
+            sps, pps, qp, frame_num=fn, deblock_idc=deblock_idc,
+            mv_per_pel=mv_per_pel),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         rd=rd)
 
@@ -709,11 +711,13 @@ def pack_gop_slices(intra, pouts, num_frames: int, mbw: int, mbh: int,
     from . import inter as inter_mod
 
     deblock_idc = 0 if rd is not None and rd.deblock else 1
+    mv_per_pel = rd.mv_per_pel if rd is not None else 2
     mv, l16, cdc, cac = pouts
     return _pack_gop_common(
         intra,
         lambda i, fn: inter_mod.pack_p_slice(
             mv[i], l16[i], cdc[i], cac[i], mbw, mbh, sps, pps, qp,
-            frame_num=fn, deblock_idc=deblock_idc),
+            frame_num=fn, deblock_idc=deblock_idc,
+            mv_per_pel=mv_per_pel),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         pool=pool, rd=rd)

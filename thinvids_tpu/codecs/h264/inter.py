@@ -6,8 +6,11 @@ prediction (§8.4.1.3), P_Skip inference (§8.4.1.1), inter CBP mapping
 (Table 9-4), and the CAVLC MB layer for P_L0_16x16 macroblocks.
 
 Scope: one reference frame (the previous recon), whole-MB partitions,
-half-pel MVs (quarter-pel mvd coding), all-inter P frames (no intra
-refresh MBs yet).
+all-inter P frames (no intra refresh MBs yet). Vectors arrive in the
+units of the encode's `subpel` (rdo.RdConfig.mv_per_pel to an integer
+sample: 2 = half-sample units, 4 = quarter) and mvd is coded in quarter
+samples, `4 // mv_per_pel` to a unit; prediction and the P_Skip
+inference compare vectors and work in either unit alike.
 """
 
 from __future__ import annotations
@@ -133,10 +136,12 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
                        u_ac: np.ndarray, v_ac: np.ndarray,
                        mbw: int, mbh: int, sps: SPS, pps: PPS, qp: int,
                        frame_num: int, native: bool | None = None,
-                       first_mb: int = 0, deblock_idc: int = 1) -> bytes:
+                       first_mb: int = 0, deblock_idc: int = 1,
+                       mv_per_pel: int = 2) -> bytes:
     """Entropy-pack one P slice straight from plane-layout levels.
 
-    mv: (nmb, 2) int; luma_plane: (16*mbh, 16*mbw) int16 quantized
+    mv: (nmb, 2) int, `mv_per_pel` units to an integer sample;
+    luma_plane: (16*mbh, 16*mbw) int16 quantized
     coeffs in natural block positions; u_dc/v_dc: (nmb, 4) hadamard-
     domain DC levels; u_ac/v_ac: (8*mbh, 8*mbw) int16 with DC positions
     zero. This is the sharded path's pack entry — the device ships raw
@@ -162,7 +167,7 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
             hdr_bytes, hdr_bits = bw.getvalue_unaligned()
             ebsp = native_mod.pack_pslice_plane(
                 hdr_bytes, hdr_bits, np.asarray(mv, np.int8), luma_plane,
-                u_dc, v_dc, u_ac, v_ac, mbw, mbh)
+                u_dc, v_dc, u_ac, v_ac, mbw, mbh, 4 // mv_per_pel)
             start = b"\x00\x00\x00\x01"
             nal_header = bytes([(2 << 5) | NAL_SLICE_NON_IDR])
             return start + nal_header + ebsp
@@ -173,17 +178,19 @@ def pack_p_slice_plane(mv: np.ndarray, luma_plane: np.ndarray,
     cdc = np.stack([u_dc, v_dc], axis=1).astype(np.int32)
     return pack_p_slice(np.asarray(mv, np.int32), l16, cdc, cac, mbw, mbh,
                         sps, pps, qp, frame_num, native=False,
-                        first_mb=first_mb, deblock_idc=deblock_idc)
+                        first_mb=first_mb, deblock_idc=deblock_idc,
+                        mv_per_pel=mv_per_pel)
 
 
 def pack_p_slice(mv: np.ndarray, luma16: np.ndarray, chroma_dc: np.ndarray,
                  chroma_ac: np.ndarray, mbw: int, mbh: int, sps: SPS,
                  pps: PPS, qp: int, frame_num: int,
                  native: bool | None = None, first_mb: int = 0,
-                 deblock_idc: int = 1) -> bytes:
+                 deblock_idc: int = 1, mv_per_pel: int = 2) -> bytes:
     """Entropy-pack one P slice into an Annex-B NAL unit.
 
-    mv: (nmb, 2) half-pel (dy, dx); luma16: (nmb, 16, 16) z-scan
+    mv: (nmb, 2) (dy, dx), `mv_per_pel` units to an integer sample
+    (2: half-sample units); luma16: (nmb, 16, 16) z-scan
     blocks of 16 zig-zag coeffs; chroma_dc: (nmb, 2, 4);
     chroma_ac: (nmb, 2, 4, 15). `first_mb` as in
     :func:`pack_p_slice_plane`.
@@ -204,13 +211,14 @@ def pack_p_slice(mv: np.ndarray, luma16: np.ndarray, chroma_dc: np.ndarray,
             hdr_bytes, hdr_bits = bw.getvalue_unaligned()
             ebsp = native_mod.pack_pslice(
                 hdr_bytes, hdr_bits, mv, luma16, chroma_dc, chroma_ac,
-                mbw, mbh)
+                mbw, mbh, 4 // mv_per_pel)
             start = b"\x00\x00\x00\x01"
             nal_header = bytes([(2 << 5) | NAL_SLICE_NON_IDR])
             return start + nal_header + ebsp
         if native:
             raise RuntimeError("native packer requested but unavailable")
 
+    mvd_scale = 4 // mv_per_pel
     mvp, skip_mv = predict_mvs(mv, mbw, mbh)
     luma_counts = np.zeros((4 * mbh, 4 * mbw), np.int32)
     chroma_counts = np.zeros((2, 2 * mbh, 2 * mbw), np.int32)
@@ -233,11 +241,11 @@ def pack_p_slice(mv: np.ndarray, luma16: np.ndarray, chroma_dc: np.ndarray,
             bw.ue(skip_run)                    # mb_skip_run
             skip_run = 0
             bw.ue(0)                           # mb_type = P_L0_16x16
-            # mv is in half-pel units; mvd is coded in quarter-pel
-            # units, horizontal component first (§7.3.5.1 compIdx
+            # mvd is coded in quarter-sample units (mvd_scale to one
+            # of mv's), horizontal component first (§7.3.5.1 compIdx
             # order); our mv layout is (dy, dx).
-            bw.se(2 * int(mv[mi, 1] - mvp[mi, 1]))   # mvd_l0 x
-            bw.se(2 * int(mv[mi, 0] - mvp[mi, 0]))   # mvd_l0 y
+            bw.se(mvd_scale * int(mv[mi, 1] - mvp[mi, 1]))   # mvd_l0 x
+            bw.se(mvd_scale * int(mv[mi, 0] - mvp[mi, 0]))   # mvd_l0 y
             bw.ue(CBP_INTER_TO_CODE[cbp])      # coded_block_pattern
             if cbp:
                 bw.se(0)                       # mb_qp_delta

@@ -9,7 +9,8 @@ Replaces the inter coding half of the reference's ffmpeg encode op point
   around dynamically re-anchored centers, half-pel 6-tap interpolation,
   and a running per-MB best-(cost, mv, pred) select — the kernel emits
   the final prediction planes, so MC never runs as a separate pass.
-  MVs are HALF-PEL units throughout.
+  MVs are in the units of `rd.subpel` throughout: half-sample units
+  with "half", quarter-sample units with "quarter" (rd.mv_per_pel).
 - Residual DCT/quant/dequant/IDCT run on the planes as they lie, taken
   once to their 128-lane column tiles (T, H, 128): the 4x4 butterflies,
   the chroma DC Hadamard, the per-block and per-MB reductions are
@@ -243,7 +244,8 @@ def _chroma_plane_to_blocks(z, mbw: int, mbh: int):
 def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
                     mbh: int, blocked: bool = True, rd=RD_OFF):
     """One P frame given previous recon planes (int16). `pred_mv` is the
-    previous frame's median MV in half-pel units (a search center).
+    previous frame's median MV (a search center), in rd.subpel's units
+    as the returned `mv` and median are.
 
     Search and compensation are the kernel's (jaxme.me_search); the
     residual (:func:`_residual_p`) transforms, quantizes and
@@ -265,7 +267,7 @@ def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
         qp32 = qp.astype(jnp.int32)
 
     mv, pred_y, pred_u, pred_v, med_mv = jaxme.me_search(
-        cy16, ry, ru, rv, pred_mv, qp32)
+        cy16, ry, ru, rv, pred_mv, qp32, subpel=rd.subpel)
 
     (luma_levels, chroma_dc, chroma_ac, recon_y, recon_u, recon_v,
      nz4) = _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp,
@@ -275,7 +277,7 @@ def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
             qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh, mbw))
         recon_y, recon_u, recon_v = jaxdeblock.deblock_frame_jax(
             recon_y, recon_u, recon_v, qp_map, intra=False, nz4=nz4,
-            mv=mv)
+            mv=mv, mv_per_pel=rd.mv_per_pel)
     with stage("layout"):
         mv = mv.reshape(n, 2)
     return (mv, luma_levels, chroma_dc, chroma_ac,
@@ -506,6 +508,17 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
 from .layout import _INTRA_FLAT_MB, _P_FLAT_MB  # noqa: E402
 
 
+def _check_mv8(rd) -> None:
+    """The int8 MV transfer rides on search candidates being bounded by
+    construction: centers clamp to ±(SEARCH_RANGE - window) pel, offsets
+    add ≤ the window (the quarter window lies inside it), so |mv| ≤
+    rd.mv_per_pel * SEARCH_RANGE units per frame — 32 half units, 64
+    quarter units (a P frame references its predecessor: MVs never
+    accumulate)."""
+    if rd.mv_per_pel * SEARCH_RANGE > 127:
+        raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
+
+
 def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
                       n_frames=None):
     """Closed-GOP compute emitting PLANE-layout levels for the sharded
@@ -520,12 +533,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
       | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only ]
     The host inverse is parallel/dispatch._unflatten_gop.
     """
-    # The int8 MV transfer rides on search candidates being bounded by
-    # construction: centers clamp to ±(SEARCH_RANGE - window) pel, offsets
-    # add ≤ the window, so |mv| ≤ 2 * SEARCH_RANGE half-pel units per
-    # frame (a P frame references its predecessor: MVs never accumulate).
-    if 2 * SEARCH_RANGE > 127:
-        raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
+    _check_mv8(rd)
     qp, qpc, y0, u0, v0 = _gop_head(ys, us, vs, qp)
     intra, (ry, ru, rv) = _intra_frame_outputs(
         y0, u0, v0, qp, mbw=mbw, mbh=mbh, rd=rd)
@@ -571,7 +579,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
 
 def _deblock_band(ry, ru, rv, qp, *, intra: bool, nz4, mv, mbw: int,
                   mbh_band: int, total_mb_rows: int, axis_name,
-                  num_bands: int):
+                  num_bands: int, mv_per_pel: int = 2):
     """Deblock one band's recon by itself: the band is a slice that
     signals disable_deblocking_filter_idc = 2, so a decoder filters no
     edge between two bands, and §8.7's order (each macroblock row needs
@@ -588,7 +596,8 @@ def _deblock_band(ry, ru, rv, qp, *, intra: bool, nz4, mv, mbw: int,
         qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh_band, mbw))
     return jaxdeblock.deblock_frame_jax(
         ry, ru, rv, qp_map, intra=intra, nz4=nz4, mv=mv,
-        mb_row0=mb_row0, total_mb_rows=total_mb_rows)
+        mb_row0=mb_row0, total_mb_rows=total_mb_rows,
+        mv_per_pel=mv_per_pel)
 
 
 @stage("halo")
@@ -717,8 +726,7 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
     so layout.unflatten_p_planes(flat, mv8, 2, ...) is the host
     inverse), plus the chained (ry, ru, rv, med_mv) carry; with
     `return_hist` the tail is (cnt, n, (ry, ru, rv, pred_mv))."""
-    if 2 * SEARCH_RANGE > 127:
-        raise ValueError("SEARCH_RANGE exceeds the int8 MV transfer")
+    _check_mv8(rd)
     if rd.deblock and (ext is not None or probe is not None
                        or return_hist):
         # Farm band slices have never run with the in-loop filter
@@ -737,7 +745,7 @@ def sfe_p_band(y, u, v, carry, qp, real_rows, *, mbw: int, mbh_band: int,
         cy16, ry, ru, rv, pred_mv, qp32, halo_rows=halo_rows,
         num_bands=num_bands, axis_name=axis_name, real_rows=real_rows,
         ext=ext, edge_top=edge_top, edge_bot=edge_bot, probe=probe,
-        return_hist=return_hist)
+        return_hist=return_hist, subpel=rd.subpel)
     if return_hist:
         mv, py, pu, pv, cnt, n = out
     else:

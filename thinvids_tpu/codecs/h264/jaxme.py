@@ -1,4 +1,4 @@
-"""Half-pel motion search + compensation as a Pallas TPU kernel.
+"""Sub-sample motion search + compensation as a Pallas TPU kernel.
 
 Replaces the r4 fused uniform-shift fori_loop in jaxinter._search_mc
 (~171 sequential device steps per P frame — launch-bound at 1080p) with
@@ -42,8 +42,16 @@ The same search semantics are also implemented in plain XLA
 against, and the path used off-TPU (CPU tests). Both produce identical
 (mv, pred).
 
-MV units are HALF-PEL throughout (the entropy packers scale mvd by 2
-to quarter-pel units).
+MV units follow `subpel` (rdo.RdConfig.subpel, a compile-time value):
+with "half" (the default) every vector in and out of this module is in
+HALF-sample units and the entropy packers scale mvd by 2; with
+"quarter" the table (offset_table("quarter")) is that one plus the
+fine half-sample classes and §8.4.2.2.1's twelve quarter positions
+(each the rounded mean of two of the G / b / h / j samples) round the
+temporal-median centre, and those positions round the zero centre; and
+every vector in and out — the candidates' cost, `pred_mv`, the returned
+`mv` and the median — is in QUARTER-sample units, which is what mvd is
+coded in.
 """
 
 from __future__ import annotations
@@ -58,6 +66,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.log import get_logging
+from .rdo import MV_PER_PEL
 from .stages import stage
 
 _LOG = get_logging(__name__)
@@ -66,6 +75,8 @@ SEARCH_RANGE = 16          # max |mv| in integer pel
 _WR = 4                    # integer window radius (pel) around each center
 _HR = 3                    # fine half-pel window radius (half units)
 _ZR = 2                    # zero-window radius (half units)
+_QR = 4                    # quarter window radius (quarter units) round
+                           # the temporal-median and the zero centre
 _CLIM = SEARCH_RANGE - _WR     # center clamp (pel)
 
 # MV-cost lambda per half-pel unit of |mv|, indexed by QP. Scales with
@@ -75,6 +86,9 @@ _CLIM = SEARCH_RANGE - _WR     # center clamp (pel)
 # zero vector, killing P_Skip runs.
 LAMBDA_H = np.maximum(
     3, np.round(2.5 * 2.0 ** ((np.arange(52) - 12) / 6.0))).astype(np.int32)
+# The same price per QUARTER-sample unit (subpel="quarter": |mv| counts
+# twice as many units for the same displacement).
+LAMBDA_Q = np.maximum(2, (LAMBDA_H + 1) // 2).astype(np.int32)
 
 # Padded-layout constants (see _pad_luma/_pad_chroma): generous halos so
 # center roll + window offset + 6-tap reach never leaves real samples.
@@ -126,10 +140,11 @@ def _window_classes(int_rad_pel: int, fine_rad_half: int
 
 
 CENTER_CLASSES = _window_classes(_WR, _HR)
-#: the temporal-median center keeps only its integer window — its role
-#: is to re-acquire motion the probe missed; sub-pel refinement around
-#: it duplicates work the probe/zero windows already do (measured: no
-#: quality change, -15% kernel time)
+#: under subpel="half" the temporal-median center keeps only its integer
+#: window — its role is to re-acquire motion the probe missed; sub-pel
+#: refinement around it duplicates work the probe/zero windows already
+#: do (measured: no quality change, -15% kernel time). Under "quarter"
+#: it is where the quarter rows go, the fine classes with them (CENTERS)
 CENTER_B_CLASSES = CENTER_CLASSES[:1]
 ZERO_CLASSES = _window_classes(_ZR // 2, _ZR)
 
@@ -139,13 +154,95 @@ def _class_offsets(classes) -> list[tuple[int, int]]:
             for wy in wys for wx in wxs]
 
 
-#: (center_index, wy, wx) in selection order; strict '<' keeps the first
-#: best, so earlier entries win ties. Center 2 is the zero vector.
-OFFSET_TABLE: list[tuple[int, int, int]] = (
-    [(0,) + o for o in _class_offsets(CENTER_CLASSES)]
-    + [(1,) + o for o in _class_offsets(CENTER_B_CLASSES)]
-    + [(2,) + o for o in _class_offsets(ZERO_CLASSES)]
-)
+# ---------------------------------------------------------------------------
+# quarter-sample candidates (subpel="quarter")
+#
+# §8.4.2.2.1 makes each of the twelve quarter positions the rounded mean
+# (p + q + 1) >> 1 of two samples of the half-sample grid: along the one
+# odd axis its two neighbours (a c d n: G/b/h and the next integer
+# sample; f i k q: j and b/h/m/s), on the diagonals the two of its four
+# neighbours that are b- or h-type (e g p r). Those samples are what a
+# HALF candidate's window holds, so a quarter candidate needs no plane
+# the kernel has not already built.
+#
+# The offsets are walked as ROWS of constant qy, one yFrac (qy & 3) at
+# a time: each xFrac of the row gets a plane of its own (the mean of the
+# two planes its position names), and a candidate is a static window of
+# it (_me_kernel.quarter_rows).
+#
+# They go round the two even-pel centres that are never more than a
+# pixel from the motion they stand for — the temporal median, which
+# under "quarter" takes the fine half-sample classes as well, and zero —
+# in a window of that pixel (_QR). Not round the probe's: that centre is
+# a multiple of _COARSE pixels, so a window of a pixel round it misses
+# most displacements; it keeps the classes it has under "half", which is
+# what refines motion the probe finds and the median does not (a GOP's
+# first P frame, a second motion in the picture). Measured on five
+# contents (PERF.md §6): with the fine classes on the median a wider
+# window there (radius 5, 6) saves no more bytes on four of them;
+# without them on the probe a fast pan's first P frame doubles.
+# ---------------------------------------------------------------------------
+
+def _quarter_pair(qy: int, qx: int) -> tuple[tuple[int, int], tuple[int, int]]:
+    """The two half-unit offsets (hy, hx) whose samples' rounded mean is
+    the sample at quarter offset (qy, qx); a point of the half grid is
+    its own pair."""
+    fy, fx = qy >> 1, qx >> 1
+    if qy & 1 and qx & 1:                   # e g p r: the b/h-type pair
+        if (fy + fx) & 1:
+            return (fy, fx), (fy + 1, fx + 1)
+        return (fy, fx + 1), (fy + 1, fx)
+    return (fy, fx), (fy + (qy & 1), fx + (qx & 1))
+
+
+def _quarter_rows(rad: int) -> list[tuple[int, list[int], list[int]]]:
+    """[(yfrac, qys, qxs)] — every offset of the ± `rad` quarter window
+    with an odd component, as rows of constant qy grouped by yfrac."""
+    span = range(-rad, rad + 1)
+    return [(yf, [q for q in span if q & 3 == yf],
+             [q for q in span if (q | yf) & 1])
+            for yf in range(4)]
+
+
+#: by `subpel`, per centre — probe, temporal median, zero: (parity
+#: classes in half units, quarter rows or None). Kernel and mirror walk
+#: a centre's classes, then its rows.
+CENTERS = {
+    "half": ((CENTER_CLASSES, None), (CENTER_B_CLASSES, None),
+             (ZERO_CLASSES, None)),
+    "quarter": ((CENTER_CLASSES, None),
+                (CENTER_CLASSES, _quarter_rows(_QR)),
+                (ZERO_CLASSES, _quarter_rows(_QR))),
+}
+
+
+def _table(subpel: str) -> list[tuple[int, int, int]]:
+    unit = MV_PER_PEL[subpel] // 2          # MV units per half sample
+    return [
+        (ci, oy, ox)
+        for ci, (classes, rows) in enumerate(CENTERS[subpel])
+        for (oy, ox) in (
+            [(unit * wy, unit * wx) for (wy, wx) in _class_offsets(classes)]
+            + [(qy, qx) for (_yf, qys, qxs) in rows or ()
+               for qy in qys for qx in qxs])]
+
+
+_TABLES = {subpel: _table(subpel) for subpel in CENTERS}
+#: (center_index, wy, wx) of subpel="half", half units, in selection
+#: order; strict '<' keeps the first best, so earlier entries win ties.
+#: Center 2 is the zero vector.
+OFFSET_TABLE: list[tuple[int, int, int]] = _TABLES["half"]
+#: MV-cost lambda by QP, per unit of `subpel`
+_LAMBDAS = {"half": LAMBDA_H, "quarter": LAMBDA_Q}
+
+
+def offset_table(subpel: str = "half") -> list[tuple[int, int, int]]:
+    """The candidates scored per macroblock under `subpel`, in selection
+    order, in that value's MV units (rdo.MV_PER_PEL to a sample)."""
+    if subpel not in _TABLES:
+        raise ValueError(
+            f"subpel must be one of {tuple(_TABLES)}, not {subpel!r}")
+    return _TABLES[subpel]
 
 
 # ---------------------------------------------------------------------------
@@ -283,8 +380,13 @@ def _pad_cur(y, H, H4, W, WcK):
 # the kernel
 # ---------------------------------------------------------------------------
 
-def _me_kernel(H: int, W: int):
+def _me_kernel(H: int, W: int, subpel: str = "half"):
     mbh, mbw, H4, RG, WcK, nch, W2K, WcuK, W2cK = _geom(H, W)
+    quarter = MV_PER_PEL[subpel] == 4
+
+    def mv_of(c, w):
+        """Centre `c` (pel) + half-unit offset `w`, in `subpel`'s units."""
+        return 4 * c + 2 * w if quarter else 2 * c + w
 
     def kernel(cent_ref,
                cur_ref,
@@ -335,37 +437,30 @@ def _me_kernel(H: int, W: int):
         pv = jnp.zeros((32, 128), jnp.int32)
         state = (bestc, bmy, bmx, py, bestcc, pu, pv)
 
-        def row_body(state, Pl, Cur, Cvr, wy, wxs, cy, cx):
-            """One ROW of candidates (fixed wy, every wx of `wxs`): Pl,
-            Cur, Cvr are the class plane and the chroma planes rolled to
-            this row, so candidate wx is the STATIC window of Pl at lane
-            _PH + (wx >> 1) (chroma: _PHC + (wx >> 2)). The row's
-            |cur - cand| planes are laid under each other and block-
-            summed by ONE matmul against SS; the selection then walks
-            the row in table order. wy, cy, cx traced; wxs static."""
-            cands = [
-                jax.lax.slice(Pl, (_KPV, _PH + (wx >> 1)),
-                              (_KPV + 64, _PH + (wx >> 1) + 256))
-                for wx in wxs]
+        def score_row(cands):
+            """The row's |cur - cand| planes laid under each other and
+            block-summed by ONE matmul against SS."""
             stack = jnp.concatenate(
                 [jnp.abs(cur - cand).astype(jnp.bfloat16)
                  for cand in cands], axis=0)              # (64 nx, 256)
-            sad = jnp.dot(stack, SS, preferred_element_type=jnp.float32)
+            return jnp.dot(stack, SS, preferred_element_type=jnp.float32)
 
-            # §8.4.2.2.2 bilinear, eighth-pel fracs (w & 3) * 2 (exact
-            # for frac 0: (64 * a + 32) >> 6 == a). ex is static per
-            # candidate, so a full-pel column drops its two zero taps.
-            ey = (wy & 3) * 2
-
+        def select_row(state, sad, cands, fracs, Cur, Cvr, ey, mvy, mvx_of):
+            """Walk a scored row in table order. `fracs` holds each
+            candidate's static chroma (ex, ox): eighth fraction and
+            whole-sample offset in x; ey and mvy are the row's (traced),
+            mvx_of(k) candidate k's."""
+            # §8.4.2.2.2 bilinear at eighth fractions (exact for frac 0:
+            # (64 * a + 32) >> 6 == a). ex is static per candidate, so a
+            # full-pel column drops its two zero taps.
             def chroma_row(C):
                 @functools.lru_cache(maxsize=None)
-                def win(dr, dl):        # shared by neighbouring wx
+                def win(dr, dl):        # shared by neighbouring candidates
                     return jax.lax.slice(
                         C, (_KPVC + dr, _PHC + dl),
                         (_KPVC + dr + 32, _PHC + dl + 128))
 
-                def cpred(wx):
-                    ex, ox = (wx & 3) * 2, wx >> 2
+                def cpred(ex, ox):
                     out = ((8 - ex) * (8 - ey) * win(0, ox)
                            + (8 - ex) * ey * win(1, ox) + 32)
                     if ex:
@@ -376,13 +471,12 @@ def _me_kernel(H: int, W: int):
 
             cpred_u, cpred_v = chroma_row(Cur), chroma_row(Cvr)
             bestc, bmy, bmx, py, bestcc, pu, pv = state
-            mvy = 2 * cy + wy
-            for k, (wx, cand) in enumerate(zip(wxs, cands)):
+            for k, (cand, frac) in enumerate(zip(cands, fracs)):
                 sad4a = jax.lax.slice(sad, (64 * k, 0), (64 * k + 64, 384)
                                       ).reshape(4, 16, 384).sum(1)
                 sad4 = jax.lax.slice(sad4a, (0, 0), (4, 256))
                 sad4c = jax.lax.slice(sad4a, (0, 256), (4, 384))
-                mvx = 2 * cx + wx
+                mvx = mvx_of(k)
                 pen = lam * (jnp.abs(mvy) + jnp.abs(mvx)
                              ).astype(jnp.float32)
                 cost = sad4 + pen
@@ -399,9 +493,25 @@ def _me_kernel(H: int, W: int):
                 bestcc = jnp.where(takec, costc, bestcc)
                 mc = jnp.broadcast_to(takec[:, None, :], (4, 8, 128)
                                       ).reshape(32, 128)
-                pu = jnp.where(mc, cpred_u(wx), pu)
-                pv = jnp.where(mc, cpred_v(wx), pv)
+                pu = jnp.where(mc, cpred_u(*frac), pu)
+                pv = jnp.where(mc, cpred_v(*frac), pv)
             return (bestc, bmy, bmx, py, bestcc, pu, pv)
+
+        def row_body(state, Pl, Cur, Cvr, wy, wxs, cy, cx):
+            """One ROW of candidates (fixed wy, every wx of `wxs`): Pl,
+            Cur, Cvr are the class plane and the chroma planes rolled to
+            this row, so candidate wx is the STATIC window of Pl at lane
+            _PH + (wx >> 1) (chroma: _PHC + (wx >> 2), eighth fraction
+            (wx & 3) * 2). wy, cy, cx traced; wxs static."""
+            cands = [
+                jax.lax.slice(Pl, (_KPV, _PH + (wx >> 1)),
+                              (_KPV + 64, _PH + (wx >> 1) + 256))
+                for wx in wxs]
+            sad = score_row(cands)
+            ey = (wy & 3) * 2
+            return select_row(
+                state, sad, cands, [((wx & 3) * 2, wx >> 2) for wx in wxs],
+                Cur, Cvr, ey, mv_of(cy, wy), lambda k: mv_of(cx, wxs[k]))
 
         def class_scan(plane, CUc, CVc, cy, cx, wys, wxs, state):
             """Walk one parity class's (wys x wxs) grid, a row of
@@ -428,7 +538,46 @@ def _me_kernel(H: int, W: int):
                 0, len(wys), outer, (Pl, Cur, Cvr, state))
             return state
 
-        def run_center(ci, classes, state):
+        def quarter_rows(planes, CUc, CVc, cy, cx, yf, qys, qxs, state):
+            """One yfrac of the quarter window (`_quarter_rows`): its
+            rows of candidates, qy a pixel further from row to row.
+            Each xfrac of the rows first gets a plane of its own — the
+            position's sample for every integer sample: §8.4.2.2.1's
+            rounded mean of the two planes `_quarter_pair` names, the
+            second moved a row or a lane where the pair says so — and a
+            candidate is then ONE static window of it, as a parity
+            class's is. The two or three rows are unrolled (static row
+            offsets, static chroma fractions): as a `fori_loop` over
+            planes rolled row by row, which is how the parity classes
+            walk their nine rows, they took 1.5 ms a 1080p frame longer
+            (PERF.md §6) — the carried planes cost more than so short a
+            loop saves."""
+            def moved(p, r, lane):
+                x = roll_rows(planes[p], -r) if r else planes[p]
+                return roll_lanes(x, -lane) if lane else x
+
+            xfs = sorted({qx & 3 for qx in qxs})
+            typed = [
+                jnp.floor((moved(*a) + moved(*b) + 1.0) * 0.5)
+                for (a, b) in (
+                    [((hy & 1) * 2 + (hx & 1), hy >> 1, hx >> 1)
+                     for (hy, hx) in _quarter_pair(yf, xf)] for xf in xfs)]
+            for qy in qys:
+                cands = [
+                    jax.lax.slice(
+                        typed[xfs.index(qx & 3)],
+                        (_KPV + (qy >> 2), _PH + (qx >> 2)),
+                        (_KPV + (qy >> 2) + 64, _PH + (qx >> 2) + 256))
+                    for qx in qxs]
+                state = select_row(
+                    state, score_row(cands), cands,
+                    [(qx & 7, qx >> 3) for qx in qxs],
+                    roll_rows(CUc, -(qy >> 3)) if qy >> 3 else CUc,
+                    roll_rows(CVc, -(qy >> 3)) if qy >> 3 else CVc,
+                    qy & 7, 4 * cy + qy, lambda k: 4 * cx + qxs[k])
+            return state
+
+        def run_center(ci, classes, state, rows):
             cy = cent_ref[0, 2 * ci]
             cx = cent_ref[0, 2 * ci + 1]
             # Interpolation planes built DIRECTLY over the 80 rows the
@@ -474,11 +623,13 @@ def _me_kernel(H: int, W: int):
                 plane = planes[par[0] * 2 + par[1]]
                 state = class_scan(plane, CUc, CVc, cy, cx, wys, wxs,
                                    state)
+            for (yf, qys, qxs) in rows or ():
+                state = quarter_rows(planes, CUc, CVc, cy, cx, yf, qys,
+                                     qxs, state)
             return state
 
-        state = run_center(0, CENTER_CLASSES, state)
-        state = run_center(1, CENTER_B_CLASSES, state)
-        state = run_center(2, ZERO_CLASSES, state)
+        for ci, (classes, rows) in enumerate(CENTERS[subpel]):
+            state = run_center(ci, classes, state, rows)
         bestc, bmy, bmx, py, bestcc, pu, pv = state
 
         mv_ref[0, 0, 0:4, :] = bmy
@@ -490,9 +641,10 @@ def _me_kernel(H: int, W: int):
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("H", "W", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("H", "W", "interpret", "subpel"))
 def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
-               W: int, interpret: bool):
+               W: int, interpret: bool, subpel: str = "half"):
     mbh, mbw, H4, RG, WcK, nch, W2K, WcuK, W2cK = _geom(H, W)
     vspec = lambda shape, imap: pl.BlockSpec(shape, imap,
                                              memory_space=pltpu.VMEM)
@@ -547,7 +699,7 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
     in_specs += list(out_specs)
     n_in = 27
     return pl.pallas_call(
-        _me_kernel(H, W),
+        _me_kernel(H, W, subpel),
         grid=(RG, nch),
         out_shape=out_shape,
         in_specs=in_specs,
@@ -563,15 +715,19 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
 # ---------------------------------------------------------------------------
 
 @stage("me_search")
-def me_search_xla(cur_y, ref_y, ref_u, ref_v, centers, lam):
-    """Pure-XLA mirror of the kernel: same OFFSET_TABLE, same strict-<
+def me_search_xla(cur_y, ref_y, ref_u, ref_v, centers, lam,
+                  subpel: str = "half"):
+    """Pure-XLA mirror of the kernel: same offset table, same strict-<
     selection, same interpolation — the executable spec the Pallas
     kernel is tested against, and the off-TPU path. Structured as a
     `fori_loop` over a device-side offset table (a fully unrolled graph
     compiles super-linearly on XLA CPU — measured minutes at 267
     offsets). cur_y int16 (H, W); ref planes int16; centers (3, 2)
-    int32 even-pel. Returns (mv (mbh, mbw, 2) int32 half-pel, pred_y,
-    pred_u, pred_v int16)."""
+    int32 even-pel. Returns (mv (mbh, mbw, 2) int32 in `subpel`'s
+    units, pred_y, pred_u, pred_v int16)."""
+    if subpel != "half":
+        return _me_search_xla_quarter(cur_y, ref_y, ref_u, ref_v, centers,
+                                      lam)
     H, W = cur_y.shape
     mbh, mbw = H // 16, W // 16
     cur = cur_y.astype(jnp.int32)
@@ -658,6 +814,98 @@ def me_search_xla(cur_y, ref_y, ref_u, ref_v, centers, lam):
             pv.astype(jnp.int16))
 
 
+def _me_search_xla_quarter(cur_y, ref_y, ref_u, ref_v, centers, lam):
+    """`me_search_xla` over offset_table("quarter"): every candidate, a
+    point of the half grid or not, is the rounded mean of the two
+    half-grid samples `_quarter_pair` names (a half-grid point pairs
+    with itself: (p + p + 1) >> 1 = p), its chroma §8.4.2.2.2's
+    bilinear at the eighth fractions qy & 7, qx & 7. Vectors in QUARTER
+    units. It would take the half table too (in quarter units); the
+    body above stays because the programs of subpel="half" are held to
+    their jaxprs of before the setting (tests/test_subpel.py)."""
+    H, W = cur_y.shape
+    mbh, mbw = H // 16, W // 16
+    h2, w2 = H // 2, W // 2
+    cur = cur_y.astype(jnp.int32)
+    ry = jnp.pad(ref_y, ((_PV, _PV), (_PH, _PH)),
+                 mode="edge").astype(jnp.int32)
+    ru = jnp.pad(ref_u, ((_PVC, _PVC + 8), (_PHC, _PHC + 8)),
+                 mode="edge").astype(jnp.int32)
+    rv = jnp.pad(ref_v, ((_PVC, _PVC + 8), (_PHC, _PHC + 8)),
+                 mode="edge").astype(jnp.int32)
+    roll_rows = lambda x, k: jnp.roll(x, k, axis=0)
+    roll_lanes = lambda x, k: jnp.roll(x, k, axis=1)
+
+    zero = (cur_y.reshape(-1)[0] * 0).astype(jnp.int32)
+    state = (jnp.full((mbh, mbw), 2**30, jnp.int32) + zero,
+             jnp.zeros((mbh, mbw), jnp.int32) + zero,
+             jnp.zeros((mbh, mbw), jnp.int32) + zero,
+             jnp.zeros((H, W), jnp.int32) + zero,
+             jnp.zeros((h2, w2), jnp.int32) + zero,
+             jnp.zeros((h2, w2), jnp.int32) + zero)
+
+    for ci in range(3):
+        cy, cx = centers[ci, 0], centers[ci, 1]
+        Rc = roll_lanes(roll_rows(ry, -cy), -cx)
+        planes = jnp.stack(_halfpel_planes(Rc, roll_rows, roll_lanes))
+        CUc = roll_lanes(roll_rows(ru, -(cy >> 1)), -(cx >> 1))
+        CVc = roll_lanes(roll_rows(rv, -(cy >> 1)), -(cx >> 1))
+        # per candidate: qy, qx, then (plane, row, lane) of its two samples
+        offs = jnp.asarray(
+            [(qy, qx) + tuple(
+                v for (hy, hx) in _quarter_pair(qy, qx)
+                for v in ((hy & 1) * 2 + (hx & 1), hy >> 1, hx >> 1))
+             for (c, qy, qx) in offset_table("quarter") if c == ci],
+            jnp.int32)
+
+        def body(i, state, planes=planes, CUc=CUc, CVc=CVc, offs=offs,
+                 cy=cy, cx=cx):
+            bestc, bmy, bmx, py, pu, pv = state
+            o = offs[i]
+            qy, qx = o[0], o[1]
+
+            def sample(k):
+                return jax.lax.dynamic_slice(
+                    planes, (o[k], _PV + o[k + 1], _PH + o[k + 2]),
+                    (1, H, W))[0]
+
+            cand = (sample(2) + sample(5) + 1) >> 1
+            sad = jnp.abs(cur - cand).reshape(mbh, 16, mbw, 16).sum((1, 3))
+            mvy = 4 * cy + qy
+            mvx = 4 * cx + qx
+            cost = sad + lam * (jnp.abs(mvy) + jnp.abs(mvx))
+            take = cost < bestc
+            bestc = jnp.where(take, cost, bestc)
+            bmy = jnp.where(take, mvy, bmy)
+            bmx = jnp.where(take, mvx, bmx)
+            tly = jnp.broadcast_to(take[:, None, :, None],
+                                   (mbh, 16, mbw, 16)).reshape(H, W)
+            py = jnp.where(tly, cand, py)
+            ey, ex = qy & 7, qx & 7
+            oy, ox = qy >> 3, qx >> 3
+
+            def cpred(C):
+                def tap(dy, dx):
+                    return jax.lax.dynamic_slice(
+                        C, (_PVC + oy + dy, _PHC + ox + dx), (h2, w2))
+                return ((8 - ex) * (8 - ey) * tap(0, 0)
+                        + ex * (8 - ey) * tap(0, 1)
+                        + (8 - ex) * ey * tap(1, 0)
+                        + ex * ey * tap(1, 1) + 32) >> 6
+
+            tlc = jnp.broadcast_to(take[:, None, :, None],
+                                   (mbh, 8, mbw, 8)).reshape(h2, w2)
+            pu = jnp.where(tlc, cpred(CUc), pu)
+            pv = jnp.where(tlc, cpred(CVc), pv)
+            return (bestc, bmy, bmx, py, pu, pv)
+
+        state = jax.lax.fori_loop(0, offs.shape[0], body, state)
+
+    _bestc, bmy, bmx, py, pu, pv = state
+    return (jnp.stack([bmy, bmx], axis=-1), py.astype(jnp.int16),
+            pu.astype(jnp.int16), pv.astype(jnp.int16))
+
+
 # ---------------------------------------------------------------------------
 # centers: coarse global-motion probe + carried median, both batched
 # ---------------------------------------------------------------------------
@@ -699,12 +947,19 @@ def hist_median(mv_flat, lim: int):
     return ((cum >= (n + 1) // 2).argmax(axis=0) - lim).astype(jnp.int32)
 
 
-def centers_from(cur16, ref16, pred_mv_h):
+def _median_center(pred_mv, per_pel: int):
+    """The previous frame's median MV (`per_pel` units a pixel) as the
+    nearest even pel, clamped."""
+    return jnp.clip((pred_mv + per_pel) >> (per_pel // 2 + 1),
+                    -(_CLIM // 2), _CLIM // 2) * 2
+
+
+def centers_from(cur16, ref16, pred_mv_h, per_pel: int = 2):
     """(3, 2) even-pel centers: probe, carried-median, zero.
-    pred_mv_h is the previous frame's median MV in half units."""
+    pred_mv_h is the previous frame's median MV, `per_pel` units a
+    pixel (2: half units)."""
     probe = coarse_probe(cur16, ref16)
-    med_pel = jnp.clip((pred_mv_h + 2) >> 2, -(_CLIM // 2),
-                       _CLIM // 2) * 2        # nearest even pel, clamped
+    med_pel = _median_center(pred_mv_h, per_pel)
     probe = jnp.clip(probe, -_CLIM, _CLIM)
     zero = jnp.zeros(2, jnp.int32) + (cur16.reshape(-1)[0] * 0).astype(
         jnp.int32)
@@ -745,7 +1000,7 @@ def motion_search() -> str | None:
 
 
 def me_search_pallas(cur_y16, ref_y16, ref_u16, ref_v16, centers, lam,
-                     interpret: bool = False):
+                     interpret: bool = False, subpel: str = "half"):
     """Kernel path: prep (pad + per-center dynamic slices — the kernel
     contains no dynamic shifts) + the Pallas call. `interpret=True`
     runs the kernel in the Pallas interpreter — the CPU parity test
@@ -771,7 +1026,8 @@ def me_search_pallas(cur_y16, ref_y16, ref_u16, ref_v16, centers, lam,
         ss = jnp.asarray(_ss_np(), jnp.bfloat16)
     with stage("me_search"):
         mvo, py, pu, pv = _me_pallas(cent, cur, refy, refu, refv, ss,
-                                     H=H, W=W, interpret=interpret)
+                                     H=H, W=W, interpret=interpret,
+                                     subpel=subpel)
         # (RG, nch, 8, 256): rows 0:4 = bmy, 4:8 = bmx, one per MB row
         # of the band; per-MB values sit at every 16th lane
         bmy = mvo[:, :, 0:4, ::16]                # (RG, nch, 4, 16)
@@ -950,7 +1206,7 @@ def probe_center_from_cost(cost, sr: int = SEARCH_RANGE):
 
 def banded_centers_from(cur16, ref16, pred_mv_h, real_rows,
                         halo_rows: int, axis_name, num_bands: int,
-                        probe=None):
+                        probe=None, per_pel: int = 2):
     """(3, 2) even-pel centers for one band's search: psum'd probe,
     carried global median, zero — the banded mirror of `centers_from`,
     with the vertical component additionally clamped to
@@ -962,8 +1218,7 @@ def banded_centers_from(cur16, ref16, pred_mv_h, real_rows,
         probe = banded_coarse_probe(cur16, ref16, real_rows, axis_name,
                                     num_bands)
     with stage("me_prep"):
-        med_pel = jnp.clip((pred_mv_h + 2) >> 2, -(_CLIM // 2),
-                           _CLIM // 2) * 2
+        med_pel = _median_center(pred_mv_h, per_pel)
         lims = jnp.asarray([min(halo_clamp(halo_rows), _CLIM), _CLIM],
                            jnp.int32)
         probe = jnp.clip(probe, -lims, lims)
@@ -1022,7 +1277,7 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
                      *, halo_rows: int, num_bands: int, axis_name,
                      real_rows, ext=None, edge_top: bool = True,
                      edge_bot: bool = True, probe=None,
-                     return_hist: bool = False):
+                     return_hist: bool = False, subpel: str = "half"):
     """Full ME+MC for one P frame of ONE BAND (the SFE search).
 
     cur/ref planes are this band's (Hb, W) shard (Hb a multiple of 16);
@@ -1046,9 +1301,11 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
     the per-MB (mv, pred) results are bit-identical to the full-mesh
     psum/ppermute program.
 
-    Returns (mv (Hb/16, mbw, 2) int32 half-pel, pred_y, pred_u, pred_v
-    int16 band planes, med_mv_h (2,) int32 — the GLOBAL median), or
-    with `return_hist` (mv, py, pu, pv, cnt, n)."""
+    Returns (mv (Hb/16, mbw, 2) int32 in `subpel`'s units, as
+    `pred_mv_h` is, pred_y, pred_u, pred_v int16 band planes, med_mv_h
+    (2,) int32 — the GLOBAL median), or with `return_hist` (mv, py, pu,
+    pv, cnt, n). The quarter window lies inside the integer one, so
+    the halo a band needs does not grow with `subpel`."""
     Hb, W = cur_y16.shape
     if halo_rows <= 0 or halo_rows % 16:
         raise ValueError("halo_rows must be a positive multiple of 16")
@@ -1069,16 +1326,15 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
         cur_ext = jnp.concatenate([
             jnp.broadcast_to(cur_y16[:1], (halo, W)), cur_y16,
             jnp.broadcast_to(cur_y16[Hb - 1:], (halo, W))])
+    per_pel = MV_PER_PEL[subpel]
     centers = banded_centers_from(cur_y16, ref_y16, pred_mv_h, real_rows,
-                                  halo, axis_name, num_bands, probe=probe)
+                                  halo, axis_name, num_bands, probe=probe,
+                                  per_pel=per_pel)
     with stage("me_prep"):
-        lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
-    if use_pallas():
-        mv_e, py_e, pu_e, pv_e = me_search_pallas(
-            cur_ext, ry_ext, ru_ext, rv_ext, centers, lam)
-    else:
-        mv_e, py_e, pu_e, pv_e = me_search_xla(
-            cur_ext, ry_ext, ru_ext, rv_ext, centers, lam)
+        lam = jnp.asarray(_LAMBDAS[subpel])[jnp.clip(qp, 0, 51)]
+    search = me_search_pallas if use_pallas() else me_search_xla
+    mv_e, py_e, pu_e, pv_e = search(
+        cur_ext, ry_ext, ru_ext, rv_ext, centers, lam, subpel=subpel)
     hm = halo // 16
     mbh_b = Hb // 16
     with stage("me_search"):
@@ -1093,28 +1349,29 @@ def me_search_banded(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
                              mv.shape[1])
         mv_flat = mv.reshape(-1, 2)
     if return_hist:
-        cnt, n = hist_counts_banded(mv_flat, mb_mask, 2 * SEARCH_RANGE,
+        cnt, n = hist_counts_banded(mv_flat, mb_mask,
+                                    per_pel * SEARCH_RANGE,
                                     axis_name, num_bands)
         return mv, py, pu, pv, cnt, n
-    med = hist_median_banded(mv_flat, mb_mask, 2 * SEARCH_RANGE,
+    med = hist_median_banded(mv_flat, mb_mask, per_pel * SEARCH_RANGE,
                              axis_name, num_bands)
     return mv, py, pu, pv, med
 
 
-def me_search(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp):
+def me_search(cur_y16, ref_y16, ref_u16, ref_v16, pred_mv_h, qp,
+              subpel: str = "half"):
     """Full ME+MC for one P frame. Inputs int16 planes (H, W multiples
-    of 16); pred_mv_h (2,) int32 half-pel (previous frame's median);
-    qp the frame's quantizer (drives the MV-cost lambda).
-    Returns (mv (mbh, mbw, 2) int32 half-pel, pred_y, pred_u, pred_v
-    int16, med_mv_h (2,) int32)."""
+    of 16); pred_mv_h (2,) int32 (previous frame's median, in
+    `subpel`'s units as every vector here); qp the frame's quantizer
+    (drives the MV-cost lambda).
+    Returns (mv (mbh, mbw, 2) int32, pred_y, pred_u, pred_v int16,
+    med_mv_h (2,) int32)."""
+    per_pel = MV_PER_PEL[subpel]
     with stage("me_prep"):
-        centers = centers_from(cur_y16, ref_y16, pred_mv_h)
-        lam = jnp.asarray(LAMBDA_H)[jnp.clip(qp, 0, 51)]
-    if use_pallas():
-        mv, pred_y, pred_u, pred_v = me_search_pallas(
-            cur_y16, ref_y16, ref_u16, ref_v16, centers, lam)
-    else:
-        mv, pred_y, pred_u, pred_v = me_search_xla(
-            cur_y16, ref_y16, ref_u16, ref_v16, centers, lam)
-    med = hist_median(mv.reshape(-1, 2), 2 * SEARCH_RANGE)
+        centers = centers_from(cur_y16, ref_y16, pred_mv_h, per_pel)
+        lam = jnp.asarray(_LAMBDAS[subpel])[jnp.clip(qp, 0, 51)]
+    search = me_search_pallas if use_pallas() else me_search_xla
+    mv, pred_y, pred_u, pred_v = search(
+        cur_y16, ref_y16, ref_u16, ref_v16, centers, lam, subpel=subpel)
+    med = hist_median(mv.reshape(-1, 2), per_pel * SEARCH_RANGE)
     return mv, pred_y, pred_u, pred_v, med

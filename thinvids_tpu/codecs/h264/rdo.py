@@ -23,6 +23,12 @@ specialization, never a traced branch:
   most visible) encode finer, busy MBs (where texture masks it)
   coarser, around the same average QP. P frames keep the slice QP
   (their mb_qp_delta would be unsignalable on skipped/uncoded MBs).
+- ``subpel``: motion-vector precision, "half" or "quarter". With
+  "quarter" the search also scores §8.4.2.2.1's quarter positions
+  round the temporal-median and the zero centre (jaxme.CENTERS) and
+  every vector between the search, the filter's bS test, the
+  packers and the P_Skip inference is in QUARTER-sample units
+  (`mv_per_pel` 4); with "half" in half-sample units (2).
 
 This module is deliberately jax-free: the pack sidecars and the host
 packers import it without initializing a device backend.
@@ -34,10 +40,14 @@ import dataclasses
 
 import numpy as np
 
+from ...core.config import SUBPELS, subpel_of
+
 #: AQ quantization of the strength knob: configs are static jit args,
 #: so the continuous setting is snapped to 1/AQ_QUANT steps to bound
 #: the number of distinct compiled programs.
 AQ_QUANT = 4
+#: units of a motion vector per integer sample, by `subpel`
+MV_PER_PEL = dict(zip(SUBPELS, (2, 4)))
 #: AQ per-MB offset clamp (QP steps either side of the frame QP).
 AQ_MAX_DELTA = 6
 #: P_Skip bias: an inter MB whose quantized levels sum to <= this (in
@@ -58,6 +68,19 @@ class RdConfig:
     #: aq strength in 1/AQ_QUANT QP units (0 = off); use from_settings
     #: or aq_from_strength to build from the float knob
     aq_q: int = 0
+    #: motion-vector precision, one of SUBPELS
+    subpel: str = "half"
+
+    def __post_init__(self) -> None:
+        if self.subpel not in SUBPELS:
+            raise ValueError(
+                f"subpel must be one of {SUBPELS}, not {self.subpel!r}")
+
+    @property
+    def mv_per_pel(self) -> int:
+        """Units of this encode's motion vectors per integer sample;
+        mvd is coded in quarter samples, 4 // mv_per_pel to a unit."""
+        return MV_PER_PEL[self.subpel]
 
     @property
     def aq_strength(self) -> float:
@@ -85,7 +108,7 @@ def aq_from_strength(strength: float) -> int:
 
 
 def rd_from_settings(settings) -> RdConfig:
-    """Build the static RD config from a Settings snapshot (the four
+    """Build the static RD config from a Settings snapshot (the five
     knobs registered in core/config.DEFAULT_SETTINGS)."""
     from ...core.config import as_bool, as_float
 
@@ -95,6 +118,7 @@ def rd_from_settings(settings) -> RdConfig:
         deblock=as_bool(settings.get("deblock", False), False),
         aq_q=aq_from_strength(as_float(settings.get("aq_strength", 0.0),
                                        0.0)),
+        subpel=subpel_of(settings),
     )
 
 

@@ -80,10 +80,15 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     # aq_strength (TVT_AQ_STRENGTH, 0..3): perceptual variance-AQ
     #   per-MB QP modulation on intra frames (0 = off; quantized to
     #   quarter steps — the config is a compile-time specialization).
+    # subpel (TVT_SUBPEL, "half" | "quarter"): motion-vector
+    #   precision. "quarter" adds §8.4.2.2.1's quarter positions to the
+    #   search (379 candidates a macroblock for 227) and codes vectors
+    #   in quarter samples; one more executable per resolution.
     "mode_decision": False,
     "pskip": False,
     "deblock": False,
     "aq_strength": 0.0,
+    "subpel": "half",
     # ABR ladder subsystem (abr/): default job type for registrations
     # that don't say (watch-folder drops named *.ladder.* always become
     # ladder jobs), the rung heights (TVT_LADDER_RUNGS; heights at or
@@ -309,6 +314,19 @@ def _coerce_like(default: Any, raw: Any) -> Any:
     return str(raw)
 
 
+#: the values of the `subpel` setting (codecs/h264/rdo.RdConfig.subpel)
+SUBPELS = ("half", "quarter")
+
+
+def subpel_of(settings: Mapping[str, Any]) -> str:
+    """The `subpel` of a settings snapshot or a job's overrides,
+    normalised and NOT clamped: RdConfig refuses what is not one of
+    SUBPELS — an environment's typo fails the job, it does not encode
+    at half. (POST /settings and the per-job overlay clamp to the
+    default, _CLAMPS, and their answer says which value was applied.)"""
+    return str(settings.get("subpel", "half")).strip().lower()
+
+
 def _clean_rung_spec(raw: Any) -> str:
     """Normalize a ladder_rungs value via the canonical parser."""
     from ..abr.ladder import parse_rung_heights
@@ -328,6 +346,8 @@ _CLAMPS: dict[str, Callable[[Any], Any]] = {
     # cap mirrors rdo.aq_from_strength's 3.0 ceiling (clamped offsets
     # saturate at ±AQ_MAX_DELTA well before that)
     "aq_strength": lambda v: min(3.0, max(0.0, as_float(v, 0.0))),
+    "subpel": lambda v: (s if (s := subpel_of({"subpel": v})) in SUBPELS
+                         else "half"),
     "gop_frames": lambda v: min(600, max(1, as_int(v, 32))),
     "scenecut": lambda v: min(100, max(0, as_int(v, 0))),
     "max_segments": lambda v: min(4096, max(1, as_int(v, 200))),
@@ -554,7 +574,12 @@ JOB_SETTING_KEYS = frozenset(
      "live_part_budget_s", "sfe_bands", "sfe_halo_rows", "tenant",
      # per-job RD operating point: a per-title encode may flip the
      # compression-efficiency features without touching the cluster
-     "mode_decision", "pskip", "deblock", "aq_strength"}
+     "mode_decision", "pskip", "deblock", "aq_strength",
+     # a job may STATE the precision, it cannot change it: an encoder
+     # reads its RdConfig from the daemon's settings, so admission
+     # refuses a value other than the daemon's (cluster/policy.py) —
+     # left out of these keys it would be dropped without a word
+     "subpel"}
 )
 
 

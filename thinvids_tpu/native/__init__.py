@@ -172,6 +172,7 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_void_p,                            # luma16
             ctypes.c_void_p, ctypes.c_void_p,           # chroma dc/ac
             ctypes.c_int32, ctypes.c_int32,             # mbw, mbh
+            ctypes.c_int32,                             # mvd_scale
             ctypes.c_void_p, ctypes.c_int64,            # out, cap
         ]
         lib.cavlc_init_scan.argtypes = [ctypes.c_void_p]
@@ -183,6 +184,7 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_void_p, ctypes.c_void_p,           # u/v DC int16
             ctypes.c_void_p, ctypes.c_void_p,           # u/v AC planes int16
             ctypes.c_int32, ctypes.c_int32,             # mbw, mbh
+            ctypes.c_int32,                             # mvd_scale
             ctypes.c_void_p, ctypes.c_int64,            # out, cap
         ]
         arrs = _marshal_tables()
@@ -270,10 +272,12 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
                       mv8: np.ndarray, luma_plane: np.ndarray,
                       u_dc: np.ndarray, v_dc: np.ndarray,
                       u_ac: np.ndarray, v_ac: np.ndarray,
-                      mbw: int, mbh: int) -> bytes:
+                      mbw: int, mbh: int, mvd_scale: int = 2) -> bytes:
     """Pack one P-slice straight from plane-layout int16 level arrays
     (zigzag/z-scan happens inside the C++ via the shared scan table) —
-    bit-identical to pack_pslice on the equivalent blocked arrays."""
+    bit-identical to pack_pslice on the equivalent blocked arrays.
+    `mvd_scale`: quarter samples to one unit of mv8 (2: half-sample
+    vectors, 1: quarter-sample vectors)."""
     lib = _build_and_load()
     nmb = mbw * mbh
 
@@ -298,7 +302,7 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
         mv8.ctypes.data, luma_plane.ctypes.data,
         u_dc.ctypes.data, v_dc.ctypes.data,
         u_ac.ctypes.data, v_ac.ctypes.data,
-        mbw, mbh, out.ctypes.data, cap)
+        mbw, mbh, mvd_scale, out.ctypes.data, cap)
     if n == -2:
         raise RuntimeError("native packer output buffer overflow")
     if n == -3:
@@ -310,9 +314,11 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
 
 def pack_pslice(header_bytes: bytes, header_bit_len: int, mv: np.ndarray,
                 luma16: np.ndarray, chroma_dc: np.ndarray,
-                chroma_ac: np.ndarray, mbw: int, mbh: int) -> bytes:
+                chroma_ac: np.ndarray, mbw: int, mbh: int,
+                mvd_scale: int = 2) -> bytes:
     """Pack one P-slice (header bits + MB layer) and return the EBSP
-    payload. Mirrors codecs/h264/inter.pack_p_slice bit-for-bit."""
+    payload. Mirrors codecs/h264/inter.pack_p_slice bit-for-bit;
+    `mvd_scale` as in :func:`pack_pslice_plane`."""
     lib = _build_and_load()
     nmb = mbw * mbh
 
@@ -334,7 +340,7 @@ def pack_pslice(header_bytes: bytes, header_bit_len: int, mv: np.ndarray,
         hdr.ctypes.data, header_bit_len,
         mv.ctypes.data, luma16.ctypes.data,
         chroma_dc.ctypes.data, chroma_ac.ctypes.data,
-        mbw, mbh, out.ctypes.data, cap)
+        mbw, mbh, mvd_scale, out.ctypes.data, cap)
     if n == -2:
         raise RuntimeError("native packer output buffer overflow")
     if n == -3:

@@ -512,17 +512,22 @@ static void compute_mv_pred(const int32_t* mv, int mbw, int mbh,
   }
 }
 
-// Packs one P picture (all-inter, P_L0_16x16 / P_Skip, single reference,
-// half-pel MVs). mv: nmb*2 as (dy, dx); luma16: nmb*16*16 z-scan blocks
-// of 16 zig-zag coeffs. Mirrors codecs/h264/inter.pack_p_slice bit-for-bit.
+// Packs one P picture (all-inter, P_L0_16x16 / P_Skip, single reference).
+// mv: nmb*2 as (dy, dx), in units of which mvd_scale make a quarter sample
+// (2: half-sample vectors, 1: quarter-sample vectors); luma16: nmb*16*16
+// z-scan blocks of 16 zig-zag coeffs. Mirrors
+// codecs/h264/inter.pack_p_slice bit-for-bit.
 int64_t cavlc_pack_pslice(
     const uint8_t* header_bytes, int32_t header_bit_len,
     const int32_t* mv,
     const int32_t* luma16,
     const int32_t* chroma_dc,
     const int32_t* chroma_ac,
-    int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap) {
-  if (!g_tables_ready || !g_inter_ready || mbw <= 0 || mbh <= 0) return -1;
+    int32_t mbw, int32_t mbh, int32_t mvd_scale,
+    uint8_t* out, int64_t out_cap) {
+  if (!g_tables_ready || !g_inter_ready || mbw <= 0 || mbh <= 0
+      || (mvd_scale != 1 && mvd_scale != 2))
+    return -1;
   static const int BX[16] = {0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3};
   static const int BY[16] = {0, 0, 1, 1, 0, 0, 1, 1, 2, 2, 3, 3, 2, 2, 3, 3};
   static const int CBX[4] = {0, 1, 0, 1};
@@ -579,10 +584,10 @@ int64_t cavlc_pack_pslice(
       bw.ue(skip_run);
       skip_run = 0;
       bw.ue(0);   // mb_type = P_L0_16x16
-      // mvd: horizontal first (§7.3.5.1); layout is (dy, dx). mv is in
-      // half-pel units, mvd is coded in quarter-pel units.
-      bw.se(2 * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
-      bw.se(2 * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
+      // mvd: horizontal first (§7.3.5.1); layout is (dy, dx). mvd is
+      // coded in quarter-sample units, mvd_scale to one of mv's.
+      bw.se(mvd_scale * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
+      bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
       bw.ue((uint32_t)g_cbp_inter[cbp]);
       if (cbp) bw.se(0);   // mb_qp_delta
 
@@ -640,7 +645,8 @@ void cavlc_init_scan_impl(const int32_t* zz) {
   g_scan_ready = true;
 }
 
-// Packs one P picture from plane-layout levels. mv: nmb*2 int8 (dy, dx);
+// Packs one P picture from plane-layout levels. mv: nmb*2 int8 (dy, dx),
+// mvd_scale as in cavlc_pack_pslice;
 // luma_plane: (16*mbh)x(16*mbw) int16; u_dc/v_dc: nmb*4 int16 (hadamard
 // domain); u_ac/v_ac: (8*mbh)x(8*mbw) int16 with DC positions zero.
 // Bit-identical to cavlc_pack_pslice on the equivalent blocked arrays.
@@ -650,9 +656,10 @@ int64_t cavlc_pack_pslice_plane_impl(
     const int16_t* luma_plane,
     const int16_t* u_dc, const int16_t* v_dc,
     const int16_t* u_ac, const int16_t* v_ac,
-    int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap) {
+    int32_t mbw, int32_t mbh, int32_t mvd_scale,
+    uint8_t* out, int64_t out_cap) {
   if (!g_tables_ready || !g_inter_ready || !g_scan_ready
-      || mbw <= 0 || mbh <= 0)
+      || mbw <= 0 || mbh <= 0 || (mvd_scale != 1 && mvd_scale != 2))
     return -1;
   static const int BX[16] = {0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3};
   static const int BY[16] = {0, 0, 1, 1, 0, 0, 1, 1, 2, 2, 3, 3, 2, 2, 3, 3};
@@ -743,9 +750,9 @@ int64_t cavlc_pack_pslice_plane_impl(
       bw.ue(skip_run);
       skip_run = 0;
       bw.ue(0);   // mb_type = P_L0_16x16
-      // mv half-pel -> mvd quarter-pel (see above).
-      bw.se(2 * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
-      bw.se(2 * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
+      // mv units -> mvd quarter samples (see above).
+      bw.se(mvd_scale * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
+      bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
       bw.ue((uint32_t)g_cbp_inter[cbp]);
       if (cbp) bw.se(0);   // mb_qp_delta
 
@@ -794,10 +801,11 @@ int64_t cavlc_pack_pslice_plane(
     const int16_t* luma_plane,
     const int16_t* u_dc, const int16_t* v_dc,
     const int16_t* u_ac, const int16_t* v_ac,
-    int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap) {
+    int32_t mbw, int32_t mbh, int32_t mvd_scale,
+    uint8_t* out, int64_t out_cap) {
   return cavlc_pack_pslice_plane_impl(
       header_bytes, header_bit_len, mv8, luma_plane, u_dc, v_dc, u_ac,
-      v_ac, mbw, mbh, out, out_cap);
+      v_ac, mbw, mbh, mvd_scale, out, out_cap);
 }
 
 }  // extern "C"

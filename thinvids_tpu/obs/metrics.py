@@ -349,6 +349,20 @@ STAGE_COUNTER_TOTALS = {
         "GOP / step executables this process set up (first calls: "
         "compiled or loaded from the compile cache); their seconds are "
         "tvt_stage_seconds_total{stage=\"program_build\"}"),
+    "mvs_coded": REGISTRY.counter(
+        "tvt_mvs_coded_total",
+        "motion vectors of P macroblocks handed to the packers"),
+    "mvs_quarter": REGISTRY.counter(
+        "tvt_mvs_quarter_total",
+        "of those, vectors with an odd quarter-sample component "
+        "(subpel=quarter alone can have any)"),
+}
+STAGE_GAUGES = {
+    "me_candidates": REGISTRY.gauge(
+        "tvt_me_candidates",
+        "candidates the motion search of the last GOP / step program "
+        "called scores per macroblock (227 at subpel=half, 379 at "
+        "quarter)"),
 }
 
 # -- origin serving (origin/serve.OriginStats + origin/cache) ----------

@@ -154,13 +154,21 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 #: lower bound: the device counts values in the blocks it kept),
 #: scene_cuts / scene_cuts_suppressed (cuts that began a GOP / came too
 #: soon after one), wave_frames / pad_frames / pad_frames_skipped (GOP
-#: waves' frames staged / repeats among them / repeats never encoded)
+#: waves' frames staged / repeats among them / repeats never encoded),
+#: mvs_coded / mvs_quarter (P macroblocks' vectors handed to the
+#: packers / those of them with an odd quarter-sample component: 0
+#: under subpel="half"; count_vectors)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "d2h_bytes",
                   "fetch_shards", "proc_pack_gops", "sfe_frames",
                   "sparse_blocks_used", "sparse_blocks_budget",
                   "sparse_values_used", "sparse_values_budget",
                   "scene_cuts", "scene_cuts_suppressed", "wave_frames",
-                  "pad_frames", "pad_frames_skipped")
+                  "pad_frames", "pad_frames_skipped", "mvs_coded",
+                  "mvs_quarter")
+#: last-value readings riding in the same snapshot: me_candidates (what
+#: the motion search of the last GOP / step program called scores per
+#: macroblock: `program_build`; 0 until one ran)
+STAGE_GAUGES = tuple(obs_metrics.STAGE_GAUGES)
 
 
 class StageProfile:
@@ -177,7 +185,7 @@ class StageProfile:
                  metrics: bool = False) -> None:
         self._lock = threading.Lock()
         self._ms = {k: 0.0 for k in STAGE_NAMES}
-        self._counts = {k: 0 for k in STAGE_COUNTERS}
+        self._counts = {k: 0 for k in STAGE_COUNTERS + STAGE_GAUGES}
         self._waves = 0
         self._mirror = mirror
         #: bridge into the obs/ metrics registry — set ONLY on the
@@ -208,6 +216,13 @@ class StageProfile:
             obs_metrics.STAGE_SECONDS.labels(stage).inc(seconds)
         if self._mirror is not None:
             self._mirror.add(stage, seconds)
+
+    def gauge(self, name: str, value: int) -> None:
+        """Set a last-value reading (STAGE_GAUGES) of this snapshot."""
+        with self._lock:
+            self._counts[name] = int(value)
+        if self._metrics:
+            obs_metrics.STAGE_GAUGES[name].set(value)
 
     def bump(self, counter: str, n: int = 1) -> None:
         """Increment a monotonic counter (STAGE_COUNTERS) by `n`."""
@@ -1374,6 +1389,7 @@ class GopShardEncoder:
         for gi, gop in enumerate(wave):
             gop_qp = int(qps_host[gi])
             if self.inter:
+                count_vectors(prof, mv8[gi][:gop.num_frames - 1], self.rd)
                 if proc is not None:
                     jobs.append((gop, self._submit_process_pack(
                         proc, mv8[gi], dc16[gi], payload_rows[gi],
@@ -2128,12 +2144,14 @@ class SfeShardEncoder(GopShardEncoder):
             rest, mv8_b, 2, mbw, bp.band_mb_rows)
         rr = band.mb_rows * 16
         n_real = band.mb_rows * mbw
+        count_vectors(self.stages, mv[:n_real], self.rd)
         return inter_mod.pack_p_slice_plane(
             mv[:n_real], lp[0][:rr], udc[0][:n_real], vdc[0][:n_real],
             uac[0][:rr // 2], vac[0][:rr // 2], mbw, band.mb_rows,
             self.sps, self.pps, qp, frame_num=frame_num,
             first_mb=band.start_mb_row * mbw,
-            deblock_idc=self._deblock_idc)
+            deblock_idc=self._deblock_idc,
+            mv_per_pel=self.rd.mv_per_pel)
 
     def _gather_frame(self, thunks: list) -> list[bytes]:
         pool = self._slice_pool()
@@ -2325,7 +2343,8 @@ def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
                        shape: str | None = None, rungs=None,
                        qp: int | None = None, total_bands: int = 0,
                        band_range: tuple[int, int] | None = None,
-                       halo_rows: int | None = None, session=None):
+                       halo_rows: int | None = None, session=None,
+                       rd: RdConfig | None = None):
     """The ONE plan-driven shard-executor seam: every encode path —
     local executor, remote worker, live pipeline — resolves its
     encoder here, keyed off the unified plan shape
@@ -2336,7 +2355,9 @@ def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
     else GOP waves); `rungs` selects the ladder form (which stages
     once and fans renditions); `band_range`/`total_bands` select the
     cross-host band-slice form (parallel/sfefarm.py) with `session`
-    carrying the halo exchange."""
+    carrying the halo exchange. `rd` None: each encoder reads the
+    process's live settings (rd_from_settings); a remote worker passes
+    the one its shard was planned with."""
     qp = int(settings.qp) if qp is None else int(qp)
     gop_frames = int(settings.gop_frames)
     max_segments = int(settings.max_segments)
@@ -2345,7 +2366,7 @@ def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
 
         return LadderShardEncoder(meta, list(rungs), mesh=mesh,
                                   gop_frames=gop_frames,
-                                  max_segments=max_segments)
+                                  max_segments=max_segments, rd=rd)
     if shape is None:
         shape = "band" if int(settings.get("sfe_bands", 0) or 0) > 0 \
             else "gop"
@@ -2359,17 +2380,17 @@ def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
                 meta, qp=qp, mesh=mesh, gop_frames=gop_frames,
                 max_segments=max_segments, total_bands=total_bands,
                 band_range=band_range, halo_rows=halo_rows,
-                session=session)
+                session=session, rd=rd)
         return SfeShardEncoder(
             meta, qp=qp, mesh=mesh, gop_frames=gop_frames,
             max_segments=max_segments,
             bands=int(settings.get("sfe_bands", 0) or 0),
-            halo_rows=halo_rows)
+            halo_rows=halo_rows, rd=rd)
     if shape != "gop":
         raise ValueError(f"unknown shard shape {shape!r}")
     return GopShardEncoder(meta, qp=qp, mesh=mesh,
                            gop_frames=gop_frames,
-                           max_segments=max_segments)
+                           max_segments=max_segments, rd=rd)
 
 
 def encode_clip_sharded(frames: list[Frame], meta: VideoMeta, qp: int = 27,
@@ -2427,6 +2448,21 @@ _PROGRAMS_BUILT: set = set()
 _PROGRAMS_LOCK = threading.Lock()
 
 
+#: the forms of `program_build` that search motion
+_P_FORMS = ("scan", "bounded", "sfe_p")
+
+
+def count_vectors(profile: StageProfile, mv, rd) -> None:
+    """Counters `mvs_coded` / `mvs_quarter` for the (..., 2) vectors of
+    P macroblocks on their way to the packers: how many, and how many
+    of them have an odd quarter-sample component (none can under
+    subpel="half", whose units are half samples)."""
+    profile.bump("mvs_coded", mv.size // 2)
+    if rd.mv_per_pel == 4:
+        profile.bump("mvs_quarter",
+                     int(np.count_nonzero((np.asarray(mv) & 1).any(-1))))
+
+
 @contextlib.contextmanager
 def program_build(form: str, rd, shape, *more):
     """Round one call of a step program from OUTSIDE its jit: the first
@@ -2434,7 +2470,15 @@ def program_build(form: str, rd, shape, *more):
     (`program_build`; `tvt:program_build` in a live device profile),
     counted and named once in the log. `form` is the executable's kind
     (`scan` | `bounded` for a GOP program by its P-frame loop, `intra`,
-    `sfe_intra`, `sfe_p`, `words`), `shape` its leading operand's."""
+    `sfe_intra`, `sfe_p`, `words`), `shape` its leading operand's.
+    Every call of a form that searches motion also sets the gauge
+    `me_candidates`: what that executable scores per macroblock."""
+    from ..codecs.h264 import jaxme
+
+    candidates = len(jaxme.offset_table(rd.subpel)) \
+        if form in _P_FORMS else None
+    if candidates is not None:
+        _TOTALS.gauge("me_candidates", candidates)
     key = (form, rd, tuple(shape), *more)
     with _PROGRAMS_LOCK:
         first = key not in _PROGRAMS_BUILT
@@ -2446,6 +2490,7 @@ def program_build(form: str, rd, shape, *more):
     with _TOTALS.stage("program_build"):
         yield
     _TOTALS.bump("programs_built")
-    _LOG.info("program built: form=%s rd=%s shape=%s %s in %.2f s "
-              "(trace, lower, compile or cache load, enqueue)", form, rd,
-              tuple(shape), more, time.perf_counter() - t0)
+    _LOG.info("program built: form=%s rd=%s shape=%s %s me_candidates=%s "
+              "in %.2f s (trace, lower, compile or cache load, enqueue)",
+              form, rd, tuple(shape), more, candidates,
+              time.perf_counter() - t0)

@@ -57,7 +57,7 @@ class FarmBandEncoder(SfeShardEncoder):
                  max_segments: int = 200, total_bands: int = 0,
                  band_range: tuple[int, int] | None = None,
                  halo_rows: int | None = None, session=None,
-                 pack_workers: int | None = None):
+                 pack_workers: int | None = None, rd=None):
         super().__init__(meta, qp=qp, mesh=mesh, gop_frames=gop_frames,
                          max_segments=max_segments, halo_rows=halo_rows,
                          pack_workers=pack_workers,
@@ -65,7 +65,8 @@ class FarmBandEncoder(SfeShardEncoder):
                          # frames anyway, and window 1 bounds retained
                          # staged GOPs on worker hosts
                          pipeline_window=1,
-                         total_bands=total_bands, band_range=band_range)
+                         total_bands=total_bands, band_range=band_range,
+                         rd=rd)
         #: cluster/halo.HaloSession (or None for a single-group layout
         #: covering the whole frame — no peers to talk to)
         self.session = session
@@ -167,7 +168,8 @@ class FarmBandEncoder(SfeShardEncoder):
                 cnt = (cnt + np.asarray(h["cnt"], np.int32)) \
                     .astype(np.int32)
                 n += int(np.asarray(h["n"]).reshape(-1)[0])
-        return jaxme.median_from_counts(cnt, n, 2 * jaxme.SEARCH_RANGE)
+        return jaxme.median_from_counts(
+            cnt, n, self.rd.mv_per_pel * jaxme.SEARCH_RANGE)
 
     # -- the lockstep GOP walk -----------------------------------------
 

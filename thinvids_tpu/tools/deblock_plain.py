@@ -99,14 +99,15 @@ def _filter_samples(p, q, bs, qp_p, qp_q, chroma):
 
 
 def deblock_picture_plain(y, u, v, qp_map, *, intra, nz4=None, mv=None,
-                          slice_of_mb_row=None):
+                          slice_of_mb_row=None, mv_per_pel=2):
     """Filter one picture as §8.7 orders it.
 
     y (16·mbh, 16·mbw), u, v (8·mbh, 8·mbw): the constructed samples;
     qp_map (mbh, mbw): QP_Y of every macroblock; `intra`: all
     macroblocks intra (else all inter, one reference); nz4 (4·mbh,
     4·mbw): the 4x4 luma block holds non-zero transform coefficients;
-    mv (mbh, mbw, 2): motion vectors in half-sample units.
+    mv (mbh, mbw, 2): motion vectors, `mv_per_pel` units to an integer
+    sample (2: half-sample units, 4: quarter-sample units).
     `slice_of_mb_row`: None = disable_deblocking_filter_idc 0; else a
     sequence giving the slice of every macroblock row, for idc 2 (edges
     between slices are left alone). Returns new (y, u, v)."""
@@ -130,7 +131,8 @@ def deblock_picture_plain(y, u, v, qp_map, *, intra, nz4=None, mv=None,
             return 2
         mv_q, mv_p = mv[gy // 4, gx // 4], mv[py // 4, px // 4]
         # >= 4 in quarter samples = >= 2 in half samples
-        if abs(mv_q[0] - mv_p[0]) >= 2 or abs(mv_q[1] - mv_p[1]) >= 2:
+        if (abs(mv_q[0] - mv_p[0]) >= mv_per_pel
+                or abs(mv_q[1] - mv_p[1]) >= mv_per_pel):
             return 1
         return 0
 
