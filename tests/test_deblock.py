@@ -268,6 +268,47 @@ def test_levels_rewording_compiles_for_the_chip_at_1080p(L, monkeypatch):
         assert 'op_name="jit(_levels_as_words)/tvt.pack/' in line, line
 
 
+@pytest.mark.parametrize("shape", [(1088, 1920), (2176, 3840), (544, 3840),
+                                   (480, 864)])
+def test_probe_box_sums_compile_for_the_chip_without_a_relayout(
+        shape, monkeypatch):
+    """ISSUE 42, kept in this file because one test file may describe
+    the TPU topology: the global-motion probe's 4x4 box sums compile
+    for a described v5e at the served plane shapes (1080p, 2160p, a
+    2160p band, a ladder rung whose width is no multiple of 128) with
+    temporaries of a few planes at most. The view they replaced,
+    `x.reshape(H // 4, 4, W // 4, 4).sum((1, 3))`, is laid out with
+    its minor dimension of 4 on 128 lanes: 267 MB of temporaries for
+    one 4 MB 1080p plane, 1.47 ms of every frame (PERF.md §5)."""
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from thinvids_tpu.codecs.h264 import jaxme
+
+    monkeypatch.setitem(os.environ, "TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:          # no TPU compiler in this image
+        pytest.skip(f"no v5e topology can be described here: {exc}")
+    plane = jax.ShapeDtypeStruct(
+        shape, jnp.int16, sharding=SingleDeviceSharding(topo.devices[0]))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = jax.jit(lambda x: jaxme._box_sum(x, 4)).lower(
+            plane).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    memory = compiled.memory_analysis()
+    H, W = shape
+    assert memory.output_size_in_bytes >= (H // 4) * (W // 4) * 4
+    assert memory.temp_size_in_bytes <= 8 * 2 * H * W
+
+
 class TestBandSplit:
     """A split-frame band is a slice with disable_deblocking_filter_idc
     2: it filters its own rows, and no edge between two bands."""

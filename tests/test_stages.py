@@ -354,16 +354,11 @@ def _equations(jaxpr, outer=()):
                     yield from _equations(inner, path)
 
 
-@pytest.mark.parametrize("case", sorted(RESIDUAL))
-def test_the_residual_keeps_the_planes_tiling(case):
-    """ISSUE 40: under `tvt.residual` no array made from a plane has a
-    minor dimension under 8 (a TPU lays the minor dimension on 128
-    lanes: the (H, W // 4, 4) views of the old butterflies were a
-    relayout at 32 times the bytes, 9.5 of a 1080p frame's 18.9 ms),
-    nothing is sliced with a lane stride, and a gather moves whole
-    rows. Only per-MB maps (at most 16 entries a macroblock: `nz4`, the
-    chroma DC levels) and the (4, 4) quant tables are smaller than
-    that, so the copies cannot come back unseen."""
+def _tiling_breaches(case, stage_name):
+    """(equations seen, arrays with a minor dimension under 8, slices
+    with a lane stride or gathers of part-rows) under one stage of a
+    traced program. Only per-MB maps (at most 16 entries a
+    macroblock) may be narrower than 8."""
     traced = RESIDUAL[case]()
     args, kwargs = _sfe(True) if case.startswith("sfe") else _gop_args()
     shape = args[0].shape
@@ -371,7 +366,7 @@ def test_the_residual_keeps_the_planes_tiling(case):
     seen, narrow, strided = 0, [], []
     for path, eqn in _equations(traced.jaxpr.jaxpr):
         scopes = [part for part in path if part.startswith(PREFIX)]
-        if not scopes or scopes[-1] != PREFIX + "residual":
+        if not scopes or scopes[-1] != PREFIX + stage_name:
             continue
         seen += 1
         name = eqn.primitive.name
@@ -387,7 +382,36 @@ def test_the_residual_keeps_the_planes_tiling(case):
                 and eqn.params["slice_sizes"][-1] != operand.shape[-1]:
             strided.append((name, operand.shape,
                             eqn.params["slice_sizes"]))
+    return seen, narrow, strided
+
+
+@pytest.mark.parametrize("case", sorted(RESIDUAL))
+def test_the_residual_keeps_the_planes_tiling(case):
+    """ISSUE 40: under `tvt.residual` no array made from a plane has a
+    minor dimension under 8 (a TPU lays the minor dimension on 128
+    lanes: the (H, W // 4, 4) views of the old butterflies were a
+    relayout at 32 times the bytes, 9.5 of a 1080p frame's 18.9 ms),
+    nothing is sliced with a lane stride, and a gather moves whole
+    rows. Only per-MB maps (at most 16 entries a macroblock: `nz4`, the
+    chroma DC levels) and the (4, 4) quant tables are smaller than
+    that, so the copies cannot come back unseen."""
+    seen, narrow, strided = _tiling_breaches(case, "residual")
     assert seen > 300, "the residual stage was not read"
+    assert not narrow, sorted(set(narrow))[:10]
+    assert not strided, strided[:10]
+
+
+@pytest.mark.parametrize("case", sorted(RESIDUAL))
+def test_the_probe_keeps_the_planes_tiling(case):
+    """ISSUE 42: the same rule under `tvt.me_prep`. The global-motion
+    probe's 4x4 box sums viewed the current and the reference plane as
+    (H // 4, 4, W // 4, 4) — 1.47 of a 1080p frame's 9.8 ms in two
+    `reshape` and two `reduce_sum`; rows are now added by row-strided
+    slices and lanes pooled by a 0/1 matrix on 128-lane pieces, in the
+    GOP programs' `coarse_probe` and in the split-frame step's
+    `banded_probe_cost` alike."""
+    seen, narrow, strided = _tiling_breaches(case, "me_prep")
+    assert seen > 100, "the ME prep stage was not read"
     assert not narrow, sorted(set(narrow))[:10]
     assert not strided, strided[:10]
 
