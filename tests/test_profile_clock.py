@@ -33,8 +33,8 @@ from thinvids_tpu.tools.pan import make_frames
 #: parent of this PR: `benchmark/tvtbench/evidence.pipeline_extent`
 #: takes min and max over ALL of them, so a span outside the wave
 #: pipeline would turn `job_fixed_ms` and `mux_ms_per_job` to ~0
-PIPELINE_SPANS = {"decode", "stage", "dispatch", "device_wait", "fetch",
-                  "sparse_unpack", "unflatten", "pack", "concat",
+PIPELINE_SPANS = {"decode", "stage", "upload", "dispatch", "device_wait",
+                  "fetch", "sparse_unpack", "unflatten", "pack", "concat",
                   "wave_dispatch", "wave_collect", "wave_fetch_start"}
 JOB_CLOCKS = ("job_build", "job_plan", "job_stitch", "job_mux",
               "job_write", "job_commit")

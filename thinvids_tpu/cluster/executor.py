@@ -793,11 +793,12 @@ class LocalExecutor:
         an empty one (split-frame) keeps the old order: dispatch n+1,
         collect n.
 
-        The decode → stack → H2D staging chain runs on a background
-        staging thread (`decode_ahead` waves ahead of the dispatch
-        window — parallel/dispatch.background_stage), so ingest
-        overlaps device compute instead of serializing ahead of it and
-        wave n+1's inputs are on the device when wave n ends.
+        The staging chain (decode → one write of each frame into the
+        wave's host arrays → H2D) runs on a background staging thread
+        (`decode_ahead` waves ahead of the dispatch window —
+        parallel/dispatch.background_stage), so ingest overlaps device
+        compute instead of serializing ahead of it and wave n+1's
+        inputs are on the device when wave n ends.
         Staging stays bounded, not free: input residency is the 2
         in-flight waves PLUS up to `decode_ahead` staged-but-undispatched
         waves (+1 blocked in the queue put) of HBM-resident YUV arrays —

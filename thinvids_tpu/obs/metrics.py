@@ -304,6 +304,10 @@ STAGE_COUNTER_TOTALS = {
         "waves that overflowed the sparse budgets and shipped dense"),
     "h2d_bytes": REGISTRY.counter(
         "tvt_h2d_bytes_total", "host-to-device bytes staged"),
+    "stage_copy_bytes": REGISTRY.counter(
+        "tvt_stage_copy_bytes_total",
+        "host bytes the staging thread copied between the decoder's "
+        "planes and the arrays GOP waves upload"),
     "d2h_bytes": REGISTRY.counter(
         "tvt_d2h_bytes_total", "device-to-host bytes fetched"),
     "fetch_shards": REGISTRY.counter(

@@ -23,8 +23,9 @@ slice is itself a program on the device's compute queue and would wait
 for all of wave n+1 — and wave n's unpack and pack run under its compute.
 
 Host side, the pipeline is instrumented per stage (StageProfile): every
-wave's source decode / staging (stack + H2D upload) / dispatch / device
-wait / D2H fetch / sparse unpack / unflatten / CAVLC pack / concat
+wave's source decode / staging (each frame written once into the
+wave's host arrays + H2D upload) / dispatch / device wait / D2H fetch /
+sparse unpack / unflatten / CAVLC pack / concat
 wall-clock accumulates on the encoder and is exported through the
 API's /metrics_snapshot (`stage_ms`). The entropy pack fans out
 at SLICE granularity across a per-encoder pool sized by `pack_workers`
@@ -34,9 +35,10 @@ retire with the encoder), decoupled from the collector-thread window
 
 Ingest is a pipelined stage, not a blocking prologue: `stage_waves`
 accepts a streaming FrameSource (ingest.open_video) or a materialized
-list and holds only the current wave's decoded frames (a sliding
-_FrameCursor window), and :func:`background_stage` runs the whole
-decode→stack→upload chain on a staging thread up to `decode_ahead`
+list and holds ONE decoded frame at a time: each is written, padded in
+place, into its slot of the wave's host arrays as it is decoded
+(_FrameCursor.take, _fill_wave), and :func:`background_stage` runs the
+whole decode→write→upload chain on a staging thread up to `decode_ahead`
 waves (TVT_DECODE_AHEAD) ahead of dispatch, overlapping source decode
 with device compute: wave n+1's inputs are on the device when wave n
 ends.
@@ -116,8 +118,11 @@ def default_mesh(devices=None) -> Mesh:
 # ---- host-stage wall-clock instrumentation --------------------------------
 
 #: canonical stage keys, in pipeline order (decode = pulling frames
-#: from the ingest source; stage = stack + H2D upload — both run on
-#: the staging thread when background_stage wraps the generator;
+#: from the ingest source; stage = writing each decoded frame into its
+#: slot of the wave's host arrays, pad rows, pad columns and repeats
+#: included, + the H2D upload, which is also clocked alone as upload —
+#: all on the staging thread when background_stage wraps the generator;
+#: the split-frame path's stage is its per-frame row pad + device_put;
 #: scale = dispatching the device-side ABR downscale that derives
 #: lower ladder rungs from the staged wave (abr/scale.py);
 #: dense_retry = what a wave that left the sparse budgets costs the
@@ -133,18 +138,21 @@ def default_mesh(devices=None) -> Mesh:
 #: sfe = the split-frame path's per-frame host leg: band unpack, band
 #: slice pack, frame assembly; scenecut = the executor's one read of
 #: the source's luma for scene cuts, parallel/scenecut.py, 0 when off)
-STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
-               "fetch", "dense_retry", "dense_reencode", "dense_fetch",
-               "sparse_unpack", "unflatten", "pack", "concat", "sfe",
-               "halo", "scenecut")
+STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
+               "device_wait", "fetch", "dense_retry", "dense_reencode",
+               "dense_fetch", "sparse_unpack", "unflatten", "pack", "concat",
+               "sfe", "halo", "scenecut")
 
 #: monotonic counters riding in the same snapshot as the stage clocks:
 #: dense_fallback_waves (waves that overflowed the sparse budgets and
 #: shipped their levels dense), h2d_bytes (host→device bytes uploaded
 #: while staging waves: once per wave whatever the ladder's rung
-#: count), d2h_bytes (device→host bytes fetched), fetch_shards
-#: (per-shard concurrent fetch transfers issued; 0 = every fetch was
-#: one blocking device_get), proc_pack_gops (GOPs handed to the
+#: count), stage_copy_bytes (host bytes the staging thread copies
+#: between the decoder's planes and the arrays a GOP wave uploads: the
+#: planes' share of h2d_bytes, each byte written once), d2h_bytes
+#: (device→host bytes fetched), fetch_shards (per-shard concurrent
+#: fetch transfers issued; 0 = every fetch was one blocking
+#: device_get), proc_pack_gops (GOPs handed to the
 #: pack_backend=process sidecars), sfe_frames (frames through the
 #: split-frame per-frame collect), sparse_{blocks,values}_{used,budget}
 #: (blocks with a level and non-zero values counted on the device,
@@ -158,8 +166,9 @@ STAGE_NAMES = ("decode", "stage", "scale", "dispatch", "device_wait",
 #: mvs_coded / mvs_quarter (P macroblocks' vectors handed to the
 #: packers / those of them with an odd quarter-sample component: 0
 #: under subpel="half"; count_vectors)
-STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "d2h_bytes",
-                  "fetch_shards", "proc_pack_gops", "sfe_frames",
+STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes",
+                  "stage_copy_bytes", "d2h_bytes", "fetch_shards",
+                  "proc_pack_gops", "sfe_frames",
                   "sparse_blocks_used", "sparse_blocks_budget",
                   "sparse_values_used", "sparse_values_budget",
                   "scene_cuts", "scene_cuts_suppressed", "wave_frames",
@@ -309,14 +318,18 @@ def frame_latency_percentiles() -> dict:
 
 
 class _FrameCursor:
-    """Sliding decoded-frame window for wave staging.
+    """Decoded frames for wave staging, pulled on demand from a
+    materialized list or a streaming FrameSource (anything exposing
+    ``iter_frames()``), each exactly once and in order.
 
-    Pulls frames on demand from a materialized list or a streaming
-    FrameSource (anything exposing ``iter_frames()``), pads them to
-    macroblock multiples, and retains only ``[lo, hi)`` — the staging
-    loop releases everything below the staged wave's end, so resident
-    decoded frames stay bounded by one wave regardless of clip length
-    (the paper's never-hold-a-whole-clip invariant)."""
+    The GOP path takes ONE frame at a time (:meth:`take`): the staging
+    loop writes it into its slot of the wave's host arrays and drops
+    it, so a single decoded frame is resident whatever the clip's or
+    the wave's length. The split-frame path keeps a sliding window of
+    frames padded to macroblock multiples (:meth:`padded`), released
+    below the staged GOP's end. Either way resident decoded frames
+    stay bounded by one wave regardless of clip length (the paper's
+    never-hold-a-whole-clip invariant)."""
 
     def __init__(self, frames, profile: StageProfile,
                  require_420: bool = False,
@@ -330,28 +343,48 @@ class _FrameCursor:
         self._lo = 0
         self._hi = 0
 
-    def get(self, i: int) -> Frame:
-        """Padded frame at absolute index `i` (must not be released)."""
+    def _pull(self, want: int) -> Frame:
+        """The source's next frame (index `_hi`), under "decode"."""
+        with self._profile.stage("decode"):
+            try:
+                f = next(self._it)
+            except StopIteration:
+                raise ValueError(
+                    f"frame stream ended at {self._hi}, but the "
+                    f"wave plan needs frame {want}") from None
+        if self._require_420 and f.chroma is not ChromaFormat.YUV420:
+            raise ValueError(
+                f"GopShardEncoder supports only 4:2:0 input, got "
+                f"{f.chroma.name}; convert before encoding")
+        self._hi += 1
+        resident = len(self._buf) + 1
+        if resident > self._stats.get("peak_resident_frames", 0):
+            self._stats["peak_resident_frames"] = resident
+        return f
+
+    def take(self, i: int) -> Frame:
+        """Frame `i` as the source decoded it (no pad, no copy), handed
+        over and NOT retained: every index is taken at most once, in
+        rising order."""
+        if i < self._hi:
+            raise IndexError(
+                f"frame {i} already released (the source stands at "
+                f"{self._hi})")
+        while True:
+            f = self._pull(i)
+            if self._hi > i:
+                self._lo = self._hi
+                return f
+
+    def padded(self, i: int) -> Frame:
+        """Frame `i` padded to macroblock multiples, kept in the window
+        until released (must not be released already)."""
         if i < self._lo:
             raise IndexError(
                 f"frame {i} already released (window starts at "
                 f"{self._lo})")
         while self._hi <= i:
-            with self._profile.stage("decode"):
-                try:
-                    f = next(self._it)
-                except StopIteration:
-                    raise ValueError(
-                        f"frame stream ended at {self._hi}, but the "
-                        f"wave plan needs frame {i}") from None
-            if self._require_420 and f.chroma is not ChromaFormat.YUV420:
-                raise ValueError(
-                    f"GopShardEncoder supports only 4:2:0 input, got "
-                    f"{f.chroma.name}; convert before encoding")
-            self._buf.append(f.padded(16))
-            self._hi += 1
-            if len(self._buf) > self._stats.get("peak_resident_frames", 0):
-                self._stats["peak_resident_frames"] = len(self._buf)
+            self._buf.append(self._pull(i).padded(16))
         return self._buf[i - self._lo]
 
     def release_below(self, i: int) -> None:
@@ -360,12 +393,61 @@ class _FrameCursor:
             self._lo += 1
 
 
+def _write_plane(slot: np.ndarray, plane: np.ndarray) -> None:
+    """Write a decoded plane into its (PH, PW) slot of a wave's host
+    array, padded in place by edge replication (core.types.
+    pad_to_shape's result, without the array it would allocate)."""
+    h, w = plane.shape
+    slot[:h, :w] = plane
+    if w < slot.shape[1]:
+        slot[:h, w:] = plane[:, -1:]
+    if h < slot.shape[0]:
+        slot[h:] = slot[h - 1]
+
+
+class _WaveArrays:
+    """Host arrays of staged GOP waves that are free again, kept for
+    the process's next wave of the same shapes, of this job or the
+    next. A new array's pages fault in as they are first written: on
+    the chip's host that is 2.96 ms a 1080p frame against 0.28 into
+    memory the process already has (PERF.md §5), so a wave's arrays
+    come back here once their upload has completed (_upload) and the
+    next wave is written over them, every byte (_fill_wave). At most
+    KEEP sets, all of the same shapes: a set of other shapes (another
+    resolution, a luma-only pass) takes the store over."""
+
+    KEEP = 2
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._free: list[list[np.ndarray]] = []
+
+    def take(self, shapes: list[tuple]) -> list[np.ndarray]:
+        """uint8 arrays of `shapes`: a free set, or new ones."""
+        with self._lock:
+            if self._free and [a.shape for a in self._free[-1]] == shapes:
+                return self._free.pop()
+        return [np.empty(shape, np.uint8) for shape in shapes]
+
+    def give(self, arrays: list[np.ndarray]) -> None:
+        """`arrays` are free: nothing reads them any more."""
+        shapes = [a.shape for a in arrays]
+        with self._lock:
+            if self._free and [a.shape for a in self._free[-1]] != shapes:
+                self._free.clear()
+            if len(self._free) < self.KEEP:
+                self._free.append(arrays)
+
+
+_WAVE_ARRAYS = _WaveArrays()
+
+
 def background_stage(staged_waves, decode_ahead: int = 2):
-    """Run a staging generator (stage_waves: source decode + np.stack +
-    H2D upload) on its own thread, up to `decode_ahead` staged waves
-    ahead of the consumer — ingest becomes a pipelined stage that
-    overlaps device compute instead of a blocking prologue on the
-    dispatch thread.
+    """Run a staging generator (stage_waves: source decode + one write
+    of each frame into the wave's host arrays + H2D upload) on its own
+    thread, up to `decode_ahead` staged waves ahead of the consumer —
+    ingest becomes a pipelined stage that overlaps device compute
+    instead of a blocking prologue on the dispatch thread.
 
     Each queued wave is ALREADY H2D-uploaded: device-side input
     residency is the consumer's in-flight window plus `decode_ahead`
@@ -812,54 +894,95 @@ class GopShardEncoder:
                              self.max_segments, cuts=self.scene_cuts)
 
     def stage_waves(self, frames):
-        """Host-side staging generator: stack frames into per-wave
-        (G, F, H, W) device arrays (HBM-resident input is the design
-        invariant — SURVEY.md §0: kernels run over HBM-resident YUV
-        planes). Lazily, one wave per iteration, so a long clip never
-        pins more than the pipeline window of waves in HBM.
+        """Host-side staging generator: per-wave (G, F, H, W) device
+        arrays (HBM-resident input is the design invariant — SURVEY.md
+        §0: kernels run over HBM-resident YUV planes). Lazily, one wave
+        per iteration, so a long clip never pins more than the pipeline
+        window of waves in HBM.
 
         `frames` may be a materialized list or a streaming FrameSource
-        (ingest.open_video); either way only the current wave's decoded
-        frames stay resident (_FrameCursor). Wrap the result in
+        (ingest.open_video); either way each frame is written ONCE, as
+        it is decoded, into the wave's host arrays (_fill_wave) and
+        those arrays are what is uploaded. Wrap the result in
         :func:`background_stage` — or use :meth:`encode` — to run the
-        decode + stack + H2D upload on a staging thread ahead of the
+        decode + write + H2D upload on a staging thread ahead of the
         dispatch loop. A wave of a plan made on scene cuts carries one
         array more, last: each GOP's real frame count (_wave_groups)."""
         for wave, full, F, cursor, real in self._wave_groups(frames,
                                                              encode=True):
-            # prefetch the wave's frames OUTSIDE the "stage" timer so
-            # the breakdown keeps decode (source pull) and stage
-            # (stack + H2D) disjoint — cursor.get runs its own
-            # "decode"-staged fill
-            cursor.get(wave[-1].end_frame - 1)
-            with self.stages.stage("stage"):
-                ys = np.stack([self._gop_plane(cursor, g, F, "y")
-                               for g in full])
-                us = np.stack([self._gop_plane(cursor, g, F, "u")
-                               for g in full])
-                vs = np.stack([self._gop_plane(cursor, g, F, "v")
-                               for g in full])
-                qps = np.asarray([self.gop_qp.get(g.index, self.qp)
-                                  for g in full], np.int32)
-                small = (qps,) if real is None else (qps, real)
-                self.stages.bump("h2d_bytes", ys.nbytes + us.nbytes
-                                 + vs.nbytes + sum(a.nbytes for a in small))
-                staged = (wave, jnp.asarray(ys), jnp.asarray(us),
-                          jnp.asarray(vs), *map(jnp.asarray, small))
-            yield staged
+            planes = self._fill_wave(cursor, wave, len(full), F, "yuv")
+            qps = np.asarray([self.gop_qp.get(g.index, self.qp)
+                              for g in full], np.int32)
+            small = (qps,) if real is None else (qps, real)
+            yield (wave, *self._upload(planes, small))
 
     def stage_luma_waves(self, frames):
         """Luma-only staging for analysis passes (rate control): chroma
         never leaves the host, halving the upload of a pass that only
         reads Y. Yields (wave, ys)."""
         for wave, full, F, cursor, _ in self._wave_groups(frames):
-            cursor.get(wave[-1].end_frame - 1)   # decode outside "stage"
+            yield (wave, *self._upload(
+                self._fill_wave(cursor, wave, len(full), F, "y")))
+
+    def _fill_wave(self, cursor: _FrameCursor, wave: list, G: int, F: int,
+                   planes: str) -> list[np.ndarray]:
+        """The wave's host arrays, one (G, F, PH, PW) uint8 array per
+        plane of `planes` (chroma at half of each of PH, PW), every
+        decoded frame written ONCE: as the source hands it over
+        ("decode" clock) it goes into its slot, padded to macroblock
+        multiples in place ("stage" clock), and is dropped. A GOP's
+        tail repeats (to the wave's static F: the program's shape; what
+        it encodes of them is the plan's to say, _wave_groups) and the
+        pad GOPs past `wave`, up to G, are filled from the slot they
+        repeat. Every byte of the arrays is written, so they may come
+        from an earlier wave (_WAVE_ARRAYS); `stage_copy_bytes` counts
+        what was written."""
+        bufs: list[np.ndarray] = []
+        for g, gop in enumerate(wave):
+            for k, i in enumerate(range(gop.start_frame, gop.end_frame)):
+                f = cursor.take(i)
+                with self.stages.stage("stage"):
+                    ph, pw = (-(-n // 16) * 16 for n in f.y.shape)
+                    if not bufs:
+                        bufs = _WAVE_ARRAYS.take([(G, F) + (
+                            (ph, pw) if p == "y" else (ph // 2, pw // 2))
+                            for p in planes])
+                    elif bufs[0].shape[2:] != (ph, pw):
+                        raise ValueError(
+                            f"frame {i} pads to {pw}x{ph}, the wave's "
+                            f"first to {bufs[0].shape[3]}x{bufs[0].shape[2]}")
+                    for buf, p in zip(bufs, planes):
+                        _write_plane(buf[g, k], getattr(f, p))
+            if gop.num_frames < F:
+                with self.stages.stage("stage"):
+                    for buf in bufs:
+                        buf[g, gop.num_frames:] = buf[g, gop.num_frames - 1]
+        if len(wave) < G:
             with self.stages.stage("stage"):
-                ys = np.stack([self._gop_plane(cursor, g, F, "y")
-                               for g in full])
-                self.stages.bump("h2d_bytes", ys.nbytes)
-                staged = (wave, jnp.asarray(ys))
-            yield staged
+                for buf in bufs:
+                    buf[len(wave):] = buf[len(wave) - 1]
+        self.stages.bump("stage_copy_bytes", sum(b.nbytes for b in bufs))
+        return bufs
+
+    def _upload(self, planes: list[np.ndarray], small: tuple = ()) -> list:
+        """H2D: a filled wave's host arrays (and its `small` ones: QPs,
+        lengths) as device arrays — clock `upload`, part of `stage`;
+        counter `h2d_bytes`. Waits for the copies: the first program of
+        a job cannot start before its inputs have arrived, and the
+        planes' arrays are free for the next wave from here on
+        (_WAVE_ARRAYS) — unless the backend made a device array a view
+        of its host array, as the CPU client does with aligned ones:
+        those stay the device's."""
+        arrays = (*planes, *small)
+        self.stages.bump("h2d_bytes", sum(a.nbytes for a in arrays))
+        with self.stages.stage("upload", part_of="stage"):
+            sent = [jnp.asarray(a) for a in arrays]
+            for dev in sent:
+                dev.block_until_ready()
+        if not any(dev.unsafe_buffer_pointer() == host.ctypes.data
+                   for dev, host in zip(sent, planes)):
+            _WAVE_ARRAYS.give(planes)
+        return sent
 
     def _wave_groups(self, frames, encode: bool = False):
         """Shared wave grouping: (wave, device-padded wave, static F,
@@ -881,8 +1004,8 @@ class GopShardEncoder:
         job runs ONE of the two programs, chosen by its plan. Counted:
         `wave_frames` staged in all, `pad_frames` of them repeats,
         `pad_frames_skipped` of those not encoded. The cursor decodes
-        frames on demand and each wave's frames are released once the
-        caller has staged them into device arrays."""
+        frames on demand, each once; the caller takes them one at a
+        time as it fills the wave's host arrays (_fill_wave)."""
         plan = self.plan(len(frames))
         cursor = _FrameCursor(frames, self.stages, require_420=encode,
                               stats=self.staging_stats)
@@ -906,9 +1029,6 @@ class GopShardEncoder:
                 self.stages.bump("pad_frames_skipped",
                                  staged - int(real.sum()))
             yield wave, full, F, cursor, real
-            # the caller staged this wave into device arrays; frames
-            # below the next wave's start will never be read again
-            cursor.release_below(wave[-1].end_frame)
 
     def encode(self, frames) -> list[EncodedSegment]:
         """Stream-encode: source decode + staging run on a background
@@ -1534,17 +1654,6 @@ class GopShardEncoder:
                 segments.extend(segs)
         return segments
 
-    @staticmethod
-    def _gop_plane(cursor: _FrameCursor, gop: GopSpec, F: int, plane: str
-                   ) -> np.ndarray:
-        arrs = [getattr(cursor.get(i), plane)
-                for i in range(gop.start_frame, gop.end_frame)]
-        # tail-repeat to the wave's static F: the program's shape. What
-        # it encodes of the repeats is the plan's to say (_wave_groups)
-        while len(arrs) < F:
-            arrs.append(arrs[-1])
-        return np.stack(arrs)
-
 
 # ---------------------------------------------------------------------------
 # split-frame encoding (SFE): shard ONE frame across the mesh
@@ -1999,11 +2108,11 @@ class SfeShardEncoder(GopShardEncoder):
         y0, y1 = self.band_lo * rows16, self.band_hi * rows16
         shard = NamedSharding(self.mesh, P("band"))
         for gop in plan.gops:
-            cursor.get(gop.end_frame - 1)   # decode outside "stage"
+            cursor.padded(gop.end_frame - 1)   # decode outside "stage"
             with self.stages.stage("stage"):
                 ys, us, vs = [], [], []
                 for i in range(gop.start_frame, gop.end_frame):
-                    f = cursor.get(i)
+                    f = cursor.padded(i)
                     ya = self._pad_rows(f.y, Hg)[y0:y1]
                     ua = self._pad_rows(f.u, Hg // 2)[y0 // 2:y1 // 2]
                     va = self._pad_rows(f.v, Hg // 2)[y0 // 2:y1 // 2]
