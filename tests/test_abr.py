@@ -657,7 +657,7 @@ class TestRemoteLadder:
 
 def test_ladder_and_hls_are_manifested_jax_free(analysis_ctx):
     """Packaging and planning must run on jax-free worker/sidecar
-    processes (same rule as parallel/packproc.py). Migrated from a
+    processes. Migrated from a
     subprocess import probe to the analyzer's import-graph proof: the
     manifest must keep declaring both modules jax-free, and the
     confinement pass (which walks the TRANSITIVE module-scope import

@@ -1,20 +1,22 @@
 """Compact device→host level-stream transfer (ISSUE 4).
 
-Covers the three layers of the boundary rework: the device-side payload
+Covers the layers of the boundary: the device-side payload
 compaction (jaxcore._compact_stream + the native/numpy unpack parity),
-bit-identity of the compact transfer against the validated sparse2 path
+bit-identity of the wave pipeline against the single-device reference
 (including the escape-heavy dense-fallback edge), the per-shard
-concurrent fetch on the 8-device virtual mesh, the process pack
-sidecars (pack_backend=process), the stage-honesty accounting
-(dense_retry / dense_fallback_waves / d2h_bytes), and the sync
+concurrent fetch on the 8-device virtual mesh, the shape of the one
+path (no mode parameter, two pinned settings), the stage-honesty
+accounting (dense_retry / dense_fallback_waves / d2h_bytes), and the sync
 confinement that keeps blocking `jax.device_get` off the hot path for
 good (now enforced tree-wide by `cli.py check`; the test here asserts
 the analyzer manifest still encodes this file's contract).
 """
 
+import importlib
+import inspect
+import json
 import os
-import subprocess
-import sys
+import urllib.request
 
 import numpy as np
 import pytest
@@ -23,8 +25,11 @@ import jax
 import jax.numpy as jnp
 
 from thinvids_tpu.codecs.h264 import jaxcore, layout
+from thinvids_tpu.codecs.h264.encoder import encode_gop
+from thinvids_tpu.codecs.h264.rdo import RdConfig, aq_from_strength
 from thinvids_tpu.core.types import Frame, VideoMeta, concat_segments
-from thinvids_tpu.parallel.dispatch import GopShardEncoder
+from thinvids_tpu.parallel.dispatch import (GopShardEncoder, default_mesh,
+                                            encode_clip_sharded)
 
 
 def _smooth_frames(n, w=64, h=48):
@@ -123,44 +128,64 @@ class TestCompactStream:
 
 
 class TestCompactTransferParity:
-    def test_bit_identical_to_sparse2_and_moves_fewer_bytes(self):
-        frames = _smooth_frames(12)
-        meta = VideoMeta(width=64, height=48, num_frames=12)
+    """The wave pipeline against the single-device reference
+    (encoder.encode_gop), GOP for GOP: the compact wire where the
+    sparse budgets hold, the levels as words where they do not."""
 
-        enc_new = GopShardEncoder(meta, qp=27, gop_frames=3,
-                                  compact_transfer=True)
-        got = concat_segments(enc_new.encode(frames))
-        snap_new = enc_new.stages.snapshot()
-        enc_old = GopShardEncoder(meta, qp=27, gop_frames=3,
-                                  compact_transfer=False)
-        want = concat_segments(enc_old.encode(frames))
-        snap_old = enc_old.stages.snapshot()
+    #: name -> (frames, encoder arguments, devices (None: the whole
+    #: mesh), whether the clip leaves the sparse budgets)
+    CASES = {
+        "smooth": (lambda: _smooth_frames(12), dict(gop_frames=3), None,
+                   False),
+        "smooth_one_device": (lambda: _smooth_frames(12),
+                              dict(gop_frames=3), 1, False),
+        "noise": (lambda: _noise_frames(8, seed=23), dict(gop_frames=2),
+                  None, True),
+        "noise_one_device": (lambda: _noise_frames(8, seed=5),
+                             dict(gop_frames=2), 1, True),
+        "serving_rd": (
+            lambda: _smooth_frames(8),
+            dict(gop_frames=4, qp=25, rd=RdConfig(
+                mode_decision=True, pskip=True, deblock=True,
+                aq_q=aq_from_strength(1.0))), 1, False),
+        "quarter_subpel": (lambda: _smooth_frames(8),
+                           dict(gop_frames=4, rd=RdConfig(subpel="quarter")),
+                           1, False),
+        "mesh_of_two": (lambda: _smooth_frames(12), dict(gop_frames=3), 2,
+                        False),
+        "mesh_of_two_noise": (lambda: _noise_frames(8, seed=23),
+                              dict(gop_frames=2), 2, True),
+        "two_gops_a_device": (lambda: _smooth_frames(12),
+                              dict(gop_frames=3, gops_per_wave=2), 1,
+                              False),
+    }
 
-        assert got == want
-        # both stayed on the sparse path...
-        assert snap_new["dense_fallback_waves"] == 0
-        assert snap_old["dense_fallback_waves"] == 0
-        # ...and the compact payload crossed the link in fewer bytes
-        # than the three budget-padded arrays
-        assert 0 < snap_new["d2h_bytes"] <= snap_old["d2h_bytes"]
-
-    def test_escape_heavy_content_takes_dense_fallback_identically(self):
-        # iid noise overflows the block budget: both transfer modes
-        # must fall back to the dense wave and still agree bit-for-bit
-        frames = _noise_frames(8, seed=23)
-        meta = VideoMeta(width=64, height=48, num_frames=8)
-
-        def run(compact):
-            enc = GopShardEncoder(meta, qp=27, gop_frames=2,
-                                  compact_transfer=compact)
-            stream = concat_segments(enc.encode(frames))
-            return stream, enc.stages.snapshot()
-
-        got, snap_new = run(True)
-        want, snap_old = run(False)
-        assert got == want
-        assert snap_new["dense_fallback_waves"] >= 1
-        assert snap_old["dense_fallback_waves"] >= 1
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_wave_bytes_are_the_single_device_reference(self, case):
+        make, kwargs, devices, goes_dense = self.CASES[case]
+        frames = make()
+        kwargs = dict(qp=27) | kwargs
+        meta = VideoMeta(width=64, height=48, num_frames=len(frames))
+        mesh = None if devices is None \
+            else default_mesh(jax.devices()[:devices])
+        enc = GopShardEncoder(meta, mesh=mesh, **kwargs)
+        segs = enc.encode(frames)
+        assert [g.gop.index for g in segs] == list(range(len(segs)))
+        assert segs[-1].gop.end_frame == len(frames)
+        want = [encode_gop(frames[s.gop.start_frame:s.gop.end_frame], meta,
+                           qp=kwargs["qp"], idr_pic_id=s.gop.index,
+                           rd=kwargs.get("rd"))
+                for s in segs]
+        assert [s.payload for s in segs] == want
+        snap = enc.stages.snapshot()
+        if goes_dense:
+            assert snap["dense_fallback_waves"] >= 1
+            return
+        assert snap["dense_fallback_waves"] == 0
+        # the payloads' used prefixes crossed, not the levels
+        L, _Lr = enc._level_sizes(max(s.gop.num_frames for s in segs),
+                                  (64 // 16) * (48 // 16))
+        assert 0 < snap["d2h_bytes"] < len(segs) * L * 2
 
     def test_dense_retry_is_its_own_stage(self, monkeypatch):
         # Stage honesty: the dense fallback's wide fetch must land in
@@ -221,106 +246,71 @@ class TestPerShardFetch:
         assert enc.stages.snapshot()["fetch_shards"] == 0
 
 
-class TestProcessPackBackend:
-    def test_process_and_thread_backends_byte_identical(self):
-        frames = _smooth_frames(12)
-        meta = VideoMeta(width=64, height=48, num_frames=12)
-        enc_t = GopShardEncoder(meta, qp=27, gop_frames=3,
-                                pack_workers=2)
-        base = concat_segments(enc_t.encode(frames))
-        enc_p = GopShardEncoder(meta, qp=27, gop_frames=3,
-                                pack_workers=2, pack_backend="process")
-        if enc_p._proc_pool is None:
-            pytest.skip("platform cannot spawn a process pool")
-        got = concat_segments(enc_p.encode(frames))
-        assert got == base
-        # the sidecars actually took the GOPs (not a silent thread
-        # fallback)
-        assert enc_p.stages.snapshot()["proc_pack_gops"] >= 4
+class TestOnePath:
+    """The GOP-wave encoder has one device program per wave, one wire
+    and one pack backend: no parameter selects another, the sidecar
+    module is gone, and the two settings that named the choices are
+    constants that report what the benchmark's configs expect
+    (core/config._PINNED)."""
 
-    def test_process_backend_dense_fallback_uses_threads(self):
-        # GOPs that leave the compact path (dense wave) must still pack
-        # correctly on the thread pool under pack_backend=process
-        frames = _noise_frames(8, seed=5)
-        meta = VideoMeta(width=64, height=48, num_frames=8)
-        enc_t = GopShardEncoder(meta, qp=27, gop_frames=2)
-        base = concat_segments(enc_t.encode(frames))
-        enc_p = GopShardEncoder(meta, qp=27, gop_frames=2,
-                                pack_backend="process")
-        if enc_p._proc_pool is None:
-            pytest.skip("platform cannot spawn a process pool")
-        assert concat_segments(enc_p.encode(frames)) == base
-        snap = enc_p.stages.snapshot()
-        assert snap["dense_fallback_waves"] >= 1
-        assert snap["proc_pack_gops"] == 0
+    @pytest.mark.parametrize("param", ["inter", "compact_transfer",
+                                       "pack_backend"])
+    @pytest.mark.parametrize("fn", [GopShardEncoder.__init__,
+                                    encode_clip_sharded],
+                             ids=["GopShardEncoder", "encode_clip_sharded"])
+    def test_no_mode_parameter(self, fn, param):
+        assert param not in inspect.signature(fn).parameters
 
-    def test_broken_pool_degrades_to_inline_pack(self):
-        # A sidecar pool that breaks mid-job must not fail the encode:
-        # the spool bytes re-pack in-process, the pool is retired, and
-        # the output stays bit-identical. No shared-memory blocks may
-        # outlive the wave either way.
-        from concurrent.futures import Future
-        from concurrent.futures.process import BrokenProcessPool
+    def test_packproc_is_gone(self):
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module("thinvids_tpu.parallel.packproc")
 
-        frames = _smooth_frames(12)
-        meta = VideoMeta(width=64, height=48, num_frames=12)
-        enc_t = GopShardEncoder(meta, qp=27, gop_frames=3)
-        base = concat_segments(enc_t.encode(frames))
+    @pytest.mark.parametrize("how", ["env", "post_settings",
+                                     "update_live_settings"])
+    @pytest.mark.parametrize("key,asked,reported", [
+        ("compact_transfer", "0", True),
+        ("pack_backend", "process", "thread")])
+    def test_pinned_setting_reports_the_configs_value(
+            self, key, asked, reported, how, monkeypatch):
+        from thinvids_tpu.core import config
 
-        class BrokenPool:
-            def submit(self, fn, *args):
-                fut = Future()
-                fut.set_exception(BrokenProcessPool("child died"))
-                return fut
-
-        enc = GopShardEncoder(meta, qp=27, gop_frames=3,
-                              pack_backend="process")
-        enc._proc_pool = BrokenPool()
-        assert concat_segments(enc.encode(frames)) == base
-        assert enc._proc_pool is None       # retired after first break
-
-    def test_pack_backend_knobs(self, monkeypatch):
-        from thinvids_tpu.core.config import (get_settings,
-                                              invalidate_settings_cache,
-                                              update_live_settings)
-
-        meta = VideoMeta(width=64, height=48, num_frames=4)
-        monkeypatch.setenv("TVT_PACK_BACKEND", "process")
-        monkeypatch.setenv("TVT_COMPACT_TRANSFER", "0")
-        invalidate_settings_cache()
+        # what every benchmark config states in `expect_settings`
+        cfg_dir = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs")
+        for name in sorted(os.listdir(cfg_dir)):
+            with open(os.path.join(cfg_dir, name)) as fh:
+                assert json.load(fh)["expect_settings"][key] == reported
+        config.reset_live_settings()
         try:
-            enc = GopShardEncoder(meta, qp=27)
-            assert enc.pack_backend == "process"
-            assert enc.compact_transfer is False
-            # constructor args beat the config tier
-            enc2 = GopShardEncoder(meta, qp=27, pack_backend="thread",
-                                   compact_transfer=True)
-            assert enc2.pack_backend == "thread"
-            assert enc2.compact_transfer is True
-        finally:
-            monkeypatch.delenv("TVT_PACK_BACKEND")
-            monkeypatch.delenv("TVT_COMPACT_TRANSFER")
-            invalidate_settings_cache()
-        # the live tier clamps unknown backends back to "thread"
-        update_live_settings({"pack_backend": "bogus"})
-        try:
-            assert get_settings(refresh=True).pack_backend == "thread"
-        finally:
-            from thinvids_tpu.core.config import reset_live_settings
+            if how == "env":
+                monkeypatch.setenv("TVT_" + key.upper(), asked)
+                config.invalidate_settings_cache()
+            elif how == "update_live_settings":
+                applied = config.update_live_settings({key: asked})
+                assert applied == {key: reported}
+            else:
+                from thinvids_tpu.api import ApiServer
+                from thinvids_tpu.cluster.coordinator import Coordinator
 
-            reset_live_settings()
-
-    def test_packproc_imports_without_jax(self):
-        # Pool children (spawn) import packproc fresh; dragging jax in
-        # would initialize a device backend per pack worker. Run in a
-        # clean interpreter so this process's imports don't mask it.
-        code = ("import sys; import thinvids_tpu.parallel.packproc; "
-                "assert 'jax' not in sys.modules, 'packproc pulled jax in'")
-        env = dict(os.environ)
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-        subprocess.run([sys.executable, "-c", code], check=True, env=env,
-                       timeout=120)
+                server = ApiServer(Coordinator()).start()
+                try:
+                    req = urllib.request.Request(
+                        server.url + "/settings", method="POST",
+                        data=json.dumps({key: asked}).encode(),
+                        headers={"Content-Type": "application/json"})
+                    with urllib.request.urlopen(req, timeout=10) as resp:
+                        assert resp.status == 200
+                    with urllib.request.urlopen(server.url + "/settings",
+                                                timeout=10) as resp:
+                        served = json.loads(resp.read())["settings"]
+                finally:
+                    server.stop()
+                assert served[key] == reported
+            got = config.get_settings(refresh=True).get(key)
+            assert got == reported and type(got) is type(reported)
+        finally:
+            monkeypatch.delenv("TVT_" + key.upper(), raising=False)
+            config.reset_live_settings()
 
 
 class TestSyncConfinement:
@@ -356,34 +346,3 @@ class TestSyncConfinement:
         open_ = [f for f in syncs.run(tree, m)
                  if f.key not in m.waivers]
         assert not open_, "\n".join(f.format() for f in open_)
-
-
-class TestProcPoolThreadSafety:
-    def test_disable_proc_pool_single_shot_across_threads(self, caplog):
-        """Regression (cli.py check TVT-T001): several collector
-        threads can hit a broken sidecar pool in the same wave window;
-        the swap-under-_proc_lock retires it exactly once (one warning,
-        no double-disable, never an exception)."""
-        import logging
-        import threading
-
-        enc = object.__new__(GopShardEncoder)
-        enc._proc_lock = threading.Lock()
-        enc._proc_pool = object()
-        barrier = threading.Barrier(8)
-
-        def hit():
-            barrier.wait()
-            enc._disable_proc_pool(RuntimeError("boom"))
-
-        workers = [threading.Thread(target=hit) for _ in range(8)]
-        with caplog.at_level(logging.WARNING,
-                             logger="thinvids_tpu.parallel.dispatch"):
-            for t in workers:
-                t.start()
-            for t in workers:
-                t.join(5)
-        assert enc._proc_pool is None
-        retired = [r for r in caplog.records
-                   if "pack sidecar pool broke" in r.getMessage()]
-        assert len(retired) == 1

@@ -89,7 +89,7 @@ D2H_BYTES_OF_2_0 = 10518
 
 
 def _words(L):
-    """int32 words the L levels of a GOP (or all-intra frame) cross the
+    """int32 words the L levels of a GOP cross the
     link as: pairs, in whole rows of `dispatch._WORD_ROW` levels."""
     row = dispatch._WORD_ROW
     return -(-L // row) * (row // 2)
@@ -165,8 +165,7 @@ def _true_fill(frames, qp, w, h):
     stack = [jnp.asarray(np.stack([getattr(p, k) for p in pads]))[None]
              for k in "yuv"]
     flat = np.asarray(dispatch._encode_gop_single(
-        *stack, jnp.asarray([qp], jnp.int32), mbw=mbw, mbh=mbh,
-        compact=True)[-1])[0]
+        *stack, jnp.asarray([qp], jnp.int32), mbw=mbw, mbh=mbh)[-1])[0]
     assert flat.dtype == np.int16
     ndc, nlac, ncdc = nmb * 16, nmb * 240, nmb * 8
     rest = np.concatenate([flat[ndc:ndc + nlac], flat[ndc + nlac + ncdc:]])
@@ -320,43 +319,26 @@ class TestOneProgramPerWave:
         assert snap["d2h_bytes"] == D2H_BYTES_OF_2_0
         assert snap["d2h_bytes"] < levels.nbytes
 
-    @pytest.mark.parametrize("path", ["compact", "sparse2", "intra"])
-    def test_dispatch_starts_no_copy_of_the_levels(self, path, monkeypatch):
+    def test_dispatch_starts_no_copy_of_the_levels(self, monkeypatch):
         """`dispatch_wave` prefetches every small output and neither the
         budget-padded compact payload nor the whole levels: 199 MB per
         1080p GOP would cross on every wave of every cell."""
         frames = _clip((0.0,))
         meta = VideoMeta(width=W, height=H, num_frames=len(frames))
-        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip(),
-                              inter=path != "intra",
-                              compact_transfer=path == "compact")
+        enc = GopShardEncoder(meta, qp=27, gop_frames=GOP, mesh=_one_chip())
         (staged,) = enc.stage_waves(frames)
-        name = "_encode_wave" if path == "intra" else "_encode_gop_single"
-        program = getattr(dispatch, name)
+        program = dispatch._encode_gop_single
         monkeypatch.setattr(
-            dispatch, name,
+            dispatch, "_encode_gop_single",
             lambda *a, **kw: tuple(_CopySpy(x) for x in program(*a, **kw)))
         handle = enc.dispatch_wave(staged)
         out, levels = handle[7], handle[-1].dense
         assert isinstance(levels, _CopySpy) and not levels.copied
         assert levels.array.dtype == jnp.int16
         assert levels not in out
-        held_back = {6} if path == "compact" else set()
         assert [spy.copied for spy in out] == [
-            i not in held_back for i in range(len(out))]
-        assert len(out) == {"compact": 7, "sparse2": 8, "intra": 6}[path]
-
-
-def _noise(n, w=W, h=H, seed=11):
-    """White-noise frames: every coefficient of an all-intra frame is
-    a level, so the frame's sparse pack overflows at any QP."""
-    from thinvids_tpu.core.types import Frame
-
-    rng = np.random.default_rng(seed)
-    return [Frame(y=rng.integers(0, 256, (h, w), np.uint8),
-                  u=rng.integers(0, 256, (h // 2, w // 2), np.uint8),
-                  v=rng.integers(0, 256, (h // 2, w // 2), np.uint8))
-            for _ in range(n)]
+            i != 6 for i in range(len(out))]
+        assert len(out) == 7
 
 
 class TestLevelsCrossAsWords:
@@ -365,29 +347,24 @@ class TestLevelsCrossAsWords:
     same bytes as 32-bit words — and the host's int16 view of the
     words is that output, element for element."""
 
-    #: name -> (encoder arguments, devices, frames, shape of the levels
-    #: as (GOPs, frames or None), ships_modes)
+    #: name -> (encoder arguments, devices, frames, GOPs in the wave)
     WAVES = {
-        "one_gop": (dict(), 1, lambda: _clip((5.0,)), (1, None)),
+        "one_gop": (dict(), 1, lambda: _clip((5.0,)), 1),
         "two_gops_a_device": (dict(gops_per_wave=2), 1,
-                              lambda: _clip((5.0, 6.0)), (2, None)),
+                              lambda: _clip((5.0, 6.0)), 2),
         "serving_set": (dict(rd=RdConfig(**RD_SERVING)), 1,
-                        lambda: _clip((6.0,)), (1, None)),
-        "mesh_of_two": (dict(), 2, lambda: _clip((0.0, 6.0)), (2, None)),
-        "all_intra": (dict(inter=False), 1, lambda: _noise(3), (1, 3)),
-        "all_intra_serving_modes": (
-            dict(inter=False, rd=RdConfig(mode_decision=True)), 2,
-            lambda: _noise(4), (2, 2)),
+                        lambda: _clip((6.0,)), 1),
+        "mesh_of_two": (dict(), 2, lambda: _clip((0.0, 6.0)), 2),
     }
 
     @pytest.mark.parametrize("wave", sorted(WAVES))
     def test_words_viewed_as_int16_are_the_programs_levels(self, wave):
-        kwargs, devices, make, (G, F) = self.WAVES[wave]
+        kwargs, devices, make, G = self.WAVES[wave]
         frames = make()
         qp = 25 if "rd" in kwargs else 27
         meta = VideoMeta(width=W, height=H, num_frames=len(frames))
         enc = GopShardEncoder(
-            meta, qp=qp, gop_frames=GOP if F is None else F,
+            meta, qp=qp, gop_frames=GOP,
             mesh=default_mesh(jax.devices()[:devices]), **kwargs)
         (staged,) = enc.stage_waves(frames)
         handle = enc.dispatch_wave(staged)
@@ -396,7 +373,7 @@ class TestLevelsCrossAsWords:
         L, _Lr = enc._level_sizes(GOP, (W // 16) * (H // 16))
         assert enc.rd.ships_modes == ("rd" in kwargs)
         assert levels.dtype == jnp.int16
-        assert levels.shape == ((G, L) if F is None else (G, F, L))
+        assert levels.shape == (G, L)
         enc.start_fetch(handle)
         assert not fetch.sparse_ok
         words = fetch.dense
@@ -414,9 +391,8 @@ class TestLevelsCrossAsWords:
         segs = enc.collect_wave(handle)
         assert fetch.dense is None              # released once fetched
         assert enc.stages.snapshot()["dense_fallback_waves"] == 1
-        if enc.inter:
-            assert [s.payload for s in segs] == _plain(
-                frames, segs, qp, rd=kwargs.get("rd"))
+        assert [s.payload for s in segs] == _plain(
+            frames, segs, qp, rd=kwargs.get("rd"))
 
     @pytest.mark.parametrize("shape", [(1, 2), (1, 254), (1, 255),
                                        (2, 258), (2, 3, 770), (3, 769),

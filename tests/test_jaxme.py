@@ -308,7 +308,7 @@ def test_kernel_lowers_inside_gop_wave_shard_map(force_pallas, subpel):
         jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
         jax.ShapeDtypeStruct((G, F, H // 2, W // 2), jnp.uint8),
         jax.ShapeDtypeStruct((G,), jnp.int32),
-        mbw=W // 16, mbh=H // 16, mesh=mesh, compact=True, rd=_rd(subpel))
+        mbw=W // 16, mbh=H // 16, mesh=mesh, rd=_rd(subpel))
     assert "tpu_custom_call" in text
 
 

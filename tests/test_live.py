@@ -471,12 +471,19 @@ class TestLiveJobEndToEnd:
         frames = textured_frames(W, H, n)
         header, recs = frame_records(META, frames)
         path = str(tmp_path / "cam.live.y4m")
-        # generous stall budget: the writer deliberately HOLDS the
-        # tail open (gate) until mid-stream serving is proven, and
-        # that hold must read as "writer still alive", not EOS — the
-        # explicit .eos marker ends the stream without the wait
+        # How long the poll below waits for mid-stream output. What it
+        # waits on is the job's first compile: 16 s of this test's 17
+        # alone, 30-36 s beside nine other compiling processes. The
+        # writer HOLDS the tail open (gate) for all of it: a writer
+        # that let go earlier (it used to, after 20 s) hands the job
+        # its last frames and `.eos` while it still compiles, and the
+        # job then goes from its first output to DONE in 10 ms, between
+        # two polls. The hold must read as "writer still alive", not
+        # EOS, so the stall budget outlasts it — the explicit .eos
+        # marker ends the stream without the wait
+        poll_s = 180.0
         snap = make_settings(qp=30, gop_frames=gop, segment_s=0.25,
-                             ladder_rungs="24", live_stall_s=30.0,
+                             ladder_rungs="24", live_stall_s=2 * poll_s,
                              heartbeat_throttle_s=0.0)
         coord, execu = make_rig(tmp_path, snap)
         api = ApiServer(coord)
@@ -491,8 +498,9 @@ class TestLiveJobEndToEnd:
                 for i, rec in enumerate(recs):
                     if i == len(recs) - 2:
                         # hold the live edge open until the test has
-                        # fetched output mid-stream (or 20 s safety)
-                        gate.wait(20.0)
+                        # fetched output mid-stream (it sets the gate
+                        # when its poll ends, however it ends)
+                        gate.wait(poll_s + 30.0)
                     out.write(rec)
                     out.flush()
                     time.sleep(0.01)
@@ -509,8 +517,8 @@ class TestLiveJobEndToEnd:
         # poll until output is served WHILE the job is still running
         served_master = served_segment = None
         lint_state = None
-        deadline = time.time() + 60
-        while time.time() < deadline:
+        deadline = time.time() + poll_s
+        while time.time() < deadline and served_segment is None:
             st = coord.store.get(job.id)
             assert st.status is not Status.FAILED, st.failure_reason
             if st.output_path and os.path.exists(st.output_path) \

@@ -96,44 +96,6 @@ class TestShardedDispatch:
     def test_mesh_has_8_virtual_devices(self):
         assert len(jax.devices()) == 8
 
-    def test_sharded_bit_identical_to_single_device(self):
-        frames = _make_frames(16)
-        meta = VideoMeta(width=64, height=48, fps_num=30, fps_den=1,
-                         num_frames=16)
-        got = encode_clip_sharded(frames, meta, qp=27, gop_frames=2,
-                                  inter=False)
-        want = _reference_stream(frames, meta, 27, 2, len(jax.devices()))
-        assert got == want
-
-    def test_sharded_uneven_wave(self):
-        # 10 frames, gop 3 → plan caps/rounds; last wave is partial.
-        frames = _make_frames(10, seed=3)
-        meta = VideoMeta(width=64, height=48, num_frames=10)
-        mesh = default_mesh()
-        enc = GopShardEncoder(meta, qp=30, mesh=mesh, gop_frames=3,
-                              inter=False)
-        segments = enc.encode(frames)
-        got = concat_segments(segments)
-        plan = enc.plan(len(frames))
-        want = _reference_stream(frames, meta, 30, 3, len(jax.devices()))
-        assert len(segments) == plan.num_gops
-        assert got == want
-
-    def test_sparse_and_dense_transfer_paths_agree(self):
-        # Smooth frames take the sparse-packed transfer; noisy frames hit
-        # the dense fallback. Both must equal the single-device stream.
-        meta = VideoMeta(width=64, height=48, num_frames=8)
-        yy, xx = np.mgrid[0:48, 0:64]
-        smooth = [Frame(
-            y=((xx + yy + 7 * i) % 256).astype(np.uint8),
-            u=np.full((24, 32), 100 + i, np.uint8),
-            v=np.full((24, 32), 140 - i, np.uint8),
-        ) for i in range(8)]
-        got = encode_clip_sharded(smooth, meta, qp=30, gop_frames=2,
-                                  inter=False)
-        want = _reference_stream(smooth, meta, 30, 2, len(jax.devices()))
-        assert got == want
-
     def test_sparse_pack_roundtrip(self):
         from thinvids_tpu.codecs.h264 import jaxcore
         import jax.numpy as jnp
@@ -154,16 +116,6 @@ class TestShardedDispatch:
                                      esc_pos, esc_val, L)
         np.testing.assert_array_equal(out, flat)
 
-    def test_sharded_decodes_via_own_decoder(self):
-        from thinvids_tpu.codecs.h264.decoder import decode_annexb
-
-        frames = _make_frames(8, seed=7)
-        meta = VideoMeta(width=64, height=48, num_frames=8)
-        stream = encode_clip_sharded(frames, meta, qp=27, gop_frames=2,
-                                     inter=False)
-        decoded = decode_annexb(stream)
-        assert len(decoded.frames) == 8
-
 
 class TestShardedInterDispatch:
     """Sharded GOP (IDR + P) coding across the virtual mesh."""
@@ -181,6 +133,29 @@ class TestShardedInterDispatch:
                 frames[gop.start_frame:gop.end_frame], meta, qp=27,
                 idr_pic_id=gop.index))
         assert got == b"".join(parts)
+
+    @pytest.mark.parametrize("max_segments,gop_lengths", [
+        (200, [2, 2, 1, 1, 1, 1, 1, 1]),    # tail repeats to F = 2
+        (6, [2, 2, 2, 2, 1, 1]),            # and two pad GOPs to 8
+    ], ids=["tail_repeats", "pad_gops"])
+    def test_uneven_wave_matches_single_device(self, max_segments,
+                                               gop_lengths):
+        # 10 frames, GOP 3 on 8 devices: GOPs of unequal length share
+        # one static F, and a wave of fewer GOPs than devices pads to
+        # the mesh; neither the repeats nor the pad GOPs are emitted.
+        from thinvids_tpu.codecs.h264.encoder import encode_gop
+
+        frames = _make_frames(10, seed=3)
+        meta = VideoMeta(width=64, height=48, num_frames=10)
+        enc = GopShardEncoder(meta, qp=30, gop_frames=3,
+                              max_segments=max_segments)
+        segments = enc.encode(frames)
+        assert [s.gop.num_frames for s in segments] == gop_lengths
+        assert [len(s.frame_sizes) for s in segments] == gop_lengths
+        want = [encode_gop(frames[s.gop.start_frame:s.gop.end_frame], meta,
+                           qp=30, idr_pic_id=s.gop.index)
+                for s in segments]
+        assert [s.payload for s in segments] == want
 
     def test_low_qp_stays_on_sparse_path(self):
         """Saturated chroma drives intra chroma DC past int8 at QP <= 20
@@ -264,7 +239,7 @@ class TestShardedInterDispatch:
         # 80x48 -> 5x3 = 15 MBs (odd): the GOP flat level vector length
         # is then not a multiple of the 16-coeff sparse block, which the
         # block-granular transfer pack must pad (regression: reshape
-        # crash in _block_sparse_pack for any odd-mb resolution).
+        # crash in the block-sparse pack for any odd-mb resolution).
         from thinvids_tpu.codecs.h264.encoder import encode_gop
 
         n, w, h = 8, 80, 48
@@ -293,8 +268,8 @@ class TestShardedInterDispatch:
             v=np.full((24, 32), 160, np.uint8),
         ) for i in range(n)]
         inter_stream = encode_clip_sharded(frames, meta, qp=27, gop_frames=8)
-        intra_stream = encode_clip_sharded(frames, meta, qp=27, gop_frames=8,
-                                           inter=False)
+        intra_stream = _reference_stream(frames, meta, 27, 8,
+                                         len(jax.devices()))
         decoded = oracle.decode_h264(inter_stream)
         assert len(decoded) == n
         # IDR cost dominates on this cheap-intra clip: 8-frame GOPs cap
@@ -387,39 +362,50 @@ def test_block_sparse_pack2_matches_the_documented_format(case):
 
 class TestHostPipeline:
     """Stage-profiled wave pipeline: slice-granular threaded pack, the
-    zero-copy int16 unflatten, native sparse unpack, per-GOP QP on the
-    intra path, and the config knobs that size it all."""
+    zero-copy int16 unflatten, native sparse unpack, per-GOP QP, and
+    the config knobs that size it all."""
 
-    def test_intra_wave_honors_per_gop_qp(self):
-        # Regression (VERDICT Weak #8): the inter=False dispatch passed
-        # one wave-wide scalar QP to the device, so gop_qp overrides
-        # (rate control) silently encoded every GOP at the base QP.
-        from thinvids_tpu.codecs.h264.encoder import (
-            encode_frame_arrays, pack_slice)
+    def test_wave_honors_per_gop_qp(self):
+        # gop_qp overrides (rate control) reach the device AND the
+        # slice headers: every slice of a GOP states its GOP's QP
+        # against the PPS base, and the pictures are those of a
+        # single-device encode of that GOP at that QP.
+        from thinvids_tpu.codecs.h264.decoder import decode_annexb
+        from thinvids_tpu.codecs.h264.encoder import encode_gop
+        from thinvids_tpu.codecs.h264.headers import (NAL_PPS, NAL_SPS,
+                                                      PPS, SPS,
+                                                      SliceHeader)
+        from thinvids_tpu.io.bits import BitReader, split_annexb
 
         frames = _make_frames(8, seed=21)
         meta = VideoMeta(width=64, height=48, num_frames=8)
-        enc = GopShardEncoder(meta, qp=27, gop_frames=2, inter=False)
+        enc = GopShardEncoder(meta, qp=27, gop_frames=2)
         plan = enc.plan(len(frames))
         qp_map = {g.index: 27 + 3 * (g.index % 3) for g in plan.gops}
         enc.gop_qp = dict(qp_map)
-        got = concat_segments(enc.encode(frames))
-
-        # reference: numpy encode of each frame at ITS GOP's QP, packed
-        # against the same SPS/PPS (init_qp 27 → headers carry the delta)
-        out = []
-        for gop in plan.gops:
-            qp = qp_map[gop.index]
-            for fi, i in enumerate(range(gop.start_frame, gop.end_frame)):
-                padded = frames[i].padded(16)
-                levels, _ = encode_frame_arrays(padded.y, padded.u,
-                                                padded.v, qp)
-                nal = pack_slice(levels, 4, 3, enc.sps, enc.pps, qp,
-                                 idr=True, idr_pic_id=i % 65536)
-                if fi == 0:
-                    nal = enc.sps.to_nal() + enc.pps.to_nal() + nal
-                out.append(nal)
-        assert got == b"".join(out)
+        segments = enc.encode(frames)
+        assert len(segments) == plan.num_gops
+        for seg in segments:
+            qp = qp_map[seg.gop.index]
+            slice_qps = []
+            for ri, t, rbsp in split_annexb(seg.payload):
+                if t == NAL_SPS:
+                    sps = SPS.parse_rbsp(rbsp)
+                elif t == NAL_PPS:
+                    pps = PPS.parse_rbsp(rbsp)
+                elif t in (1, 5):
+                    slice_qps.append(SliceHeader.parse(
+                        BitReader(rbsp), sps, pps, t, ri).qp)
+            assert pps.init_qp == 27
+            assert slice_qps == [qp] * seg.gop.num_frames
+            plain = encode_gop(
+                frames[seg.gop.start_frame:seg.gop.end_frame], meta, qp=qp,
+                idr_pic_id=seg.gop.index)
+            for got, want in zip(decode_annexb(seg.payload).frames,
+                                 decode_annexb(plain).frames, strict=True):
+                for plane in "yuv":
+                    np.testing.assert_array_equal(getattr(got, plane),
+                                                  getattr(want, plane))
 
     def test_threaded_pack_and_int16_paths_bit_identical(self, monkeypatch):
         # Parity matrix over the new pack path: slice pool off/on,
@@ -448,17 +434,6 @@ class TestHostPipeline:
                             lambda *a, **k: False)
         assert stream(8) == base
         assert stream(1) == base
-
-    def test_intra_threaded_pack_bit_identical(self):
-        frames = _make_frames(8, seed=4)
-        meta = VideoMeta(width=64, height=48, num_frames=8)
-
-        def stream(pack_workers):
-            enc = GopShardEncoder(meta, qp=30, gop_frames=2, inter=False,
-                                  pack_workers=pack_workers)
-            return concat_segments(enc.encode(frames))
-
-        assert stream(8) == stream(1)
 
     def test_native_sparse_unpack_matches_python(self):
         from thinvids_tpu import native as native_mod
@@ -583,7 +558,7 @@ class TestHostPipeline:
         from thinvids_tpu.codecs.h264.encoder import (
             gop_slice_thunks_planes, pack_gop_slices_planes)
         from thinvids_tpu.codecs.h264.headers import PPS, SPS
-        from thinvids_tpu.parallel.dispatch import _unflatten_gop
+        from thinvids_tpu.codecs.h264.layout import unflatten_gop
 
         w, h, n = 64, 48, 4
         frames = _make_frames(n, seed=5)
@@ -592,8 +567,8 @@ class TestHostPipeline:
         vs = jnp.asarray(np.stack([f.v for f in frames]))
         mv8, flat = jaxinter.encode_gop_planes(ys, us, vs, jnp.asarray(27),
                                                mbw=4, mbh=3)
-        intra, planes = _unflatten_gop(np.asarray(flat), np.asarray(mv8),
-                                       n, 4, 3)
+        intra, planes = unflatten_gop(np.asarray(flat), np.asarray(mv8),
+                                      n, 4, 3)
         sps, pps = SPS(width=w, height=h), PPS(init_qp=27)
         serial = pack_gop_slices_planes(intra, planes, n, 4, 3, sps, pps,
                                         27, idr_pic_id=0)

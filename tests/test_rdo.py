@@ -308,49 +308,6 @@ class TestShardedPaths:
             for g in range(0, n, gop))
         assert served == direct
 
-    @pytest.mark.slow
-    def test_process_pack_backend_with_features(self):
-        from thinvids_tpu.core.types import concat_segments
-        from thinvids_tpu.parallel.dispatch import GopShardEncoder
-
-        rd = RD_ALL                   # same program as the test above
-        w, h, n, gop = 96, 80, 4, 4
-        frames = make_frames(n, w, h)
-        meta = _meta(w, h, n)
-        thr = GopShardEncoder(meta, qp=27, gop_frames=gop, rd=rd,
-                              pack_backend="thread")
-        prc = GopShardEncoder(meta, qp=27, gop_frames=gop, rd=rd,
-                              pack_backend="process")
-        try:
-            a = concat_segments(thr.encode_waves(thr.stage_waves(frames)))
-            b = concat_segments(prc.encode_waves(prc.stage_waves(frames)))
-        finally:
-            if prc._proc_pool is not None:
-                prc._proc_pool.shutdown()
-        assert a == b
-
-    def test_intra_only_path_ships_modes(self):
-        from thinvids_tpu.core.types import concat_segments
-        from thinvids_tpu.parallel.dispatch import GopShardEncoder
-
-        rd = RdConfig(mode_decision=True)
-        w, h, n = 96, 80, 1
-        frames = make_frames(n, w, h)
-        meta = _meta(w, h, n)
-        enc = GopShardEncoder(meta, qp=27, gop_frames=1, inter=False,
-                              rd=rd)
-        stream = concat_segments(enc.encode_waves(
-            enc.stage_waves(frames)))
-        dec = dec_mod.decode_annexb(stream)
-        assert len(dec.frames) == n
-
-    def test_all_intra_rejects_deblock(self):
-        from thinvids_tpu.parallel.dispatch import GopShardEncoder
-
-        with pytest.raises(ValueError, match="deblock"):
-            GopShardEncoder(_meta(64, 48, 2), qp=27, inter=False,
-                            rd=RdConfig(deblock=True))
-
     def test_rd_resolves_from_settings(self):
         from thinvids_tpu.core.config import (reset_live_settings,
                                               update_live_settings)

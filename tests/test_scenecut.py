@@ -899,7 +899,7 @@ class TestACutAlignedGopStopsAtItsLength:
                 jax.ShapeDtypeStruct(c, jnp.uint8),
                 jax.ShapeDtypeStruct(c, jnp.uint8),
                 jax.ShapeDtypeStruct((G,), jnp.int32)]
-        kw = dict(mbw=W // 16, mbh=H // 16, compact=True)
+        kw = dict(mbw=W // 16, mbh=H // 16)
         if program == "_encode_wave_gop":
             kw["mesh"] = dispatch.default_mesh(jax.devices()[:2])
         fn = functools.partial(getattr(dispatch, program), **kw)
@@ -933,8 +933,7 @@ class TestACutAlignedGopStopsAtItsLength:
                 jax.ShapeDtypeStruct(c, jnp.uint8),
                 jax.ShapeDtypeStruct((1,), jnp.int32)]
         fn = functools.partial(dispatch._encode_gop_single.__wrapped__,
-                               mbw=W // 16, mbh=H // 16, compact=True,
-                               rd=rd)
+                               mbw=W // 16, mbh=H // 16, rd=rd)
         jax.make_jaxpr(fn)(*args, *args[3:] * bounded)
         assert len(traces) == 1
 

@@ -103,11 +103,10 @@ def _lower_sfe(program, p_frame, how="lower", **more):
 
 CASES = {
     "gop_single": (
-        lambda: _lower_gop(dispatch._encode_gop_single, compact=True),
+        lambda: _lower_gop(dispatch._encode_gop_single),
         GOP | SPARSE),
     "wave_gop": (
-        lambda: _lower_gop(dispatch._encode_wave_gop, mesh=_gop_mesh(),
-                           compact=True),
+        lambda: _lower_gop(dispatch._encode_wave_gop, mesh=_gop_mesh()),
         GOP | SPARSE),
     "sfe_intra": (
         lambda: _lower_sfe(dispatch._sfe_intra_step, False),
@@ -124,8 +123,7 @@ CASES = {
     # the RD features: the in-loop filter is a stage of its own (in a
     # band it filters the band's own rows: no halo exchange round it)
     "gop_single_rd": (
-        lambda: _lower_gop(dispatch._encode_gop_single, compact=True,
-                           rd=RD_ON),
+        lambda: _lower_gop(dispatch._encode_gop_single, rd=RD_ON),
         GOP | SPARSE | {"deblock"}),
     "sfe_p_rd": (
         lambda: _lower_sfe(dispatch._sfe_p_step, True, rd=RD_ON),
@@ -133,37 +131,34 @@ CASES = {
     # the P-frame loop with a traced bound: `tvt.layout` still names
     # the `while`, the stages inside it keep their names
     "gop_single_cuts": (
-        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
-                           compact=True),
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True),
         GOP | SPARSE),
     "wave_gop_cuts": (
         lambda: _lower_gop(dispatch._encode_wave_gop, cuts=True,
-                           mesh=_gop_mesh(), compact=True),
+                           mesh=_gop_mesh()),
         GOP | SPARSE),
     "gop_single_rd_cuts": (
-        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
-                           compact=True, rd=RD_ON),
+        lambda: _lower_gop(dispatch._encode_gop_single, cuts=True, rd=RD_ON),
         GOP | SPARSE | {"deblock"}),
     # ISSUE 39, the executable of `serving-1080p-edited`: the bounded
     # loop with all four serving tools inside, on one device and a mesh
     "gop_single_serving_cuts": (
         lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
-                           compact=True, rd=RD_SERVING),
+                           rd=RD_SERVING),
         GOP | SPARSE | {"deblock"}),
     "wave_gop_serving_cuts": (
         lambda: _lower_gop(dispatch._encode_wave_gop, cuts=True,
-                           mesh=_gop_mesh(), compact=True, rd=RD_SERVING),
+                           mesh=_gop_mesh(), rd=RD_SERVING),
         GOP | SPARSE | {"deblock"}),
     # ISSUE 41, the executables of `serving-1080p-camera`: the quarter
     # rows run inside the search's stage in the scan form, the bounded
     # form and the split-frame P step
     "gop_single_serving_quarter": (
-        lambda: _lower_gop(dispatch._encode_gop_single, compact=True,
-                           rd=RD_QUARTER),
+        lambda: _lower_gop(dispatch._encode_gop_single, rd=RD_QUARTER),
         GOP | SPARSE | {"deblock"}),
     "gop_single_serving_quarter_cuts": (
         lambda: _lower_gop(dispatch._encode_gop_single, cuts=True,
-                           compact=True, rd=RD_QUARTER),
+                           rd=RD_QUARTER),
         GOP | SPARSE | {"deblock"}),
     "sfe_p_quarter": (
         lambda: _lower_sfe(dispatch._sfe_p_step, True,
@@ -225,11 +220,10 @@ def test_step_program_ops_are_filed_under_their_stage(case):
 #: the GOP programs whose last output is the GOP's whole int16 levels,
 #: left on the device for the dense fallback (ISSUE 31)
 LEVELS_OUT = {
-    "gop_single": (dispatch._encode_gop_single, lambda: dict(compact=True)),
+    "gop_single": (dispatch._encode_gop_single, dict),
     "wave_gop": (dispatch._encode_wave_gop,
-                 lambda: dict(compact=True, mesh=_gop_mesh())),
-    "gop_single_rd": (dispatch._encode_gop_single,
-                      lambda: dict(compact=True, rd=RD_ON)),
+                 lambda: dict(mesh=_gop_mesh())),
+    "gop_single_rd": (dispatch._encode_gop_single, lambda: dict(rd=RD_ON)),
 }
 
 
@@ -322,14 +316,13 @@ def test_no_scatter_under_the_pack_stage(case):
 #: the split-frame P step
 RESIDUAL = {
     "gop_single": lambda: _lower_gop(
-        dispatch._encode_gop_single, how="trace", compact=True),
+        dispatch._encode_gop_single, how="trace"),
     "gop_single_cuts": lambda: _lower_gop(
-        dispatch._encode_gop_single, cuts=True, how="trace", compact=True),
+        dispatch._encode_gop_single, cuts=True, how="trace"),
     "gop_single_serving": lambda: _lower_gop(
-        dispatch._encode_gop_single, how="trace", compact=True,
-        rd=RD_SERVING),
+        dispatch._encode_gop_single, how="trace", rd=RD_SERVING),
     "gop_single_serving_cuts": lambda: _lower_gop(
-        dispatch._encode_gop_single, cuts=True, how="trace", compact=True,
+        dispatch._encode_gop_single, cuts=True, how="trace",
         rd=RD_SERVING),
     "sfe_p": lambda: _lower_sfe(dispatch._sfe_p_step, True, how="trace"),
     "sfe_p_rd": lambda: _lower_sfe(dispatch._sfe_p_step, True, how="trace",

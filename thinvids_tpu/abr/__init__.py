@@ -15,8 +15,7 @@ Three pieces, split along the jax boundary:
   executors drive. jax-free at module scope.
 - :mod:`.hls` — closed-GOP-aligned fMP4 segmenter + media/master
   playlist writer + conformance lint. jax-free entirely, so packaging
-  runs on worker/sidecar processes that never load a device backend
-  (same rule as parallel/packproc.py).
+  runs on worker/sidecar processes that never load a device backend.
 
 This package intentionally has NO module-scope imports: `ladder` and
 `hls` must stay importable on jax-free processes, and importing `scale`

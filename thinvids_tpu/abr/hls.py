@@ -1,6 +1,6 @@
 """HLS packaging: closed-GOP-aligned fMP4 segments + playlists.
 
-jax-FREE by contract (grep-guarded, like parallel/packproc.py): the
+jax-FREE by contract (analysis/manifest.py `jax_free`): the
 packager consumes the entropy-packed Annex-B segments the encoders
 already produced, so it can run on the coordinator's control plane, on
 a worker sidecar, or in a test process that never loads a device

@@ -18,8 +18,8 @@ fanning into that rung's own encoder. collect_wave returns one
 so the executor's wave retry / halt / progress machinery applies to
 the whole rendition set at GOP granularity.
 
-This module stays jax-free at MODULE scope (grep-guarded, like
-parallel/packproc.py): planning runs on the coordinator's control
+This module stays jax-free at MODULE scope (analysis/manifest.py
+`jax_free`): planning runs on the coordinator's control
 plane and the HLS side never needs a device backend; the jax-touching
 imports (dispatch, scale, rc) live inside the functions that need them.
 """

@@ -6,7 +6,8 @@ and extends it to the whole env/settings surface.
 
 TVT-C001  a DEFAULT_SETTINGS key with no reader outside core/config.py
           (attribute access, string reference, or TVT_ env mention —
-          dashboards' .html files count as readers).
+          dashboards' .html files count as readers; the reported
+          constants of core/config._PINNED have none by design).
 TVT-C002  an env knob that either doesn't live in the TVT_* namespace
           (foreign platform prefixes exempt) or is a TVT_* name that
           is neither a registered settings key (TVT_<KEY>) nor one of
@@ -57,8 +58,12 @@ def check_dead_keys(tree: SourceTree, manifest: Manifest,
         consts |= string_constants(tree.tree(mod))
     html = _html_text(tree)
     findings = []
+    from ..core.config import _PINNED
+
     for key in sorted(defaults):
         env = "TVT_" + key.upper()
+        if key in _PINNED:      # a reported constant: no reader by design
+            continue
         if key in attrs or key in consts or env in consts:
             continue
         # substring matches keep the original grep-guard semantics:

@@ -200,7 +200,6 @@ class Manifest:
         "thinvids_tpu.abr.hls",
         "thinvids_tpu.abr.ladder",
         "thinvids_tpu.live.packager",
-        "thinvids_tpu.parallel.packproc",
         "thinvids_tpu.codecs.h264.layout",
         "thinvids_tpu.io",              # whole package
         "thinvids_tpu.ingest.tail",

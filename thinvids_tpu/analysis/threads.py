@@ -1,8 +1,8 @@
 """Pass 3 — thread-safety audit.
 
-The farm spans five concurrency domains (staging threads, per-encoder
-pack/fetch pools, spawn-context pack sidecars, per-shard worker
-daemons, and the lease/packager/HTTP machinery); this pass inventories
+The farm spans four concurrency domains (staging threads, per-encoder
+pack/fetch pools, per-shard worker daemons, and the
+lease/packager/HTTP machinery); this pass inventories
 the thread entrypoints and flags the shared mutable state they can
 race on:
 

@@ -925,8 +925,8 @@ class ApiServer:
         # Host encode-stage breakdown (decode / stage / dispatch /
         # device wait / fetch / dense_retry / sparse unpack / unflatten
         # / pack / concat wall-clock ms) plus the boundary counters
-        # (dense_fallback_waves, d2h_bytes, fetch_shards,
-        # proc_pack_gops — parallel/dispatch.STAGE_COUNTERS) for
+        # (dense_fallback_waves, d2h_bytes, fetch_shards —
+        # parallel/dispatch.STAGE_COUNTERS) for
         # every live encoder in this process. Read through sys.modules:
         # if no encoder ever ran here (e.g. a pure-manager node), don't
         # drag jax in just to report an empty dict.
@@ -938,8 +938,9 @@ class ApiServer:
         disp = _sys.modules.get("thinvids_tpu.parallel.dispatch")
         stage_ms = getattr(disp, "stage_snapshot", None)
         out["stage_ms"] = stage_ms() if stage_ms is not None else {}
-        # SFE per-frame latency percentiles — the frame_done_t data,
-        # summarized for operators (dashboard SFE line + this snapshot)
+        # SFE per-frame latency percentiles (the gaps between frames'
+        # bitstream-ready times), summarized for operators (dashboard
+        # SFE line + this snapshot)
         sfe_lat = getattr(disp, "frame_latency_percentiles", None)
         out["sfe_latency_ms"] = sfe_lat() if sfe_lat is not None else {}
         # which motion search this process traced: "pallas" (the TPU

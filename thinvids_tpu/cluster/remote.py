@@ -2149,11 +2149,9 @@ def encode_shard(desc: Mapping[str, Any], frames, mesh=None, tracer=None,
     decode work and resident memory per claim instead of O(clip).
 
     The encoder is built from this process's settings snapshot, so a
-    worker inherits the full collect path — compact device→host level
-    transfer (TVT_COMPACT_TRANSFER), per-shard concurrent fetch, and
-    the pack backend (TVT_PACK_BACKEND) — from its own environment;
-    output stays bit-identical to the coordinator's plan regardless of
-    which transfer/pack path each worker takes (parity-tested).
+    worker sizes the collect path (pack_workers, pipeline_window,
+    decode_ahead) from its own environment; output stays bit-identical
+    to the coordinator's plan whatever those sizes are (parity-tested).
 
     `tracer` (an obs/trace span sink — the daemon's SpanBuffer) binds
     to the encoder's stage profile so the worker's decode/dispatch/

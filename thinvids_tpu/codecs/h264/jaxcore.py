@@ -648,67 +648,6 @@ _BLOCK = 16
 _BLOCK_BUDGET_DIV = 4
 
 
-@stage("pack")
-def _block_sparse_pack(flat, budget_div: int = _BLOCK_BUDGET_DIV):
-    """Compact a flat int16 level vector on device at BLOCK granularity.
-
-    The element-granular `_sparse_pack` needs cumsums and scatters over
-    the full coefficient vector; at 16-coeff-block granularity the
-    position computation shrinks 16x and the values move by a gather
-    of whole blocks. No served program calls this one-tier form (the
-    GOP and split-frame paths pack with `_block_sparse_pack2`), so it
-    keeps the scatters that PR 25 took out of that function, and no
-    chip run has timed it (PERF.md §7).
-
-    Returns (nblk, n_esc, bitmap, payload, esc_pos, esc_val):
-    - bitmap: 1 bit per 16-coeff block (any-nonzero), L/128 bytes;
-    - payload: the nonzero blocks' 16 coeffs each, int8-clipped, in
-      block order, in a fixed (L/16//budget_div, 16) buffer (tail
-      zeroed);
-    - esc_pos/esc_val: payload-flat positions + true values of coeffs
-      exceeding int8, in a fixed _SPARSE_ESCAPES buffer.
-    Caller must fall back to a dense fetch iff nblk > budget or
-    n_esc > _SPARSE_ESCAPES (see `block_sparse_fits`).
-    """
-    L = flat.shape[0]
-    NB = -(-L // _BLOCK)
-    pad = NB * _BLOCK - L
-    if pad:        # odd-mb-count resolutions: L need not divide 16
-        flat = jnp.concatenate([flat, jnp.zeros(pad, flat.dtype)])
-    budget = NB // budget_div
-    blocks = flat.reshape(NB, _BLOCK)
-    bmask = jnp.any(blocks != 0, axis=1)
-    nblk = jnp.sum(bmask.astype(jnp.int32))
-    pos = jnp.cumsum(bmask.astype(jnp.int32)) - 1
-    idx = jnp.where(bmask, pos, budget)
-    blist = jnp.zeros(budget + 1, jnp.int32).at[idx].set(
-        jnp.arange(NB, dtype=jnp.int32), mode="drop")[:budget]
-    gathered = jnp.take(blocks, blist, axis=0)           # (budget, 16)
-    live = (jnp.arange(budget, dtype=jnp.int32) < nblk)[:, None]
-    gathered = jnp.where(live, gathered, 0)
-    payload = jnp.clip(gathered, -_I8_MAX, _I8_MAX).astype(jnp.int8)
-    bitmap = jnp.sum(
-        _pad8(bmask).reshape(-1, 8).astype(jnp.uint8) * _BIT_WEIGHTS,
-        axis=-1).astype(jnp.uint8)
-    gflat = gathered.reshape(-1)
-    esc_mask = jnp.abs(gflat) > _I8_MAX
-    n_esc = jnp.sum(esc_mask.astype(jnp.int32))
-    epos = jnp.cumsum(esc_mask.astype(jnp.int32)) - 1
-    eidx = jnp.where(esc_mask, epos, _SPARSE_ESCAPES)
-    esc_pos = jnp.zeros(_SPARSE_ESCAPES + 1, jnp.int32).at[eidx].set(
-        jnp.arange(gflat.shape[0], dtype=jnp.int32), mode="drop"
-    )[:_SPARSE_ESCAPES]
-    esc_val = jnp.zeros(_SPARSE_ESCAPES + 1, jnp.int32).at[eidx].set(
-        gflat.astype(jnp.int32), mode="drop")[:_SPARSE_ESCAPES]
-    return nblk, n_esc, bitmap, payload, esc_pos, esc_val
-
-
-def block_sparse_fits(nblk: int, n_esc: int, L: int,
-                      budget_div: int = _BLOCK_BUDGET_DIV) -> bool:
-    return (int(nblk) <= (-(-L // _BLOCK)) // budget_div
-            and int(n_esc) <= _SPARSE_ESCAPES)
-
-
 def _compact_left(shift, *streams):
     """Order-preserving stream compaction with static addressing.
 
@@ -753,8 +692,9 @@ _VAL_BUDGET_DIV = 24
 @stage("pack")
 def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
                         val_div: int = _VAL_BUDGET_DIV):
-    """Two-tier device compaction: block-granular gather (tier 1, see
-    _block_sparse_pack) + within-block value compaction (tier 2).
+    """Two-tier device compaction: block-granular gather of the
+    16-coeff blocks with a level (tier 1) + within-block value
+    compaction (tier 2).
 
     The device→host transfer is what this pack shrinks (its rate on a
     directly attached chip is not measured); tier 1 alone ships 16
@@ -847,8 +787,7 @@ def _block_sparse_unpack2(nblk: int, nval: int, bitmap: np.ndarray,
                           bmask16: np.ndarray, vals: np.ndarray,
                           L: int) -> np.ndarray:
     """Host inverse of _block_sparse_pack2 → flat int16 levels (the
-    single numpy implementation lives in the jax-free layout module so
-    the process pack sidecars can share it)."""
+    single numpy implementation lives in the jax-free layout module)."""
     from .layout import block_sparse_unpack2_host
 
     return block_sparse_unpack2_host(nblk, nval, bitmap, bmask16, vals, L)
@@ -892,26 +831,6 @@ def _compact_stream(nblk, nval, bitmap, bmask16, vals):
         payload, vals_u8, ((nb8 + 2 * nblk).astype(jnp.int32),))
     used = (nb8 + 2 * nblk + nval).astype(jnp.int32)
     return used, payload
-
-
-def _block_sparse_unpack(nblk: int, n_esc: int, bitmap: np.ndarray,
-                         payload: np.ndarray, esc_pos: np.ndarray,
-                         esc_val: np.ndarray, L: int) -> np.ndarray:
-    """Host inverse of _block_sparse_pack → flat int16 levels (CAVLC
-    levels fit int16 at every legal qp; int16 halves the memset +
-    scatter traffic on the 1-core host)."""
-    NB = -(-L // _BLOCK)
-    bm = np.unpackbits(bitmap)[:NB].astype(bool)
-    pay = payload[:nblk].astype(np.int16)
-    if n_esc:
-        ep = esc_pos[:n_esc]
-        ok = ep < nblk * _BLOCK
-        flatpay = pay.reshape(-1)
-        flatpay[ep[ok]] = esc_val[:n_esc][ok].astype(np.int16)
-        pay = flatpay.reshape(nblk, _BLOCK)
-    out = np.zeros((NB, _BLOCK), np.int16)
-    out[bm] = pay
-    return out.reshape(-1)[:L]
 
 
 def _pad8(mask):

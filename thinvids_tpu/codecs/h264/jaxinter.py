@@ -437,9 +437,9 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
 # flat vector is (F-1) * nmb * _P_FLAT_MB int16 values laid out
 # struct-of-arrays: all luma coeff planes, then u DC, v DC (hadamard
 # domain), then u AC, v AC coeff planes (DC positions zeroed). The
-# values live in the jax-free layout module (the host inverses and the
-# process pack sidecars read them without dragging jax in); re-exported
-# here next to the encode that emits the layout.
+# values live in the jax-free layout module (the host inverses read
+# them without dragging jax in); re-exported here next to the encode
+# that emits the layout.
 from .layout import _INTRA_FLAT_MB, _P_FLAT_MB  # noqa: E402
 
 
@@ -466,7 +466,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
       | u DC (F-1, nmb, 4) | v DC (F-1, nmb, 4)
       | u AC plane (F-1, H/2, W/2) | v AC plane (F-1, H/2, W/2)
       | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only ]
-    The host inverse is parallel/dispatch._unflatten_gop.
+    The host inverse is codecs/h264/layout.unflatten_gop.
     """
     _check_mv8(rd)
     qp, qpc, y0, u0, v0 = _gop_head(ys, us, vs, qp)

@@ -7,10 +7,9 @@ segments → per-slice views), and the COMPACT payload format the device
 compaction stage (jaxcore._compact_stream) ships over the device→host
 link.
 
-Deliberately jax-free: the process-based pack sidecars
-(parallel/packproc.py) import it in child processes that must never
-initialize a backend, and the numpy implementations double as the
-no-compiler parity references for the native entries.
+Deliberately jax-free: tools/fuzz_native.py and the native packer's
+tests import it without a backend, and the numpy implementations double
+as the no-compiler parity references for the native entries.
 
 Compact payload format (all offsets in bytes, NB = ceil(L / 16) sparse
 blocks, nb8 = ceil(NB / 8)):
@@ -40,15 +39,6 @@ _INTRA_FLAT_MB = 384
 
 #: 16-coeff granularity of the block-sparse transfer tiers
 SPARSE_BLOCK = 16
-
-
-def rest_len(num_frames: int, mbw: int, mbh: int) -> int:
-    """Coefficient count of the SPARSE remainder of one GOP's flat
-    vector: the full layout minus the dense-shipped hadamard DC prefix
-    (luma DC nmb*16 + chroma DC nmb*8 — see dispatch._per_gop_sparse)."""
-    nmb = mbw * mbh
-    return (nmb * (_INTRA_FLAT_MB - 24)
-            + (num_frames - 1) * nmb * _P_FLAT_MB)
 
 
 # ---- compact payload parsing ----------------------------------------------
@@ -136,9 +126,7 @@ def unpack_compact_auto(payload: np.ndarray, nblk: int, nval: int,
                         L: int) -> np.ndarray:
     """Two-tier compact unpack: the native single-pass parse+scatter
     when a compiler exists, :func:`unpack_compact_host` otherwise
-    (identical output — tested). The ONE dispatcher shared by the
-    in-process collect path (parallel/dispatch) and the pack sidecars
-    (parallel/packproc)."""
+    (identical output — tested)."""
     from ... import native as native_mod
 
     if native_mod.available():

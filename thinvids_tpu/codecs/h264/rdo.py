@@ -30,8 +30,8 @@ specialization, never a traced branch:
   packers and the P_Skip inference is in QUARTER-sample units
   (`mv_per_pel` 4); with "half" in half-sample units (2).
 
-This module is deliberately jax-free: the pack sidecars and the host
-packers import it without initializing a device backend.
+This module is deliberately jax-free: the host packers import it
+without initializing a device backend.
 """
 
 from __future__ import annotations

@@ -47,7 +47,7 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     "pipeline_worker_count": 8,      # logical pipeline slots (devices or hosts)
     "drain_ratio": 0.75,             # admit next job at >= this encode drain
     "min_idle_workers": 4,
-    "reject_av1": False,             # we ENCODE AV1 (ref rejected it as input)
+    "reject_av1": False,             # AV1 input is admitted (ref rejected it)
     "large_file_gb": 15.0,
     "large_file_behavior": "direct",  # reject | direct | nfs
     # segmentation / sharding
@@ -154,16 +154,14 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     # the window to the HBM / host memory the waves' outputs may pin.
     "pack_workers": 0,
     "pipeline_window": 4,
-    # device→host boundary (parallel/dispatch.py): compact_transfer
-    # folds each GOP's sparse level streams into one byte payload ON
-    # DEVICE so the bulk fetch moves only the used bytes
-    # (TVT_COMPACT_TRANSFER=0 restores the three-array sparse2
-    # transfer — the validated fallback, bit-identical output);
-    # pack_backend=process opts into shared-memory pack sidecar
-    # processes (TVT_PACK_BACKEND) that run unpack+pack outside the
-    # coordinator's GIL — the 4K host-pack ceiling.
+    # Reported constants (_PINNED), not settings: the wave pipeline
+    # has one wire and one pack backend and nothing in the program
+    # reads these two. GET /settings goes on stating them because
+    # benchmark/configs/*.json `expect_settings` compare them; no
+    # environment variable and no update moves them. They go with
+    # ROADMAP.md Queue 3, D1 (i).
     "compact_transfer": True,
-    "pack_backend": "thread",        # thread | process
+    "pack_backend": "thread",
     # split-frame encoding (parallel/dispatch.SfeShardEncoder): shard
     # ONE frame across the mesh as horizontal MB-row bands, each coded
     # as its own H.264 slice — the single-stream latency mode.
@@ -336,6 +334,10 @@ def _clean_rung_spec(raw: Any) -> str:
         or DEFAULT_SETTINGS["ladder_rungs"]
 
 
+#: keys whose one admitted value is the default (DEFAULT_SETTINGS says why)
+_PINNED = {key: DEFAULT_SETTINGS[key]
+           for key in ("compact_transfer", "pack_backend")}
+
 # Validation clamps applied on live updates, mirroring the reference's
 # POST /settings clamping (/root/reference/manager/app.py:1790-1916).
 _CLAMPS: dict[str, Callable[[Any], Any]] = {
@@ -388,9 +390,6 @@ _CLAMPS: dict[str, Callable[[Any], Any]] = {
     "trace_ring_spans": lambda v: min(65536, max(256, as_int(v, 4096))),
     "pack_workers": lambda v: min(256, max(0, as_int(v, 0))),
     "pipeline_window": lambda v: min(64, max(1, as_int(v, 4))),
-    "pack_backend": lambda v: str(v)
-    if str(v) in ("thread", "process")
-    else "thread",
     "sfe_bands": lambda v: min(64, max(0, as_int(v, 0))),
     # multiple of 16 (band/ext-plane MB alignment), floor 16, cap 128
     "sfe_halo_rows": lambda v: min(128, max(16, (as_int(v, 32) // 16) * 16)),
@@ -460,6 +459,8 @@ def _clean_tenant_shares(raw: Any) -> str:
 def _validate_setting(key: str, raw: Any) -> Any:
     """Clamp-or-coerce one setting value; shared by the live tier and the
     per-job overlay so both validate identically."""
+    if key in _PINNED:
+        return _PINNED[key]
     clamp = _CLAMPS.get(key)
     return clamp(raw) if clamp else _coerce_like(DEFAULT_SETTINGS[key], raw)
 
@@ -504,7 +505,7 @@ class _LiveStore:
             merged = dict(DEFAULT_SETTINGS)
             for key, default in DEFAULT_SETTINGS.items():
                 env = os.environ.get(_ENV_PREFIX + key.upper())
-                if env is not None:
+                if env is not None and key not in _PINNED:
                     merged[key] = _coerce_like(default, env)
             merged.update(self._live)
             snap = Settings(values=merged)

@@ -116,8 +116,8 @@ def _build_and_load() -> ctypes.CDLL:
         try:
             if not os.path.exists(_SO):
                 os.makedirs(_BUILD_DIR, exist_ok=True)
-                # pid-unique tmp: concurrent builders (spawned pack
-                # sidecars racing a fresh checkout) each compile their
+                # pid-unique tmp: concurrent builders (daemons and
+                # tests racing a fresh checkout) each compile their
                 # own file and atomically replace — last wins, every
                 # one valid. A shared tmp let builder B keep writing
                 # into the inode builder A had already renamed to _SO.
@@ -218,7 +218,7 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
     """Pack one I-slice (header bits + MB layer) and return the EBSP payload.
 
     When all four level arrays arrive as int16 (the flat transfer layout's
-    views, parallel/dispatch._unflatten_gop) they go to the zero-copy
+    views, codecs/h264/layout.unflatten_gop) they go to the zero-copy
     `cavlc_pack_islice16` entry; anything else is widened to int32 and
     packed through the original entry. Identical bits either way.
     `qp_delta` (per-MB qp offsets vs the slice qp, perceptual AQ) emits

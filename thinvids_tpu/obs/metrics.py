@@ -313,9 +313,6 @@ STAGE_COUNTER_TOTALS = {
     "fetch_shards": REGISTRY.counter(
         "tvt_fetch_shards_total",
         "per-shard concurrent D2H transfers issued"),
-    "proc_pack_gops": REGISTRY.counter(
-        "tvt_proc_pack_gops_total",
-        "GOPs handed to the process pack sidecars"),
     "sfe_frames": REGISTRY.counter(
         "tvt_sfe_frames_total",
         "frames through the split-frame per-frame collect path"),

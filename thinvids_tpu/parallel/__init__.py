@@ -6,10 +6,9 @@ to worker nodes over a task queue (/root/reference/worker/tasks.py:597-609,
 devices of a `jax.sharding.Mesh` with `shard_map`, and encoded segments are
 re-assembled in index order (the stitcher analog, tasks.py:2047-2069).
 
-Imports are lazy: the process-based pack sidecars (packproc.py) live in
-this package but run in spawned children that must import it WITHOUT
-dragging dispatch's jax dependency in (initializing a device backend in
-every pack worker would be fatal on real hardware).
+Imports are lazy: the coordinator's control plane imports `planner`
+from this package (cluster/executor, cluster/remote) WITHOUT dragging
+dispatch's jax dependency in.
 """
 
 __all__ = ["plan_segments", "GopShardEncoder", "encode_clip_sharded"]

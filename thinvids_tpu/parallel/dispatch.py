@@ -3,12 +3,11 @@
 The reference's dispatch loop enqueued one encode task per segment onto a
 Redis-backed queue consumed by worker nodes (/root/reference/worker/
 tasks.py:1167-1281); here a wave of GOPs is one SPMD program over the mesh:
-frames live HBM-resident per device, the jitted intra compute runs a
-sequential `lax.map` over the GOP's frames (the carry will hold reference
-frames once P-frames land), and the quantized levels return to host for
-entropy packing. Encoded segments concat in index order; bit-identity with
-the single-device encode is asserted by tests/test_parallel.py on an
-8-device virtual mesh.
+frames live HBM-resident per device, each device encodes its GOP as an
+IDR frame and a chain of P frames (codecs/h264/jaxinter), and the
+quantized levels return to host for entropy packing. Encoded segments
+concat in index order; bit-identity with the single-device encode is
+asserted by tests/test_parallel.py on an 8-device virtual mesh.
 
 A wave is the pipeline's unit: ONE GOP per mesh device (`gops_per_wave`
 1), one program shape per clip (every GOP is staged to the plan's
@@ -43,20 +42,14 @@ waves (TVT_DECODE_AHEAD) ahead of dispatch, overlapping source decode
 with device compute: wave n+1's inputs are on the device when wave n
 ends.
 
-The device→host boundary itself is compacted and parallelized three
-ways (BENCH r04→r05 showed every device-side win dying here):
-`compact_transfer` (TVT_COMPACT_TRANSFER, default on) adds a device
-stage that packs the two-tier sparse streams into ONE contiguous byte
-payload per GOP (jaxcore._compact_stream; format in codecs/h264/
-layout.py) so the bulk fetch moves `used` bytes instead of three
-budget-padded arrays; collect_wave fetches with one transfer thread
-per device shard so the per-transfer latency overlaps across the mesh
-instead of serializing; and `pack_backend=process`
-(TVT_PACK_BACKEND) opts into shared-memory pack sidecars (packproc.py)
-that run unpack+unflatten+pack outside this process's GIL. Every path
-is bit-identical to the original sparse2 transfer (parity-tested), and
-the old path stays live as the validated fallback (compact_transfer
-off, thread backend, dense wave fallback).
+The device→host boundary has ONE wire: the wave's program packs each
+GOP's two-tier sparse streams into one contiguous byte payload
+(jaxcore._compact_stream; format in codecs/h264/layout.py) and the bulk
+fetch moves its `used` prefix, one transfer thread per device shard on
+a mesh so the per-transfer latency overlaps instead of serializing. A
+wave whose levels leave the sparse budgets (grainy footage) ships the
+levels its program already computed, whole, as 32-bit words
+(_levels_as_words) — the one fallback; nothing is encoded twice.
 
 Beside the GOP-wave encoder lives the split-frame mode
 (:class:`SfeShardEncoder`, `sfe_bands`/TVT_SFE_BANDS): ONE frame
@@ -83,7 +76,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from collections import deque
 
-from ..core.config import as_bool, get_settings
+from ..core.config import get_settings
 from ..core.log import get_logging
 # jax-free observability layer: the process-cumulative stage totals
 # bridge into the Prometheus registry, and a bound span recorder (the
@@ -99,8 +92,8 @@ from ..codecs.h264.encoder import (FrameLevels, _mode_policy,
 from ..codecs.h264.headers import PPS, SPS
 from ..codecs.h264.rdo import RD_OFF, RdConfig, rd_from_settings
 from ..codecs.h264.stages import stage
-# Transfer-layout contract (jax-free module shared with the process
-# pack sidecars): per-MB flat sizes + the zero-copy host unflattens.
+# Transfer-layout contract (jax-free module): per-MB flat sizes + the
+# zero-copy host unflattens.
 from ..codecs.h264.layout import _INTRA_FLAT_MB as _INTRA_MB
 from ..codecs.h264.layout import (_P_FLAT_MB, unflatten_gop,
                                   unflatten_gop_parts, unflatten_intra,
@@ -152,14 +145,13 @@ STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
 #: planes' share of h2d_bytes, each byte written once), d2h_bytes
 #: (device→host bytes fetched), fetch_shards (per-shard concurrent
 #: fetch transfers issued; 0 = every fetch was one blocking
-#: device_get), proc_pack_gops (GOPs handed to the
-#: pack_backend=process sidecars), sfe_frames (frames through the
-#: split-frame per-frame collect), sparse_{blocks,values}_{used,budget}
-#: (blocks with a level and non-zero values counted on the device,
-#: against what the sparse transfer buffers hold, summed over every GOP
-#: or split-frame band collected, all-intra waves apart; used / budget
-#: over 1 means the wave went dense, and the value count is then a
-#: lower bound: the device counts values in the blocks it kept),
+#: device_get), sfe_frames (frames through the split-frame per-frame
+#: collect), sparse_{blocks,values}_{used,budget} (blocks with a level
+#: and non-zero values counted on the device, against what the sparse
+#: transfer buffers hold, summed over every GOP or split-frame band
+#: collected; used / budget over 1 means the wave went dense, and the
+#: value count is then a lower bound: the device counts values in the
+#: blocks it kept),
 #: scene_cuts / scene_cuts_suppressed (cuts that began a GOP / came too
 #: soon after one), wave_frames / pad_frames / pad_frames_skipped (GOP
 #: waves' frames staged / repeats among them / repeats never encoded),
@@ -168,8 +160,7 @@ STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
 #: under subpel="half"; count_vectors)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes",
                   "stage_copy_bytes", "d2h_bytes", "fetch_shards",
-                  "proc_pack_gops", "sfe_frames",
-                  "sparse_blocks_used", "sparse_blocks_budget",
+                  "sfe_frames", "sparse_blocks_used", "sparse_blocks_budget",
                   "sparse_values_used", "sparse_values_budget",
                   "scene_cuts", "scene_cuts_suppressed", "wave_frames",
                   "pad_frames", "pad_frames_skipped", "mvs_coded",
@@ -293,10 +284,9 @@ def stage_snapshot() -> dict:
 
 #: process-cumulative SFE per-frame latency samples (ms) — the gaps
 #: between consecutive frames' bitstream-ready times across every
-#: SfeShardEncoder that ran here. The data frame_done_t always
-#: recorded, finally summarized: /metrics_snapshot and the dashboard
-#: surface p50/p99 from this ring, and each sample also observes the
-#: tvt_sfe_frame_latency_seconds histogram.
+#: SfeShardEncoder that ran here (_note_frame_done). /metrics_snapshot
+#: and the dashboard surface p50/p99 from this ring, and each sample
+#: also observes the tvt_sfe_frame_latency_seconds histogram.
 _SFE_LAT_MS: deque = deque(maxlen=4096)
 #: guards ring iteration vs the collector threads' appends (a deque
 #: mutated mid-iteration raises RuntimeError — the snapshot endpoint
@@ -505,35 +495,13 @@ def background_stage(staged_waves, decode_ahead: int = 2):
     return drain()
 
 
-def _sparse_unpack2_host(nblk: int, nval: int, bitmap, bmask16, vals,
-                         L: int) -> np.ndarray:
-    """Two-tier sparse unpack: native scatter when a compiler exists,
-    jaxcore's numpy reference otherwise (identical output — tested)."""
-    from .. import native as native_mod
-
-    if native_mod.available():
-        return native_mod.block_sparse_unpack2(nblk, nval, bitmap,
-                                               bmask16, vals, L)
-    return jaxcore._block_sparse_unpack2(nblk, nval, bitmap, bmask16,
-                                         vals, L)
-
-
-def _flat_levels(y, u, v, qp, mbw, mbh, rd=RD_OFF):
-    out = jaxcore._intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
-    ldc, lac, cdc, cac = out[:4]
-    with stage("layout"):
-        parts = [ldc.reshape(-1), lac.reshape(-1), cdc.reshape(-1),
-                 cac.reshape(-1)]
-        if rd.ships_modes:
-            parts.append(jaxcore._mode_tail(out[7], out[8], out[9])
-                         .astype(jnp.int32))
-        return jnp.concatenate(parts)
-
-
-def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
-                    rd=RD_OFF, n_frames=None):
-    """(F, H, W) GOP → (mv int8, dense intra-DC segments, two-tier
-    sparse levels for the rest); `n_frames` as encode_gop_planes'.
+def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, rd=RD_OFF,
+                    n_frames=None):
+    """(F, H, W) GOP → (mv8, dense, nblk, nval, n_esc, used, payload,
+    flat): mv int8, the dense intra-DC segments, and the two-tier
+    sparse levels of the rest folded into one contiguous byte payload
+    (jaxcore._compact_stream) with its counts; `n_frames` as
+    encode_gop_planes'.
 
     BOTH intra hadamard DC segments — luma DC (nmb * 16) and chroma DC
     (nmb * 8), ~390 KB combined at 1080p — ship DENSE: hadamard DC
@@ -544,12 +512,7 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
     fallback, so low-QP encodes would otherwise fall permanently into
     the slow path (ADVICE round 5).
 
-    With `compact` the three sparse streams additionally fold into one
-    contiguous byte payload on device (jaxcore._compact_stream), so the
-    output is (mv8, dense, nblk, nval, n_esc, used, payload) — 7 arrays
-    — instead of the 8-array (…, bitmap, bmask16, vals) layout.
-
-    The LAST output, after those, is `flat` itself: the GOP's whole
+    The LAST output is `flat` itself: the GOP's whole
     int16 levels, as encode_gop_planes built them. It stays on the
     device (dispatch_wave starts no copy of it) and start_fetch either
     drops it — the budgets held, which is every wave of ordinary
@@ -576,8 +539,6 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
         dense = jnp.concatenate(dense_parts)
     nblk, nval, n_esc, bitmap, bmask16, vals = \
         jaxcore._block_sparse_pack2(rest)
-    if not compact:
-        return (mv8, dense, nblk, nval, n_esc, bitmap, bmask16, vals, flat)
     used, payload = jaxcore._compact_stream(nblk, nval, bitmap, bmask16,
                                             vals)
     return (mv8, dense, nblk, nval, n_esc, used, payload, flat)
@@ -585,49 +546,39 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, compact: bool = False,
 
 @stage("layout")
 def _map_gops(one, xs):
-    """`lax.map` over a device's GOPs (or an all-intra GOP's frames).
-    The scope names the loop itself; the stages inside `one` keep
-    their own names (codecs/h264/stages.py)."""
+    """`lax.map` over a device's GOPs. The scope names the loop
+    itself; the stages inside `one` keep their own names
+    (codecs/h264/stages.py)."""
     return jax.lax.map(one, xs)
 
 
-# Zero-copy unflatten views (flat transfer segments → slice arrays) —
-# the implementations live in the jax-free layout module so the process
-# pack sidecars share them; these aliases keep this module's historical
-# names for callers and tests.
-_unflatten_gop = unflatten_gop
-_unflatten_gop_parts = unflatten_gop_parts
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("mbw", "mbh", "mesh", "compact", "rd"))
+@functools.partial(jax.jit, static_argnames=("mbw", "mbh", "mesh", "rd"))
 def _encode_wave_gop(ys, us, vs, qps, n_frames=None, *, mbw: int, mbh: int,
-                     mesh: Mesh, compact: bool = False, rd=RD_OFF):
+                     mesh: Mesh, rd=RD_OFF):
     """ys: (G, F, H, W) uint8 sharded over `gop`, G = devices x k; each
     device sequentially encodes its k GOPs (IDR + P, jaxinter) at its
     per-GOP QP (qps: (G,) int32, the rate-control hook) and sparse-packs
-    the plane-layout levels (`compact`: see _per_gop_sparse). `n_frames`
-    as _encode_gop_single's: each device's loop has its own GOP's bound."""
+    the plane-layout levels (_per_gop_sparse). `n_frames` as
+    _encode_gop_single's: each device's loop has its own GOP's bound."""
 
     def per_dev(y_g, u_g, v_g, qp_g, n_g):
         def one(args):
             y, u, v, qp, n = args
-            return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
-                                   rd=rd, n_frames=n)
+            return _per_gop_sparse(y, u, v, qp, mbw, mbh, rd=rd,
+                                   n_frames=n)
         return _map_gops(one, (y_g, u_g, v_g, qp_g, n_g))
 
     shard = shard_map(
         per_dev, mesh=mesh,
         in_specs=(P("gop"),) * 5,        # n_frames None: no leaf, no spec
-        out_specs=(P("gop"),) * (8 if compact else 9),
+        out_specs=(P("gop"),) * 8,
     )
     return shard(ys, us, vs, qps, n_frames)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("mbw", "mbh", "compact", "rd"))
+@functools.partial(jax.jit, static_argnames=("mbw", "mbh", "rd"))
 def _encode_gop_single(ys, us, vs, qps, n_frames=None, *, mbw: int,
-                       mbh: int, compact: bool = False, rd=RD_OFF):
+                       mbh: int, rd=RD_OFF):
     """Single-device wave: the same per-GOP program WITHOUT the
     shard_map wrapper, which on one chip buys nothing and cost a lot
     under an older jax (compile 33 s → 810 s on a v5e; under jax 0.9.0
@@ -637,47 +588,8 @@ def _encode_gop_single(ys, us, vs, qps, n_frames=None, *, mbw: int,
     frames past it are not encoded (jaxinter._loop_p_frames)."""
     def one(args):
         y, u, v, qp, n = args
-        return _per_gop_sparse(y, u, v, qp, mbw, mbh, compact=compact,
-                               rd=rd, n_frames=n)
+        return _per_gop_sparse(y, u, v, qp, mbw, mbh, rd=rd, n_frames=n)
     return _map_gops(one, (ys, us, vs, qps, n_frames))
-
-
-@functools.partial(jax.jit, static_argnames=("mbw", "mbh", "mesh", "rd"))
-def _encode_wave(ys, us, vs, qps, *, mbw: int, mbh: int, mesh: Mesh,
-                 rd=RD_OFF):
-    """All-intra wave. ys: (G, F, H, W) uint8 sharded over `gop`; qps:
-    (G,) int32 per-GOP QP — the rate-control hook (this path used to
-    take one wave-wide scalar, silently encoding every GOP at base QP
-    regardless of `gop_qp` overrides).
-
-    Returns per-frame sparse-packed levels (jaxcore._sparse_pack — ~10x
-    fewer device→host bytes than raw int32) with leading (G, F) dims;
-    the host checks the nnz/escape counts for the rare dense fallback,
-    which fetches the last output: the levels themselves as int16
-    (covers the full CAVLC level range), (G, F, L), left on the device
-    and re-worded for the link like the GOP programs' (_per_gop_sparse).
-    """
-
-    def per_gop(y_g, u_g, v_g, qp_g):
-        # y_g: (1, F, H, W) — this device's GOP(s); qp_g: (1,)
-        def one(y_f, u_f, v_f, qp1):
-            def per_frame(planes):
-                y, u, v = planes
-                flat = _flat_levels(y, u, v, qp1, mbw, mbh, rd=rd)
-                with stage("layout"):
-                    flat16 = flat.astype(jnp.int16)
-                return jaxcore._sparse_pack(flat) + (flat16,)
-
-            return _map_gops(per_frame, (y_f, u_f, v_f))
-
-        return jax.vmap(one)(y_g, u_g, v_g, qp_g)         # each (1, F, ...)
-
-    shard = shard_map(
-        per_gop, mesh=mesh,
-        in_specs=(P("gop"),) * 4,
-        out_specs=(P("gop"),) * 7,
-    )
-    return shard(ys, us, vs, qps)
 
 
 #: levels in a row of the re-wording program (_levels_as_words): the
@@ -758,18 +670,13 @@ class GopShardEncoder:
 
     def __init__(self, meta: VideoMeta, qp: int = 27, mesh: Mesh | None = None,
                  gop_frames: int = 32, max_segments: int = 200,
-                 inter: bool = True, gops_per_wave: int = 1,
+                 gops_per_wave: int = 1,
                  pack_workers: int | None = None,
                  pipeline_window: int | None = None,
                  decode_ahead: int | None = None,
-                 compact_transfer: bool | None = None,
-                 pack_backend: str | None = None,
                  rd: RdConfig | None = None):
         self.meta = meta
         self.qp = qp
-        #: inter=True encodes each GOP as IDR + P frames (motion-coded);
-        #: False keeps the all-intra path (every frame IDR).
-        self.inter = inter
         self.mesh = mesh if mesh is not None else default_mesh()
         self.gop_frames = gop_frames
         self.max_segments = max_segments
@@ -779,7 +686,7 @@ class GopShardEncoder:
         #: tail (unpack + pack after the last) are each one wave long,
         #: and a longer wave buys no device time (18.70 ms per 1080p
         #: frame at 1x32, 18.71 at 4x32: ledger PR 28). Analysis passes
-        #: and tests may still batch more. Inter path only.
+        #: and tests may still batch more.
         self.gops_per_wave = max(1, int(gops_per_wave))
         self.sps = SPS(width=meta.width, height=meta.height,
                        fps_num=meta.fps_num, fps_den=meta.fps_den)
@@ -794,10 +701,6 @@ class GopShardEncoder:
         if rd is None:
             rd = rd_from_settings(snap)
         self.rd = rd
-        if self.rd.deblock and not inter:
-            raise ValueError(
-                "deblock requires the inter (GOP) path: the all-intra "
-                "encoder has no recon chain to filter")
         #: slice-granular CAVLC pack threads (0/None in config = all
         #: cores). Decoupled from the wave window: the pack pool sizes
         #: to the HOST (cpu count), the window to device queue depth.
@@ -819,15 +722,6 @@ class GopShardEncoder:
         if decode_ahead is None:
             decode_ahead = int(snap.get("decode_ahead", 0) or 0)
         self.decode_ahead = int(decode_ahead) or self.DECODE_AHEAD
-        #: device-side stream compaction (jaxcore._compact_stream): the
-        #: sparse GOP streams fold into one byte payload on device and
-        #: the host fetches only the used prefix. Default on; off keeps
-        #: the original three-array sparse2 transfer (the validated
-        #: fallback — bit-identical either way, parity-tested).
-        if compact_transfer is None:
-            compact_transfer = as_bool(snap.get("compact_transfer", True),
-                                       True)
-        self.compact_transfer = bool(compact_transfer)
         #: per-stage host wall-clock (/metrics_snapshot `stage_ms`)
         self.stages = StageProfile(mirror=_TOTALS)
         #: streaming-ingest instrumentation: peak decoded frames the
@@ -842,22 +736,6 @@ class GopShardEncoder:
         #: attached chip is not measured). None on single-device
         #: meshes (nothing to overlap — plain device_get).
         self._fetch_pool = self._new_fetch_pool()
-        #: entropy-pack execution backend: "thread" (slice thunks on
-        #: the pack pool) or "process" (GOP-granular shared-memory
-        #: sidecars, packproc.py — unpack+pack outside this process's
-        #: GIL). Process packing rides the compact payload; waves that
-        #: fall off it (dense fallback, compact_transfer off, intra
-        #: path) pack on threads as before.
-        if pack_backend is None:
-            pack_backend = str(snap.get("pack_backend", "thread")
-                               or "thread")
-        self.pack_backend = str(pack_backend)
-        #: guards _proc_pool: collect_wave runs on one collector thread
-        #: per in-flight wave, and any of them may retire a broken
-        #: sidecar pool (_disable_proc_pool) while the others read it —
-        #: flagged by `cli.py check` (TVT-T001) and locked since
-        self._proc_lock = threading.Lock()
-        self._proc_pool = self._new_proc_pool()
         #: one warning per encoder when async D2H prefetch is refused
         #: (a platform where copy_to_host_async silently no-ops must be
         #: visible in the logs, not swallowed)
@@ -996,8 +874,8 @@ class GopShardEncoder:
         scene cuts pins F to `frames_per_gop` (`pin_frames`): its GOP
         lengths follow the content, and a clip whose shots are all
         short would otherwise compile a program shape of its own. Such
-        a plan's waves, where they go to the GOP programs (`encode`,
-        the inter path), come with `real`, the (G,) int32 frame counts
+        a plan's waves, where they go to the GOP programs (`encode`),
+        come with `real`, the (G,) int32 frame counts
         of their GOPs: the program's P-frame loop stops there, so the
         repeats are staged and sent but not encoded. Every other wave
         has None in its place and runs the loop over all F frames: a
@@ -1010,7 +888,7 @@ class GopShardEncoder:
         cursor = _FrameCursor(frames, self.stages, require_420=encode,
                               stats=self.staging_stats)
         D = self.num_devices
-        per_wave = D * (self.gops_per_wave if self.inter else 1)
+        per_wave = D * self.gops_per_wave
         gops = list(plan.gops)
         F = max((g.num_frames for g in gops), default=0)
         if plan.pin_frames:
@@ -1024,7 +902,7 @@ class GopShardEncoder:
             self.stages.bump("pad_frames",
                              staged - sum(g.num_frames for g in wave))
             real = None
-            if plan.pin_frames and encode and self.inter:
+            if plan.pin_frames and encode:
                 real = np.asarray([g.num_frames for g in full], np.int32)
                 self.stages.bump("pad_frames_skipped",
                                  staged - int(real.sum()))
@@ -1048,21 +926,15 @@ class GopShardEncoder:
             wave, ysd, usd, vsd, qpsd, *reald = staged
             ph, pw = ysd.shape[2], ysd.shape[3]
             mbh, mbw = ph // 16, pw // 16
-            compact = self.inter and self.compact_transfer
-            form = "intra" if not self.inter \
-                else "bounded" if reald else "scan"
-            with program_build(form, self.rd, ysd.shape, compact):
-                if self.inter and self.num_devices == 1:
+            with program_build("bounded" if reald else "scan", self.rd,
+                               ysd.shape):
+                if self.num_devices == 1:
                     out = _encode_gop_single(ysd, usd, vsd, qpsd, *reald,
-                                             mbw=mbw, mbh=mbh,
-                                             compact=compact, rd=self.rd)
-                elif self.inter:
+                                             mbw=mbw, mbh=mbh, rd=self.rd)
+                else:
                     out = _encode_wave_gop(ysd, usd, vsd, qpsd, *reald,
                                            mbw=mbw, mbh=mbh, mesh=self.mesh,
-                                           compact=compact, rd=self.rd)
-                else:
-                    out = _encode_wave(ysd, usd, vsd, qpsd, mbw=mbw,
-                                       mbh=mbh, mesh=self.mesh, rd=self.rd)
+                                           rd=self.rd)
             # The last output is the wave's whole int16 levels (199 MB
             # per 1080p GOP): it goes onto the handle, not among the
             # outputs the sparse path indexes, and NO copy of it is
@@ -1072,12 +944,11 @@ class GopShardEncoder:
             if not self._async_copy_unavailable:
                 for i, arr in enumerate(out):
                     # Start the device->host copies now, overlapped with
-                    # the next wave's compute. The compact payload
-                    # (index 6) is NOT prefetched: collect_wave fetches
-                    # only its used prefix, and an async copy would drag
-                    # the whole budget-padded buffer across the link
-                    # anyway.
-                    if compact and i == 6:
+                    # the next wave's compute. The payload (index 6)
+                    # is NOT prefetched: collect_wave fetches only its
+                    # used prefix, and an async copy would drag the
+                    # whole budget-padded buffer across the link anyway.
+                    if i == 6:
                         continue
                     try:
                         arr.copy_to_host_async()
@@ -1122,30 +993,6 @@ class GopShardEncoder:
 
         pool = cf.ThreadPoolExecutor(min(32, 2 * self.num_devices),
                                      thread_name_prefix="tvt-fetch")
-        weakref.finalize(self, pool.shutdown, False)
-        return pool
-
-    def _new_proc_pool(self):
-        """GOP-granular pack sidecar processes (pack_backend=process),
-        or None for the threaded backend. Spawn context: children
-        import packproc fresh and must never inherit (or initialize) a
-        jax backend. Falls back to threads with a warning when the
-        platform can't spawn a pool."""
-        if self.pack_backend != "process" or not self.inter:
-            return None
-        import concurrent.futures as cf
-        import multiprocessing as mp
-        import weakref
-
-        try:
-            pool = cf.ProcessPoolExecutor(
-                max(1, min(self.pack_workers, 8)),
-                mp_context=mp.get_context("spawn"))
-        except Exception as exc:    # noqa: BLE001 - degrade, don't die
-            _LOG.warning("pack_backend=process unavailable (%s: %s); "
-                         "falling back to threaded pack",
-                         type(exc).__name__, exc)
-            return None
         weakref.finalize(self, pool.shutdown, False)
         return pool
 
@@ -1254,94 +1101,18 @@ class GopShardEncoder:
                         used: int, L: int) -> np.ndarray:
         """Compact payload's used prefix → flat int16 levels (the
         native-or-numpy dispatch lives with the format contract,
-        layout.unpack_compact_auto — shared with the pack sidecars)."""
+        layout.unpack_compact_auto)."""
         from ..codecs.h264.layout import unpack_compact_auto
 
         return unpack_compact_auto(payload_row[:used], nblk, nval, L)
 
-    @staticmethod
-    def _release_spool(shm, spools: list) -> None:
-        if shm in spools:
-            spools.remove(shm)
-        shm.close()
-        try:
-            shm.unlink()
-        except FileNotFoundError:       # pragma: no cover
-            pass
-
-    def _disable_proc_pool(self, exc: BaseException) -> None:
-        """Runtime degrade: a broken sidecar pool (spawn refused, child
-        OOM-killed) must not fail the encode — retire the pool and pack
-        the rest of the job on threads. Swap-under-lock: several
-        collector threads can hit the broken pool in the same wave
-        window, and exactly ONE of them must log the retirement."""
-        with self._proc_lock:
-            pool, self._proc_pool = self._proc_pool, None
-        if pool is not None:
-            _LOG.warning(
-                "pack sidecar pool broke (%s: %s); packing on threads "
-                "from here on", type(exc).__name__, exc)
-
-    def _submit_process_pack(self, proc, mv8_g, dc16_g, payload_row,
-                             nblk: int, nval: int, used: int,
-                             gop: GopSpec, F: int, mbw: int, mbh: int,
-                             gop_qp: int, spools: list):
-        """Spool one GOP's compact transfer parts ([mv8 | dense DC |
-        payload]) into a shared-memory block and submit its
-        unpack+unflatten+pack to the sidecar pool (packproc). Returns a
-        callable yielding the slice payloads; it releases the spool
-        after the result lands (`spools` lets collect_wave release
-        blocks whose gather was never reached when a wave fails
-        mid-flight). A BROKEN pool degrades instead of failing the
-        wave: the same spool bytes pack in-process via packproc."""
-        import dataclasses as _dc
-        from concurrent.futures.process import BrokenProcessPool
-        from multiprocessing import shared_memory
-
-        from . import packproc
-
-        mv = np.ascontiguousarray(mv8_g).view(np.uint8).reshape(-1)
-        dn = np.ascontiguousarray(dc16_g).view(np.uint8).reshape(-1)
-        pl = np.ascontiguousarray(payload_row[:used])
-        total = mv.nbytes + dn.nbytes + pl.nbytes
-        shm = shared_memory.SharedMemory(create=True, size=max(1, total))
-        spools.append(shm)
-        buf = np.frombuffer(shm.buf, np.uint8)
-        buf[:mv.nbytes] = mv
-        buf[mv.nbytes:mv.nbytes + dn.nbytes] = dn
-        buf[mv.nbytes + dn.nbytes:total] = pl
-        del buf     # shm.close() refuses while exported views exist
-        args = (shm.name, mv.nbytes, dn.nbytes, pl.nbytes, nblk, nval,
-                gop.num_frames, F, mbw, mbh, _dc.asdict(self.sps),
-                _dc.asdict(self.pps), gop_qp, gop.index,
-                _dc.asdict(self.rd))
-        try:
-            fut = proc.submit(packproc.pack_gop_from_shm, *args)
-        except Exception:
-            self._release_spool(shm, spools)
-            raise
-        self.stages.bump("proc_pack_gops")
-
-        def gather() -> list[bytes]:
-            try:
-                return fut.result()
-            except BrokenProcessPool as exc:
-                self._disable_proc_pool(exc)
-                # the spool holds everything the child would have read
-                return packproc.pack_gop_from_shm(*args)
-            finally:
-                self._release_spool(shm, spools)
-
-        return gather
-
     def _level_sizes(self, F: int, nmb: int) -> tuple[int, int]:
-        """(L, Lr) of one GOP's (inter) or frame's (intra) flat levels:
-        the whole vector, and its sparse remainder once both intra
-        hadamard DC segments (luma + chroma) and the [mode16 | dqp16]
-        tail, when shipped, go dense (_per_gop_sparse)."""
+        """(L, Lr) of one GOP's flat levels: the whole vector, and its
+        sparse remainder once both intra hadamard DC segments (luma +
+        chroma) and the [mode16 | dqp16] tail, when shipped, go dense
+        (_per_gop_sparse)."""
         tail = 2 * nmb if self.rd.ships_modes else 0
-        L = (nmb * _INTRA_MB + (F - 1) * nmb * _P_FLAT_MB + tail
-             if self.inter else nmb * _INTRA_MB + tail)
+        L = nmb * _INTRA_MB + (F - 1) * nmb * _P_FLAT_MB + tail
         return L, L - nmb * 16 - nmb * 8 - tail
 
     def _note_sparse_fill(self, nblk, nval, L: int,
@@ -1391,7 +1162,6 @@ class GopShardEncoder:
             if fetch.tiny is not None:
                 return
             prof = self.stages
-            compact = self.inter and self.compact_transfer
             tracer = prof.tracer()
             with (tracer.span("wave_fetch_start") if tracer is not None
                   else contextlib.nullcontext()):
@@ -1400,26 +1170,15 @@ class GopShardEncoder:
                 # the stage breakdown — and lets a budget overflow
                 # skip the bulk sparse fetch entirely.
                 with prof.stage("device_wait"):
-                    if self.inter:
-                        tiny = jax.device_get(list(out[2:6] if compact
-                                                   else out[2:5]))
-                    else:
-                        tiny = jax.device_get([out[0], out[1]])
+                    tiny = jax.device_get(list(out[2:6]))
                 prof.bump("d2h_bytes", sum(int(a.nbytes) for a in tiny))
-                L, Lr = self._level_sizes(ysd.shape[1], mbw * mbh)
-                if self.inter:
-                    nblk, nval, n_esc = tiny[:3]
-                    self._note_sparse_fill(nblk, nval, Lr)
-                    fetch.sparse_ok = jaxcore.block_sparse2_fits(
-                        nblk.max(), nval.max(), n_esc.max(), Lr)
-                    if fetch.sparse_ok and compact:
-                        fetch.payload = self._slice_payload_rows(
-                            out[6], tiny[3])
-                else:
-                    nnz, n_esc = tiny
-                    fetch.sparse_ok = jaxcore.sparse_fits(
-                        nnz.max(), n_esc.max(), L)
+                _, Lr = self._level_sizes(ysd.shape[1], mbw * mbh)
+                nblk, nval, n_esc, used = tiny
+                self._note_sparse_fill(nblk, nval, Lr)
+                fetch.sparse_ok = jaxcore.block_sparse2_fits(
+                    nblk.max(), nval.max(), n_esc.max(), Lr)
                 if fetch.sparse_ok:
+                    fetch.payload = self._slice_payload_rows(out[6], used)
                     fetch.dense = None
                 else:
                     with program_build("words", None, fetch.dense.shape):
@@ -1428,41 +1187,22 @@ class GopShardEncoder:
             fetch.tiny = tiny
 
     def collect_wave(self, pending: tuple) -> list[EncodedSegment]:
-        """Fetch one dispatched wave's levels (compact or sparse, with
-        the dense fallback) and entropy-pack its GOPs on host, fanning
-        the pack across the slice pool — or, with pack_backend=process,
-        handing whole GOPs to the shared-memory sidecars."""
+        """Fetch one dispatched wave's levels — the payload's used
+        prefixes, or the whole levels as words where the sparse budgets
+        did not hold — and entropy-pack its GOPs on host, every slice
+        of the wave a thunk on the slice pool."""
         self.start_fetch(pending)
         wave, ysd, _usd, _vsd, qpsd, mbw, mbh, out, fetch = pending
         prof = self.stages
         F = ysd.shape[1]
-        nmb = mbw * mbh
         ships_modes = self.rd.ships_modes
-        L, Lr = self._level_sizes(F, nmb)
-        compact = self.inter and self.compact_transfer
-        tiny, sparse_ok = fetch.tiny, fetch.sparse_ok
-        flat = None
-        used = payload_rows = None
-        if self.inter:
-            nblk, nval = tiny[0], tiny[1]
-            if sparse_ok:
-                with prof.stage("fetch"):
-                    if compact:
-                        used = tiny[3]
-                        mv8, dc16 = self._fetch_bulk(out[0:2])
-                        payload_rows = self._gather_payload_rows(
-                            fetch.payload)
-                    else:
-                        mv8, dc16, bitmap, bmask16, vals = \
-                            self._fetch_bulk(
-                                (out[0], out[1], out[5], out[6], out[7]))
+        L, Lr = self._level_sizes(F, mbw * mbh)
+        nblk, nval, _n_esc, used = fetch.tiny
+        if fetch.sparse_ok:
+            with prof.stage("fetch"):
+                mv8, dc16 = self._fetch_bulk(out[0:2])
+                payload_rows = self._gather_payload_rows(fetch.payload)
         else:
-            nnz, n_esc = tiny
-            if sparse_ok:
-                with prof.stage("fetch"):
-                    bitmap, vals, esc_pos, esc_val = \
-                        self._fetch_bulk(out[2:6])
-        if not sparse_ok:
             # Wave-wide dense fallback: the wide fetch of the levels
             # the wave's program left on the device, re-worded and sent
             # by start_fetch. Not rare on grainy footage: white grain of
@@ -1479,9 +1219,8 @@ class GopShardEncoder:
                 # the levels again, no copy (less the last row's padding)
                 flat = words.view(np.int16)[..., :L]
                 prof.bump("d2h_bytes", int(flat.nbytes))
-                if self.inter:
-                    # MVs come from the sparse outputs, as ever
-                    (mv8,) = self._fetch_bulk(out[0:1])
+                # MVs come from the sparse outputs, as ever
+                (mv8,) = self._fetch_bulk(out[0:1])
         # Header QP must match what the device QUANTIZED with — read it
         # from the staged per-wave array, not the live gop_qp dict (a
         # caller mutating gop_qp between passes must not desync slices
@@ -1495,65 +1234,32 @@ class GopShardEncoder:
                                              + self.frame_offset))
                     for g in wave]
         # Phase 1: unpack levels and SUBMIT every GOP's pack work — the
-        # slice pool packs the whole wave's slices concurrently (or the
-        # process sidecars take whole GOPs); phase 2 gathers in GOP
-        # order.
+        # slice pool packs the whole wave's slices concurrently; phase
+        # 2 gathers in GOP order.
         pool = self._slice_pool()
-        with self._proc_lock:
-            proc = self._proc_pool if (compact and sparse_ok) else None
-        #: live shared-memory spools of this wave's process-pack jobs —
-        #: released by each gather(), and swept below if the wave dies
-        #: before every gather ran (a leaked block outlives the process)
-        spools: list = []
         jobs: list[tuple] = []
         for gi, gop in enumerate(wave):
-            gop_qp = int(qps_host[gi])
-            if self.inter:
-                count_vectors(prof, mv8[gi][:gop.num_frames - 1], self.rd)
-                if proc is not None:
-                    jobs.append((gop, self._submit_process_pack(
-                        proc, mv8[gi], dc16[gi], payload_rows[gi],
-                        int(nblk[gi]), int(nval[gi]), int(used[gi]),
-                        gop, F, mbw, mbh, gop_qp, spools)))
-                    continue
-                if sparse_ok:
-                    with prof.stage("sparse_unpack"):
-                        if compact:
-                            rest = self._unpack_compact(
-                                payload_rows[gi], int(nblk[gi]),
-                                int(nval[gi]), int(used[gi]), Lr)
-                        else:
-                            rest = _sparse_unpack2_host(
-                                int(nblk[gi]), int(nval[gi]), bitmap[gi],
-                                bmask16[gi], vals[gi], Lr)
-                    with prof.stage("unflatten"):
-                        intra, planes = unflatten_gop_parts(
-                            dc16[gi], rest, mv8[gi], F, mbw, mbh,
-                            ships_modes=ships_modes)
-                else:
-                    with prof.stage("unflatten"):
-                        intra, planes = unflatten_gop(
-                            flat[gi], mv8[gi], F, mbw, mbh,
-                            ships_modes=ships_modes)
-                # gop.num_frames (not F) drops the wave's tail-repeat
-                # padding.
-                thunks = gop_slice_thunks_planes(
-                    intra, planes, gop.num_frames, mbw, mbh, self.sps,
-                    self.pps, gop_qp, idr_pic_id=gop.index, rd=self.rd)
+            count_vectors(prof, mv8[gi][:gop.num_frames - 1], self.rd)
+            if fetch.sparse_ok:
+                with prof.stage("sparse_unpack"):
+                    rest = self._unpack_compact(
+                        payload_rows[gi], int(nblk[gi]), int(nval[gi]),
+                        int(used[gi]), Lr)
+                with prof.stage("unflatten"):
+                    intra, planes = unflatten_gop_parts(
+                        dc16[gi], rest, mv8[gi], F, mbw, mbh,
+                        ships_modes=ships_modes)
             else:
-                thunks = []
-                for fi in range(gop.num_frames):
-                    if sparse_ok:
-                        with prof.stage("sparse_unpack"):
-                            raw = jaxcore._sparse_unpack(
-                                int(nnz[gi, fi]), int(n_esc[gi, fi]),
-                                bitmap[gi, fi], vals[gi, fi],
-                                esc_pos[gi, fi], esc_val[gi, fi], L)
-                    else:
-                        raw = flat[gi, fi]
-                    thunks.append(functools.partial(
-                        self._pack_intra_frame, raw, mbw, mbh, gop, fi,
-                        gop_qp))
+                with prof.stage("unflatten"):
+                    intra, planes = unflatten_gop(
+                        flat[gi], mv8[gi], F, mbw, mbh,
+                        ships_modes=ships_modes)
+            # gop.num_frames (not F) drops the wave's tail-repeat
+            # padding.
+            thunks = gop_slice_thunks_planes(
+                intra, planes, gop.num_frames, mbw, mbh, self.sps,
+                self.pps, int(qps_host[gi]), idr_pic_id=gop.index,
+                rd=self.rd)
             if pool is None:
                 jobs.append(
                     (gop, lambda ts=thunks: [t() for t in ts]))
@@ -1562,32 +1268,16 @@ class GopShardEncoder:
                 jobs.append(
                     (gop, lambda fs=futs: [f.result() for f in fs]))
         segments: list[EncodedSegment] = []
-        try:
-            for gop, gather in jobs:
-                with prof.stage("pack"):
-                    payload = gather()
-                with prof.stage("concat"):
-                    seg = EncodedSegment(
-                        gop=gop, payload=b"".join(payload),
-                        frame_sizes=tuple(len(p) for p in payload))
-                segments.append(seg)
-        finally:
-            for shm in list(spools):    # gathers that never ran
-                self._release_spool(shm, spools)
+        for gop, gather in jobs:
+            with prof.stage("pack"):
+                payload = gather()
+            with prof.stage("concat"):
+                seg = EncodedSegment(
+                    gop=gop, payload=b"".join(payload),
+                    frame_sizes=tuple(len(p) for p in payload))
+            segments.append(seg)
         prof.count_wave()
         return segments
-
-    def _pack_intra_frame(self, raw, mbw: int, mbh: int, gop: GopSpec,
-                          fi: int, qp: int) -> bytes:
-        """Pack one all-intra frame's IDR slice (+ SPS/PPS at the GOP
-        head) from its flat levels — the intra path's slice-pool unit."""
-        levels = jaxcore._unpack_levels(raw, mbw, mbh, self.rd)
-        nal = pack_slice(levels, mbw, mbh, self.sps, self.pps, qp,
-                         idr=True,
-                         idr_pic_id=(gop.start_frame + fi) % 65536)
-        if fi == 0:
-            nal = self.sps.to_nal() + self.pps.to_nal() + nal
-        return nal
 
     #: default in-flight wave window when neither the constructor nor
     #: the `pipeline_window` setting (TVT_PIPELINE_WINDOW) override it.
@@ -1936,8 +1626,9 @@ class SfeShardEncoder(GopShardEncoder):
     device, and the collect path is PER FRAME: a frame's band levels
     are fetched and its band slices packed (concurrently on the pack
     pool) as soon as its step completes, while the device runs the
-    next frame — `frame_done_t` records each frame's bitstream-ready
-    timestamp, which `frame_latencies_ms` turns into per-frame latency.
+    next frame — the gap between consecutive frames' bitstream-ready
+    times is the per-frame latency (`_note_frame_done`, read through
+    :func:`frame_latency_percentiles`).
 
     A "wave" for the executor's retry/progress machinery is one GOP
     (closed: an IDR step resets the carry, so a failed GOP re-dispatches
@@ -2002,11 +1693,9 @@ class SfeShardEncoder(GopShardEncoder):
                          ("band",))
         super().__init__(meta, qp=qp, mesh=band_mesh,
                          gop_frames=gop_frames, max_segments=max_segments,
-                         inter=True, gops_per_wave=1,
-                         pack_workers=pack_workers,
+                         gops_per_wave=1, pack_workers=pack_workers,
                          pipeline_window=pipeline_window,
-                         decode_ahead=decode_ahead,
-                         pack_backend="thread", rd=rd)
+                         decode_ahead=decode_ahead, rd=rd)
         if halo_rows is None:
             halo_rows = int(snap.get("sfe_halo_rows", 32) or 32)
         #: reference rows exchanged per side (multiple of 16). >= 23
@@ -2018,12 +1707,6 @@ class SfeShardEncoder(GopShardEncoder):
         self.halo_rows = max(16, (int(halo_rows) // 16) * 16)
         self.halo_rows = min(self.halo_rows,
                              self.band_plan.band_mb_rows * 16)
-        #: per-frame bitstream-ready timestamps (time.perf_counter), in
-        #: encode order — frame_latencies_ms' source. Bounded: a
-        #: long-running job appends one entry per frame forever, so
-        #: only the most recent window survives (enough for any
-        #: latency percentile).
-        self.frame_done_t: deque = deque(maxlen=4096)
         #: previous frame's bitstream-ready perf_counter — the source
         #: of the per-frame latency gap fed to the process-global
         #: _SFE_LAT_MS ring + the tvt_sfe_frame_latency_seconds
@@ -2269,15 +1952,13 @@ class SfeShardEncoder(GopShardEncoder):
         return [f.result() for f in [pool.submit(t) for t in thunks]]
 
     def _note_frame_done(self, frame_index: int) -> None:
-        """One SFE frame's bitstream is ready: stamp frame_done_t,
-        count it, and — when a previous frame
-        exists — record the steady-state gap as a latency sample
-        (global percentile ring + histogram) and a `sfe_frame` span in
-        the job's trace."""
+        """One SFE frame's bitstream is ready: count it, and — when a
+        previous frame exists — record the steady-state gap as a
+        latency sample (global percentile ring + histogram) and a
+        `sfe_frame` span in the job's trace."""
         now = time.perf_counter()
         prev, self._last_frame_done = self._last_frame_done, now
         self.stages.bump("sfe_frames")
-        self.frame_done_t.append(now)
         if prev is None or now <= prev:
             return
         gap = now - prev
@@ -2436,17 +2117,6 @@ class SfeShardEncoder(GopShardEncoder):
             intra = intra + (flat_b[t:t + nmb], flat_b[t + nmb:])
         return self._pack_intra_levels(intra, bi, qp, idr_pic_id)
 
-    def frame_latencies_ms(self) -> list[float]:
-        """Per-frame pipeline latency: the gap between consecutive
-        frames' bitstream-ready timestamps within the steady state —
-        at the live edge each frame exits the (device step → fetch →
-        band pack) pipeline one such gap after entering it. The first
-        frame of the run (cold: includes dispatch of the whole first
-        GOP) is excluded. Sorted first: overlapping collector threads
-        (pipeline_window > 1) append near-, not strictly-, in order."""
-        ts = sorted(self.frame_done_t)
-        return [(b - a) * 1e3 for a, b in zip(ts, ts[1:])]
-
 
 def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
                        shape: str | None = None, rungs=None,
@@ -2503,13 +2173,12 @@ def make_shard_encoder(meta: VideoMeta, settings, mesh, *,
 
 
 def encode_clip_sharded(frames: list[Frame], meta: VideoMeta, qp: int = 27,
-                        mesh: Mesh | None = None, gop_frames: int = 32,
-                        inter: bool = True) -> bytes:
+                        mesh: Mesh | None = None,
+                        gop_frames: int = 32) -> bytes:
     """Convenience: plan → shard encode → order-restoring concat."""
     from ..core.types import concat_segments
 
-    enc = GopShardEncoder(meta, qp=qp, mesh=mesh, gop_frames=gop_frames,
-                          inter=inter)
+    enc = GopShardEncoder(meta, qp=qp, mesh=mesh, gop_frames=gop_frames)
     return concat_segments(enc.encode(frames))
 
 
@@ -2578,7 +2247,7 @@ def program_build(form: str, rd, shape, *more):
     call of each (form, rd, shape, *more) of this process is clocked
     (`program_build`; `tvt:program_build` in a live device profile),
     counted and named once in the log. `form` is the executable's kind
-    (`scan` | `bounded` for a GOP program by its P-frame loop, `intra`,
+    (`scan` | `bounded` for a GOP program by its P-frame loop,
     `sfe_intra`, `sfe_p`, `words`), `shape` its leading operand's.
     Every call of a form that searches motion also sets the gauge
     `me_candidates`: what that executable scores per macroblock."""
