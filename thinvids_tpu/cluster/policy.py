@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 
-from ..core.config import Settings, subpel_of
+from ..core.config import Settings, as_bool, subpel_of
 from ..core.types import VideoMeta
 
 # Codecs whose long-GOP/interlace quirks made stream-copy segmentation
@@ -52,6 +52,28 @@ def evaluate_job_policy(meta: VideoMeta, settings: Settings,
                    f"encodes at subpel={runs!r} (TVT_SUBPEL / POST "
                    f"/settings): vector precision is a daemon-wide "
                    f"setting")
+
+    # Likewise intra macroblocks in P pictures (`p_intra`), and a job
+    # of the one shape whose step programs have no such decision: a
+    # band-shape job is refused, not encoded all-inter and reported
+    # done.
+    job_settings = job_settings or {}
+    p_intra = as_bool(settings.get("p_intra", False), False)
+    if as_bool(job_settings.get("p_intra", p_intra), False) != p_intra:
+        return PolicyDecision(
+            accepted=False,
+            reason=f"p_intra={job_settings.get('p_intra')!r} asked per "
+                   f"job, but this daemon encodes at p_intra={p_intra} "
+                   f"(TVT_P_INTRA / POST /settings): intra macroblocks "
+                   f"in P pictures are a daemon-wide setting")
+    if p_intra and int(job_settings.get(
+            "sfe_bands", settings.get("sfe_bands", 0)) or 0) > 0:
+        return PolicyDecision(
+            accepted=False,
+            reason="sfe_bands > 0 under p_intra: split-frame band "
+                   "slices have no intra / inter decision in P "
+                   "pictures; submit the job without sfe_bands (GOP "
+                   "shape) or to a daemon without TVT_P_INTRA")
 
     if settings.reject_av1 and codec == "av1":
         return PolicyDecision(accepted=False, reason="av1 input rejected")

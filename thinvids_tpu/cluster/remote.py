@@ -47,7 +47,7 @@ import threading
 import time
 from typing import TYPE_CHECKING, Any, Callable, Iterable, Mapping
 
-from ..core.config import subpel_of
+from ..core.config import as_bool, subpel_of
 from ..core.status import ShardState, Status
 from ..core.types import (ChromaFormat, EncodedSegment, GopSpec, SegmentPlan,
                           VideoMeta)
@@ -188,6 +188,9 @@ class Shard:
     #: the vector precision the plan was signed with (the `subpel`
     #: setting): the worker encodes at it whatever its own daemon's is
     subpel: str = "half"
+    #: the plan was signed with `p_intra` on: intra macroblocks in P
+    #: pictures, likewise whatever the worker's own daemon says
+    p_intra: bool = False
     #: hosts that rejected this shard's shape (old workers): the claim
     #: never offers it to them again, so an unsupported rejection
     #: cannot ping-pong
@@ -291,8 +294,15 @@ class Shard:
         # before the setting knows neither and answers `unsupported`,
         # where a key it ignored would have had it encode the shard at
         # half-sample precision under the plan's signature.
-        tag = self.shape if self.subpel == "half" \
-            else f"{self.shape}/{self.subpel}"
+        # `p_intra` is a third part, after the precision it is then
+        # never left out before ("gop/half/p_intra"): a worker from
+        # before THAT setting reads the rest as a precision it does
+        # not know.
+        tag = self.shape
+        if self.subpel != "half" or self.p_intra:
+            tag += f"/{self.subpel}"
+        if self.p_intra:
+            tag += "/p_intra"
         if tag != "gop":
             desc["shape"] = tag
         if self.shape == "band":
@@ -1476,6 +1486,9 @@ class RemoteExecutor(LocalExecutor):
         # and the vector precision: other bytes for the same GOPs
         if subpel_of(settings) != "half":
             fields.extend(["subpel", subpel_of(settings)])
+        # and intra macroblocks in P pictures, likewise
+        if as_bool(settings.get("p_intra", False), False):
+            fields.append("p_intra")
         return hashlib.sha256("|".join(fields).encode()).hexdigest()[:16]
 
     @staticmethod
@@ -1604,6 +1617,7 @@ class RemoteExecutor(LocalExecutor):
         for shard in shards:
             # the signature holds it, so a resumed plan's is the same
             shard.subpel = subpel_of(settings)
+            shard.p_intra = as_bool(settings.get("p_intra", False), False)
         refs = parts.begin_job(job.id, rec)
         reused = 0
         if resume and shards and shards[0].shape == "band":
@@ -2057,27 +2071,35 @@ class UnsupportedShardShape(RuntimeError):
     stops offering the shard to this host."""
 
 
+def _wire_tag(desc: Mapping[str, Any]) -> tuple[str, str, tuple[str, ...]]:
+    """(shard shape, `subpel`, further flags) of a claim descriptor's
+    shape tag (Shard.descriptor): no tag is a GOP range, no precision
+    in it is half."""
+    shape, *rest = str(desc.get("shape", "gop") or "gop").split("/")
+    return shape, (rest[0] if rest and rest[0] else "half"), tuple(rest[1:])
+
+
 def wire_shape(desc: Mapping[str, Any]) -> tuple[str, str]:
-    """(shard shape, `subpel`) of a claim descriptor's shape tag
-    (Shard.descriptor): no tag is a GOP range, no precision in it is
-    half."""
-    shape, _, subpel = str(desc.get("shape", "gop") or "gop").partition("/")
-    return shape, subpel or "half"
+    """(shard shape, `subpel`) of a claim descriptor's shape tag."""
+    return _wire_tag(desc)[:2]
 
 
 def _shard_rd(desc: Mapping[str, Any]):
     """The RdConfig a claimed shard is encoded with: this worker's own
-    settings, at the vector precision the coordinator signed the plan
-    with."""
+    settings, at the vector precision and with the `p_intra` the
+    coordinator signed the plan with."""
     from ..codecs.h264.rdo import rd_from_settings
     from ..core.config import SUBPELS, get_settings
 
-    subpel = wire_shape(desc)[1]
+    _, subpel, flags = _wire_tag(desc)
     if subpel not in SUBPELS:
         raise UnsupportedShardShape(
             f"vector precision {subpel!r} not implemented by this worker")
+    if set(flags) - {"p_intra"}:
+        raise UnsupportedShardShape(
+            f"shard tag parts {flags!r} not implemented by this worker")
     return dataclasses.replace(rd_from_settings(get_settings()),
-                               subpel=subpel)
+                               subpel=subpel, p_intra="p_intra" in flags)
 
 
 def _encode_band_shard(desc: Mapping[str, Any], frames, mesh=None,

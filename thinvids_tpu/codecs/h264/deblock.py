@@ -30,14 +30,16 @@ and the numpy reference encoder (a python loop over t). The plain
 reference it is tested against is tools/deblock_plain.py; libavcodec
 agrees with both sample for sample (tests/test_deblock.py).
 
-Boundary strength (§8.7.2.1, restricted to this codec's streams —
-pictures are homogeneous: all-intra IDR or all-inter P, one reference,
-16x16 partitions):
+Boundary strength (§8.7.2.1, restricted to this codec's streams — one
+reference, 16x16 partitions, frame pictures):
 
     intra picture:  MB edge -> 4, internal edge -> 3
     P picture:      either side's 4x4 luma block coded -> 2,
                     |mv_p - mv_q| >= 1 integer pel (either comp) -> 1,
                     else 0
+    P picture with intra macroblocks (`intra_mb`, rd.p_intra): an MB
+                    edge with an intra macroblock on either side -> 4,
+                    an edge inside one -> 3, the P rule elsewhere
 
 A plane handed to `deblock_frame` is filtered as ONE slice whose first
 row has nothing above it: a split-frame band filters its own rows and
@@ -226,7 +228,8 @@ def _edge_params(grid, intra: bool, xp, mv_per_pel: int = 2):
 
     `grid` is the skewed per-macroblock metadata [t, 22, y]: 16 flags
     "4x4 luma block coded" (raster), QP_Y, mv (2), and the masks
-    `internal edges exist`, `left edge exists`, `top edge exists`."""
+    `internal edges exist`, `left edge exists`, `top edge exists`; a
+    P picture that may hold intra macroblocks has a 23rd, `is intra`."""
     T, mbh = grid.shape[0], grid.shape[-1]
     nz = grid[:, :16].reshape(T, 4, 4, mbh)          # [t, by, bx, y]
     qp = grid[:, 16]
@@ -257,6 +260,18 @@ def _edge_params(grid, intra: bool, xp, mv_per_pel: int = 2):
             (nz | nz_t) > 0, 2,
             xp.where(moved(_from_above(mv, xp))
                      & first[None, :, None, None], 1, 0))
+        if grid.shape[1] > 22:
+            own = grid[:, 22]
+
+            def mixed(bs, other, mb_edge):
+                either = ((own | other) > 0)[:, None, None, :]
+                return xp.where(mb_edge, xp.where(either, 4, bs),
+                                xp.where(own[:, None, None, :] > 0, 3, bs))
+
+            bs_v = mixed(bs_v, _from_left(own, xp),
+                         first[None, None, :, None])
+            bs_h = mixed(bs_h, _from_above(own, xp),
+                         first[None, :, None, None])
     exists_v = xp.where(first[None, None, :, None],
                         left_ok[:, None, None, :], on[:, None, None, :])
     exists_h = xp.where(first[None, :, None, None],
@@ -478,7 +493,8 @@ def _wavefront_step(carry, blocks, xp):
 
 def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
                   mb_row0=0, total_mb_rows: int | None = None,
-                  edges=None, mv_per_pel: int = 2, ops=NUMPY_OPS):
+                  edges=None, mv_per_pel: int = 2, intra_mb=None,
+                  ops=NUMPY_OPS):
     """Deblock one (padded) frame, or one band slice by itself.
 
     y: (16·mbh_p, 16·mbw) luma plane (any int dtype; uint8 ok);
@@ -486,7 +502,9 @@ def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
     `intra` selects the picture-homogeneous bS rule. For P pictures,
     nz4: (4·mbh_p, 4·mbw) any-nonzero per 4x4 luma block and
     mv: (mbh_p, mbw, 2) MVs, `mv_per_pel` units to an integer sample
-    (2: half-sample units; 4: quarter). `mb_row0` (may be traced) and
+    (2: half-sample units; 4: quarter), and `intra_mb`: None, or the
+    (mbh_p, mbw) map of the P picture's intra macroblocks (whose nz4
+    and mv are not read). `mb_row0` (may be traced) and
     `total_mb_rows` say where the plane's first macroblock row lies in
     the picture and how many the picture has: rows past the picture
     (band padding) are left alone. `edges` = (internal, left, top)
@@ -514,11 +532,13 @@ def deblock_frame(y, u, v, qp_map, *, intra: bool, nz4=None, mv=None,
             nz = ops.asarray(nz4).astype(xp.int32).reshape(mbh, 4, mbw, 4)
             mv = ops.asarray(mv).astype(xp.int32)
         lanes = ops.lanes(mbh)
-        grid = xp.concatenate(                       # [y, 22, x]
+        grid = xp.concatenate(                       # [y, 22 or 23, x]
             [xp.transpose(nz, (0, 1, 3, 2)).reshape(mbh, 16, mbw),
              ops.asarray(qp_map).astype(xp.int32)[:, None],
              xp.transpose(mv, (0, 2, 1))]
-            + [ops.asarray(e).astype(xp.int32)[:, None] for e in edges],
+            + [ops.asarray(e).astype(xp.int32)[:, None] for e in edges]
+            + ([] if intra or intra_mb is None
+               else [ops.asarray(intra_mb).astype(xp.int32)[:, None]]),
             axis=1)
         prm = _edge_params(
             _skew(_to_lanes(grid, (2, 1, 0), lanes, ops), mbh, xp),

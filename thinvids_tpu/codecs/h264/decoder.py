@@ -2,7 +2,8 @@
 
 Independent implementation of the decode direction — parses Annex-B
 streams (SPS/PPS, IDR + non-IDR slices, CAVLC, I16x16 and P_L0_16x16,
-multi-slice pictures) and reconstructs frames. Used by tests as the
+Intra16x16 macroblocks inside P slices, multi-slice pictures) and
+reconstructs frames. Used by tests as the
 in-repo conformance check of encoder output (alongside the libavcodec
 ctypes oracle — which this container may not have) and by the
 stamp/seam verification tooling to decode without external binaries.
@@ -64,6 +65,9 @@ class DecodedStream:
     #: quarter-sample units, skipped macroblocks' inferred ones
     #: included; None for an intra picture
     mvs: list = dataclasses.field(default_factory=list)
+    #: per P picture the (mbh, mbw) bool map of its intra macroblocks
+    #: (their `mvs` entries read 0); None for an intra picture
+    intra_mbs: list = dataclasses.field(default_factory=list)
 
 
 class _Picture:
@@ -81,6 +85,10 @@ class _Picture:
         self.luma_counts = np.zeros((4 * mbh, 4 * mbw), np.int32)
         self.chroma_counts = np.zeros((2, 2 * mbh, 2 * mbw), np.int32)
         self.mv = np.zeros((mbh, mbw, 2), np.int32)     # (dy, dx) quarter
+        # the P picture's intra macroblocks (§7.3.5 mb_type 5..30):
+        # refIdx -1 to a neighbour's vector prediction, bS 3 / 4 to
+        # the filter
+        self.intra_mb = np.zeros((mbh, mbw), bool)
         self.decoded = 0                                # MBs decoded so far
         # in-loop deblocking state: the effective QP_Y of every MB (the
         # running slice QP after mb_qp_delta; uncoded MBs keep the
@@ -215,119 +223,140 @@ def _mvp_and_skip(pic: _Picture, my: int, mx: int, slice_first: int):
     mbw = pic.mbw
     mi = my * mbw + mx
     zero = np.zeros(2, np.int32)
+
+    def neighbour(ny, nx):
+        """(refIdx is 0, mv) of an available macroblock: an intra one
+        has refIdx -1 and no vector (§8.4.1.3.2)."""
+        if pic.intra_mb[ny, nx]:
+            return False, zero
+        return True, pic.mv[ny, nx]
+
     avail_a = mx > 0 and mi - 1 >= slice_first
     avail_b = my > 0 and mi - mbw >= slice_first
-    mva = pic.mv[my, mx - 1] if avail_a else zero
-    mvb = pic.mv[my - 1, mx] if avail_b else zero
+    ref_a, mva = neighbour(my, mx - 1) if avail_a else (False, zero)
+    ref_b, mvb = neighbour(my - 1, mx) if avail_b else (False, zero)
     if my > 0 and mx + 1 < mbw and mi - mbw + 1 >= slice_first:
-        avail_c, mvc = True, pic.mv[my - 1, mx + 1]
+        avail_c = True
+        ref_c, mvc = neighbour(my - 1, mx + 1)
     elif my > 0 and mx > 0 and mi - mbw - 1 >= slice_first:
-        avail_c, mvc = True, pic.mv[my - 1, mx - 1]
+        avail_c = True
+        ref_c, mvc = neighbour(my - 1, mx - 1)
     else:
-        avail_c, mvc = False, zero
-    n_avail = int(avail_a) + int(avail_b) + int(avail_c)
+        avail_c, ref_c, mvc = False, False, zero
     if not avail_b and not avail_c and avail_a:
-        p = mva
-    elif n_avail == 1:
-        p = mva if avail_a else (mvb if avail_b else mvc)
+        ref_b, mvb, ref_c, mvc = ref_a, mva, ref_a, mva
+    if int(ref_a) + int(ref_b) + int(ref_c) == 1:
+        # the one neighbour that refers to the same picture
+        p = mva if ref_a else (mvb if ref_b else mvc)
     else:
         p = np.array([_median3(int(mva[0]), int(mvb[0]), int(mvc[0])),
                       _median3(int(mva[1]), int(mvb[1]), int(mvc[1]))],
                      np.int32)
     if (not avail_a or not avail_b
-            or (mva[0] == 0 and mva[1] == 0)
-            or (mvb[0] == 0 and mvb[1] == 0)):
+            or (ref_a and mva[0] == 0 and mva[1] == 0)
+            or (ref_b and mvb[0] == 0 and mvb[1] == 0)):
         skip = zero
     else:
         skip = p
     return np.asarray(p, np.int32), np.asarray(skip, np.int32)
 
 
+def _decode_intra16_mb(br: BitReader, pic: _Picture, mi: int, first: int,
+                       i_type: int, qp: int) -> int:
+    """One Intra16x16 macroblock after its mb_type (`i_type`: Table
+    7-11's, 1..24), of an I slice or — §7.3.5, Table 7-13: mb_type 5 +
+    i_type — of a P slice: intra_chroma_pred_mode, mb_qp_delta, the
+    residual, and the reconstruction predicted from the CURRENT
+    picture's unfiltered samples, whatever kind the neighbours they
+    belong to are (constrained_intra_pred_flag is 0). Returns the QP
+    after the macroblock's delta."""
+    mbw = pic.mbw
+    my, mx = divmod(mi, mbw)
+    y, u, v = pic.y, pic.u, pic.v
+    luma_counts, chroma_counts = pic.luma_counts, pic.chroma_counts
+    luma_mode = (i_type - 1) % 4
+    cbp_chroma = ((i_type - 1) // 4) % 3
+    cbp_luma = 15 if (i_type - 1) >= 12 else 0
+    chroma_mode = br.ue()
+    qp += br.se()                       # mb_qp_delta
+    pic.qp_mb[my, mx] = qp
+    qpc = chroma_qp(qp)
+
+    # in-slice neighbor availability (§7.4.3): an MB in another
+    # slice is unavailable to prediction AND to nC derivation
+    a_ok = mx > 0 and mi - 1 >= first
+    b_ok = my > 0 and mi - mbw >= first
+    d_ok = my > 0 and mx > 0 and mi - mbw - 1 >= first
+
+    by0, bx0 = 4 * my, 4 * mx
+    na = int(luma_counts[by0, bx0 - 1]) if a_ok else None
+    nb = int(luma_counts[by0 - 1, bx0]) if b_ok else None
+    luma_dc = np.array(
+        cavlc.decode_residual(br, cavlc.luma_nc(na, nb), 16), np.int32)
+
+    luma_ac = np.zeros((16, 15), np.int32)
+    for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+        gy, gx = by0 + by, bx0 + bx
+        if cbp_luma:
+            na = (int(luma_counts[gy, gx - 1])
+                  if gx > bx0 or a_ok else None) if gx > 0 else None
+            nb = (int(luma_counts[gy - 1, gx])
+                  if gy > by0 or b_ok else None) if gy > 0 else None
+            coeffs = cavlc.decode_residual(br, cavlc.luma_nc(na, nb), 15)
+            luma_ac[bi] = coeffs
+            luma_counts[gy, gx] = sum(1 for c in coeffs if c)
+        else:
+            luma_counts[gy, gx] = 0
+
+    chroma_dc = np.zeros((2, 4), np.int32)
+    if cbp_chroma > 0:
+        for ci in range(2):
+            chroma_dc[ci] = cavlc.decode_residual(br, -1, 4)
+    chroma_ac = np.zeros((2, 4, 15), np.int32)
+    cy0, cx0 = 2 * my, 2 * mx
+    for ci in range(2):
+        for bi, (bx, by) in enumerate(CHROMA_BLOCK_ORDER):
+            gy, gx = cy0 + by, cx0 + bx
+            if cbp_chroma == 2:
+                na = (int(chroma_counts[ci, gy, gx - 1])
+                      if gx > cx0 or a_ok else None) if gx > 0 else None
+                nb = (int(chroma_counts[ci, gy - 1, gx])
+                      if gy > cy0 or b_ok else None) if gy > 0 else None
+                coeffs = cavlc.decode_residual(
+                    br, cavlc.luma_nc(na, nb), 15)
+                chroma_ac[ci, bi] = coeffs
+                chroma_counts[ci, gy, gx] = sum(1 for c in coeffs if c)
+            else:
+                chroma_counts[ci, gy, gx] = 0
+
+    # Reconstruct.
+    top = y[16 * my - 1, 16 * mx:16 * mx + 16] if b_ok else None
+    left = y[16 * my:16 * my + 16, 16 * mx - 1] if a_ok else None
+    tl = int(y[16 * my - 1, 16 * mx - 1]) if d_ok else None
+    pred = predict_luma16(luma_mode, top, left, tl)
+    y[16 * my:16 * my + 16, 16 * mx:16 * mx + 16] = reconstruct_luma16(
+        pred, luma_dc, luma_ac, qp)
+    for ci, plane in enumerate((u, v)):
+        ctop = plane[8 * my - 1, 8 * mx:8 * mx + 8] if b_ok else None
+        cleft = plane[8 * my:8 * my + 8, 8 * mx - 1] if a_ok else None
+        ctl = int(plane[8 * my - 1, 8 * mx - 1]) if d_ok else None
+        cpred = predict_chroma8(chroma_mode, ctop, cleft, ctl)
+        plane[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = reconstruct_chroma8(
+            cpred, chroma_dc[ci], chroma_ac[ci], qpc)
+    return qp
+
+
 def _decode_islice(br: BitReader, pic: _Picture,
                    header: SliceHeader) -> None:
     """Decode one I slice (any first_mb) into the picture state."""
-    mbw, mbh = pic.mbw, pic.mbh
-    nmb = mbw * mbh
-    first = header.first_mb
+    nmb = pic.mbw * pic.mbh
     qp = header.qp
-    y, u, v = pic.y, pic.u, pic.v
-    luma_counts, chroma_counts = pic.luma_counts, pic.chroma_counts
-
-    mi = first
+    mi = header.first_mb
     while mi < nmb and br.more_rbsp_data():
-        my, mx = divmod(mi, mbw)
         mb_type = br.ue()
         if not 1 <= mb_type <= 24:
             raise ValueError(f"unsupported I mb_type {mb_type}")
-        luma_mode = (mb_type - 1) % 4
-        cbp_chroma = ((mb_type - 1) // 4) % 3
-        cbp_luma = 15 if (mb_type - 1) >= 12 else 0
-        chroma_mode = br.ue()
-        qp += br.se()                       # mb_qp_delta
-        pic.qp_mb[my, mx] = qp
-        qpc = chroma_qp(qp)
-
-        # in-slice neighbor availability (§7.4.3): an MB in another
-        # slice is unavailable to prediction AND to nC derivation
-        a_ok = mx > 0 and mi - 1 >= first
-        b_ok = my > 0 and mi - mbw >= first
-        d_ok = my > 0 and mx > 0 and mi - mbw - 1 >= first
-
-        by0, bx0 = 4 * my, 4 * mx
-        na = int(luma_counts[by0, bx0 - 1]) if a_ok else None
-        nb = int(luma_counts[by0 - 1, bx0]) if b_ok else None
-        luma_dc = np.array(
-            cavlc.decode_residual(br, cavlc.luma_nc(na, nb), 16), np.int32)
-
-        luma_ac = np.zeros((16, 15), np.int32)
-        for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
-            gy, gx = by0 + by, bx0 + bx
-            if cbp_luma:
-                na = (int(luma_counts[gy, gx - 1])
-                      if gx > bx0 or a_ok else None) if gx > 0 else None
-                nb = (int(luma_counts[gy - 1, gx])
-                      if gy > by0 or b_ok else None) if gy > 0 else None
-                coeffs = cavlc.decode_residual(br, cavlc.luma_nc(na, nb), 15)
-                luma_ac[bi] = coeffs
-                luma_counts[gy, gx] = sum(1 for c in coeffs if c)
-            else:
-                luma_counts[gy, gx] = 0
-
-        chroma_dc = np.zeros((2, 4), np.int32)
-        if cbp_chroma > 0:
-            for ci in range(2):
-                chroma_dc[ci] = cavlc.decode_residual(br, -1, 4)
-        chroma_ac = np.zeros((2, 4, 15), np.int32)
-        cy0, cx0 = 2 * my, 2 * mx
-        for ci in range(2):
-            for bi, (bx, by) in enumerate(CHROMA_BLOCK_ORDER):
-                gy, gx = cy0 + by, cx0 + bx
-                if cbp_chroma == 2:
-                    na = (int(chroma_counts[ci, gy, gx - 1])
-                          if gx > cx0 or a_ok else None) if gx > 0 else None
-                    nb = (int(chroma_counts[ci, gy - 1, gx])
-                          if gy > cy0 or b_ok else None) if gy > 0 else None
-                    coeffs = cavlc.decode_residual(
-                        br, cavlc.luma_nc(na, nb), 15)
-                    chroma_ac[ci, bi] = coeffs
-                    chroma_counts[ci, gy, gx] = sum(1 for c in coeffs if c)
-                else:
-                    chroma_counts[ci, gy, gx] = 0
-
-        # Reconstruct.
-        top = y[16 * my - 1, 16 * mx:16 * mx + 16] if b_ok else None
-        left = y[16 * my:16 * my + 16, 16 * mx - 1] if a_ok else None
-        tl = int(y[16 * my - 1, 16 * mx - 1]) if d_ok else None
-        pred = predict_luma16(luma_mode, top, left, tl)
-        y[16 * my:16 * my + 16, 16 * mx:16 * mx + 16] = reconstruct_luma16(
-            pred, luma_dc, luma_ac, qp)
-        for ci, plane in enumerate((u, v)):
-            ctop = plane[8 * my - 1, 8 * mx:8 * mx + 8] if b_ok else None
-            cleft = plane[8 * my:8 * my + 8, 8 * mx - 1] if a_ok else None
-            ctl = int(plane[8 * my - 1, 8 * mx - 1]) if d_ok else None
-            cpred = predict_chroma8(chroma_mode, ctop, cleft, ctl)
-            plane[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = reconstruct_chroma8(
-                cpred, chroma_dc[ci], chroma_ac[ci], qpc)
+        qp = _decode_intra16_mb(br, pic, mi, header.first_mb, mb_type, qp)
         pic.decoded += 1
         mi += 1
 
@@ -353,7 +382,8 @@ def _recon_p_mb(pic: _Picture, ref: _RefFrame, my: int, mx: int, mv,
 
 def _decode_pslice(br: BitReader, pic: _Picture, header: SliceHeader,
                    ref: _RefFrame) -> None:
-    """Decode one P slice (any first_mb): skip runs, P_L0_16x16 MBs."""
+    """Decode one P slice (any first_mb): skip runs, P_L0_16x16 and
+    Intra16x16 MBs."""
     mbw, mbh = pic.mbw, pic.mbh
     nmb = mbw * mbh
     first = header.first_mb
@@ -380,6 +410,14 @@ def _decode_pslice(br: BitReader, pic: _Picture, header: SliceHeader,
             break                              # trailing skip run
         my, mx = divmod(mi, mbw)
         mb_type = br.ue()
+        if 6 <= mb_type <= 29:
+            # Table 7-13: an Intra16x16 macroblock, 5 + Table 7-11's
+            qp = _decode_intra16_mb(br, pic, mi, first, mb_type - 5, qp)
+            pic.intra_mb[my, mx] = True
+            pic.mv[my, mx] = 0
+            pic.decoded += 1
+            mi += 1
+            continue
         if mb_type != 0:
             raise ValueError(f"unsupported P mb_type {mb_type}")
         mvd_x = br.se()                        # quarter samples, x first
@@ -443,6 +481,7 @@ def decode_annexb(stream: bytes) -> DecodedStream:
     pps: PPS | None = None
     frames: list[Frame] = []
     mvs: list = []
+    intra_mbs: list = []
     pic: _Picture | None = None
     ref: _RefFrame | None = None
 
@@ -467,12 +506,14 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             pic.y, pic.u, pic.v = deblock_frame(
                 pic.y, pic.u, pic.v, pic.qp_mb, intra=pic.intra,
                 nz4=nz4, mv=None if pic.intra else pic.mv, mv_per_pel=4,
-                edges=pic.deblock_edges())
+                edges=pic.deblock_edges(),
+                intra_mb=pic.intra_mb if pic.intra_mb.any() else None)
         w, h = sps.width, sps.height
         frames.append(Frame(
             pic.y[:h, :w], pic.u[:h // 2, :w // 2],
             pic.v[:h // 2, :w // 2], pts=len(frames)))
         mvs.append(None if pic.intra else pic.mv)
+        intra_mbs.append(None if pic.intra else pic.intra_mb)
         ref = _RefFrame(pic)                  # next P picture's reference
         pic = None
 
@@ -514,4 +555,5 @@ def decode_annexb(stream: bytes) -> DecodedStream:
                      fps_num=sps.fps_num, fps_den=sps.fps_den,
                      num_frames=len(frames), chroma=ChromaFormat.YUV420,
                      codec="h264", size_bytes=len(stream))
-    return DecodedStream(meta=meta, frames=frames, mvs=mvs)
+    return DecodedStream(meta=meta, frames=frames, mvs=mvs,
+                         intra_mbs=intra_mbs)

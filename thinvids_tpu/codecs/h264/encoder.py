@@ -670,13 +670,14 @@ def gop_slice_thunks_planes(intra, planes, num_frames: int, mbw: int,
 
     deblock_idc = 0 if rd is not None and rd.deblock else 1
     mv_per_pel = rd.mv_per_pel if rd is not None else 2
-    mv8, lp, udc, vdc, uac, vac = planes
+    mv8, lp, udc, vdc, uac, vac, *pmode = planes
     return _gop_slice_thunks(
         intra,
         lambda i, fn: inter_mod.pack_p_slice_plane(
             mv8[i], lp[i], udc[i], vdc[i], uac[i], vac[i], mbw, mbh,
             sps, pps, qp, frame_num=fn, deblock_idc=deblock_idc,
-            mv_per_pel=mv_per_pel),
+            mv_per_pel=mv_per_pel,
+            pmode=pmode[0][i] if pmode else None),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         rd=rd)
 
@@ -688,7 +689,8 @@ def pack_gop_slices_planes(intra, planes, num_frames: int, mbw: int,
     """Entropy-pack one GOP whose P frames arrive as PLANE-layout level
     arrays (the sharded transfer format, jaxinter.encode_gop_planes):
     planes = (mv8 (F-1,nmb,2) int8, luma planes (F-1,H,W) int16,
-    u_dc/v_dc (F-1,nmb,4) int16, u_ac/v_ac (F-1,H/2,W/2) int16).
+    u_dc/v_dc (F-1,nmb,4) int16, u_ac/v_ac (F-1,H/2,W/2) int16[,
+    pmode (F-1,nmb): the kind channel of rd.p_intra]).
     The intra frame stays blocked (jaxcore._intra_core emits blocked).
     Bit-identical to pack_gop_slices on the equivalent blocked arrays."""
     return run_slice_thunks(
@@ -705,19 +707,21 @@ def pack_gop_slices(intra, pouts, num_frames: int, mbw: int, mbh: int,
     (the single-device encode_gop path).
 
     intra: (luma_dc, luma_ac, chroma_dc, chroma_ac[, mode16, dqp16]);
-    pouts: the P frames' (mv, luma16, chroma_dc, chroma_ac), leading
-    dim >= num frames - 1 (extra tail-padding entries are ignored).
+    pouts: the P frames' (mv, luma16, chroma_dc, chroma_ac[, pmode]),
+    leading dim >= num frames - 1 (extra tail-padding entries are
+    ignored).
     """
     from . import inter as inter_mod
 
     deblock_idc = 0 if rd is not None and rd.deblock else 1
     mv_per_pel = rd.mv_per_pel if rd is not None else 2
-    mv, l16, cdc, cac = pouts
+    mv, l16, cdc, cac, *pmode = pouts
     return _pack_gop_common(
         intra,
         lambda i, fn: inter_mod.pack_p_slice(
             mv[i], l16[i], cdc[i], cac[i], mbw, mbh, sps, pps, qp,
             frame_num=fn, deblock_idc=deblock_idc,
-            mv_per_pel=mv_per_pel),
+            mv_per_pel=mv_per_pel,
+            pmode=pmode[0][i] if pmode else None),
         num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
         pool=pool, rd=rd)

@@ -118,10 +118,11 @@ JAX_OPS = _JaxOps()
 def deblock_frame_jax(y, u, v, qp_map, *, intra: bool, nz4=None,
                       mv=None, mb_row0=0,
                       total_mb_rows: int | None = None,
-                      mv_per_pel: int = 2):
+                      mv_per_pel: int = 2, intra_mb=None):
     """Traced deblock of one (padded) frame or band slice — see
     deblock.deblock_frame for the argument contract. Input planes keep
     their dtypes (int16 recon in, int16 out)."""
     return deblock_frame(y, u, v, qp_map, intra=intra, nz4=nz4, mv=mv,
                          mb_row0=mb_row0, total_mb_rows=total_mb_rows,
-                         mv_per_pel=mv_per_pel, ops=JAX_OPS)
+                         mv_per_pel=mv_per_pel, intra_mb=intra_mb,
+                         ops=JAX_OPS)

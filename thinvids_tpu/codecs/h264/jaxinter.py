@@ -37,13 +37,16 @@ import jax
 import jax.numpy as jnp
 
 from .jaxcore import (
+    _H4,
     _MF,
     _QPC,
     _V,
     _ZZ,
     _ZSCAN,
     _intra_core,
+    _luma_dc_quant,
     _mode_tail,
+    _pick3,
     _varying_zero,
 )
 from . import jaxdeblock, jaxme, rdo
@@ -118,12 +121,13 @@ def _class_tiles(tbl, shape):
                      jnp.where(cls == 1, tbl[0, 1], tbl[1, 1]))
 
 
-def _quant_plane(w, mf_plane, qp):
+def _quant_plane(w, mf_plane, qp, bias_div: int = 6):
     """Quantize an INTER coefficient plane with the f = (1 << qbits) / 6
     rounding bias (over-rounding inter residuals inflates levels and
-    bitrate; the intra paths in jaxcore keep the standard 1/3)."""
+    bitrate; the intra paths, jaxcore's and `_p_intra`'s `bias_div` 3,
+    keep the standard 1/3)."""
     qbits = 15 + qp // 6
-    f = (1 << qbits) // 6
+    f = (1 << qbits) // bias_div
     z = (jnp.abs(w) * mf_plane + f) >> qbits
     return jnp.where(w < 0, -z, z)
 
@@ -193,6 +197,11 @@ def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
     sharded transfer path's format; the relayout then happens on host
     inside the pack pool (measured: the blocked transposes + zigzag
     gathers cost ~0.5 s per 1080p GOP on a v5e chip).
+
+    With rd.p_intra every macroblock is then coded inter or Intra16x16
+    (:func:`_p_intra`), the filter reads its bS from the mixed picture,
+    and the tuple ends in `pmode`, the (nmb,) int16 kind channel
+    (rdo.pmode_word; 0 = inter).
     """
     n = mbw * mbh
     with stage("layout"):
@@ -206,17 +215,28 @@ def _encode_p_plane(cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, *, mbw: int,
 
     (luma_levels, chroma_dc, chroma_ac, recon_y, recon_u, recon_v,
      nz4) = _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp,
-                        qpc, mbw=mbw, mbh=mbh, blocked=blocked, rd=rd)
+                        qpc, mbw=mbw, mbh=mbh,
+                        blocked=blocked and not rd.p_intra, rd=rd)
+    pmode = intra_mb = None
+    if rd.p_intra:
+        (luma_levels, chroma_dc, chroma_ac, recon_y, recon_u, recon_v,
+         mv, pmode) = _p_intra(
+            (cy16, cu16, cv16), (pred_y, pred_u, pred_v),
+            (luma_levels, chroma_dc, chroma_ac),
+            (recon_y, recon_u, recon_v), mv, med_mv, qp, qpc, mbw=mbw,
+            mbh=mbh, blocked=blocked, rd=rd)
+        intra_mb = pmode.reshape(mbh, mbw) != 0
     if rd.deblock:
         with stage("deblock"):
             qp_map = jnp.broadcast_to(qp.astype(jnp.int32), (mbh, mbw))
         recon_y, recon_u, recon_v = jaxdeblock.deblock_frame_jax(
             recon_y, recon_u, recon_v, qp_map, intra=False, nz4=nz4,
-            mv=mv, mv_per_pel=rd.mv_per_pel)
+            mv=mv, mv_per_pel=rd.mv_per_pel, intra_mb=intra_mb)
     with stage("layout"):
         mv = mv.reshape(n, 2)
-    return (mv, luma_levels, chroma_dc, chroma_ac,
-            recon_y, recon_u, recon_v, med_mv)
+    out = (mv, luma_levels, chroma_dc, chroma_ac,
+           recon_y, recon_u, recon_v, med_mv)
+    return out + (pmode,) if rd.p_intra else out
 
 
 @stage("residual")
@@ -343,6 +363,315 @@ def _residual_p(cy16, cu16, cv16, pred_y, pred_u, pred_v, qp, qpc, *,
             nz4)
 
 
+# ---------------------------------------------------------------------------
+# rd.p_intra: intra macroblocks in P pictures
+# ---------------------------------------------------------------------------
+
+_H4_NP = np.asarray(_H4, np.float32)
+# 1-D half of the Intra16x16 luma DC Hadamard over the DC positions (0,
+# 4, 8, 12) of a 16-sample MB row or column; every other position reads 0
+_HAD16_DC = np.zeros((16, 16), np.float32)
+_HAD16_DC[::4, ::4] = _H4_NP
+
+
+def _mb_spread(m, shape, mb: int):
+    """A (R, C) map over (T, R * mb, 128) tiles: entry (r, c) over the
+    `mb` rows and `mb` lanes of its block (int32)."""
+    T, g = shape[0], _LANES // mb
+    R, C = m.shape
+    m = jnp.pad(m, ((0, 0), (0, T * g - C))).reshape(R, T, g)
+    wide = _lane_spread(m.transpose(1, 0, 2), mb)        # (T, R, 128)
+    return jnp.broadcast_to(wide[:, :, None, :], (T, R, mb, _LANES)
+                            ).reshape(shape).astype(jnp.int32)
+
+
+def _mb_sum(x, mb: int, ncols: int):
+    """(T, H, 128) tiles -> (H // mb, ncols) int32: the sum over every
+    mb x mb block (exact below 2**24)."""
+    T, H, _ = x.shape
+    rows = x.astype(_F32).reshape(T, H // mb, mb, _LANES).sum(axis=2)
+    return _tile_maps(_lane_pool(rows, mb), ncols).astype(jnp.int32)
+
+
+def _intra_preds(rec_t, mb: int, mbw: int, mbh: int):
+    """The V and H prediction tiles of every macroblock of a plane from
+    its neighbours' reconstruction `rec_t` (§8.3.3), and the lines they
+    were made from (for DC): (pred_v, pred_h, top, left) — `top` (T,
+    mbh, 128), the sample row above each macroblock row (zeros above
+    the first), `left` (H, mbw), the sample column left of each
+    macroblock column (zeros left of the first), all int32."""
+    T, H, _ = rec_t.shape
+    g = _LANES // mb
+    bottom = rec_t[:, mb - 1::mb].astype(jnp.int32)      # (T, mbh, 128)
+    top = jnp.concatenate([jnp.zeros_like(bottom[:, :1]), bottom[:, :-1]],
+                          axis=1)
+    pick = jnp.asarray((np.arange(_LANES)[:, None]
+                        == np.arange(mb - 1, _LANES, mb)[None, :]
+                        ).astype(np.float32))
+    right = _tile_maps(jnp.einsum("thl,lm->thm", rec_t.astype(_F32), pick,
+                                  precision=_HI), mbw)   # (H, mbw)
+    left = jnp.pad(right, ((0, 0), (1, 0)))[:, :mbw].astype(jnp.int32)
+    pred_v = jnp.broadcast_to(top[:, :, None, :], (T, mbh, mb, _LANES)
+                              ).reshape(T, H, _LANES)
+    cols = jnp.pad(left, ((0, 0), (0, T * g - mbw))).reshape(H, T, g)
+    pred_h = _lane_spread(cols.transpose(1, 0, 2), mb).astype(jnp.int32)
+    return pred_v, pred_h, top, left
+
+
+def _mb_cost(resid, mb: int, ncols: int, satd: bool):
+    """Per-macroblock cost of residual tiles: SATD (4x4 Hadamard, / 2,
+    jaxcore._satd16's) or SAD."""
+    if satd:
+        t = jnp.abs(_lane_mm(_row_mm(resid, _H4_NP), _H4_NP))
+        return _mb_sum(t, mb, ncols) // 2
+    return _mb_sum(jnp.abs(resid), mb, ncols)
+
+
+def _se_bits(v):
+    """Bits of se(v), v a small int32 array: 2 * floor(log2(2|v|)) + 1
+    (1 for 0), the logarithm counted on thresholds."""
+    x = 2 * jnp.abs(v)
+    n = sum((x >= (1 << k)).astype(jnp.int32) for k in range(1, 11))
+    return 2 * n + 1
+
+
+def _intra_candidates(rec_t, mb: int, mbw: int, mbh: int, chroma: bool):
+    """(V, H, DC) prediction tiles of every macroblock of one plane from
+    the reconstruction `rec_t` of the macroblocks left of and above it,
+    by §8.3.3 (luma, 16x16) or §8.3.4 (chroma, 8x8; DC per 4x4 block)."""
+    shape = rec_t.shape
+    pred_v, pred_h, top, left = _intra_preds(rec_t, mb, mbw, mbh)
+    row = jax.lax.broadcasted_iota(jnp.int32, (mbh, mbw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (mbh, mbw), 1)
+    has_top, has_left = row > 0, col > 0
+    both = has_top & has_left
+    if not chroma:
+        tsum = _tile_maps(_lane_pool(top, 16), mbw).astype(jnp.int32)
+        lsum = left.reshape(mbh, 16, mbw).sum(axis=1)
+        dc = jnp.where(both, (tsum + lsum + 16) >> 5,
+                       jnp.where(has_top, (tsum + 8) >> 4,
+                                 jnp.where(has_left, (lsum + 8) >> 4, 128)))
+        return pred_v, pred_h, _mb_spread(dc, shape, 16)
+    t = _tile_maps(_lane_pool(top, 4), 2 * mbw).astype(jnp.int32)
+    t0, t1 = t[:, 0::2], t[:, 1::2]
+    ls = left.reshape(mbh, 2, 4, mbw).sum(axis=2)
+    l0, l1 = ls[:, 0], ls[:, 1]
+
+    def one(tq, lq, first_top: bool):
+        mean_t, mean_l = (tq + 2) >> 2, (lq + 2) >> 2
+        a, b = (mean_t, mean_l) if first_top else (mean_l, mean_t)
+        ok_a, ok_b = (has_top, has_left) if first_top \
+            else (has_left, has_top)
+        return jnp.where(ok_a, a, jnp.where(ok_b, b, 128))
+
+    q00 = jnp.where(both, (t0 + l0 + 4) >> 3, one(t0, l0, True))
+    q10 = one(t1, l0, True)                 # prefers its own top quarter
+    q01 = one(t0, l1, False)                # prefers its own left quarter
+    q11 = jnp.where(both, (t1 + l1 + 4) >> 3, one(t1, l1, True))
+    quads = jnp.stack([jnp.stack([q00, q10], -1),
+                       jnp.stack([q01, q11], -1)], 1
+                      ).reshape(2 * mbh, 2 * mbw)
+    return pred_v, pred_h, _mb_spread(quads, shape, 4)
+
+
+def _by_mode(mode, which, shape, mb: int, preds):
+    """Of `preds` (V, H, DC) every macroblock's own, by its `mode` map;
+    `which` = the mode numbers of (V, H)."""
+    m = _mb_spread(mode, shape, mb)
+    return jnp.where(m == which[0], preds[0],
+                     jnp.where(m == which[1], preds[1], preds[2]))
+
+
+def _dc_positions(shape):
+    return ((jax.lax.broadcasted_iota(jnp.int32, shape, 1) % 4 == 0)
+            & (jax.lax.broadcasted_iota(jnp.int32, shape, 2) % 4 == 0))
+
+
+def _intra16_luma(src_t, pred_t, qp32):
+    """Intra16x16 luma of every macroblock of the tiles, in
+    jaxcore._luma_mb_batch's arithmetic: (levels, recon), the levels'
+    DC positions holding the Hadamard-domain DC levels (§8.5.10: level
+    (u, v) of a macroblock at the DC position of its block (u, v))."""
+    ys = src_t.shape
+    dc_pos = _dc_positions(ys)
+    w = _fwd4_tiles(src_t - pred_t)
+    wd = _row_mm(_lane_mm(w, _HAD16_DC), _HAD16_DC).astype(jnp.int32) // 2
+    z = jnp.where(dc_pos, _luma_dc_quant(wd, qp32),
+                  _quant_plane(w, _class_tiles(_MF[qp32 % 6], ys), qp32,
+                               bias_div=3))
+    fdc = _row_mm(_lane_mm(jnp.where(dc_pos, z, 0), _HAD16_DC),
+                  _HAD16_DC).astype(jnp.int32)
+    # jaxcore._luma_dc_dequant's scaling of the inverse Hadamard
+    ls = _V[qp32 % 6, 0, 0] * 16
+    shift = jnp.maximum(6 - qp32 // 6, 1)
+    dcr = jnp.where(qp32 >= 36,
+                    (fdc * ls) << jnp.maximum(qp32 // 6 - 6, 0),
+                    (fdc * ls + (1 << (shift - 1))) >> shift)
+    d = jnp.where(dc_pos, dcr,
+                  _dequant_plane(z, _class_tiles(_V[qp32 % 6], ys), qp32))
+    return z, jnp.clip((_inv4_tiles(d) + 32 >> 6) + pred_t, 0, 255)
+
+
+def _intra_chroma(src_t, pred_t, qpc):
+    """One chroma plane of every macroblock coded intra, in
+    jaxcore._chroma_mb_batch's arithmetic (the intra rounding, where
+    `_residual_p` has the inter one): (levels with the Hadamard-domain
+    DC levels at the DC positions, recon)."""
+    cs = src_t.shape
+    dc_pos = _dc_positions(cs)
+    wch = _fwd4_tiles(src_t - pred_t)
+    wd2 = _chroma_dc_tiles(wch)
+    qbits = 15 + qpc // 6
+    zdc = (jnp.abs(wd2) * _MF[qpc % 6, 0, 0] + 2 * ((1 << qbits) // 3)
+           ) >> (qbits + 1)
+    z = jnp.where(dc_pos, jnp.where(wd2 < 0, -zdc, zdc),
+                  _quant_plane(wch, _class_tiles(_MF[qpc % 6], cs), qpc,
+                               bias_div=3))
+    dcr = ((_chroma_dc_tiles(jnp.where(dc_pos, z, 0))
+            * (_V[qpc % 6, 0, 0] * 16)) << (qpc // 6)) >> 5
+    d = jnp.where(dc_pos, dcr,
+                  _dequant_plane(z, _class_tiles(_V[qpc % 6], cs), qpc))
+    return z, jnp.clip((_inv4_tiles(d) + 32 >> 6) + pred_t, 0, 255)
+
+
+@stage("p_intra")
+def _p_intra(src, pred, levels, recon, mv, med_mv, qp, qpc, *, mbw: int,
+             mbh: int, blocked: bool, rd):
+    """rd.p_intra: of every macroblock of a P picture, inter as
+    :func:`_residual_p` coded it or Intra16x16.
+
+    src, pred: the (y, u, v) source and inter prediction planes;
+    levels: `_residual_p`'s PLANE-layout (luma, chroma_dc, chroma_ac);
+    recon: its unfiltered reconstruction; mv (mbh, mbw, 2) with the
+    frame's median `med_mv`. Returns (luma, chroma_dc, chroma_ac,
+    recon_y, recon_u, recon_v, mv, pmode): the mixed levels (blocked
+    when `blocked`), the mixed unfiltered reconstruction, the vectors
+    with the intra macroblocks' zeroed, and the (nmb,) int16 kind
+    channel (rdo.pmode_word).
+
+    An intra macroblock's luma levels lie where an inter one's do, the
+    Hadamard-domain DC level (u, v) of §8.5.10 at the DC position of
+    its 4x4 block (u, v) — as `_residual_p` keeps the chroma DC levels
+    — so the transfer layouts carry both kinds in one plane.
+
+    Schedule (no scan over macroblock rows: everything is
+    plane-parallel). THE WISH: the three Intra16x16 predictions (V, H,
+    DC, by availability) of EVERY macroblock from its A / B neighbours'
+    all-inter reconstruction, at once; the cost of the best against the
+    inter prediction's on one scale (SATD with rd.mode_decision, else
+    SAD), each plus lambda times its side bits (rdo.P_INTRA_BITS; the
+    vector's against the frame's median); ties stay inter. THE CODING,
+    in rdo.P_INTRA_PASSES passes over the picture: pass k codes the
+    wishing macroblocks of class (x + y) mod passes == k, each in the
+    mode the wish chose, predicted from the reconstruction AS IT STANDS
+    — its A / B neighbours are of class k - 1, coded in the pass before
+    and final. Class 0's neighbours are of the last class, coded last:
+    a macroblock of that class stays inter where the one right of it or
+    below it went intra in pass 0, so what that one was predicted from
+    stands. What is coded is thus what §8.3.3 makes a decoder form,
+    sample for sample; in a solid region of wishes one macroblock in
+    `passes` stays inter. A candidate with a level beyond the sparse
+    wire's int8 (a DC step of 88 or more at QP 25) stays inter: one
+    such level would send its whole wave through the dense fallback."""
+    cy16, cu16, cv16 = src
+    zy_p, cdc_p, cac_p = levels
+    W = cy16.shape[1]
+    n = mbw * mbh
+    qp32 = qp.astype(jnp.int32)
+    satd = rd.mode_decision
+    srcs = [_to_tiles(c).astype(jnp.int32) for c in src]
+    recs = tuple(_to_tiles(r).astype(jnp.int32) for r in recon)
+    ys, cs = srcs[0].shape, srcs[1].shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (mbh, mbw), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (mbh, mbw), 1)
+    has_top, has_left = row > 0, col > 0
+    inf = jnp.int32(1 << 29)
+    sizes = (16, 8, 8)
+
+    def candidates(recs):
+        return [_intra_candidates(r, mb, mbw, mbh, chroma=mb == 8)
+                for r, mb in zip(recs, sizes)]
+
+    # --- the wish: modes and costs from the all-inter neighbours -----
+    cand = candidates(recs)
+    costs = [[_mb_cost(s_t - p, mb, mbw, satd) for p in preds]
+             for s_t, preds, mb in zip(srcs, cand, sizes)]
+    (c_v, c_h, c_dc), cu, cv_ = costs
+    # strict <, earlier wins: DC (always there), then V, then H
+    best_y, ymode = _pick3(c_dc, 2, jnp.where(has_top, c_v, inf), 0,
+                           jnp.where(has_left, c_h, inf), 1)
+    best_c, cmode = _pick3(cu[2] + cv_[2], 0,
+                           jnp.where(has_top, cu[0] + cv_[0], inf), 2,
+                           jnp.where(has_left, cu[1] + cv_[1], inf), 1)
+    inter = sum(_mb_cost(s_t - _to_tiles(p), mb, mbw, satd)
+                for s_t, p, mb in zip(srcs, pred, sizes))
+    lam = jnp.asarray(rdo.P_INTRA_LAMBDA, jnp.int32)[jnp.clip(qp32, 0, 51)]
+    mvd = (mv.astype(jnp.int32) - med_mv.astype(jnp.int32)
+           ) * (4 // rd.mv_per_pel)
+    inter = inter + lam * (_se_bits(mvd[..., 0]) + _se_bits(mvd[..., 1]))
+    wish = best_y + best_c + lam * rdo.P_INTRA_BITS < inter
+
+    # --- the coding: one class of macroblocks a pass ------------------
+    passes = rdo.P_INTRA_PASSES
+    dc_pos_c = _dc_positions(cs)
+
+    def code_class(k, state):
+        zy, zu, zv, udc, vdc, recs, intra_mb = state
+        cand = candidates(recs)
+        zy_i, ry_i = _intra16_luma(
+            srcs[0], _by_mode(ymode, (0, 1), ys, 16, cand[0]), qp32)
+        zc_i, rc_i = zip(*(
+            _intra_chroma(s_t, _by_mode(cmode, (2, 1), cs, 8, preds), qpc)
+            for s_t, preds in zip(srcs[1:], cand[1:])))
+        peak = _mb_sum(jnp.abs(zy_i) > 127, 16, mbw) + sum(
+            _mb_sum(jnp.abs(z) > 127, 8, mbw) for z in zc_i)
+        # the macroblock right of / below one that is intra already
+        # was predicted from this one as it stands
+        after = (jnp.pad(intra_mb, ((0, 0), (0, 1)))[:, 1:]
+                 | jnp.pad(intra_mb, ((0, 1), (0, 0)))[1:])
+        take = wish & ((row + col) % passes == k) & (peak == 0) & ~after
+        take_y = _mb_spread(take, ys, 16) > 0
+        take_c = _mb_spread(take, cs, 8) > 0
+        take_mb = take.reshape(n, 1)
+        zu, zv = (jnp.where(take_c, jnp.where(dc_pos_c, 0, z_i), z)
+                  for z_i, z in zip(zc_i, (zu, zv)))
+        udc, vdc = (jnp.where(take_mb,
+                              _chroma_dc_levels(z_i, mbw).reshape(n, 4), dc)
+                    for z_i, dc in zip(zc_i, (udc, vdc)))
+        recs = tuple(jnp.where(t, r_i, r) for t, r_i, r in zip(
+            (take_y, take_c, take_c), (ry_i, *rc_i), recs))
+        return (jnp.where(take_y, zy_i, zy), zu, zv, udc, vdc, recs,
+                intra_mb | take)
+
+    none = (jnp.zeros((mbh, mbw), jnp.int32) + _varying_zero(cy16)) > 0
+    state = (_to_tiles(zy_p).astype(jnp.int32),
+             _to_tiles(cac_p[0]).astype(jnp.int32),
+             _to_tiles(cac_p[1]).astype(jnp.int32),
+             cdc_p[0].astype(jnp.int32), cdc_p[1].astype(jnp.int32),
+             recs, none)
+    zy, zu, zv, udc, vdc, recs, intra_mb = jax.lax.fori_loop(
+        0, passes, code_class, state)
+
+    zy = _from_tiles(zy.astype(jnp.int16), W)
+    cac = [_from_tiles(z.astype(jnp.int16), W // 2) for z in (zu, zv)]
+    cdc = [udc.astype(jnp.int16), vdc.astype(jnp.int16)]
+    recon_y = _from_tiles(recs[0].astype(jnp.int16), W)
+    recon_u = _from_tiles(recs[1].astype(jnp.int16), W // 2)
+    recon_v = _from_tiles(recs[2].astype(jnp.int16), W // 2)
+    pmode = jnp.where(intra_mb, rdo.pmode_word(ymode, cmode), 0
+                      ).reshape(n).astype(jnp.int16)
+    mv = jnp.where(intra_mb[..., None], 0, mv)
+    if blocked:
+        zy = _luma_plane_to_blocks(zy, mbw, mbh).astype(jnp.int32)
+        chroma_dc = jnp.stack(cdc, axis=1).astype(jnp.int32)
+        chroma_ac = jnp.stack(
+            [_chroma_plane_to_blocks(a, mbw, mbh)[..., 1:] for a in cac],
+            axis=1).astype(jnp.int32)
+    else:
+        chroma_dc, chroma_ac = jnp.stack(cdc), jnp.stack(cac)
+    return (zy, chroma_dc, chroma_ac, recon_y, recon_u, recon_v, mv, pmode)
+
+
 def _intra_frame_outputs(y, u, v, qp, *, mbw: int, mbh: int, rd):
     """Shared IDR half of the GOP programs: intra core + (optionally)
     deblocked recon carry + the pack-facing intra tuple (4 blocked
@@ -400,7 +729,8 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
 
     ys: (F, H, W) uint8. Returns the intra frame's level arrays (plus
     the mode/dqp side channel when rd.ships_modes) and the P frames'
-    (mv, luma16, chroma_dc, chroma_ac) stacked over F-1; with
+    (mv, luma16, chroma_dc, chroma_ac), with rd.p_intra also their
+    `pmode` (F-1, nmb), stacked over F-1; with
     `emit_recon` also the per-frame reconstructed planes (tests/metrics
     — costs F x frame HBM, off by default). With rd.deblock the recon
     chained between frames (and emitted) is the §8.7-filtered plane —
@@ -413,24 +743,24 @@ def encode_gop_jit(ys, us, vs, qp, *, mbw: int, mbh: int,
     def p_step(carry, xs):
         ry, ru, rv, pred_mv = carry
         cy, cu, cv = xs
-        (mv, l16, cdc, cac, ry2, ru2, rv2, med_mv) = _encode_p_plane(
+        (mv, l16, cdc, cac, ry2, ru2, rv2, med_mv, *pmode
+         ) = _encode_p_plane(
             cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, mbw=mbw, mbh=mbh,
             rd=rd)
-        outs = (mv, l16, cdc, cac)
+        outs = (mv, l16, cdc, cac, *pmode)
         if emit_recon:
             outs = outs + (ry2, ru2, rv2)
         return (ry2, ru2, rv2, med_mv), outs
 
     pouts = _scan_p_frames(p_step, (ry, ru, rv), (ys, us, vs))
     if emit_recon:
-        mv, l16, cdc, cac, pry, pru, prv = pouts
+        *pouts, pry, pru, prv = pouts
         with stage("layout"):
             recon_y = jnp.concatenate([ry[None], pry]).astype(jnp.int32)
             recon_u = jnp.concatenate([ru[None], pru]).astype(jnp.int32)
             recon_v = jnp.concatenate([rv[None], prv]).astype(jnp.int32)
-        return intra, (mv, l16, cdc, cac), (recon_y, recon_u, recon_v)
-    mv, l16, cdc, cac = pouts
-    return intra, (mv, l16, cdc, cac)
+        return intra, tuple(pouts), (recon_y, recon_u, recon_v)
+    return intra, tuple(pouts)
 
 
 # Per-MB flat sizes for the plane-layout GOP transfer: the P part of the
@@ -465,6 +795,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
       | luma coeff planes   (F-1, H, W)
       | u DC (F-1, nmb, 4) | v DC (F-1, nmb, 4)
       | u AC plane (F-1, H/2, W/2) | v AC plane (F-1, H/2, W/2)
+      | P pmode (F-1, nmb)                        — rd.p_intra only
       | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only ]
     The host inverse is codecs/h264/layout.unflatten_gop.
     """
@@ -476,12 +807,14 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
     def p_step(carry, xs):
         ry, ru, rv, pred_mv = carry
         cy, cu, cv = xs
-        (mv, lp, cdc, cac, ry2, ru2, rv2, med_mv) = _encode_p_plane(
+        (mv, lp, cdc, cac, ry2, ru2, rv2, med_mv, *pmode
+         ) = _encode_p_plane(
             cy, cu, cv, ry, ru, rv, pred_mv, qp, qpc, mbw=mbw, mbh=mbh,
             blocked=False, rd=rd)
-        return (ry2, ru2, rv2, med_mv), (mv.astype(jnp.int8), lp, cdc, cac)
+        return (ry2, ru2, rv2, med_mv), (mv.astype(jnp.int8), lp, cdc, cac,
+                                         *pmode)
 
-    mv8, lps, cdcs, cacs = _scan_p_frames(
+    mv8, lps, cdcs, cacs, *pmodes = _scan_p_frames(
         p_step, (ry, ru, rv), (ys, us, vs), n_frames)
     # cdcs: (F-1, 2, n, 4) int16; cacs: (F-1, 2, H/2, W/2) int16
     with stage("layout"):
@@ -494,6 +827,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
             cdcs[:, 0].reshape(-1), cdcs[:, 1].reshape(-1),
             cacs[:, 0].reshape(-1), cacs[:, 1].reshape(-1),
         ]
+        parts.extend(m.reshape(-1) for m in pmodes)
         if rd.ships_modes:
             parts.extend([intra[4], intra[5]])
         flat = jnp.concatenate(parts)

@@ -33,8 +33,14 @@ import numpy as np
 
 # Per-MB flat sizes. Intra: luma DC 16 + luma AC 240 + chroma DC 8 +
 # chroma AC 120. P plane layout: luma coeff plane 256 + u/v hadamard DC
-# 4+4 + u/v AC planes 64+64 (MVs ride separately as int8).
+# 4+4 + u/v AC planes 64+64 (MVs ride separately as int8); with
+# rd.p_intra one more, the macroblock's kind word (rdo.pmode_word).
 _P_FLAT_MB = 256 + 4 + 4 + 64 + 64        # = 392
+
+
+def p_flat_mb(p_intra: bool = False) -> int:
+    """Levels a P macroblock takes in the flat transfer layout."""
+    return _P_FLAT_MB + (1 if p_intra else 0)
 _INTRA_FLAT_MB = 384
 
 #: 16-coeff granularity of the block-sparse transfer tiers
@@ -152,10 +158,11 @@ def unflatten_intra(seg: np.ndarray, nmb: int):
 
 
 def unflatten_p_planes(seg: np.ndarray, mv8: np.ndarray, num_frames: int,
-                       mbw: int, mbh: int):
+                       mbw: int, mbh: int, p_intra: bool = False):
     """Flat P segment → plane VIEWS (the plane->blocked scan happens
     inside the native packer, cavlc_pack_pslice_plane, so no relayout
-    pass runs on the host)."""
+    pass runs on the host). With `p_intra` the segment ends in the
+    frames' kind channel, `pmode` (F-1, nmb), a seventh view."""
     nmb = mbw * mbh
     H, W = mbh * 16, mbw * 16
     hw2 = (H // 2) * (W // 2)
@@ -170,22 +177,28 @@ def unflatten_p_planes(seg: np.ndarray, mv8: np.ndarray, num_frames: int,
     uac = seg[o:o + F1 * hw2].reshape(F1, H // 2, W // 2)
     o += F1 * hw2
     vac = seg[o:o + F1 * hw2].reshape(F1, H // 2, W // 2)
+    o += F1 * hw2
+    if p_intra:
+        return (np.asarray(mv8), lp, udc, vdc, uac, vac,
+                seg[o:o + F1 * nmb].reshape(F1, nmb))
     return (np.asarray(mv8), lp, udc, vdc, uac, vac)
 
 
 def unflatten_gop(flat: np.ndarray, mv8: np.ndarray, num_frames: int,
-                  mbw: int, mbh: int, ships_modes: bool = False):
+                  mbw: int, mbh: int, ships_modes: bool = False,
+                  p_intra: bool = False):
     """Host inverse of jaxinter.encode_gop_planes: split the flat int16
     vector into (intra blocked arrays, P plane views). EVERY array is a
     zero-copy view into `flat`. With `ships_modes` the vector ends in
     the per-MB intra [mode16 | dqp16] side channel, appended to the
-    returned intra tuple."""
+    returned intra tuple; `p_intra` as unflatten_p_planes'."""
     nmb = mbw * mbh
     flat = np.asarray(flat)
     o = nmb * _INTRA_FLAT_MB
     intra = unflatten_intra(flat[:o], nmb)
     p_end = flat.shape[0] - (2 * nmb if ships_modes else 0)
-    planes = unflatten_p_planes(flat[o:p_end], mv8, num_frames, mbw, mbh)
+    planes = unflatten_p_planes(flat[o:p_end], mv8, num_frames, mbw, mbh,
+                                p_intra)
     if ships_modes:
         intra = intra + (flat[p_end:p_end + nmb], flat[p_end + nmb:])
     return intra, planes
@@ -193,7 +206,8 @@ def unflatten_gop(flat: np.ndarray, mv8: np.ndarray, num_frames: int,
 
 def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
                         mv8: np.ndarray, num_frames: int,
-                        mbw: int, mbh: int, ships_modes: bool = False):
+                        mbw: int, mbh: int, ships_modes: bool = False,
+                        p_intra: bool = False):
     """Sparse-path unflatten straight from the two transfer segments —
     dense = [il_dc | ic_dc] (the hadamard DC prefix, _per_gop_sparse;
     with `ships_modes` also the [mode16 | dqp16] tail, appended to the
@@ -209,7 +223,8 @@ def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
     il_ac = rest[:nlac].reshape(nmb, 16, 15)
     o = nlac + nmb * 120
     ic_ac = rest[nlac:o].reshape(nmb, 2, 4, 15)
-    planes = unflatten_p_planes(rest[o:], mv8, num_frames, mbw, mbh)
+    planes = unflatten_p_planes(rest[o:], mv8, num_frames, mbw, mbh,
+                                p_intra)
     intra = (il_dc, il_ac, ic_dc, ic_ac)
     if ships_modes:
         t = ndc + nmb * 8

@@ -29,6 +29,13 @@ specialization, never a traced branch:
   every vector between the search, the filter's bS test, the
   packers and the P_Skip inference is in QUARTER-sample units
   (`mv_per_pel` 4); with "half" in half-sample units (2).
+- ``p_intra``: intra macroblocks in P pictures (§7.3.5 mb_type 5..30
+  in a P slice). Every P macroblock is coded inter, as without the
+  setting, or Intra16x16 (V, H or DC, predicted from the CURRENT
+  picture's unfiltered reconstruction, §8.3.3), whichever costs less
+  (jaxinter._p_intra); the filter takes its bS per edge from the
+  per-macroblock map, the packers code the macroblock's kind, and a
+  neighbour's vector prediction sees refIdx -1 there.
 
 This module is deliberately jax-free: the host packers import it
 without initializing a device backend.
@@ -56,6 +63,36 @@ AQ_MAX_DELTA = 6
 #: distortion it buys back (on the pan clip of tools/pan.py, CPU run:
 #: bits fall with no PSNR loss at 2; 4+ starts to visibly smear grain).
 PSKIP_SUM = 2
+#: `p_intra`'s handicap of the Intra16x16 candidate, in bits: what
+#: such a macroblock of a P slice pays before its first AC coefficient
+#: (mb_type ue(6..30), intra_chroma_pred_mode, mb_qp_delta, the luma DC
+#: block) and the margin a SATD / SAD cost needs for being blind to
+#: how much dearer an intra residual codes than an inter one of the
+#: same cost. The decision compares `intra cost + lambda *
+#: P_INTRA_BITS` with `inter cost + lambda * (the vector's bits)`, on
+#: one scale (SATD with mode_decision, else SAD), lambda =
+#: P_INTRA_LAMBDA[qp]. Chosen in PR 45's step 0 on tools/crossing.py
+#: at 1080p, QP 25, the serving tools on (PERF.md §6): a P picture's
+#: bits fall by 5.6 % at 24 (26 % of its macroblocks intra, PSNR-Y
+#: +0.55 dB), 6.4 % at 96, 7.3 % at 128 (16 %, +0.36 dB), 7.8 % at
+#: 160 (10 %), 7.0 % at 400 (4 %, +0.09 dB).
+P_INTRA_BITS = 128
+#: passes in which `p_intra` codes the macroblocks that want intra:
+#: pass k those of class (x + y) mod P_INTRA_PASSES == k, each
+#: predicted from neighbours the pass before made final; where wishes
+#: touch, one macroblock in P_INTRA_PASSES stays inter
+#: (jaxinter._p_intra). Each pass is one plane-parallel Intra16x16
+#: residual of the picture.
+P_INTRA_PASSES = 4
+#: lambda of a SATD / SAD cost at each QP: round(2 ** ((qp - 12) / 6)),
+#: at least 1 (the square root of the SSD lambda 0.85 * 2 ** ((qp - 12)
+#: / 3), as x264's table is)
+P_INTRA_LAMBDA = tuple(max(1, int(round(2.0 ** ((q - 12) / 6.0))))
+                       for q in range(52))
+#: the per-macroblock word of a P picture's kind channel (`pmode`, the
+#: transfer layouts' and the packers'): 0 = inter; an intra macroblock
+#: holds 1 | Intra16x16 luma mode << 1 | intra chroma mode << 3
+P_INTRA_FLAG = 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +107,9 @@ class RdConfig:
     aq_q: int = 0
     #: motion-vector precision, one of SUBPELS
     subpel: str = "half"
+    #: intra macroblocks in P pictures (the per-MB inter / Intra16x16
+    #: decision of jaxinter._p_intra)
+    p_intra: bool = False
 
     def __post_init__(self) -> None:
         if self.subpel not in SUBPELS:
@@ -108,7 +148,7 @@ def aq_from_strength(strength: float) -> int:
 
 
 def rd_from_settings(settings) -> RdConfig:
-    """Build the static RD config from a Settings snapshot (the five
+    """Build the static RD config from a Settings snapshot (the six
     knobs registered in core/config.DEFAULT_SETTINGS)."""
     from ...core.config import as_bool, as_float
 
@@ -119,6 +159,7 @@ def rd_from_settings(settings) -> RdConfig:
         aq_q=aq_from_strength(as_float(settings.get("aq_strength", 0.0),
                                        0.0)),
         subpel=subpel_of(settings),
+        p_intra=as_bool(settings.get("p_intra", False), False),
     )
 
 
@@ -217,3 +258,14 @@ def clamp_qp_map(base_qp, offsets) -> np.ndarray:
     """Per-MB QP = base + offset, clamped to the legal H.264 range."""
     return np.clip(np.asarray(base_qp) + np.asarray(offsets), 0, 51
                    ).astype(np.int32)
+
+
+def pmode_word(luma_mode, chroma_mode):
+    """The `pmode` word of an intra macroblock (arrays or ints)."""
+    return P_INTRA_FLAG | (luma_mode << 1) | (chroma_mode << 3)
+
+
+def pmode_fields(pmode):
+    """(is_intra, luma_mode, chroma_mode) of `pmode` words."""
+    pmode = np.asarray(pmode, np.int32)
+    return (pmode & P_INTRA_FLAG) != 0, (pmode >> 1) & 3, (pmode >> 3) & 3

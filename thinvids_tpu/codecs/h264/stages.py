@@ -31,6 +31,8 @@ STAGES = (
     "me_search",    # the motion-search kernel (or its XLA mirror)
     "me_median",    # the frame's median MV (next frame's center)
     "residual",     # P-frame transform, quant, recon
+    "p_intra",      # rd.p_intra: the inter / Intra16x16 decision of a
+                    # P macroblock and the chosen ones' residual
     "deblock",      # in-loop filter (rd.deblock)
     "pack",         # sparse packs of the level vector
     "compact",      # fold of the sparse streams into one payload

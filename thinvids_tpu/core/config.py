@@ -84,11 +84,19 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     #   precision. "quarter" adds §8.4.2.2.1's quarter positions to the
     #   search (379 candidates a macroblock for 227) and codes vectors
     #   in quarter samples; one more executable per resolution.
+    # p_intra (TVT_P_INTRA): intra macroblocks in P pictures (§7.3.5
+    #   mb_type 5..30 in a P slice): every P macroblock is coded inter
+    #   or Intra16x16, whichever costs less — what footage whose
+    #   objects cross and uncover each other needs, since the search
+    #   reaches +-4 pixels round three frame-global centres. One more
+    #   executable per resolution; GOP-shape jobs (transcode, ladder,
+    #   live); a band-shape job (`sfe_bands`) is refused at admission.
     "mode_decision": False,
     "pskip": False,
     "deblock": False,
     "aq_strength": 0.0,
     "subpel": "half",
+    "p_intra": False,
     # ABR ladder subsystem (abr/): default job type for registrations
     # that don't say (watch-folder drops named *.ladder.* always become
     # ladder jobs), the rung heights (TVT_LADDER_RUNGS; heights at or
@@ -350,6 +358,7 @@ _CLAMPS: dict[str, Callable[[Any], Any]] = {
     "aq_strength": lambda v: min(3.0, max(0.0, as_float(v, 0.0))),
     "subpel": lambda v: (s if (s := subpel_of({"subpel": v})) in SUBPELS
                          else "half"),
+    "p_intra": lambda v: as_bool(v, False),
     "gop_frames": lambda v: min(600, max(1, as_int(v, 32))),
     "scenecut": lambda v: min(100, max(0, as_int(v, 0))),
     "max_segments": lambda v: min(4096, max(1, as_int(v, 200))),
@@ -580,7 +589,9 @@ JOB_SETTING_KEYS = frozenset(
      # reads its RdConfig from the daemon's settings, so admission
      # refuses a value other than the daemon's (cluster/policy.py) —
      # left out of these keys it would be dropped without a word
-     "subpel"}
+     "subpel",
+     # likewise intra macroblocks in P pictures
+     "p_intra"}
 )
 
 

@@ -174,6 +174,7 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_int32, ctypes.c_int32,             # mbw, mbh
             ctypes.c_int32,                             # mvd_scale
             ctypes.c_void_p, ctypes.c_int64,            # out, cap
+            ctypes.c_void_p,                            # pmode (or NULL)
         ]
         lib.cavlc_init_scan.argtypes = [ctypes.c_void_p]
         lib.cavlc_pack_pslice_plane.restype = ctypes.c_int64
@@ -186,6 +187,7 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_int32, ctypes.c_int32,             # mbw, mbh
             ctypes.c_int32,                             # mvd_scale
             ctypes.c_void_p, ctypes.c_int64,            # out, cap
+            ctypes.c_void_p,                            # pmode (or NULL)
         ]
         arrs = _marshal_tables()
         from ..codecs.h264.inter import CBP_INTER_TO_CODE
@@ -268,16 +270,30 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
     return out[:n].tobytes()
 
 
+def _pmode_array(pmode, nmb: int):
+    """A P picture's kind channel as the packers take it: None, or
+    (nmb,) contiguous int16."""
+    if pmode is None:
+        return None
+    pmode = np.ascontiguousarray(pmode, np.int16)
+    if pmode.shape != (nmb,):
+        raise ValueError(f"bad pmode shape {pmode.shape}, want ({nmb},)")
+    return pmode
+
+
 def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
                       mv8: np.ndarray, luma_plane: np.ndarray,
                       u_dc: np.ndarray, v_dc: np.ndarray,
                       u_ac: np.ndarray, v_ac: np.ndarray,
-                      mbw: int, mbh: int, mvd_scale: int = 2) -> bytes:
+                      mbw: int, mbh: int, mvd_scale: int = 2,
+                      pmode=None) -> bytes:
     """Pack one P-slice straight from plane-layout int16 level arrays
     (zigzag/z-scan happens inside the C++ via the shared scan table) —
     bit-identical to pack_pslice on the equivalent blocked arrays.
     `mvd_scale`: quarter samples to one unit of mv8 (2: half-sample
-    vectors, 1: quarter-sample vectors)."""
+    vectors, 1: quarter-sample vectors). `pmode`: None, or the (nmb,)
+    kind channel of a picture with intra macroblocks
+    (codecs/h264/inter.pack_p_slice)."""
     lib = _build_and_load()
     nmb = mbw * mbh
 
@@ -293,6 +309,7 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
     v_dc = prep(v_dc, (nmb, 4), np.int16)
     u_ac = prep(u_ac, (8 * mbh, 8 * mbw), np.int16)
     v_ac = prep(v_ac, (8 * mbh, 8 * mbw), np.int16)
+    pmode = _pmode_array(pmode, nmb)
 
     cap = max(8192, nmb * 4096)
     out = np.empty(cap, np.uint8)
@@ -302,7 +319,8 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
         mv8.ctypes.data, luma_plane.ctypes.data,
         u_dc.ctypes.data, v_dc.ctypes.data,
         u_ac.ctypes.data, v_ac.ctypes.data,
-        mbw, mbh, mvd_scale, out.ctypes.data, cap)
+        mbw, mbh, mvd_scale, out.ctypes.data, cap,
+        None if pmode is None else pmode.ctypes.data)
     if n == -2:
         raise RuntimeError("native packer output buffer overflow")
     if n == -3:
@@ -315,10 +333,10 @@ def pack_pslice_plane(header_bytes: bytes, header_bit_len: int,
 def pack_pslice(header_bytes: bytes, header_bit_len: int, mv: np.ndarray,
                 luma16: np.ndarray, chroma_dc: np.ndarray,
                 chroma_ac: np.ndarray, mbw: int, mbh: int,
-                mvd_scale: int = 2) -> bytes:
+                mvd_scale: int = 2, pmode=None) -> bytes:
     """Pack one P-slice (header bits + MB layer) and return the EBSP
     payload. Mirrors codecs/h264/inter.pack_p_slice bit-for-bit;
-    `mvd_scale` as in :func:`pack_pslice_plane`."""
+    `mvd_scale` and `pmode` as in :func:`pack_pslice_plane`."""
     lib = _build_and_load()
     nmb = mbw * mbh
 
@@ -332,6 +350,7 @@ def pack_pslice(header_bytes: bytes, header_bit_len: int, mv: np.ndarray,
     luma16 = prep(luma16, (nmb, 16, 16))
     chroma_dc = prep(chroma_dc, (nmb, 2, 4))
     chroma_ac = prep(chroma_ac, (nmb, 2, 4, 15))
+    pmode = _pmode_array(pmode, nmb)
 
     cap = max(8192, nmb * 4096)
     out = np.empty(cap, np.uint8)
@@ -340,7 +359,8 @@ def pack_pslice(header_bytes: bytes, header_bit_len: int, mv: np.ndarray,
         hdr.ctypes.data, header_bit_len,
         mv.ctypes.data, luma16.ctypes.data,
         chroma_dc.ctypes.data, chroma_ac.ctypes.data,
-        mbw, mbh, mvd_scale, out.ctypes.data, cap)
+        mbw, mbh, mvd_scale, out.ctypes.data, cap,
+        None if pmode is None else pmode.ctypes.data)
     if n == -2:
         raise RuntimeError("native packer output buffer overflow")
     if n == -3:

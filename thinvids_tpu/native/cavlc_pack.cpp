@@ -366,6 +366,45 @@ static int64_t sparse_unpack2_core(int32_t nblk, int32_t nval,
   return 0;
 }
 
+static int32_t g_zz[16];      // zigzag position -> raster index in a 4x4
+static bool g_scan_ready = false;
+
+// Head and luma of one Intra16x16 macroblock of a P slice (§7.3.5, Table
+// 7-13: mb_type 5 + the I-slice mb_type), after its mb_skip_run. pm: the
+// macroblock's kind word (bit 0 set; luma mode bits 1-2, chroma mode bits
+// 3-4). l16: its 16 z-scan blocks of 16 zig-zag levels, a block's first
+// the Hadamard-domain DC level of its place in the 4x4 DC matrix (block
+// (bx, by): level (by, bx)), the other 15 its AC levels. Returns 0 or -3.
+template <typename T, typename NcFn>
+static int pack_intra16_in_p(BitWriter& bw, int pm, int cbp_chroma,
+                             const T* l16, int by0, int bx0, int lw,
+                             int32_t* lcnt, NcFn luma_nc) {
+  static const int BX[16] = {0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3};
+  static const int BY[16] = {0, 0, 1, 1, 0, 0, 1, 1, 2, 2, 3, 3, 2, 2, 3, 3};
+  bool has_ac = false;
+  for (int bi = 0; bi < 16 && !has_ac; bi++)
+    for (int k = 1; k < 16; k++)
+      if (l16[bi * 16 + k]) { has_ac = true; break; }
+  bw.ue((uint32_t)(5 + 1 + ((pm >> 1) & 3) + 4 * cbp_chroma
+                   + (has_ac ? 12 : 0)));
+  bw.ue((uint32_t)((pm >> 3) & 3));   // intra_chroma_pred_mode
+  bw.se(0);                           // mb_qp_delta
+  T matrix[16], dc[16];
+  for (int bi = 0; bi < 16; bi++) matrix[BY[bi] * 4 + BX[bi]] = l16[bi * 16];
+  for (int k = 0; k < 16; k++) dc[k] = matrix[g_zz[k]];
+  if (encode_residual(bw, dc, 16, luma_nc(by0, bx0)) < 0) return -3;
+  for (int bi = 0; bi < 16; bi++) {
+    const int gy = by0 + BY[bi], gx = bx0 + BX[bi];
+    int tc = 0;
+    if (has_ac) {
+      tc = encode_residual(bw, l16 + bi * 16 + 1, 15, luma_nc(gy, gx));
+      if (tc < 0) return -3;
+    }
+    lcnt[(size_t)gy * lw + gx] = tc;
+  }
+  return 0;
+}
+
 }  // namespace
 
 extern "C" {
@@ -461,47 +500,54 @@ static inline int32_t median3(int32_t a, int32_t b, int32_t c) {
 // MV prediction (median, C->D fallback) + P_Skip predictor, §8.4.1.3/1.1.
 // Shared by the blocked and plane-layout P-slice packers — their
 // bit-identity contract rides on this being the single implementation.
-static void compute_mv_pred(const int32_t* mv, int mbw, int mbh,
+// pmode: nullptr, or the picture's kind channel (bit 0: intra). An intra
+// neighbour is available with refIdx -1 and the vector 0: it is not "the
+// one neighbour with this reference", stands as 0 in the median, and its
+// zero vector does not zero a P_Skip's (codecs/h264/inter.predict_mvs).
+static void compute_mv_pred(const int32_t* mv, const int16_t* pmode,
+                            int mbw, int mbh,
                             std::vector<int32_t>& mvp,
                             std::vector<int32_t>& skipmv) {
   const int nmb = mbw * mbh;
   mvp.resize((size_t)nmb * 2);
   skipmv.resize((size_t)nmb * 2);
+  struct Nb { bool ref; int32_t v[2]; };
+  auto neighbour = [&](bool avail, int idx) {
+    Nb n = {avail && !(pmode && (pmode[idx] & 1)), {0, 0}};
+    if (n.ref) {
+      n.v[0] = mv[(size_t)idx * 2];
+      n.v[1] = mv[(size_t)idx * 2 + 1];
+    }
+    return n;
+  };
   for (int my = 0; my < mbh; my++) {
     for (int mx = 0; mx < mbw; mx++) {
       const int mi = my * mbw + mx;
       const bool avail_a = mx > 0, avail_b = my > 0;
-      int32_t mva[2] = {avail_a ? mv[(size_t)(mi - 1) * 2] : 0,
-                        avail_a ? mv[(size_t)(mi - 1) * 2 + 1] : 0};
-      int32_t mvb[2] = {avail_b ? mv[(size_t)(mi - mbw) * 2] : 0,
-                        avail_b ? mv[(size_t)(mi - mbw) * 2 + 1] : 0};
-      int32_t mvc[2] = {0, 0};
+      const Nb a = neighbour(avail_a, mi - 1);
+      Nb b = neighbour(avail_b, mi - mbw);
       bool avail_c = false;
+      Nb c = {false, {0, 0}};
       if (my > 0 && mx + 1 < mbw) {
         avail_c = true;
-        mvc[0] = mv[(size_t)(mi - mbw + 1) * 2];
-        mvc[1] = mv[(size_t)(mi - mbw + 1) * 2 + 1];
+        c = neighbour(true, mi - mbw + 1);
       } else if (my > 0 && mx > 0) {
         avail_c = true;
-        mvc[0] = mv[(size_t)(mi - mbw - 1) * 2];
-        mvc[1] = mv[(size_t)(mi - mbw - 1) * 2 + 1];
+        c = neighbour(true, mi - mbw - 1);
       }
-      const int n_avail = (int)avail_a + (int)avail_b + (int)avail_c;
+      if (!avail_b && !avail_c && avail_a) b = c = a;
       int32_t p[2];
-      if (!avail_b && !avail_c && avail_a) {
-        p[0] = mva[0]; p[1] = mva[1];
-      } else if (n_avail == 1) {
-        if (avail_a)      { p[0] = mva[0]; p[1] = mva[1]; }
-        else if (avail_b) { p[0] = mvb[0]; p[1] = mvb[1]; }
-        else              { p[0] = mvc[0]; p[1] = mvc[1]; }
+      if ((int)a.ref + (int)b.ref + (int)c.ref == 1) {
+        const Nb& one = a.ref ? a : (b.ref ? b : c);
+        p[0] = one.v[0]; p[1] = one.v[1];
       } else {
-        p[0] = median3(mva[0], mvb[0], mvc[0]);
-        p[1] = median3(mva[1], mvb[1], mvc[1]);
+        p[0] = median3(a.v[0], b.v[0], c.v[0]);
+        p[1] = median3(a.v[1], b.v[1], c.v[1]);
       }
       mvp[(size_t)mi * 2] = p[0];
       mvp[(size_t)mi * 2 + 1] = p[1];
-      if (!avail_a || !avail_b || (mva[0] == 0 && mva[1] == 0)
-          || (mvb[0] == 0 && mvb[1] == 0)) {
+      if (!avail_a || !avail_b || (a.ref && a.v[0] == 0 && a.v[1] == 0)
+          || (b.ref && b.v[0] == 0 && b.v[1] == 0)) {
         skipmv[(size_t)mi * 2] = 0;
         skipmv[(size_t)mi * 2 + 1] = 0;
       } else {
@@ -512,10 +558,12 @@ static void compute_mv_pred(const int32_t* mv, int mbw, int mbh,
   }
 }
 
-// Packs one P picture (all-inter, P_L0_16x16 / P_Skip, single reference).
+// Packs one P picture (P_L0_16x16 / P_Skip, single reference, and where
+// pmode says so Intra16x16 macroblocks).
 // mv: nmb*2 as (dy, dx), in units of which mvd_scale make a quarter sample
 // (2: half-sample vectors, 1: quarter-sample vectors); luma16: nmb*16*16
-// z-scan blocks of 16 zig-zag coeffs. Mirrors
+// z-scan blocks of 16 zig-zag coeffs; pmode: nullptr (all inter) or nmb
+// kind words (pack_intra16_in_p). Mirrors
 // codecs/h264/inter.pack_p_slice bit-for-bit.
 int64_t cavlc_pack_pslice(
     const uint8_t* header_bytes, int32_t header_bit_len,
@@ -524,9 +572,9 @@ int64_t cavlc_pack_pslice(
     const int32_t* chroma_dc,
     const int32_t* chroma_ac,
     int32_t mbw, int32_t mbh, int32_t mvd_scale,
-    uint8_t* out, int64_t out_cap) {
+    uint8_t* out, int64_t out_cap, const int16_t* pmode) {
   if (!g_tables_ready || !g_inter_ready || mbw <= 0 || mbh <= 0
-      || (mvd_scale != 1 && mvd_scale != 2))
+      || (mvd_scale != 1 && mvd_scale != 2) || (pmode && !g_scan_ready))
     return -1;
   static const int BX[16] = {0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3};
   static const int BY[16] = {0, 0, 1, 1, 0, 0, 1, 1, 2, 2, 3, 3, 2, 2, 3, 3};
@@ -541,7 +589,7 @@ int64_t cavlc_pack_pslice(
     bw.write(header_bytes[header_bit_len / 8] >> (8 - rem), rem);
 
   std::vector<int32_t> mvp, skipmv;
-  compute_mv_pred(mv, mbw, mbh, mvp, skipmv);
+  compute_mv_pred(mv, pmode, mbw, mbh, mvp, skipmv);
 
   const int lw = 4 * mbw, lh = 4 * mbh;
   const int cw = 2 * mbw, ch = 2 * mbh;
@@ -573,34 +621,43 @@ int64_t cavlc_pack_pslice(
         for (int i = 0; i < 8 && !cbp_chroma; i++)
           if (cdc[i]) cbp_chroma = 1;
       const int cbp = cbp_luma | (cbp_chroma << 4);
-
-      const bool is_skip = cbp == 0
-          && mv[(size_t)mi * 2] == skipmv[(size_t)mi * 2]
-          && mv[(size_t)mi * 2 + 1] == skipmv[(size_t)mi * 2 + 1];
-      if (is_skip) {
-        skip_run++;
-        continue;   // neighbor counts stay 0
-      }
-      bw.ue(skip_run);
-      skip_run = 0;
-      bw.ue(0);   // mb_type = P_L0_16x16
-      // mvd: horizontal first (§7.3.5.1); layout is (dy, dx). mvd is
-      // coded in quarter-sample units, mvd_scale to one of mv's.
-      bw.se(mvd_scale * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
-      bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
-      bw.ue((uint32_t)g_cbp_inter[cbp]);
-      if (cbp) bw.se(0);   // mb_qp_delta
-
       const int by0 = 4 * my, bx0 = 4 * mx;
-      for (int bi = 0; bi < 16; bi++) {
-        int gy = by0 + BY[bi], gx = bx0 + BX[bi];
-        if (cbp_luma & (1 << (bi / 4))) {
-          int tc = encode_residual(bw, l16 + (size_t)bi * 16, 16,
-                                   luma_nc(gy, gx));
-          if (tc < 0) return -3;
-          lcnt[(size_t)gy * lw + gx] = tc;
-        } else {
-          lcnt[(size_t)gy * lw + gx] = 0;
+
+      if (pmode && (pmode[mi] & 1)) {
+        bw.ue(skip_run);
+        skip_run = 0;
+        if (pack_intra16_in_p(bw, pmode[mi], cbp_chroma, l16, by0, bx0, lw,
+                              lcnt.data(), luma_nc) < 0)
+          return -3;
+      } else {
+        const bool is_skip = cbp == 0
+            && mv[(size_t)mi * 2] == skipmv[(size_t)mi * 2]
+            && mv[(size_t)mi * 2 + 1] == skipmv[(size_t)mi * 2 + 1];
+        if (is_skip) {
+          skip_run++;
+          continue;   // neighbor counts stay 0
+        }
+        bw.ue(skip_run);
+        skip_run = 0;
+        bw.ue(0);   // mb_type = P_L0_16x16
+        // mvd: horizontal first (§7.3.5.1); layout is (dy, dx). mvd is
+        // coded in quarter-sample units, mvd_scale to one of mv's.
+        bw.se(mvd_scale
+              * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
+        bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
+        bw.ue((uint32_t)g_cbp_inter[cbp]);
+        if (cbp) bw.se(0);   // mb_qp_delta
+
+        for (int bi = 0; bi < 16; bi++) {
+          int gy = by0 + BY[bi], gx = bx0 + BX[bi];
+          if (cbp_luma & (1 << (bi / 4))) {
+            int tc = encode_residual(bw, l16 + (size_t)bi * 16, 16,
+                                     luma_nc(gy, gx));
+            if (tc < 0) return -3;
+            lcnt[(size_t)gy * lw + gx] = tc;
+          } else {
+            lcnt[(size_t)gy * lw + gx] = 0;
+          }
         }
       }
       if (cbp_chroma > 0)
@@ -637,9 +694,6 @@ int64_t cavlc_pack_pslice(
 // This variant reads coefficients straight from the planes through the
 // zig-zag offset table, so no relayout pass exists anywhere.
 
-static int32_t g_zz[16];      // zigzag position -> raster index in a 4x4
-static bool g_scan_ready = false;
-
 void cavlc_init_scan_impl(const int32_t* zz) {
   std::memcpy(g_zz, zz, sizeof(g_zz));
   g_scan_ready = true;
@@ -648,7 +702,9 @@ void cavlc_init_scan_impl(const int32_t* zz) {
 // Packs one P picture from plane-layout levels. mv: nmb*2 int8 (dy, dx),
 // mvd_scale as in cavlc_pack_pslice;
 // luma_plane: (16*mbh)x(16*mbw) int16; u_dc/v_dc: nmb*4 int16 (hadamard
-// domain); u_ac/v_ac: (8*mbh)x(8*mbw) int16 with DC positions zero.
+// domain); u_ac/v_ac: (8*mbh)x(8*mbw) int16 with DC positions zero; pmode
+// as cavlc_pack_pslice's (an intra macroblock's Hadamard-domain luma DC
+// levels lie at the DC positions of its 4x4 blocks).
 // Bit-identical to cavlc_pack_pslice on the equivalent blocked arrays.
 int64_t cavlc_pack_pslice_plane_impl(
     const uint8_t* header_bytes, int32_t header_bit_len,
@@ -657,7 +713,7 @@ int64_t cavlc_pack_pslice_plane_impl(
     const int16_t* u_dc, const int16_t* v_dc,
     const int16_t* u_ac, const int16_t* v_ac,
     int32_t mbw, int32_t mbh, int32_t mvd_scale,
-    uint8_t* out, int64_t out_cap) {
+    uint8_t* out, int64_t out_cap, const int16_t* pmode) {
   if (!g_tables_ready || !g_inter_ready || !g_scan_ready
       || mbw <= 0 || mbh <= 0 || (mvd_scale != 1 && mvd_scale != 2))
     return -1;
@@ -679,7 +735,7 @@ int64_t cavlc_pack_pslice_plane_impl(
   for (size_t i = 0; i < (size_t)nmb * 2; i++) mv[i] = mv8[i];
 
   std::vector<int32_t> mvp, skipmv;
-  compute_mv_pred(mv.data(), mbw, mbh, mvp, skipmv);
+  compute_mv_pred(mv.data(), pmode, mbw, mbh, mvp, skipmv);
 
   const int lw = 4 * mbw, lh = 4 * mbh;
   const int cw = 2 * mbw, ch = 2 * mbh;
@@ -739,32 +795,41 @@ int64_t cavlc_pack_pslice_plane_impl(
           for (int j = 0; j < 4; j++)
             if (cdcl[ci][j]) { cbp_chroma = 1; break; }
       const int cbp = cbp_luma | (cbp_chroma << 4);
-
-      const bool is_skip = cbp == 0
-          && mv[(size_t)mi * 2] == skipmv[(size_t)mi * 2]
-          && mv[(size_t)mi * 2 + 1] == skipmv[(size_t)mi * 2 + 1];
-      if (is_skip) {
-        skip_run++;
-        continue;
-      }
-      bw.ue(skip_run);
-      skip_run = 0;
-      bw.ue(0);   // mb_type = P_L0_16x16
-      // mv units -> mvd quarter samples (see above).
-      bw.se(mvd_scale * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
-      bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
-      bw.ue((uint32_t)g_cbp_inter[cbp]);
-      if (cbp) bw.se(0);   // mb_qp_delta
-
       const int by0 = 4 * my, bx0 = 4 * mx;
-      for (int bi = 0; bi < 16; bi++) {
-        int gy = by0 + BY[bi], gx = bx0 + BX[bi];
-        if (cbp_luma & (1 << (bi / 4))) {
-          int tc = encode_residual(bw, l16[bi], 16, luma_nc(gy, gx));
-          if (tc < 0) return -3;
-          lcnt[(size_t)gy * lw + gx] = tc;
-        } else {
-          lcnt[(size_t)gy * lw + gx] = 0;
+
+      if (pmode && (pmode[mi] & 1)) {
+        bw.ue(skip_run);
+        skip_run = 0;
+        if (pack_intra16_in_p(bw, pmode[mi], cbp_chroma, &l16[0][0], by0,
+                              bx0, lw, lcnt.data(), luma_nc) < 0)
+          return -3;
+      } else {
+        const bool is_skip = cbp == 0
+            && mv[(size_t)mi * 2] == skipmv[(size_t)mi * 2]
+            && mv[(size_t)mi * 2 + 1] == skipmv[(size_t)mi * 2 + 1];
+        if (is_skip) {
+          skip_run++;
+          continue;
+        }
+        bw.ue(skip_run);
+        skip_run = 0;
+        bw.ue(0);   // mb_type = P_L0_16x16
+        // mv units -> mvd quarter samples (see above).
+        bw.se(mvd_scale
+              * (mv[(size_t)mi * 2 + 1] - mvp[(size_t)mi * 2 + 1]));
+        bw.se(mvd_scale * (mv[(size_t)mi * 2] - mvp[(size_t)mi * 2]));
+        bw.ue((uint32_t)g_cbp_inter[cbp]);
+        if (cbp) bw.se(0);   // mb_qp_delta
+
+        for (int bi = 0; bi < 16; bi++) {
+          int gy = by0 + BY[bi], gx = bx0 + BX[bi];
+          if (cbp_luma & (1 << (bi / 4))) {
+            int tc = encode_residual(bw, l16[bi], 16, luma_nc(gy, gx));
+            if (tc < 0) return -3;
+            lcnt[(size_t)gy * lw + gx] = tc;
+          } else {
+            lcnt[(size_t)gy * lw + gx] = 0;
+          }
         }
       }
       if (cbp_chroma > 0)
@@ -802,10 +867,10 @@ int64_t cavlc_pack_pslice_plane(
     const int16_t* u_dc, const int16_t* v_dc,
     const int16_t* u_ac, const int16_t* v_ac,
     int32_t mbw, int32_t mbh, int32_t mvd_scale,
-    uint8_t* out, int64_t out_cap) {
+    uint8_t* out, int64_t out_cap, const int16_t* pmode) {
   return cavlc_pack_pslice_plane_impl(
       header_bytes, header_bit_len, mv8, luma_plane, u_dc, v_dc, u_ac,
-      v_ac, mbw, mbh, mvd_scale, out, out_cap);
+      v_ac, mbw, mbh, mvd_scale, out, out_cap, pmode);
 }
 
 }  // extern "C"

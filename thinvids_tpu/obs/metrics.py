@@ -357,6 +357,13 @@ STAGE_COUNTER_TOTALS = {
         "tvt_mvs_quarter_total",
         "of those, vectors with an odd quarter-sample component "
         "(subpel=quarter alone can have any)"),
+    "p_mbs_coded": REGISTRY.counter(
+        "tvt_p_mbs_coded_total",
+        "macroblocks of P pictures handed to the packers with their "
+        "kind channel (p_intra alone ships one)"),
+    "p_mbs_intra": REGISTRY.counter(
+        "tvt_p_mbs_intra_total",
+        "of those, macroblocks coded Intra16x16"),
 }
 STAGE_GAUGES = {
     "me_candidates": REGISTRY.gauge(

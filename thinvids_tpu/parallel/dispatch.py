@@ -95,7 +95,7 @@ from ..codecs.h264.stages import stage
 # Transfer-layout contract (jax-free module): per-MB flat sizes + the
 # zero-copy host unflattens.
 from ..codecs.h264.layout import _INTRA_FLAT_MB as _INTRA_MB
-from ..codecs.h264.layout import (_P_FLAT_MB, unflatten_gop,
+from ..codecs.h264.layout import (_P_FLAT_MB, p_flat_mb, unflatten_gop,
                                   unflatten_gop_parts, unflatten_intra,
                                   unflatten_p_planes)
 from .planner import plan_bands, plan_fixed_segments, plan_segments
@@ -157,14 +157,16 @@ STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
 #: waves' frames staged / repeats among them / repeats never encoded),
 #: mvs_coded / mvs_quarter (P macroblocks' vectors handed to the
 #: packers / those of them with an odd quarter-sample component: 0
-#: under subpel="half"; count_vectors)
+#: under subpel="half"; count_vectors),
+#: p_mbs_coded / p_mbs_intra (macroblocks of P pictures handed to the
+#: packers under p_intra / those of them intra; count_kinds)
 STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes",
                   "stage_copy_bytes", "d2h_bytes", "fetch_shards",
                   "sfe_frames", "sparse_blocks_used", "sparse_blocks_budget",
                   "sparse_values_used", "sparse_values_budget",
                   "scene_cuts", "scene_cuts_suppressed", "wave_frames",
                   "pad_frames", "pad_frames_skipped", "mvs_coded",
-                  "mvs_quarter")
+                  "mvs_quarter", "p_mbs_coded", "p_mbs_intra")
 #: last-value readings riding in the same snapshot: me_candidates (what
 #: the motion search of the last GOP / step program called scores per
 #: macroblock: `program_build`; 0 until one ran)
@@ -1112,7 +1114,8 @@ class GopShardEncoder:
         chroma) and the [mode16 | dqp16] tail, when shipped, go dense
         (_per_gop_sparse)."""
         tail = 2 * nmb if self.rd.ships_modes else 0
-        L = nmb * _INTRA_MB + (F - 1) * nmb * _P_FLAT_MB + tail
+        L = nmb * _INTRA_MB \
+            + (F - 1) * nmb * p_flat_mb(self.rd.p_intra) + tail
         return L, L - nmb * 16 - nmb * 8 - tail
 
     def _note_sparse_fill(self, nblk, nval, L: int,
@@ -1248,14 +1251,17 @@ class GopShardEncoder:
                 with prof.stage("unflatten"):
                     intra, planes = unflatten_gop_parts(
                         dc16[gi], rest, mv8[gi], F, mbw, mbh,
-                        ships_modes=ships_modes)
+                        ships_modes=ships_modes,
+                        p_intra=self.rd.p_intra)
             else:
                 with prof.stage("unflatten"):
                     intra, planes = unflatten_gop(
                         flat[gi], mv8[gi], F, mbw, mbh,
-                        ships_modes=ships_modes)
+                        ships_modes=ships_modes, p_intra=self.rd.p_intra)
             # gop.num_frames (not F) drops the wave's tail-repeat
             # padding.
+            if self.rd.p_intra:
+                count_kinds(prof, planes[6][:gop.num_frames - 1])
             thunks = gop_slice_thunks_planes(
                 intra, planes, gop.num_frames, mbw, mbh, self.sps,
                 self.pps, int(qps_host[gi]), idr_pic_id=gop.index,
@@ -1727,6 +1733,13 @@ class SfeShardEncoder(GopShardEncoder):
         # in-loop filter runs slice-locally in a band (every band slice
         # signals disable_deblocking_filter_idc 2); cross-host (farm)
         # slices have never run with it and stay refused.
+        if self.rd.p_intra:
+            # the band steps (jaxinter.sfe_p_band) have no intra /
+            # inter decision: refuse, as admission does
+            # (cluster/policy.py), rather than encode all-inter
+            raise ValueError(
+                "p_intra is not supported by split-frame encoding; "
+                "encode this job in GOP shape (sfe_bands 0)")
         if self.rd.aq_q:
             _LOG.warning("perceptual AQ is not supported by split-frame "
                          "encoding; encoding this job with aq off")
@@ -2239,6 +2252,14 @@ def count_vectors(profile: StageProfile, mv, rd) -> None:
     if rd.mv_per_pel == 4:
         profile.bump("mvs_quarter",
                      int(np.count_nonzero((np.asarray(mv) & 1).any(-1))))
+
+
+def count_kinds(profile: StageProfile, pmode) -> None:
+    """Counters `p_mbs_coded` / `p_mbs_intra` for the (..., nmb) kind
+    channel of P pictures on their way to the packers (rd.p_intra):
+    how many macroblocks, and how many of them are intra."""
+    profile.bump("p_mbs_coded", int(pmode.size))
+    profile.bump("p_mbs_intra", int(np.count_nonzero(pmode)))
 
 
 @contextlib.contextmanager

@@ -170,6 +170,75 @@ def fuzz_pack(native_mod, rng: np.random.Generator) -> None:
         f"native={nat[0]} python={py[0]}")
 
 
+def fuzz_pack_p(native_mod, rng: np.random.Generator) -> None:
+    """Drive both P-slice packers (blocked `cavlc_pack_pslice`, plane
+    `cavlc_pack_pslice_plane`) with random vectors, levels and — half
+    the time — a kind channel of random intra macroblocks in random
+    modes (rd.p_intra; §7.3.5 mb_type 5..30 in a P slice, refIdx -1
+    neighbours in the vector prediction and the P_Skip inference,
+    skip runs that end at an intra macroblock): each must write the
+    bytes of the pure-Python `inter.pack_p_slice` on the same arrays,
+    or all must reject the levels with ValueError."""
+    from ..codecs.h264 import inter
+    from ..codecs.h264.headers import PPS, SPS
+    from ..codecs.h264.rdo import pmode_word
+
+    mbw, mbh = int(rng.integers(1, 6)), int(rng.integers(1, 5))
+    nmb = mbw * mbh
+    scale = int(rng.choice([1, 3, 40, 2000, 32767]))
+    density = float(rng.choice([0.0, 0.01, 0.1, 0.6]))
+
+    def levels(shape):
+        v = rng.integers(-scale, scale + 1, size=shape)
+        return np.where(rng.random(shape) < density, v, 0).astype(np.int16)
+
+    lp = levels((16 * mbh, 16 * mbw))
+    uac, vac = levels((8 * mbh, 8 * mbw)), levels((8 * mbh, 8 * mbw))
+    uac[::4, ::4] = vac[::4, ::4] = 0           # DC positions read 0
+    udc, vdc = levels((nmb, 4)), levels((nmb, 4))
+    # whole macroblocks without a level, so that skip runs form
+    for mi in np.flatnonzero(rng.random(nmb) < 0.4):
+        my, mx = divmod(int(mi), mbw)
+        lp[16 * my:16 * my + 16, 16 * mx:16 * mx + 16] = 0
+        uac[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = 0
+        vac[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = 0
+        udc[mi] = vdc[mi] = 0
+    mv = rng.integers(-3, 4, (nmb, 2)).astype(np.int8)
+    mv[rng.random(nmb) < 0.5] = 0
+    pmode = None
+    if rng.random() < 0.5:
+        kinds = rng.random(nmb) < float(rng.choice([0.1, 0.5, 1.0]))
+        pmode = np.where(kinds, pmode_word(rng.integers(0, 4, nmb),
+                                           rng.integers(0, 4, nmb)),
+                         0).astype(np.int16)
+    per_pel = int(rng.choice([2, 4]))
+    sps, pps = SPS(width=mbw * 16, height=mbh * 16), PPS(init_qp=27)
+    l16, cac = inter.blocked_from_planes(lp, uac, vac, mbw, mbh)
+    cdc = np.stack([udc, vdc], axis=1).astype(np.int32)
+
+    def blocked(native):
+        return inter.pack_p_slice(
+            mv.astype(np.int32), l16, cdc, cac, mbw, mbh, sps, pps, 27, 1,
+            native=native, mv_per_pel=per_pel, pmode=pmode)
+
+    def plane(native):
+        return inter.pack_p_slice_plane(
+            mv, lp, udc, vdc, uac, vac, mbw, mbh, sps, pps, 27, 1,
+            native=native, mv_per_pel=per_pel, pmode=pmode)
+
+    got = []
+    for pack, native in ((blocked, False), (blocked, True), (plane, True),
+                         (plane, False)):
+        try:
+            got.append(("ok", pack(native)))
+        except ValueError:
+            got.append(("reject", None))
+    assert all(g == got[0] for g in got), (
+        f"P pack parity divergence at {mbw}x{mbh} scale={scale} "
+        f"density={density} pmode={None if pmode is None else pmode.tolist()}"
+        f": {[g[0] for g in got]}")
+
+
 def _check_pair(got_n, got_h, ctx: str):
     """The shared accept/reject + parity contract. Returns (accepted,
     rejected) increments."""
@@ -262,6 +331,7 @@ def main(argv: list[str] | None = None) -> int:
             accepted += a
             rejected += r
         fuzz_pack(native_mod, rng)
+        fuzz_pack_p(native_mod, rng)
     print(f"fuzz_native: {args.iterations} valid cases, {cases} "
           f"mutations ({accepted} accepted, {rejected} rejected), "
           f"0 crashes, 0 divergences")
