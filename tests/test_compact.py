@@ -127,6 +127,178 @@ class TestCompactStream:
             layout.unpack_compact_host(payload[:used - 1], nblk, nval, L)
 
 
+def _gather_pack2(flat, budget_div=jaxcore._BLOCK_BUDGET_DIV,
+                  val_div=jaxcore._VAL_BUDGET_DIV):
+    """`jaxcore._block_sparse_pack2` as it stood before ISSUE 47 — tier 1
+    a row gather, `jnp.take(blocks, blist)`, one dynamic address a
+    budget slot — kept here as the oracle of the chunk append that
+    took its place."""
+    L = flat.shape[0]
+    NB = -(-L // 16)
+    flat = jnp.concatenate([flat.astype(jnp.int16),
+                            jnp.zeros(NB * 16 - L, jnp.int16)])
+    budget = NB // budget_div
+    vbudget = L // val_div
+    blocks = flat.reshape(NB, 16)
+    bmask = jnp.any(blocks != 0, axis=1)
+    nblk = jnp.sum(bmask.astype(jnp.int32))
+    pos = jnp.cumsum(bmask.astype(jnp.int32)) - 1
+    (came,) = jaxcore._compact_left(
+        jnp.where(bmask, jnp.arange(NB, dtype=jnp.int32) - pos, 0))
+    slot = jnp.arange(budget, dtype=jnp.int32)
+    live = slot < nblk
+    blist = jnp.where(live, slot + came[:budget], 0)
+    gathered = jnp.take(blocks, blist, axis=0)
+    gathered = jnp.where(live[:, None], gathered, 0)
+    bitmap = jnp.sum(
+        jaxcore._pad8(bmask).reshape(-1, 8).astype(jnp.uint8)
+        * jaxcore._BIT_WEIGHTS, axis=-1).astype(jnp.uint8)
+    emask = gathered != 0
+    lanes = jnp.asarray([1 << k for k in range(16)], jnp.int32)
+    bmask16 = jnp.sum(emask.astype(jnp.int32) * lanes,
+                      axis=1).astype(jnp.uint16)
+    counts = jnp.sum(emask.astype(jnp.int32), axis=1)
+    offs = jnp.cumsum(counts) - counts
+    within = jnp.cumsum(emask.astype(jnp.int32), axis=1) - 1
+    nval = jnp.sum(counts)
+    at = jnp.arange(budget * 16, dtype=jnp.int32).reshape(emask.shape)
+    shift = jnp.where(emask, at - (offs[:, None] + within), 0)
+    clipped = jnp.clip(gathered, -127, 127).astype(jnp.int8)
+    _, vals = jaxcore._compact_left(shift.reshape(-1), clipped.reshape(-1))
+    vals = jnp.pad(vals, (0, max(vbudget - vals.shape[0], 0)))[:vbudget]
+    n_esc = jnp.sum((jnp.abs(gathered) > 127).astype(jnp.int32))
+    return (nblk, nval, n_esc, bitmap, bmask16, vals)
+
+
+class TestChunkAppend:
+    """ISSUE 47: tier 1 of `_block_sparse_pack2` appends chunks of
+    `_APPEND_CHUNK` blocks where it gathered rows. All six outputs
+    equal the gather form's (`_gather_pack2`) for every input, past
+    the budgets too; the Pallas kernel, run by the interpreter, equals
+    its XLA mirror on the same cases."""
+
+    CHUNK = jaxcore._APPEND_CHUNK
+    #: blocks of the common case: three chunks and a part of a fourth
+    NB = 3 * CHUNK + 100
+    #: and of the cases whose kept blocks fill several chunks of the
+    #: output (a 1080p GOP fills about 60 of its 383)
+    BIG = 16 * CHUNK + 100
+
+    @staticmethod
+    def _levels(nb, L, at, rng, big=None):
+        """`L` levels in `nb` blocks, those of index `at` holding one
+        to three of them (`big`: one level of that size in the first)."""
+        flat = np.zeros(nb * 16, np.int32)
+        for b in at:
+            lanes = rng.choice(16, rng.integers(1, 4), replace=False)
+            flat[b * 16 + lanes] = rng.choice([-1, 1], len(lanes)) \
+                * rng.integers(1, 120, len(lanes))
+        if big is not None:
+            flat[at[0] * 16 + 3] = big
+        return flat[:L]
+
+    def _case(self, name):
+        """name -> (flat levels, budget_div, val_div)."""
+        rng = np.random.default_rng(len(name))
+        nb = self.NB
+        budget = nb // 4
+
+        def some(count, lo=0, hi=None):
+            return np.sort(rng.choice(np.arange(lo, hi or nb), count,
+                                      replace=False))
+
+        L = nb * 16 - 5                     # not a multiple of 16
+        div = (jaxcore._BLOCK_BUDGET_DIV, jaxcore._VAL_BUDGET_DIV)
+        if name == "fill_0pct":
+            return np.zeros(L, np.int32), *div
+        if name == "one_block":
+            return self._levels(nb, L, [self.CHUNK + 7], rng), *div
+        if name == "the_last_block":        # the partial one: 11 levels
+            flat = np.zeros(L, np.int32)
+            flat[-1] = -3
+            return flat, *div
+        fills = {"fill_15pct": 0.15, "exactly_the_budget": 1.0,
+                 "fill_101pct": 1.01, "fill_200pct": 2.0}
+        if name in fills:
+            count = int(round(fills[name] * budget))
+            return self._levels(nb, L, some(count), rng), *div
+        if name == "all_in_the_last_chunk":
+            return self._levels(nb, L, some(90, 3 * self.CHUNK), rng), *div
+        if name == "whole_chunks":          # NB a multiple of the chunk
+            nb = 2 * self.CHUNK
+            return self._levels(nb, nb * 16, some(700, 0, nb), rng), *div
+        if name == "unit_divisors":         # the split-frame form
+            nb = self.CHUNK + 900
+            return self._levels(nb, nb * 16 - 9, some(3000, 0, nb),
+                                rng), 1, 1
+        if name == "escape":
+            return self._levels(nb, L, some(200), rng, big=-300), *div
+        # the output's chunks beyond the first: a chunk's live blocks
+        # pass the end of one and open the next
+        nb = self.BIG
+        L = nb * 16 - 5
+        many = {"four_chunks_out": (0.9, div),
+                "past_the_last_chunk_out": (2.0, div),
+                "sixteen_chunks_out": (0.7, (1, 1))}
+        if name in many:
+            fill, divs = many[name]
+            return self._levels(nb, L, some(int(fill * (nb // divs[0]))),
+                                rng), *divs
+        if name == "chunks_end_on_the_outputs_edges":
+            # live blocks a chunk: the second ends on the output's
+            # first edge, a full one lies edge to edge, an empty one
+            # stores nothing, the next two end on the third edge
+            at = [c * self.CHUNK + rng.choice(self.CHUNK, n, replace=False)
+                  for c, n in enumerate(
+                      [1500, 2596, 4096, 0, 3000, 1096, 5, 4091, 17])]
+            return self._levels(nb, L, np.sort(np.concatenate(at)),
+                                rng), *div
+        raise KeyError(name)
+
+    CASES = ["fill_0pct", "one_block", "the_last_block", "fill_15pct",
+             "exactly_the_budget", "fill_101pct", "fill_200pct",
+             "all_in_the_last_chunk", "whole_chunks", "unit_divisors",
+             "escape", "four_chunks_out", "past_the_last_chunk_out",
+             "sixteen_chunks_out", "chunks_end_on_the_outputs_edges"]
+
+    @pytest.mark.parametrize("form", ["mirror", "kernel"])
+    @pytest.mark.parametrize("case", CASES)
+    def test_all_six_outputs_equal_the_gather_forms(self, case, form,
+                                                    monkeypatch):
+        from thinvids_tpu.codecs.h264 import jaxme
+
+        if form == "kernel":
+            monkeypatch.setattr(jaxme, "use_pallas", lambda: True)
+            kernel = jaxcore._append_kernel
+            monkeypatch.setattr(
+                jaxcore, "_append_kernel",
+                lambda *a: kernel(*a, interpret=True))
+        flat, budget_div, val_div = self._case(case)
+        L = flat.shape[0]
+        want = [np.asarray(x) for x in jax.jit(
+            _gather_pack2, static_argnums=(1, 2))(
+                jnp.asarray(flat), budget_div, val_div)]
+        got = [np.asarray(x) for x in jax.jit(
+            jaxcore._block_sparse_pack2, static_argnums=(1, 2))(
+                jnp.asarray(flat), budget_div, val_div)]
+        for name, g, w in zip(("nblk", "nval", "n_esc", "bitmap",
+                               "bmask16", "vals"), got, want):
+            assert g.dtype == w.dtype and g.shape == w.shape, name
+            np.testing.assert_array_equal(g, w, err_msg=name)
+        nblk, nval, n_esc, bitmap, bmask16, vals = got
+        assert int(nblk) == len(np.unique(np.nonzero(flat)[0] // 16))
+        fits = jaxcore.block_sparse2_fits(nblk, nval, n_esc, L,
+                                          budget_div, val_div)
+        assert fits == (case not in ("fill_101pct", "fill_200pct",
+                                     "escape", "past_the_last_chunk_out"))
+        assert (int(n_esc) > 0) == (case == "escape")
+        if fits:
+            np.testing.assert_array_equal(
+                layout.block_sparse_unpack2_host(
+                    int(nblk), int(nval), bitmap, bmask16, vals, L),
+                flat.astype(np.int16))
+
+
 class TestCompactTransferParity:
     """The wave pipeline against the single-device reference
     (encoder.encode_gop), GOP for GOP: the compact wire where the

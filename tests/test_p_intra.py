@@ -411,14 +411,17 @@ class TestIntraResidualOnTiles:
 # p_intra off: the parent's programs
 # ---------------------------------------------------------------------------
 
-#: sha256[:16] of str(jax.make_jaxpr(dispatch._encode_gop_single)) at
-#: the parent commit of PR 45 (20e3ab8), 2 GOPs of 4 frames of 96x64,
-#: scan form and bounded form: with `p_intra` off (the default) the
-#: setting adds no equation to any GOP program. A PR that changes those
-#: programs on purpose records its own (the loop below prints them).
+#: sha256[:16] of str(jax.make_jaxpr(dispatch._encode_gop_single)), 2
+#: GOPs of 4 frames of 96x64, scan form and bounded form: with
+#: `p_intra` off (the default) the setting adds no equation to any GOP
+#: program — PR 45 recorded them at its parent commit (20e3ab8:
+#: a90f8e4d8ccbbf61 / bf7bea49157f8076 library, fe577b42d339bd61 /
+#: b89ccc80a38b1b9b serving). A PR that changes those programs on
+#: purpose records its own (the loop below prints them); these are
+#: PR 47's, whose pack appends chunks (jaxcore._append_blocks).
 PARENT_JAXPR = {
-    "library": ("a90f8e4d8ccbbf61", "bf7bea49157f8076"),
-    "serving": ("fe577b42d339bd61", "b89ccc80a38b1b9b"),
+    "library": ("ecf2adff318bd32a", "8e7c99422a697e43"),
+    "serving": ("8133ebc0ff357abd", "fb0fba2f45ee10a0"),
 }
 
 

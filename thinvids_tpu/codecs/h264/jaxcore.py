@@ -24,8 +24,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from . import rdo
+from . import jaxme, rdo
 from .encoder import FrameLevels, _mode_policy
 from .intra import LUMA_BLOCK_ORDER
 from .rdo import RD_OFF
@@ -648,8 +650,14 @@ _BLOCK = 16
 _BLOCK_BUDGET_DIV = 4
 
 
-def _compact_left(shift, *streams):
-    """Order-preserving stream compaction with static addressing.
+def _slide_left(x, by: int):
+    """`x` moved left by `by` along its last axis, zeros entering."""
+    return jnp.pad(x[..., by:], [(0, 0)] * (x.ndim - 1) + [(0, by)])
+
+
+def _compact_left(shift, *streams, move=_slide_left):
+    """Order-preserving stream compaction with static addressing,
+    along the last axis.
 
     `shift[i]` is how far element i moves left: its position minus its
     rank among the kept elements, and 0 in a slot that holds nothing
@@ -660,16 +668,155 @@ def _compact_left(shift, *streams):
     after ceil(log2(n)) rounds element i sits at i - shift[i] and every
     other slot holds 0. Returns the moved (shift, *streams); a moved
     shift still says how far its element came, so position + shift is
-    where it started."""
-    n = shift.shape[0]
+    where it started. A stream may have leading axes `shift` lacks
+    (they move alike). `move(x, by)` is the left move; a rotation does
+    as well as `_slide_left`, since no element moves past slot 0."""
+    n = shift.shape[-1]
     for k in range(max(n - 1, 0).bit_length()):
         by = 1 << k
         leaves = shift & by != 0
         movers = [jnp.where(leaves, x, 0) for x in (shift, *streams)]
         # no mover lands on a stayer, so OR merges the two
-        shift, *streams = ((x ^ m) | jnp.pad(m[by:], (0, by))
+        shift, *streams = ((x ^ m) | move(m, by)
                            for x, m in zip((shift, *streams), movers))
     return (shift, *streams)
+
+
+# Tier 1 of the two-tier pack appends CHUNKS of this many consecutive
+# blocks (a power of two, whole 128-lane registers): each chunk's
+# blocks with a level are moved to its front by `_compact_left` and
+# the chunk is stored whole where the last one's live blocks ended —
+# one dynamic address a chunk (1,531 a 1080p GOP of 32 frames), where
+# the row gather this replaced had one per budget slot (1.57 M, 29 ns
+# each on the v5e: 1.27-1.84 ms of every 1080p frame, and 5-50 % more
+# or less of it wherever the program's buffers happened to lie,
+# PERF.md §6 PR 47). 12 rounds a chunk at 4,096; the kernel alone on
+# a 1080p GOP (v5e, PR 47): 8.2 / 4.7 / 3.0 / 3.1 ms at 1,024 / 2,048 /
+# 4,096 / 8,192.
+_APPEND_CHUNK = 4096
+#: a block as the append moves it: 8 int32 words of two levels each
+_BLOCK_WORDS = _BLOCK // 2
+
+
+def _append_loop(offs, shift, words, budget: int):
+    """Tier 1's append as XLA ops (the CPU mirror of
+    `_append_kernel`): every chunk compacted at once, then stored
+    whole at its offset, first to last, so that each store overwrites
+    the dead tail of the one before. Past the budget the stores land
+    behind it. On the v5e this form read 0.76 ms a 1080p frame in the
+    served programs for the kernel's 0.09 (PERF.md §6 PR 47), which is
+    why the chip does not run it."""
+    chunk = _APPEND_CHUNK
+    chunks = words.shape[1] // chunk
+    base = jnp.arange(chunks, dtype=jnp.int32) * chunk - offs[:-1]
+    _, moved = _compact_left(
+        jnp.maximum(shift.reshape(chunks, chunk) - base[:, None], 0),
+        words.reshape(_BLOCK_WORDS, chunks, chunk))
+
+    def store(c, out):
+        return jax.lax.dynamic_update_slice(
+            out, moved[:, c], (0, jnp.minimum(offs[c], budget)))
+
+    # the init derived from data: see _varying_zero
+    return jax.lax.fori_loop(
+        0, chunks, store,
+        jnp.zeros((_BLOCK_WORDS, budget + chunk), jnp.int32)
+        + _varying_zero(words))
+
+
+def _append_kernel(offs, shift, words, budget: int,
+                   interpret: bool = False):
+    """Tier 1's append as one Pallas kernel (custom call
+    `tvt_pack_append`): grid step c reads chunk c — its words and
+    their shifts, one (8, chunk) register row each per 128 blocks —,
+    compacts it in VMEM, turns it to where the live blocks so far end
+    (`offs[c]`, prefetched) and ORs it into the output block that
+    offset lies in, which stays in VMEM until the offset leaves it.
+    What of a chunk passes the block's end waits in `carry` and opens
+    the next block; one more grid step than chunks flushes the last
+    carry. The levels are read once and the kept blocks written once.
+    Output blocks no offset reached are never written (the caller's
+    `live` mask zeroes them), and past `cap` nothing is: the leading
+    blocks are the ones kept."""
+    chunk = _APPEND_CHUNK
+    chunks = words.shape[1] // chunk
+    blocks_out = budget // chunk + 2
+    cap = (blocks_out - 1) * chunk
+
+    def block_of(c, offs_ref):
+        return jnp.minimum(offs_ref[c], cap) // chunk
+
+    def kernel(offs_ref, shift_ref, words_ref, out_ref, carry_ref):
+        c = pl.program_id(0)
+        off = offs_ref[c]
+
+        @pl.when(c == 0)
+        def _():
+            carry_ref[...] = jnp.zeros_like(carry_ref)
+
+        @pl.when((c == 0) | (block_of(c, offs_ref)
+                             != block_of(jnp.maximum(c - 1, 0), offs_ref)))
+        def _():
+            out_ref[...] = carry_ref[...]
+
+        @pl.when((c < chunks) & (off < cap))
+        def _():
+            local = jnp.maximum(shift_ref[...] - (c * chunk - off), 0)
+            _, moved = _compact_left(
+                jnp.broadcast_to(local, words_ref.shape), words_ref[...],
+                move=lambda x, by: pltpu.roll(x, chunk - by, 1))
+            fill = off % chunk
+            # turned a bit of `fill` at a time, by static rotations as
+            # the rounds are (`pltpu.roll` by a traced count compiles,
+            # but no chip has run it for this repo: PERF.md §7)
+            for k in range((chunk - 1).bit_length()):
+                moved = jnp.where((fill >> k) & 1 == 1,
+                                  pltpu.roll(moved, 1 << k, 1), moved)
+            here = jax.lax.broadcasted_iota(
+                jnp.int32, moved.shape, 1) >= fill
+            out_ref[...] |= jnp.where(here, moved, 0)
+            carry_ref[...] = jnp.where(here, 0, moved)
+
+    def chunk_spec(rows):
+        return pl.BlockSpec(
+            (rows, chunk), lambda c, _: (0, jnp.minimum(c, chunks - 1)))
+
+    return pl.pallas_call(
+        kernel, name="tvt_pack_append", interpret=interpret,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(chunks + 1,),
+            in_specs=[chunk_spec(1), chunk_spec(_BLOCK_WORDS)],
+            out_specs=pl.BlockSpec(
+                (_BLOCK_WORDS, chunk), lambda c, o: (0, block_of(c, o))),
+            scratch_shapes=[pltpu.VMEM((_BLOCK_WORDS, chunk), jnp.int32)]),
+        # under shard_map the output varies over the mesh axes the
+        # levels do (check_vma requires it to be said)
+        out_shape=jax.ShapeDtypeStruct(
+            (_BLOCK_WORDS, blocks_out * chunk), jnp.int32,
+            vma=jax.typeof(words).vma),
+    )(offs, shift[None, :], words)
+
+
+@jax.named_scope("append")
+def _append_blocks(blocks, bmask, pos, budget: int):
+    """Tier 1: the first `budget` columns of `blocks` ((16, NB) int16,
+    NB a multiple of `_APPEND_CHUNK`) that `bmask` marks, in order, as
+    (budget, 16). `pos` is `bmask`'s running count less one. Slots
+    past the marked count hold anything."""
+    chunks = blocks.shape[1] // _APPEND_CHUNK
+    offs = jnp.concatenate([
+        jnp.zeros(1, jnp.int32),
+        pos.reshape(chunks, _APPEND_CHUNK)[:, -1] + 1])
+    # how far a marked block lies from its rank; a chunk takes its own
+    # part of that off (`_compact_left` needs shifts inside the chunk)
+    shift = jnp.where(
+        bmask, jnp.arange(bmask.shape[0], dtype=jnp.int32) - pos, 0)
+    words = (blocks[:_BLOCK_WORDS].astype(jnp.int32) & 0xFFFF) \
+        | (blocks[_BLOCK_WORDS:].astype(jnp.int32) << 16)
+    append = _append_kernel if jaxme.use_pallas() else _append_loop
+    kept = append(offs, shift, words, budget)[:, :budget]
+    return jnp.concatenate(
+        [(kept << 16) >> 16, kept >> 16]).astype(jnp.int16).T
 
 
 # Value-stream budget for the two-tier pack: elementwise nonzero density
@@ -692,9 +839,9 @@ _VAL_BUDGET_DIV = 24
 @stage("pack")
 def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
                         val_div: int = _VAL_BUDGET_DIV):
-    """Two-tier device compaction: block-granular gather of the
-    16-coeff blocks with a level (tier 1) + within-block value
-    compaction (tier 2).
+    """Two-tier device compaction: the 16-coeff blocks with a level,
+    in order (tier 1: an append of chunks, `_append_blocks`) + within-
+    block value compaction (tier 2).
 
     The device→host transfer is what this pack shrinks (its rate on a
     directly attached chip is not measured); tier 1 alone ships 16
@@ -705,7 +852,7 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
 
     Returns (nblk, nval, n_esc, bitmap, bmask16, vals):
     - bitmap: 1 bit per block (any-nonzero), ceil(L/16)/8 bytes;
-    - bmask16: per gathered block, a uint16 lane-occupancy mask
+    - bmask16: per kept block, a uint16 lane-occupancy mask
       (bit k = coeff k nonzero), fixed (NB//budget_div,) buffer;
     - vals: the nonzero coeffs in (block, lane) order, int8-clipped,
       fixed (L//val_div,) buffer;
@@ -717,35 +864,41 @@ def _block_sparse_pack2(flat, budget_div: int = _BLOCK_BUDGET_DIV,
     Caller falls back to a dense fetch iff nblk/nval/n_esc exceed their
     budgets (`block_sparse2_fits`).
 
-    Both compactions (nonzero blocks → `blist`, nonzero values →
-    `vals`) go through `_compact_left`. As scatters
-    (`zeros.at[pos].set(..., mode="drop")`) XLA's TPU lowering sorted
-    the (position, value) pairs and then applied them one at a time:
-    5.52 + 1.57 ms per 1080p frame for the values and 1.17 ms for the
-    block list, of a 10.25 ms pack (PERF_LEDGER PR 24, `hd-backlog`).
-    Same bytes out, overflow included: past a budget the leading
-    blocks / values are the ones kept.
+    Both compactions (nonzero blocks inside a chunk, nonzero values →
+    `vals`) go through `_compact_left`, and a chunk is placed by ONE
+    dynamic offset. What the device made of the other forms, per 1080p
+    frame: as scatters (`zeros.at[pos].set(..., mode="drop")`, a sort
+    of the (position, value) pairs, then one update at a time) 5.52 +
+    1.57 ms for the values and 1.17 ms for the block list, of a
+    10.25 ms pack (PERF_LEDGER PR 24, `hd-backlog`); tier 1 as a row
+    gather (`jnp.take(blocks, blist)`, one dynamic address a budget
+    slot, until ISSUE 47) 1.27-1.84 ms, which moved by up to half with
+    the placement of the program's buffers (tests/test_compact.py
+    keeps that form as the oracle). Same bytes out, overflow
+    included: past a budget the leading blocks / values are the ones
+    kept.
     """
     L = flat.shape[0]
     NB = -(-L // _BLOCK)
-    pad = NB * _BLOCK - L
+    # zero blocks fill the last chunk of tier 1's append
+    pad = -(-NB // _APPEND_CHUNK) * _APPEND_CHUNK * _BLOCK - L
     flat = flat.astype(jnp.int16)       # CAVLC levels fit int16
     if pad:
         flat = jnp.concatenate([flat, jnp.zeros(pad, flat.dtype)])
     budget = NB // budget_div
     vbudget = L // val_div
-    blocks = flat.reshape(NB, _BLOCK)
-    bmask = jnp.any(blocks != 0, axis=1)
+    # a block is a column, its 16 levels on sublanes; held as such, or
+    # the compiler turns the levels once for the mask and once, as
+    # int32 at 8 times the bytes, for the append
+    blocks = jax.lax.optimization_barrier(flat.reshape(-1, _BLOCK).T)
+    bmask = jnp.any(blocks != 0, axis=0)
     nblk = jnp.sum(bmask.astype(jnp.int32))
     pos = jnp.cumsum(bmask.astype(jnp.int32)) - 1
-    # the k-th nonzero block's index is k plus the shift it arrives with
-    (came,) = _compact_left(
-        jnp.where(bmask, jnp.arange(NB, dtype=jnp.int32) - pos, 0))
     slot = jnp.arange(budget, dtype=jnp.int32)
     live = slot < nblk
-    blist = jnp.where(live, slot + came[:budget], 0)
-    gathered = jnp.take(blocks, blist, axis=0)           # (budget, 16)
+    gathered = _append_blocks(blocks, bmask, pos, budget)  # (budget, 16)
     gathered = jnp.where(live[:, None], gathered, 0)
+    bmask = bmask[:NB]
     bitmap = jnp.sum(
         _pad8(bmask).reshape(-1, 8).astype(jnp.uint8) * _BIT_WEIGHTS,
         axis=-1).astype(jnp.uint8)
