@@ -32,9 +32,12 @@ from thinvids_tpu.tools.pan import make_frames
 #: the "X" events of a local GOP-shape job's GET /trace/<job> at the
 #: parent of this PR: `benchmark/tvtbench/evidence.pipeline_extent`
 #: takes min and max over ALL of them, so a span outside the wave
-#: pipeline would turn `job_fixed_ms` and `mux_ms_per_job` to ~0
+#: pipeline would turn `job_fixed_ms` and `mux_ms_per_job` to ~0.
+#: (Since PR 48 a job inside the sparse budgets has no `unflatten`:
+#: its slice thunks build their views on the levels they unpack; the
+#: span is the dense fallback's and a library-less host's.)
 PIPELINE_SPANS = {"decode", "stage", "upload", "dispatch", "device_wait",
-                  "fetch", "sparse_unpack", "unflatten", "pack", "concat",
+                  "fetch", "sparse_unpack", "pack", "concat",
                   "wave_dispatch", "wave_collect", "wave_fetch_start"}
 JOB_CLOCKS = ("job_build", "job_plan", "job_stitch", "job_mux",
               "job_write", "job_commit")

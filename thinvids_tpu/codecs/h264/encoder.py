@@ -591,9 +591,9 @@ def unpack_mode16(mode16: np.ndarray):
     return m & 15, m >> 4
 
 
-def _gop_slice_thunks(intra, pack_p, num_frames: int, mbw: int, mbh: int,
-                      sps: SPS, pps: PPS, qp: int, idr_pic_id: int,
-                      with_headers: bool, rd=None) -> list:
+def _gop_slice_thunks(intra_of, pack_p, num_frames: int, mbw: int,
+                      mbh: int, sps: SPS, pps: PPS, qp: int,
+                      idr_pic_id: int, with_headers: bool, rd=None) -> list:
     """Per-slice pack closures for one GOP (IDR thunk first, then one
     per P frame). A GOP's slices are independent bit-strings until the
     final concat, so callers may run the thunks on a thread pool (the
@@ -602,31 +602,34 @@ def _gop_slice_thunks(intra, pack_p, num_frames: int, mbw: int, mbh: int,
     funnels through here so the bit-identity contract between paths
     cannot drift in the IDR/header logic.
 
-    `intra` is the 4-tuple of blocked level arrays, or — when the
-    encode shipped the per-MB side channel (rd.ships_modes) — a
-    6-tuple with (mode16, dqp16) appended."""
+    `intra_of()` gives the IDR's levels when its thunk RUNS (a caller
+    may unpack them there, on the packing thread): the 4-tuple of
+    blocked level arrays, or — when the encode shipped the per-MB side
+    channel (rd.ships_modes) — a 6-tuple with (mode16, dqp16)
+    appended."""
     from .rdo import RD_OFF
 
     if rd is None:
         rd = RD_OFF
-    if len(intra) == 6:
-        il_dc, il_ac, ic_dc, ic_ac, mode16, dqp16 = intra
-        luma_mode, chroma_mode = unpack_mode16(mode16)
-        qp_delta = np.asarray(dqp16, np.int32)
-        if not np.any(qp_delta):
-            qp_delta = None
-    else:
-        il_dc, il_ac, ic_dc, ic_ac = intra
-        luma_mode, chroma_mode = _mode_policy(mbw, mbh)
-        qp_delta = None
-    intra_levels = FrameLevels(
-        luma_mode=luma_mode, chroma_mode=chroma_mode,
-        luma_dc=il_dc, luma_ac=il_ac, chroma_dc=ic_dc, chroma_ac=ic_ac,
-        qp_delta=qp_delta)
     head = sps.to_nal() + pps.to_nal() if with_headers else b""
     deblock_idc = 0 if rd.deblock else 1
 
     def pack_idr():
+        intra = intra_of()
+        if len(intra) == 6:
+            il_dc, il_ac, ic_dc, ic_ac, mode16, dqp16 = intra
+            luma_mode, chroma_mode = unpack_mode16(mode16)
+            qp_delta = np.asarray(dqp16, np.int32)
+            if not np.any(qp_delta):
+                qp_delta = None
+        else:
+            il_dc, il_ac, ic_dc, ic_ac = intra
+            luma_mode, chroma_mode = _mode_policy(mbw, mbh)
+            qp_delta = None
+        intra_levels = FrameLevels(
+            luma_mode=luma_mode, chroma_mode=chroma_mode,
+            luma_dc=il_dc, luma_ac=il_ac, chroma_dc=ic_dc, chroma_ac=ic_ac,
+            qp_delta=qp_delta)
         return head + pack_slice(intra_levels, mbw, mbh, sps, pps, qp,
                                  frame_num=0, idr=True,
                                  idr_pic_id=idr_pic_id % 65536,
@@ -654,8 +657,9 @@ def _pack_gop_common(intra, pack_p, num_frames: int, mbw: int, mbh: int,
     intra levels + one P slice per remaining frame via `pack_p(i,
     frame_num)`, optionally fanned across `pool` at slice granularity."""
     return run_slice_thunks(
-        _gop_slice_thunks(intra, pack_p, num_frames, mbw, mbh, sps, pps,
-                          qp, idr_pic_id, with_headers, rd=rd), pool)
+        _gop_slice_thunks(lambda: intra, pack_p, num_frames, mbw, mbh,
+                          sps, pps, qp, idr_pic_id, with_headers, rd=rd),
+        pool)
 
 
 def gop_slice_thunks_planes(intra, planes, num_frames: int, mbw: int,
@@ -666,20 +670,36 @@ def gop_slice_thunks_planes(intra, planes, num_frames: int, mbw: int,
     pack_gop_slices_planes for the array contract). dispatch.collect_wave
     submits these so slices from ALL of a wave's GOPs pack concurrently
     on the pack pool instead of GOP-by-GOP."""
+    return gop_slice_thunks_frames(
+        lambda: intra, lambda i: tuple(a[i] for a in planes), num_frames,
+        mbw, mbh, sps, pps, qp, idr_pic_id, with_headers, rd=rd)
+
+
+def gop_slice_thunks_frames(intra_of, frame_of, num_frames: int, mbw: int,
+                            mbh: int, sps: SPS, pps: PPS, qp: int,
+                            idr_pic_id: int, with_headers: bool = True,
+                            rd=None) -> list:
+    """:func:`gop_slice_thunks_planes` with the levels handed over
+    slice by slice, when each thunk runs and on the thread that runs
+    it: `intra_of()` the IDR's tuple, `frame_of(i)` P frame i's row of
+    the plane arrays (mv8, lp, udc, vdc, uac, vac[, pmode]). A thunk's
+    bytes own nothing of what it was handed, so a caller may give
+    every slice of a thread the same memory (dispatch.collect_wave
+    unpacks each slice's levels there)."""
     from . import inter as inter_mod
 
     deblock_idc = 0 if rd is not None and rd.deblock else 1
     mv_per_pel = rd.mv_per_pel if rd is not None else 2
-    mv8, lp, udc, vdc, uac, vac, *pmode = planes
-    return _gop_slice_thunks(
-        intra,
-        lambda i, fn: inter_mod.pack_p_slice_plane(
-            mv8[i], lp[i], udc[i], vdc[i], uac[i], vac[i], mbw, mbh,
-            sps, pps, qp, frame_num=fn, deblock_idc=deblock_idc,
-            mv_per_pel=mv_per_pel,
-            pmode=pmode[0][i] if pmode else None),
-        num_frames, mbw, mbh, sps, pps, qp, idr_pic_id, with_headers,
-        rd=rd)
+
+    def pack_p(i, fn):
+        mv, lp, udc, vdc, uac, vac, *pmode = frame_of(i)
+        return inter_mod.pack_p_slice_plane(
+            mv, lp, udc, vdc, uac, vac, mbw, mbh, sps, pps, qp,
+            frame_num=fn, deblock_idc=deblock_idc, mv_per_pel=mv_per_pel,
+            pmode=pmode[0] if pmode else None)
+
+    return _gop_slice_thunks(intra_of, pack_p, num_frames, mbw, mbh, sps,
+                             pps, qp, idr_pic_id, with_headers, rd=rd)
 
 
 def pack_gop_slices_planes(intra, planes, num_frames: int, mbw: int,

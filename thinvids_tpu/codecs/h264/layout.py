@@ -25,9 +25,18 @@ blocks, nb8 = ceil(NB / 8)):
 after is transfer padding (the device buffer is budget-sized, the host
 fetches a quantized slice). nblk/nval ride as separate tiny count
 arrays, fetched with the device-wait barrier.
+
+The payload unpacks whole (unpack_compact_*: a split-frame band) or in
+RANGES of the level vector (a GOP: each slice thunk unpacks the levels
+it packs, rest_spans): one validating pass files an index — for every
+INDEX_STRIDE-th block the live blocks and the values before it
+(index_compact_*) — and a range starts from the entry at or before its
+first block (unpack_compact_range_*).
 """
 
 from __future__ import annotations
+
+import math
 
 import numpy as np
 
@@ -45,6 +54,18 @@ _INTRA_FLAT_MB = 384
 
 #: 16-coeff granularity of the block-sparse transfer tiers
 SPARSE_BLOCK = 16
+
+#: blocks between two entries of a compact payload's index (a multiple
+#: of 64: the native pass counts the bitmap a 64-bit word at a time).
+#: A range's unpack walks at most this many blocks to its start; a
+#: 1080p GOP's index has 1,558 entries.
+INDEX_STRIDE = 4096
+
+
+def index_entries(L: int) -> int:
+    """Entries in the index of a compact payload of `L` levels."""
+    NB = -(-L // SPARSE_BLOCK)
+    return -(-NB // INDEX_STRIDE)
 
 
 # ---- compact payload parsing ----------------------------------------------
@@ -74,13 +95,19 @@ def split_compact(payload: np.ndarray, nblk: int, nval: int, L: int):
     return bitmap, bmask16, vals
 
 
-def block_sparse_unpack2_host(nblk: int, nval: int, bitmap: np.ndarray,
-                              bmask16: np.ndarray, vals: np.ndarray,
-                              L: int) -> np.ndarray:
-    """Numpy inverse of jaxcore._block_sparse_pack2 → flat int16 levels
-    (the native scatter's parity reference; jaxcore re-exports it).
-    Rejects count/stream disagreement like the native core: corrupt
-    counts must fail loudly, not decode as silent zeros."""
+def _lane_bits(masks: np.ndarray) -> np.ndarray:
+    """uint16 lane masks → (n, 16) booleans, lane k = bit k."""
+    return ((masks.astype(np.uint32)[:, None]
+             >> np.arange(SPARSE_BLOCK, dtype=np.uint32)) & 1).astype(bool)
+
+
+def _checked_streams(nblk: int, nval: int, bitmap: np.ndarray,
+                     bmask16: np.ndarray, vals: np.ndarray, L: int):
+    """The validation every numpy unpack shares: (bm, lane_bits) — the
+    (NB,) live-block and (nblk, 16) live-lane booleans — of streams
+    that agree with their counts. Rejects count/stream disagreement
+    like the native index pass: corrupt counts must fail loudly, not
+    decode as silent zeros."""
     NB = -(-L // SPARSE_BLOCK)
     if L <= 0 or nblk < 0 or nval < 0:
         raise ValueError("sparse stream counts out of range")
@@ -96,25 +123,32 @@ def block_sparse_unpack2_host(nblk: int, nval: int, bitmap: np.ndarray,
     bits = np.unpackbits(bitmap[:nb8])
     if bits[NB:].any():
         # pack never sets the byte-padding bits past NB; a set one is
-        # a corrupt bitmap (the native core's tail scan rejects it too
-        # — fuzz-found asymmetry, tools/fuzz_native.py)
+        # a corrupt bitmap (the native pass rejects it too —
+        # fuzz-found asymmetry, tools/fuzz_native.py)
         raise ValueError("sparse bitmap padding bits set")
     bm = bits[:NB].astype(bool)
-    masks = np.asarray(bmask16)[:nblk].astype(np.uint32)
-    lane_bits = ((masks[:, None] >> np.arange(SPARSE_BLOCK, dtype=np.uint32))
-                 & 1).astype(bool)                      # (nblk, 16)
-    # Explicit count agreement, like the native core's bi/vi checks:
-    # numpy's size-1 broadcasting otherwise lets a corrupt nval=1
-    # stream silently replicate one value across every live lane
+    lane_bits = _lane_bits(np.asarray(bmask16)[:nblk])  # (nblk, 16)
+    # Explicit count agreement, like the native pass's totals: numpy's
+    # size-1 broadcasting otherwise lets a corrupt nval=1 stream
+    # silently replicate one value across every live lane
     # (fuzz-found, tools/fuzz_native.py)
     if int(bm.sum()) != int(nblk):
         raise ValueError("sparse bitmap disagrees with nblk")
     if int(lane_bits.sum()) != int(nval):
         raise ValueError("sparse lane masks disagree with nval")
+    return bm, lane_bits
+
+
+def block_sparse_unpack2_host(nblk: int, nval: int, bitmap: np.ndarray,
+                              bmask16: np.ndarray, vals: np.ndarray,
+                              L: int) -> np.ndarray:
+    """Numpy inverse of jaxcore._block_sparse_pack2 → flat int16 levels
+    (the native scatter's parity reference; jaxcore re-exports it)."""
+    bm, lane_bits = _checked_streams(nblk, nval, bitmap, bmask16, vals, L)
     stream = np.asarray(vals)[:nval].astype(np.int16)
     rows = np.zeros((nblk, SPARSE_BLOCK), np.int16)
     rows[lane_bits] = stream        # row-major = (block, lane) order
-    out = np.zeros((NB, SPARSE_BLOCK), np.int16)
+    out = np.zeros((bm.shape[0], SPARSE_BLOCK), np.int16)
     out[bm] = rows
     return out.reshape(-1)[:L]
 
@@ -138,6 +172,47 @@ def unpack_compact_auto(payload: np.ndarray, nblk: int, nval: int,
     if native_mod.available():
         return native_mod.unpack_compact(nblk, nval, payload, L)
     return unpack_compact_host(payload, nblk, nval, L)
+
+
+def index_compact_host(payload: np.ndarray, nblk: int, nval: int,
+                       L: int) -> np.ndarray:
+    """Numpy twin of native.index_compact: validate one compact payload
+    as :func:`unpack_compact_host` does (same errors) and return its
+    index, (index_entries(L), 2) int64: entry j = (live blocks, values)
+    before block j * INDEX_STRIDE."""
+    bitmap, bmask16, vals = split_compact(payload, nblk, nval, L)
+    bm, lane_bits = _checked_streams(int(nblk), int(nval), bitmap,
+                                     bmask16, vals, L)
+    at = np.arange(index_entries(L)) * INDEX_STRIDE
+    blocks_before = np.concatenate([[0], np.cumsum(bm)])[at]
+    values_before = np.concatenate([[0], np.cumsum(lane_bits.sum(1))])
+    return np.stack([blocks_before, values_before[blocks_before]],
+                    axis=1).astype(np.int64)
+
+
+def unpack_compact_range_host(payload: np.ndarray, nblk: int, nval: int,
+                              L: int, index: np.ndarray, l0: int, l1: int,
+                              out: np.ndarray) -> None:
+    """Numpy twin of native.unpack_compact_range: levels [l0, l1) of
+    the vector into `out` (l1 - l0 int16, zeroed here), read from the
+    index entry at or before block l0 // 16 on. A block the range's
+    edge cuts gives this side its own lanes."""
+    bitmap, bmask16, vals = split_compact(payload, nblk, nval, L)
+    if not 0 <= l0 <= l1 <= L:
+        raise ValueError(f"level range [{l0}, {l1}) outside [0, {L})")
+    out[:] = 0
+    if l0 == l1:
+        return
+    j = (l0 // SPARSE_BLOCK) // INDEX_STRIDE
+    b_from, b_to = j * INDEX_STRIDE, -(-l1 // SPARSE_BLOCK)
+    bi, vi = (int(x) for x in index[j])
+    bits = np.unpackbits(bitmap[b_from // 8:-(-b_to // 8)])
+    live = b_from + np.flatnonzero(bits[:b_to - b_from])
+    lane_bits = _lane_bits(bmask16[bi:bi + live.shape[0]])
+    at = (live[:, None] * SPARSE_BLOCK + np.arange(SPARSE_BLOCK))[lane_bits]
+    stream = vals[vi:vi + at.shape[0]].astype(np.int16)
+    mine = (at >= l0) & (at < l1)
+    out[at[mine] - l0] = stream[mine]
 
 
 # ---- zero-copy unflatten (flat transfer segments → slice views) ------------
@@ -204,6 +279,19 @@ def unflatten_gop(flat: np.ndarray, mv8: np.ndarray, num_frames: int,
     return intra, planes
 
 
+def split_dense_dc(dense: np.ndarray, nmb: int, ships_modes: bool = False):
+    """The dense transfer segment [il_dc | ic_dc (| mode16 | dqp16)] →
+    (il_dc, ic_dc) views, and the (mode16, dqp16) tail or ()."""
+    ndc = nmb * 16
+    dense = np.asarray(dense)
+    il_dc = dense[:ndc].reshape(nmb, 16)
+    ic_dc = dense[ndc:ndc + nmb * 8].reshape(nmb, 2, 4)
+    if not ships_modes:
+        return il_dc, ic_dc, ()
+    t = ndc + nmb * 8
+    return il_dc, ic_dc, (dense[t:t + nmb], dense[t + nmb:t + 2 * nmb])
+
+
 def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
                         mv8: np.ndarray, num_frames: int,
                         mbw: int, mbh: int, ships_modes: bool = False,
@@ -215,18 +303,38 @@ def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
     first concatenating them back into the full flat layout (which
     copied ~25 MB per 1080p GOP). Views only."""
     nmb = mbw * mbh
-    ndc, nlac = nmb * 16, nmb * 240
-    dense = np.asarray(dense)
+    nlac = nmb * 240
     rest = np.asarray(rest)
-    il_dc = dense[:ndc].reshape(nmb, 16)
-    ic_dc = dense[ndc:ndc + nmb * 8].reshape(nmb, 2, 4)
+    il_dc, ic_dc, modes = split_dense_dc(dense, nmb, ships_modes)
     il_ac = rest[:nlac].reshape(nmb, 16, 15)
     o = nlac + nmb * 120
     ic_ac = rest[nlac:o].reshape(nmb, 2, 4, 15)
     planes = unflatten_p_planes(rest[o:], mv8, num_frames, mbw, mbh,
                                 p_intra)
-    intra = (il_dc, il_ac, ic_dc, ic_ac)
-    if ships_modes:
-        t = ndc + nmb * 8
-        intra = intra + (dense[t:t + nmb], dense[t + nmb:t + 2 * nmb])
-    return intra, planes
+    return (il_dc, il_ac, ic_dc, ic_ac) + modes, planes
+
+
+def rest_spans(num_frames: int, mbw: int, mbh: int, p_intra: bool = False):
+    """Where each slice's levels lie in the sparse remainder `rest` =
+    [il_ac | ic_ac | P planes], for unpacking it slice by slice: the
+    IDR's spans, then one list per P frame, each span an (offset,
+    length, shape) triple in the order :func:`unflatten_gop_parts` hands the
+    views — il_ac, ic_ac; lp, udc, vdc, uac, vac[, pmode]. The P
+    segment is component-major (:func:`unflatten_p_planes`), so a P
+    frame is five or six separate runs of the vector. The offsets
+    need not be multiples of 16."""
+    nmb = mbw * mbh
+    H, W = mbh * 16, mbw * 16
+    F1 = num_frames - 1
+    intra = [(0, nmb * 240, (nmb, 16, 15)),
+             (nmb * 240, nmb * 120, (nmb, 2, 4, 15))]
+    shapes = [(H, W), (nmb, 4), (nmb, 4), (H // 2, W // 2),
+              (H // 2, W // 2)] + ([(nmb,)] if p_intra else [])
+    frames = [[] for _ in range(F1)]
+    o = nmb * 360
+    for shape in shapes:
+        n = math.prod(shape)
+        for i in range(F1):
+            frames[i].append((o + i * n, n, shape))
+        o += F1 * n
+    return intra, frames

@@ -164,6 +164,22 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_void_p, ctypes.c_int64,            # payload, len
             ctypes.c_void_p, ctypes.c_int64,            # out, L
         ]
+        lib.cavlc_compact_index.restype = ctypes.c_int64
+        lib.cavlc_compact_index.argtypes = [
+            ctypes.c_int32, ctypes.c_int32,             # nblk, nval
+            ctypes.c_void_p, ctypes.c_int64,            # payload, len
+            ctypes.c_int64, ctypes.c_int64,             # L, stride
+            ctypes.c_void_p,                            # index out
+        ]
+        lib.cavlc_unpack_compact_range.restype = ctypes.c_int64
+        lib.cavlc_unpack_compact_range.argtypes = [
+            ctypes.c_int32, ctypes.c_int32,             # nblk, nval
+            ctypes.c_void_p, ctypes.c_int64,            # payload, len
+            ctypes.c_int64, ctypes.c_int64,             # L, stride
+            ctypes.c_void_p,                            # index
+            ctypes.c_int64, ctypes.c_int64,             # l0, l1
+            ctypes.c_void_p,                            # out
+        ]
         lib.cavlc_init_inter.argtypes = [ctypes.c_void_p]
         lib.cavlc_pack_pslice.restype = ctypes.c_int64
         lib.cavlc_pack_pslice.argtypes = [
@@ -393,15 +409,35 @@ def block_sparse_unpack2(nblk: int, nval: int, bitmap: np.ndarray,
     if (nblk > bmask16.size or nval > vals.size
             or bitmap.size < -(-NB // 8)):
         raise ValueError("sparse stream counts exceed buffer sizes")
-    # np.zeros = calloc: the native scatter relies on the buffer being
-    # zeroed, and lazy OS zero-pages beat an explicit 50 MB/GOP memset
-    out = np.zeros(NB * 16, np.int16)
+    # The scatter writes the nonzero levels alone, so `out` arrives
+    # zeroed. np.zeros = calloc, whose pages the kernel faults in and
+    # zeroes at first touch — cheap at a split-frame band's size, the
+    # one caller left (a GOP's 204 MB at 1080p paid three quarters of
+    # this call in those faults; GOP waves unpack in ranges into kept
+    # frame-sized memory instead: index_compact / unpack_compact_range).
+    out = np.zeros(L, np.int16)
     rc = lib.cavlc_sparse_unpack2(
         int(nblk), int(nval), bitmap.ctypes.data, bmask16.ctypes.data,
         vals.ctypes.data, out.ctypes.data, L)
     if rc != 0:
         raise ValueError("sparse level stream inconsistent with counts")
-    return out[:L]
+    return out
+
+
+def _compact_args(nblk: int, nval: int, payload: np.ndarray, L: int):
+    """The checks every compact entry makes before C sees a pointer
+    (the C side also checks the payload's length against the counts
+    and returns -2)."""
+    if L <= 0 or nblk < 0 or nval < 0:
+        raise ValueError("compact stream counts out of range")
+    return np.ascontiguousarray(payload, np.uint8)
+
+
+def _compact_rc(rc: int) -> None:
+    if rc == -2:
+        raise ValueError("compact payload truncated for its counts")
+    if rc != 0:
+        raise ValueError("compact level stream inconsistent with counts")
 
 
 def unpack_compact(nblk: int, nval: int, payload: np.ndarray,
@@ -410,21 +446,56 @@ def unpack_compact(nblk: int, nval: int, payload: np.ndarray,
     payload (bitmap | bmask16 byte pairs | int8 vals — format pinned in
     codecs/h264/layout.py) → flat int16 levels, parsed in C with no
     intermediate stream views (layout.unpack_compact_host is the
-    no-compiler fallback and the parity reference)."""
+    no-compiler fallback and the parity reference). The whole vector
+    in one new array: for a split-frame band's worth of levels."""
     lib = _build_and_load()
-    payload = np.ascontiguousarray(payload, np.uint8)
-    NB = -(-L // 16)
-    # Bounds hardening to match block_sparse_unpack2 (the C side also
-    # checks payload_len against the counts and returns -2)
-    if L <= 0 or nblk < 0 or nval < 0:
-        raise ValueError("compact stream counts out of range")
-    # np.zeros = calloc, same lazy-zero-page contract as above
-    out = np.zeros(NB * 16, np.int16)
-    rc = lib.cavlc_unpack_compact(
+    payload = _compact_args(nblk, nval, payload, L)
+    out = np.zeros(L, np.int16)         # zeroed: see block_sparse_unpack2
+    _compact_rc(lib.cavlc_unpack_compact(
         int(nblk), int(nval), payload.ctypes.data, payload.nbytes,
-        out.ctypes.data, L)
-    if rc == -2:
-        raise ValueError("compact payload truncated for its counts")
-    if rc != 0:
-        raise ValueError("compact level stream inconsistent with counts")
-    return out[:L]
+        out.ctypes.data, L))
+    return out
+
+
+def index_compact(nblk: int, nval: int, payload: np.ndarray,
+                  L: int) -> np.ndarray:
+    """One pass over a compact payload's bitmap and lane masks: every
+    check :func:`unpack_compact` makes (same errors), no level written,
+    and the (n, 2) int64 index :func:`unpack_compact_range` starts
+    from — layout.index_compact_host is the parity reference and says
+    what an entry holds."""
+    from ..codecs.h264.layout import INDEX_STRIDE, index_entries
+
+    lib = _build_and_load()
+    payload = _compact_args(nblk, nval, payload, L)
+    index = np.empty((index_entries(L), 2), np.int64)
+    _compact_rc(lib.cavlc_compact_index(
+        int(nblk), int(nval), payload.ctypes.data, payload.nbytes, L,
+        INDEX_STRIDE, index.ctypes.data))
+    return index
+
+
+def unpack_compact_range(nblk: int, nval: int, payload: np.ndarray,
+                         L: int, index: np.ndarray, l0: int, l1: int,
+                         out: np.ndarray) -> None:
+    """Levels [l0, l1) of the vector a compact payload holds, into
+    `out` (l1 - l0 int16, C-contiguous; zeroed here, so a scratch
+    dirty with another slice's levels will do). `index` is
+    :func:`index_compact`'s for the same payload and counts. Runs with
+    the GIL released: slice thunks call it side by side
+    (layout.unpack_compact_range_host is the parity reference)."""
+    from ..codecs.h264.layout import INDEX_STRIDE, index_entries
+
+    lib = _build_and_load()
+    payload = _compact_args(nblk, nval, payload, L)
+    if not 0 <= l0 <= l1 <= L:
+        raise ValueError(f"level range [{l0}, {l1}) outside [0, {L})")
+    if (index.dtype != np.int64 or not index.flags.c_contiguous
+            or index.shape != (index_entries(L), 2)):
+        raise ValueError("not this payload's index")
+    if (out.dtype != np.int16 or out.shape != (l1 - l0,)
+            or not out.flags.c_contiguous or not out.flags.writeable):
+        raise ValueError(f"bad destination for {l1 - l0} levels")
+    _compact_rc(lib.cavlc_unpack_compact_range(
+        int(nblk), int(nval), payload.ctypes.data, payload.nbytes, L,
+        INDEX_STRIDE, index.ctypes.data, l0, l1, out.ctypes.data))

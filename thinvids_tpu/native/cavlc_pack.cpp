@@ -321,50 +321,155 @@ static int64_t pack_islice_impl(
   return emit_ebsp(bw, out, out_cap);
 }
 
-// Shared scatter core of the two sparse-stream unpack entries: bitmap
-// (1 bit/16-coeff block, big-endian within bytes) + per-live-block
-// uint16 lane masks (via `mask_at(i)` — aligned uint16 reads for the
-// array entry, byte-pair reads for the compact payload) + the packed
-// nonzero values -> flat int16 levels in `out` (L coeffs; the caller
-// allocates ceil(L/16)*16 so the tail block never lands out of
-// bounds). One O(nval) scatter instead of numpy's three boolean index
-// passes over the full vector (~25 M coeffs per 1080p GOP). `out` MUST
-// arrive zeroed — the Python wrappers hand a fresh np.zeros (calloc)
-// buffer, so the zero fill is lazy OS zero-pages instead of a 50 MB
-// memset per GOP. Returns 0, or -1 when the streams disagree with the
-// counts (corrupt transfer).
+// ---- sparse level streams -> flat int16 levels -----------------------------
+//
+// Both wire forms (the three budget-padded arrays, and the compact
+// payload that concatenates them: codecs/h264/layout.py) are a bitmap
+// (1 bit per 16-coeff block, big-endian within bytes), a uint16 lane
+// mask per live block (via `mask_at(i)` — aligned uint16 reads for the
+// array entry, byte-pair reads for the payload, whose mask section has
+// no alignment guarantee) and the packed nonzero int8 values in
+// (block, lane) order. Two passes, shared by every entry below:
+//
+//   sparse_index_core    reads bitmap and masks ONCE, a word at a time,
+//                        does all the validation, and (optionally) files
+//                        the running counts every `stride` blocks;
+//   sparse_scatter_core  writes levels [l0, l1) of the vector from a
+//                        point whose running counts are known — block 0,
+//                        or an index entry.
+//
+// The whole-vector entries are the one-range case: validate, then
+// scatter [0, L) from block 0.
+
+// Validation + index pass. Returns 0, or -1 when the streams disagree
+// with the counts (corrupt transfer): live blocks != nblk, mask bits !=
+// nval, or a padding bit past block NB set — what the numpy reference
+// rejects. `index` (or NULL): int64 pairs, entry j = (live blocks,
+// values) before block j * stride, ceil(NB / stride) of them; `stride`
+// a multiple of 64. Reads no mask past the nblk-th.
+template <typename MaskAt>
+static int64_t sparse_index_core(int32_t nblk, int32_t nval,
+                                 const uint8_t* bitmap, MaskAt mask_at,
+                                 int64_t L, int64_t stride,
+                                 int64_t* index) {
+  const int64_t NB = (L + 15) / 16;
+  const int64_t nb8 = (NB + 7) / 8;
+  if ((NB & 7) && (bitmap[nb8 - 1] & (0xFFu >> (NB & 7)))) return -1;
+  const int64_t step = stride / 8;          // bitmap bytes an entry
+  int64_t bi = 0, vi = 0, mi = 0;           // mi: masks summed into vi
+  for (int64_t byte = 0; byte < nb8; byte += step) {
+    for (; mi < bi; mi++) vi += __builtin_popcount(mask_at((int32_t)mi));
+    if (index) {
+      *index++ = bi;
+      *index++ = vi;
+    }
+    const int64_t end = byte + step < nb8 ? byte + step : nb8;
+    int64_t p = byte;
+    for (; p + 8 <= end; p += 8) {
+      uint64_t w;
+      std::memcpy(&w, bitmap + p, 8);
+      bi += __builtin_popcountll(w);
+    }
+    for (; p < end; p++) bi += __builtin_popcount(bitmap[p]);
+    if (bi > nblk) return -1;
+  }
+  for (; mi < bi; mi++) vi += __builtin_popcount(mask_at((int32_t)mi));
+  return (bi == nblk && vi == nval) ? 0 : -1;
+}
+
+// Scatter levels [l0, l1) of the vector into `out` (l1 - l0 int16,
+// ZEROED by the caller), starting at block `b` before which `bi` live
+// blocks and `vi` values lie (b * 16 <= l0). A block the range's edge
+// cuts gives this side its own lanes only. One O(values) scatter
+// instead of numpy's three boolean index passes over the full vector.
+// Memory-safe whatever the counts it is handed: no mask past the
+// nblk-th and no value past the nval-th is read, nothing outside `out`
+// is written; returns -1 where the streams ask for either (they cannot
+// once sparse_index_core passed and (b, bi, vi) is its entry), else 0.
+template <typename MaskAt>
+static int64_t sparse_scatter_core(int32_t nblk, int32_t nval,
+                                   const uint8_t* bitmap, MaskAt mask_at,
+                                   const int8_t* vals, int64_t L,
+                                   int64_t b, int64_t bi, int64_t vi,
+                                   int64_t l0, int64_t l1, int16_t* out) {
+  const int64_t NB = (L + 15) / 16;
+  const int64_t n = l1 - l0;
+  const int64_t b0 = l0 / 16;
+  int64_t b1 = (l1 + 15) / 16;
+  if (b1 > NB) b1 = NB;
+  if (b < 0 || b > b0 || bi < 0 || vi < 0) return -1;
+  // live blocks of [b, b0): counted past, their values skipped
+  for (; b < b0; b++) {
+    if (!(bitmap[b >> 3] & (0x80u >> (b & 7)))) continue;
+    if (bi >= nblk) return -1;
+    vi += __builtin_popcount(mask_at((int32_t)bi++));
+  }
+  while (b < b1) {
+    // the bits of this byte from block b on, below b1
+    uint32_t bits = bitmap[b >> 3] & (0xFFu >> (b & 7));
+    const int64_t byte_b = b & ~(int64_t)7;
+    if (b1 - byte_b < 8) bits &= 0xFF00u >> (b1 - byte_b);
+    b = byte_b + 8;
+    while (bits) {
+      const int lead = __builtin_clz(bits) - 24;
+      bits &= ~(0x80u >> lead);
+      if (bi >= nblk) return -1;
+      uint32_t m = mask_at((int32_t)bi++);
+      if (vi + __builtin_popcount(m) > nval) return -1;
+      const int64_t base = (byte_b + lead) * 16 - l0;
+      if (base >= 0 && base + 16 <= n) {
+        int16_t* o = out + base;
+        while (m) {
+          o[__builtin_ctz(m)] = vals[vi++];
+          m &= m - 1;
+        }
+      } else {                              // cut by an edge of the range
+        while (m) {
+          const int64_t at = base + __builtin_ctz(m);
+          m &= m - 1;
+          if (at >= 0 && at < n) out[at] = vals[vi];
+          vi++;
+        }
+      }
+    }
+  }
+  return 0;
+}
+
+// One whole vector: `out` holds L zeroed int16.
 template <typename MaskAt>
 static int64_t sparse_unpack2_core(int32_t nblk, int32_t nval,
                                    const uint8_t* bitmap, MaskAt mask_at,
                                    const int8_t* vals, int16_t* out,
                                    int64_t L) {
-  const int64_t NB = (L + 15) / 16;
-  int32_t bi = 0, vi = 0;
-  int64_t b = 0;
-  for (; b < NB && bi < nblk; b++) {
-    if (!(bitmap[b >> 3] & (0x80u >> (b & 7)))) continue;
-    uint32_t m = mask_at(bi++);
-    if (vi + __builtin_popcount(m) > nval) return -1;
-    int16_t* o = out + b * 16;
-    while (m) {
-      const int k = __builtin_ctz(m);
-      m &= m - 1;
-      o[k] = vals[vi++];
-    }
-  }
-  if (bi != nblk || vi != nval) return -1;
-  // Any set bit AFTER the nblk-th live block is a corrupt bitmap too —
-  // it must fail loudly like the numpy reference, not decode those
-  // blocks as silent zeros. Byte-granular tail scan.
-  const int64_t nbytes = (NB + 7) / 8;
-  int64_t byte = b >> 3;
-  if (byte < nbytes) {
-    if (bitmap[byte] & (0xFFu >> (b & 7))) return -1;
-    for (byte++; byte < nbytes; byte++)
-      if (bitmap[byte]) return -1;
-  }
-  return 0;
+  // no index: one stride over the whole bitmap
+  if (sparse_index_core(nblk, nval, bitmap, mask_at, L, (int64_t)1 << 40,
+                        nullptr))
+    return -1;
+  return sparse_scatter_core(nblk, nval, bitmap, mask_at, vals, L,
+                             0, 0, 0, 0, L, out);
 }
+
+// The compact payload's three sections (codecs/h264/layout.py).
+struct CompactView {
+  const uint8_t* bitmap;
+  const uint8_t* masks;
+  const int8_t* vals;
+  // false: the payload is shorter than its counts demand
+  bool parse(int32_t nblk, int32_t nval, const uint8_t* payload,
+             int64_t payload_len, int64_t L) {
+    const int64_t NB = (L + 15) / 16;
+    const int64_t nb8 = (NB + 7) / 8;
+    if (payload_len < nb8 + 2 * (int64_t)nblk + nval) return false;
+    bitmap = payload;
+    masks = payload + nb8;
+    vals = (const int8_t*)(payload + nb8 + 2 * (int64_t)nblk);
+    return true;
+  }
+  uint32_t operator()(int32_t i) const {
+    return (uint32_t)masks[2 * i] | ((uint32_t)masks[2 * i + 1] << 8);
+  }
+};
 
 static int32_t g_zz[16];      // zigzag position -> raster index in a 4x4
 static bool g_scan_ready = false;
@@ -460,26 +565,50 @@ int64_t cavlc_sparse_unpack2(
 // Host inverse of jaxcore._compact_stream: ONE contiguous payload
 // (bitmap | bmask16 little-endian byte pairs | int8 vals — see
 // codecs/h264/layout.py for the format) -> flat int16 levels, no
-// intermediate stream views or copies. The lane masks are read as byte
-// pairs because the vals section's start (nb8 + 2*nblk) gives the
-// payload no alignment guarantee. Returns 0, -1 on count/stream
-// disagreement, -2 when the payload is shorter than the counts demand.
+// intermediate stream views or copies. `out`: L zeroed int16. Returns
+// 0, -1 on count/stream disagreement, -2 when the payload is shorter
+// than the counts demand.
 int64_t cavlc_unpack_compact(
     int32_t nblk, int32_t nval,
     const uint8_t* payload, int64_t payload_len,
     int16_t* out, int64_t L) {
-  const int64_t NB = (L + 15) / 16;
-  const int64_t nb8 = (NB + 7) / 8;
-  if (payload_len < nb8 + 2 * (int64_t)nblk + nval) return -2;
-  const uint8_t* mb = payload + nb8;
-  const int8_t* vals =
-      (const int8_t*)(payload + nb8 + 2 * (int64_t)nblk);
-  return sparse_unpack2_core(
-      nblk, nval, payload,
-      [mb](int32_t i) {
-        return (uint32_t)mb[2 * i] | ((uint32_t)mb[2 * i + 1] << 8);
-      },
-      vals, out, L);
+  CompactView v;
+  if (!v.parse(nblk, nval, payload, payload_len, L)) return -2;
+  return sparse_unpack2_core(nblk, nval, v.bitmap, v, v.vals, out, L);
+}
+
+// The index pass over a compact payload: every check of
+// cavlc_unpack_compact (same return codes), no level written, and
+// `index` filled as sparse_index_core files it (ceil(NB / stride)
+// int64 pairs; stride a multiple of 64).
+int64_t cavlc_compact_index(
+    int32_t nblk, int32_t nval,
+    const uint8_t* payload, int64_t payload_len, int64_t L,
+    int64_t stride, int64_t* index) {
+  CompactView v;
+  if (!v.parse(nblk, nval, payload, payload_len, L)) return -2;
+  return sparse_index_core(nblk, nval, v.bitmap, v, L, stride, index);
+}
+
+// Levels [l0, l1) of an indexed compact payload -> `out` (l1 - l0
+// int16, zeroed here: the caller's scratch is dirty with the last
+// slice's levels), starting from the index entry at or before l0 / 16.
+// Same return codes; -1 also for a range or an index entry that
+// cannot be.
+int64_t cavlc_unpack_compact_range(
+    int32_t nblk, int32_t nval,
+    const uint8_t* payload, int64_t payload_len, int64_t L,
+    int64_t stride, const int64_t* index,
+    int64_t l0, int64_t l1, int16_t* out) {
+  CompactView v;
+  if (!v.parse(nblk, nval, payload, payload_len, L)) return -2;
+  if (l0 < 0 || l1 < l0 || l1 > L || stride < 64 || stride % 64) return -1;
+  if (l0 == l1) return 0;
+  std::memset(out, 0, (size_t)(l1 - l0) * sizeof(int16_t));
+  const int64_t j = (l0 / 16) / stride;
+  return sparse_scatter_core(nblk, nval, v.bitmap, v, v.vals, L,
+                             j * stride, index[2 * j], index[2 * j + 1],
+                             l0, l1, out);
 }
 
 // ---- P-slice support -------------------------------------------------------

@@ -364,6 +364,11 @@ STAGE_COUNTER_TOTALS = {
     "p_mbs_intra": REGISTRY.counter(
         "tvt_p_mbs_intra_total",
         "of those, macroblocks coded Intra16x16"),
+    "unpack_ranges": REGISTRY.counter(
+        "tvt_unpack_ranges_total",
+        "runs of a compact payload's level vector unpacked inside the "
+        "slice thunks that pack them (GOP waves inside the sparse "
+        "budgets, with the native library)"),
 }
 STAGE_GAUGES = {
     "me_candidates": REGISTRY.gauge(

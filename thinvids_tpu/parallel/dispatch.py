@@ -86,9 +86,9 @@ from ..obs import metrics as obs_metrics, trace as obs_trace
 from ..core.types import (BandPlan, ChromaFormat, EncodedSegment, Frame,
                           GopSpec, SegmentPlan, VideoMeta)
 from ..codecs.h264 import jaxcore
-from ..codecs.h264.encoder import (FrameLevels, _mode_policy,
-                                   gop_slice_thunks_planes, pack_slice,
-                                   unpack_mode16)
+from ..codecs.h264.encoder import (FrameLevels, _mode_policy, pack_slice,
+                                   gop_slice_thunks_frames, unpack_mode16,
+                                   gop_slice_thunks_planes)
 from ..codecs.h264.headers import PPS, SPS
 from ..codecs.h264.rdo import RD_OFF, RdConfig, rd_from_settings
 from ..codecs.h264.stages import stage
@@ -137,36 +137,36 @@ STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
                "sfe", "halo", "scenecut")
 
 #: monotonic counters riding in the same snapshot as the stage clocks:
-#: dense_fallback_waves (waves that overflowed the sparse budgets and
-#: shipped their levels dense), h2d_bytes (host→device bytes uploaded
-#: while staging waves: once per wave whatever the ladder's rung
-#: count), stage_copy_bytes (host bytes the staging thread copies
-#: between the decoder's planes and the arrays a GOP wave uploads: the
-#: planes' share of h2d_bytes, each byte written once), d2h_bytes
-#: (device→host bytes fetched), fetch_shards (per-shard concurrent
-#: fetch transfers issued; 0 = every fetch was one blocking
-#: device_get), sfe_frames (frames through the split-frame per-frame
-#: collect), sparse_{blocks,values}_{used,budget} (blocks with a level
-#: and non-zero values counted on the device, against what the sparse
-#: transfer buffers hold, summed over every GOP or split-frame band
-#: collected; used / budget over 1 means the wave went dense, and the
-#: value count is then a lower bound: the device counts values in the
-#: blocks it kept),
-#: scene_cuts / scene_cuts_suppressed (cuts that began a GOP / came too
-#: soon after one), wave_frames / pad_frames / pad_frames_skipped (GOP
-#: waves' frames staged / repeats among them / repeats never encoded),
-#: mvs_coded / mvs_quarter (P macroblocks' vectors handed to the
-#: packers / those of them with an odd quarter-sample component: 0
-#: under subpel="half"; count_vectors),
-#: p_mbs_coded / p_mbs_intra (macroblocks of P pictures handed to the
-#: packers under p_intra / those of them intra; count_kinds)
-STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes",
-                  "stage_copy_bytes", "d2h_bytes", "fetch_shards",
-                  "sfe_frames", "sparse_blocks_used", "sparse_blocks_budget",
-                  "sparse_values_used", "sparse_values_budget",
-                  "scene_cuts", "scene_cuts_suppressed", "wave_frames",
-                  "pad_frames", "pad_frames_skipped", "mvs_coded",
-                  "mvs_quarter", "p_mbs_coded", "p_mbs_intra")
+#: dense_fallback_waves (waves that overflowed the sparse budgets and shipped
+#: their levels dense), h2d_bytes (host→device bytes uploaded while staging
+#: waves: once per wave whatever the ladder's rung count), stage_copy_bytes
+#: (host bytes the staging thread copies between the decoder's planes and the
+#: arrays a GOP wave uploads: the planes' share of h2d_bytes, each byte written
+#: once), d2h_bytes (device→host bytes fetched), fetch_shards (per-shard
+#: concurrent fetch transfers issued; 0 = every fetch was one blocking
+#: device_get), sfe_frames (frames through the split-frame per-frame collect),
+#: sparse_{blocks,values}_{used,budget} (blocks with a level and non-zero
+#: values counted on the device, against what the sparse transfer buffers hold,
+#: summed over every GOP or split-frame band collected; used / budget over 1
+#: means the wave went dense, and the value count is then a lower bound: the
+#: device counts values in the blocks it kept), scene_cuts /
+#: scene_cuts_suppressed (cuts that began a GOP / came too soon after one),
+#: wave_frames / pad_frames / pad_frames_skipped (GOP waves' frames staged /
+#: repeats among them / repeats never encoded), mvs_coded / mvs_quarter (P
+#: macroblocks' vectors handed to the packers / those of them with an odd
+#: quarter-sample component: 0 under subpel="half"; count_vectors), p_mbs_coded
+#: / p_mbs_intra (macroblocks of P pictures handed to the packers under p_intra
+#: / those of them intra; count_kinds), unpack_ranges (runs of a compact
+#: payload's level vector unpacked inside slice thunks: 2 + (frames packed - 1)
+#: x (5, or 6 under p_intra) a GOP; 0 on a dense wave, a split-frame job or a
+#: host without the native library)
+STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "stage_copy_bytes",
+                  "d2h_bytes", "fetch_shards", "sfe_frames",
+                  "sparse_blocks_used", "sparse_blocks_budget",
+                  "sparse_values_used", "sparse_values_budget", "scene_cuts",
+                  "scene_cuts_suppressed", "wave_frames", "pad_frames",
+                  "pad_frames_skipped", "mvs_coded", "mvs_quarter",
+                  "p_mbs_coded", "p_mbs_intra", "unpack_ranges")
 #: last-value readings riding in the same snapshot: me_candidates (what
 #: the motion search of the last GOP / step program called scores per
 #: macroblock: `program_build`; 0 until one ran)
@@ -1236,36 +1236,36 @@ class GopShardEncoder:
                                 start_frame=(g.start_frame
                                              + self.frame_offset))
                     for g in wave]
-        # Phase 1: unpack levels and SUBMIT every GOP's pack work — the
-        # slice pool packs the whole wave's slices concurrently; phase
-        # 2 gathers in GOP order.
+        # Phase 1: SUBMIT every GOP's pack work — the slice pool packs
+        # the whole wave's slices concurrently; phase 2 gathers in GOP
+        # order. A compact payload is not unpacked here: this thread
+        # validates and indexes it (`sparse_unpack`) and the thunk that
+        # packs a slice unpacks its runs of the level vector
+        # (_compact_gop_thunks): a GOP's first slice is with the pool a
+        # millisecond after its payload. The dense fallback takes views.
         pool = self._slice_pool()
         jobs: list[tuple] = []
         for gi, gop in enumerate(wave):
             count_vectors(prof, mv8[gi][:gop.num_frames - 1], self.rd)
+            # gop.num_frames (not F) drops the wave's tail-repeat
+            # padding.
+            slices = (gop.num_frames, mbw, mbh, self.sps, self.pps,
+                      int(qps_host[gi]))
             if fetch.sparse_ok:
-                with prof.stage("sparse_unpack"):
-                    rest = self._unpack_compact(
-                        payload_rows[gi], int(nblk[gi]), int(nval[gi]),
-                        int(used[gi]), Lr)
-                with prof.stage("unflatten"):
-                    intra, planes = unflatten_gop_parts(
-                        dc16[gi], rest, mv8[gi], F, mbw, mbh,
-                        ships_modes=ships_modes,
-                        p_intra=self.rd.p_intra)
+                thunks = _compact_gop_thunks(
+                    prof, payload_rows[gi][:int(used[gi])], int(nblk[gi]),
+                    int(nval[gi]), Lr, dc16[gi], mv8[gi], F, slices,
+                    gop.index, self.rd)
             else:
                 with prof.stage("unflatten"):
                     intra, planes = unflatten_gop(
                         flat[gi], mv8[gi], F, mbw, mbh,
                         ships_modes=ships_modes, p_intra=self.rd.p_intra)
-            # gop.num_frames (not F) drops the wave's tail-repeat
-            # padding.
-            if self.rd.p_intra:
-                count_kinds(prof, planes[6][:gop.num_frames - 1])
-            thunks = gop_slice_thunks_planes(
-                intra, planes, gop.num_frames, mbw, mbh, self.sps,
-                self.pps, int(qps_host[gi]), idr_pic_id=gop.index,
-                rd=self.rd)
+                if self.rd.p_intra:
+                    count_kinds(prof, planes[6][:gop.num_frames - 1])
+                thunks = gop_slice_thunks_planes(
+                    intra, planes, *slices, idr_pic_id=gop.index,
+                    rd=self.rd)
             if pool is None:
                 jobs.append(
                     (gop, lambda ts=thunks: [t() for t in ts]))
@@ -2293,3 +2293,104 @@ def program_build(form: str, rd, shape, *more):
               "in %.2f s (trace, lower, compile or cache load, enqueue)",
               form, rd, tuple(shape), more, candidates,
               time.perf_counter() - t0)
+
+
+#: each packing thread's level scratch (_level_scratch)
+_SCRATCH = threading.local()
+
+
+def _level_scratch(levels: int) -> np.ndarray:
+    """`levels` int16 of the calling thread's own memory, made at its
+    first slice and kept as long as the thread lives (a pack pool
+    thread: as long as its encoder), so its pages are faulted in once
+    and not once a GOP. Whatever the thread's last slice left is still
+    in it."""
+    room = getattr(_SCRATCH, "room", None)
+    if room is None or room.shape[0] < levels:
+        room = _SCRATCH.room = np.empty(levels, np.int16)
+    return room[:levels]
+
+
+def _compact_gop_thunks(stages: StageProfile, payload: np.ndarray,
+                        nblk: int, nval: int, Lr: int, dense: np.ndarray,
+                        mv8: np.ndarray, F: int, slices: tuple,
+                        idr_pic_id: int, rd) -> list:
+    """Slice thunks of one GOP whose levels crossed as a compact
+    payload (`slices`: gop_slice_thunks_*'s positional arguments from
+    the frame count on). With the native library the payload is
+    indexed here and unpacked in ranges by the thunks
+    (:class:`_CompactGop`); without it numpy unpacks the whole vector
+    here — it has no cheap range — and the thunks take views, as the
+    dense fallback's do. The clock `sparse_unpack` is what the calling
+    thread spends on the payload before it can submit a thunk, either
+    way."""
+    from .. import native
+    from ..codecs.h264.layout import unpack_compact_host
+
+    num_frames, mbw, mbh = slices[:3]
+    if native.available():
+        with stages.stage("sparse_unpack"):
+            levels = _CompactGop(stages, payload, nblk, nval, Lr, dense,
+                                 mv8, F, mbw, mbh, rd)
+        return gop_slice_thunks_frames(levels.intra, levels.p_frame, *slices,
+                                       idr_pic_id=idr_pic_id, rd=rd)
+    with stages.stage("sparse_unpack"):
+        rest = unpack_compact_host(payload, nblk, nval, Lr)
+    with stages.stage("unflatten"):
+        intra, planes = unflatten_gop_parts(
+            dense, rest, mv8, F, mbw, mbh, ships_modes=rd.ships_modes,
+            p_intra=rd.p_intra)
+    if rd.p_intra:
+        count_kinds(stages, planes[6][:num_frames - 1])
+    return gop_slice_thunks_planes(intra, planes, *slices,
+                                   idr_pic_id=idr_pic_id, rd=rd)
+
+
+class _CompactGop:
+    """One GOP of a sparse wave as its slice thunks read it: the
+    compact payload, validated and indexed (the constructor: the one
+    pass the collecting thread makes), and the dense DC prefix. A
+    thunk unpacks the runs of the level vector its slice is made of
+    (layout.rest_spans) into :func:`_level_scratch`'s memory — frame-
+    sized, kept by the thread that runs it, dirty with its last slice
+    — and packs the views; no array of the GOP's length exists. The
+    views are the ones unflatten_gop_parts gives on the whole vector,
+    value for value."""
+
+    def __init__(self, stages: StageProfile, payload: np.ndarray,
+                 nblk: int, nval: int, Lr: int, dense: np.ndarray,
+                 mv8: np.ndarray, F: int, mbw: int, mbh: int, rd) -> None:
+        from .. import native
+        from ..codecs.h264.layout import rest_spans, split_dense_dc
+
+        self._stages = stages
+        self._stream = (nblk, nval, payload, Lr)
+        self._index = native.index_compact(*self._stream)
+        self._unpack_range = native.unpack_compact_range
+        self._dc = split_dense_dc(dense, mbw * mbh, rd.ships_modes)
+        self._mv8, self._p_intra = mv8, rd.p_intra
+        self._intra, self._frames = rest_spans(F, mbw, mbh, rd.p_intra)
+        self._frame_levels = mbw * mbh * p_flat_mb(rd.p_intra)
+
+    def _unpack(self, spans) -> list[np.ndarray]:
+        """The spans' levels, side by side in this thread's scratch."""
+        room = _level_scratch(self._frame_levels)
+        views, o = [], 0
+        for l0, n, shape in spans:
+            self._unpack_range(*self._stream, self._index, l0, l0 + n,
+                               room[o:o + n])
+            views.append(room[o:o + n].reshape(shape))
+            o += n
+        self._stages.bump("unpack_ranges", len(spans))
+        return views
+
+    def intra(self) -> tuple:
+        il_dc, ic_dc, modes = self._dc
+        il_ac, ic_ac = self._unpack(self._intra)
+        return (il_dc, il_ac, ic_dc, ic_ac) + modes
+
+    def p_frame(self, i: int) -> tuple:
+        views = self._unpack(self._frames[i])
+        if self._p_intra:
+            count_kinds(self._stages, views[5])
+        return (self._mv8[i], *views)

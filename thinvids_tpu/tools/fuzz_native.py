@@ -1,8 +1,9 @@
 """Corruption/truncation fuzz harness for the native entropy code.
 
 The native CAVLC parsers (`cavlc_unpack_compact`,
-`cavlc_sparse_unpack2`) consume bytes that crossed the device→host
-link, and `cavlc_pack_islice16` consumes the level arrays they
+`cavlc_sparse_unpack2`, and the pair GOP waves use: the index pass
+`cavlc_compact_index` + the ranged `cavlc_unpack_compact_range`)
+consume bytes that crossed the device→host link, and `cavlc_pack_islice16` consumes the level arrays they
 produce; none of them may ever read or write out of bounds, whatever a
 torn transfer hands them. This harness drives all three with valid
 payloads, then systematic mutations (byte flips, truncations, garbage
@@ -101,6 +102,33 @@ def run_both_compact(native_mod, layout, L, nblk, nval, payload):
     except _REJECT:
         got_h = ("reject", None)
     return got_n, got_h
+
+
+def run_both_ranged(native_mod, layout, rng, L, nblk, nval, payload):
+    """The index pass + the ranged unpack over a random partition of
+    [0, L) (cuts mostly inside blocks), each range into a dirty
+    destination: the levels put back together, or the rejection."""
+    cuts = sorted({0, L, *rng.integers(
+        0, L + 1, size=int(rng.integers(0, 9))).tolist()})
+    ranges = list(zip(cuts[:-1], cuts[1:]))
+
+    def both(index_of, unpack_range):
+        try:
+            index = index_of()
+            out = np.full(L, 0x6B6B, np.int16)
+            for l0, l1 in ranges:
+                unpack_range(index, l0, l1, out[l0:l1])
+            return ("ok", out)
+        except _REJECT:
+            return ("reject", None)
+
+    return (
+        both(lambda: native_mod.index_compact(nblk, nval, payload, L),
+             lambda *a: native_mod.unpack_compact_range(
+                 nblk, nval, payload, L, *a)),
+        both(lambda: layout.index_compact_host(payload, nblk, nval, L),
+             lambda *a: layout.unpack_compact_range_host(
+                 payload, nblk, nval, L, *a)))
 
 
 def run_both_sparse2(native_mod, layout, L, nblk, nval, bitmap, masks,
@@ -306,6 +334,13 @@ def main(argv: list[str] | None = None) -> int:
         assert got_n[0] == got_h[0] == "ok", "valid payload rejected"
         assert np.array_equal(got_n[1], got_h[1]), \
             "native/host divergence on a VALID payload"
+        whole = got_h[1]
+        got_n, got_h = run_both_ranged(native_mod, layout, rng, L, nblk,
+                                       nval, payload)
+        assert got_n[0] == got_h[0] == "ok", "valid payload rejected"
+        assert np.array_equal(got_n[1], whole) \
+            and np.array_equal(got_h[1], whole), \
+            "ranged unpack diverges from the whole vector's"
         got_n, got_h = run_both_sparse2(native_mod, layout, L, nblk,
                                         nval, bitmap, masks, vals)
         assert got_n[0] == got_h[0] == "ok"
@@ -321,6 +356,17 @@ def main(argv: list[str] | None = None) -> int:
                                    f"nval={mval}")
             accepted += a
             rejected += r
+            # the index pass accepts and rejects what the whole-vector
+            # parser does, and its ranges hold the same levels
+            ranged = run_both_ranged(native_mod, layout, rng, mL, mblk,
+                                     mval, mpayload)
+            _check_pair(*ranged, ctx=f"ranged L={mL} nblk={mblk} "
+                                     f"nval={mval}")
+            assert ranged[0][0] == pair[0][0] and (
+                pair[0][0] == "reject"
+                or np.array_equal(ranged[0][1], pair[0][1])), (
+                f"ranged/whole divergence on L={mL} nblk={mblk} "
+                f"nval={mval}")
         for mcase in sparse2_mutations(rng, L, nblk, nval, bitmap,
                                        masks, vals):
             cases += 1
