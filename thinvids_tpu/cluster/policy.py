@@ -75,6 +75,26 @@ def evaluate_job_policy(meta: VideoMeta, settings: Settings,
                    "pictures; submit the job without sfe_bands (GOP "
                    "shape) or to a daemon without TVT_P_INTRA")
 
+    # And Intra4x4 macroblocks in IDR pictures (`intra4x4`), the same
+    # two ways: the band steps code an IDR band Intra16x16 alone.
+    intra4x4 = as_bool(settings.get("intra4x4", False), False)
+    if as_bool(job_settings.get("intra4x4", intra4x4), False) != intra4x4:
+        return PolicyDecision(
+            accepted=False,
+            reason=f"intra4x4={job_settings.get('intra4x4')!r} asked per "
+                   f"job, but this daemon encodes at intra4x4={intra4x4} "
+                   f"(TVT_INTRA4X4 / POST /settings): Intra4x4 "
+                   f"macroblocks in IDR pictures are a daemon-wide "
+                   f"setting")
+    if intra4x4 and int(job_settings.get(
+            "sfe_bands", settings.get("sfe_bands", 0)) or 0) > 0:
+        return PolicyDecision(
+            accepted=False,
+            reason="sfe_bands > 0 under intra4x4: split-frame band "
+                   "slices code their IDR bands Intra16x16 alone; "
+                   "submit the job without sfe_bands (GOP shape) or to "
+                   "a daemon without TVT_INTRA4X4")
+
     if settings.reject_av1 and codec == "av1":
         return PolicyDecision(accepted=False, reason="av1 input rejected")
 

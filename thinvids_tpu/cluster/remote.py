@@ -191,6 +191,8 @@ class Shard:
     #: the plan was signed with `p_intra` on: intra macroblocks in P
     #: pictures, likewise whatever the worker's own daemon says
     p_intra: bool = False
+    #: ... or with `intra4x4` on: Intra4x4 macroblocks in IDR pictures
+    intra4x4: bool = False
     #: hosts that rejected this shard's shape (old workers): the claim
     #: never offers it to them again, so an unsupported rejection
     #: cannot ping-pong
@@ -298,11 +300,14 @@ class Shard:
         # never left out before ("gop/half/p_intra"): a worker from
         # before THAT setting reads the rest as a precision it does
         # not know.
+        # `intra4x4` likewise, a part of its own after those.
         tag = self.shape
-        if self.subpel != "half" or self.p_intra:
+        if self.subpel != "half" or self.p_intra or self.intra4x4:
             tag += f"/{self.subpel}"
         if self.p_intra:
             tag += "/p_intra"
+        if self.intra4x4:
+            tag += "/intra4x4"
         if tag != "gop":
             desc["shape"] = tag
         if self.shape == "band":
@@ -1489,6 +1494,9 @@ class RemoteExecutor(LocalExecutor):
         # and intra macroblocks in P pictures, likewise
         if as_bool(settings.get("p_intra", False), False):
             fields.append("p_intra")
+        # and Intra4x4 macroblocks in IDR pictures
+        if as_bool(settings.get("intra4x4", False), False):
+            fields.append("intra4x4")
         return hashlib.sha256("|".join(fields).encode()).hexdigest()[:16]
 
     @staticmethod
@@ -1618,6 +1626,7 @@ class RemoteExecutor(LocalExecutor):
             # the signature holds it, so a resumed plan's is the same
             shard.subpel = subpel_of(settings)
             shard.p_intra = as_bool(settings.get("p_intra", False), False)
+            shard.intra4x4 = as_bool(settings.get("intra4x4", False), False)
         refs = parts.begin_job(job.id, rec)
         reused = 0
         if resume and shards and shards[0].shape == "band":
@@ -2086,8 +2095,8 @@ def wire_shape(desc: Mapping[str, Any]) -> tuple[str, str]:
 
 def _shard_rd(desc: Mapping[str, Any]):
     """The RdConfig a claimed shard is encoded with: this worker's own
-    settings, at the vector precision and with the `p_intra` the
-    coordinator signed the plan with."""
+    settings, at the vector precision and with the `p_intra` and
+    `intra4x4` the coordinator signed the plan with."""
     from ..codecs.h264.rdo import rd_from_settings
     from ..core.config import SUBPELS, get_settings
 
@@ -2095,11 +2104,12 @@ def _shard_rd(desc: Mapping[str, Any]):
     if subpel not in SUBPELS:
         raise UnsupportedShardShape(
             f"vector precision {subpel!r} not implemented by this worker")
-    if set(flags) - {"p_intra"}:
+    if set(flags) - {"p_intra", "intra4x4"}:
         raise UnsupportedShardShape(
             f"shard tag parts {flags!r} not implemented by this worker")
     return dataclasses.replace(rd_from_settings(get_settings()),
-                               subpel=subpel, p_intra="p_intra" in flags)
+                               subpel=subpel, p_intra="p_intra" in flags,
+                               intra4x4="intra4x4" in flags)
 
 
 def _encode_band_shard(desc: Mapping[str, Any], frames, mesh=None,

@@ -1,7 +1,8 @@
 """H.264 baseline decoder (subset matching the encoder's profile).
 
 Independent implementation of the decode direction — parses Annex-B
-streams (SPS/PPS, IDR + non-IDR slices, CAVLC, I16x16 and P_L0_16x16,
+streams (SPS/PPS, IDR + non-IDR slices, CAVLC, I16x16, I_NxN (Intra4x4)
+in I slices and P_L0_16x16,
 Intra16x16 macroblocks inside P slices, multi-slice pictures) and
 reconstructs frames. Used by tests as the
 in-repo conformance check of encoder output (alongside the libavcodec
@@ -43,11 +44,17 @@ from .headers import (
 from .inter import _CODE_TO_CBP_INTER, _median3
 from .intra import (
     CHROMA_BLOCK_ORDER,
+    I4_DC,
+    I4_NO_TOP_RIGHT,
     LUMA_BLOCK_ORDER,
+    i4_neighbours,
+    i4_pred_mode,
     predict_chroma8,
+    predict_luma4,
     predict_luma16,
     reconstruct_chroma8,
     reconstruct_luma16,
+    reconstruct_luma4,
 )
 from .transform import chroma_qp, dequant_4x4, inverse_4x4, inverse_zigzag
 
@@ -68,6 +75,11 @@ class DecodedStream:
     #: per P picture the (mbh, mbw) bool map of its intra macroblocks
     #: (their `mvs` entries read 0); None for an intra picture
     intra_mbs: list = dataclasses.field(default_factory=list)
+    #: per picture the (mbh, mbw) bool map of its Intra4x4 macroblocks
+    #: and the (4 mbh, 4 mbw) Intra4x4PredMode of every block (DC
+    #: outside them)
+    i4_mbs: list = dataclasses.field(default_factory=list)
+    i4_modes: list = dataclasses.field(default_factory=list)
 
 
 class _Picture:
@@ -89,6 +101,13 @@ class _Picture:
         # refIdx -1 to a neighbour's vector prediction, bS 3 / 4 to
         # the filter
         self.intra_mb = np.zeros((mbh, mbw), bool)
+        # Intra4x4PredMode of every 4x4 block (§8.3.1.1 predicts a
+        # block's from its neighbours'); DC wherever the macroblock is
+        # not Intra4x4
+        self.i4_mode = np.full((4 * mbh, 4 * mbw), I4_DC, np.int32)
+        # per picture, for callers: the (mbh, mbw) map of Intra4x4
+        # macroblocks
+        self.i4_mb = np.zeros((mbh, mbw), bool)
         self.decoded = 0                                # MBs decoded so far
         # in-loop deblocking state: the effective QP_Y of every MB (the
         # running slice QP after mb_qp_delta; uncoded MBs keep the
@@ -261,6 +280,49 @@ def _mvp_and_skip(pic: _Picture, my: int, mx: int, slice_first: int):
     return np.asarray(p, np.int32), np.asarray(skip, np.int32)
 
 
+def _decode_chroma_residual(br: BitReader, pic: _Picture, my: int, mx: int,
+                            cbp_chroma: int, a_ok: bool, b_ok: bool):
+    """(chroma_dc (2, 4), chroma_ac (2, 4, 15)) of one macroblock."""
+    chroma_counts = pic.chroma_counts
+    chroma_dc = np.zeros((2, 4), np.int32)
+    if cbp_chroma > 0:
+        for ci in range(2):
+            chroma_dc[ci] = cavlc.decode_residual(br, -1, 4)
+    chroma_ac = np.zeros((2, 4, 15), np.int32)
+    cy0, cx0 = 2 * my, 2 * mx
+    for ci in range(2):
+        for bi, (bx, by) in enumerate(CHROMA_BLOCK_ORDER):
+            gy, gx = cy0 + by, cx0 + bx
+            if cbp_chroma == 2:
+                na = (int(chroma_counts[ci, gy, gx - 1])
+                      if gx > cx0 or a_ok else None) if gx > 0 else None
+                nb = (int(chroma_counts[ci, gy - 1, gx])
+                      if gy > cy0 or b_ok else None) if gy > 0 else None
+                coeffs = cavlc.decode_residual(
+                    br, cavlc.luma_nc(na, nb), 15)
+                chroma_ac[ci, bi] = coeffs
+                chroma_counts[ci, gy, gx] = sum(1 for c in coeffs if c)
+            else:
+                chroma_counts[ci, gy, gx] = 0
+    return chroma_dc, chroma_ac
+
+
+def _recon_intra_chroma(pic: _Picture, my: int, mx: int, chroma_mode: int,
+                        chroma_dc, chroma_ac, qp: int, a_ok: bool,
+                        b_ok: bool, d_ok: bool) -> None:
+    """Both chroma planes of an intra macroblock of either kind:
+    §8.3.4's prediction from the picture's unfiltered samples, plus
+    the residual at the macroblock's chroma QP."""
+    qpc = chroma_qp(qp)
+    for ci, plane in enumerate((pic.u, pic.v)):
+        ctop = plane[8 * my - 1, 8 * mx:8 * mx + 8] if b_ok else None
+        cleft = plane[8 * my:8 * my + 8, 8 * mx - 1] if a_ok else None
+        ctl = int(plane[8 * my - 1, 8 * mx - 1]) if d_ok else None
+        cpred = predict_chroma8(chroma_mode, ctop, cleft, ctl)
+        plane[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = reconstruct_chroma8(
+            cpred, chroma_dc[ci], chroma_ac[ci], qpc)
+
+
 def _decode_intra16_mb(br: BitReader, pic: _Picture, mi: int, first: int,
                        i_type: int, qp: int) -> int:
     """One Intra16x16 macroblock after its mb_type (`i_type`: Table
@@ -272,15 +334,14 @@ def _decode_intra16_mb(br: BitReader, pic: _Picture, mi: int, first: int,
     after the macroblock's delta."""
     mbw = pic.mbw
     my, mx = divmod(mi, mbw)
-    y, u, v = pic.y, pic.u, pic.v
-    luma_counts, chroma_counts = pic.luma_counts, pic.chroma_counts
+    y = pic.y
+    luma_counts = pic.luma_counts
     luma_mode = (i_type - 1) % 4
     cbp_chroma = ((i_type - 1) // 4) % 3
     cbp_luma = 15 if (i_type - 1) >= 12 else 0
     chroma_mode = br.ue()
     qp += br.se()                       # mb_qp_delta
     pic.qp_mb[my, mx] = qp
-    qpc = chroma_qp(qp)
 
     # in-slice neighbor availability (§7.4.3): an MB in another
     # slice is unavailable to prediction AND to nC derivation
@@ -308,26 +369,8 @@ def _decode_intra16_mb(br: BitReader, pic: _Picture, mi: int, first: int,
         else:
             luma_counts[gy, gx] = 0
 
-    chroma_dc = np.zeros((2, 4), np.int32)
-    if cbp_chroma > 0:
-        for ci in range(2):
-            chroma_dc[ci] = cavlc.decode_residual(br, -1, 4)
-    chroma_ac = np.zeros((2, 4, 15), np.int32)
-    cy0, cx0 = 2 * my, 2 * mx
-    for ci in range(2):
-        for bi, (bx, by) in enumerate(CHROMA_BLOCK_ORDER):
-            gy, gx = cy0 + by, cx0 + bx
-            if cbp_chroma == 2:
-                na = (int(chroma_counts[ci, gy, gx - 1])
-                      if gx > cx0 or a_ok else None) if gx > 0 else None
-                nb = (int(chroma_counts[ci, gy - 1, gx])
-                      if gy > cy0 or b_ok else None) if gy > 0 else None
-                coeffs = cavlc.decode_residual(
-                    br, cavlc.luma_nc(na, nb), 15)
-                chroma_ac[ci, bi] = coeffs
-                chroma_counts[ci, gy, gx] = sum(1 for c in coeffs if c)
-            else:
-                chroma_counts[ci, gy, gx] = 0
+    chroma_dc, chroma_ac = _decode_chroma_residual(
+        br, pic, my, mx, cbp_chroma, a_ok, b_ok)
 
     # Reconstruct.
     top = y[16 * my - 1, 16 * mx:16 * mx + 16] if b_ok else None
@@ -336,13 +379,92 @@ def _decode_intra16_mb(br: BitReader, pic: _Picture, mi: int, first: int,
     pred = predict_luma16(luma_mode, top, left, tl)
     y[16 * my:16 * my + 16, 16 * mx:16 * mx + 16] = reconstruct_luma16(
         pred, luma_dc, luma_ac, qp)
-    for ci, plane in enumerate((u, v)):
-        ctop = plane[8 * my - 1, 8 * mx:8 * mx + 8] if b_ok else None
-        cleft = plane[8 * my:8 * my + 8, 8 * mx - 1] if a_ok else None
-        ctl = int(plane[8 * my - 1, 8 * mx - 1]) if d_ok else None
-        cpred = predict_chroma8(chroma_mode, ctop, cleft, ctl)
-        plane[8 * my:8 * my + 8, 8 * mx:8 * mx + 8] = reconstruct_chroma8(
-            cpred, chroma_dc[ci], chroma_ac[ci], qpc)
+    _recon_intra_chroma(pic, my, mx, chroma_mode, chroma_dc, chroma_ac, qp,
+                        a_ok, b_ok, d_ok)
+    return qp
+
+
+def _decode_intra4x4_mb(br: BitReader, pic: _Picture, mi: int, first: int,
+                        qp: int) -> int:
+    """One I_NxN macroblock after its mb_type (§7.3.5, transform 4x4):
+    sixteen prev_intra4x4_pred_mode_flag / rem_intra4x4_pred_mode
+    against §8.3.1.1's predicted mode, intra_chroma_pred_mode,
+    coded_block_pattern as me(v) by Table 9-4's Intra column,
+    mb_qp_delta ONLY where the pattern is not 0, then each block, in
+    decoding order, predicted from the samples already reconstructed
+    (§8.3.1.2) and rebuilt from its sixteen levels. Returns the QP
+    after the macroblock."""
+    from .encoder import CODE_TO_CBP_INTRA
+
+    mbw = pic.mbw
+    my, mx = divmod(mi, mbw)
+    y = pic.y
+    a_ok = mx > 0 and mi - 1 >= first
+    b_ok = my > 0 and mi - mbw >= first
+    c_ok = my > 0 and mx + 1 < mbw and mi - mbw + 1 >= first
+    d_ok = my > 0 and mx > 0 and mi - mbw - 1 >= first
+    by0, bx0 = 4 * my, 4 * mx
+
+    modes = np.zeros(16, np.int32)
+    for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+        gy, gx = by0 + by, bx0 + bx
+        pm = i4_pred_mode(
+            int(pic.i4_mode[gy, gx - 1]) if bx or a_ok else None,
+            int(pic.i4_mode[gy - 1, gx]) if by or b_ok else None)
+        if br.read_bit():
+            mode = pm
+        else:
+            rem = br.read(3)
+            mode = rem + (rem >= pm)
+        modes[bi] = pic.i4_mode[gy, gx] = mode
+    chroma_mode = br.ue()
+    code = br.ue()
+    if code > 47:
+        raise ValueError(f"coded_block_pattern code {code} out of range")
+    cbp = CODE_TO_CBP_INTRA[code]
+    cbp_luma, cbp_chroma = cbp & 15, cbp >> 4
+    if cbp:
+        qp += br.se()                   # mb_qp_delta
+    pic.qp_mb[my, mx] = qp
+    pic.i4_mb[my, mx] = True
+
+    levels = np.zeros((16, 16), np.int32)
+    for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+        gy, gx = by0 + by, bx0 + bx
+        if cbp_luma & (1 << (bi // 4)):
+            na = int(pic.luma_counts[gy, gx - 1]) if bx or a_ok else None
+            nb = int(pic.luma_counts[gy - 1, gx]) if by or b_ok else None
+            coeffs = cavlc.decode_residual(br, cavlc.luma_nc(na, nb), 16)
+            levels[bi] = coeffs
+            pic.luma_counts[gy, gx] = sum(1 for c in coeffs if c)
+        else:
+            pic.luma_counts[gy, gx] = 0
+    chroma_dc, chroma_ac = _decode_chroma_residual(
+        br, pic, my, mx, cbp_chroma, a_ok, b_ok)
+
+    for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+        gy, gx = by0 + by, bx0 + bx
+        has_top, has_left = bool(by or b_ok), bool(bx or a_ok)
+        if by:
+            has_tr = bi not in I4_NO_TOP_RIGHT
+        else:
+            has_tr = b_ok if bx < 3 else c_ok
+        # the corner's macroblock: this one, A, B or D (a slice may
+        # start inside a row, where B is there and D is not)
+        has_corner = (bool(bx) or a_ok) if by else (b_ok if bx else d_ok)
+        top, left, corner = i4_neighbours(y, gx, gy, has_top, has_left,
+                                          has_tr, has_corner)
+        mode = int(modes[bi])
+        if (mode in (0, 3, 7) and top is None) \
+                or (mode in (1, 8) and left is None) \
+                or (mode in (4, 5, 6) and corner is None):
+            raise ValueError(
+                f"Intra4x4 mode {mode} without its neighbours at MB {mi}")
+        pred = predict_luma4(mode, top, left, corner)
+        y[4 * gy:4 * gy + 4, 4 * gx:4 * gx + 4] = reconstruct_luma4(
+            pred, levels[bi], qp)
+    _recon_intra_chroma(pic, my, mx, chroma_mode, chroma_dc, chroma_ac, qp,
+                        a_ok, b_ok, d_ok)
     return qp
 
 
@@ -354,9 +476,13 @@ def _decode_islice(br: BitReader, pic: _Picture,
     mi = header.first_mb
     while mi < nmb and br.more_rbsp_data():
         mb_type = br.ue()
-        if not 1 <= mb_type <= 24:
+        if mb_type == 0:
+            qp = _decode_intra4x4_mb(br, pic, mi, header.first_mb, qp)
+        elif not 1 <= mb_type <= 24:
             raise ValueError(f"unsupported I mb_type {mb_type}")
-        qp = _decode_intra16_mb(br, pic, mi, header.first_mb, mb_type, qp)
+        else:
+            qp = _decode_intra16_mb(br, pic, mi, header.first_mb, mb_type,
+                                    qp)
         pic.decoded += 1
         mi += 1
 
@@ -482,6 +608,8 @@ def decode_annexb(stream: bytes) -> DecodedStream:
     frames: list[Frame] = []
     mvs: list = []
     intra_mbs: list = []
+    i4_mbs: list = []
+    i4_modes: list = []
     pic: _Picture | None = None
     ref: _RefFrame | None = None
 
@@ -514,6 +642,8 @@ def decode_annexb(stream: bytes) -> DecodedStream:
             pic.v[:h // 2, :w // 2], pts=len(frames)))
         mvs.append(None if pic.intra else pic.mv)
         intra_mbs.append(None if pic.intra else pic.intra_mb)
+        i4_mbs.append(pic.i4_mb)
+        i4_modes.append(pic.i4_mode)
         ref = _RefFrame(pic)                  # next P picture's reference
         pic = None
 
@@ -556,4 +686,5 @@ def decode_annexb(stream: bytes) -> DecodedStream:
                      num_frames=len(frames), chroma=ChromaFormat.YUV420,
                      codec="h264", size_bytes=len(stream))
     return DecodedStream(meta=meta, frames=frames, mvs=mvs,
-                         intra_mbs=intra_mbs)
+                         intra_mbs=intra_mbs, i4_mbs=i4_mbs,
+                         i4_modes=i4_modes)

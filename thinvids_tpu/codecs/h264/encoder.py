@@ -39,10 +39,18 @@ from .intra import (
     CHROMA_H,
     CHROMA_V,
     LUMA_BLOCK_ORDER,
+    I4_DC,
+    I4_NO_TOP_RIGHT,
     LUMA_DC,
     LUMA_H,
+    LUMA_I4X4,
     LUMA_V,
+    encode_luma4,
+    i4_modes_allowed,
+    i4_neighbours,
+    i4_pred_mode,
     predict_chroma8,
+    predict_luma4,
     predict_luma16,
     reconstruct_chroma8,
     reconstruct_luma16,
@@ -79,6 +87,39 @@ class FrameLevels:
     #: per-MB qp - slice qp (perceptual AQ; None = flat QP, the
     #: historical layout). Packers emit it as mb_qp_delta.
     qp_delta: np.ndarray | None = None
+    #: (nmb, 16) Intra4x4PredMode of each block, z-scan order, read
+    #: where luma_mode is LUMA_I4X4 (rd.intra4x4; None without). Such a
+    #: macroblock keeps block b's sixteen zig-zag levels as
+    #: luma_dc[mi, b] (the first) and luma_ac[mi, b] (the rest).
+    i4_modes: np.ndarray | None = None
+
+
+#: Table 9-4, the Intra_4x4 column (chroma_format_idc 1): codeNum ->
+#: coded_block_pattern (the Inter column is inter._CODE_TO_CBP_INTER)
+CODE_TO_CBP_INTRA = (
+    47, 31, 15, 0, 23, 27, 29, 30, 7, 11, 13, 14, 39, 43, 45, 46,
+    16, 3, 5, 10, 12, 19, 21, 26, 28, 35, 37, 42, 44, 1, 2, 4,
+    8, 17, 18, 20, 24, 6, 9, 22, 25, 32, 33, 34, 36, 40, 38, 41,
+)
+CBP_INTRA_TO_CODE = [0] * 48
+for _code, _cbp in enumerate(CODE_TO_CBP_INTRA):
+    CBP_INTRA_TO_CODE[_cbp] = _code
+
+
+def pack_i4_modes(modes: np.ndarray) -> np.ndarray:
+    """(nmb, 16) block modes → the transfer's (nmb, 4) int16 words,
+    four 4-bit modes a word, block 4k in the low nibble of word k."""
+    m = np.asarray(modes, np.int32).reshape(-1, 4, 4)
+    w = m[..., 0] | (m[..., 1] << 4) | (m[..., 2] << 8) | (m[..., 3] << 12)
+    return w.astype(np.uint16).view(np.int16)
+
+
+def unpack_i4_modes(words: np.ndarray) -> np.ndarray:
+    """:func:`pack_i4_modes`' inverse → (nmb, 16) int32."""
+    w = np.ascontiguousarray(words, np.int16).view(np.uint16) \
+        .astype(np.int32).reshape(-1, 4)
+    return np.stack([(w >> s) & 15 for s in (0, 4, 8, 12)],
+                    axis=-1).reshape(-1, 16)
 
 
 def _mode_policy(mbw: int, mbh: int) -> tuple[np.ndarray, np.ndarray]:
@@ -337,12 +378,127 @@ def encode_frame_arrays(y: np.ndarray, u: np.ndarray, v: np.ndarray,
             else:
                 py, pu, pv = preds_v[mx]
                 store_mb(mi, my, mx, LUMA_V, CHROMA_V, py, pu, pv)
+    if rd.intra4x4:
+        _intra4x4_luma_np(y, qp, qp_mb, levels, recon_y, rd)
     return levels, (recon_y, recon_u, recon_v)
 
 
+def _intra4x4_luma_np(y, qp: int, qp_mb, levels: FrameLevels, recon_y,
+                      rd) -> None:
+    """rd.intra4x4: the luma of an IDR picture coded again, macroblock
+    by macroblock in raster order, each as Intra16x16 or Intra4x4 —
+    the numpy twin of jaxcore._intra4x4_luma, which walks the same
+    macroblocks as a wavefront. Overwrites `levels`' luma arrays,
+    modes and QP deltas and `recon_y`; chroma is left as the
+    Intra16x16 path coded it.
+
+    A macroblock's Intra16x16 candidate is V, H or DC by SATD from its
+    TRUE reconstructed neighbours (rd.mode_decision; else the raster
+    policy's mode). Its Intra4x4 candidate takes each block, in
+    decoding order, through the modes its neighbours allow: cost =
+    sum |Hadamard| of source less prediction + 2 lambda * the mode's
+    bits, strict-< from mode 0 up, then codes the block (closed loop:
+    the next block predicts from this one's reconstruction). The kind
+    is Intra4x4 where its summed cost + 2 lambda * I4X4_BITS is less
+    than the Intra16x16 candidate's sum |Hadamard| (twice its SATD: no
+    sum here is halved, so none rounds). An Intra4x4 macroblock with no
+    level at all (luma and chroma) codes no mb_qp_delta, so its QP is
+    the macroblock's before it (§7.4.5): `qp_delta` says so."""
+    from . import rdo
+
+    mbh, mbw = y.shape[0] // 16, y.shape[1] // 16
+    policy_luma, _ = _mode_policy(mbw, mbh)
+    levels.i4_modes = np.full((mbh * mbw, 16), I4_DC, np.int32)
+    blk_mode = np.full((4 * mbh, 4 * mbw), I4_DC, np.int32)
+    held = np.zeros(mbh * mbw, bool)
+    for mi in range(mbh * mbw):
+        my, mx = divmod(mi, mbw)
+        q = int(qp_mb[mi])
+        lam2 = 2 * rdo.P_INTRA_LAMBDA[q]
+        ys, xs = slice(16 * my, 16 * my + 16), slice(16 * mx, 16 * mx + 16)
+        src = y[ys, xs].astype(np.int32)
+        top = recon_y[16 * my - 1, xs] if my else None
+        left = recon_y[ys, 16 * mx - 1] if mx else None
+
+        # Intra16x16 candidate
+        def cost16(m):
+            r = src - predict_luma16(m, top, left, None)
+            return sum(rdo.sath4_np(r[4 * by:4 * by + 4, 4 * bx:4 * bx + 4])
+                       for bx, by in LUMA_BLOCK_ORDER)
+
+        mode16 = int(policy_luma[mi])
+        c16 = None
+        if rd.mode_decision:
+            for m, ok in ((LUMA_V, my), (LUMA_H, mx), (LUMA_DC, True)):
+                c = cost16(m) if ok else None
+                if ok and (c16 is None or c < c16):
+                    c16, mode16 = c, m
+        else:
+            c16 = cost16(mode16)
+        pred16 = predict_luma16(mode16, top, left, None)
+
+        # Intra4x4 candidate, coded into recon_y as it goes
+        c4 = lam2 * rdo.I4X4_BITS
+        lev4 = np.zeros((16, 16), np.int32)
+        modes4 = np.zeros(16, np.int32)
+        for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+            gx, gy = 4 * mx + bx, 4 * my + by
+            has_top, has_left = gy > 0, gx > 0
+            if by:
+                has_tr = bi not in I4_NO_TOP_RIGHT
+            else:
+                has_tr = has_top and (bx < 3 or mx + 1 < mbw)
+            nb = i4_neighbours(recon_y, gx, gy, has_top, has_left, has_tr)
+            pm = i4_pred_mode(
+                int(blk_mode[gy, gx - 1]) if has_left else None,
+                int(blk_mode[gy - 1, gx]) if has_top else None)
+            bsrc = src[4 * by:4 * by + 4, 4 * bx:4 * bx + 4]
+            best = None
+            for m in i4_modes_allowed(has_top, has_left):
+                pred = predict_luma4(m, *nb)
+                c = rdo.sath4_np(bsrc - pred.astype(np.int32)) \
+                    + lam2 * rdo.I4X4_MODE_BITS[m != pm]
+                if best is None or c < best:
+                    best, bmode, bpred = c, m, pred
+            c4 += best
+            modes4[bi] = blk_mode[gy, gx] = bmode
+            lev4[bi], rec = encode_luma4(bsrc, bpred, q)
+            recon_y[4 * gy:4 * gy + 4, 4 * gx:4 * gx + 4] = rec
+
+        if c4 < c16:
+            levels.luma_mode[mi] = LUMA_I4X4
+            levels.i4_modes[mi] = modes4
+            levels.luma_dc[mi] = lev4[:, 0]
+            levels.luma_ac[mi] = lev4[:, 1:]
+            held[mi] = not (lev4.any() or levels.chroma_dc[mi].any()
+                            or levels.chroma_ac[mi].any())
+        else:
+            blk_mode[4 * my:4 * my + 4, 4 * mx:4 * mx + 4] = I4_DC
+            levels.luma_mode[mi] = mode16
+            dc, ac, rec = _encode_luma_mb_np(y[ys, xs], pred16, q)
+            levels.luma_dc[mi], levels.luma_ac[mi] = dc, ac
+            recon_y[ys, xs] = rec
+    # QP_Y of a macroblock that codes no delta is its predecessor's
+    eff = np.asarray(qp_mb, np.int32).copy()
+    prev = qp
+    for mi in range(mbh * mbw):
+        if held[mi]:
+            eff[mi] = prev
+        prev = eff[mi]
+    levels.qp_delta = (eff - qp).astype(np.int32)
+
+
 def mb_cbp(levels: FrameLevels, mi: int) -> tuple[int, int]:
-    """(cbp_luma in {0,15}, cbp_chroma in {0,1,2}) for MB `mi`."""
-    cbp_luma = 15 if np.any(levels.luma_ac[mi]) else 0
+    """(cbp_luma, cbp_chroma in {0,1,2}) for MB `mi`: cbp_luma in
+    {0, 15} for Intra16x16, one bit per 8x8 quadrant (four blocks of
+    the z-scan) for Intra4x4."""
+    if levels.luma_mode[mi] == LUMA_I4X4:
+        cbp_luma = sum(
+            1 << k for k in range(4)
+            if np.any(levels.luma_dc[mi, 4 * k:4 * k + 4])
+            or np.any(levels.luma_ac[mi, 4 * k:4 * k + 4]))
+    else:
+        cbp_luma = 15 if np.any(levels.luma_ac[mi]) else 0
     if np.any(levels.chroma_ac[mi]):
         cbp_chroma = 2
     elif np.any(levels.chroma_dc[mi]):
@@ -385,7 +541,8 @@ def pack_slice(levels: FrameLevels, mbw: int, mbh: int, sps: SPS, pps: PPS,
             ebsp = native_mod.pack_islice(
                 hdr_bytes, hdr_bits, levels.luma_mode, levels.chroma_mode,
                 levels.luma_dc, levels.luma_ac, levels.chroma_dc,
-                levels.chroma_ac, mbw, mbh, qp_delta=levels.qp_delta)
+                levels.chroma_ac, mbw, mbh, qp_delta=levels.qp_delta,
+                i4_modes=levels.i4_modes)
             start = b"\x00\x00\x00\x01"
             nal_header = bytes([(3 << 5) | (NAL_SLICE_IDR if idr else 1)])
             return start + nal_header + ebsp
@@ -401,36 +558,66 @@ def pack_slice(levels: FrameLevels, mbw: int, mbh: int, sps: SPS, pps: PPS,
     # slice qp, so the coded value is the successive difference.
     dqp = levels.qp_delta
     prev_off = 0
+    # Intra4x4PredMode of every 4x4 block for §8.3.1.1's prediction;
+    # an Intra16x16 macroblock's blocks read DC
+    blk_mode = np.full((4 * mbh, 4 * mbw), I4_DC, np.int32)
     for my in range(mbh):
         for mx in range(mbw):
             mi = my * mbw + mx
             cbp_luma, cbp_chroma = mb_cbp(levels, mi)
-            mb_type = 1 + int(levels.luma_mode[mi]) + 4 * cbp_chroma \
-                + 12 * (1 if cbp_luma else 0)
-            bw.ue(mb_type)
+            by0, bx0 = 4 * my, 4 * mx
+            i4x4 = levels.luma_mode[mi] == LUMA_I4X4
+            if i4x4:
+                bw.ue(0)                         # mb_type I_NxN
+                for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
+                    gy, gx = by0 + by, bx0 + bx
+                    mode = int(levels.i4_modes[mi, bi])
+                    pm = i4_pred_mode(
+                        int(blk_mode[gy, gx - 1]) if gx > 0 else None,
+                        int(blk_mode[gy - 1, gx]) if gy > 0 else None)
+                    blk_mode[gy, gx] = mode
+                    if mode == pm:
+                        bw.write_bit(1)   # prev_intra4x4_pred_mode_flag
+                    else:
+                        # the flag 0, then rem_intra4x4_pred_mode u(3)
+                        bw.write(mode - (mode > pm), 4)
+            else:
+                bw.ue(1 + int(levels.luma_mode[mi]) + 4 * cbp_chroma
+                      + 12 * (1 if cbp_luma else 0))
             bw.ue(int(levels.chroma_mode[mi]))   # intra_chroma_pred_mode
-            if dqp is None:
+            if i4x4:
+                bw.ue(CBP_INTRA_TO_CODE[cbp_luma | (cbp_chroma << 4)])
+            if i4x4 and not (cbp_luma or cbp_chroma):
+                # no mb_qp_delta (§7.3.5): the macroblock's QP is the
+                # one before it, which is what its levels' dqp says
+                if dqp is not None and int(dqp[mi]) != prev_off:
+                    raise ValueError(
+                        f"Intra4x4 MB {mi} codes no level but changes QP")
+            elif dqp is None:
                 bw.se(0)                         # mb_qp_delta
             else:
                 bw.se(int(dqp[mi]) - prev_off)
                 prev_off = int(dqp[mi])
 
-            # Luma DC: nC from blkIdx 0 neighbors.
-            by0, bx0 = 4 * my, 4 * mx
-            na = int(luma_counts[by0, bx0 - 1]) if bx0 > 0 else None
-            nb = int(luma_counts[by0 - 1, bx0]) if by0 > 0 else None
-            cavlc.encode_residual(bw, levels.luma_dc[mi].tolist(),
-                                  cavlc.luma_nc(na, nb))
+            if not i4x4:
+                # Luma DC: nC from blkIdx 0 neighbors.
+                na = int(luma_counts[by0, bx0 - 1]) if bx0 > 0 else None
+                nb = int(luma_counts[by0 - 1, bx0]) if by0 > 0 else None
+                cavlc.encode_residual(bw, levels.luma_dc[mi].tolist(),
+                                      cavlc.luma_nc(na, nb))
 
-            # Luma AC in z-scan block order.
+            # Luma blocks in z-scan order: Intra16x16's fifteen AC
+            # levels, Intra4x4's sixteen where its quadrant's bit is set.
             for bi, (bx, by) in enumerate(LUMA_BLOCK_ORDER):
                 gy, gx = by0 + by, bx0 + bx
-                if cbp_luma:
+                if cbp_luma & (1 << (bi // 4)):
                     na = int(luma_counts[gy, gx - 1]) if gx > 0 else None
                     nb = int(luma_counts[gy - 1, gx]) if gy > 0 else None
-                    tc = cavlc.encode_residual(
-                        bw, levels.luma_ac[mi, bi].tolist(), cavlc.luma_nc(na, nb))
-                    luma_counts[gy, gx] = tc
+                    coeffs = levels.luma_ac[mi, bi].tolist()
+                    if i4x4:
+                        coeffs = [int(levels.luma_dc[mi, bi])] + coeffs
+                    luma_counts[gy, gx] = cavlc.encode_residual(
+                        bw, coeffs, cavlc.luma_nc(na, nb))
                 else:
                     luma_counts[gy, gx] = 0
 
@@ -606,7 +793,8 @@ def _gop_slice_thunks(intra_of, pack_p, num_frames: int, mbw: int,
     may unpack them there, on the packing thread): the 4-tuple of
     blocked level arrays, or — when the encode shipped the per-MB side
     channel (rd.ships_modes) — a 6-tuple with (mode16, dqp16)
-    appended."""
+    appended, or under rd.intra4x4 a 7-tuple with the blocks' modes as
+    (nmb, 4) words (pack_i4_modes) after those."""
     from .rdo import RD_OFF
 
     if rd is None:
@@ -616,9 +804,12 @@ def _gop_slice_thunks(intra_of, pack_p, num_frames: int, mbw: int,
 
     def pack_idr():
         intra = intra_of()
-        if len(intra) == 6:
-            il_dc, il_ac, ic_dc, ic_ac, mode16, dqp16 = intra
+        i4_modes = None
+        if len(intra) >= 6:
+            il_dc, il_ac, ic_dc, ic_ac, mode16, dqp16, *i4m = intra
             luma_mode, chroma_mode = unpack_mode16(mode16)
+            if i4m:
+                i4_modes = unpack_i4_modes(i4m[0])
             qp_delta = np.asarray(dqp16, np.int32)
             if not np.any(qp_delta):
                 qp_delta = None
@@ -629,7 +820,7 @@ def _gop_slice_thunks(intra_of, pack_p, num_frames: int, mbw: int,
         intra_levels = FrameLevels(
             luma_mode=luma_mode, chroma_mode=chroma_mode,
             luma_dc=il_dc, luma_ac=il_ac, chroma_dc=ic_dc, chroma_ac=ic_ac,
-            qp_delta=qp_delta)
+            qp_delta=qp_delta, i4_modes=i4_modes)
         return head + pack_slice(intra_levels, mbw, mbh, sps, pps, qp,
                                  frame_num=0, idr=True,
                                  idr_pic_id=idr_pic_id % 65536,

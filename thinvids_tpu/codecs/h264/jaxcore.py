@@ -28,8 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import jaxme, rdo
-from .encoder import FrameLevels, _mode_policy
-from .intra import LUMA_BLOCK_ORDER
+from .encoder import FrameLevels, _mode_policy, unpack_i4_modes
+from .intra import I4_DC, I4_NO_TOP_RIGHT, LUMA_BLOCK_ORDER, LUMA_I4X4
 from .rdo import RD_OFF
 from .stages import stage
 from .transform import MF_TABLE, V_TABLE, ZIGZAG_4x4, CHROMA_QP_TABLE
@@ -308,9 +308,23 @@ def _chroma_dc_pred_row(ts4, ls4, avail_left, avail_top):
     return jnp.concatenate([top, bot], axis=1)
 
 
-@stage("intra")
 def _intra_core(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
-    """Intra compute for one (padded) frame.
+    """Intra compute for one (padded) frame: the Intra16x16 picture
+    (:func:`_intra16_core`, whose ten arrays these are) and, with
+    ``rd.intra4x4``, its luma coded again with each macroblock
+    Intra16x16 or Intra4x4 (:func:`_intra4x4_luma`): the luma levels,
+    reconstruction, modes and QP deltas are then that stage's, an
+    eleventh array holds the blocks' modes, and chroma stays as the
+    first stage coded it."""
+    out = _intra16_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
+    if rd.intra4x4:
+        out = _intra4x4_luma(y, qp, out, mbw=mbw, mbh=mbh, rd=rd)
+    return out
+
+
+@stage("intra")
+def _intra16_core(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
+    """Intra16x16 compute for one (padded) frame.
 
     Returns (luma_dc, luma_ac, chroma_dc, chroma_ac, recon_y, recon_u,
     recon_v, luma_mode, chroma_mode, qp_delta): the historical seven
@@ -553,12 +567,424 @@ def _intra_core(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
             qp_delta)
 
 
-def _mode_tail(luma_mode, chroma_mode, qp_delta):
+# ---------------------------------------------------------------------------
+# rd.intra4x4: Intra4x4 macroblocks in an IDR picture (§8.3.1)
+#
+# An Intra4x4 block is predicted from the RECONSTRUCTION of the blocks
+# to its left, above, above left and above right, so a macroblock's
+# sixteen blocks are coded one after another, and a macroblock after
+# its left, upper, upper left and upper right neighbours. The schedule
+# is a wavefront over macroblocks: front t holds the macroblocks with
+# mx + 2 my = t, one per macroblock row, and every array of a step
+# keeps the row `my` on its LAST axis (the lanes), the sample positions
+# on the axes before it. mbw + 2 (mbh - 1) steps a picture (254 at
+# 1080p), each of them a macroblock's sixteen blocks in ten unrolled
+# rounds (two blocks that need nothing of each other share a round).
+# The numpy twin (encoder._intra4x4_luma_np) walks the same macroblocks
+# in raster order.
+# ---------------------------------------------------------------------------
+
+#: z-scan index of the block at (bx, by)
+_BLK_AT = {xy: i for i, xy in enumerate(LUMA_BLOCK_ORDER)}
+
+
+def _split4(x, ax: int):
+    return [jax.lax.index_in_dim(x, i, ax, keepdims=False) for i in range(4)]
+
+
+def _fwd1(x, ax: int):
+    """The core transform's rows (_CF) along axis `ax`, as adds."""
+    x0, x1, x2, x3 = _split4(x, ax)
+    a0, a1, a2, a3 = x0 + x3, x1 + x2, x1 - x2, x0 - x3
+    return jnp.stack([a0 + a1, 2 * a3 + a2, a0 - a1, a3 - 2 * a2], axis=ax)
+
+
+def _had1(x, ax: int):
+    """The Hadamard rows (_H4) along axis `ax`."""
+    x0, x1, x2, x3 = _split4(x, ax)
+    a0, a1, a2, a3 = x0 + x3, x1 + x2, x1 - x2, x0 - x3
+    return jnp.stack([a0 + a1, a3 + a2, a0 - a1, a3 - a2], axis=ax)
+
+
+def _inv1(x, ax: int):
+    """One pass of the inverse transform (_inv4's) along axis `ax`."""
+    d0, d1, d2, d3 = _split4(x, ax)
+    e0, e1 = d0 + d2, d0 - d2
+    e2, e3 = (d1 >> 1) - d3, d1 + (d3 >> 1)
+    return jnp.stack([e0 + e3, e1 + e2, e1 - e2, e0 - e3], axis=ax)
+
+
+def _row_above(x):
+    """A front's array as the macroblock row BELOW sees it: lane my
+    holds what lane my - 1 held, zeros entering at row 0."""
+    return jnp.pad(x[..., :-1], [(0, 0)] * (x.ndim - 1) + [(1, 0)])
+
+
+def _quant_lanes(w, mf, qbits, r: int, c: int):
+    """_quant with the macroblock on the last axis: `w` has the
+    coefficient's row on axis `r` and column on axis `c`, `mf` is
+    (4, 4, n), `qbits` (n,)."""
+    shape = [1] * w.ndim
+    shape[r], shape[c], shape[-1] = 4, 4, w.shape[-1]
+    f = (1 << qbits) // 3
+    z = (jnp.abs(w) * mf.reshape(shape) + f) >> qbits
+    return jnp.where(w < 0, -z, z)
+
+
+def _i4_weights() -> np.ndarray:
+    """§8.3.1.2's nine predictions as ONE matrix: row (mode, y, x)
+    holds the weights, summing to 4, of the thirteen neighbour samples
+    and the DC value [t0..t7 | l0..l3 | m | dc] whose weighted sum,
+    plus 2, shifted right by 2, is the predicted sample — (a + 2b + c +
+    2) >> 2 as it stands, (a + b + 1) >> 1 as (2a + 2b + 2) >> 2 and a
+    plain copy (V, H, DC, horizontal-up's last samples) as (4a + 2) >>
+    2, the same values. Mode-major over Table 8-2's modes; DC's value
+    depends on what is available and is computed beside the matrix."""
+    T, L, M, DC = list(range(8)), list(range(8, 12)), 12, 13
+
+    def vec(*terms):
+        w = np.zeros(14, np.float32)
+        for k, a in terms:
+            w[k] += a
+        return w
+
+    f3 = lambda a, b, c: vec((a, 1), (b, 2), (c, 1))
+    f2 = lambda a, b: vec((a, 2), (b, 2))
+    one = lambda a: vec((a, 4))
+    # up the left column, through the corner (4), along the top row
+    e = [L[3], L[2], L[1], L[0], M, T[0], T[1], T[2], T[3]]
+    f3e = lambda k: f3(e[k - 1], e[k], e[k + 1])
+    f2e = lambda k: f2(e[k], e[k + 1])
+
+    def ddl(y, x):
+        if x == 3 and y == 3:
+            return vec((T[6], 1), (T[7], 3))
+        return f3(T[x + y], T[x + y + 1], T[x + y + 2])
+
+    def vr(y, x):
+        z = 2 * x - y
+        if z >= 0:
+            k = 4 + x - (y >> 1)
+            return f3e(k) if z % 2 else f2e(k)
+        return f3e(4) if z == -1 else f3e(5 - y)
+
+    def hd(y, x):
+        z = 2 * y - x
+        if z >= 0:
+            k = 4 - (y - (x >> 1))
+            return f3e(k) if z % 2 else f2e(k - 1)
+        return f3e(4) if z == -1 else f3e(3 + x)
+
+    def vl(y, x):
+        k = x + (y >> 1)
+        return f3(T[k], T[k + 1], T[k + 2]) if y % 2 else f2(T[k], T[k + 1])
+
+    def hu(y, x):
+        z, k = x + 2 * y, y + (x >> 1)
+        if z > 5:
+            return one(L[3])
+        if z == 5:
+            return vec((L[2], 1), (L[3], 3))
+        return f3(L[k], L[k + 1], L[k + 2]) if z % 2 else f2(L[k], L[k + 1])
+
+    modes = (lambda y, x: one(T[x]), lambda y, x: one(L[y]),
+             lambda y, x: one(DC), ddl,
+             lambda y, x: f3e(4 + x - y), vr, hd, vl, hu)
+    return np.stack([at(y, x) for at in modes
+                     for y in range(4) for x in range(4)])
+
+
+_I4_WEIGHTS = _i4_weights()                  # (9 * 16, 14)
+
+
+def _i4_predictions(t, l, m, has_top, has_left):
+    """§8.3.1.2's nine predictions of one block for every macroblock
+    of a front: `t` (8, n) the samples above and above right, `l` (4,
+    n) those to the left, `m` (n,) the corner → (9, 4, 4, n),
+    mode-major, [row, column]: one product with `_I4_WEIGHTS` — exact
+    in f32 at Precision.HIGHEST, every sum being under 2**10. Values
+    of a mode whose samples are missing are never chosen (the caller's
+    `allowed`)."""
+    st, sl = t[:4].sum(axis=0), l.sum(axis=0)
+    dc = jnp.where(has_top & has_left, (st + sl + 4) >> 3,
+                   jnp.where(has_left, (sl + 2) >> 2,
+                             jnp.where(has_top, (st + 2) >> 2, 128)))
+    nb = jnp.concatenate([t, l, m[None], dc[None]]).astype(jnp.float32)
+    preds = (jax.lax.dot(jnp.asarray(_I4_WEIGHTS), nb,
+                         precision=jax.lax.Precision.HIGHEST)
+             .astype(jnp.int32) + 2) >> 2
+    return preds.reshape(9, 4, 4, m.shape[0])
+
+
+def _i4_code_block(src, pred, mf, vq, qbits, qshift):
+    """One Intra4x4 block of every macroblock of a front, (4, 4, n):
+    the plain 4x4 transform on all sixteen coefficients → (levels (4,
+    4, n) [row, column], reconstruction (4, 4, n))."""
+    w = _fwd1(_fwd1(src - pred, 0), 1)
+    z = _quant_lanes(w, mf, qbits, 0, 1)
+    d = (z * vq) << qshift
+    r = (_inv1(_inv1(d, 1), 0) + 32) >> 6
+    return z, jnp.clip(pred + r, 0, 255)
+
+
+def _i16_code_mb(src, pred, mf, vq, qbits, qp):
+    """_luma_mb_batch with the macroblock on the last axis: src / pred
+    (16, 16, n) → (DC levels (4, 4, n) [by, bx], the blocks' levels
+    (4, 4, 4, 4, n) [by, bx, row, column] (the DC position is not
+    read), recon (16, 16, n))."""
+    n = src.shape[-1]
+    qshift = qp // 6
+    x = (src - pred).reshape(4, 4, 4, 4, n)             # [by, r, bx, c]
+    w = _fwd1(_fwd1(x, 1), 3)
+    wd = _had1(_had1(w[:, 0, :, 0], 0), 1) // 2         # [by, bx]
+    f = (1 << qbits) // 3
+    zd = (jnp.abs(wd) * mf[0, 0] + 2 * f) >> (qbits + 1)
+    zd = jnp.where(wd < 0, -zd, zd)
+    z = _quant_lanes(w, mf, qbits, 1, 3)
+    # closed-loop recon from the signaled levels (_luma_dc_dequant)
+    fd = _had1(_had1(zd, 0), 1) * (vq[0, 0] * 16)
+    shift = jnp.maximum(6 - qshift, 1)
+    dcr = jnp.where(qp >= 36, fd << jnp.maximum(qshift - 6, 0),
+                    (fd + (1 << (shift - 1))) >> shift)
+    d = (z * vq[None, :, None, :, :]) << qshift
+    d = d.at[:, 0, :, 0].set(dcr)
+    r = (_inv1(_inv1(d, 3), 1) + 32) >> 6
+    rec = jnp.clip(pred.reshape(4, 4, 4, 4, n) + r, 0, 255)
+    return zd, z.transpose(0, 2, 1, 3, 4), rec.reshape(16, 16, n)
+
+
+def _sath16(resid):
+    """Sum of |4x4 Hadamard| over (..., 16, 16, n) residuals → (..., n),
+    NOT halved (twice _satd16's scale: rdo.sath4_np summed)."""
+    lead = resid.shape[:-3]
+    k = len(lead)
+    x = resid.reshape(*lead, 4, 4, 4, 4, resid.shape[-1])
+    h = _had1(_had1(x, k + 1), k + 3)
+    return jnp.abs(h).sum(axis=(k, k + 1, k + 2, k + 3))
+
+
+@stage("intra4x4")
+def _intra4x4_luma(y, qp, core, *, mbw: int, mbh: int, rd):
+    """rd.intra4x4: an IDR picture's luma coded again, each macroblock
+    Intra16x16 or Intra4x4, whichever costs less; `core` is
+    _intra16_core's result, whose chroma (and the QP map's AQ
+    offsets) stay. Returns the ten arrays with the luma levels,
+    reconstruction, `luma_mode` (4 = Intra4x4, intra.LUMA_I4X4) and
+    `qp_delta` replaced, and an eleventh: the blocks' Intra4x4PredMode,
+    (nmb, 16) in z-scan order (DC where the macroblock is Intra16x16).
+
+    Per macroblock, from the neighbours' FINAL reconstruction (closed
+    loop, sample for sample what a decoder holds):
+    - the Intra16x16 candidate: V, H or DC by sum |Hadamard| of the
+      residual, strict-< in that order (rd.mode_decision; else the
+      raster policy's mode);
+    - the Intra4x4 candidate: each block in decoding order through the
+      modes its neighbours allow, cost = sum |Hadamard| + 2 lambda *
+      (1 bit where the mode is §8.3.1.1's predicted one, else 4),
+      strict-< from mode 0 up, then coded, so the next block predicts
+      from its reconstruction;
+    - Intra4x4 where its summed cost + 2 lambda * rdo.I4X4_BITS is
+      less than the Intra16x16 candidate's (ties stay Intra16x16).
+    A macroblock that ends Intra4x4 with no level at all codes no
+    mb_qp_delta: its QP is its predecessor's (§7.4.5), and `qp_delta`
+    (the side channel's and the filter's QP map) says so."""
+    (_, _, chroma_dc, chroma_ac, _, recon_u, recon_v, _, chroma_mode,
+     qp_delta) = core
+    n, T = mbh, mbw + 2 * (mbh - 1)
+    zero = _varying_zero(y)
+    qp = qp.astype(jnp.int32)
+    qp_mb = jnp.clip(qp + qp_delta, 0, 51).reshape(mbh, mbw)
+
+    # the picture's macroblocks by front: [t, ..., my] holds macroblock
+    # (t - 2 my, my), whatever where that column is outside the picture
+    t_idx, my_idx = np.meshgrid(np.arange(T), np.arange(n), indexing="ij")
+    mx_idx = t_idx - 2 * my_idx
+    inside = (mx_idx >= 0) & (mx_idx < mbw)
+    mx_clip = np.clip(mx_idx, 0, mbw - 1)
+
+    def by_front(a):
+        """(mbh, mbw, ...) → (T, ..., n)."""
+        return jnp.moveaxis(a[my_idx, mx_clip], 1, -1)
+
+    src = by_front(y.astype(jnp.int32).reshape(mbh, 16, mbw, 16)
+                   .transpose(0, 2, 1, 3))
+    qp_f = by_front(qp_mb)
+    mf_f, vq_f = by_front(_MF[qp_mb % 6]), by_front(_V[qp_mb % 6])
+    lam2_f = by_front(
+        2 * jnp.asarray(rdo.P_INTRA_LAMBDA, jnp.int32)[qp_mb])
+    policy = _mode_policy(mbw, mbh)[0].reshape(mbh, mbw)[my_idx, mx_clip]
+    has_top = jnp.asarray(np.arange(n) > 0)
+    steps = (src, qp_f, mf_f, vq_f, lam2_f, jnp.asarray(policy),
+             jnp.asarray(mx_idx > 0), jnp.asarray(mx_idx + 1 < mbw))
+
+    def front(carry, xs):
+        left_col, bot1, bot2, corner3, left_modes, mbot1, mbot2 = carry
+        sy, qp_v, mf, vq, lam2, pol, has_left, has_right = xs
+        qbits, qshift = 15 + qp_v // 6, qp_v // 6
+        top_row = _row_above(bot2)                  # (16, n)
+        tr_row = _row_above(bot1)[:4]
+        corner = _row_above(corner3)
+        top_modes = _row_above(mbot2)
+        has_tr_mb = has_top & has_right
+
+        # --- the Intra16x16 candidate ---
+        pred_v = jnp.broadcast_to(top_row[None], (16, 16, n))
+        pred_h = jnp.broadcast_to(left_col[:, None], (16, 16, n))
+        st, sl = top_row.sum(axis=0), left_col.sum(axis=0)
+        dc = jnp.where(has_top & has_left, (st + sl + 16) >> 5,
+                       jnp.where(has_left, (sl + 8) >> 4,
+                                 jnp.where(has_top, (st + 8) >> 4, 128)))
+        pred_dc = jnp.broadcast_to(dc, (16, 16, n))
+        if rd.mode_decision:
+            c = _sath16(sy[None] - jnp.stack([pred_v, pred_h, pred_dc]))
+            c16, mode16 = _pick3(jnp.where(has_top, c[0], _COST_INF), 0,
+                                 jnp.where(has_left, c[1], _COST_INF), 1,
+                                 c[2], 2)
+        else:
+            mode16 = pol
+        pred16 = jnp.where(mode16 == 0, pred_v,
+                           jnp.where(mode16 == 1, pred_h, pred_dc))
+        if not rd.mode_decision:
+            c16 = _sath16(sy - pred16)
+        dc16, z16, rec16 = _i16_code_mb(sy, pred16, mf, vq, qbits, qp_v)
+
+        # --- the Intra4x4 candidate: the blocks in decoding order, two
+        # at a time where neither needs the other (bx + 2 by equal: ten
+        # rounds for sixteen blocks), side by side on the lanes ---
+        rec, modes, levs = {}, {}, {}
+        c4 = lam2 * rdo.I4X4_BITS
+        always = jnp.ones(n, bool)
+
+        def neighbours(bx, by):
+            """(above + above right (8, n), left (4, n), corner, has
+            above, has left, the upper and the left block's mode)."""
+            if by:
+                above = rec[bx, by - 1][3]
+                b_top, mode_b = always, modes[bx, by - 1]
+                above_right = (rec[bx + 1, by - 1][3]
+                               if _BLK_AT[bx, by] not in I4_NO_TOP_RIGHT
+                               else None)
+            else:
+                above = top_row[4 * bx:4 * bx + 4]
+                b_top, mode_b = has_top, top_modes[bx]
+                above_right = (top_row[4 * bx + 4:4 * bx + 8] if bx < 3
+                               else jnp.where(has_tr_mb, tr_row, above[3]))
+            if above_right is None:
+                above_right = jnp.broadcast_to(above[3], (4, n))
+            if bx:
+                beside = rec[bx - 1, by][:, 3]
+                b_left, mode_a = always, modes[bx - 1, by]
+            else:
+                beside = left_col[4 * by:4 * by + 4]
+                b_left, mode_a = has_left, left_modes[by]
+            if bx and by:
+                m = rec[bx - 1, by - 1][3, 3]
+            elif bx:
+                m = top_row[4 * bx - 1]
+            elif by:
+                m = left_col[4 * by - 1]
+            else:
+                m = corner
+            return (jnp.concatenate([above, above_right]), beside, m,
+                    b_top, b_left, mode_a, mode_b,
+                    sy[4 * by:4 * by + 4, 4 * bx:4 * bx + 4])
+
+        for round_ in range(10):
+            blocks = [(bx, by) for bx, by in LUMA_BLOCK_ORDER
+                      if bx + 2 * by == round_]
+            k = len(blocks)
+            wide = lambda x: jnp.concatenate([x] * k, axis=-1)
+            t, beside, m, b_top, b_left, mode_a, mode_b, bsrc = (
+                jnp.concatenate(xs, axis=-1)
+                for xs in zip(*(neighbours(*xy) for xy in blocks)))
+            preds = _i4_predictions(t, beside, m, b_top, b_left)
+            sat = jnp.abs(_had1(_had1(bsrc[None] - preds, 1), 2)) \
+                .sum(axis=(1, 2))                            # (9, k n)
+            both = b_top & b_left
+            pm = jnp.where(both, jnp.minimum(mode_a, mode_b), I4_DC)
+            allowed = jnp.stack([b_top, b_left, wide(always), b_top, both,
+                                 both, both, b_top, b_left])
+            nine = jnp.arange(9)[:, None]
+            cost = jnp.where(
+                allowed,
+                sat + wide(lam2) * jnp.where(pm == nine,
+                                             *rdo.I4X4_MODE_BITS),
+                _COST_INF)
+            # the first of the cheapest: strict-< from mode 0 up
+            mode = jnp.argmin(cost, axis=0).astype(jnp.int32)
+            best = cost.min(axis=0)
+            pred = jnp.where(mode == nine[..., None, None], preds,
+                             0).sum(axis=0)
+            lev, recon = _i4_code_block(bsrc, pred, wide(mf), wide(vq),
+                                        wide(qbits), wide(qshift))
+            for i, xy in enumerate(blocks):
+                mine = slice(i * n, (i + 1) * n)
+                c4 = c4 + best[mine]
+                modes[xy] = mode[mine]
+                levs[xy], rec[xy] = lev[..., mine], recon[..., mine]
+
+        tiles = lambda of: jnp.stack(
+            [jnp.stack([of[bx, by] for bx in range(4)]) for by in range(4)])
+        rec4 = tiles(rec).transpose(0, 2, 1, 3, 4).reshape(16, 16, n)
+        lev4, modes4 = tiles(levs), tiles(modes)     # [by, bx, ...]
+
+        # --- the kind ---
+        i4 = c4 < c16
+        recf = jnp.where(i4, rec4, rec16)
+        modes_f = jnp.where(i4, modes4, I4_DC)
+        out = (dc16, jnp.where(i4, lev4, z16), recf,
+               jnp.where(i4, LUMA_I4X4, mode16), modes_f)
+        carry = (recf[:, 15], recf[15], bot1, bot2[15], modes_f[:, 3],
+                 modes_f[3], mbot1)
+        return carry, out
+
+    z16, z4 = jnp.zeros((16, n), jnp.int32) + zero, \
+        jnp.full((4, n), I4_DC, jnp.int32) + zero
+    _, (dc_f, lev_f, rec_f, mode_f, modes_f) = jax.lax.scan(
+        front, (z16, z16, z16, z16[0], z4, z4, z4), steps)
+
+    def by_mb(a):
+        """(T, ..., n) → (nmb, ...)."""
+        a = jnp.moveaxis(a, -1, 1)[
+            np.arange(mbw)[None] + 2 * np.arange(n)[:, None],
+            np.arange(n)[:, None]]
+        return a.reshape(mbh * mbw, *a.shape[2:])
+
+    # blocks to the z-scan, levels to the zig-zag, once a picture
+    luma_mode = by_mb(mode_f)
+    i4_modes = by_mb(modes_f).reshape(-1, 16)[:, _ZSCAN]
+    lev = _zigzag(by_mb(lev_f).reshape(-1, 16, 4, 4)[:, _ZSCAN])
+    luma_ac = lev[..., 1:]
+    luma_dc = jnp.where((luma_mode == LUMA_I4X4)[:, None], lev[..., 0],
+                        _zigzag(by_mb(dc_f)))
+    recon_y = by_mb(rec_f).reshape(mbh, mbw, 16, 16) \
+        .transpose(0, 2, 1, 3).reshape(16 * mbh, 16 * mbw)
+    # a macroblock that codes no delta takes its predecessor's QP
+    held = ((luma_mode == LUMA_I4X4)
+            & ~jnp.any(luma_dc != 0, axis=1)
+            & ~jnp.any(luma_ac != 0, axis=(1, 2))
+            & ~jnp.any(chroma_dc != 0, axis=(1, 2))
+            & ~jnp.any(chroma_ac != 0, axis=(1, 2, 3)))
+    last = jax.lax.cummax(jnp.where(held, -1, jnp.arange(mbh * mbw)))
+    qp_delta = jnp.where(last < 0, 0, qp_delta[jnp.maximum(last, 0)])
+    return (luma_dc, luma_ac, chroma_dc, chroma_ac, recon_y, recon_u,
+            recon_v, luma_mode, chroma_mode, qp_delta, i4_modes)
+
+
+def _mode_tail(luma_mode, chroma_mode, qp_delta, i4_modes=None):
     """The per-MB side channel appended to intra transfer vectors when
-    rd.ships_modes: [mode16 | dqp16], mode16 = luma | chroma << 4."""
-    return jnp.concatenate([
-        (luma_mode | (chroma_mode << 4)).astype(jnp.int16),
-        qp_delta.astype(jnp.int16)])
+    rd.ships_modes: [mode16 | dqp16], mode16 = luma | chroma << 4, and
+    with rd.intra4x4 (`i4_modes`, _intra_core's eleventh array) the
+    blocks' modes after them, four 4-bit modes to an int16 word, four
+    words a macroblock (encoder.unpack_i4_modes is the inverse)."""
+    parts = [(luma_mode | (chroma_mode << 4)).astype(jnp.int16),
+             qp_delta.astype(jnp.int16)]
+    if i4_modes is not None:
+        m = i4_modes.reshape(-1, 4)
+        w = m[:, 0] | (m[:, 1] << 4) | (m[:, 2] << 8) | (m[:, 3] << 12)
+        # the top nibble reaches the sign bit: wrap by hand, exactly
+        parts.append((w - ((w >> 15) << 16)).astype(jnp.int16))
+    return jnp.concatenate(parts)
 
 
 @functools.partial(jax.jit, static_argnames=("mbw", "mbh", "dtype", "rd"))
@@ -578,14 +1004,13 @@ def _encode_intra_packed(y, u, v, qp, *, mbw: int, mbh: int, dtype,
         flat = jnp.concatenate(parts).astype(dtype)
         if rd.ships_modes:
             flat = jnp.concatenate([flat,
-                                    _mode_tail(out[7], out[8], out[9])
-                                    .astype(dtype)])
+                                    _mode_tail(*out[7:]).astype(dtype)])
     return flat
 
 
 def intra_flat_len(nmb: int, rd=RD_OFF) -> int:
     """Length of one frame's flat intra transfer vector."""
-    return nmb * 384 + (2 * nmb if rd.ships_modes else 0)
+    return nmb * (384 + rd.intra_tail_mb)
 
 
 _I8_MAX = 127
@@ -1019,8 +1444,7 @@ def _encode_intra_sparse(y, u, v, qp, *, mbw: int, mbh: int, rd=RD_OFF):
         parts = [luma_dc.reshape(-1), luma_ac.reshape(-1),
                  chroma_dc.reshape(-1), chroma_ac.reshape(-1)]
         if rd.ships_modes:
-            parts.append(_mode_tail(out[7], out[8], out[9])
-                         .astype(jnp.int32))
+            parts.append(_mode_tail(*out[7:]).astype(jnp.int32))
         flat = jnp.concatenate(parts)
     return _sparse_pack(flat)
 
@@ -1033,12 +1457,17 @@ def _unpack_levels(flat: np.ndarray, mbw: int, mbh: int,
     # keep the transfer dtype: int16 feeds the zero-copy native entry
     # (cavlc_pack_islice16), int32 the original one — no widening here
     flat = np.asarray(flat)
+    i4_modes = None
     if rd.ships_modes:
         mode16 = np.asarray(flat[offs[4]:offs[4] + nmb], np.int32)
         luma_mode = mode16 & 15
         chroma_mode = mode16 >> 4
         qp_delta = np.asarray(flat[offs[4] + nmb:offs[4] + 2 * nmb],
                               np.int32)
+        if rd.intra4x4:
+            i4_modes = unpack_i4_modes(
+                np.asarray(flat[offs[4] + 2 * nmb:offs[4] + 6 * nmb])
+                .astype(np.int16))
     else:
         luma_mode, chroma_mode = _mode_policy(mbw, mbh)
         qp_delta = None
@@ -1050,6 +1479,7 @@ def _unpack_levels(flat: np.ndarray, mbw: int, mbh: int,
         chroma_dc=flat[offs[2]:offs[3]].reshape(nmb, 2, 4),
         chroma_ac=flat[offs[3]:offs[4]].reshape(nmb, 2, 4, 15),
         qp_delta=qp_delta,
+        i4_modes=i4_modes,
     )
 
 

@@ -676,10 +676,10 @@ def _intra_frame_outputs(y, u, v, qp, *, mbw: int, mbh: int, rd):
     """Shared IDR half of the GOP programs: intra core + (optionally)
     deblocked recon carry + the pack-facing intra tuple (4 blocked
     arrays, or 6 with the per-MB [mode16 | dqp16] side channel when
-    rd.ships_modes)."""
+    rd.ships_modes, 7 with rd.intra4x4's block-mode words)."""
     out = _intra_core(y, u, v, qp, mbw=mbw, mbh=mbh, rd=rd)
     il_dc, il_ac, ic_dc, ic_ac, ry, ru, rv = out[:7]
-    luma_mode, chroma_mode, qp_delta = out[7:]
+    qp_delta = out[9]
     with stage("intra"):
         ry = ry.astype(jnp.int16)
         ru = ru.astype(jnp.int16)
@@ -691,9 +691,12 @@ def _intra_frame_outputs(y, u, v, qp, *, mbw: int, mbh: int, rd):
             ry, ru, rv, qp_map, intra=True)
     if rd.ships_modes:
         with stage("intra"):
-            tail = _mode_tail(luma_mode, chroma_mode, qp_delta)
-            intra = (il_dc, il_ac, ic_dc, ic_ac,
-                     tail[:mbw * mbh], tail[mbw * mbh:])
+            tail = _mode_tail(*out[7:])
+            nmb = mbw * mbh
+            intra = (il_dc, il_ac, ic_dc, ic_ac, tail[:nmb],
+                     tail[nmb:2 * nmb])
+            if rd.intra4x4:
+                intra += (tail[2 * nmb:],)
     else:
         intra = (il_dc, il_ac, ic_dc, ic_ac)
     return intra, (ry, ru, rv)
@@ -796,7 +799,8 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
       | u DC (F-1, nmb, 4) | v DC (F-1, nmb, 4)
       | u AC plane (F-1, H/2, W/2) | v AC plane (F-1, H/2, W/2)
       | P pmode (F-1, nmb)                        — rd.p_intra only
-      | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only ]
+      | intra mode16 (nmb) | intra dqp16 (nmb)   — rd.ships_modes only
+      | intra block modes (nmb, 4)                — rd.intra4x4 only ]
     The host inverse is codecs/h264/layout.unflatten_gop.
     """
     _check_mv8(rd)
@@ -828,8 +832,7 @@ def encode_gop_planes(ys, us, vs, qp, *, mbw: int, mbh: int, rd=RD_OFF,
             cacs[:, 0].reshape(-1), cacs[:, 1].reshape(-1),
         ]
         parts.extend(m.reshape(-1) for m in pmodes)
-        if rd.ships_modes:
-            parts.extend([intra[4], intra[5]])
+        parts.extend(intra[4:])
         flat = jnp.concatenate(parts)
     return mv8, flat
 

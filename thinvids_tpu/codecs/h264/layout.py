@@ -259,37 +259,54 @@ def unflatten_p_planes(seg: np.ndarray, mv8: np.ndarray, num_frames: int,
     return (np.asarray(mv8), lp, udc, vdc, uac, vac)
 
 
+def intra_tail_mb(ships_modes: bool, intra4x4: bool = False) -> int:
+    """int16 words a macroblock of the IDR's side channel at the end
+    of the level vector (rdo.RdConfig.intra_tail_mb): [mode16 | dqp16]
+    when modes ship, then the sixteen 4-bit Intra4x4 block modes in
+    four words (encoder.unpack_i4_modes) under `intra4x4`."""
+    if not ships_modes:
+        return 0
+    return 6 if intra4x4 else 2
+
+
+def _split_tail(tail: np.ndarray, nmb: int) -> tuple:
+    """The side channel's (mode16, dqp16[, block-mode words (nmb, 4)])."""
+    return (tail[:nmb], tail[nmb:2 * nmb]) + (
+        (tail[2 * nmb:].reshape(nmb, 4),) if tail.shape[0] > 2 * nmb else ())
+
+
 def unflatten_gop(flat: np.ndarray, mv8: np.ndarray, num_frames: int,
                   mbw: int, mbh: int, ships_modes: bool = False,
-                  p_intra: bool = False):
+                  p_intra: bool = False, intra4x4: bool = False):
     """Host inverse of jaxinter.encode_gop_planes: split the flat int16
     vector into (intra blocked arrays, P plane views). EVERY array is a
     zero-copy view into `flat`. With `ships_modes` the vector ends in
-    the per-MB intra [mode16 | dqp16] side channel, appended to the
-    returned intra tuple; `p_intra` as unflatten_p_planes'."""
+    the per-MB intra [mode16 | dqp16] side channel (and `intra4x4`'s
+    block modes), appended to the returned intra tuple; `p_intra` as
+    unflatten_p_planes'."""
     nmb = mbw * mbh
     flat = np.asarray(flat)
     o = nmb * _INTRA_FLAT_MB
     intra = unflatten_intra(flat[:o], nmb)
-    p_end = flat.shape[0] - (2 * nmb if ships_modes else 0)
+    p_end = flat.shape[0] - nmb * intra_tail_mb(ships_modes, intra4x4)
     planes = unflatten_p_planes(flat[o:p_end], mv8, num_frames, mbw, mbh,
                                 p_intra)
     if ships_modes:
-        intra = intra + (flat[p_end:p_end + nmb], flat[p_end + nmb:])
+        intra = intra + _split_tail(flat[p_end:], nmb)
     return intra, planes
 
 
 def split_dense_dc(dense: np.ndarray, nmb: int, ships_modes: bool = False):
-    """The dense transfer segment [il_dc | ic_dc (| mode16 | dqp16)] →
-    (il_dc, ic_dc) views, and the (mode16, dqp16) tail or ()."""
+    """The dense transfer segment [il_dc | ic_dc (| mode16 | dqp16 (|
+    block modes))] → (il_dc, ic_dc) views, and the side channel's
+    tuple or ()."""
     ndc = nmb * 16
     dense = np.asarray(dense)
     il_dc = dense[:ndc].reshape(nmb, 16)
     ic_dc = dense[ndc:ndc + nmb * 8].reshape(nmb, 2, 4)
     if not ships_modes:
         return il_dc, ic_dc, ()
-    t = ndc + nmb * 8
-    return il_dc, ic_dc, (dense[t:t + nmb], dense[t + nmb:t + 2 * nmb])
+    return il_dc, ic_dc, _split_tail(dense[ndc + nmb * 8:], nmb)
 
 
 def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
@@ -298,7 +315,7 @@ def unflatten_gop_parts(dense: np.ndarray, rest: np.ndarray,
                         p_intra: bool = False):
     """Sparse-path unflatten straight from the two transfer segments —
     dense = [il_dc | ic_dc] (the hadamard DC prefix, _per_gop_sparse;
-    with `ships_modes` also the [mode16 | dqp16] tail, appended to the
+    with `ships_modes` also the side channel's tail, appended to the
     returned intra tuple), rest = [il_ac | ic_ac | P planes] — without
     first concatenating them back into the full flat layout (which
     copied ~25 MB per 1080p GOP). Views only."""

@@ -36,6 +36,14 @@ specialization, never a traced branch:
   (jaxinter._p_intra); the filter takes its bS per edge from the
   per-macroblock map, the packers code the macroblock's kind, and a
   neighbour's vector prediction sees refIdx -1 there.
+- ``intra4x4``: Intra4x4 macroblocks in IDR pictures (§7.3.5 I_NxN,
+  §8.3.1). Every macroblock of an IDR picture is coded Intra16x16,
+  with the candidates it has without the setting, or as sixteen 4x4
+  blocks each predicted from its own reconstructed neighbours in one
+  of §8.3.1.2's nine directions, whichever costs less
+  (jaxcore._intra4x4_luma); the level arrays carry the kind as
+  `luma_mode` 4 (intra.LUMA_I4X4) and the block modes beside them,
+  the packers code mb_type 0. Chroma is the Intra16x16 path's.
 
 This module is deliberately jax-free: the host packers import it
 without initializing a device backend.
@@ -89,6 +97,18 @@ P_INTRA_PASSES = 4
 #: / 3), as x264's table is)
 P_INTRA_LAMBDA = tuple(max(1, int(round(2.0 ** ((q - 12) / 6.0))))
                        for q in range(52))
+#: `intra4x4`'s rate terms, in bits, on the scale of P_INTRA_LAMBDA: a
+#: block whose mode is the predicted one (§8.3.1.1) pays
+#: prev_intra4x4_pred_mode_flag alone, any other the flag and
+#: rem_intra4x4_pred_mode
+I4X4_MODE_BITS = (1, 4)
+#: ... and the handicap of the Intra4x4 kind against Intra16x16: the
+#: decision takes Intra4x4 where `sum over blocks (SATD + lambda *
+#: mode bits) + lambda * I4X4_BITS` is LESS than the Intra16x16
+#: candidate's SATD (ties stay Intra16x16). Chosen in PR 49's step 0
+#: on tools/screen.py at 1080p, QP 25, the serving tools on (PERF.md
+#: §6 has the sweep).
+I4X4_BITS = 16
 #: the per-macroblock word of a P picture's kind channel (`pmode`, the
 #: transfer layouts' and the packers'): 0 = inter; an intra macroblock
 #: holds 1 | Intra16x16 luma mode << 1 | intra chroma mode << 3
@@ -110,6 +130,9 @@ class RdConfig:
     #: intra macroblocks in P pictures (the per-MB inter / Intra16x16
     #: decision of jaxinter._p_intra)
     p_intra: bool = False
+    #: Intra4x4 macroblocks in IDR pictures (the per-MB Intra16x16 /
+    #: Intra4x4 decision of jaxcore._intra4x4_luma)
+    intra4x4: bool = False
 
     def __post_init__(self) -> None:
         if self.subpel not in SUBPELS:
@@ -133,8 +156,17 @@ class RdConfig:
     @property
     def ships_modes(self) -> bool:
         """True when the transfer layouts carry a per-MB intra mode
-        (+ qp-delta) side channel (see layout.extra_len)."""
-        return self.mode_decision or self.aq_q > 0
+        (+ qp-delta) side channel (see layout.intra_tail_mb), which
+        with `intra4x4` also holds the blocks' modes."""
+        return self.mode_decision or self.aq_q > 0 or self.intra4x4
+
+    @property
+    def intra_tail_mb(self) -> int:
+        """int16 words a macroblock in the IDR's side channel
+        (layout.intra_tail_mb, the host's side of the same rule)."""
+        from .layout import intra_tail_mb
+
+        return intra_tail_mb(self.ships_modes, self.intra4x4)
 
 
 #: the feature-off config: every existing path's behavior, bit for bit
@@ -160,6 +192,7 @@ def rd_from_settings(settings) -> RdConfig:
                                        0.0)),
         subpel=subpel_of(settings),
         p_intra=as_bool(settings.get("p_intra", False), False),
+        intra4x4=as_bool(settings.get("intra4x4", False), False),
     )
 
 
@@ -185,6 +218,13 @@ def satd16_np(resid: np.ndarray) -> int:
             t = _H4 @ b @ _H4
             total += int(np.abs(t).sum())
     return total // 2
+
+
+def sath4_np(resid: np.ndarray) -> int:
+    """Sum of |Hadamard4x4| of one (4, 4) residual, NOT halved: the
+    Intra4x4 decision sums it over a macroblock's blocks and compares
+    with twice the Intra16x16 SATD, so no block rounds on its own."""
+    return int(np.abs(_H4 @ resid.astype(np.int64) @ _H4).sum())
 
 
 def satd8_np(resid: np.ndarray) -> int:

@@ -27,6 +27,8 @@ PREFIX = "tvt."
 
 STAGES = (
     "intra",        # _intra_core and what the IDR paths add round it
+    "intra4x4",     # rd.intra4x4: the IDR's luma again, each macroblock
+                    # Intra16x16 or Intra4x4 (jaxcore._intra4x4_luma)
     "me_prep",      # search centers, padding, center stacks
     "me_search",    # the motion-search kernel (or its XLA mirror)
     "me_median",    # the frame's median MV (next frame's center)

@@ -91,12 +91,21 @@ DEFAULT_SETTINGS: dict[str, Any] = {
     #   reaches +-4 pixels round three frame-global centres. One more
     #   executable per resolution; GOP-shape jobs (transcode, ladder,
     #   live); a band-shape job (`sfe_bands`) is refused at admission.
+    # intra4x4 (TVT_INTRA4X4): Intra4x4 macroblocks in IDR pictures
+    #   (§7.3.5 I_NxN, §8.3.1): every macroblock of an IDR picture is
+    #   coded Intra16x16 or as sixteen 4x4 blocks, each predicted from
+    #   its own reconstructed neighbours in one of nine directions,
+    #   whichever costs less — what screen content (text, window
+    #   edges, UI over video) needs. One more executable per
+    #   resolution; GOP-shape jobs (transcode, ladder, live); a
+    #   band-shape job (`sfe_bands`) is refused at admission.
     "mode_decision": False,
     "pskip": False,
     "deblock": False,
     "aq_strength": 0.0,
     "subpel": "half",
     "p_intra": False,
+    "intra4x4": False,
     # ABR ladder subsystem (abr/): default job type for registrations
     # that don't say (watch-folder drops named *.ladder.* always become
     # ladder jobs), the rung heights (TVT_LADDER_RUNGS; heights at or
@@ -359,6 +368,7 @@ _CLAMPS: dict[str, Callable[[Any], Any]] = {
     "subpel": lambda v: (s if (s := subpel_of({"subpel": v})) in SUBPELS
                          else "half"),
     "p_intra": lambda v: as_bool(v, False),
+    "intra4x4": lambda v: as_bool(v, False),
     "gop_frames": lambda v: min(600, max(1, as_int(v, 32))),
     "scenecut": lambda v: min(100, max(0, as_int(v, 0))),
     "max_segments": lambda v: min(4096, max(1, as_int(v, 200))),
@@ -590,8 +600,9 @@ JOB_SETTING_KEYS = frozenset(
      # refuses a value other than the daemon's (cluster/policy.py) —
      # left out of these keys it would be dropped without a word
      "subpel",
-     # likewise intra macroblocks in P pictures
-     "p_intra"}
+     # likewise intra macroblocks in P pictures and Intra4x4
+     # macroblocks in IDR pictures
+     "p_intra", "intra4x4"}
 )
 
 

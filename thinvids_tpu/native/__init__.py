@@ -146,6 +146,7 @@ def _build_and_load() -> ctypes.CDLL:
             ctypes.c_int32, ctypes.c_int32,             # mbw, mbh
             ctypes.c_void_p, ctypes.c_int64,            # out, cap
             ctypes.c_void_p,                            # qp_delta (or NULL)
+            ctypes.c_void_p,                            # i4_modes (or NULL)
         ]
         lib.cavlc_pack_islice.restype = ctypes.c_int64
         lib.cavlc_pack_islice.argtypes = _islice_sig
@@ -232,7 +233,8 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
                 luma_dc: np.ndarray, luma_ac: np.ndarray,
                 chroma_dc: np.ndarray, chroma_ac: np.ndarray,
                 mbw: int, mbh: int,
-                qp_delta: np.ndarray | None = None) -> bytes:
+                qp_delta: np.ndarray | None = None,
+                i4_modes: np.ndarray | None = None) -> bytes:
     """Pack one I-slice (header bits + MB layer) and return the EBSP payload.
 
     When all four level arrays arrive as int16 (the flat transfer layout's
@@ -240,7 +242,9 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
     `cavlc_pack_islice16` entry; anything else is widened to int32 and
     packed through the original entry. Identical bits either way.
     `qp_delta` (per-MB qp offsets vs the slice qp, perceptual AQ) emits
-    chained mb_qp_delta values instead of se(0).
+    chained mb_qp_delta values instead of se(0). `i4_modes` ((nmb, 16)
+    Intra4x4PredMode, z-scan order) serves the macroblocks whose
+    luma_mode is 4 (Intra4x4, mb_type I_NxN; FrameLevels.i4_modes).
     """
     lib = _build_and_load()
     nmb = mbw * mbh
@@ -264,6 +268,10 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
     if qp_delta is not None:
         qp_delta = prep(qp_delta, (nmb,), np.int8)
         dqp_ptr = qp_delta.ctypes.data
+    i4_ptr = None
+    if i4_modes is not None:
+        i4_modes = prep(i4_modes, (nmb, 16), np.uint8)
+        i4_ptr = i4_modes.ctypes.data
 
     # CAVLC worst case ≈ 28 bits/coeff × 384 coeffs ≈ 1.4 KB per MB (plus
     # emulation-prevention expansion); 4 KB/MB is a safe ceiling.
@@ -276,7 +284,10 @@ def pack_islice(header_bytes: bytes, header_bit_len: int,
         luma_mode.ctypes.data, chroma_mode.ctypes.data,
         luma_dc.ctypes.data, luma_ac.ctypes.data,
         chroma_dc.ctypes.data, chroma_ac.ctypes.data,
-        mbw, mbh, out.ctypes.data, cap, dqp_ptr)
+        mbw, mbh, out.ctypes.data, cap, dqp_ptr, i4_ptr)
+    if n == -4:
+        raise ValueError("Intra4x4 macroblock without modes, with a mode "
+                         "past 8, or with no level and a changed QP")
     if n == -2:
         raise RuntimeError("native packer output buffer overflow")
     if n == -3:

@@ -208,9 +208,30 @@ static int64_t emit_ebsp(const BitWriter& bw, uint8_t* out, int64_t out_cap) {
   return o;
 }
 
+// Table 9-4, the Intra_4x4 column (chroma_format_idc 1): codeNum ->
+// coded_block_pattern (encoder.CODE_TO_CBP_INTRA).
+static const uint8_t kCodeToCbpIntra[48] = {
+    47, 31, 15, 0,  23, 27, 29, 30, 7,  11, 13, 14, 39, 43, 45, 46,
+    16, 3,  5,  10, 12, 19, 21, 26, 28, 35, 37, 42, 44, 1,  2,  4,
+    8,  17, 18, 20, 24, 6,  9,  22, 25, 32, 33, 34, 36, 40, 38, 41};
+
+struct CbpIntraToCode {
+  uint8_t code[48];
+  CbpIntraToCode() {
+    for (int c = 0; c < 48; c++) code[kCodeToCbpIntra[c]] = (uint8_t)c;
+  }
+};
+static const CbpIntraToCode g_cbp_intra;
+
 // Packs slice-header bits + all MB data + rbsp trailing, applies emulation
-// prevention. Returns EBSP byte length, or -1 on error / -2 if out_cap is
-// too small. Templated over the level dtype: the sharded transfer hands
+// prevention. A macroblock whose luma_mode is 4 is Intra4x4 (mb_type
+// I_NxN): its sixteen block modes come from `i4_modes` (nmb * 16, z-scan
+// order), block b's sixteen levels are luma_dc[b] then luma_ac[b][0..14],
+// its coded_block_pattern is me(v) with one luma bit a quadrant, and it
+// codes mb_qp_delta only where the pattern is not 0. Returns EBSP byte
+// length, or -1 on error / -2 if out_cap is too small / -4 on an
+// Intra4x4 macroblock without modes, with a mode past 8, or with no
+// level and a QP other than its predecessor's. Templated over the level dtype: the sharded transfer hands
 // the host int16 views (cavlc_pack_islice16) and packing them directly
 // kills the ~4-array astype(int32) copy chain that used to run per GOP.
 template <typename T>
@@ -223,7 +244,8 @@ static int64_t pack_islice_impl(
     const T* chroma_ac,  // nmb*2*4*15
     int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap,
     const int8_t* qp_delta /* nmb per-MB qp offsets vs slice qp, or
-                              nullptr = flat QP (se(0) per MB) */) {
+                              nullptr = flat QP (se(0) per MB) */,
+    const uint8_t* i4_modes /* nmb*16 Intra4x4PredMode, or nullptr */) {
   if (!g_tables_ready || mbw <= 0 || mbh <= 0) return -1;
   // z-scan order of 4x4 luma blocks within a MB: (bx, by)
   static const int BX[16] = {0, 1, 0, 1, 2, 3, 2, 3, 0, 1, 0, 1, 2, 3, 2, 3};
@@ -242,6 +264,9 @@ static int64_t pack_islice_impl(
   const int cw = 2 * mbw, ch = 2 * mbh;
   std::vector<int32_t> lcnt((size_t)lw * lh, 0);
   std::vector<int32_t> ccnt((size_t)2 * cw * ch, 0);
+  // Intra4x4PredMode of every 4x4 block (8.3.1.1 predicts from the
+  // neighbours'); an Intra16x16 macroblock's blocks read DC (2)
+  std::vector<uint8_t> bmode(i4_modes ? (size_t)lw * lh : 0, 2);
 
   auto luma_nc = [&](int gy, int gx) {
     return nc_from_counts(lcnt.data(), lw, gy, gx);
@@ -258,9 +283,21 @@ static int64_t pack_islice_impl(
       const T* cac = chroma_ac + (size_t)mi * 2 * 4 * 15;
       const T* cdc = chroma_dc + (size_t)mi * 2 * 4;
 
+      const T* ldc = luma_dc + (size_t)mi * 16;
+      const bool i4x4 = luma_mode[mi] == 4;
+      if (i4x4 && !i4_modes) return -4;
       int cbp_luma = 0;
-      for (int i = 0; i < 16 * 15 && !cbp_luma; i++)
-        if (lac[i]) cbp_luma = 15;
+      if (i4x4) {
+        for (int bi = 0; bi < 16; bi++) {
+          if (cbp_luma & (1 << (bi / 4))) continue;
+          bool any = ldc[bi] != 0;
+          for (int i = 0; i < 15 && !any; i++) any = lac[bi * 15 + i] != 0;
+          if (any) cbp_luma |= 1 << (bi / 4);
+        }
+      } else {
+        for (int i = 0; i < 16 * 15 && !cbp_luma; i++)
+          if (lac[i]) cbp_luma = 15;
+      }
       int cbp_chroma = 0;
       for (int i = 0; i < 2 * 4 * 15 && cbp_chroma < 2; i++)
         if (cac[i]) cbp_chroma = 2;
@@ -268,10 +305,36 @@ static int64_t pack_islice_impl(
         for (int i = 0; i < 8 && !cbp_chroma; i++)
           if (cdc[i]) cbp_chroma = 1;
 
-      int mb_type = 1 + luma_mode[mi] + 4 * cbp_chroma + (cbp_luma ? 12 : 0);
-      bw.ue((uint32_t)mb_type);
+      const int by0 = 4 * my, bx0 = 4 * mx;
+      if (i4x4) {
+        bw.ue(0);  // mb_type I_NxN
+        for (int bi = 0; bi < 16; bi++) {
+          const int gy = by0 + BY[bi], gx = bx0 + BX[bi];
+          const int mode = i4_modes[(size_t)mi * 16 + bi];
+          if (mode > 8) return -4;
+          int pm = 2;
+          if (gx > 0 && gy > 0) {
+            const int a = bmode[(size_t)gy * lw + gx - 1];
+            const int b = bmode[(size_t)(gy - 1) * lw + gx];
+            pm = a < b ? a : b;
+          }
+          bmode[(size_t)gy * lw + gx] = (uint8_t)mode;
+          if (mode == pm)
+            bw.write(1, 1);  // prev_intra4x4_pred_mode_flag
+          else               // the flag 0, rem_intra4x4_pred_mode u(3)
+            bw.write((uint32_t)(mode - (mode > pm)), 4);
+        }
+      } else {
+        int mb_type =
+            1 + luma_mode[mi] + 4 * cbp_chroma + (cbp_luma ? 12 : 0);
+        bw.ue((uint32_t)mb_type);
+      }
       bw.ue((uint32_t)chroma_mode[mi]);
-      if (qp_delta) {
+      if (i4x4) bw.ue(g_cbp_intra.code[cbp_luma | (cbp_chroma << 4)]);
+      if (i4x4 && !cbp_luma && !cbp_chroma) {
+        // no mb_qp_delta (7.3.5): the macroblock's QP is the one before
+        if (qp_delta && qp_delta[mi] != prev_qp_off) return -4;
+      } else if (qp_delta) {
         // mb_qp_delta chains vs the previous MB's qp (§7.4.5);
         // qp_delta[] holds offsets vs the slice qp.
         bw.se((int32_t)qp_delta[mi] - prev_qp_off);
@@ -280,15 +343,22 @@ static int64_t pack_islice_impl(
         bw.se(0);  // mb_qp_delta
       }
 
-      const int by0 = 4 * my, bx0 = 4 * mx;
-      if (encode_residual(bw, luma_dc + (size_t)mi * 16, 16,
-                          luma_nc(by0, bx0)) < 0)
+      if (!i4x4 && encode_residual(bw, ldc, 16, luma_nc(by0, bx0)) < 0)
         return -3;
 
       for (int bi = 0; bi < 16; bi++) {
         int gy = by0 + BY[bi], gx = bx0 + BX[bi];
-        if (cbp_luma) {
-          int tc = encode_residual(bw, lac + (size_t)bi * 15, 15, luma_nc(gy, gx));
+        if (cbp_luma & (1 << (bi / 4))) {
+          int tc;
+          if (i4x4) {
+            T blk[16];
+            blk[0] = ldc[bi];
+            for (int i = 0; i < 15; i++) blk[i + 1] = lac[bi * 15 + i];
+            tc = encode_residual(bw, blk, 16, luma_nc(gy, gx));
+          } else {
+            tc = encode_residual(bw, lac + (size_t)bi * 15, 15,
+                                 luma_nc(gy, gx));
+          }
           if (tc < 0) return -3;
           lcnt[(size_t)gy * lw + gx] = tc;
         } else {
@@ -532,10 +602,11 @@ int64_t cavlc_pack_islice(
     const int32_t* luma_dc, const int32_t* luma_ac,
     const int32_t* chroma_dc, const int32_t* chroma_ac,
     int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap,
-    const int8_t* qp_delta) {
+    const int8_t* qp_delta, const uint8_t* i4_modes) {
   return pack_islice_impl(header_bytes, header_bit_len, luma_mode,
                           chroma_mode, luma_dc, luma_ac, chroma_dc,
-                          chroma_ac, mbw, mbh, out, out_cap, qp_delta);
+                          chroma_ac, mbw, mbh, out, out_cap, qp_delta,
+                          i4_modes);
 }
 
 // int16 entry: packs the flat transfer layout's level views directly.
@@ -545,10 +616,11 @@ int64_t cavlc_pack_islice16(
     const int16_t* luma_dc, const int16_t* luma_ac,
     const int16_t* chroma_dc, const int16_t* chroma_ac,
     int32_t mbw, int32_t mbh, uint8_t* out, int64_t out_cap,
-    const int8_t* qp_delta) {
+    const int8_t* qp_delta, const uint8_t* i4_modes) {
   return pack_islice_impl(header_bytes, header_bit_len, luma_mode,
                           chroma_mode, luma_dc, luma_ac, chroma_dc,
-                          chroma_ac, mbw, mbh, out, out_cap, qp_delta);
+                          chroma_ac, mbw, mbh, out, out_cap, qp_delta,
+                          i4_modes);
 }
 
 // Host inverse of jaxcore._block_sparse_pack2 over the three separate

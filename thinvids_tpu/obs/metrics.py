@@ -364,6 +364,13 @@ STAGE_COUNTER_TOTALS = {
     "p_mbs_intra": REGISTRY.counter(
         "tvt_p_mbs_intra_total",
         "of those, macroblocks coded Intra16x16"),
+    "i_mbs_coded": REGISTRY.counter(
+        "tvt_i_mbs_coded_total",
+        "macroblocks of IDR pictures handed to the packers with their "
+        "kind (intra4x4 alone counts them)"),
+    "i_mbs_4x4": REGISTRY.counter(
+        "tvt_i_mbs_4x4_total",
+        "of those, macroblocks coded Intra4x4"),
     "unpack_ranges": REGISTRY.counter(
         "tvt_unpack_ranges_total",
         "runs of a compact payload's level vector unpacked inside the "

@@ -156,7 +156,9 @@ STAGE_NAMES = ("decode", "stage", "upload", "scale", "dispatch",
 #: macroblocks' vectors handed to the packers / those of them with an odd
 #: quarter-sample component: 0 under subpel="half"; count_vectors), p_mbs_coded
 #: / p_mbs_intra (macroblocks of P pictures handed to the packers under p_intra
-#: / those of them intra; count_kinds), unpack_ranges (runs of a compact
+#: / those of them intra; count_kinds), i_mbs_coded / i_mbs_4x4 (macroblocks of
+#: IDR pictures handed to the packers under intra4x4 / those of them Intra4x4;
+#: count_i_kinds), unpack_ranges (runs of a compact
 #: payload's level vector unpacked inside slice thunks: 2 + (frames packed - 1)
 #: x (5, or 6 under p_intra) a GOP; 0 on a dense wave, a split-frame job or a
 #: host without the native library)
@@ -166,7 +168,8 @@ STAGE_COUNTERS = ("dense_fallback_waves", "h2d_bytes", "stage_copy_bytes",
                   "sparse_values_used", "sparse_values_budget", "scene_cuts",
                   "scene_cuts_suppressed", "wave_frames", "pad_frames",
                   "pad_frames_skipped", "mvs_coded", "mvs_quarter",
-                  "p_mbs_coded", "p_mbs_intra", "unpack_ranges")
+                  "p_mbs_coded", "p_mbs_intra", "i_mbs_coded", "i_mbs_4x4",
+                  "unpack_ranges")
 #: last-value readings riding in the same snapshot: me_candidates (what
 #: the motion search of the last GOP / step program called scores per
 #: macroblock: `program_build`; 0 until one ran)
@@ -530,11 +533,12 @@ def _per_gop_sparse(y, u, v, qp, mbw: int, mbh: int, rd=RD_OFF,
     with stage("pack"):
         dense_parts = [flat[:ndc], flat[ndc + nlac:ndc + nlac + ncdc]]
         if rd.ships_modes:
-            # intra [mode16 | dqp16] tail rides the dense prefix (it is
-            # small and mode 0 = V would defeat the sparse pack anyway)
-            dense_parts.append(flat[-2 * nmb:])
+            # intra [mode16 | dqp16 (| block modes)] tail rides the dense
+            # prefix (small, and mode 0 = V would defeat the sparse pack)
+            tail = nmb * rd.intra_tail_mb
+            dense_parts.append(flat[-tail:])
             rest = jnp.concatenate([flat[ndc:ndc + nlac],
-                                    flat[ndc + nlac + ncdc:-2 * nmb]])
+                                    flat[ndc + nlac + ncdc:-tail]])
         else:
             rest = jnp.concatenate([flat[ndc:ndc + nlac],
                                     flat[ndc + nlac + ncdc:]])
@@ -1111,9 +1115,9 @@ class GopShardEncoder:
     def _level_sizes(self, F: int, nmb: int) -> tuple[int, int]:
         """(L, Lr) of one GOP's flat levels: the whole vector, and its
         sparse remainder once both intra hadamard DC segments (luma +
-        chroma) and the [mode16 | dqp16] tail, when shipped, go dense
+        chroma) and the side channel's tail, when shipped, go dense
         (_per_gop_sparse)."""
-        tail = 2 * nmb if self.rd.ships_modes else 0
+        tail = nmb * self.rd.intra_tail_mb
         L = nmb * _INTRA_MB \
             + (F - 1) * nmb * p_flat_mb(self.rd.p_intra) + tail
         return L, L - nmb * 16 - nmb * 8 - tail
@@ -1260,9 +1264,11 @@ class GopShardEncoder:
                 with prof.stage("unflatten"):
                     intra, planes = unflatten_gop(
                         flat[gi], mv8[gi], F, mbw, mbh,
-                        ships_modes=ships_modes, p_intra=self.rd.p_intra)
+                        ships_modes=ships_modes, p_intra=self.rd.p_intra,
+                        intra4x4=self.rd.intra4x4)
                 if self.rd.p_intra:
                     count_kinds(prof, planes[6][:gop.num_frames - 1])
+                count_i_kinds(prof, intra, self.rd)
                 thunks = gop_slice_thunks_planes(
                     intra, planes, *slices, idr_pic_id=gop.index,
                     rd=self.rd)
@@ -1739,6 +1745,13 @@ class SfeShardEncoder(GopShardEncoder):
             # (cluster/policy.py), rather than encode all-inter
             raise ValueError(
                 "p_intra is not supported by split-frame encoding; "
+                "encode this job in GOP shape (sfe_bands 0)")
+        if self.rd.intra4x4:
+            # the band steps code an IDR band Intra16x16 alone (their
+            # slice-local rows have no wavefront): refuse, as admission
+            # does (cluster/policy.py)
+            raise ValueError(
+                "intra4x4 is not supported by split-frame encoding; "
                 "encode this job in GOP shape (sfe_bands 0)")
         if self.rd.aq_q:
             _LOG.warning("perceptual AQ is not supported by split-frame "
@@ -2262,6 +2275,16 @@ def count_kinds(profile: StageProfile, pmode) -> None:
     profile.bump("p_mbs_intra", int(np.count_nonzero(pmode)))
 
 
+def count_i_kinds(profile: StageProfile, intra: tuple, rd) -> None:
+    """Counters `i_mbs_coded` / `i_mbs_4x4` for an IDR picture's levels
+    on their way to the packers (rd.intra4x4; nothing without): its
+    macroblocks, and those whose mode16 word says Intra4x4."""
+    if rd.intra4x4:
+        kind = np.asarray(intra[4]) & 15
+        profile.bump("i_mbs_coded", int(kind.size))
+        profile.bump("i_mbs_4x4", int(np.count_nonzero(kind == 4)))
+
+
 @contextlib.contextmanager
 def program_build(form: str, rd, shape, *more):
     """Round one call of a step program from OUTSIDE its jit: the first
@@ -2342,6 +2365,7 @@ def _compact_gop_thunks(stages: StageProfile, payload: np.ndarray,
             p_intra=rd.p_intra)
     if rd.p_intra:
         count_kinds(stages, planes[6][:num_frames - 1])
+    count_i_kinds(stages, intra, rd)
     return gop_slice_thunks_planes(intra, planes, *slices,
                                    idr_pic_id=idr_pic_id, rd=rd)
 
@@ -2368,7 +2392,7 @@ class _CompactGop:
         self._index = native.index_compact(*self._stream)
         self._unpack_range = native.unpack_compact_range
         self._dc = split_dense_dc(dense, mbw * mbh, rd.ships_modes)
-        self._mv8, self._p_intra = mv8, rd.p_intra
+        self._mv8, self._rd = mv8, rd
         self._intra, self._frames = rest_spans(F, mbw, mbh, rd.p_intra)
         self._frame_levels = mbw * mbh * p_flat_mb(rd.p_intra)
 
@@ -2387,10 +2411,12 @@ class _CompactGop:
     def intra(self) -> tuple:
         il_dc, ic_dc, modes = self._dc
         il_ac, ic_ac = self._unpack(self._intra)
-        return (il_dc, il_ac, ic_dc, ic_ac) + modes
+        intra = (il_dc, il_ac, ic_dc, ic_ac) + modes
+        count_i_kinds(self._stages, intra, self._rd)
+        return intra
 
     def p_frame(self, i: int) -> tuple:
         views = self._unpack(self._frames[i])
-        if self._p_intra:
+        if self._rd.p_intra:
             count_kinds(self._stages, views[5])
         return (self._mv8[i], *views)

@@ -198,6 +198,64 @@ def fuzz_pack(native_mod, rng: np.random.Generator) -> None:
         f"native={nat[0]} python={py[0]}")
 
 
+def random_islice_case(seed: int):
+    """An I slice of random macroblock kinds (`intra4x4`: half of them
+    Intra4x4, mb_type I_NxN), random Intra4x4PredModes, sparse random
+    levels (some macroblocks with none at all) and QP offsets that obey
+    §7.3.5: an Intra4x4 macroblock without a level keeps its
+    predecessor's. → (FrameLevels, mbw, mbh)."""
+    from ..codecs.h264.encoder import FrameLevels
+
+    rng = np.random.default_rng([20261004, seed])
+    mbw, mbh = int(rng.integers(1, 6)), int(rng.integers(1, 5))
+    nmb = mbw * mbh
+    scale = int(rng.choice([1, 3, 40, 2000]))
+    density = float(rng.choice([0.0, 0.02, 0.2, 0.7]))
+    flat = np.where(rng.random(nmb * 384) < density,
+                    rng.integers(-scale, scale + 1, nmb * 384),
+                    0).astype(np.int16)
+    flat.reshape(nmb, 384)[rng.random(nmb) < 0.3] = 0
+    o = nmb * 16
+    levels = FrameLevels(
+        luma_mode=np.where(rng.random(nmb) < 0.5, 4,
+                           rng.integers(0, 3, nmb)).astype(np.int32),
+        chroma_mode=rng.integers(0, 3, nmb).astype(np.int32),
+        luma_dc=flat[:o].reshape(nmb, 16),
+        luma_ac=flat[o:o + nmb * 240].reshape(nmb, 16, 15),
+        chroma_dc=flat[o + nmb * 240:o + nmb * 248].reshape(nmb, 2, 4),
+        chroma_ac=flat[o + nmb * 248:].reshape(nmb, 2, 4, 15),
+        qp_delta=rng.integers(-6, 7, nmb).astype(np.int32),
+        i4_modes=rng.integers(0, 9, (nmb, 16)).astype(np.int32))
+    # row 0 / column 0 Intra16x16 macroblocks need a mode they have
+    levels.luma_mode[:mbw][levels.luma_mode[:mbw] != 4] = 2
+    levels.luma_mode[::mbw][levels.luma_mode[::mbw] != 4] = 2
+    prev = 0
+    for mi in range(nmb):
+        if levels.luma_mode[mi] == 4 and not flat.reshape(nmb, 384)[mi].any():
+            levels.qp_delta[mi] = prev
+        prev = levels.qp_delta[mi]
+    return levels, mbw, mbh
+
+
+def islice_packers_agree(case) -> bool:
+    """The native and the pure-Python I-slice packers on one
+    :func:`random_islice_case`: the same NAL bytes, or both reject the
+    levels with ValueError."""
+    from ..codecs.h264.encoder import pack_slice
+    from ..codecs.h264.headers import PPS, SPS
+
+    levels, mbw, mbh = case
+    sps, pps = SPS(width=mbw * 16, height=mbh * 16), PPS(init_qp=27)
+    got = []
+    for use_native in (True, False):
+        try:
+            got.append(("ok", pack_slice(levels, mbw, mbh, sps, pps, 27,
+                                         native=use_native)))
+        except ValueError:
+            got.append(("reject", None))
+    return got[0] == got[1]
+
+
 def fuzz_pack_p(native_mod, rng: np.random.Generator) -> None:
     """Drive both P-slice packers (blocked `cavlc_pack_pslice`, plane
     `cavlc_pack_pslice_plane`) with random vectors, levels and — half
@@ -325,7 +383,7 @@ def main(argv: list[str] | None = None) -> int:
 
     rng = np.random.default_rng(args.seed)
     cases = accepted = rejected = 0
-    for _ in range(args.iterations):
+    for it in range(args.iterations):
         L, nblk, nval, payload, bitmap, masks, vals = \
             build_valid_case(rng)
         # valid case: both accept, bit-identical
@@ -378,6 +436,8 @@ def main(argv: list[str] | None = None) -> int:
             rejected += r
         fuzz_pack(native_mod, rng)
         fuzz_pack_p(native_mod, rng)
+        assert islice_packers_agree(random_islice_case(
+            args.seed + it)), f"Intra4x4 pack parity divergence at {it}"
     print(f"fuzz_native: {args.iterations} valid cases, {cases} "
           f"mutations ({accepted} accepted, {rejected} rejected), "
           f"0 crashes, 0 divergences")
