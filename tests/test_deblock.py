@@ -309,6 +309,57 @@ def test_probe_box_sums_compile_for_the_chip_without_a_relayout(
     assert memory.temp_size_in_bytes <= 8 * 2 * H * W
 
 
+@pytest.mark.parametrize("subpel", ["half", "quarter"])
+def test_me_kernel_compiles_for_the_chip_at_1080p(subpel, monkeypatch):
+    """ISSUE 50, kept in this file because one test file may describe
+    the TPU topology: the motion-search kernel compiles for a described
+    v5e at the served 1080p shape under both tables — Mosaic takes the
+    (256, 128) selector, the row's `take` masks stacked 32 sublanes a
+    candidate and their (128, 384) expander, which the interpreter
+    (tests/test_jaxme.py) runs but cannot refuse. Nothing runs."""
+    import functools
+    import os
+
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from thinvids_tpu.codecs.h264 import jaxme
+
+    monkeypatch.setitem(os.environ, "TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:          # no TPU compiler in this image
+        pytest.skip(f"no v5e topology can be described here: {exc}")
+    chip = SingleDeviceSharding(topo.devices[0])
+    H, W = 1088, 1920
+    _mbh, _mbw, H4, RG, WcK, nch, W2K, _WcuK, W2cK = jaxme._geom(H, W)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    lowered = jax.jit(functools.partial(
+        jaxme._me_pallas, H=H, W=W, interpret=False, subpel=subpel)).lower(
+        arg((1, 8), jnp.int32), arg((H4, WcK), jnp.int16),
+        arg((3, H4 + 128, W2K), jnp.int16),
+        arg((3, H4 // 2 + 64, W2cK), jnp.int16),
+        arg((3, H4 // 2 + 64, W2cK), jnp.int16),
+        arg(jaxme._ss_np().shape, jnp.bfloat16),
+        arg(jaxme._ex_np().shape, jnp.bfloat16))
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    assert "tpu_custom_call" in compiled.as_text()
+    # one vector value a macroblock: 16 live lanes of the 128 a tile has
+    # (the three prediction planes beside them, and the tuple's table)
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 0 <= out - (RG * nch * 8 * 128 * 4 + 3 * H4 * WcK) < 4096
+
+
 class TestBandSplit:
     """A split-frame band is a slice with disable_deblocking_filter_idc
     2: it filters its own rows, and no edge between two bands."""

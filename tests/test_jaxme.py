@@ -23,21 +23,30 @@ from thinvids_tpu.codecs.h264 import jaxme
 from thinvids_tpu.codecs.h264.rdo import MV_PER_PEL
 
 
-def _mixed_motion_frames(w, h, seed=0):
-    """(cur, ref_y, ref_u, ref_v) where different MBs have different
-    true motion: the left half pans (+3, +3), the right half (-2, +1),
-    with texture + noise so SADs are distinctive."""
+def _frames_over_noise(w, h, seed, cut):
+    """(cur, ref_y, ref_u, ref_v) over a noise scene: ref is the scene,
+    `cut(scene, pad)` the current frame taken from it (pad samples of
+    scene lie round the picture); texture + noise, so SADs are
+    distinctive."""
     rng = np.random.default_rng(seed)
     pad = 8
     scene = rng.integers(0, 255, (h + 2 * pad, w + 2 * pad)).astype(np.uint8)
     ref = scene[pad:pad + h, pad:pad + w]
-    cur = np.empty_like(ref)
-    cur[:, :w // 2] = scene[pad + 3:pad + 3 + h, pad + 3:pad + 3 + w // 2]
-    cur[:, w // 2:] = scene[pad - 2:pad - 2 + h,
-                            pad + w // 2 + 1:pad + w + 1]
+    cur = cut(scene, pad)
     ref_u = rng.integers(0, 255, (h // 2, w // 2)).astype(np.uint8)
     ref_v = rng.integers(0, 255, (h // 2, w // 2)).astype(np.uint8)
     return cur, ref, ref_u, ref_v
+
+
+def _mixed_motion_frames(w, h, seed=0):
+    """Different MBs have different true motion: the left half pans
+    (+3, +3), the right half (-2, +1)."""
+    def cut(scene, pad):
+        return np.concatenate([
+            scene[pad + 3:pad + 3 + h, pad + 3:pad + 3 + w // 2],
+            scene[pad - 2:pad - 2 + h, pad + w // 2 + 1:pad + w + 1]],
+            axis=1)
+    return _frames_over_noise(w, h, seed, cut)
 
 
 SUBPELS = ("half", "quarter")
@@ -148,6 +157,44 @@ def test_pallas_kernel_breaks_ties_in_table_order(kind, subpel):
         assert len({tuple(v) for v in mv}) > 1
 
 
+def _per_mb_motion_frames(w, h, dy, seed=3):
+    """Every macroblock column c moves by its OWN whole-pixel vector
+    (dy, (c % 9) - 4): the sixteen macroblocks of a 256-lane chunk all
+    find their match in ONE row of candidates (the integer class's row
+    wy = 2 dy, nine candidates) and nine different candidates of it."""
+    def cut(scene, pad):
+        return np.concatenate([
+            scene[pad + dy:pad + dy + h,
+                  pad + 16 * c + c % 9 - 4:pad + 16 * c + c % 9 + 12]
+            for c in range(w // 16)], axis=1)
+    return _frames_over_noise(w, h, seed, cut)
+
+
+# The running best is per MACROBLOCK and a row's `take` masks are
+# widened to luma and chroma lanes together, once per row of candidates:
+# a mask that lands on a neighbour's lanes (or a chroma mask on the
+# wrong eight) shows here, where every macroblock of a chunk takes
+# another candidate of the same row, and nowhere on uniform motion.
+# 512 x 64: two chunks; the second one's columns start another cycle.
+@pytest.mark.parametrize("subpel", SUBPELS)
+@pytest.mark.parametrize("dy", [1, -2])
+def test_neighbours_take_different_winners_in_one_row(dy, subpel):
+    w, h = 512, 64
+    per_pel = MV_PER_PEL[subpel]
+    mv = _kernel_and_spec(_per_mb_motion_frames(w, h, dy),
+                          jnp.zeros((3, 2), jnp.int32),
+                          jnp.asarray(jaxme._LAMBDAS[subpel])[27],
+                          "mask expansion, lane for lane", subpel)
+    mv = mv.reshape(h // 16, w // 16, 2)
+    want_x = per_pel * (np.arange(w // 16) % 9 - 4)
+    # interior columns: a column at the picture's edge may match the
+    # clamped reference as well somewhere else
+    assert (mv[:, 1:-1, 0] == per_pel * dy).all()
+    assert (mv[:, 1:-1, 1] == want_x[None, 1:-1]).all()
+    for chunk in (mv[0, :16, 1], mv[0, 16:, 1]):
+        assert len(set(chunk.tolist())) >= 8
+
+
 def test_quarter_table_is_the_half_table_and_more():
     """The quarter table holds the half table's candidates in quarter
     units and, beside them: the fine half-sample classes round the
@@ -192,26 +239,39 @@ def _sub_jaxprs(eqn):
 
 
 def _kernel_matmuls(jaxpr, trips=1):
-    """[(times run per grid step, left-side rows)] of every matmul in
-    the kernel body, loops multiplied out."""
+    """[(times run per grid step, left side's shape, right side's
+    shape)] of every matmul in the kernel body, loops multiplied out."""
     out = []
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         assert name != "while", "a loop without a static trip count"
         if name == "dot_general":
-            out.append((trips, eqn.invars[0].aval.shape[0]))
+            out.append((trips, eqn.invars[0].aval.shape,
+                        eqn.invars[1].aval.shape))
         inner = trips * eqn.params["length"] if name == "scan" else trips
         for sub in _sub_jaxprs(eqn):
             out += _kernel_matmuls(sub, inner)
     return out
 
 
-@pytest.mark.parametrize("subpel", SUBPELS)
-def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
-    """The mechanism's "how often" is static: per grid step the MXU is
-    handed the constant selector once per row of candidates (39 rows;
-    68 under "quarter"), not once per candidate (227; 379), and
-    never for fewer than 128 rows."""
+def _kernel_loop_carries(jaxpr):
+    """[avals carried from step to step] of every loop in the kernel
+    body."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            nc = eqn.params["num_consts"]
+            out.append([v.aval for v in
+                        eqn.invars[nc:nc + eqn.params["num_carry"]]])
+        for sub in _sub_jaxprs(eqn):
+            out += _kernel_loop_carries(sub)
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_jaxpr(subpel):
+    """The body of the one `pallas_call` `_me_pallas` makes (320 x 128:
+    2 bands x 2 chunks)."""
     H, W = 128, 320
     _mbh, _mbw, H4, _RG, WcK, _nch, W2K, _WcuK, W2cK = jaxme._geom(H, W)
     sd = jax.ShapeDtypeStruct
@@ -221,7 +281,8 @@ def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
         sd((3, H4 + 128, W2K), jnp.int16),
         sd((3, H4 // 2 + 64, W2cK), jnp.int16),
         sd((3, H4 // 2 + 64, W2cK), jnp.int16),
-        sd((256, 384), jnp.bfloat16))
+        sd(jaxme._ss_np().shape, jnp.bfloat16),
+        sd(jaxme._ex_np().shape, jnp.bfloat16))
 
     def find(jaxpr):
         for eqn in jaxpr.eqns:
@@ -232,7 +293,26 @@ def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
 
     calls = list(find(closed.jaxpr))
     assert len(calls) == 1, "one kernel, one pallas_call"
-    matmuls = _kernel_matmuls(calls[0].params["jaxpr"])
+    return calls[0].params["jaxpr"]
+
+
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
+    """The mechanism's "how often" is static. The selector the kernel is
+    handed is (256, 128): one column a macroblock of the chunk, not one
+    a lane. Per grid step the MXU gets it once per row of candidates
+    (39 rows; 68 under "quarter"), not once per candidate (227; 379),
+    never for fewer than 128 rows, and each candidate's 64 rows pass it
+    exactly once. The row's `take` masks are widened to lanes by one
+    more matmul a row, against the (128, 384) expander — counted apart
+    — and nothing the MXU writes is wider than its 384 columns."""
+    assert jaxme._ss_np().shape == (256, 128)
+    assert jaxme._ex_np().shape == (128, 384)
+    matmuls = _kernel_matmuls(_kernel_jaxpr(subpel))
+    select = [(t, lhs[0]) for t, lhs, rhs in matmuls if rhs == (256, 128)]
+    expand = [(t, lhs[0]) for t, lhs, rhs in matmuls if rhs == (128, 384)]
+    assert len(select) + len(expand) == len(matmuls)
+    assert max(rhs[1] for _t, _lhs, rhs in matmuls) <= 384
 
     rows = sum(len(wys) for (cl, _q) in jaxme.CENTERS[subpel]
                for (_p, wys, _wxs) in cl) \
@@ -241,11 +321,31 @@ def test_kernel_runs_one_matmul_per_row_of_candidates(subpel):
     cands = len(jaxme.offset_table(subpel))
     assert (rows, cands) == {"half": (39, 227),
                              "quarter": (68, 379)}[subpel]
-    assert sum(t for t, _m in matmuls) == rows
+    assert sum(t for t, _m in select) == rows
     assert 4 * rows < cands
     # every candidate is in exactly one matmul, 64 rows of it each
-    assert sum(t * m for t, m in matmuls) == 64 * cands
-    assert min(m for _t, m in matmuls) >= 128
+    assert sum(t * m for t, m in select) == 64 * cands
+    assert min(m for _t, m in select) >= 128
+    # the expander: once a row of candidates; a candidate's mask goes on
+    # 8 sublanes deep for each of its 4 macroblock rows
+    assert sum(t for t, _m in expand) == rows
+    assert sum(t * m for t, m in expand) == 32 * cands
+
+
+@pytest.mark.parametrize("subpel", SUBPELS)
+def test_kernel_keeps_one_running_best(subpel):
+    """Luma and chroma follow ONE running best per macroblock: what a
+    class's loop carries beside the planes and the three predictions is
+    one (4, 128) f32 cost and its two (4, 128) int32 vector components
+    — no second cost over chroma lanes (the (4, 128) f32 `bestcc` the
+    kernel had until PR 50), none per luma lane ((4, 256))."""
+    loops = _kernel_loop_carries(_kernel_jaxpr(subpel))
+    assert len(loops) == sum(len(cl) for (cl, _q) in jaxme.CENTERS[subpel])
+    for carries in loops:
+        small = [(a.shape, str(a.dtype)) for a in carries
+                 if len(a.shape) == 2 and a.shape[0] == 4]
+        assert sorted(small) == [((4, 128), "float32"),
+                                 ((4, 128), "int32"), ((4, 128), "int32")]
 
 
 # ---------------------------------------------------------------------------

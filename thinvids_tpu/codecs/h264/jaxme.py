@@ -15,12 +15,14 @@ is the hot op and is built TPU-first):
   candidates (fixed wy, 2..9 values of wx): the row's |cur - cand|
   planes (64 rows each) are laid under each other and multiplied by
   the constant 0/1 block-sum selector, `dot(stack(64 nx, 256),
-  SS(256, 384))` — 39 matmuls per grid step for the 227 candidates.
+  SS(256, 128))` — 39 matmuls per grid step for the 227 candidates —
+  and a 16-row group sum leaves ONE sum a macroblock, on lane m of 128.
   absdiff values (<= 255) are exact in bf16 and the f32 accumulation
-  is exact (< 2^24), so the SADs are integer-exact. SS leaves every
-  lane holding its MB's sum, so no per-MB -> per-lane expansion is
-  needed (pltpu.repeat is a TILE repeat, not the element repeat it
-  looks like).
+  is exact (< 2^24), so the SADs are integer-exact. The running best
+  is per macroblock; a row's `take` masks reach the lanes of the
+  predictions by a second 0/1 matmul, `dot(takes(32 nx, 128),
+  EX(128, 384))` (pltpu.repeat is a TILE repeat, not the element
+  repeat it looks like).
 - Search centers are folded in on the XLA side: the wide-padded
   reference planes are re-anchored per center with dynamic slices and
   stacked (leading dim 3), so the kernel needs no dynamic shifts at
@@ -296,13 +298,15 @@ def _geom(H: int, W: int):
     resolution-independent (a frame-wide variant overflowed the 16 MB
     physical VMEM at 1080p). The band is 64 rows, and the block-sum
     matmul takes a whole ROW of candidates (M = 64 nx = 128..576),
-    because a matmul against the constant selector has a fixed cost
-    worth about 90 rows: in a serial loop on a v5e, dot((M, 256),
-    SS(256, 384)) with its |x - c| producer took 169 / 210 / 275 / 437
-    / 920 ns at M = 16 / 64 / 128 / 256 / 576 — about 120 ns + 1.4 ns
-    per row (PR 27, step 0; PERF.md §5). Per 64 rows that is 210 ns at
-    M = 64 (one candidate per matmul), 102 ns at M = 576, and 674 ns
-    at M = 16 — the "3x slower" once measured for a 16-row band."""
+    because a matmul against a constant selector has a fixed cost
+    worth about 90 rows (PR 27's step 0, with the (256, 384) selector
+    of the time: about 120 ns + 1.4 ns per row in a serial loop on a
+    v5e — 210 ns per 64 rows at one candidate a matmul, 102 ns at
+    nine). Step 0 of PR 50 (PERF.md §5; `_me_pallas` alone at 1088 x
+    1920, ten calls back to back, ms a call, one v5e): the (256, 384)
+    selector 4.95-4.98 (9.44-9.45 under "quarter"), the (256, 128) one
+    with the masks' expander 4.69-4.70 (9.12); 18.27 -> 17.31 at 2176 x
+    3840 (35.09 -> 33.88)."""
     mbh, mbw = H // 16, W // 16
     H4 = _round_up(H, 64)               # band-padded height
     RG = H4 // 64                       # grid rows (bands)
@@ -321,24 +325,36 @@ _LWC = 256                  # chroma: 2 x 128-lane blocks
 
 @functools.lru_cache(maxsize=None)
 def _ss_np():
-    """(256, 384) per-lane block-sum, luma and chroma fused into ONE
-    matmul: columns [0, 256) put every luma lane's MB SAD on that lane
-    (out[l, l2] = 1 iff l // 16 == l2 // 16), columns [256, 384) do the
-    same for chroma lanes (l // 16 == c // 8). dot(ad, SS) followed by
-    a row-group sum leaves every lane holding its MB's SAD — the
-    running best state stays per-lane and needs no MB->lane
-    expansion. The left side is a row of candidates' |cur - cand|
-    planes laid under each other (row_body), so SS is pushed into the
-    MXU 39 times per grid step, not 227. Each MB sum is written 16
-    (chroma: 8) times over: 388 GFLOP per 1080p P frame, which the
-    kernel runs at about 40 % of the v5e's bf16 peak (PERF.md §5)."""
-    m = np.zeros((256, 384), np.float32)
-    for l in range(256):
-        mb = l // 16
-        for l2 in range(16 * mb, 16 * mb + 16):
-            m[l, l2] = 1.0
-        for c in range(8 * mb, 8 * mb + 8):
-            m[l, 256 + c] = 1.0
+    """(256, 128) block-sum selector: column m < 16 is 1 on the 16 lanes
+    of the chunk's macroblock m (out[l, m] = 1 iff l // 16 == m), the
+    other 112 columns 0 — 128 is the narrowest tile the MXU takes.
+    dot(ad, SS) followed by a 16-row group sum leaves ONE sum per
+    macroblock, on lane m, so the running best is per macroblock. The
+    left side is a row of candidates' |cur - cand| planes laid under
+    each other (score_row), so SS is pushed into the MXU 39 times per
+    grid step, not 227. With the masks' way back to the lanes (_ex_np)
+    that is 227 GFLOP per 1080p P frame — 130 here, 97 there — where
+    the (256, 384) selector that wrote every sum on 16 luma and 8
+    chroma lanes took 388 (PERF.md §3, §5). Not built, because step 0
+    of PR 50 read no lower with it: the 16-row group sum on the MXU as
+    well (a 0/1 left factor on the stack, the sums then through SS as
+    16 hi + lo): 4.82-4.84 ms a 1080p call for 4.69-4.72."""
+    m = np.zeros((256, 128), np.float32)
+    m[np.arange(256), np.arange(256) // 16] = 1.0
+    return m
+
+
+@functools.lru_cache(maxsize=None)
+def _ex_np():
+    """(128, 384) mask expander, `_ss_np`'s counterpart: row m < 16 is 1
+    on macroblock m's 16 luma lanes (columns 16 m ... 16 m + 15) and on
+    its 8 chroma lanes (columns 256 + 8 m ... 256 + 8 m + 7), so
+    dot(takes, EX) widens a row of candidates' 0/1 `take` masks from
+    macroblocks to the lanes the prediction selects run on, luma and
+    chroma in one pass (select_row)."""
+    m = np.zeros((128, 384), np.float32)
+    m[np.arange(256) // 16, np.arange(256)] = 1.0
+    m[np.arange(128) // 8, 256 + np.arange(128)] = 1.0
     return m
 
 
@@ -394,7 +410,7 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
                ry00, ry10, ry20, ry30, ry01, ry11, ry21, ry31,
                ru00, ru10, ru20, ru30, ru01, ru11, ru21, ru31,
                rv00, rv10, rv20, rv30, rv01, rv11, rv21, rv31,
-               ss_ref, _dmv, _dpy, _dpu, _dpv,
+               ss_ref, ex_ref, _dmv, _dpy, _dpu, _dpv,
                mv_ref, py_ref, pu_ref, pv_ref):
         # Inputs arrive PRE-CENTERED per search center (leading dim 3,
         # XLA-side dynamic slice of a wide pad): no dynamic shifts in
@@ -413,7 +429,8 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
             jnp.concatenate([rv01[:], rv11[:], rv21[:], rv31[:]], axis=1),
         ], axis=2)
         cur = cur_ref[:].astype(jnp.float32)              # (64, 256)
-        SS = ss_ref[:]                                    # (256, 384) bf16
+        SS = ss_ref[:]                                    # (256, 128) bf16
+        EX = ex_ref[:]                                    # (128, 384) bf16
         lam = cent_ref[0, 6].astype(jnp.float32)
 
         # constant-shift rolls only; negative shifts wrap mod the size
@@ -424,23 +441,25 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
             """Roll rows by a traced 0/1 without a dynamic rotate."""
             return jnp.where(flag > 0, roll_rows(x, -1), x)
 
-        # Running best per LANE (4 MB rows x 256 luma / 128 chroma
-        # lanes). Luma and chroma track the same per-MB cost values in
-        # the same order, so their winners agree exactly (integer-exact
-        # f32 sums), and the chroma prediction always matches the coded
-        # luma MV.
-        bestc = jnp.full((4, 256), 2.0**30, jnp.float32)
-        bmy = jnp.zeros((4, 256), jnp.int32)
-        bmx = jnp.zeros((4, 256), jnp.int32)
+        # ONE running best per MACROBLOCK (4 MB rows x the chunk's 16
+        # MBs on lanes 0..15 of 128; the other lanes score 0 + the MV
+        # cost and are never read): cost and vector. Luma and chroma
+        # predictions follow it through the row's `take` masks, widened
+        # from macroblocks to lanes once per row of candidates
+        # (select_row), so the chroma prediction always matches the
+        # coded luma MV.
+        best = jnp.full((4, 128), 2.0**30, jnp.float32)
+        bmy = jnp.zeros((4, 128), jnp.int32)
+        bmx = jnp.zeros((4, 128), jnp.int32)
         py = jnp.zeros((64, 256), jnp.float32)
-        bestcc = jnp.full((4, 128), 2.0**30, jnp.float32)
         pu = jnp.zeros((32, 128), jnp.int32)
         pv = jnp.zeros((32, 128), jnp.int32)
-        state = (bestc, bmy, bmx, py, bestcc, pu, pv)
+        state = (best, bmy, bmx, py, pu, pv)
 
         def score_row(cands):
             """The row's |cur - cand| planes laid under each other and
-            block-summed by ONE matmul against SS."""
+            block-summed by ONE matmul against SS: column m of the
+            (64 nx, 128) result holds macroblock m's row sums."""
             stack = jnp.concatenate(
                 [jnp.abs(cur - cand).astype(jnp.bfloat16)
                  for cand in cands], axis=0)              # (64 nx, 256)
@@ -471,32 +490,43 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
                 return cpred
 
             cpred_u, cpred_v = chroma_row(Cur), chroma_row(Cvr)
-            bestc, bmy, bmx, py, bestcc, pu, pv = state
-            for k, (cand, frac) in enumerate(zip(cands, fracs)):
-                sad4a = jax.lax.slice(sad, (64 * k, 0), (64 * k + 64, 384)
-                                      ).reshape(4, 16, 384).sum(1)
-                sad4 = jax.lax.slice(sad4a, (0, 0), (4, 256))
-                sad4c = jax.lax.slice(sad4a, (0, 256), (4, 384))
+            best, bmy, bmx, py, pu, pv = state
+            # the serial chain runs on costs alone, one vreg a candidate;
+            # each `take` goes on as 0/1, its macroblock rows 8 sublanes
+            # deep (a vreg's)
+            takes = []
+            for k in range(len(cands)):
+                sad4 = jax.lax.slice(sad, (64 * k, 0), (64 * k + 64, 128)
+                                     ).reshape(4, 16, 128).sum(1)
                 mvx = mvx_of(k)
-                pen = lam * (jnp.abs(mvy) + jnp.abs(mvx)
-                             ).astype(jnp.float32)
-                cost = sad4 + pen
-                take = cost < bestc                       # (4, 256) bool
-                bestc = jnp.where(take, cost, bestc)
+                cost = sad4 + lam * (jnp.abs(mvy) + jnp.abs(mvx)
+                                     ).astype(jnp.float32)
+                take = cost < best                        # (4, 128) bool
+                best = jnp.where(take, cost, best)
                 bmy = jnp.where(take, mvy, bmy)
                 bmx = jnp.where(take, mvx, bmx)
-                tly = jnp.broadcast_to(take[:, None, :], (4, 16, 256)
-                                       ).reshape(64, 256)
-                py = jnp.where(tly, cand, py)
-
-                costc = sad4c + pen
-                takec = costc < bestcc                    # (4, 128)
-                bestcc = jnp.where(takec, costc, bestcc)
-                mc = jnp.broadcast_to(takec[:, None, :], (4, 8, 128)
-                                      ).reshape(32, 128)
+                takes.append(jnp.broadcast_to(
+                    take.astype(jnp.float32)[:, None, :], (4, 8, 128)
+                    ).reshape(32, 128))
+            # the row's masks, macroblocks -> luma and chroma lanes, by
+            # ONE 0/1 matmul: 32 rows a candidate come back as whole
+            # vregs of the predictions' tiling — 8 of a macroblock row's
+            # 16 luma rows (taken twice), its 8 chroma rows — and stay
+            # f32 until they are used (a bool costs more to move than
+            # to make)
+            wide = jnp.dot(jnp.concatenate(takes, axis=0
+                                           ).astype(jnp.bfloat16), EX,
+                           preferred_element_type=jnp.float32)
+            for k, (cand, frac) in enumerate(zip(cands, fracs)):
+                tly = jax.lax.slice(wide, (32 * k, 0), (32 * k + 32, 256))
+                tly = jnp.broadcast_to(tly.reshape(4, 1, 8, 256),
+                                       (4, 2, 8, 256)).reshape(64, 256)
+                py = jnp.where(tly > 0.5, cand, py)
+                mc = jax.lax.slice(wide, (32 * k, 256),
+                                   (32 * k + 32, 384)) > 0.5
                 pu = jnp.where(mc, cpred_u(*frac), pu)
                 pv = jnp.where(mc, cpred_v(*frac), pv)
-            return (bestc, bmy, bmx, py, bestcc, pu, pv)
+            return (best, bmy, bmx, py, pu, pv)
 
         def row_body(state, Pl, Cur, Cvr, wy, wxs, cy, cx):
             """One ROW of candidates (fixed wy, every wx of `wxs`): Pl,
@@ -631,7 +661,7 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
 
         for ci, (classes, rows) in enumerate(CENTERS[subpel]):
             state = run_center(ci, classes, state, rows)
-        bestc, bmy, bmx, py, bestcc, pu, pv = state
+        _best, bmy, bmx, py, pu, pv = state
 
         mv_ref[0, 0, 0:4, :] = bmy
         mv_ref[0, 0, 4:8, :] = bmx
@@ -644,7 +674,7 @@ def _me_kernel(H: int, W: int, subpel: str = "half"):
 
 @functools.partial(jax.jit,
                    static_argnames=("H", "W", "interpret", "subpel"))
-def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
+def _me_pallas(cent, cur, refy, refu, refv, ss, ex, *, H: int,
                W: int, interpret: bool, subpel: str = "half"):
     mbh, mbw, H4, RG, WcK, nch, W2K, WcuK, W2cK = _geom(H, W)
     vspec = lambda shape, imap: pl.BlockSpec(shape, imap,
@@ -666,19 +696,19 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
                 in_specs.append(vspec((3, 16, 128), functools.partial(
                     lambda r, c, k=0, kl=0: (0, 2 * r + k, c + kl),
                     k=k, kl=kl)))
-    in_specs.append(vspec((256, 384), lambda r, c: (0, 0)))
+    in_specs += [vspec(m.shape, lambda r, c: (0, 0)) for m in (ss, ex)]
 
     # under shard_map the outputs vary over the same mesh axes as the
     # frame they are computed from (check_vma requires it to be said)
     vma = jax.typeof(cur).vma
     out_shape = (
-        jax.ShapeDtypeStruct((RG, nch, 8, 256), jnp.int32, vma=vma),
+        jax.ShapeDtypeStruct((RG, nch, 8, 128), jnp.int32, vma=vma),
         jax.ShapeDtypeStruct((H4, WcK), jnp.int16, vma=vma),
         jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16, vma=vma),
         jax.ShapeDtypeStruct((H4 // 2, WcuK), jnp.int16, vma=vma),
     )
     out_specs = (
-        pl.BlockSpec((1, 1, 8, 256), lambda r, c: (r, c, 0, 0),
+        pl.BlockSpec((1, 1, 8, 128), lambda r, c: (r, c, 0, 0),
                      memory_space=pltpu.VMEM),
         vspec((64, 256), lambda r, c: (r, c)),
         vspec((32, 128), lambda r, c: (r, c)),
@@ -692,13 +722,13 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
     # constants) so XLA cannot CSE them.
     z16 = (cur[0, 0] * 0).astype(jnp.int16)
     dummies = (
-        jnp.zeros((RG, nch, 8, 256), jnp.int32) + z16.astype(jnp.int32),
+        jnp.zeros((RG, nch, 8, 128), jnp.int32) + z16.astype(jnp.int32),
         jnp.zeros((H4, WcK), jnp.int16) + z16,
         jnp.zeros((H4 // 2, WcuK), jnp.int16) + z16,
         jnp.zeros((H4 // 2, WcuK), jnp.int16) + z16,
     )
     in_specs += list(out_specs)
-    n_in = 27
+    n_in = 28
     return pl.pallas_call(
         _me_kernel(H, W, subpel),
         grid=(RG, nch),
@@ -708,7 +738,7 @@ def _me_pallas(cent, cur, refy, refu, refv, ss, *, H: int,
         interpret=interpret,
         input_output_aliases={n_in + i: i for i in range(4)},
     )(cent, cur,
-      *[refy] * 8, *[refu] * 8, *[refv] * 8, ss, *dummies)
+      *[refy] * 8, *[refu] * 8, *[refv] * 8, ss, ex, *dummies)
 
 
 # ---------------------------------------------------------------------------
@@ -1037,14 +1067,15 @@ def me_search_pallas(cur_y16, ref_y16, ref_u16, ref_v16, centers, lam,
         refu = _center_stack(wu_, ccys, ccxs, H4 // 2 + 64, W2cK)
         refv = _center_stack(wv_, ccys, ccxs, H4 // 2 + 64, W2cK)
         ss = jnp.asarray(_ss_np(), jnp.bfloat16)
+        ex = jnp.asarray(_ex_np(), jnp.bfloat16)
     with stage("me_search"):
-        mvo, py, pu, pv = _me_pallas(cent, cur, refy, refu, refv, ss,
+        mvo, py, pu, pv = _me_pallas(cent, cur, refy, refu, refv, ss, ex,
                                      H=H, W=W, interpret=interpret,
                                      subpel=subpel)
-        # (RG, nch, 8, 256): rows 0:4 = bmy, 4:8 = bmx, one per MB row
-        # of the band; per-MB values sit at every 16th lane
-        bmy = mvo[:, :, 0:4, ::16]                # (RG, nch, 4, 16)
-        bmx = mvo[:, :, 4:8, ::16]
+        # (RG, nch, 8, 128): rows 0:4 = bmy, 4:8 = bmx, one per MB row
+        # of the band; the chunk's 16 MBs sit on lanes 0..15
+        bmy = mvo[:, :, 0:4, :16]                 # (RG, nch, 4, 16)
+        bmx = mvo[:, :, 4:8, :16]
         bmy = bmy.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
         bmx = bmx.transpose(0, 2, 1, 3).reshape(4 * RG, nch * 16)
         mv = jnp.stack([bmy[:mbh, :mbw], bmx[:mbh, :mbw]], axis=-1)
